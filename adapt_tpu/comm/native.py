@@ -3,8 +3,9 @@
 The reference leans on pip-native compression (lz4/zfpy C bindings,
 ``/root/reference/README.md:19``); our native piece is first-party:
 ``native/qcodec.cpp``, an LZ77 byte codec compiled on first use with g++
-and loaded through ctypes (no pybind11 in this image). Falls back to
-zlib (stdlib) if no toolchain is available, keeping the codec API usable
+into ``native/build/`` (git-ignored: a checkout carries the source, never
+a binary built elsewhere) and loaded through ctypes. Falls back to zlib
+(stdlib) if no toolchain is available, keeping the codec API usable
 everywhere.
 """
 

@@ -50,6 +50,7 @@ from adapt_tpu.comm.framing import (
 from adapt_tpu.config import FaultConfig
 from adapt_tpu.control.registry import WorkerRegistry
 from adapt_tpu.control.worker import TaskResult, WorkerState
+from adapt_tpu.utils.compile_cache import ensure_compile_cache
 from adapt_tpu.utils.logging import get_logger
 from adapt_tpu.utils.metrics import global_metrics
 from adapt_tpu.utils.telemetry import (
@@ -985,6 +986,9 @@ class RemoteWorkerProxy:
     def is_configured(self, stage_index: int) -> bool:
         return stage_index in self._configured
 
+    def configured_stages(self) -> tuple[int, ...]:
+        return tuple(sorted(self._configured))
+
     def configure(
         self, stage_index: int, fn, host_variables, spec=None, abort=None
     ) -> int:
@@ -1503,7 +1507,16 @@ def main() -> None:
         help="dial a dispatcher WorkerGateway and join its pool",
     )
     p.add_argument("--worker-id", default=None)
-    p.add_argument("--device-index", type=int, default=0)
+    p.add_argument(
+        "--device-index",
+        type=int,
+        default=0,
+        help="index into THIS process's jax.devices(). A TPU chip "
+        "belongs to one process at a time: on real chips run ONE worker "
+        "process per host (it sees every local chip), not one per chip "
+        "— several local processes each asking for the chip only works "
+        "on the CPU backend",
+    )
     p.add_argument("--host", default="127.0.0.1")
     p.add_argument("--heartbeat", type=float, default=0.5)
     p.add_argument(
@@ -1527,6 +1540,7 @@ def main() -> None:
     args = p.parse_args()
     if (args.port is None) == (args.connect is None):
         p.error("exactly one of --port / --connect is required")
+    ensure_compile_cache()
     server = RemoteStageServer(
         args.port or 0,
         device_index=args.device_index,

@@ -752,9 +752,9 @@ class Dispatcher:
     def _acquire(self, stage_index: int, exclude: set[str]) -> StageWorker:
         """Late binding: pick a live worker for this stage *now* (reference
         ``_acquire_and_configure_worker``, call site
-        ``src/dispatcher.py:178``). Preference: already-configured idle >
-        idle > shallowest queue; excluded (suspect) workers only as a last
-        resort."""
+        ``src/dispatcher.py:178``). Preference: already-configured >
+        holding the fewest other stages > idle > shallowest queue;
+        excluded (suspect) workers only as a last resort."""
         # Role-tagged leases partition the pool: a worker registered
         # under a dedicated role (the disaggregated serving tier's
         # role="prefill" pool, runtime/disagg) must never be acquired
@@ -795,6 +795,13 @@ class Dispatcher:
                 # silent hang — see _watchdog_loop).
                 1 if strikes.get(w.worker_id, 0) else 0,
                 0 if w.is_configured(stage_index) else 1,
+                # Spread before stacking: a stage that must be newly
+                # bound goes to the worker holding the fewest stages,
+                # so a pool with a chip per stage pipelines across
+                # chips (and a failover lands on the least-loaded
+                # survivor) instead of piling onto whichever idle
+                # worker the shuffle put first.
+                len(w.configured_stages()),
                 0 if w.state is WorkerState.IDLE else 1,
                 w.queue_depth,
             )

@@ -181,6 +181,11 @@ class StageWorker:
         with self._bind_lock:
             return stage_index in self._bindings
 
+    def configured_stages(self) -> tuple[int, ...]:
+        """Stage indices currently bound to this worker's device."""
+        with self._bind_lock:
+            return tuple(sorted(self._bindings))
+
     def configure(
         self, stage_index: int, fn, host_variables, spec=None, abort=None
     ) -> int:

@@ -375,7 +375,7 @@ class CausalSelfAttention(nn.Module):
 
     def decode_step_paged(
         self, x_t, k_pool, v_pool, page_table, index, valid_from=None,
-        attn_impl=None, split=None,
+        attn_impl=None, split=None, head_shard=None,
     ):
         """One token against a PAGED cache (``ops/paged_attention``):
         write this step's K/V into the slot's physical page at
@@ -387,7 +387,10 @@ class CausalSelfAttention(nn.Module):
         before the scatter, and dequant fuses into the attention — see
         ``ops/paged_attention``); ``page_table`` (b, pages_per_slot)
         int32 (idle rows may map everything to the trash page — their
-        writes land there, unread)."""
+        writes land there, unread). ``head_shard`` = ``(mesh, axis)``
+        when the caller's program is tp-partitioned: the Pallas kernel
+        then runs per head shard (``ops.paged_attention._head_sharded``)
+        — here and in the chunk/verify twins below."""
         b = x_t.shape[0]
         page = pool_values(k_pool).shape[2]
         q, k, v = self._project(x_t)  # q (b, h, 1, hd); k/v (b, kv_h, 1, hd)
@@ -421,7 +424,7 @@ class CausalSelfAttention(nn.Module):
         o = paged_attention(
             q, k_pool, v_pool, page_table, index,
             self._window_from(index, b, valid_from), prefer=attn_impl,
-            split=split,
+            split=split, head_shard=head_shard,
         ).astype(x_t.dtype)
         o = self._ungroup_o(o, 1)
         o = jnp.swapaxes(o, 1, 2).reshape(b, 1, self.dim)
@@ -429,6 +432,7 @@ class CausalSelfAttention(nn.Module):
 
     def prefill_chunk_paged(
         self, x, k_pool, v_pool, pages, pos0, attn_impl=None,
+        head_shard=None,
     ):
         """Incremental prefill of a CHUNK of positions [pos0, pos0 + C)
         directly against a paged window: write the chunk's K/V into its
@@ -466,7 +470,7 @@ class CausalSelfAttention(nn.Module):
         k_pool, v_pool = self._write_kv_pair(k_pool, v_pool, k, v, write)
         o = paged_chunk_attention(
             q, k_pool, v_pool, pages, pos0, c, prefer=attn_impl,
-            window=self.window,
+            window=self.window, head_shard=head_shard,
         ).astype(x.dtype)
         o = self._ungroup_o(o, c)  # (1, h, C, hd)
         o = jnp.swapaxes(o, 1, 2).reshape(b, c, self.dim)
@@ -614,7 +618,7 @@ class CausalSelfAttention(nn.Module):
 
     def verify_chunk_paged(
         self, x, k_pool, v_pool, page_table, index, attn_impl=None,
-        tree_tail=0, split=None,
+        tree_tail=0, split=None, head_shard=None,
     ):
         """Batched verify over a PAGED cache: scatter each slot's K
         chunk tokens into its own pages at ``index[b]..index[b]+K-1``
@@ -657,6 +661,7 @@ class CausalSelfAttention(nn.Module):
         o = paged_verify_attention(
             q, k_pool, v_pool, page_table, idx, kc, prefer=attn_impl,
             window=self.window, tree_tail=tree_tail, split=split,
+            head_shard=head_shard,
         ).astype(x.dtype)
         o = self._ungroup_o(o, kc)
         o = jnp.swapaxes(o, 1, 2).reshape(b, kc, self.dim)
@@ -743,20 +748,21 @@ class DecoderBlock(nn.Module):
 
     def decode_step_paged(
         self, x_t, k_pool, v_pool, page_table, index, valid_from=None,
-        attn_impl=None, split=None,
+        attn_impl=None, split=None, head_shard=None,
     ):
         a, kp, vp = self.attn.decode_step_paged(
             self.ln1(x_t), k_pool, v_pool, page_table, index, valid_from,
-            attn_impl, split,
+            attn_impl, split, head_shard,
         )
         x_t = x_t + a
         return x_t + self._mlp(self.ln2(x_t)), kp, vp
 
     def prefill_chunk_paged(
         self, x, k_pool, v_pool, pages, pos0, attn_impl=None,
+        head_shard=None,
     ):
         a, kp, vp = self.attn.prefill_chunk_paged(
-            self.ln1(x), k_pool, v_pool, pages, pos0, attn_impl
+            self.ln1(x), k_pool, v_pool, pages, pos0, attn_impl, head_shard
         )
         x = x + a
         return x + self._mlp(self.ln2(x)), kp, vp
@@ -777,11 +783,11 @@ class DecoderBlock(nn.Module):
 
     def verify_chunk_paged(
         self, x, k_pool, v_pool, page_table, index, attn_impl=None,
-        tree_tail=0, split=None,
+        tree_tail=0, split=None, head_shard=None,
     ):
         a, kp, vp = self.attn.verify_chunk_paged(
             self.ln1(x), k_pool, v_pool, page_table, index, attn_impl,
-            tree_tail, split,
+            tree_tail, split, head_shard,
         )
         x = x + a
         return x + self._mlp(self.ln2(x)), kp, vp

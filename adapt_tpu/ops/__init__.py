@@ -15,9 +15,9 @@ from adapt_tpu.ops.decode_attention import (
     decode_attention,
     decode_attention_reference,
     default_decode_split,
-    kernel_dispatch_stats,
     verify_attention,
 )
+from adapt_tpu.ops.dispatch import kernel_dispatch_stats
 from adapt_tpu.ops.paged_attention import (
     paged_attention,
     paged_attention_reference,

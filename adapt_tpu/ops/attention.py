@@ -30,21 +30,17 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
+from adapt_tpu.ops.dispatch import pallas_interpret, record_kernel_dispatch
 
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
-
+_VMEM = pltpu.VMEM
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
 _NEG_INF = -1e30
 
 #: Measured dispatch budget (real v5e chip, artifacts
-#: benchmarks/results/r03/{attn_crossover,attn_longseq}.json and the
+#: benchmarks/results/r03/attn_longseq.json and the
 #: end-to-end ViT A/B in tpu_vit_b16_ab.json): XLA's fused attention
 #: beats the Pallas kernel while the materialized f32 score tensor
 #: (batch*heads*s_q*s_k*4 bytes) is small — end-to-end ViT-B/16 ran 1.9x
@@ -137,6 +133,11 @@ def _attn_kernel(
     j = pl.program_id(2)
     block_q = q_ref.shape[1]
     q_start = pl.program_id(1) * block_q
+    # Whole-array SMEM vectors: this (batch, head) row's scalar is read
+    # by program_id (Mosaic refuses a (1,) block of a longer rank-1
+    # array).
+    vf = vf_ref[pl.program_id(0)] if has_vf else None
+    shift = shift_ref[0] if has_shift else 0
 
     @pl.when(j == 0)
     def _init():
@@ -168,12 +169,11 @@ def _attn_kernel(
         if has_vf:
             # Ragged head: keys before this row's first real token are
             # left padding.
-            s = jnp.where(cols >= vf_ref[0], s, _NEG_INF)
+            s = jnp.where(cols >= vf, s, _NEG_INF)
         if causal:
             rows = q_start + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0
             )
-            shift = shift_ref[0] if has_shift else 0
             s = jnp.where(rows >= cols + shift, s, _NEG_INF)
             if window is not None:
                 # Sliding band: row i attends cols in (i - window, i].
@@ -194,10 +194,7 @@ def _attn_kernel(
     # lands, the MXU stays idle).
     live = None
     if causal:
-        live = (
-            j * block_k + (shift_ref[0] if has_shift else 0)
-            <= q_start + block_q - 1
-        )
+        live = j * block_k + shift <= q_start + block_q - 1
         if window is not None:
             # Lowest row's band floor: cols <= q_start - window are dead
             # for every row in the tile.
@@ -205,7 +202,7 @@ def _attn_kernel(
                 live, (j + 1) * block_k - 1 > q_start - window
             )
     if has_vf:
-        past_pad = (j + 1) * block_k > vf_ref[0]
+        past_pad = (j + 1) * block_k > vf
         live = past_pad if live is None else jnp.logical_and(live, past_pad)
     if live is not None:
         pl.when(live)(_step)
@@ -246,7 +243,7 @@ def flash_attention(
     Dispatch is perf-measured, not dogmatic: while the materialized
     f32 score tensor stays under ``FLASH_SCORE_BYTES_BUDGET`` the XLA
     path wins on the real chip (end-to-end ViT-B/16: 1.9x — artifacts
-    ``benchmarks/results/r03/attn_crossover.json`` / ``attn_longseq``);
+    ``benchmarks/results/r03/attn_longseq.json`` / ``tpu_vit_b16_ab``);
     past it the streaming Pallas kernel takes over — O(S*D) HBM and
     O(block) VMEM, serving 32k+ sequences where XLA's scores exceed HBM
     outright. ``prefer="pallas"`` or ``"xla"`` forces a path (tests, the
@@ -288,6 +285,7 @@ def flash_attention(
         raise ValueError("window requires causal=True")
     if prefer is None:
         prefer = "pallas" if scores_over_budget(q.shape, k.shape) else "xla"
+    record_kernel_dispatch("flash", prefer)
     if prefer == "xla":
         return attention_reference(
             q, k, v, causal=causal, valid_from=valid_from, window=window
@@ -399,10 +397,8 @@ def _bwd_streams(q_shape, k_shape, causal, block_q, block_k) -> bool:
     """Static decision (shapes only) shared by fwd and bwd: does the
     backward run the streaming Pallas passes? False -> one materialized
     jnp-oracle recompute, which is faster wherever scores fit and is the
-    only option off pallas-tpu or on the causal ragged-cross-attention
-    shape the forward itself oracles."""
-    if pltpu is None:  # pragma: no cover — jax builds without pallas-tpu
-        return False
+    only option on the causal ragged-cross-attention shape the forward
+    itself oracles."""
     return scores_over_budget(q_shape, k_shape) and not _oracle_shape(
         q_shape, k_shape, causal, block_k
     )
@@ -460,16 +456,6 @@ def _flash_impl(
         raise ValueError("causal_shift requires causal=True")
     if window is not None and (not causal or causal_shift is not None):
         raise ValueError("window requires causal=True without causal_shift")
-    if pltpu is None:  # pragma: no cover — jax builds without pallas-tpu
-        return (
-            _reference_with_lse(q, k, v, causal, valid_from, causal_shift,
-                                window)
-            if with_lse
-            else attention_reference(
-                q, k, v, causal=causal, valid_from=valid_from,
-                causal_shift=causal_shift, window=window,
-            )
-        )
     b, h, s_q, d = q.shape
     s_k = k.shape[2]
     block_q = min(block_q, max(s_q, 8))
@@ -513,7 +499,6 @@ def _flash_impl(
         has_shift=causal_shift is not None,
         window=window,
     )
-    on_tpu = jax.default_backend() == "tpu"
     scratch = [
         pltpu.VMEM((block_q, 1), jnp.float32),
         pltpu.VMEM((block_q, 1), jnp.float32),
@@ -538,26 +523,18 @@ def _flash_impl(
     ]
     operands = [qf, kf, vf]
     if valid_from is not None:
-        # Per-(batch, head) left-pad scalar rides in SMEM.
+        # Per-(batch, head) left-pad scalars ride whole in SMEM.
         operands.append(
             jnp.repeat(jnp.asarray(valid_from, jnp.int32), h)
         )
-        in_specs.append(
-            pl.BlockSpec(
-                (1,), lambda bh, qi, kj: (bh,), memory_space=pltpu.SMEM
-            )
-        )
+        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
     if causal_shift is not None:
         # One global diagonal-offset scalar in SMEM (traced: striped
         # ring varies it per step without recompiling).
         operands.append(
             jnp.reshape(jnp.asarray(causal_shift, jnp.int32), (1,))
         )
-        in_specs.append(
-            pl.BlockSpec(
-                (1,), lambda bh, qi, kj: (0,), memory_space=pltpu.SMEM
-            )
-        )
+        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
     out, lse = pl.pallas_call(
         kernel,
         # K/V stream one block per innermost grid step; scratch carries
@@ -582,14 +559,10 @@ def _flash_impl(
             jax.ShapeDtypeStruct((b * h, 8, sp_q), jnp.float32),
         ],
         scratch_shapes=scratch,
-        compiler_params=(
-            pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary")
-            )
-            if on_tpu and pltpu is not None
-            else None
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
-        interpret=not on_tpu,
+        interpret=pallas_interpret(),
     )(*operands)
     out = out.reshape(b, h, sp_q, d)[:, :, :s_q, :]
     if not with_lse:
@@ -669,6 +642,7 @@ def _bwd_dq_kernel(
     j = pl.program_id(2)
     block_q = q_ref.shape[1]
     q_start = pl.program_id(1) * block_q
+    vf = vf_ref[pl.program_id(0)] if has_vf else None
 
     @pl.when(j == 0)
     def _init():
@@ -694,7 +668,7 @@ def _bwd_dq_kernel(
         if valid_k != num_kv * block_k:
             s = jnp.where(cols < valid_k, s, _NEG_INF)
         if has_vf:
-            s = jnp.where(cols >= vf_ref[0], s, _NEG_INF)
+            s = jnp.where(cols >= vf, s, _NEG_INF)
         if causal:
             rows = q_start + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0
@@ -721,7 +695,7 @@ def _bwd_dq_kernel(
                 live, (j + 1) * block_k - 1 > q_start - window
             )
     if has_vf:
-        past_pad = (j + 1) * block_k > vf_ref[0]
+        past_pad = (j + 1) * block_k > vf
         live = past_pad if live is None else jnp.logical_and(live, past_pad)
     if live is not None:
         pl.when(live)(_step)
@@ -760,6 +734,7 @@ def _bwd_dkv_kernel(
     block_k = k_ref.shape[1]
     k_start = pl.program_id(1) * block_k
     q_start = i * block_q
+    vf = vf_ref[pl.program_id(0)] if has_vf else None
 
     @pl.when(i == 0)
     def _init():
@@ -786,7 +761,7 @@ def _bwd_dkv_kernel(
         if valid_k != sp_k:
             s = jnp.where(cols < valid_k, s, _NEG_INF)
         if has_vf:
-            s = jnp.where(cols >= vf_ref[0], s, _NEG_INF)
+            s = jnp.where(cols >= vf, s, _NEG_INF)
         if causal:
             rows = q_start + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0
@@ -821,7 +796,7 @@ def _bwd_dkv_kernel(
             )
     if has_vf:
         # A K block entirely inside the left padding gets zero gradient.
-        past_pad = k_start + block_k > vf_ref[0]
+        past_pad = k_start + block_k > vf
         live = past_pad if live is None else jnp.logical_and(live, past_pad)
     if live is not None:
         pl.when(live)(_step)
@@ -890,14 +865,10 @@ def _flash_bwd_impl(
     deltaf = jnp.broadcast_to(
         delta.reshape(b * h, 1, sp_q), (b * h, 8, sp_q)
     )
-    on_tpu = jax.default_backend() == "tpu"
-    params = (
-        pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
-        )
-        if on_tpu
-        else None
+    params = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary")
     )
+    interpret = pallas_interpret()
     q_spec = pl.BlockSpec(
         (1, block_q, d), lambda bh, a, b_: (bh, a, 0), memory_space=_VMEM
     )
@@ -910,11 +881,7 @@ def _flash_bwd_impl(
     vf_operands, vf_specs = [], []
     if valid_from is not None:
         vf_operands = [jnp.repeat(jnp.asarray(valid_from, jnp.int32), h)]
-        vf_specs = [
-            pl.BlockSpec(
-                (1,), lambda bh, a, b_: (bh,), memory_space=pltpu.SMEM
-            )
-        ]
+        vf_specs = [pl.BlockSpec(memory_space=pltpu.SMEM)]
     dq = pl.pallas_call(
         functools.partial(
             _bwd_dq_kernel,
@@ -933,7 +900,7 @@ def _flash_bwd_impl(
         out_shape=jax.ShapeDtypeStruct(qf.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         compiler_params=params,
-        interpret=not on_tpu,
+        interpret=interpret,
     )(qf, kf, vf, dof, lsef, deltaf, *vf_operands)
 
     q_spec_kv = pl.BlockSpec(
@@ -977,7 +944,7 @@ def _flash_bwd_impl(
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
         compiler_params=params,
-        interpret=not on_tpu,
+        interpret=interpret,
     )(qf, kf, vf, dof, lsef, deltaf, *vf_operands)
 
     dq = dq.reshape(b, h, sp_q, d)[:, :, :s_q, :]

@@ -53,47 +53,13 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover — jax builds without pallas-tpu
-    pltpu = None
-    _VMEM = None
-
+from adapt_tpu.ops.dispatch import on_tpu, pallas_interpret, resolve_prefer
 from adapt_tpu.ops.quantize import unpack_int4
 
+_VMEM = pltpu.VMEM
 _NEG_INF = -1e30
-
-# -- kernel-vs-oracle dispatch accounting ------------------------------------
-
-#: Last-resolved path + lifetime counts per decode/verify/prefill op —
-#: the ``_kernel_supported`` fallback used to degrade to the XLA oracle
-#: SILENTLY (a perf cliff invisible in metrics). Every dispatcher
-#: records its decision here at trace time; ``utils.profiling``'s
-#: engine collector exports them as ``engine.kernel_dispatch.<op>``
-#: gauges (1.0 = the Pallas kernel, 0.0 = the XLA oracle) plus
-#: per-path totals. Counts move at TRACE time (dispatch is resolved
-#: when the surrounding program lowers, not per executed tick), so the
-#: gauge answers "which path is this serving program actually built
-#: on", which is the question the fallback cliff poses.
-_KERNEL_DISPATCHES: dict[str, dict[str, float]] = {}
-
-
-def record_kernel_dispatch(op: str, path: str) -> None:
-    """Record one dispatch resolution for ``op`` (``"pallas"`` or
-    ``"xla"``)."""
-    d = _KERNEL_DISPATCHES.setdefault(
-        op, {"pallas": 0.0, "xla": 0.0, "last": 0.0}
-    )
-    d[path] += 1.0
-    d["last"] = 1.0 if path == "pallas" else 0.0
-
-
-def kernel_dispatch_stats() -> dict[str, dict[str, float]]:
-    """Snapshot of the per-op dispatch books (copies — safe to mutate)."""
-    return {op: dict(d) for op, d in _KERNEL_DISPATCHES.items()}
 
 
 def default_decode_split(num_blocks: int) -> int:
@@ -119,11 +85,7 @@ def resolve_decode_split(num_blocks: int, split: int | None) -> int:
     interpreter gains nothing from fan-out."""
     if split is not None:
         return split
-    return (
-        default_decode_split(num_blocks)
-        if jax.default_backend() == "tpu"
-        else 1
-    )
+    return default_decode_split(num_blocks) if on_tpu() else 1
 
 #: Cache-position block per grid step for QUANTIZED caches. 1024 = 8
 #: sublanes x 128 lanes of the chunked scale view, the smallest block
@@ -166,7 +128,7 @@ def default_block_k(cache_len: int, quantized: bool) -> int:
     for bk in (1024, 512, _MIN_NATIVE_BLOCK_K):
         if cache_len % bk == 0:
             return bk
-    return DECODE_BLOCK_K  # leaves _supported() False -> XLA fallback
+    return DECODE_BLOCK_K  # _supported() False: XLA on auto, raise if forced
 
 
 def decode_kernel_wins(cache_len: int, quantized: bool) -> bool:
@@ -181,7 +143,7 @@ def decode_kernel_wins(cache_len: int, quantized: bool) -> bool:
 
 
 def _supported(cache_len: int, block_k: int, quantized: bool) -> bool:
-    if pltpu is None or cache_len % block_k:
+    if cache_len % block_k:
         return False
     # int8 scale tiles need (block_k//128) >= 8 rows per (8, 128) tile.
     return not quantized or block_k % DECODE_BLOCK_K == 0
@@ -249,13 +211,20 @@ def _decode_kernel(
     quantized,
     has_vf,
     packed=False,
+    lead=(0,),
 ):
     """One (batch, kv_head) row: stream cache blocks innermost, online
     softmax in scratch. ``q_ref`` (1, gq, hd) — gq = GQA group rows,
     sublane-padded; ``k_ref``/``v_ref`` (1, block_k, hd) int8 or native
     (``packed``: (1, block_k, hd // 2) int4 nibbles, unpacked in VMEM);
     scale tiles (1, 8, 128) f32 chunked views covering this block's
-    positions row-major; ``idx_ref``/``vf_ref`` (1,) SMEM scalars."""
+    positions row-major. ``lead`` indexes the unit leading axes off the
+    K/V/scale tiles — ``(0, 0)`` for the paged pools' (1, 1, page, hd)
+    blocks (a LOAD with indices; Mosaic refuses a ``ref.at`` view whose
+    lane width is under a tile, which head_dim 64 is).
+    ``idx_ref``/``vf_ref`` whole (b * kv_h,) SMEM
+    vectors, this row's scalar read by ``program_id(0)`` (Mosaic
+    refuses a (1,) block of a longer rank-1 array)."""
     refs = list(refs)
     ksc_ref = refs.pop(0) if quantized else None
     vsc_ref = refs.pop(0) if quantized else None
@@ -263,6 +232,8 @@ def _decode_kernel(
     o_ref, m_scr, l_scr, acc_scr = refs
     j = pl.program_id(1)
     gq = q_ref.shape[1]
+    idx = idx_ref[pl.program_id(0)]
+    vf = vf_ref[pl.program_id(0)] if has_vf else None
 
     @pl.when(j == 0)
     def _init():
@@ -272,23 +243,21 @@ def _decode_kernel(
         cols = j * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (gq, block_k), 1
         )
-        live = cols <= idx_ref[0]
+        live = cols <= idx
         if has_vf:
-            live = jnp.logical_and(live, cols >= vf_ref[0])
+            live = jnp.logical_and(live, cols >= vf)
         _attend_tile(
-            q_ref[0], k_ref[0], v_ref[0],
-            ksc_ref[0].reshape(1, block_k) if quantized else None,
-            vsc_ref[0].reshape(1, block_k) if quantized else None,
+            q_ref[0], k_ref[lead], v_ref[lead],
+            ksc_ref[lead].reshape(1, block_k) if quantized else None,
+            vsc_ref[lead].reshape(1, block_k) if quantized else None,
             live, m_scr, l_scr, acc_scr, sm_scale, packed,
         )
 
     # Blocks entirely past the write index (the still-dead cache tail)
     # or entirely inside ragged left padding contribute nothing.
-    live_block = j * block_k <= idx_ref[0]
+    live_block = j * block_k <= idx
     if has_vf:
-        live_block = jnp.logical_and(
-            live_block, (j + 1) * block_k > vf_ref[0]
-        )
+        live_block = jnp.logical_and(live_block, (j + 1) * block_k > vf)
     pl.when(live_block)(_step)
 
     @pl.when(j == num_kv - 1)
@@ -311,6 +280,7 @@ def _decode_split_kernel(
     quantized,
     has_vf,
     packed=False,
+    lead=(0,),
 ):
     """Flash-decoding split variant of :func:`_decode_kernel`: grid
     (b * kv_h, split, bps) — each (row, split) streams ITS ``bps``
@@ -332,6 +302,8 @@ def _decode_split_kernel(
     j = pl.program_id(2)
     jg = s_id * bps + j  # global block index
     gq = q_ref.shape[1]
+    idx = idx_ref[pl.program_id(0)]
+    vf = vf_ref[pl.program_id(0)] if has_vf else None
 
     @pl.when(j == 0)
     def _init():
@@ -341,23 +313,19 @@ def _decode_split_kernel(
         cols = jg * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (gq, block_k), 1
         )
-        live = cols <= idx_ref[0]
+        live = cols <= idx
         if has_vf:
-            live = jnp.logical_and(live, cols >= vf_ref[0])
+            live = jnp.logical_and(live, cols >= vf)
         _attend_tile(
-            q_ref[0], k_ref[0], v_ref[0],
-            ksc_ref[0].reshape(1, block_k) if quantized else None,
-            vsc_ref[0].reshape(1, block_k) if quantized else None,
+            q_ref[0], k_ref[lead], v_ref[lead],
+            ksc_ref[lead].reshape(1, block_k) if quantized else None,
+            vsc_ref[lead].reshape(1, block_k) if quantized else None,
             live, m_scr, l_scr, acc_scr, sm_scale, packed,
         )
 
-    live_block = jnp.logical_and(
-        jg < num_kv, jg * block_k <= idx_ref[0]
-    )
+    live_block = jnp.logical_and(jg < num_kv, jg * block_k <= idx)
     if has_vf:
-        live_block = jnp.logical_and(
-            live_block, (jg + 1) * block_k > vf_ref[0]
-        )
+        live_block = jnp.logical_and(live_block, (jg + 1) * block_k > vf)
     pl.when(live_block)(_step)
 
     @pl.when(j == bps - 1)
@@ -429,15 +397,11 @@ def _decode_impl(q, k_vals, v_vals, k_scales, v_scales, index, valid_from,
     def kv_map(bh, *js):
         return (bh, blk(bh, *js), 0)
 
-    def smem_map(bh, *js):
-        del js
-        return (bh,)
-
     in_specs = [
         pl.BlockSpec((1, gq, hd), row_map, memory_space=_VMEM),
         pl.BlockSpec((1, block_k, hdk), kv_map, memory_space=_VMEM),
         pl.BlockSpec((1, block_k, hdk), kv_map, memory_space=_VMEM),
-        pl.BlockSpec((1,), smem_map, memory_space=pltpu.SMEM),
+        pl.BlockSpec(memory_space=pltpu.SMEM),
     ]
     operands = [qf, kf, vf, idx]
     if quantized:
@@ -455,11 +419,8 @@ def _decode_impl(q, k_vals, v_vals, k_scales, v_scales, index, valid_from,
             )
     if has_vf:
         operands.append(jnp.repeat(jnp.asarray(valid_from, jnp.int32), kvh))
-        in_specs.append(
-            pl.BlockSpec((1,), smem_map, memory_space=pltpu.SMEM)
-        )
+        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
 
-    on_tpu = jax.default_backend() == "tpu"
     scratch = [
         pltpu.VMEM((gq, 1), jnp.float32),
         pltpu.VMEM((gq, 1), jnp.float32),
@@ -483,14 +444,10 @@ def _decode_impl(q, k_vals, v_vals, k_scales, v_scales, index, valid_from,
             ),
             out_shape=jax.ShapeDtypeStruct((b * kvh, gq, hd), q.dtype),
             scratch_shapes=scratch,
-            compiler_params=(
-                pltpu.CompilerParams(
-                    dimension_semantics=("parallel", "arbitrary")
-                )
-                if on_tpu
-                else None
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary")
             ),
-            interpret=not on_tpu,
+            interpret=pallas_interpret(),
         )(*operands)
         return out.reshape(b, kvh, gq, hd)[:, :, :g, :]
 
@@ -523,14 +480,10 @@ def _decode_impl(q, k_vals, v_vals, k_scales, v_scales, index, valid_from,
             jax.ShapeDtypeStruct((b * kvh, split, gq, hd), jnp.float32),
         ),
         scratch_shapes=scratch,
-        compiler_params=(
-            pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary")
-            )
-            if on_tpu
-            else None
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
-        interpret=not on_tpu,
+        interpret=pallas_interpret(),
     )(*operands)
     out = _combine_splits(o_p, m_p, l_p, q.dtype)
     return out.reshape(b, kvh, gq, hd)[:, :, :g, :]
@@ -736,9 +689,9 @@ def decode_attention(
 
     ``prefer``: None = auto (``decode_kernel_wins``, the measured rule),
     ``"xla"`` = the einsum oracle, ``"pallas"`` = the streaming kernel
-    (falls back to the oracle off-pallas or when L doesn't divide into
-    supported blocks: native caches need L % 256 == 0, int8 caches
-    L % 1024 == 0 — the scale-tile layout). ``block_k`` None picks the
+    (raises when L doesn't divide into supported blocks: native caches
+    need L % 256 == 0, int8 caches L % 1024 == 0 — the scale-tile
+    layout). ``block_k`` None picks the
     largest supported block (``default_block_k``). ``split`` is the
     flash-decoding KV-length split factor: None auto-derives
     (``default_decode_split`` of the block count on real TPUs; 1
@@ -759,17 +712,24 @@ def decode_attention(
     cache_len = (cache_k[0] if quantized else cache_k).shape[2]
     if block_k is None:
         block_k = default_block_k(cache_len, quantized)
-    if prefer is None:
-        prefer = (
-            "pallas" if decode_kernel_wins(cache_len, quantized) else "xla"
+    unsupported = None
+    if not _supported(cache_len, block_k, quantized):
+        unsupported = (
+            f"cache_len {cache_len} does not divide into block_k "
+            f"{block_k} (native caches need a multiple of "
+            f"{_MIN_NATIVE_BLOCK_K}, quantized caches of {DECODE_BLOCK_K})"
         )
-    elif prefer not in ("pallas", "xla"):
-        raise ValueError(
-            f"prefer={prefer!r}: expected None, 'pallas' or 'xla'"
+    elif quantized and on_tpu() and cache_k[0].shape[-1] * 2 == q.shape[-1]:
+        # Measured on a v5e (paged twins, same tile body): ROADMAP A1.
+        unsupported = (
+            "int4 caches: the in-VMEM nibble unpack exceeds Mosaic's "
+            "scoped VMEM limit on a TPU"
         )
-    if prefer == "pallas" and _supported(cache_len, block_k, quantized):
+    if resolve_prefer(
+        "decode", prefer, unsupported,
+        decode_kernel_wins(cache_len, quantized),
+    ):
         split = resolve_decode_split(cache_len // block_k, split)
-        record_kernel_dispatch("decode", "pallas")
         if quantized:
             (kvl, ksc), (vvl, vsc) = cache_k, cache_v
             return _decode_impl(
@@ -779,7 +739,6 @@ def decode_attention(
             q, cache_k, cache_v, None, None, index, valid_from, block_k,
             split,
         )
-    record_kernel_dispatch("decode", "xla")
     return decode_attention_reference(
         q, cache_k, cache_v, index, valid_from
     )

@@ -51,11 +51,12 @@ Layouts:
   in VMEM, the ``_decode_kernel`` discipline). On REAL TPUs the
   quantized kernel path additionally requires
   ``page % DECODE_BLOCK_K == 0`` so the scale tile fills a full f32
-  (8, 128) tile (``_kernel_supported`` — the dense int8 path's
-  constraint); smaller quantized pages serve through the XLA oracle
-  until a hardware A/B motivates a packed-scale layout. Off-TPU the
-  interpreter has no tiling, so CI parity drives the quantized kernel
-  bodies at ordinary page sizes.
+  (8, 128) tile (``kernel_unsupported`` — the dense int8 path's
+  constraint); smaller quantized pages, and on hardware every int4
+  pool (its unpack does not fit scoped VMEM), serve through the XLA
+  oracle by that stated rule. Off-TPU the interpreter has no tiling,
+  so CI parity drives every quantized kernel body at ordinary page
+  sizes.
 - page table: (slots, pages_per_slot) int32 physical page ids; entries
   past a slot's live window may be ANY valid page id (their positions
   are masked, their blocks' compute skipped — point them at page 0).
@@ -76,6 +77,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
 from adapt_tpu.ops.decode_attention import (
     DECODE_BLOCK_K,
@@ -85,19 +88,12 @@ from adapt_tpu.ops.decode_attention import (
     _decode_split_kernel,
     _init_softmax_scratch,
     check_head_parity,
-    record_kernel_dispatch,
     resolve_decode_split,
 )
+from adapt_tpu.ops.dispatch import on_tpu, pallas_interpret, resolve_prefer
 from adapt_tpu.ops.quantize import unpack_int4
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover — jax builds without pallas-tpu
-    pltpu = None
-    _VMEM = None
-
+_VMEM = pltpu.VMEM
 DEFAULT_PAGE_SIZE = 128
 
 
@@ -120,22 +116,74 @@ def _split_pools(k_pool, v_pool):
     return k_pool, v_pool, None, None
 
 
-def _kernel_supported(page: int, quantized: bool) -> bool:
-    """Shared pallas-dispatch gate for the three paged kernels. Native
-    pools need a lane-multiple page. Quantized pools ALSO need the
-    scale tile to satisfy f32 (8, 128) tiling ON HARDWARE: a page
-    carries page/128 rows of 128 scales, so real TPUs require
-    ``page % DECODE_BLOCK_K == 0`` (the dense int8 path's documented
-    constraint — small pages would hand Mosaic a 1-sublane f32 tile);
-    smaller quantized pages fall back to the XLA oracle until a
-    hardware A/B motivates a packed-scale layout. The INTERPRETER has
-    no tiling, so off-TPU the CI parity tests still drive the quantized
-    kernel bodies at ordinary page sizes."""
-    if pltpu is None or page % 128:
-        return False
-    if quantized and jax.default_backend() == "tpu":
-        return page % DECODE_BLOCK_K == 0
-    return True
+def kernel_unsupported(q, k_pool) -> str | None:
+    """Shared pallas-dispatch gate for the three paged kernels: None
+    when they can serve these operands, else the constraint broken (the
+    reason auto dispatch routes to the XLA oracle, and the error a
+    forced ``prefer="pallas"`` raises). Native pools need a
+    lane-multiple page. ON HARDWARE quantized pools also need the scale
+    tile to satisfy f32 (8, 128) tiling: a page carries page/128 rows
+    of 128 scales, so ``page % DECODE_BLOCK_K == 0`` (smaller quantized
+    pages would hand Mosaic a 1-sublane f32 tile); and int4 pools do
+    not serve at all — measured on a v5e, the in-VMEM nibble unpack
+    (``unpack_int4``'s stack + reshape interleave, minor dim 2 padded
+    to 128 lanes) asks for 24.6 MB of scoped VMEM at that page size
+    against Mosaic's 16 MB (ROADMAP A1 carries the lane-dense rewrite).
+    The INTERPRETER has no tiling, so off-TPU the CI parity tests still
+    drive every kernel body at ordinary page sizes."""
+    vals = pool_values(k_pool)
+    page = vals.shape[2]
+    quantized = isinstance(k_pool, tuple)
+    if page % 128:
+        return f"page_size {page} is not a multiple of 128"
+    if quantized and on_tpu():
+        if vals.shape[3] * 2 == q.shape[-1]:
+            return (
+                "int4 pools: the in-VMEM nibble unpack exceeds Mosaic's "
+                "scoped VMEM limit on a TPU"
+            )
+        if page % DECODE_BLOCK_K:
+            return (
+                f"page_size {page} is not a multiple of {DECODE_BLOCK_K} "
+                "(quantized pools: the scale tile must fill an f32 "
+                "(8, 128) tile on a TPU)"
+            )
+    return None
+
+
+def _head_sharded(fn, head_shard, sharded, replicated):
+    """``fn(*sharded, *replicated)`` — directly, or per head shard.
+
+    Under tensor parallelism the batcher's programs are GSPMD-
+    partitioned, and Mosaic kernels cannot be partitioned
+    automatically: a ``pallas_call`` there must sit inside a
+    ``shard_map``. ``head_shard`` = ``(mesh, axis)`` runs ``fn`` once
+    per shard of the KV-HEAD axis — dim 1 of every ``sharded`` operand
+    (q, pools, scale planes) and of the output — with ``replicated``
+    operands (page table, positions) whole on every shard. The kernels
+    derive grid and GQA fold from the head count they are given, so the
+    per-shard body is the single-device kernel unchanged. ``None``
+    entries (absent scales / valid_from) are closed over, not mapped."""
+    args = [*sharded, *replicated]
+    if head_shard is None:
+        return fn(*args)
+    mesh, axis = head_shard
+    live = [i for i, a in enumerate(args) if a is not None]
+    heads = P(None, axis)
+
+    def body(*xs):
+        full = [None] * len(args)
+        for i, x in zip(live, xs):
+            full[i] = x
+        return fn(*full)
+
+    return jax.shard_map(
+        body,
+        mesh=mesh,
+        in_specs=tuple(heads if i < len(sharded) else P() for i in live),
+        out_specs=heads,
+        check_vma=False,
+    )(*(args[i] for i in live))
 
 
 def paged_attention_reference(q, k_pool, v_pool, page_table, index,
@@ -199,9 +247,9 @@ def _paged_impl(q, k_pool, v_pool, k_scales, v_scales, page_table, index,
         # Ragged tail clamps to a valid table column (masked in-kernel).
         return jnp.minimum(s_id * bps + j, pages_per_slot - 1)
 
-    # Scalar-prefetch operand 0: the page table, flattened with the idx /
-    # valid_from vectors appended is NOT needed — table stays 2-D; the
-    # kernel's SMEM scalars (idx, vf) remain ordinary SMEM inputs.
+    # Scalar-prefetch operand 0 is the page table (2-D, consumed by the
+    # index maps); the per-row idx / valid_from vectors are whole-array
+    # SMEM inputs the kernel indexes by program_id(0).
     def q_map(bh, *js_table):
         return (bh, 0, 0)
 
@@ -209,14 +257,11 @@ def _paged_impl(q, k_pool, v_pool, k_scales, v_scales, page_table, index,
         *js, table_ref = js_table
         return (table_ref[bh // kvh, blk(bh, *js)], bh % kvh, 0, 0)
 
-    def smem_map(bh, *js_table):
-        return (bh,)
-
     in_specs = [
         pl.BlockSpec((1, gq, hd), q_map, memory_space=_VMEM),
         pl.BlockSpec((1, 1, page, hdk), kv_map, memory_space=_VMEM),
         pl.BlockSpec((1, 1, page, hdk), kv_map, memory_space=_VMEM),
-        pl.BlockSpec((1,), smem_map, memory_space=pltpu.SMEM),
+        pl.BlockSpec(memory_space=pltpu.SMEM),
     ]
     operands = [qf, k_pool, v_pool, idx]
     if quantized:
@@ -237,11 +282,8 @@ def _paged_impl(q, k_pool, v_pool, k_scales, v_scales, page_table, index,
             )
     if has_vf:
         operands.append(jnp.repeat(jnp.asarray(valid_from, jnp.int32), kvh))
-        in_specs.append(
-            pl.BlockSpec((1,), smem_map, memory_space=pltpu.SMEM)
-        )
+        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
 
-    on_tpu = jax.default_backend() == "tpu"
     scratch = [
         pltpu.VMEM((gq, 1), jnp.float32),
         pltpu.VMEM((gq, 1), jnp.float32),
@@ -268,14 +310,10 @@ def _paged_impl(q, k_pool, v_pool, k_scales, v_scales, page_table, index,
             kernel,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((b * kvh, gq, hd), q.dtype),
-            compiler_params=(
-                pltpu.CompilerParams(
-                    dimension_semantics=("parallel", "arbitrary")
-                )
-                if on_tpu
-                else None
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary")
             ),
-            interpret=not on_tpu,
+            interpret=pallas_interpret(),
         )(jnp.asarray(page_table, jnp.int32), *operands)
         return out.reshape(b, kvh, gq, hd)[:, :, :g, :]
 
@@ -315,14 +353,10 @@ def _paged_impl(q, k_pool, v_pool, k_scales, v_scales, page_table, index,
             jax.ShapeDtypeStruct((b * kvh, split, gq, hd), jnp.float32),
             jax.ShapeDtypeStruct((b * kvh, split, gq, hd), jnp.float32),
         ),
-        compiler_params=(
-            pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary")
-            )
-            if on_tpu
-            else None
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
-        interpret=not on_tpu,
+        interpret=pallas_interpret(),
     )(jnp.asarray(page_table, jnp.int32), *operands)
     out = _combine_splits(o_p, m_p, l_p, q.dtype)
     return out.reshape(b, kvh, gq, hd)[:, :, :g, :]
@@ -332,17 +366,18 @@ def _paged_kernel(table_ref, q_ref, k_ref, v_ref, idx_ref, *refs, block_k,
                   num_kv, sm_scale, quantized, has_vf, packed=False):
     """Scalar-prefetch wrapper: the table ref arrives first (consumed by
     the index_maps, unused in the body) and the K/V tiles arrive as
-    (1, 1, page, hd) — drop the head axis and delegate to the contiguous
-    decode kernel body (one attention discipline, two layouts).
-    Quantized pools add chunked (1, 1, page/128, 128) f32 scale tiles,
-    table-addressed like the int8 payload; ``_decode_kernel``'s quantized branch applies
-    them to the score/probability columns in VMEM — the fused dequant
-    (``packed``: int4 nibble pools, unpacked there too)."""
+    (1, 1, page, hd) — ``lead=(0, 0)`` loads them past the page and head
+    axes and the contiguous decode kernel body does the rest (one
+    attention discipline, two layouts). Quantized pools add chunked
+    (1, 1, page/128, 128) f32 scale tiles, table-addressed like the int8
+    payload; ``_decode_kernel``'s quantized branch applies them to the
+    score/probability columns in VMEM — the fused dequant (``packed``:
+    int4 nibble pools, unpacked there too)."""
     del table_ref
     _decode_kernel(
         q_ref,
-        k_ref.at[:, 0],
-        v_ref.at[:, 0],
+        k_ref,
+        v_ref,
         idx_ref,
         *refs,
         block_k=block_k,
@@ -351,6 +386,7 @@ def _paged_kernel(table_ref, q_ref, k_ref, v_ref, idx_ref, *refs, block_k,
         quantized=quantized,
         has_vf=has_vf,
         packed=packed,
+        lead=(0, 0),
     )
 
 
@@ -358,13 +394,13 @@ def _paged_split_kernel(table_ref, q_ref, k_ref, v_ref, idx_ref, *refs,
                         block_k, num_kv, bps, sm_scale, quantized, has_vf,
                         packed=False):
     """Flash-split scalar-prefetch wrapper: grid (b * kv_h, split, bps)
-    — drop the table/head axes and delegate to the dense split kernel
-    (partial emission + masked ragged tail)."""
+    — drop the table ref, load past the page/head axes and delegate to
+    the dense split kernel (partial emission + masked ragged tail)."""
     del table_ref
     _decode_split_kernel(
         q_ref,
-        k_ref.at[:, 0],
-        v_ref.at[:, 0],
+        k_ref,
+        v_ref,
         idx_ref,
         *refs,
         block_k=block_k,
@@ -374,6 +410,7 @@ def _paged_split_kernel(table_ref, q_ref, k_ref, v_ref, idx_ref, *refs,
         quantized=quantized,
         has_vf=has_vf,
         packed=packed,
+        lead=(0, 0),
     )
 
 
@@ -397,6 +434,7 @@ def _chunk_kernel(pages_ref, q_ref, k_ref, v_ref, pos0_ref, *refs,
     o_ref, m_scr, l_scr, acc_scr = refs
     j = pl.program_id(1)
     gc = q_ref.shape[1]
+    pos0 = pos0_ref[0]
 
     @pl.when(j == 0)
     def _init():
@@ -407,13 +445,11 @@ def _chunk_kernel(pages_ref, q_ref, k_ref, v_ref, pos0_ref, *refs,
         cols = j * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (gc, block_k), 1
         )
-        live = cols <= pos0_ref[0] + rows
+        live = cols <= pos0 + rows
         if window is not None:
             # Sliding window: row at absolute position p attends
             # (p - window, p].
-            live = jnp.logical_and(
-                live, cols > pos0_ref[0] + rows - window
-            )
+            live = jnp.logical_and(live, cols > pos0 + rows - window)
         _attend_tile(
             q_ref[0], k_ref[0, 0], v_ref[0, 0],
             ksc_ref[0, 0].reshape(1, block_k) if quantized else None,
@@ -424,10 +460,10 @@ def _chunk_kernel(pages_ref, q_ref, k_ref, v_ref, pos0_ref, *refs,
     # Pages entirely past the chunk's last position are dead (the pow2
     # padding's trash pages land here too); under a sliding window so
     # are pages entirely below EVERY row's window (row 0's is lowest).
-    live_block = j * block_k <= pos0_ref[0] + chunk - 1
+    live_block = j * block_k <= pos0 + chunk - 1
     if window is not None:
         live_block = jnp.logical_and(
-            live_block, (j + 1) * block_k - 1 > pos0_ref[0] - window
+            live_block, (j + 1) * block_k - 1 > pos0 - window
         )
     pl.when(live_block)(_step)
 
@@ -508,15 +544,11 @@ def _chunk_impl(q, k_pool, v_pool, k_scales, v_scales, pages, pos0, chunk,
     def kv_map(h, j, pages_ref):
         return (pages_ref[j], h, 0, 0)
 
-    def smem_map(h, j, pages_ref):
-        del h, j, pages_ref
-        return (0,)
-
     in_specs = [
         pl.BlockSpec((1, gcp, hd), q_map, memory_space=_VMEM),
         pl.BlockSpec((1, 1, page, hdk), kv_map, memory_space=_VMEM),
         pl.BlockSpec((1, 1, page, hdk), kv_map, memory_space=_VMEM),
-        pl.BlockSpec((1,), smem_map, memory_space=pltpu.SMEM),
+        pl.BlockSpec(memory_space=pltpu.SMEM),
     ]
     operands = [qf, k_pool, v_pool, pos0v]
     if quantized:
@@ -532,7 +564,6 @@ def _chunk_impl(q, k_pool, v_pool, k_scales, v_scales, pages, pos0, chunk,
                     (1, 1, page // 128, 128), kv_map, memory_space=_VMEM
                 )
             )
-    on_tpu = jax.default_backend() == "tpu"
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(kvh, n),
@@ -557,14 +588,10 @@ def _chunk_impl(q, k_pool, v_pool, k_scales, v_scales, pages, pos0, chunk,
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((kvh, gcp, hd), q.dtype),
-        compiler_params=(
-            pltpu.CompilerParams(
-                dimension_semantics=("parallel", "arbitrary")
-            )
-            if on_tpu
-            else None
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")
         ),
-        interpret=not on_tpu,
+        interpret=pallas_interpret(),
     )(jnp.asarray(pages, jnp.int32), *operands)
     return out.reshape(1, kvh, gcp, hd)[:, :, :gc, :]
 
@@ -578,6 +605,7 @@ def paged_chunk_attention(
     chunk: int,
     prefer: str | None = None,
     window: int | None = None,
+    head_shard=None,
 ) -> jax.Array:
     """Chunk-prefill attention over a paged window, in place — the
     incremental-prefill counterpart of :func:`paged_attention` (no
@@ -587,28 +615,19 @@ def paged_chunk_attention(
     q (1, kv_h, g*chunk, hd) group-folded; ``pages`` (n,) covers the
     whole live window [0, pos0 + chunk) (pow2 padding to the trash page
     is fine — those positions are past every row's mask). Pools may be
-    quantized ``(int8 values, f32 scales)`` pairs. Dispatch as
-    :func:`paged_attention`: kernel on real TPUs with lane-multiple
-    pages, oracle elsewhere."""
-    quantized = isinstance(k_pool, tuple)
+    quantized ``(int8 values, f32 scales)`` pairs. Dispatch and
+    ``head_shard`` as :func:`paged_attention`."""
     check_head_parity(q.shape[1], pool_values(k_pool).shape[1])
-    page = pool_values(k_pool).shape[2]
-    supported = _kernel_supported(page, quantized)
-    if prefer is None:
-        prefer = (
-            "pallas"
-            if supported and jax.default_backend() == "tpu"
-            else "xla"
-        )
-    elif prefer not in ("pallas", "xla"):
-        raise ValueError(
-            f"prefer={prefer!r}: expected None, 'pallas' or 'xla'"
-        )
-    if prefer == "pallas" and supported:
-        record_kernel_dispatch("paged_chunk", "pallas")
+    if resolve_prefer(
+        "paged_chunk", prefer, kernel_unsupported(q, k_pool), on_tpu()
+    ):
         kv, vv, ks, vs = _split_pools(k_pool, v_pool)
-        return _chunk_impl(q, kv, vv, ks, vs, pages, pos0, chunk, window)
-    record_kernel_dispatch("paged_chunk", "xla")
+        return _head_sharded(
+            functools.partial(_chunk_impl, chunk=chunk, window=window),
+            head_shard,
+            (q, kv, vv, ks, vs),
+            (jnp.asarray(pages, jnp.int32), jnp.asarray(pos0, jnp.int32)),
+        )
     return paged_chunk_attention_reference(
         q, k_pool, v_pool, pages, pos0, chunk, window
     )
@@ -651,7 +670,8 @@ def _verify_kernel(table_ref, q_ref, k_ref, v_ref, idx_ref, *refs,
     K-major verify rows streams ITS page-table row innermost (scalar
     prefetch, as ``_paged_kernel``) with ``_chunk_kernel``'s per-row
     diagonal mask anchored at this slot's OWN base position
-    (``idx_ref`` SMEM) — the speculative verify over a paged cache.
+    (``idx_ref`` SMEM, whole vector, read by ``program_id(0)``) — the
+    speculative verify over a paged cache.
     Dead rows (negative index) skip every block and emit zeros.
     Quantized pools add chunked (page/128, 128) f32 scale tiles applied to the
     score/probability columns in VMEM (the fused dequant; ``packed``
@@ -678,6 +698,7 @@ def _verify_kernel(table_ref, q_ref, k_ref, v_ref, idx_ref, *refs,
         jg = j
         last_j = num_kv - 1
     gc = q_ref.shape[1]
+    idx = idx_ref[pl.program_id(0)]
 
     @pl.when(j == 0)
     def _init():
@@ -692,13 +713,13 @@ def _verify_kernel(table_ref, q_ref, k_ref, v_ref, idx_ref, *refs,
             depth = jnp.minimum(rows, chunk - 1 - tree_tail)
         else:
             depth = rows
-        live = cols <= idx_ref[0] + depth
+        live = cols <= idx + depth
         if window is not None:
-            live = jnp.logical_and(live, cols > idx_ref[0] + depth - window)
+            live = jnp.logical_and(live, cols > idx + depth - window)
         if tree_tail:
             # A leaf row's own physical slot is live even though it sits
             # past the chain edge; siblings' slots stay masked.
-            live = jnp.logical_or(live, cols == idx_ref[0] + rows)
+            live = jnp.logical_or(live, cols == idx + rows)
         _attend_tile(
             q_ref[0], k_ref[0, 0], v_ref[0, 0],
             ksc_ref[0, 0].reshape(1, block_k) if quantized else None,
@@ -710,12 +731,12 @@ def _verify_kernel(table_ref, q_ref, k_ref, v_ref, idx_ref, *refs,
     # page, for a negative dead-row index); under a sliding window so
     # are pages wholly below row 0's window. The ragged split tail's
     # clamped pages mask here too (jg >= num_kv).
-    live_block = jg * block_k <= idx_ref[0] + chunk - 1
+    live_block = jg * block_k <= idx + chunk - 1
     if split_mode:
         live_block = jnp.logical_and(live_block, jg < num_kv)
     if window is not None:
         live_block = jnp.logical_and(
-            live_block, (jg + 1) * block_k - 1 > idx_ref[0] - window
+            live_block, (jg + 1) * block_k - 1 > idx - window
         )
     pl.when(live_block)(_step)
 
@@ -768,14 +789,11 @@ def _verify_impl(q, k_pool, v_pool, k_scales, v_scales, page_table, index,
         *js, table_ref = js_table
         return (table_ref[bh // kvh, blk(bh, *js)], bh % kvh, 0, 0)
 
-    def smem_map(bh, *js_table):
-        return (bh,)
-
     in_specs = [
         pl.BlockSpec((1, gcp, hd), q_map, memory_space=_VMEM),
         pl.BlockSpec((1, 1, page, hdk), kv_map, memory_space=_VMEM),
         pl.BlockSpec((1, 1, page, hdk), kv_map, memory_space=_VMEM),
-        pl.BlockSpec((1,), smem_map, memory_space=pltpu.SMEM),
+        pl.BlockSpec(memory_space=pltpu.SMEM),
     ]
     operands = [qf, k_pool, v_pool, idx]
     if quantized:
@@ -789,7 +807,6 @@ def _verify_impl(q, k_pool, v_pool, k_scales, v_scales, page_table, index,
                     (1, 1, page // 128, 128), kv_map, memory_space=_VMEM
                 )
             )
-    on_tpu = jax.default_backend() == "tpu"
     scratch = [
         pltpu.VMEM((gcp, 1), jnp.float32),
         pltpu.VMEM((gcp, 1), jnp.float32),
@@ -817,14 +834,10 @@ def _verify_impl(q, k_pool, v_pool, k_scales, v_scales, page_table, index,
             ),
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((b * kvh, gcp, hd), q.dtype),
-            compiler_params=(
-                pltpu.CompilerParams(
-                    dimension_semantics=("parallel", "arbitrary")
-                )
-                if on_tpu
-                else None
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary")
             ),
-            interpret=not on_tpu,
+            interpret=pallas_interpret(),
         )(jnp.asarray(page_table, jnp.int32), *operands)
         return out.reshape(b, kvh, gcp, hd)[:, :, :gc, :]
 
@@ -862,14 +875,10 @@ def _verify_impl(q, k_pool, v_pool, k_scales, v_scales, page_table, index,
             jax.ShapeDtypeStruct((b * kvh, split, gcp, hd), jnp.float32),
             jax.ShapeDtypeStruct((b * kvh, split, gcp, hd), jnp.float32),
         ),
-        compiler_params=(
-            pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary")
-            )
-            if on_tpu
-            else None
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
-        interpret=not on_tpu,
+        interpret=pallas_interpret(),
     )(jnp.asarray(page_table, jnp.int32), *operands)
     out = _combine_splits(o_p, m_p, l_p, q.dtype)
     return out.reshape(b, kvh, gcp, hd)[:, :, :gc, :]
@@ -886,6 +895,7 @@ def paged_verify_attention(
     window: int | None = None,
     tree_tail: int = 0,
     split: int | None = None,
+    head_shard=None,
 ) -> jax.Array:
     """Batched multi-token verify attention over a paged KV cache — the
     speculative-decode counterpart of :func:`paged_attention` (K chunk
@@ -898,36 +908,26 @@ def paged_verify_attention(
     ``tree_tail`` marks the chunk's last w rows as tree-draft leaves
     (``decode_attention.verify_attention``'s mask). ``split`` is the
     flash-decoding page-axis split (None = auto on TPU, 1 off-TPU).
-    Dispatch as :func:`paged_attention`: the scalar-prefetch
-    kernel on a real TPU with lane-multiple pages (the gather oracle
-    materializes every slot's whole window — the traffic paging exists
-    to avoid), the oracle everywhere else. Grids and the GQA fold
-    derive from the shapes given — the per-shard head count under
-    tensor parallelism — so q and pool must carry the same head count
-    (``decode_attention.check_head_parity``)."""
-    quantized = isinstance(k_pool, tuple)
+    Dispatch and ``head_shard`` as :func:`paged_attention`. Grids and
+    the GQA fold derive from the shapes given — the per-shard head
+    count under tensor parallelism — so q and pool must carry the same
+    head count (``decode_attention.check_head_parity``)."""
     check_head_parity(q.shape[1], pool_values(k_pool).shape[1])
-    page = pool_values(k_pool).shape[2]
-    supported = _kernel_supported(page, quantized)
-    if prefer is None:
-        prefer = (
-            "pallas"
-            if supported and jax.default_backend() == "tpu"
-            else "xla"
-        )
-    elif prefer not in ("pallas", "xla"):
-        raise ValueError(
-            f"prefer={prefer!r}: expected None, 'pallas' or 'xla'"
-        )
-    if prefer == "pallas" and supported:
-        split = resolve_decode_split(page_table.shape[1], split)
-        record_kernel_dispatch("paged_verify", "pallas")
+    if resolve_prefer(
+        "paged_verify", prefer, kernel_unsupported(q, k_pool), on_tpu()
+    ):
         kv, vv, ks, vs = _split_pools(k_pool, v_pool)
-        return _verify_impl(
-            q, kv, vv, ks, vs, page_table, index, chunk, window,
-            tree_tail, split,
+        return _head_sharded(
+            functools.partial(
+                _verify_impl, chunk=chunk, window=window,
+                tree_tail=tree_tail,
+                split=resolve_decode_split(page_table.shape[1], split),
+            ),
+            head_shard,
+            (q, kv, vv, ks, vs),
+            (jnp.asarray(page_table, jnp.int32),
+             jnp.asarray(index, jnp.int32)),
         )
-    record_kernel_dispatch("paged_verify", "xla")
     return paged_verify_attention_reference(
         q, k_pool, v_pool, page_table, index, chunk, window, tree_tail
     )
@@ -942,6 +942,7 @@ def paged_attention(
     valid_from=None,
     prefer: str | None = None,
     split: int | None = None,
+    head_shard=None,
 ) -> jax.Array:
     """Decode attention over a paged KV cache.
 
@@ -954,32 +955,35 @@ def paged_attention(
     window, the exact traffic paging exists to avoid), the oracle
     everywhere else (off-TPU the kernel only has the Pallas INTERPRETER,
     orders of magnitude slower than XLA's gather — tests opt in with
-    ``prefer="pallas"``). ``"pallas"`` / ``"xla"`` force. ``split`` is
+    ``prefer="pallas"``). ``"pallas"`` / ``"xla"`` force; a forced
+    kernel on an unsupported page size raises
+    (``dispatch.resolve_prefer``). ``head_shard`` = ``(mesh, axis)``
+    under tensor parallelism: the kernel runs per KV-head shard inside
+    a ``shard_map`` (``_head_sharded``); the oracle needs none — GSPMD
+    partitions its einsums. ``split`` is
     the flash-decoding split along the slot's page list (None = auto:
     ``decode_attention.default_decode_split`` of pages_per_slot on a
     real TPU, 1 off-TPU; 1 = the original single-stream kernel,
     bit-exact). Grids/folds
     derive from the given (per-shard, under TP) head count — q and pool
     must agree (``decode_attention.check_head_parity``)."""
-    quantized = isinstance(k_pool, tuple)
     check_head_parity(q.shape[1], pool_values(k_pool).shape[1])
-    page = pool_values(k_pool).shape[2]
-    supported = _kernel_supported(page, quantized)
-    if prefer is None:
-        on_tpu = jax.default_backend() == "tpu"
-        prefer = "pallas" if (supported and on_tpu) else "xla"
-    elif prefer not in ("pallas", "xla"):
-        raise ValueError(
-            f"prefer={prefer!r}: expected None, 'pallas' or 'xla'"
-        )
-    if prefer == "pallas" and supported:
-        split = resolve_decode_split(page_table.shape[1], split)
-        record_kernel_dispatch("paged_decode", "pallas")
+    if resolve_prefer(
+        "paged_decode", prefer, kernel_unsupported(q, k_pool), on_tpu()
+    ):
         kv, vv, ks, vs = _split_pools(k_pool, v_pool)
-        return _paged_impl(
-            q, kv, vv, ks, vs, page_table, index, valid_from, split
+        return _head_sharded(
+            functools.partial(
+                _paged_impl,
+                split=resolve_decode_split(page_table.shape[1], split),
+            ),
+            head_shard,
+            (q, kv, vv, ks, vs),
+            (jnp.asarray(page_table, jnp.int32),
+             jnp.asarray(index, jnp.int32),
+             None if valid_from is None
+             else jnp.asarray(valid_from, jnp.int32)),
         )
-    record_kernel_dispatch("paged_decode", "xla")
     return paged_attention_reference(
         q, k_pool, v_pool, page_table, index, valid_from
     )

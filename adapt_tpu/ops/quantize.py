@@ -25,23 +25,14 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # TPU memory spaces are importable everywhere jax is, but be safe
-    from jax.experimental.pallas import tpu as pltpu
+from adapt_tpu.ops.dispatch import pallas_interpret
 
-    _VMEM = pltpu.VMEM
-    _SMEM = pltpu.SMEM
-except Exception:  # pragma: no cover - exotic builds
-    pltpu = None
-    _VMEM = None
-    _SMEM = None
-
+_VMEM = pltpu.VMEM
+_SMEM = pltpu.SMEM
 LANES = 128
 BLOCK_ROWS = 64  # one scale per 64*128 = 8192 elements
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @jax.tree_util.register_pytree_node_class
@@ -116,7 +107,7 @@ def quantize(x: jax.Array) -> QuantizedTensor:
             jax.ShapeDtypeStruct(rows.shape, jnp.int8),
             jax.ShapeDtypeStruct((num_blocks, 1), jnp.float32),
         ),
-        interpret=_interpret(),
+        interpret=pallas_interpret(),
     )(rows)
     return QuantizedTensor(vals, scales, tuple(x.shape), x.dtype)
 
@@ -146,7 +137,7 @@ def dequantize(qt: QuantizedTensor) -> jax.Array:
             (BLOCK_ROWS, LANES), lambda i: (i, 0), memory_space=_VMEM
         ),
         out_shape=jax.ShapeDtypeStruct(qt.values.shape, jnp.float32),
-        interpret=_interpret(),
+        interpret=pallas_interpret(),
     )(qt.values, qt.scales)
     size = math.prod(qt.shape)
     return out.reshape(-1)[:size].reshape(qt.shape).astype(qt.dtype)

@@ -62,7 +62,6 @@ from adapt_tpu.models.transformer_lm import (
     sample_next_tokens,
     validate_generate_args,
 )
-from adapt_tpu.parallel.compat import shard_map
 
 
 @dataclasses.dataclass(frozen=True)
@@ -291,8 +290,9 @@ def _pipelined_impl(
     rows2 = P(None, dp_axis) if dp_axis else rep
 
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
+        check_vma=False,
         in_specs=(
             param_specs,
             rep_tree(embed_vars),

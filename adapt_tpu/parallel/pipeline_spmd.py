@@ -52,10 +52,6 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 
-from adapt_tpu.parallel.compat import shard_map as _shard_map_compat
-from adapt_tpu.parallel.compat import to_varying as _to_varying
-
-
 def stack_stage_params(per_block_variables: list[Any]) -> Any:
     """Stack identical-structure per-block param pytrees along a new leading
     axis (the pipeline-shardable layout)."""
@@ -147,12 +143,13 @@ def spmd_pipeline(
             outputs = jnp.where(write, updated, outputs)
             return (y, outputs), None
 
-        init = _to_varying(
+        init = lax.pcast(
             (
                 jnp.zeros(mb_shape, xs_local.dtype),
                 jnp.zeros((num_micro, *mb_shape), xs_local.dtype),
             ),
             vary_axes,
+            to="varying",
         )
         (_, outputs), _ = lax.scan(step, init, jnp.arange(ticks))
         # Only the last rank holds real outputs; replicate over the pipeline
@@ -204,7 +201,7 @@ def spmd_pipeline(
             return (cur, sendbuf, outputs), None
 
         first = lax.dynamic_index_in_dim(xs_local, 0, 0, keepdims=False)
-        init = _to_varying(
+        init = lax.pcast(
             (
                 jnp.where(
                     rank == 0, first, jnp.zeros(mb_shape, xs_local.dtype)
@@ -213,6 +210,7 @@ def spmd_pipeline(
                 jnp.zeros((num_micro, *mb_shape), xs_local.dtype),
             ),
             vary_axes,
+            to="varying",
         )
         (_, _, outputs), _ = lax.scan(step, init, jnp.arange(ticks))
         return lax.psum(outputs, axis)
@@ -220,8 +218,9 @@ def spmd_pipeline(
     body = (
         pipelined_serial if schedule == "serial" else pipelined_overlap
     )
-    pipelined = _shard_map_compat(
-        body, mesh=mesh, in_specs=(param_specs, x_spec), out_specs=x_spec
+    pipelined = jax.shard_map(
+        body, mesh=mesh, in_specs=(param_specs, x_spec), out_specs=x_spec,
+        check_vma=False,
     )
     return pipelined(stacked_params, xs)
 

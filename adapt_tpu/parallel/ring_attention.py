@@ -24,7 +24,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from adapt_tpu.parallel.compat import shard_map, to_varying
 
 _NEG_INF = -1e30
 
@@ -193,8 +192,9 @@ def ring_attention(
     spec = P(None, None, axis, None)
 
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
+        check_vma=False,
         in_specs=(spec, spec, spec),
         out_specs=spec,
     )
@@ -230,13 +230,14 @@ def ring_attention(
             return (m, l, o, k_nxt, v_nxt), None
 
         init = (
-            *to_varying(
+            *lax.pcast(
                 (
                     jnp.full((b, h, sq, 1), _NEG_INF, q_l.dtype),
                     jnp.zeros((b, h, sq, 1), q_l.dtype),
                     jnp.zeros((b, h, sq, d), q_l.dtype),
                 ),
                 (axis,),
+                to="varying",
             ),
             k_l,
             v_l,
@@ -281,8 +282,9 @@ def _ring_attention_flash(
     spec = P(None, None, axis, None)
 
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
+        check_vma=False,
         in_specs=(spec, spec, spec),
         out_specs=spec,
     )

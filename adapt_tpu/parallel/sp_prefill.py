@@ -74,7 +74,6 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from adapt_tpu.models.transformer_lm import TransformerLM, validate_tp
-from adapt_tpu.parallel.compat import shard_map
 from adapt_tpu.parallel.sharding import lm_tp_rules, replicate, tree_shardings
 from adapt_tpu.utils.logging import get_logger
 from adapt_tpu.utils.profiling import aggregate_size_fn, global_compile_sentinel
@@ -122,8 +121,8 @@ def ring_collect(x, mesh: Mesh, axis: str, seq_dim: int = 2,
         )
     ring = [(i, (i + 1) % n) for i in range(n)]
 
-    @partial(shard_map, mesh=mesh, in_specs=(in_spec,),
-             out_specs=out_spec)
+    @partial(jax.shard_map, mesh=mesh, in_specs=(in_spec,),
+             out_specs=out_spec, check_vma=False)
     def run(xl):
         rank = lax.axis_index(axis)
         s_local = xl.shape[seq_dim]

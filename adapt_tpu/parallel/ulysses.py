@@ -30,7 +30,6 @@ import jax
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from adapt_tpu.parallel.compat import shard_map
 
 
 def ulysses_attention(
@@ -65,8 +64,9 @@ def ulysses_attention(
     spec = P(None, None, axis, None)
 
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
+        check_vma=False,
         in_specs=(spec, spec, spec),
         out_specs=spec,
     )

@@ -1439,6 +1439,17 @@ class ContinuousBatcher:
             caches,
         )
 
+    def _head_shard(self):
+        """``(mesh, axis)`` for the paged attention dispatchers while
+        the programs are tp-partitioned, None otherwise: a Pallas
+        kernel inside a GSPMD program must run per head shard under
+        ``shard_map`` (``ops.paged_attention._head_sharded``). Read at
+        TRACE time, like ``_shard_kv``'s sharding — the static
+        ``epoch`` argument re-traces after a recovery."""
+        if self._mesh is None:
+            return None
+        return (self._mesh, self._axis)
+
     def _repl_state(self, dstate):
         """Explicit in/out sharding for the per-slot sampling state:
         pinned REPLICATED through every donated program. Left to
@@ -1590,6 +1601,7 @@ class ContinuousBatcher:
                         variables[name], x, kp, vp, table, pos, None,
                         self._kernel.attn_impl,
                         self._kernel.decode_split,
+                        self._head_shard(),
                         method="decode_step_paged",
                     )
                     new_caches.append((kp, vp))
@@ -1730,6 +1742,7 @@ class ContinuousBatcher:
                     variables[name], x, kp, vp, table, pos,
                     self._kernel.attn_impl, w,
                     self._kernel.decode_split,
+                    self._head_shard(),
                     method="verify_chunk_paged",
                 )
                 new_caches.append((kp, vp))
@@ -2497,6 +2510,7 @@ class ContinuousBatcher:
             ):
                 h, kp, vp = block.apply(
                     variables[name], h, kp, vp, pages, pos0,
+                    head_shard=self._head_shard(),
                     method="prefill_chunk_paged",
                 )
                 new_caches.append((kp, vp))

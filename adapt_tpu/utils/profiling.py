@@ -474,22 +474,23 @@ def unregister_memory_source(label: str, obj) -> None:
 
 
 def _device_memory_stats() -> dict[str, float]:
-    """``memory.hbm_*`` from the backend, when it reports them (TPU/GPU
-    backends do; CPU returns None/raises — then nothing is exported,
-    rather than a lying zero)."""
+    """``memory.hbm_*`` from the backend, summed over EVERY local
+    device (one process drives all the chips of a host — reading chip 0
+    alone would hide three quarters of a four-chip host), when the
+    backend reports them (TPU/GPU backends do; CPU returns None/raises
+    — then nothing is exported, rather than a lying zero)."""
+    out: dict[str, float] = {}
     try:
         import jax
 
-        stats = jax.local_devices()[0].memory_stats()
+        for dev in jax.local_devices():
+            stats = dev.memory_stats() or {}
+            for key in ("bytes_in_use", "bytes_limit"):
+                if key in stats:
+                    name = f"memory.hbm_{key}"
+                    out[name] = out.get(name, 0.0) + float(stats[key])
     except Exception:  # noqa: BLE001 — no backend / no stats: no gauges
         return {}
-    if not stats:
-        return {}
-    out = {}
-    if "bytes_in_use" in stats:
-        out["memory.hbm_bytes_in_use"] = float(stats["bytes_in_use"])
-    if "bytes_limit" in stats:
-        out["memory.hbm_bytes_limit"] = float(stats["bytes_limit"])
     return out
 
 
@@ -515,15 +516,14 @@ def engine_collector(reg: MetricsRegistry) -> None:
     # retired batcher's engine.flops.*/mbu/mfu entries disappear with
     # its memory gauges instead of scraping stale forever.
     totals.update(_roofline_gauges())
-    # Kernel-vs-oracle dispatch gauges (ops/decode_attention records
-    # every dispatcher resolution at trace time): the
-    # ``_kernel_supported`` fallback to the XLA oracle used to be
-    # SILENT — a perf cliff invisible in metrics. 1.0 = the op's most
-    # recent lowering took the Pallas kernel, 0.0 = the oracle; the
-    # per-path lifetime counts ride along so a mixed history (some
-    # programs on each path) is visible too.
+    # Kernel-vs-oracle dispatch gauges (ops/dispatch books every
+    # dispatcher resolution at trace time), so a route to the XLA
+    # oracle is never a perf cliff invisible in metrics. 1.0 = the
+    # op's most recent lowering took the Pallas kernel, 0.0 = the
+    # oracle; the per-path lifetime counts ride along so a mixed
+    # history (some programs on each path) is visible too.
     try:
-        from adapt_tpu.ops.decode_attention import kernel_dispatch_stats
+        from adapt_tpu.ops.dispatch import kernel_dispatch_stats
 
         for op, d in kernel_dispatch_stats().items():
             totals[f"engine.kernel_dispatch.{op}"] = d["last"]
@@ -556,18 +556,15 @@ global_metrics().register_collector(engine_collector)
 # -- roofline accounting ----------------------------------------------------
 
 #: Peak (FLOP/s, HBM bytes/s) per device KIND (``device.device_kind``,
-#: lowercased) with a bare-platform fallback row — the denominators of
-#: MFU/MBU. Generation rows are the published bf16 peak FLOP/s and HBM
-#: bandwidth: v4 275 TF / 1.23 TB/s, v5e 197 TF / 819 GB/s (mirroring
-#: ``benchmarks/tpu_models.py`` TPU_V5E_PEAK_FLOPS and the
-#: ``benchmarks/README.md`` decode-MBU model), v5p 459 TF / 2.77 TB/s,
-#: v6e (Trillium) 918 TF / 1.64 TB/s. The bare ``"tpu"`` row keeps the
-#: historical v5e default for kinds not listed (override via the env
-#: knobs below). Platforms absent here (CPU!) get NO mfu/mbu gauges —
-#: flops and bytes export alone, because dividing by a made-up peak
-#: would manufacture a utilization number.
+#: lowercased) — the denominators of MFU/MBU. Rows are the published
+#: per-chip bf16 peak FLOP/s and HBM bandwidth (Google Cloud TPU
+#: documentation): v4 275 TF / 1.23 TB/s, v5e 197 TF / 819 GB/s
+#: (mirrored by ``benchmarks/tpu_models.py``), v5p 459 TF / 2.77 TB/s,
+#: v6e (Trillium) 918 TF / 1.64 TB/s. A kind that is not listed (and
+#: CPU!) gets NO mfu/mbu gauges — flops and bytes export alone, because
+#: dividing by another chip's peak would manufacture a utilization
+#: number; ``bench.py`` treats an unlisted kind as an error.
 ROOFLINE_PEAKS: dict[str, tuple[float, float]] = {
-    "tpu": (197e12, 8.19e11),
     "tpu v4": (275e12, 1.2288e12),
     "tpu v5e": (197e12, 8.19e11),
     "tpu v5 lite": (197e12, 8.19e11),
@@ -585,9 +582,9 @@ def roofline_peaks() -> tuple[float, float] | None:
     override everything (set BOTH — the knob for unlisted hardware,
     and what lets tests exercise the mfu/mbu math on the CPU backend
     with explicit, visible peaks); otherwise the device KIND row
-    (``jax.local_devices()[0].device_kind``, lowercased — v4/v5e/v5p/
-    v6e each have their own peaks), falling back to the bare platform
-    row. Catalog: ``docs/OBSERVABILITY.md`` "Roofline gauges"."""
+    (``jax.local_devices()[0].device_kind``, lowercased — the chips of
+    one host are one kind). An unlisted kind is None, like the CPU.
+    Catalog: ``docs/OBSERVABILITY.md`` "Roofline gauges"."""
     env_f = os.environ.get("ADAPT_TPU_PEAK_FLOPS")
     env_b = os.environ.get("ADAPT_TPU_PEAK_BYTES_S")
     if env_f and env_b:
@@ -598,14 +595,10 @@ def roofline_peaks() -> tuple[float, float] | None:
     try:
         import jax
 
-        dev = jax.local_devices()[0]
-        platform = dev.platform
-        kind = str(getattr(dev, "device_kind", "") or "").lower()
+        kind = str(jax.local_devices()[0].device_kind or "").lower()
     except Exception:  # noqa: BLE001 — no backend: no claims
         return None
-    if kind in ROOFLINE_PEAKS:
-        return ROOFLINE_PEAKS[kind]
-    return ROOFLINE_PEAKS.get(platform)
+    return ROOFLINE_PEAKS.get(kind)
 
 
 #: Weakly-held roofline sources: (label, id) -> object exposing

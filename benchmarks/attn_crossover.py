@@ -14,11 +14,10 @@ TPU; together with the end-to-end A/B (``tpu_vit_b16_ab.json``) and the
 long-sequence sweep (``attn_longseq.json``) it backs the dispatch in
 ``adapt_tpu.ops.attention`` (``FLASH_SCORE_BYTES_BUDGET`` +
 ``FLASH_MIN_SEQ`` guard). Perf-first dispatch, backed by artifacts
-rather than folklore — note the caveat recorded in this artifact: at
-small shapes these standalone micro-timings are relay-overhead-dominated
-and the END-TO-END A/B is the authority.
+rather than folklore — at small shapes a standalone micro-timing is
+dispatch-overhead-dominated and the END-TO-END A/B is the authority.
 
-Usage: ``python benchmarks/attn_crossover.py --out benchmarks/results/r03/attn_crossover.json``
+Usage: ``python benchmarks/attn_crossover.py --out chiprun_out/attn_crossover.json``
 """
 
 from __future__ import annotations

@@ -509,9 +509,10 @@ def build_batcher(
     prefill_chunk: int | None = None,
     runtime=None,
 ):
-    """The harness's model+batcher factory (CPU-forced; tiny LM — the
-    harness measures the serving tier's behavior under load, not model
-    quality). ``scheduler`` (a ``config.SchedulerConfig``) turns the
+    """The harness's model+batcher factory (tiny LM — the harness
+    measures the serving tier's behavior under load, not model quality;
+    the backend is whatever the caller's environment selects: the CPU
+    gates export ``JAX_PLATFORMS=cpu``). ``scheduler`` (a ``config.SchedulerConfig``) turns the
     traffic-control tier on — the quota-on arm of an overload A/B.
     ``cache_tier`` (a ``config.CacheTierConfig``; paged only) turns
     the host-DRAM spill tier on — the tier-on arm of the corpus A/B —
@@ -523,11 +524,9 @@ def build_batcher(
     ``benchmarks.common.force_cpu_mesh``). ``runtime`` (a
     ``config.RuntimeConfig``) selects the tick runtime — depth 2 is
     the pipelined/async arm of the runtime A/B."""
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     import jax
     import jax.numpy as jnp
 
-    jax.config.update("jax_platforms", "cpu")
     from adapt_tpu.models.transformer_lm import lm_tiny
     from adapt_tpu.runtime.continuous import ContinuousBatcher
 

@@ -36,7 +36,7 @@ Emits TWO gated records (one JSON line each):
 
 Per-config tick walls and compile counts ride as extras.
 ``engine.mbu`` gating on the decode program stays PENDING the first
-real TPU row (BENCH_r06+ probe rebuild): on CPU there is no honest
+real TPU row (ROADMAP A0/A1): on CPU there is no honest
 peak to divide by (``utils/profiling.roofline_peaks``).
 
 Usage: ``python benchmarks/micro/decode_split.py [--ticks 3]``

@@ -79,7 +79,7 @@ def main() -> int:
             t0 = time.perf_counter()
             trials = 10
             for i in range(trials):
-                # distinct inputs defeat execution dedup (common.py)
+                # a distinct input per trial
                 y = np.asarray(fn(stacked, xs + i * 1e-6))
             return y, (time.perf_counter() - t0) / trials
 

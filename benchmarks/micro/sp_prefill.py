@@ -152,7 +152,7 @@ def main() -> int:
                 jnp.zeros((1,), jnp.int32),
             ).compile()
         )
-        # sp=1 wall: run it (distinct inputs defeat dedup).
+        # sp=1 wall: run it.
         w1.submit(1, long_prompt)
         t0 = time.perf_counter()
         outs = []

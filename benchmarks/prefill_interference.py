@@ -149,7 +149,6 @@ def main() -> int:
         return 0
     env = dict(os.environ)
     if cpu:
-        env.pop("PYTHONPATH", None)
         env["JAX_PLATFORMS"] = "cpu"
     cmd = [sys.executable, os.path.abspath(__file__), "--child",
            "--long", str(long_len), "--chunk", str(chunk)]

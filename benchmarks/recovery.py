@@ -15,8 +15,9 @@ Two configs:
                              stage-weight device_put, not a toy one
 
 Runs on the virtual CPU mesh: recovery time is a *control-plane + weight
-movement* metric, not an MXU metric, and only the CPU backend gives honest
-``block_until_ready`` semantics in this image (see benchmarks/common.py).
+movement* metric, not an MXU metric — but a weight move between real
+chips is not a host memcpy, so the real-chip recovery-to-serve number is
+still to be taken (ROADMAP A6/B0).
 
 Definition measured: from the moment a worker is killed (crash mode: the
 exec loop dies and stops heartbeating — the reference's machine death)

@@ -128,7 +128,6 @@ def main() -> int:
         return 0
     env = dict(os.environ)
     if cpu:
-        env.pop("PYTHONPATH", None)
         env["JAX_PLATFORMS"] = "cpu"
     metric = f"speculative_{draft_kind}_k{k}_tokens_per_sec"
     cmd = [sys.executable, os.path.abspath(__file__), "--child",

@@ -15,7 +15,7 @@
 // A final sequence may have no match (offset omitted when the stream ends
 // after literals).
 //
-// Exposed via ctypes (no pybind11 in this image): see adapt_tpu/comm/codec.py.
+// Exposed via ctypes: see adapt_tpu/comm/native.py.
 
 #include <cstdint>
 #include <cstring>

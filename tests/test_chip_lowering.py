@@ -1,0 +1,324 @@
+"""Every Pallas entry the serving path can pick must LOWER for a TPU.
+
+The CPU suite runs the kernels through the Pallas interpreter, which
+checks none of Mosaic's rules — that is how a rank-1 ``(1,)`` SMEM
+block (refused by the TPU lowering) and a ``pallas_call`` inside a
+GSPMD-partitioned program ("Mosaic kernels cannot be automatically
+partitioned") both shipped. Here the backend question is answered
+"tpu" for the duration of a test, so every dispatcher picks the
+compiled kernel, and the program is cross-lowered with
+``lowering_platforms=("tpu",)`` from the CPU: the Pallas-to-Mosaic
+lowering and the partitioning check run without a chip. What Mosaic's
+own compiler accepts is ``chip_smoke.py``'s job.
+"""
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from adapt_tpu.ops.attention import flash_attention, flash_attention_with_lse
+from adapt_tpu.ops.decode_attention import decode_attention
+from adapt_tpu.ops.dispatch import kernel_dispatch_stats
+from adapt_tpu.ops.paged_attention import (
+    paged_attention,
+    paged_attention_reference,
+    paged_chunk_attention,
+    paged_verify_attention,
+)
+from adapt_tpu.ops.quantize import QuantizedTensor, dequantize, quantize
+from adapt_tpu.utils import compile_cache
+
+B, KVH, HD, PPS, NPAGES = 8, 12, 64, 8, 65
+
+
+@pytest.fixture
+def as_tpu(monkeypatch):
+    """Answer "tpu" wherever the ops ask for the backend, with clean
+    jit caches on both sides (a trace cached under the interpreter
+    would lower vacuously; one cached here would not run on CPU)."""
+    jax.clear_caches()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    yield
+    jax.clear_caches()
+
+
+def sds(shape, dtype=jnp.bfloat16):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+def pool(page, dtype):
+    """An abstract K or V pool: native, or the quantized
+    ``(values, scales)`` pair (int4 packs two nibbles per lane)."""
+    if dtype == "native":
+        return sds((NPAGES, KVH, page, HD))
+    width = HD // 2 if dtype == "int4" else HD
+    return (
+        sds((NPAGES, KVH, page, width), jnp.int8),
+        sds((NPAGES, KVH, page, 1), jnp.float32),
+    )
+
+
+def lower_for_tpu(fn, *args, kernels=1):
+    text = (
+        jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",)).as_text()
+    )
+    assert text.count("tpu_custom_call") >= kernels, (
+        "no Mosaic kernel in the lowered program — the dispatcher "
+        "routed away or the trace was interpreted"
+    )
+
+
+TABLE = sds((B, PPS), jnp.int32)
+INDEX = sds((B,), jnp.int32)
+
+
+@pytest.mark.parametrize("split", [1, 2, None])
+@pytest.mark.parametrize("dtype,page", [("native", 128), ("int8", 1024)])
+def test_paged_decode_lowers(as_tpu, split, dtype, page):
+    k = pool(page, dtype)
+    lower_for_tpu(
+        lambda q, k, v, t, i, vf: paged_attention(
+            q, k, v, t, i, vf, split=split
+        ),
+        sds((B, KVH, 1, HD)), k, k, TABLE, INDEX, INDEX,
+    )
+
+
+@pytest.mark.parametrize("tree_tail", [0, 2])
+@pytest.mark.parametrize("dtype,page", [("native", 128), ("int8", 1024)])
+def test_paged_verify_lowers(as_tpu, tree_tail, dtype, page):
+    k = pool(page, dtype)
+    for split in (1, None):
+        lower_for_tpu(
+            lambda q, k, v, t, i: paged_verify_attention(
+                q, k, v, t, i, 5, tree_tail=tree_tail, split=split
+            ),
+            sds((B, KVH, 5, HD)), k, k, TABLE, INDEX,
+        )
+
+
+@pytest.mark.parametrize("dtype,page,window", [
+    ("native", 128, None), ("native", 128, 300), ("int8", 1024, None),
+])
+def test_paged_chunk_lowers(as_tpu, dtype, page, window):
+    k = pool(page, dtype)
+    chunk = max(256, page)
+    lower_for_tpu(
+        lambda q, k, v, p, pos0: paged_chunk_attention(
+            q, k, v, p, pos0, chunk, window=window
+        ),
+        sds((1, KVH, chunk, HD)), k, k, sds((4,), jnp.int32),
+        sds((), jnp.int32),
+    )
+
+
+@pytest.mark.parametrize("quantized", [False, True])
+def test_dense_decode_lowers(as_tpu, quantized):
+    cache = sds((B, KVH, 2048, HD))
+    if quantized:
+        cache = (
+            sds((B, KVH, 2048, HD), jnp.int8),
+            sds((B, KVH, 2048, 1), jnp.float32),
+        )
+    for split in (1, None):
+        lower_for_tpu(
+            lambda q, k, v, i, vf: decode_attention(
+                q, k, v, i, vf, prefer="pallas", split=split
+            ),
+            sds((B, KVH, 1, HD)), cache, cache, INDEX, INDEX,
+        )
+
+
+def test_flash_lowers(as_tpu):
+    q = sds((2, 12, 512, HD))
+    vf = sds((2,), jnp.int32)
+    lower_for_tpu(
+        lambda q: flash_attention(q, q, q, causal=True, prefer="pallas"), q
+    )
+    lower_for_tpu(
+        lambda q, vf: flash_attention(
+            q, q, q, causal=True, prefer="pallas", valid_from=vf, window=200
+        ),
+        q, vf,
+    )
+    lower_for_tpu(
+        lambda q, s: flash_attention_with_lse(
+            q, q, q, causal=True, causal_shift=s
+        ),
+        q, sds((), jnp.int32),
+    )
+
+
+def test_flash_streaming_grad_lowers(as_tpu):
+    # Past FLASH_MIN_SEQ the backward streams too: forward + dQ + dK/dV.
+    q = sds((1, 1, 32768, HD))
+    vf = sds((1,), jnp.int32)
+
+    def loss(q, vf):
+        return flash_attention(
+            q, q, q, causal=True, valid_from=vf
+        ).astype(jnp.float32).sum()
+
+    lower_for_tpu(jax.grad(loss), q, vf, kernels=3)
+
+
+def test_quantize_roundtrip_lowers(as_tpu):
+    x = sds((3, 64 * 128), jnp.float32)
+    lower_for_tpu(quantize, x)
+    qt = QuantizedTensor(
+        sds((3 * 64, 128), jnp.int8), sds((3, 1), jnp.float32),
+        (3, 64 * 128), jnp.float32,
+    )
+    lower_for_tpu(dequantize, qt)
+
+
+@pytest.mark.parametrize("dtype,page,why", [
+    # int8 scale tiles need 1024-position pages on hardware.
+    ("int8", 128, "page_size 128"),
+    # The int4 nibble unpack does not fit Mosaic's scoped VMEM (measured
+    # on a v5e; ROADMAP A1).
+    ("int4", 1024, "int4 pools"),
+])
+def test_stated_rules_route_to_xla_on_tpu(as_tpu, dtype, page, why):
+    """What the kernels cannot serve on hardware is routed by a rule
+    the books report; forcing the kernel raises instead of serving the
+    oracle under its name."""
+    k = pool(page, dtype)
+    args = (sds((B, KVH, 1, HD)), k, k, TABLE, INDEX)
+    before = kernel_dispatch_stats().get("paged_decode", {"xla": 0.0})
+    text = jax.jit(paged_attention).trace(*args).lower(
+        lowering_platforms=("tpu",)
+    ).as_text()
+    assert "tpu_custom_call" not in text
+    assert kernel_dispatch_stats()["paged_decode"]["xla"] == before["xla"] + 1
+    with pytest.raises(ValueError, match=why):
+        jax.jit(
+            lambda *a: paged_attention(*a, prefer="pallas")
+        ).trace(*args)
+
+
+def _tp_mesh(devices, n=4):
+    return Mesh(np.asarray(devices[:n]), ("tp",))
+
+
+def test_tp4_paged_decode_lowers_under_shard_map(as_tpu, devices):
+    """The batcher's programs are GSPMD-partitioned over the tp mesh;
+    the kernel must sit in a shard_map over the head axis or the TPU
+    lowering refuses the program."""
+    mesh = _tp_mesh(devices)
+    heads = NamedSharding(mesh, P(None, "tp"))
+    repl = NamedSharding(mesh, P())
+
+    def step(q, k, v, t, i, head_shard):
+        return paged_attention(q, k, v, t, i, head_shard=head_shard)
+
+    k = pool(128, "native")
+    args = (sds((B, KVH, 1, HD)), k, k, TABLE, INDEX)
+    shardings = (heads, heads, heads, repl, repl)
+    sharded = jax.jit(
+        functools.partial(step, head_shard=(mesh, "tp")),
+        in_shardings=shardings, out_shardings=heads,
+    )
+    text = sharded.trace(*args).lower(
+        lowering_platforms=("tpu",)
+    ).as_text()
+    assert "tpu_custom_call" in text
+    bare = jax.jit(
+        functools.partial(step, head_shard=None),
+        in_shardings=shardings, out_shardings=heads,
+    )
+    with pytest.raises(NotImplementedError, match="shard_map"):
+        bare.trace(*args).lower(lowering_platforms=("tpu",))
+
+
+def test_head_sharded_kernels_match_oracles(devices):
+    """Interpreter parity of the shard_map route itself: per-shard
+    kernels over a tp=4 head split equal the single-device oracles
+    (decode with int8 pools and ragged rows, verify with a tree tail
+    and a split, chunk)."""
+    from adapt_tpu.ops.paged_attention import (
+        paged_chunk_attention_reference,
+        paged_verify_attention_reference,
+    )
+    from adapt_tpu.ops.quantize import quantize_kv_vectors
+
+    shard = (_tp_mesh(devices), "tp")
+    rng = np.random.RandomState(0)
+    b, kvh, g, hd, page = 2, 4, 2, 64, 128
+    kp = jnp.asarray(rng.randn(6, kvh, page, hd), jnp.float32)
+    vp = jnp.asarray(rng.randn(6, kvh, page, hd), jnp.float32)
+    table = jnp.asarray([[1, 2], [3, 4]], jnp.int32)
+    index = jnp.asarray([200, 90], jnp.int32)
+
+    def close(out, ref):
+        np.testing.assert_allclose(
+            np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5
+        )
+
+    q = jnp.asarray(rng.randn(b, kvh, g, hd), jnp.float32)
+    kq, vq = quantize_kv_vectors(kp), quantize_kv_vectors(vp)
+    vf = jnp.asarray([3, 0], jnp.int32)
+    close(
+        jax.jit(
+            lambda *a: paged_attention(
+                *a, prefer="pallas", split=2, head_shard=shard
+            )
+        )(q, kq, vq, table, index, vf),
+        paged_attention_reference(q, kq, vq, table, index, vf),
+    )
+    qv = jnp.asarray(rng.randn(b, kvh, g * 5, hd), jnp.float32)
+    close(
+        jax.jit(
+            lambda *a: paged_verify_attention(
+                *a, 5, prefer="pallas", tree_tail=2, split=2,
+                head_shard=shard,
+            )
+        )(qv, kp, vp, table, index),
+        paged_verify_attention_reference(
+            qv, kp, vp, table, index, 5, tree_tail=2
+        ),
+    )
+    qc = jnp.asarray(rng.randn(1, kvh, g * page, hd), jnp.float32)
+    pages = jnp.asarray([3, 5], jnp.int32)
+    close(
+        jax.jit(
+            lambda *a: paged_chunk_attention(
+                *a, page, prefer="pallas", head_shard=shard
+            )
+        )(qc, kp, vp, pages, jnp.int32(page)),
+        paged_chunk_attention_reference(qc, kp, vp, pages, page, page),
+    )
+
+
+# -- compile cache placement --------------------------------------------------
+
+
+def test_compile_cache_env_wins(monkeypatch):
+    updates = []
+    monkeypatch.setattr(
+        jax.config, "update", lambda *a: updates.append(a)
+    )
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
+    assert compile_cache.ensure_compile_cache() == "/some/dir"
+    assert updates == []
+
+
+def test_compile_cache_default_is_fixed_under_checkout(monkeypatch):
+    updates = []
+    monkeypatch.setattr(
+        jax.config, "update", lambda *a: updates.append(a)
+    )
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    want = os.path.join(checkout, ".jax_cache")
+    assert compile_cache.ensure_compile_cache() == want
+    assert compile_cache.ensure_compile_cache() == want  # no pid/time in it
+    assert updates == [
+        ("jax_compilation_cache_dir", want),
+        ("jax_persistent_cache_min_compile_time_secs", 0.0),
+    ] * 2

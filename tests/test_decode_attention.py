@@ -119,22 +119,23 @@ def test_short_native_cache_takes_the_kernel(rng, length):
     )
 
 
-def test_unsupported_configs_fall_back_to_oracle(rng):
+def test_forced_kernel_on_unsupported_config_raises(rng):
     # Native 192 (not 256-divisible) and int8 256 (scale tiles need
-    # 1024-divisible caches): prefer="pallas" silently serves the
-    # oracle — outputs are bit-identical to the reference because the
-    # same code path ran.
+    # 1024-divisible caches): a forced prefer="pallas" raises by name
+    # instead of serving the oracle under the kernel's name; auto
+    # dispatch routes to the oracle and books it.
     b, kvh, g, hd = 2, 2, 1, 64
     q = jax.random.normal(jax.random.fold_in(rng, 5), (b, kvh, g, hd))
     index = jnp.asarray(100, jnp.int32)
     ck, cv = _caches(rng, b, kvh, 192, hd, False, 100)
-    out = decode_attention(q, ck, cv, index, prefer="pallas")
+    with pytest.raises(ValueError, match="cache_len 192"):
+        decode_attention(q, ck, cv, index, prefer="pallas")
+    out = decode_attention(q, ck, cv, index)
     ref = decode_attention_reference(q, ck, cv, index)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
     ck8, cv8 = _caches(rng, b, kvh, 256, hd, True, 100)
-    out8 = decode_attention(q, ck8, cv8, index, prefer="pallas")
-    ref8 = decode_attention_reference(q, ck8, cv8, index)
-    np.testing.assert_array_equal(np.asarray(out8), np.asarray(ref8))
+    with pytest.raises(ValueError, match="cache_len 256"):
+        decode_attention(q, ck8, cv8, index, prefer="pallas")
 
 
 def test_bad_prefer_raises(rng):

@@ -254,14 +254,17 @@ def test_paged_chunk_kernel_quantized_matches_oracle(rng):
         )
 
 
-def test_paged_kernel_unsupported_page_size_falls_back(rng):
-    # page 16 is not a lane multiple: prefer="pallas" serves the oracle.
+def test_paged_kernel_unsupported_page_size_raises_when_forced(rng):
+    # page 16 is not a lane multiple: auto dispatch serves the oracle, a
+    # forced prefer="pallas" raises instead of serving it silently.
     b, kvh, g, hd, page, npages = 1, 2, 1, 64, 16, 8
     q = jax.random.normal(rng, (b, kvh, g, hd))
     kp = jax.random.normal(jax.random.fold_in(rng, 1), (npages, kvh, page, hd))
     vp = jax.random.normal(jax.random.fold_in(rng, 2), (npages, kvh, page, hd))
     table = jnp.asarray([[2, 5, 1]], jnp.int32)
-    out = paged_attention(q, kp, vp, table, 30, prefer="pallas")
+    with pytest.raises(ValueError, match="page_size 16"):
+        paged_attention(q, kp, vp, table, 30, prefer="pallas")
+    out = paged_attention(q, kp, vp, table, 30)
     ref = paged_attention_reference(q, kp, vp, table, 30)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-6)
 
