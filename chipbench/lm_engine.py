@@ -1,0 +1,508 @@
+"""One LM cell, start to finish, in one process: weights from the
+seed, the program's ``ContinuousBatcher`` built from the configuration
+file's serving block, a correctness sample against the plain
+reference, warm-up of every shape the traffic sends, the standing
+population, then the measured window.
+
+The program is driven through ``submit`` + ``tick`` from ONE thread
+(the load generator and the server share the machine's cores; a second
+thread would only add scheduling noise). Every number comes from
+token events stamped in the ``on_token`` callback.
+"""
+
+from __future__ import annotations
+
+import time
+
+import numpy as np
+
+from chipbench import traffic as tg
+from chipbench import window as win
+
+#: Served logprobs (bf16 weights, activations and cache, kernels) against
+#: the plain reference (the same bf16 weights read as float32, every
+#: product at ``highest``). What differs is bf16 rounding of
+#: activations through every layer. The largest error a v5e showed in
+#: this PR's runs is 0.0347 (PERF.md); the tolerance is twice that,
+#: since another seed's sample may round worse. ``--fault drop_block``
+#: leaves one block out of the reference, which is what a served
+#: model one block short looks like from here; PERF.md section 3 has
+#: the error that run read on the chip, and a tier-1 test holds the
+#: rehearsal to ``correct`` false under it.
+LOGPROB_TOL = 0.07
+#: Seconds of the window that a ``--trace 1`` run records (its end).
+TRACE_SECONDS = 8.0
+
+
+class Phases:
+    """Where set-up went: printed on a line of its own."""
+
+    def __init__(self, clock0: float):
+        self.t = clock0
+        self.parts: list[tuple[str, float]] = []
+
+    def mark(self, name: str) -> None:
+        now = time.perf_counter()
+        self.parts.append((name, now - self.t))
+        self.t = now
+
+    def line(self) -> str:
+        return "setup: " + "  ".join(f"{n} {s:.1f}s" for n, s in self.parts)
+
+
+class CompileCounter:
+    """Backend compiles (persistent-cache loads included) as
+    ``jax.monitoring`` reports them: every jitted program of the
+    process, registered with the program's ``CompileSentinel`` or
+    not. One in the window makes the run incorrect."""
+
+    def __init__(self):
+        import jax
+
+        self.count = 0
+        self.seconds = 0.0
+        jax.monitoring.register_event_duration_secs_listener(self._on)
+
+    def _on(self, event, seconds, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            self.count += 1
+            self.seconds += seconds
+
+
+def build_model(model: dict, dtype_name: str, seed: int):
+    """The program's LM at the file's sizes, weights made on the device
+    in one jitted call and cast there to the served type."""
+    import jax
+    import jax.numpy as jnp
+
+    from adapt_tpu.models.transformer_lm import transformer_lm
+
+    dtype = jnp.dtype(dtype_name)
+    lm = transformer_lm(
+        model["vocab_size"], model["n_embd"], model["n_layer"],
+        model["n_head"], model["n_inner"], max_len=model["n_positions"],
+        dtype=dtype,
+    )
+    # --seed may exceed 31 bits: fold the high part in.
+    key = jax.random.fold_in(
+        jax.random.PRNGKey(seed & 0x7FFFFFFF), seed >> 31
+    )
+
+    @jax.jit
+    def init(key):
+        tree = lm.graph.init(key, jnp.zeros((1, 8), jnp.int32))
+        return jax.tree.map(lambda x: x.astype(dtype), tree)
+
+    variables = jax.block_until_ready(init(key))
+    return lm, variables
+
+
+class Driver:
+    """Submits requests, ticks the server, and keeps the books the
+    metrics are read from."""
+
+    def __init__(self, srv, vocab: int, seed: int, annotate):
+        self.srv = srv
+        self.vocab = vocab
+        self.seed = seed
+        self.annotate = annotate
+        self.events: list[tuple[float, int, int]] = []
+        self.reqs: dict[int, dict] = {}
+        self.live: dict[int, dict] = {}
+        self.ticks: list[tuple[float, float, int, int]] = []
+        self.finished: list[dict] = []
+        self.submitted = 0
+        self.failed = 0
+        self.pool_peak = 0
+        self.sample_pool = False
+        #: Closed loop: the seed-permuted template walk the callers
+        #: draw their next request from (None: open loop).
+        self.stream = None
+        self._refilled = 0
+
+    def submit(self, req: tg.Request, due: float, client=None):
+        ids = tg.token_ids(self.seed, self.submitted, req.prompt_len,
+                           self.vocab)
+        self.submitted += 1
+        t = time.perf_counter()
+        try:
+            with self.annotate("chipbench.submit"):
+                rid = self.srv.submit(ids, req.out_len, on_token=self._token)
+        except Exception as e:  # noqa: BLE001 — refused counts as failed
+            print(f"submit refused: {type(e).__name__}: {e}", flush=True)
+            self.failed += 1
+            return None
+        info = dict(
+            rid=rid, prompt_len=req.prompt_len, out_len=req.out_len,
+            due=due, t_submit=t, emitted=0, client=client, ids=ids,
+            tokens=[],
+        )
+        self.reqs[rid] = self.live[rid] = info
+        return rid
+
+    def _token(self, rid: int, token: int, index: int) -> None:
+        t = time.perf_counter()
+        self.events.append((t, rid, index))
+        info = self.reqs[rid]
+        info["emitted"] = index + 1
+        info["tokens"].append(token)
+        if not 0 <= token < self.vocab:
+            info["bad"] = True
+        if index + 1 >= info["out_len"]:
+            info["t_done"] = t
+            self.live.pop(rid, None)
+            self.finished.append(info)
+
+    def refill(self) -> None:
+        """Closed loop: every caller whose request completed sends its
+        next one, due at the instant the last one completed."""
+        if self.stream is None:
+            return
+        done, self._refilled = (
+            self.finished[self._refilled:], len(self.finished)
+        )
+        for info in done:
+            if info["client"] is not None:
+                self.submit(tg.Request(*next(self.stream)),
+                            info["t_done"], client=info["client"])
+
+    def tick(self) -> None:
+        ctx = sum(
+            r["prompt_len"] + r["emitted"]
+            for r in self.live.values() if r["emitted"]
+        )
+        t0 = time.perf_counter()
+        with self.annotate("chipbench.tick"):
+            n = self.srv.tick()
+        self.ticks.append((t0, time.perf_counter(), n, ctx))
+        if self.sample_pool:
+            self.pool_peak = max(
+                self.pool_peak, self.srv.stats().get("pages_in_use", 0)
+            )
+
+    def run_until(self, done, limit_s: float = 600.0) -> None:
+        t_end = time.perf_counter() + limit_s
+        while not done():
+            if time.perf_counter() > t_end:
+                raise TimeoutError("set-up phase did not finish")
+            self.refill()
+            self.tick()
+
+
+SAMPLE_STEPS = 8
+
+
+def _sample_prompts(chunk: int, max_len: int) -> list[int]:
+    """Prompt lengths of the correctness sample: two whole-prompt
+    prefills and one that goes through chunked prefill."""
+    return [40, chunk - 17, min(chunk + 45, max_len - SAMPLE_STEPS)]
+
+
+def correctness_sample(drv: Driver, variables, serving: dict, max_len: int,
+                       fault: str = ""):
+    """Three seeded requests served outside the window, one of them
+    through chunked prefill, all decoding through the paged kernel;
+    their served logprobs against the plain reference's."""
+    import jax.numpy as jnp
+
+    from chipbench.reference import next_token_logprobs
+
+    steps = SAMPLE_STEPS
+    lens = _sample_prompts(serving["prefill_chunk"], max_len)
+    rids = [
+        drv.submit(tg.Request(n, steps), due=time.perf_counter())
+        for n in lens
+    ]
+    if None in rids:
+        return False, float("nan")
+    drv.run_until(lambda: all(r not in drv.live for r in rids))
+    width = max(lens) + steps
+    ids = np.zeros((len(rids), width), np.int32)
+    for row, rid in enumerate(rids):
+        info = drv.reqs[rid]
+        seq = np.concatenate([info["ids"], np.asarray(info["tokens"])])
+        ids[row, : len(seq)] = seq  # causal: the padding is never read
+    if fault == "drop_block":  # the self-test of this comparison
+        variables = {
+            k: v for k, v in variables.items() if k != "decoder_block_0"
+        }
+    want = np.asarray(next_token_logprobs(variables, jnp.asarray(ids)))
+    worst = 0.0
+    for row, rid in enumerate(rids):
+        n = lens[row]
+        got = np.asarray(drv.srv.logprobs(rid), np.float32)
+        ref = want[row, n - 1: n - 1 + steps]
+        if got.shape != ref.shape or not np.isfinite(got).all():
+            return False, float("nan")
+        worst = max(worst, float(np.max(np.abs(got - ref))))
+    return worst <= LOGPROB_TOL, worst
+
+
+def warm_up(drv: Driver, pairs) -> int:
+    """Send every distinct (prompt, output) shape the window will send,
+    to its first token, then cancel it. Empirical on purpose: the
+    benchmark does not mirror the program's bucketing rules, so a
+    change to them cannot leave a shape cold."""
+    slots = len(drv.srv.slots)
+    todo = sorted(set(pairs))
+    for i in range(0, len(todo), slots):
+        rids = [
+            drv.submit(tg.Request(p, o), due=time.perf_counter())
+            for p, o in todo[i: i + slots]
+        ]
+        rids = [r for r in rids if r is not None]
+        drv.run_until(
+            lambda: all(drv.reqs[r]["emitted"] for r in rids)
+        )
+        for r in rids:
+            drv.srv.cancel(r)
+            drv.live.pop(r, None)
+        drv.run_until(lambda: drv.srv.stats()["active"] == 0)
+    return len(todo)
+
+
+def measure(drv: Driver, traffic: dict, pairs, seconds: float, seed: int,
+            trace_dir: str | None):
+    """The measured window. Opens right after a tick has committed and
+    closes right after the first tick that ends past ``seconds``: both
+    edges sit on commit instants, so a rate counts whole ticks over
+    exactly the time they took."""
+    import jax
+
+    arrivals = (
+        [] if traffic["loop"] == "closed"
+        else tg.open_schedule(traffic, pairs, seed, seconds + 1.0)
+    )
+    late_ms: list[float] = []
+    trace = dict(on=False, t0=0.0, t1=0.0, prefill0=0, prefill1=0)
+    t_trace = seconds - min(seconds, TRACE_SECONDS)
+    i = 0
+    t_open = time.perf_counter()
+    while True:
+        now = time.perf_counter()
+        if now - t_open >= seconds:
+            break
+        if trace_dir and not trace["on"] and now - t_open >= t_trace:
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0
+            jax.profiler.start_trace(trace_dir, profiler_options=opts)
+            trace.update(
+                on=True, t0=time.perf_counter(),
+                prefill0=drv.srv.stats()["prefill_tokens"],
+            )
+        while i < len(arrivals) and t_open + arrivals[i].due_s <= now:
+            due = t_open + arrivals[i].due_s
+            drv.submit(arrivals[i], due)
+            late_ms.append((time.perf_counter() - due) * 1e3)
+            i += 1
+        drv.refill()
+        if drv.live:
+            drv.tick()
+        else:
+            time.sleep(0.001)  # idle server: wait for the next arrival
+    t_close = time.perf_counter()
+    if trace["on"]:
+        trace.update(
+            t1=t_close, prefill1=drv.srv.stats()["prefill_tokens"]
+        )
+        jax.profiler.stop_trace()
+    return dict(t_open=t_open, t_close=t_close, late_ms=late_ms, trace=trace)
+
+
+def _mute(*_, **__):
+    pass
+
+
+def pool_pages(serving: dict, pairs, max_len: int) -> int:
+    """The pool rule: every slot can hold the longest request this
+    traffic sends (or the correctness sample's, in set-up), plus the
+    trash page, and not a page more. No request ever waits on pages,
+    and no page is reserved that the deployment's own traffic could
+    not fill. The prompt buckets are multiples of the page size, so a
+    request's reservation, max(bucket, prompt + answer), rounds to the
+    same pages as prompt + answer."""
+    longest = max(
+        max(p + o for p, o in pairs), serving["prompt_buckets"][0],
+        max(_sample_prompts(serving["prefill_chunk"], max_len))
+        + SAMPLE_STEPS,
+    )
+    return serving["slots"] * -(-longest // serving["page_size"]) + 1
+
+
+def sweep(drv: Driver, traffic: dict, pairs, opts) -> None:
+    """Find the knee once, when the cell is defined: one window per
+    rate in one process, the server's state carried from one to the
+    next. A rate is sustained while nothing is queued at the close and
+    the requests in flight do not grow from window to window."""
+    for k, rate in enumerate(opts.sweep):
+        m = measure(drv, {**traffic, "rate_per_s": rate}, pairs,
+                    opts.seconds, opts.seed + k, None)
+        t0, t1 = m["t_open"], m["t_close"]
+        due = {rid: info["due"] for rid, info in drv.reqs.items()}
+        ttft = win.first_token_ms(drv.events, due, t0, t1)
+        gaps = win.token_gaps_ms(drv.events, t0, t1)
+        print(
+            f"sweep rate {rate:.2f}/s: in flight {len(drv.live)} queued "
+            f"{drv.srv.stats()['queued']} ttft p50 "
+            f"{win.percentile(ttft, 50):.0f} p95 "
+            f"{win.percentile(ttft, 95):.0f} itl p50 "
+            f"{win.percentile(gaps, 50):.1f} p95 "
+            f"{win.percentile(gaps, 95):.1f} tok/s "
+            f"{win.tokens_in_window(drv.events, t0, t1) / (t1 - t0):.1f} "
+            f"late p99 {win.percentile(m['late_ms'], 99):.1f}",
+            flush=True,
+        )
+    drv.srv.close()
+    return None
+
+
+def run_cell(cell: dict, config: dict, traffic: dict, opts) -> dict:
+    """Engine entry point: ``opts`` has seed, seconds, trace (bool),
+    trace_dir, rehearse (bool), clock0 and annotate."""
+    import jax
+
+    from adapt_tpu.runtime.continuous import ContinuousBatcher
+    from adapt_tpu.utils.metrics import global_metrics
+
+    phases = Phases(opts.clock0)
+    phases.mark("imports")
+    model = dict(config["model"])
+    # The deployment: the configuration's serving block, then what the
+    # traffic mix says of it (a mix is served by as many slots as its
+    # callers need), then the rehearsal's tiny sizes.
+    serving = {**config["serving"], **traffic.get("serving", {})}
+    if opts.rehearse:
+        model.update(config["rehearse"]["model"])
+        serving.update(config["rehearse"]["serving"])
+    max_total = min(model["n_positions"], serving["prompt_buckets"][-1])
+    pairs = tg.templates(traffic, max_total)
+    serving["pool_pages"] = pool_pages(serving, pairs, model["n_positions"])
+    compiles = CompileCounter()
+    lm, variables = build_model(model, config["dtype"], opts.seed)
+    phases.mark("weights")
+    itemsize = jax.numpy.dtype(config["dtype"]).itemsize
+    page_bytes = (
+        2 * model["n_layer"] * model["n_embd"] * serving["page_size"]
+        * itemsize
+    )
+    print(
+        f"deployment: slots {serving['slots']}  pool {serving['pool_pages']}"
+        f" pages x {page_bytes} B = {serving['pool_pages'] * page_bytes} B"
+        f"  weights "
+        f"{sum(x.nbytes for x in jax.tree.leaves(variables))} B",
+        flush=True,
+    )
+    srv = ContinuousBatcher(
+        lm, variables,
+        slots=serving["slots"], chunk=serving["chunk"],
+        kv_layout=serving["kv_layout"], page_size=serving["page_size"],
+        pool_pages=serving["pool_pages"],
+        prefill_chunk=serving["prefill_chunk"],
+        prompt_buckets=tuple(serving["prompt_buckets"]),
+    )
+    phases.mark("batcher")
+    drv = Driver(srv, model["vocab_size"], opts.seed, opts.annotate)
+    clients = traffic.get("clients")
+    if clients == "slots":
+        clients = serving["slots"]
+    closed = traffic["loop"] == "closed"
+    n_standing = clients if closed else traffic["standing"]["count"]
+    n_standing = min(n_standing, serving["slots"])
+    standing = tg.standing_population(pairs, n_standing)
+
+    ok, worst = correctness_sample(
+        drv, variables, serving, model["n_positions"], opts.fault
+    )
+    print(
+        f"correctness: served logprobs vs plain reference, max|err| "
+        f"{worst:.4f} (tolerance {LOGPROB_TOL}) -> {'ok' if ok else 'WRONG'}",
+        flush=True,
+    )
+    phases.mark("correctness")
+    n_shapes = warm_up(drv, pairs)
+    phases.mark(f"warm-up({n_shapes} shapes)")
+    setup_submitted = drv.submitted
+    setup_failed = drv.failed
+
+    if closed:
+        drv.stream = tg.template_stream(pairs, opts.seed)
+    t_admit = time.perf_counter()
+    st_rids = [
+        drv.submit(r, t_admit, client=(k if closed else None))
+        for k, r in enumerate(standing)
+    ]
+    st_rids = [r for r in st_rids if r is not None]
+    drv.run_until(lambda: all(drv.reqs[r]["emitted"] for r in st_rids))
+    phases.mark(f"standing({len(st_rids)})")
+    drv.sample_pool = opts.trace
+    compiles_before = compiles.count
+    snap = global_metrics().snapshot(window=True)
+    setup_s = time.perf_counter() - opts.clock0
+    say = _mute if opts.rehearse else print  # a CPU wall is no result
+    say(phases.line() + f"  | compiles {compiles.count} "
+        f"({compiles.seconds:.1f}s in backend compile or cache load)",
+        flush=True)
+    if opts.sweep:
+        return sweep(drv, traffic, pairs, opts)
+
+    m = measure(drv, traffic, pairs, opts.seconds, opts.seed,
+                opts.trace_dir if opts.trace else None)
+    hist = global_metrics().snapshot(since=snap, reservoirs=True)
+    compiled_in_window = compiles.count - compiles_before
+    stats = srv.stats()
+    srv.close()
+
+    t_open, t_close = m["t_open"], m["t_close"]
+    length = t_close - t_open
+    gaps = win.token_gaps_ms(drv.events, t_open, t_close)
+    due = {rid: info["due"] for rid, info in drv.reqs.items()}
+    ttft = win.first_token_ms(drv.events, due, t_open, t_close)
+    tokens = win.tokens_in_window(drv.events, t_open, t_close)
+    say(win.histogram_line("ttft_ms", ttft), flush=True)
+    say(win.histogram_line("itl_ms", gaps), flush=True)
+    say(win.histogram_line("generator_late_ms", m["late_ms"]), flush=True)
+
+    wrong = sum(
+        1 for info in drv.finished
+        if info.get("bad") or info["emitted"] != info["out_len"]
+    ) + sum(1 for info in drv.live.values() if info.get("bad"))
+    attempted = len(st_rids) + sum(
+        1 for info in drv.reqs.values()
+        if t_open < info["t_submit"] <= t_close
+    )
+    failed = (drv.failed - setup_failed) + wrong
+    if compiled_in_window:
+        print(f"INCORRECT: {compiled_in_window} program(s) compiled inside "
+              "the window", flush=True)
+    say(
+        f"window {length:.3f}s  ticks "
+        f"{sum(1 for t in drv.ticks if t_open < t[1] <= t_close)}  tokens "
+        f"{tokens}  first tokens {len(ttft)}  finished "
+        f"{sum(1 for f in drv.finished if t_open < f['t_done'] <= t_close)}"
+        f"  queued at close {stats['queued']}  set-up requests "
+        f"{setup_submitted} (failed {setup_failed})",
+        flush=True,
+    )
+    e2e = {
+        "ttft_p50_ms": win.percentile(ttft, 50),
+        "itl_p95_ms": win.percentile(gaps, 95),
+        "out_tok_per_s": tokens / length,
+        "setup_s": setup_s,
+    }
+    # Every candidate on an earlier line, judged in this cell or not:
+    # how a cell's metrics are chosen from its spread runs.
+    say("e2e " + "  ".join(
+        f"{k} {v:.4f}" for k, v in e2e.items() if v is not None
+    ), flush=True)
+    records = dict(
+        events=drv.events, ticks=drv.ticks, t_open=t_open, t_close=t_close,
+        gaps_ms=gaps, ttft_ms=ttft, late_ms=m["late_ms"], trace=m["trace"],
+        reqs=drv.reqs,
+        histograms=hist.get("histograms", {}), stats=stats,
+        pool_peak_pages=drv.pool_peak, model=model, serving=serving,
+        itemsize=itemsize,
+    )
+    return dict(
+        correct=bool(ok and not compiled_in_window and setup_failed == 0),
+        attempted=attempted, failed=failed, e2e=e2e, records=records,
+    )

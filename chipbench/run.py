@@ -1,0 +1,190 @@
+"""The benchmark's one command:
+
+    python3 chipbench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
+
+One process per run. The last line of standard output is the result
+object; where set-up went, histograms and lateness go on earlier
+lines. Without the chips the cell asks for it exits non-zero and
+prints no result. ``--rehearse`` walks cells at tiny widths on the CPU
+and prints no device number.
+"""
+
+from __future__ import annotations
+
+import time
+
+CLOCK0 = time.perf_counter()  # set-up is timed from process start
+
+import argparse  # noqa: E402
+import contextlib  # noqa: E402
+import json  # noqa: E402
+import os  # noqa: E402
+import shutil  # noqa: E402
+import sys  # noqa: E402
+from pathlib import Path  # noqa: E402
+
+ROOT = Path(__file__).resolve().parents[1]
+if str(ROOT) not in sys.path:
+    sys.path.insert(0, str(ROOT))
+
+from chipbench import lm_engine  # noqa: E402
+from chipbench import manifest as mf  # noqa: E402
+from chipbench import xtrace  # noqa: E402
+
+
+def _sanitize(name: str) -> str:
+    return "".join(c if c.isalnum() or c in "._-" else "_" for c in name)
+
+
+def _device_block(devices, chips: int) -> dict:
+    peak = 0
+    for d in devices[:chips]:
+        stats = d.memory_stats() or {}
+        peak = max(peak, int(stats.get("peak_bytes_in_use", 0)))
+    return {
+        "platform": devices[0].platform,
+        "kind": devices[0].device_kind,
+        "count": len(devices),
+        "memory_peak_bytes": peak,
+    }
+
+
+def run_one(manifest, name: str, args, root: Path = ROOT):
+    """Run one cell; returns the result object (None in rehearsal)."""
+    import jax
+
+    cell = mf.cell(manifest, name)
+    config = mf.config_of(manifest, cell, root)
+    traffic = mf.traffic_of(manifest, cell, root)
+    devices = jax.devices()
+    if args.rehearse:
+        if devices[0].platform != "cpu":
+            raise SystemExit("--rehearse needs JAX_PLATFORMS=cpu")
+    elif devices[0].platform != "tpu" or len(devices) < cell["chips"]:
+        raise SystemExit(
+            f"{name} needs {cell['chips']} TPU chip(s); JAX found "
+            f"{len(devices)} x {devices[0].platform} "
+            f"({devices[0].device_kind})"
+        )
+    else:
+        # Where JAX_COMPILATION_CACHE_DIR says, else the program's own
+        # fixed place inside the checkout (<checkout>/.jax_cache).
+        from adapt_tpu.utils.compile_cache import ensure_compile_cache
+
+        print(f"compile cache {ensure_compile_cache()}", flush=True)
+    if str(root) not in sys.path:
+        sys.path.insert(0, str(root))  # readers a later PR adds
+    trace_dir = str(root / ".chipbench_trace" / name)
+    if args.trace:
+        shutil.rmtree(trace_dir, ignore_errors=True)
+    opts = argparse.Namespace(
+        seed=args.seed, seconds=args.seconds, trace=bool(args.trace),
+        trace_dir=trace_dir, rehearse=args.rehearse, clock0=CLOCK0,
+        fault=args.fault,
+        sweep=[float(r) for r in args.sweep.split(",")] if args.sweep else None,
+        annotate=(
+            jax.profiler.TraceAnnotation if args.trace
+            else contextlib.nullcontext
+        ),
+    )
+    out = lm_engine.run_cell(cell, config, traffic, opts)
+    if out is None:  # a sweep prints its own lines and no result
+        return None
+    device = _device_block(devices, cell["chips"])
+    if not args.trace:
+        wanted = mf.metrics_of(manifest, name, "end_to_end")
+        values = out["e2e"]
+        trace = None
+    else:
+        path = xtrace.find_xplane(trace_dir)
+        trace = xtrace.load(path) if path else None
+        wanted = mf.metrics_of(manifest, name, "per_layer")
+        values = {
+            m["name"]: mf.reader_of(manifest, m["name"], root)(
+                trace, out["records"], device["kind"]
+            )
+            for m in wanted
+        }
+    metrics = {
+        m["name"]: {"value": values[m["name"]], "unit": m["unit"]}
+        for m in wanted if values.get(m["name"]) is not None
+    }
+    if args.rehearse:
+        print(
+            f"rehearsal {name}: correct={out['correct']} attempted="
+            f"{out['attempted']} failed={out['failed']} would report "
+            f"{sorted(metrics)} (no device was measured)", flush=True,
+        )
+        return None
+    result = {
+        "correct": out["correct"], "attempted": out["attempted"],
+        "failed": out["failed"], "metrics": metrics, "device": device,
+    }
+    if trace is not None and trace.devices:
+        tr = out["records"]["trace"]
+        busy = [xtrace.busy_seconds(d) for d in trace.devices]
+        device["busy_s"] = sum(busy) / len(busy)
+        device["window_s"] = tr["t1"] - tr["t0"]
+        dev = trace.devices[0]
+        result["breakdown"] = {
+            "device_ops": xtrace.top(
+                {_sanitize(k): v for k, v in xtrace.op_seconds(dev).items()}
+            ),
+            "idle_gaps": xtrace.top(
+                {_sanitize(k): v
+                 for k, v in xtrace.idle_gaps(dev, trace.host).items()}
+            ),
+        }
+    return result
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    ap.add_argument("--workload")
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--seconds", type=float, default=None)
+    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    ap.add_argument(
+        "--rehearse", action="store_true",
+        help="tiny widths on the CPU, every cell (or --workload); "
+        "prints no result and no device number",
+    )
+    ap.add_argument(
+        "--sweep", default="",
+        help="open-loop cells: comma-separated rates, one window each in "
+        "one process; prints a line per rate and no result",
+    )
+    ap.add_argument(
+        "--fault", default="", choices=("", "drop_block"),
+        help="self-test of `correct`: drop_block leaves one block out of "
+        "the plain reference, so a sound comparison must answer false",
+    )
+    ap.add_argument(
+        "--root", default=str(ROOT),
+        help="directory that holds BENCHMARK.json (tests point this at "
+        "a copy with files added)",
+    )
+    args = ap.parse_args(argv)
+    root = Path(args.root)
+    manifest = mf.load(root)
+    if args.seconds is None:
+        args.seconds = 3.0 if args.rehearse else manifest["run_seconds"]
+    if args.rehearse:
+        names = [args.workload] if args.workload else [
+            w["name"] for w in manifest["workloads"]
+        ]
+        for name in names:
+            for trace in (0, 1):
+                args.trace = trace
+                run_one(manifest, name, args, root)
+        return 0
+    if not args.workload:
+        ap.error("--workload is required")
+    result = run_one(manifest, args.workload, args, root)
+    if result is not None:
+        print(json.dumps(result), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -1,0 +1,169 @@
+"""Reduction of a JAX profiler trace (``*.xplane.pb``) to numbers:
+device busy time, time per operation name, and the idle gaps by what
+the host was doing in them. Read with ``jax.profiler.ProfileData`` and
+nothing else.
+
+A TPU's plane is ``/device:TPU:<n>``; its line ``XLA Ops`` holds one
+event per executed HLO operation (a Pallas kernel appears under its
+jitted name, e.g. ``_paged_impl``), ``XLA Modules`` one per executed
+program (``jit__step_chunk(...)``). Host threads are the lines of
+``/host:CPU``; ``jax.profiler.TraceAnnotation`` spans land there.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import glob
+import os
+import re
+from collections import defaultdict
+
+_OP = re.compile(r"^%?([A-Za-z_][\w\-]*?)(?:\.\d+)*(?: =|\(|$)")
+_MODULE = re.compile(r"^(?:jit_)?(.*?)(?:\(\d+\))?$")
+#: Gaps shorter than this are launch latency between back-to-back
+#: operations, not something the host did; they are summed apart.
+SHORT_GAP_NS = 20_000
+_WRAPPERS = frozenset({"while", "conditional", "call"})
+
+
+def op_name(event_name: str) -> str:
+    """``%copy-start.31 = (f32[...]) ...`` -> ``copy-start``."""
+    m = _OP.match(event_name)
+    return m.group(1) if m else event_name.split(" ", 1)[0]
+
+
+def module_name(event_name: str) -> str:
+    """``jit__step_chunk(1234)`` -> ``_step_chunk``."""
+    return _MODULE.match(event_name).group(1)
+
+
+@dataclasses.dataclass
+class DeviceTrace:
+    """One device's operations as (start_ns, end_ns, name) lists."""
+
+    ops: list
+    modules: list
+
+
+@dataclasses.dataclass
+class Trace:
+    devices: list  # DeviceTrace per device plane, in plane order
+    host: list  # (start_ns, end_ns, name) of every host-thread event
+
+
+def find_xplane(trace_dir: str) -> str | None:
+    found = sorted(
+        glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"), recursive=True)
+    )
+    return found[-1] if found else None
+
+
+def load(path: str) -> Trace:
+    from jax.profiler import ProfileData
+
+    data = ProfileData.from_file(path)
+    devices, host = [], []
+    for plane in data.planes:
+        if plane.name.startswith("/device:TPU:"):
+            ops, modules = [], []
+            for line in plane.lines:
+                if line.name == "XLA Ops":
+                    ops = [
+                        (e.start_ns, e.start_ns + e.duration_ns,
+                         op_name(e.name))
+                        for e in line.events
+                    ]
+                elif line.name == "XLA Modules":
+                    modules = [
+                        (e.start_ns, e.start_ns + e.duration_ns,
+                         module_name(e.name))
+                        for e in line.events
+                    ]
+            if ops or modules:
+                devices.append(DeviceTrace(ops, modules))
+        elif plane.name == "/host:CPU":
+            for line in plane.lines:
+                host.extend(
+                    (e.start_ns, e.start_ns + e.duration_ns, e.name)
+                    for e in line.events
+                )
+    return Trace(devices, host)
+
+
+def union(intervals) -> list[tuple[float, float]]:
+    """Merged, sorted, non-overlapping (start, end) intervals."""
+    out: list[list[float]] = []
+    for s, e in sorted((s, e) for s, e, *_ in intervals):
+        if out and s <= out[-1][1]:
+            out[-1][1] = max(out[-1][1], e)
+        else:
+            out.append([s, e])
+    return [(s, e) for s, e in out]
+
+
+def busy_seconds(dev: DeviceTrace) -> float:
+    """Seconds in which at least one operation ran on the device."""
+    return sum(e - s for s, e in union(dev.ops)) / 1e9
+
+
+def op_seconds(dev: DeviceTrace) -> dict[str, float]:
+    """Device seconds per operation name. Control-flow wrappers
+    (``while`` around a scan's body) span the operations they run and
+    do no work themselves, so they are left out."""
+    total: dict[str, float] = defaultdict(float)
+    for s, e, name in dev.ops:
+        if name not in _WRAPPERS:
+            total[name] += (e - s) / 1e9
+    return dict(total)
+
+
+def module_seconds(dev: DeviceTrace) -> dict[str, tuple[int, float]]:
+    """(runs, device seconds) per program name."""
+    out: dict[str, list] = defaultdict(lambda: [0, 0.0])
+    for s, e, name in dev.modules:
+        out[name][0] += 1
+        out[name][1] += (e - s) / 1e9
+    return {k: (n, t) for k, (n, t) in out.items()}
+
+
+def idle_gaps(dev: DeviceTrace, host, t0_ns=None, t1_ns=None):
+    """Idle seconds of the device by the host event that covers the
+    middle of each gap: the innermost (shortest) covering event, so a
+    ``chipbench.*`` annotation is named only where nothing more
+    specific (``PjitFunction_*``, a transfer) ran inside it. Gaps
+    under ``SHORT_GAP_NS`` go to ``gaps_under_20us``."""
+    busy = union(dev.ops)
+    if not busy:
+        return {}
+    t0 = busy[0][0] if t0_ns is None else t0_ns
+    t1 = busy[-1][1] if t1_ns is None else t1_ns
+    edges = [t0] + [x for s, e in busy for x in (s, e)] + [t1]
+    gaps = [
+        (edges[i], edges[i + 1])
+        for i in range(0, len(edges), 2)
+        if edges[i + 1] > edges[i]
+    ]
+    host = sorted(host)
+    out: dict[str, float] = defaultdict(float)
+    j = 0
+    for s, e in gaps:
+        if e - s < SHORT_GAP_NS:
+            out["gaps_under_20us"] += (e - s) / 1e9
+            continue
+        mid = (s + e) / 2
+        while j < len(host) and host[j][1] < s - 5e9:
+            j += 1  # events that ended long before cannot cover
+        best = None
+        for hs, he, name in host[j:]:
+            if hs > mid:
+                break
+            if he >= mid and (best is None or he - hs < best[0]):
+                best = (he - hs, name)
+        out[best[1] if best else "no_host_event"] += (e - s) / 1e9
+    return dict(out)
+
+
+def top(d: dict[str, float], n: int = 10) -> list[list]:
+    return [
+        [k, v] for k, v in sorted(d.items(), key=lambda kv: -kv[1])[:n]
+    ]
