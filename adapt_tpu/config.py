@@ -742,11 +742,13 @@ class ObservabilityConfig:
     # to the crash survives the process.
     snapshot_on_recovery: bool = True
     # Engine-tier per-phase timing (utils.profiling.EngineObs): tick
-    # phases (admit/prefill/draft/verify/decode/commit/update) and
-    # pipeline stage/hop phases record engine.phase.<name>_s histograms
-    # (+ spans when tracing is on). One branch per phase site when
-    # False; enabled cost measured by benchmarks/micro/obs_overhead.py
-    # against the <5% tick budget. Enable-only, like trace_enabled.
+    # phases (tick/admit/prefill/launch/draft/verify/decode/fetch/
+    # commit/update) and pipeline stage/hop phases record
+    # engine.phase.<name>_s histograms (+ spans when tracing is on).
+    # When False a phase site is one branch and its profiler annotation
+    # (always on: a jax.profiler session sees the phases either way);
+    # enabled cost measured by benchmarks/micro/obs_overhead.py against
+    # the <5% tick budget. Enable-only, like trace_enabled.
     obs_engine: bool = False
     # Compile-sentinel warmup (utils.profiling.CompileSentinel): jit
     # cache growth within a program's first N sentinel samples after

@@ -2147,17 +2147,16 @@ class ContinuousBatcher:
             return
         if self.prefix_cached(req.prompt) >= m:
             return  # hierarchy-resident: nothing to compute
-        eo = self._eobs
-        eo_on = eo.enabled
-        t_ph = eo.now() if eo_on else 0.0
         tracer = global_tracer()
         t0 = tracer.now() if tracer.enabled else 0.0
         try:
-            n, blocks = self._sp.prefill(req.prompt)
-            adopted = self.adopt_prefill_pages(
-                req.prompt, blocks, self._page,
-                self._kv_dtype if self._kv_quant else False,
-            )
+            # span=False: batcher.sp_prefill below is the tracer row.
+            with self._eobs.region("sp_prefill", span=False):
+                n, blocks = self._sp.prefill(req.prompt)
+                adopted = self.adopt_prefill_pages(
+                    req.prompt, blocks, self._page,
+                    self._kv_dtype if self._kv_quant else False,
+                )
         except Exception:  # noqa: BLE001 — degrade, never wedge
             log.exception(
                 "sp prefill failed for request %d; admission falls "
@@ -2197,9 +2196,6 @@ class ContinuousBatcher:
                 adopted=adopted,
                 sp=self._sp.sp,
             )
-        if eo_on:
-            # span=False: batcher.sp_prefill above is the tracer row.
-            eo.phase("sp_prefill", t_ph, span=False)
         global_flight_recorder().record(
             "sp_prefill",
             request=req.req_id,
@@ -4382,7 +4378,8 @@ class ContinuousBatcher:
                 if cow:
                     tok0, lp0 = fg.first, fg.first_lp
                 else:
-                    tok0, lp0 = int(first[0]), float(first_lp[0])
+                    with self._eobs.region("first_token"):
+                        tok0, lp0 = int(first[0]), float(first_lp[0])
                     if self._capacity is not None and cap_tokens:
                         # The int() above is the host sync, so this
                         # wall covers dispatch AND compute.
@@ -4550,60 +4547,71 @@ class ContinuousBatcher:
         pos0 = slot.pf_done  # page-aligned (chunks are page multiples)
         clen = min(self._prefill_chunk, s0 - pos0)
         final = pos0 + clen >= s0
-        cbucket = -(-clen // P) * P
-        n_strip = (pos0 + cbucket) // P
-        owned = self._pager.owned(slot.idx)
-        assert n_strip <= len(owned)
-        # Pad the window to a power-of-two page count so a long prompt
-        # compiles log2 variants instead of one per chunk ordinal (pad
-        # entries point at the trash page; their positions sit past the
-        # chunk's causal window, masked and compute-skipped).
-        n_pad = 1
-        while n_pad < n_strip:
-            n_pad *= 2
-        pages = owned[:n_strip] + [0] * (n_pad - n_strip)
-        ids = np.zeros((1, cbucket), np.int32)
-        ids[0, :clen] = req.prompt[pos0:pos0 + clen]
-        first, first_lp, self._caches = self._prefill_suffix_fn(
-            cbucket, n_pad, sample=final
-        )(
-            self.variables,
-            self._caches,
-            self._h2d(np.asarray(pages, np.int32)),
-            self._h2d(ids),
-            self._h2d(np.array([pos0, clen, req.top_k], np.int32)),
-            self._h2d(np.array(
-                [req.temperature, req.top_p], np.float32
-            )),
-            self._h2d(req.folded_keys[0][None]),
-            # Only the final pass samples; mid-prefill passes must not
-            # fork compile variants over sampling flags they never use.
-            truncate=final and req.top_k < self.lm.vocab,
-            nucleus=final and req.top_p < 1.0,
-        )
-        slot.pf_done = pos0 + clen
-        self._count_prefill(clen)
-        if tracer.enabled:
-            tracer.add_span(
-                "batcher.prefill_chunk",
-                start=t0,
-                end=tracer.now(),
-                request=req.req_id,
-                pos0=int(pos0),
-                chunk_len=int(clen),
-                final=final,
+        # span=False: batcher.prefill_chunk below is the tracer row.
+        with self._eobs.region(
+            "prefill_chunk",
+            span=False,
+            request=req.req_id,
+            pos0=int(pos0),
+            chunk_len=int(clen),
+            final=final,
+        ):
+            cbucket = -(-clen // P) * P
+            n_strip = (pos0 + cbucket) // P
+            owned = self._pager.owned(slot.idx)
+            assert n_strip <= len(owned)
+            # Pad the window to a power-of-two page count so a long prompt
+            # compiles log2 variants instead of one per chunk ordinal (pad
+            # entries point at the trash page; their positions sit past the
+            # chunk's causal window, masked and compute-skipped).
+            n_pad = 1
+            while n_pad < n_strip:
+                n_pad *= 2
+            pages = owned[:n_strip] + [0] * (n_pad - n_strip)
+            ids = np.zeros((1, cbucket), np.int32)
+            ids[0, :clen] = req.prompt[pos0:pos0 + clen]
+            first, first_lp, self._caches = self._prefill_suffix_fn(
+                cbucket, n_pad, sample=final
+            )(
+                self.variables,
+                self._caches,
+                self._h2d(np.asarray(pages, np.int32)),
+                self._h2d(ids),
+                self._h2d(np.array([pos0, clen, req.top_k], np.int32)),
+                self._h2d(np.array(
+                    [req.temperature, req.top_p], np.float32
+                )),
+                self._h2d(req.folded_keys[0][None]),
+                # Only the final pass samples; mid-prefill passes must not
+                # fork compile variants over sampling flags they never use.
+                truncate=final and req.top_k < self.lm.vocab,
+                nucleus=final and req.top_p < 1.0,
             )
-        if final:
-            for j in range(s0 // P):  # register() skips known keys
-                self._pager.register(
-                    owned[j], Pager.prefix_key(req.prompt, (j + 1) * P)
+            slot.pf_done = pos0 + clen
+            self._count_prefill(clen)
+            if tracer.enabled:
+                tracer.add_span(
+                    "batcher.prefill_chunk",
+                    start=t0,
+                    end=tracer.now(),
+                    request=req.req_id,
+                    pos0=int(pos0),
+                    chunk_len=int(clen),
+                    final=final,
                 )
-            slot.pf_done = -1
-            self._commit(slot, int(first[0]), float(first_lp[0]))
-            if slot.req is req:
-                if self._spec:
-                    self._admit_draft(slot.idx, req)
-                self._stage_decode_row(slot)
+            if final:
+                for j in range(s0 // P):  # register() skips known keys
+                    self._pager.register(
+                        owned[j], Pager.prefix_key(req.prompt, (j + 1) * P)
+                    )
+                slot.pf_done = -1
+                with self._eobs.region("first_token"):
+                    tok0, lp0 = int(first[0]), float(first_lp[0])
+                self._commit(slot, tok0, lp0)
+                if slot.req is req:
+                    if self._spec:
+                        self._admit_draft(slot.idx, req)
+                    self._stage_decode_row(slot)
 
     def _spec_decode(self, active, tracer):
         """Dispatch one SPECULATIVE decode round for the whole slot
@@ -4635,44 +4643,42 @@ class ContinuousBatcher:
             (d, sample, truncate, nucleus)
         )
         eo = self._eobs
-        # Snapshot the gate ONCE per call: flipping obs_engine while a
-        # tick is in flight must never pair a 0.0 open with an enabled
-        # close (a perf-counter-sized garbage histogram sample).
-        eo_on = eo.enabled
-        t_ph = eo.now() if eo_on else 0.0
         # Only the span tags consume the id tuple — don't build it on
         # the untraced hot path.
         req_ids = (
             tuple(s.req.req_id for s in active) if tracer.enabled else ()
         )
         t_draft = tracer.now() if tracer.enabled else 0.0
-        if w:
-            # Tree drafts: d chain steps + the argmax-leaf step + one
-            # leaf-coverage step (the leaf token's own draft-cache
-            # write), with the top-w leaf candidates harvested from
-            # logits the scan computes anyway (equal draft FLOPs per
-            # committed token). cands = the top-w ids of the step that
-            # predicts the post-chain position (scan index d).
-            dtoks, dtops, self._draft_caches = draft_chunk(
-                self._draft_lm,
-                self._draft_variables,
-                self._dstate["tok"],
-                self._dstate["pos"],
-                self._draft_caches,
-                n=d + 2,
-                tail_w=w,
-            )
-            cands = dtops[d]  # (B, w); cands[:, 0] == dtoks[d]
-        else:
-            cands = None
-            dtoks, self._draft_caches = draft_chunk(
-                self._draft_lm,
-                self._draft_variables,
-                self._dstate["tok"],
-                self._dstate["pos"],
-                self._draft_caches,
-                n=d + 1,
-            )
+        # span=False: decode.draft below is the tracer row.
+        with eo.region("draft", span=False):
+            if w:
+                # Tree drafts: d chain steps + the argmax-leaf step +
+                # one leaf-coverage step (the leaf token's own
+                # draft-cache write), with the top-w leaf candidates
+                # harvested from logits the scan computes anyway (equal
+                # draft FLOPs per committed token). cands = the top-w
+                # ids of the step that predicts the post-chain position
+                # (scan index d).
+                dtoks, dtops, self._draft_caches = draft_chunk(
+                    self._draft_lm,
+                    self._draft_variables,
+                    self._dstate["tok"],
+                    self._dstate["pos"],
+                    self._draft_caches,
+                    n=d + 2,
+                    tail_w=w,
+                )
+                cands = dtops[d]  # (B, w); cands[:, 0] == dtoks[d]
+            else:
+                cands = None
+                dtoks, self._draft_caches = draft_chunk(
+                    self._draft_lm,
+                    self._draft_variables,
+                    self._dstate["tok"],
+                    self._dstate["pos"],
+                    self._draft_caches,
+                    n=d + 1,
+                )
         if tracer.enabled:
             # Dispatch-side cost of the draft scan; the verify span
             # below carries the host sync. Tagged with the same request
@@ -4686,9 +4692,9 @@ class ContinuousBatcher:
                 draft_k=d,
                 requests=req_ids,
             )
-        if eo_on:
-            # span=False: decode.draft above is the tracer row.
-            t_ph = eo.phase("draft", t_ph, span=False)
+        # The verify stamp closes in the commit half (eo.phase): armed
+        # only if the gate is on NOW, and closed only if still armed.
+        t_ph = eo.now() if eo.enabled else 0.0
         t_verify = tracer.now() if tracer.enabled else 0.0
         toks, lps, acc, self._caches, self._dstate = self._spec_verify(
             self.variables,
@@ -4740,28 +4746,37 @@ class ContinuousBatcher:
         one-tick lag (drained at :meth:`drain` / :meth:`run` exit /
         :meth:`recover`).
 
-        Engine-tier phase timing (``utils.profiling.EngineObs``,
-        ``obs_engine``): admit / prefill / draft / verify / decode /
-        dispatch / commit_lag / commit / update each record one
-        ``engine.phase.<name>_s`` histogram sample per tick when
-        enabled; disabled, each site costs one branch. decode/verify
-        span dispatch→results-landed, so under the pipelined loop they
-        OVERLAP the other phases — that overlap is the win, gauged as
-        ``runtime.overlap_ratio``. The compile sentinel samples once
-        at the end of every commit half, so an unexpected recompile is
-        flagged next to the tick that paid for it."""
-        if self._depth <= 1:
+        Phases (``utils.profiling.EngineObs``): the call is one
+        ``engine.tick`` region holding ``engine.admit`` /
+        ``engine.prefill`` (one ``engine.prefill_chunk`` per pass) /
+        ``engine.launch`` (with ``engine.draft`` when speculating) /
+        ``engine.fetch`` / ``engine.commit`` / ``engine.update``, and
+        ``engine.first_token`` around each blocking first-token read.
+        Every region is a ``jax.profiler.TraceAnnotation`` always (a
+        profiler session puts them on the device trace's clock), and
+        with ``obs_engine`` on each also records one
+        ``engine.phase.<name>_s`` histogram sample; off, a site costs
+        the annotation and one branch. The cross-half stamps verify /
+        decode / dispatch / commit_lag are histogram-only:
+        decode/verify span dispatch→results-landed, so under the
+        pipelined loop they OVERLAP the other phases — that overlap is
+        the win, gauged as ``runtime.overlap_ratio``. The compile
+        sentinel samples once at the end of every commit half, so an
+        unexpected recompile is flagged next to the tick that paid for
+        it."""
+        with self._eobs.region("tick"):
+            if self._depth <= 1:
+                fl = self._tick_dispatch()
+                return self._tick_commit(fl) if fl is not None else 0
+            # Pipelined: dispatch t FIRST (its programs enqueue behind
+            # t-1's on the device stream), then commit t-1 on the host
+            # while t runs. _ensure_mesh inside the dispatch half drains
+            # the in-flight tick through recover() on a device loss.
             fl = self._tick_dispatch()
-            return self._tick_commit(fl) if fl is not None else 0
-        # Pipelined: dispatch t FIRST (its programs enqueue behind
-        # t-1's on the device stream), then commit t-1 on the host
-        # while t runs. _ensure_mesh inside the dispatch half drains
-        # the in-flight tick through recover() on a device loss.
-        fl = self._tick_dispatch()
-        prev, self._inflight = self._inflight, fl
-        if prev is not None:
-            return self._tick_commit(prev)
-        return 0
+            prev, self._inflight = self._inflight, fl
+            if prev is not None:
+                return self._tick_commit(prev)
+            return 0
 
     def drain(self) -> int:
         """Commit the in-flight tick, if any (pipelined runtime) —
@@ -4794,9 +4809,6 @@ class ContinuousBatcher:
             # evictions this tick find their content host-backed.
             self._tier_step()
         eo = self._eobs
-        # Snapshot the gate ONCE per tick (see _spec_decode).
-        eo_on = eo.enabled
-        t_ph = eo.now() if eo_on else 0.0
         # Prefill-stall accounting (continuous.prefill_stall_s): when
         # requests were already DECODING at tick entry, every second
         # this tick spends on in-tick prefill work (admission prefill
@@ -4810,9 +4822,8 @@ class ContinuousBatcher:
         )
         t_stall0 = time.perf_counter() if decode_waiting else 0.0
         pf_tokens0 = self._prefill_tokens
-        self._admit()
-        if eo_on:
-            t_ph = eo.phase("admit", t_ph)
+        with eo.region("admit"):
+            self._admit()
         for slot in self.slots:
             if slot.req is None:
                 continue
@@ -4821,16 +4832,15 @@ class ContinuousBatcher:
                 self._cancelled.discard(slot.req.req_id)
             if cancelled:  # mid-prefill or between chunks
                 self._finish(slot, reason="cancelled")
-        for slot in self.slots:
-            if slot.req is not None and slot.pf_done >= 0:
-                self._prefill_step(slot)  # interleaves with decode below
+        with eo.region("prefill"):
+            for slot in self.slots:
+                if slot.req is not None and slot.pf_done >= 0:
+                    self._prefill_step(slot)  # interleaves with decode
         if decode_waiting and self._prefill_tokens > pf_tokens0:
             global_metrics().observe(
                 "continuous.prefill_stall_s",
                 time.perf_counter() - t_stall0,
             )
-        if eo_on:
-            eo.phase("prefill", t_ph)
         active = [
             s for s in self.slots
             if s.req is not None and s.pf_done < 0
@@ -4881,44 +4891,52 @@ class ContinuousBatcher:
             self._sentinel.sample(write_gauges=False)
             return None
         tracer = global_tracer()
-        if self._spec is not None:
-            fl = self._spec_decode(active, tracer)
-        else:
-            t_ph = eo.now() if eo_on else 0.0
-            # The whole per-slot staging block the old path rebuilt and
-            # transferred here every tick (tokens/pos/keys/temps/top_ks/
-            # top_ps/greedy — O(slots x fields) jnp.asarray calls) is
-            # GONE: the state already lives on device (_dstate, staged
-            # once per admission), so a steady-state tick stages zero
-            # host scalars and the paged table re-uploads only when it
-            # changed.
-            truncate = any(s.req.top_k < self.lm.vocab for s in active)
-            nucleus = any(s.req.top_p < 1.0 for s in active)
-            self._variants.setdefault("continuous.step_chunk", set()).add(
-                (truncate, nucleus)
-            )
-            t_chunk = tracer.now() if tracer.enabled else 0.0
-            toks, lps, self._caches, self._dstate = self._step_chunk(
-                self.variables,
-                self._caches,
-                self._dstate,
-                self._current_table() if self._paged else None,
-                truncate=truncate,
-                nucleus=nucleus,
-                epoch=self._mesh_epoch,
-            )
-            with self._cv:
-                self._ticks += 1
-            global_metrics().inc("continuous.ticks")
-            # The chunk's ONE host fetch covers both arrays — started
-            # here (async), landed at commit.
-            fl = _InFlight(
-                fetch=_AsyncFetch((toks, lps)),
-                reqs=[],
-                lives=[],
-                t_span=t_chunk,
-                t_eo=t_ph,
-            )
+        # Snapshot the gate ONCE for the cross-half stamps armed below
+        # (decode's open, dispatch's close): flipping obs_engine
+        # mid-tick must never pair a 0.0 open with an enabled close (a
+        # perf-counter-sized garbage sample).
+        eo_on = eo.enabled
+        with eo.region("launch"):
+            if self._spec is not None:
+                fl = self._spec_decode(active, tracer)
+            else:
+                t_ph = eo.now() if eo_on else 0.0
+                # The whole per-slot staging block the old path rebuilt
+                # and transferred here every tick (tokens/pos/keys/
+                # temps/top_ks/top_ps/greedy — O(slots x fields)
+                # jnp.asarray calls) is GONE: the state already lives
+                # on device (_dstate, staged once per admission), so a
+                # steady-state tick stages zero host scalars and the
+                # paged table re-uploads only when it changed.
+                truncate = any(
+                    s.req.top_k < self.lm.vocab for s in active
+                )
+                nucleus = any(s.req.top_p < 1.0 for s in active)
+                self._variants.setdefault(
+                    "continuous.step_chunk", set()
+                ).add((truncate, nucleus))
+                t_chunk = tracer.now() if tracer.enabled else 0.0
+                toks, lps, self._caches, self._dstate = self._step_chunk(
+                    self.variables,
+                    self._caches,
+                    self._dstate,
+                    self._current_table() if self._paged else None,
+                    truncate=truncate,
+                    nucleus=nucleus,
+                    epoch=self._mesh_epoch,
+                )
+                with self._cv:
+                    self._ticks += 1
+                global_metrics().inc("continuous.ticks")
+                # The chunk's ONE host fetch covers both arrays —
+                # started here (async), landed at commit.
+                fl = _InFlight(
+                    fetch=_AsyncFetch((toks, lps)),
+                    reqs=[],
+                    lives=[],
+                    t_span=t_chunk,
+                    t_eo=t_ph,
+                )
         # Binding identity for every slot in the decode batch: commit
         # applies a slot's column only while it still holds the same
         # request object AND the same life (slot.tokens list identity —
@@ -4956,7 +4974,10 @@ class ContinuousBatcher:
             # tick's dispatch wall at depth 2 (the lag the stream
             # timing docs describe).
             eo.phase("commit_lag", fl.t_dispatched, span=False)
-        host = fl.fetch.commit()
+        # The blocked part of the result fetch (``fetch.wait_s``): what
+        # the device, or the transfer after it, made the host wait.
+        with eo.region("fetch"):
+            host = fl.fetch.commit()
         tracer = global_tracer()
         if fl.spec is None:
             toks, lps = host
@@ -5033,63 +5054,60 @@ class ContinuousBatcher:
                     "runtime.overlap_ratio",
                     max(0.0, 1.0 - fl.fetch.wait_s / wall),
                 )
-        t_ph = eo.now() if eo_on else 0.0
-        for i, slot in enumerate(self.slots):
-            req = fl.reqs[i]
-            if req is None:
-                continue
-            if (
-                slot.req is not req
-                or slot.tokens is not fl.lives[i]
-                or slot.pf_done >= 0
-            ):
-                # The binding moved since dispatch (retire + re-admit,
-                # preempt + replay — possible only under the one-tick
-                # lag): this column belongs to a dead life. Drop it.
-                continue
-            # limits[i] is the slot's committable token count this tick:
-            # the full chunk in lockstep mode, the accepted prefix + 1
-            # correction token in speculative mode (rows desynchronize).
-            for j in range(int(limits[i])):
-                self._commit(slot, int(toks[j, i]), float(lps[j, i]))
-                if slot.req is not req:  # finished (steps or EOS)
-                    break
-            if slot.req is req:
-                # pos invariant at tick entry: the next step consumes
-                # last_token (stream index emitted-1) at s0 + emitted - 1.
-                slot.pos = slot.s0 + slot.emitted - 1
-        if eo_on:
-            t_ph = eo.phase("commit", t_ph)
-        if self._paged and self._window is not None:
-            # Rolling-window recycling: pages wholly behind every future
-            # read ((o+1)*P <= pos - window + 1 — reads from here on
-            # mask positions < index - window + 1 and writes land at
-            # >= pos) go back to the pool MID-REQUEST, so pool pressure
-            # bounds by the window, not the sequence.
-            for slot in self.slots:
-                if slot.req is None or slot.pf_done >= 0:
+        with eo.region("commit"):
+            for i, slot in enumerate(self.slots):
+                req = fl.reqs[i]
+                if req is None:
                     continue
-                dead = max(
-                    0, slot.pos - self._window + 1
-                ) // self._page - self._pager.base(slot.idx)
-                if dead > 0:
-                    self._pager.release_prefix(slot.idx, dead)
-        # Flush the tick's timeline/SLO bookkeeping in O(1) registry
-        # lock acquisitions (not one per committed token): batched ITL
-        # samples, SLO attainment counters/gauges, goodput counters +
-        # windowed rate gauge.
-        if self.obs_timeline:
-            self._obs_flush()
-        # Post-commit occupancy: slots retired by this chunk are gone.
-        global_metrics().set_gauge(
-            "continuous.active_slots",
-            sum(1 for sl in self.slots if sl.req is not None),
-        )
-        if eo_on:
-            # "update" = post-commit bookkeeping: window recycling, the
-            # batched ITL flush, occupancy gauges.
-            eo.phase("update", t_ph)
-        self._sentinel.sample(write_gauges=False)
+                if (
+                    slot.req is not req
+                    or slot.tokens is not fl.lives[i]
+                    or slot.pf_done >= 0
+                ):
+                    # The binding moved since dispatch (retire + re-admit,
+                    # preempt + replay — possible only under the one-tick
+                    # lag): this column belongs to a dead life. Drop it.
+                    continue
+                # limits[i] is the slot's committable token count this tick:
+                # the full chunk in lockstep mode, the accepted prefix + 1
+                # correction token in speculative mode (rows desynchronize).
+                for j in range(int(limits[i])):
+                    self._commit(slot, int(toks[j, i]), float(lps[j, i]))
+                    if slot.req is not req:  # finished (steps or EOS)
+                        break
+                if slot.req is req:
+                    # pos invariant at tick entry: the next step consumes
+                    # last_token (stream index emitted-1) at s0 + emitted - 1.
+                    slot.pos = slot.s0 + slot.emitted - 1
+        # "update" = post-commit bookkeeping: window recycling, the
+        # batched ITL flush, occupancy gauges, the sentinel sample.
+        with eo.region("update"):
+            if self._paged and self._window is not None:
+                # Rolling-window recycling: pages wholly behind every future
+                # read ((o+1)*P <= pos - window + 1 — reads from here on
+                # mask positions < index - window + 1 and writes land at
+                # >= pos) go back to the pool MID-REQUEST, so pool pressure
+                # bounds by the window, not the sequence.
+                for slot in self.slots:
+                    if slot.req is None or slot.pf_done >= 0:
+                        continue
+                    dead = max(
+                        0, slot.pos - self._window + 1
+                    ) // self._page - self._pager.base(slot.idx)
+                    if dead > 0:
+                        self._pager.release_prefix(slot.idx, dead)
+            # Flush the tick's timeline/SLO bookkeeping in O(1) registry
+            # lock acquisitions (not one per committed token): batched ITL
+            # samples, SLO attainment counters/gauges, goodput counters +
+            # windowed rate gauge.
+            if self.obs_timeline:
+                self._obs_flush()
+            # Post-commit occupancy: slots retired by this chunk are gone.
+            global_metrics().set_gauge(
+                "continuous.active_slots",
+                sum(1 for sl in self.slots if sl.req is not None),
+            )
+            self._sentinel.sample(write_gauges=False)
         return fl.n_active
 
     def capacity_book(self) -> dict | None:

@@ -85,6 +85,8 @@ import time
 import weakref
 from collections.abc import Callable
 
+from jax.profiler import TraceAnnotation
+
 from adapt_tpu.utils.logging import get_logger, kv
 from adapt_tpu.utils.metrics import MetricsRegistry, global_metrics
 from adapt_tpu.utils.tracing import global_flight_recorder, global_tracer
@@ -687,11 +689,37 @@ def _roofline_gauges() -> dict[str, float]:
 # -- tick-phase timing ------------------------------------------------------
 
 
+class _Region:
+    """An enabled :meth:`EngineObs.region`: the profiler annotation plus
+    the :meth:`EngineObs.phase` record on exit."""
+
+    __slots__ = ("_eo", "_name", "_ann", "_span", "_attrs", "_t0")
+
+    def __init__(self, eo, name, ann, span, attrs):
+        self._eo, self._name, self._ann = eo, name, ann
+        self._span, self._attrs = span, attrs
+
+    def __enter__(self):
+        self._t0 = time.perf_counter()
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._ann.__exit__(*exc)
+        self._eo.phase(
+            self._name, self._t0, span=self._span, **self._attrs
+        )
+
+
 class EngineObs:
     """Process-global gate for per-phase engine timing.
 
-    ``enabled`` is the one branch every phase site pays when off (the
-    ``obs_timeline`` pattern). On, :meth:`phase` records one
+    A phase site is ``with eo.region(name):``. It always enters a
+    ``jax.profiler.TraceAnnotation("engine.<name>")`` — free without a
+    profiler session, and under one (``Tracer.device_trace``) the span
+    lands in ``/host:CPU`` on the clock of the device's ``XLA Ops``.
+    ``enabled`` is the one branch every site pays beyond that (the
+    ``obs_timeline`` pattern). On, the region also records one
     ``engine.phase.<name>_s`` histogram sample (one registry-lock hold)
     and, when the global tracer is enabled, an ``engine.<name>`` span —
     so tick phases land in the same Perfetto timeline as the request
@@ -713,12 +741,32 @@ class EngineObs:
     def now() -> float:
         return time.perf_counter()
 
+    def region(self, name: str, *, span: bool = True, **attrs):
+        """Context manager around phase ``name`` on one thread: the
+        profiler annotation always, and when ``enabled`` (read once, at
+        entry, so a mid-region toggle cannot pair a missing open with a
+        close) what :meth:`phase` records. ``attrs`` become the
+        annotation's event stats and the ring span's args.
+        ``span=False`` for sites that already record their own tracer
+        span (``batcher.prefill_chunk``, ``decode.draft``)."""
+        ann = TraceAnnotation("engine." + name, **attrs)
+        if not self.enabled:
+            return ann
+        return _Region(self, name, ann, span, attrs)
+
     def phase(
         self, name: str, t0: float, *, span: bool = True, **attrs
     ) -> float:
         """Close phase ``name`` opened at ``t0``; returns the close time
-        (the next phase's open). ``span=False`` for sites that already
-        record their own tracer span (``LocalPipeline``'s stage/hop)."""
+        (the next phase's open). For the tick's cross-half stamps
+        (``decode`` / ``verify`` / ``dispatch`` / ``commit_lag``): they
+        open in the dispatch half and close in the commit half, one
+        ``tick()`` call later at ``pipeline_depth=2``, so they overlap
+        the other phases, cannot nest and cannot be profiler
+        annotations. Every same-thread site of the tick uses
+        :meth:`region`; ``LocalPipeline``'s stage/hop stay on this form
+        until the stage tier has a cell that reads them. ``span=False``
+        for sites that already record their own tracer span."""
         t1 = time.perf_counter()
         self.last_s[name] = t1 - t0
         global_metrics().observe(f"engine.phase.{name}_s", t1 - t0)

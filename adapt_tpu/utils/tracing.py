@@ -33,9 +33,11 @@ import — the switch a remote worker process (``python -m
 adapt_tpu.comm.remote``) is enabled with, since no dispatcher-side
 config reaches its constructor.
 
-An optional bridge to ``jax.profiler`` (:meth:`Tracer.device_trace`)
-covers XLA-level profiling on TPU; this module's spans are the
-host/serving-path complement.
+:meth:`Tracer.device_trace` opens a ``jax.profiler`` session: the
+device's operations, and on the same clock the tick's phases, which
+``utils.profiling.EngineObs.region`` writes as profiler annotations.
+This module's ring spans are the request-tier complement (cross-thread
+and cross-process intervals a nested annotation cannot express).
 """
 
 from __future__ import annotations
@@ -276,10 +278,19 @@ class Tracer:
 
     @contextlib.contextmanager
     def device_trace(self, logdir: str):
-        """XLA-level profiling (TensorBoard-viewable) around a region."""
+        """A ``jax.profiler`` session around a region: the device's
+        operations and, in ``/host:CPU`` on the same clock, every
+        ``EngineObs.region`` span (``engine.tick`` and the phases inside
+        it) — whether or not this tracer or ``obs_engine`` is enabled.
+        Python-call tracing is off: it would outweigh the host work the
+        spans are there to show. Writes ``<logdir>/plugins/profile/
+        <time>/*.xplane.pb`` (TensorBoard / Perfetto /
+        ``chipbench/xtrace.py``)."""
         import jax
 
-        jax.profiler.start_trace(logdir)
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(logdir, profiler_options=opts)
         try:
             yield
         finally:

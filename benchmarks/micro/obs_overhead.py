@@ -2,23 +2,26 @@
 
 The instrumentation contract (ISSUE 2, extended by the engine tier):
 request timelines, engine phase timing and tracing must be cheap enough
-to leave on. Disabled, the only residue is one branch per site
-(``obs_timeline`` False + ``obs_engine`` off + tracer off == pre-PR
-tick); enabled, the budget is < 5% added tick wall time on CPU.
+to leave on. Disabled, the only residue is one branch per site and,
+at the tick's phase sites (``EngineObs.region``), one
+``jax.profiler.TraceAnnotation`` (~0.4 us with no profiler session,
+about ten a tick: always on, so part of the floor and not of the
+overhead measured here); enabled, the budget is < 5% added tick wall
+time on CPU.
 
 Four configurations over the SAME ContinuousBatcher steady state
 (all slots decoding, no admissions, chunked ticks):
 
 - ``off``     — ``obs_timeline=False``, engine obs off, tracer disabled
-  (the floor; the always-on compile-sentinel sample per tick is part of
-  this floor by design).
+  (the floor; the always-on compile-sentinel sample per tick and the
+  phase sites' profiler annotations are part of this floor by design).
 - ``timeline``— default serving config: TTFT/ITL/queue-wait histograms
   + flight-recorder lifecycle events (engine + tracer still off).
   Every request carries an ``SLOSpec``, so this config ALSO pays the
   per-commit SLO evaluation + the per-tick goodput/attainment flush —
   the budget below covers SLO tracking, not just the bare histograms.
 - ``engine``  — timeline + ``obs_engine`` per-phase histograms
-  (``engine.phase.{admit,prefill,decode,commit,update}_s``).
+  (``engine.phase.{tick,admit,prefill,launch,decode,fetch,commit,update}_s``).
 - ``trace``   — engine + the span ring (prefill/decode-chunk spans).
 - ``federation`` — trace + the telemetry-federation REPORT PATH
   (``utils/telemetry``): a ``TelemetryReporter.collect()`` (windowed
