@@ -40,6 +40,7 @@ from adapt_tpu.ops.decode_attention import (
     verify_attention,
 )
 from adapt_tpu.ops.paged_attention import (
+    append_kv_paged,
     paged_attention,
     paged_chunk_attention,
     paged_verify_attention,
@@ -414,11 +415,8 @@ class CausalSelfAttention(nn.Module):
         phys = jnp.where(live_row, phys, 0)
         off = safe % page
 
-        # Advanced-index scatter: rows (phys[i], :, off[i], :) <- token i.
-        def write(pool, t):
-            return pool.at[phys, :, off, :].set(
-                t[:, :, 0, :].astype(pool.dtype)
-            )
+        def write(pool, t):  # rows (phys[i], :, off[i], :) <- token i
+            return append_kv_paged(pool, t, phys[:, None], off[:, None])
 
         k_pool, v_pool = self._write_kv_pair(k_pool, v_pool, k, v, write)
         o = paged_attention(
@@ -622,7 +620,7 @@ class CausalSelfAttention(nn.Module):
     ):
         """Batched verify over a PAGED cache: scatter each slot's K
         chunk tokens into its own pages at ``index[b]..index[b]+K-1``
-        (table-mapped, one advanced-index scatter), then attend each
+        (table-mapped, ``append_kv_paged``), then attend each
         row's paged window up to its own diagonal
         (:func:`paged_verify_attention`) — ``verify_chunk``'s exact
         semantics over ``decode_step_paged``'s layout. ``index`` (b,);
@@ -649,13 +647,11 @@ class CausalSelfAttention(nn.Module):
         phys = jnp.take_along_axis(page_table, pos // page, axis=1)
         phys = jnp.where(live_row[:, None], phys, 0)  # dead -> trash page
         off = pos % page
-        # Advanced-index scatter: (phys[b,t], :, off[b,t], :) <- token t
-        # of slot b. Dead rows' K writes pile unordered onto the trash
-        # page — never read (their masks are empty).
+        # (phys[b,t], :, off[b,t], :) <- token t of slot b. Dead rows'
+        # K writes pile onto the trash page — never read (their masks
+        # are empty).
         def write(pool, t):
-            return pool.at[phys, :, off, :].set(
-                jnp.swapaxes(t, 1, 2).astype(pool.dtype)
-            )
+            return append_kv_paged(pool, t, phys, off)
 
         k_pool, v_pool = self._write_kv_pair(k_pool, v_pool, k, v, write)
         o = paged_verify_attention(

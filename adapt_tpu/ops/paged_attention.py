@@ -76,6 +76,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
@@ -103,6 +104,59 @@ def pool_values(pool):
     the one place shape/head/page derivation looks, so every entry
     point sees through the tuple identically."""
     return pool[0] if isinstance(pool, tuple) else pool
+
+
+def append_kv_paged(pool, new, phys, off):
+    """Paged twin of ``decode_attention.append_kv`` — THE per-token
+    write into a page pool, shared by ``decode_step_paged`` (K == 1) and
+    ``verify_chunk_paged`` (K chunk tokens per row).
+
+    pool (num_pages, kv_h, P, w) — a native pool or ONE member of a
+    quantized pair (the scale plane has w == 1); new (b, kv_h, K, w);
+    ``phys``/``off`` (b, K) int32: token (i, t) lands on
+    ``pool[phys[i, t], :, off[i, t], :]``. Dead rows arrive routed to
+    the trash page by the caller; rows that collide there overwrite
+    each other, unread.
+
+    The write must reach the pool in the layout the buffer already has:
+    the Mosaic kernels pin row-major operands, and an XLA scatter whose
+    window spans the head axis (``pool.at[phys, :, off, :]``) wants its
+    window dimensions minor, so the compiler relaid the whole pool out
+    around it — two to three copies of every plane at every decode step
+    on a v5e. Which write is in place depends on where the lanes are,
+    so it is chosen from the row width (measured on the chip, PERF.md
+    section 6, PR 25):
+
+    - ``w`` fills whole 128-lane tiles: the resident layout is already
+      row-major, and ONE scatter indexed over (page, head, offset) with
+      only ``w`` as its window updates it in place.
+    - ``w`` narrower than a lane tile (head_dim 64, scale planes): the
+      buffer lives with the page axis on the lanes, and that scatter
+      costs two relayouts. A ``dynamic_update_slice`` of one
+      (1, kv_h, 1, w) slab per token under a ``fori_loop`` is in place
+      in ANY layout; one relayout to the kernel's stays (ROADMAP). The
+      slab is sliced straight out of ``new`` — a transposed or reshaped
+      update operand drags the carry's layout with it and the copies
+      come back.
+
+    ``tests/test_chip_lowering.py`` counts the copies both leave in a
+    program compiled for a v5e."""
+    b, kvh, kc, w = new.shape
+    new = new.astype(pool.dtype)
+    if w % 128 == 0:
+        return pool.at[
+            phys[:, None, :], jnp.arange(kvh)[None, :, None],
+            off[:, None, :], :,
+        ].set(new)
+
+    def write(n, pool):
+        i, t = n // kc, n % kc
+        slab = lax.dynamic_slice(new, (i, 0, t, 0), (1, kvh, 1, w))
+        return lax.dynamic_update_slice(
+            pool, slab, (phys[i, t], 0, off[i, t], 0)
+        )
+
+    return lax.fori_loop(0, b * kc, write, pool)
 
 
 def _split_pools(k_pool, v_pool):

@@ -290,6 +290,7 @@ from adapt_tpu.models.transformer_lm import (
     validate_tp,
 )
 from adapt_tpu.ops.decode_attention import check_head_parity
+from adapt_tpu.ops.paged_attention import append_kv_paged
 from adapt_tpu.ops.quantize import dequantize_params, quantize_params
 from adapt_tpu.parallel.sharding import (
     fetch_head_shards,
@@ -1883,7 +1884,10 @@ class ContinuousBatcher:
 
                 def fix(pool):
                     vec = pool[phys_src, :, off_src, :]  # (B, kvh, wd)
-                    return pool.at[phys_dst, :, off_dst, :].set(vec)
+                    return append_kv_paged(
+                        pool, vec[:, :, None, :], phys_dst[:, None],
+                        off_dst[:, None],
+                    )
 
             else:
 

@@ -1,0 +1,139 @@
+"""Count whole-pool copies in a benchmark cell's compiled decode program.
+
+    chiprun -- python3 scripts/step_chunk_copies.py <cell>      # on the chip
+    JAX_PLATFORMS=cpu python3 scripts/step_chunk_copies.py <cell> --describe
+
+Builds the cell's ``ContinuousBatcher`` as ``chipbench/lm_engine.py``
+does (same model, slots and pool pages), lowers the batcher's own
+``_step_chunk`` on ``ShapeDtypeStruct``s, compiles it, and counts the
+``copy`` / ``copy-start`` operations of the pool's shape in
+``compiled.as_text()`` by result layout. On the chip it compiles for
+the attached device; ``--describe`` compiles for a described v5e from
+the CPU (nothing runs; weights and pools are still allocated on the
+host). PERF.md section 5 quotes these counts; ``tests/
+test_chip_lowering.py`` guards a two-block version of them in tier 1.
+"""
+
+from __future__ import annotations
+
+import argparse
+import os
+import re
+import sys
+from pathlib import Path
+
+ROOT = Path(__file__).resolve().parents[1]
+if str(ROOT) not in sys.path:
+    sys.path.insert(0, str(ROOT))
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    ap.add_argument("cell", help="a workload name of BENCHMARK.json")
+    ap.add_argument(
+        "--describe", action="store_true",
+        help="compile for a described v5e:2x2 chip from the CPU",
+    )
+    ap.add_argument("--out", help="also write the compiled text here")
+    args = ap.parse_args(argv)
+
+    import jax
+    import jax.numpy as jnp
+
+    from adapt_tpu.runtime.continuous import ContinuousBatcher
+    from chipbench import lm_engine
+    from chipbench import manifest as mf
+    from chipbench import traffic as tg
+
+    manifest = mf.load(ROOT)
+    cell = mf.cell(manifest, args.cell)
+    config = mf.config_of(manifest, cell, ROOT)
+    traffic = mf.traffic_of(manifest, cell, ROOT)
+    model = dict(config["model"])
+    serving = {**config["serving"], **traffic.get("serving", {})}
+    pairs = tg.templates(
+        traffic, min(model["n_positions"], serving["prompt_buckets"][-1])
+    )
+    serving["pool_pages"] = lm_engine.pool_pages(
+        serving, pairs, model["n_positions"]
+    )
+    sharding = None
+    if args.describe:
+        from jax.experimental import topologies
+        from jax.sharding import SingleDeviceSharding
+
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+        sharding = SingleDeviceSharding(topo.devices[0])
+    elif jax.devices()[0].platform != "tpu":
+        raise SystemExit("no TPU here: pass --describe to compile for one")
+    else:
+        from adapt_tpu.utils.compile_cache import ensure_compile_cache
+
+        ensure_compile_cache()
+    lm, variables = lm_engine.build_model(model, config["dtype"], 1)
+    srv = ContinuousBatcher(
+        lm, variables,
+        slots=serving["slots"], chunk=serving["chunk"],
+        kv_layout=serving["kv_layout"], page_size=serving["page_size"],
+        pool_pages=serving["pool_pages"],
+        prefill_chunk=serving["prefill_chunk"],
+        prompt_buckets=tuple(serving["prompt_buckets"]),
+    )
+    if args.describe:
+        # The dispatchers ask the backend whether to compile or to
+        # interpret a kernel; the program below is for the chip.
+        jax.clear_caches()
+        jax.default_backend = lambda: "tpu"
+
+    def abstract(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
+
+    a_vars, a_caches, a_dstate = jax.tree.map(
+        abstract, (srv.variables, srv._caches, srv._dstate)
+    )
+    a_table = abstract(
+        jax.ShapeDtypeStruct(
+            (len(srv.slots), srv._pager.pages_per_slot), jnp.int32
+        )
+    )
+    planes = jax.tree.leaves(srv._caches)
+    compiled = type(srv)._step_chunk.lower(
+        srv, a_vars, a_caches, a_dstate, a_table,
+        truncate=False, nucleus=False, epoch=srv._mesh_epoch,
+    ).compile()
+    text = compiled.as_text()
+    srv.close()
+    dims = re.escape(",".join(map(str, planes[0].shape)))
+    pat = re.compile(
+        r"= \(?\w+\[" + dims + r"\](\{[^}]*\})?.*? (copy|copy-start)\("
+    )
+    kinds: dict[str, int] = {}
+    for line in text.splitlines():
+        if m := pat.search(line):
+            key = f"{m.group(2)} -> {m.group(1) or ''}"
+            kinds[key] = kinds.get(key, 0) + 1
+    total = sum(kinds.values())
+    where = (
+        "described v5e, no chip" if args.describe
+        else jax.devices()[0].device_kind
+    )
+    print(
+        f"STEP_COPIES {args.cell}: pool {planes[0].shape} x {len(planes)} "
+        f"planes, slots {len(srv.slots)}, chunk {srv.chunk}: pool-shaped "
+        f"copy/copy-start ops {total} ({total / len(planes):.2f} a plane); "
+        f"Mosaic calls {text.count('tpu_custom_call')}; temporaries "
+        f"{compiled.memory_analysis().temp_size_in_bytes} B ({where})",
+        flush=True,
+    )
+    for key, n in sorted(kinds.items()):
+        print(f"    {n:4d} x {key}", flush=True)
+    if args.out:
+        Path(args.out).write_text(text)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

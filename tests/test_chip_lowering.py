@@ -10,17 +10,30 @@ compiled kernel, and the program is cross-lowered with
 ``lowering_platforms=("tpu",)`` from the CPU: the Pallas-to-Mosaic
 lowering and the partitioning check run without a chip. What Mosaic's
 own compiler accepts is ``chip_smoke.py``'s job.
+
+The last section goes one step further and COMPILES, for a v5e that is
+described and not attached, the decode step's write into the paged
+pool, and counts the whole-pool copies the compiler scheduled around
+it.
 """
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax import lax
+from jax.sharding import (
+    Mesh,
+    NamedSharding,
+    PartitionSpec as P,
+    SingleDeviceSharding,
+)
 
+from adapt_tpu.models.transformer_lm import DecoderBlock
 from adapt_tpu.ops.attention import flash_attention, flash_attention_with_lse
 from adapt_tpu.ops.decode_attention import decode_attention
 from adapt_tpu.ops.dispatch import kernel_dispatch_stats
@@ -293,6 +306,171 @@ def test_head_sharded_kernels_match_oracles(devices):
         )(qc, kp, vp, pages, jnp.int32(page)),
         paged_chunk_attention_reference(qc, kp, vp, pages, page, page),
     )
+
+
+# -- the pool write compiles with no relayout of the pool ----------------------
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """One chip of a described v5e (the TPU compiler is installed here;
+    no chip is attached). Skips where it cannot be described."""
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 — no libtpu, or it is taken
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent
+    cache but cannot be read back without a chip (the next one warns):
+    keep these compiles out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _pool_copies(text, shape):
+    """``(relayouts, moves)`` of pool-shaped buffers in a compiled
+    program's text: a ``copy`` (or a ``copy-start`` whose two layouts
+    differ) rewrites the whole pool plane into another physical layout;
+    a ``copy-start`` between equal layouts is the compiler staging a
+    plane through fast memory (``S(1)``), which it does to planes small
+    enough to fit."""
+    dims = re.escape(",".join(map(str, shape)))
+    buf = r"\w+\[" + dims + r"\](\{[^}]*\})"
+    sync = re.compile(r"= " + buf + r" copy\(")
+    start = re.compile(r"= \(" + buf + ", " + buf + r".*\) copy-start\(")
+
+    def tiles(layout):
+        return re.sub(r"S\(\d+\)", "", layout)
+
+    relayouts = moves = 0
+    for line in text.splitlines():
+        if sync.search(line):
+            relayouts += 1
+        elif m := start.search(line):
+            if tiles(m.group(1)) == tiles(m.group(2)):
+                moves += 1
+            else:
+                relayouts += 1
+    return relayouts, moves
+
+
+_LAYERS = 2
+
+#: (pool shape, model dim, heads, mlp, slots, pages per slot): the
+#: benchmark's two deployments at their published widths.
+_CGPT = ((169, 16, 128, 128), 2048, 16, 8192, 24, 7)  # cgpt1b3_batchgen
+_XL = ((57, 25, 128, 64), 1600, 25, 6400, 8, 7)  # gpt2xl_doc
+
+
+@pytest.mark.parametrize("deploy,form,relayouts_per_plane", [
+    # head_dim 128: the pool's resident layout IS the kernel's, and
+    # ``append_kv_paged`` takes its head-indexed scatter.
+    (_CGPT, "step", 0),
+    (_CGPT, "scan8", 0),
+    (_CGPT, "verify", 0),
+    # head_dim 64 lives with the 128-wide page axis on the lanes and
+    # takes the row loop; one relayout per plane to the kernel's
+    # row-major stays (ROADMAP).
+    (_XL, "step", 1),
+], ids=["hd128-step", "hd128-scan8", "hd128-verify", "hd64-step"])
+def test_pool_write_compiles_without_pool_relayout(
+    as_tpu, one_chip, no_persistent_cache, deploy, form, relayouts_per_plane
+):
+    """Two real ``DecoderBlock``s, pools donated, compiled for the
+    described chip: the per-token write plus the Mosaic call schedule
+    no whole-pool relayout (the advanced-index scatter this replaced
+    cost 2 per plane at head_dim 128, 3 inside a scan's body + entry +
+    exit, and 3 at head_dim 64). Layout assignment is a heuristic —
+    how the update operand is produced decides the pool's layout — so
+    this count is the guard."""
+    shape, dim, heads, mlp, slots, pps = deploy
+    block = DecoderBlock(dim, heads, mlp, dtype=jnp.bfloat16)
+    kc = 4 if form == "verify" else 1
+
+    def on_chip(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda a: on_chip(a.shape, a.dtype),
+        jax.eval_shape(
+            lambda: block.init(
+                jax.random.PRNGKey(0), jnp.zeros((1, 1, dim), jnp.bfloat16)
+            )
+        ),
+    )
+
+    def step(params, x, pools, table, index):
+        out = []
+        for kp, vp in pools:
+            if form == "verify":
+                x, kp, vp = block.apply(
+                    params, x, kp, vp, table, index, "pallas",
+                    method="verify_chunk_paged",
+                )
+            else:
+                x, kp, vp = block.apply(
+                    params, x, kp, vp, table, index, None, "pallas",
+                    method="decode_step_paged",
+                )
+            out.append((kp, vp))
+        return x, out
+
+    def program(params, x, pools, table, index):
+        if form != "scan8":
+            return step(params, x, pools, table, index)
+
+        def body(carry, _):
+            x, pools, index = carry
+            x, pools = step(params, x, pools, table, index)
+            return (x, pools, index + 1), None
+
+        (x, pools, _), _ = lax.scan(
+            body, (x, pools, index), None, length=8
+        )
+        return x, pools
+
+    compiled = jax.jit(program, donate_argnums=(2,)).lower(
+        params, on_chip((slots, kc, dim)),
+        [(on_chip(shape), on_chip(shape)) for _ in range(_LAYERS)],
+        on_chip((slots, pps), jnp.int32), on_chip((slots,), jnp.int32),
+    ).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= _LAYERS
+    relayouts, moves = _pool_copies(text, shape)
+    planes = 2 * _LAYERS
+    assert relayouts <= relayouts_per_plane * planes, (relayouts, moves)
+    if relayouts_per_plane == 0:
+        assert moves == 0, moves
+
+
+def test_pool_copies_counts_what_the_scatter_cost():
+    """The counter itself, on lines of the shapes the compiler prints
+    (the parent's program at head_dim 64 held all three kinds)."""
+    text = """
+  %copy.91 = bf16[57,25,128,64]{3,1,2,0:T(8,128)(2,1)S(1)} copy(%copy-done.1), sharding={replicated}
+  %copy.112 = bf16[57,25,128,64]{2,3,1,0:T(8,128)(2,1)} copy(%fusion.1)
+  %copy-start.1 = (bf16[57,25,128,64]{2,3,1,0:T(8,128)(2,1)S(1)}, bf16[57,25,128,64]{2,3,1,0:T(8,128)(2,1)}, u32[]{:S(2)}) copy-start(%pools_0__0_.1)
+  %copy-start.2 = (bf16[57,25,128,64]{3,2,1,0:T(8,128)(2,1)}, bf16[57,25,128,64]{2,3,1,0:T(8,128)(2,1)}, u32[]{:S(2)}) copy-start(%p)
+  %copy.3 = bf16[8,25,1,64]{3,0,1,2:T(8,128)(2,1)} copy(%x)
+  %dus = bf16[57,25,128,64]{2,3,1,0:T(8,128)(2,1)} dynamic-update-slice(%a, %copy.3, %i, %z, %j, %z)
+"""
+    assert _pool_copies(text, (57, 25, 128, 64)) == (3, 1)
+    assert _pool_copies(text, (8, 25, 1, 64)) == (1, 0)
 
 
 # -- compile cache placement --------------------------------------------------

@@ -13,11 +13,17 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from adapt_tpu.models.transformer_lm import generate, lm_tiny
+from adapt_tpu.models.transformer_lm import (
+    CausalSelfAttention,
+    generate,
+    lm_tiny,
+)
 from adapt_tpu.ops.paged_attention import (
+    append_kv_paged,
     paged_attention,
     paged_attention_reference,
 )
+from adapt_tpu.ops.quantize import quantize_kv_vectors
 from adapt_tpu.runtime.continuous import ContinuousBatcher
 from adapt_tpu.runtime.paged import Pager, insert_prefill_pages
 
@@ -174,8 +180,6 @@ def test_paged_verify_kernel_matches_oracle(rng):
 def _quantized_pool(key, npages, kvh, page, hd):
     """Random native pool quantized with THE shared per-vector scheme —
     (int8 values, f32 scales (npages, kvh, page, 1)) pair."""
-    from adapt_tpu.ops.quantize import quantize_kv_vectors
-
     return quantize_kv_vectors(
         jax.random.normal(key, (npages, kvh, page, hd))
     )
@@ -281,6 +285,100 @@ def test_insert_prefill_pages_roundtrip(rng):
     np.testing.assert_allclose(got[:, :40], np.asarray(kv)[0], rtol=1e-6)
     assert (got[:, 40:] == 0).all()
     assert (np.asarray(pool)[[0, 1, 3, 5, 6, 8, 9]] == 0).all()
+
+
+# -- the per-token pool write --------------------------------------------------
+
+
+def _scatter_oracle(pool, new, phys, off):
+    """The advanced-index scatter ``append_kv_paged`` replaced, kept
+    here as its oracle: ``pool[phys[i,t], :, off[i,t], :] <- new[i, :,
+    t, :]``."""
+    return pool.at[phys, :, off, :].set(
+        jnp.swapaxes(new, 1, 2).astype(pool.dtype)
+    )
+
+
+@pytest.mark.parametrize("mode", ["decode", "verify"])
+@pytest.mark.parametrize("kv_dtype", ["native", "int8", "int4"])
+@pytest.mark.parametrize("hd", [16, 128])
+def test_paged_write_equals_scatter_oracle(rng, mode, kv_dtype, hd):
+    """``decode_step_paged`` / ``verify_chunk_paged`` leave the pools
+    bit-equal to the old scatter's: distinct rows, a dead row
+    (``idx < 0``) whose table maps real pages landing on the trash
+    page, two rows writing one physical page, a verify chunk crossing
+    a page edge — native pools and int8 / int4-packed (values, scales)
+    pairs, at a row width under a lane tile (the row loop) and at a
+    whole one (the head-indexed scatter)."""
+    heads, kvh, page, npages = 4, 2, 8, 9
+    dim = heads * hd
+    kc = 1 if mode == "decode" else 5
+    attn = CausalSelfAttention(dim, heads, kv_heads=kvh)
+    kx, kp = jax.random.split(rng)
+    x = jax.random.normal(kx, (4, kc, dim))
+    params = attn.init(kp, x)
+    # Row 0 and row 3 write the SAME physical page 5 (offsets apart);
+    # row 1's chunk crosses from page 2 into page 6 in verify; row 2 is
+    # dead and its table maps real pages (7, 8) that must stay clean.
+    table = jnp.asarray([[5, 1], [2, 6], [7, 8], [3, 5]], jnp.int32)
+    index = jnp.asarray([1, page - 2, -1, page + 2], jnp.int32)
+
+    def fresh(seed):
+        vals = jax.random.normal(
+            jax.random.PRNGKey(seed), (npages, kvh, page, hd)
+        )
+        if kv_dtype == "native":
+            return vals
+        return quantize_kv_vectors(vals, kv_dtype)
+
+    k_pool, v_pool = fresh(1), fresh(2)
+    if mode == "decode":
+        _, k_new, v_new = attn.apply(
+            params, x, k_pool, v_pool, table, index, None, "xla",
+            method="decode_step_paged",
+        )
+    else:
+        _, k_new, v_new = attn.apply(
+            params, x, k_pool, v_pool, table, index, "xla",
+            method="verify_chunk_paged",
+        )
+    _, k, v = attn.apply(params, x, method="_project")  # (b, kvh, K, hd)
+    pos = jnp.maximum(index, 0)[:, None] + jnp.arange(kc)[None, :]
+    phys = jnp.take_along_axis(table, pos // page, axis=1)
+    phys = jnp.where((index >= 0)[:, None], phys, 0)
+    off = pos % page
+    for pool, new, got in ((k_pool, k, k_new), (v_pool, v, v_new)):
+        if kv_dtype == "native":
+            pool, new, got = (pool,), (new,), (got,)
+        else:
+            new = quantize_kv_vectors(new, kv_dtype)
+        for member, t, g in zip(pool, new, got):
+            want = _scatter_oracle(member, t, phys, off)
+            assert g.dtype == member.dtype and g.shape == member.shape
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(want))
+            # The dead row's own pages are untouched; its write sits on
+            # the trash page.
+            np.testing.assert_array_equal(
+                np.asarray(g)[7:], np.asarray(member)[7:]
+            )
+            assert (np.asarray(g)[0] != np.asarray(member)[0]).any()
+
+
+@pytest.mark.parametrize("w", [4, 128])
+def test_paged_write_dead_rows_collide_on_trash_only(w):
+    """Several dead rows all target ``(page 0, offset 0)``: whichever
+    lands last, nothing but the trash page differs from the oracle."""
+    pool = jnp.zeros((4, 2, 8, w))
+    new = jnp.arange(3 * 2 * w, dtype=jnp.float32).reshape(3, 2, 1, w) + 1
+    phys = jnp.asarray([[0], [2], [0]], jnp.int32)
+    off = jnp.asarray([[0], [3], [0]], jnp.int32)
+    got = np.asarray(jax.jit(append_kv_paged)(pool, new, phys, off))
+    want = np.asarray(_scatter_oracle(pool, new, phys, off))
+    np.testing.assert_array_equal(got[1:], want[1:])
+    assert any(
+        (got[0, :, 0, :] == np.asarray(new)[i, :, 0, :]).all() for i in (0, 2)
+    )
+    assert (got[0, :, 1:, :] == 0).all()
 
 
 # -- batcher equivalence -----------------------------------------------------
