@@ -1,8 +1,10 @@
 """One LM cell, start to finish, in one process: weights from the
-seed, the program's ``ContinuousBatcher`` built from the configuration
-file's serving block, a correctness sample against the plain
-reference, warm-up of every shape the traffic sends, the standing
-population, then the measured window.
+seed by the builder the configuration file names, the program's
+``ContinuousBatcher`` built from the file's serving block, a
+correctness sample against the plain reference the file names, warm-up
+of every shape the traffic sends, the standing population, then the
+measured window. What the engine knows of the architecture is the
+builder's ``shape`` (``builders.py``) and nothing else.
 
 The program is driven through ``submit`` + ``tick`` from ONE thread
 (the load generator and the server share the machine's cores; a second
@@ -12,24 +14,16 @@ token events stamped in the ``on_token`` callback.
 
 from __future__ import annotations
 
+import gc
 import time
 
 import numpy as np
 
+from chipbench import manifest as mf
+from chipbench import stall
 from chipbench import traffic as tg
 from chipbench import window as win
 
-#: Served logprobs (bf16 weights, activations and cache, kernels) against
-#: the plain reference (the same bf16 weights read as float32, every
-#: product at ``highest``). What differs is bf16 rounding of
-#: activations through every layer. The largest error a v5e showed in
-#: this PR's runs is 0.0347 (PERF.md); the tolerance is twice that,
-#: since another seed's sample may round worse. ``--fault drop_block``
-#: leaves one block out of the reference, which is what a served
-#: model one block short looks like from here; PERF.md section 3 has
-#: the error that run read on the chip, and a tier-1 test holds the
-#: rehearsal to ``correct`` false under it.
-LOGPROB_TOL = 0.07
 #: Seconds of the window that a ``--trace 1`` run records (its end).
 TRACE_SECONDS = 8.0
 
@@ -69,34 +63,6 @@ class CompileCounter:
             self.seconds += seconds
 
 
-def build_model(model: dict, dtype_name: str, seed: int):
-    """The program's LM at the file's sizes, weights made on the device
-    in one jitted call and cast there to the served type."""
-    import jax
-    import jax.numpy as jnp
-
-    from adapt_tpu.models.transformer_lm import transformer_lm
-
-    dtype = jnp.dtype(dtype_name)
-    lm = transformer_lm(
-        model["vocab_size"], model["n_embd"], model["n_layer"],
-        model["n_head"], model["n_inner"], max_len=model["n_positions"],
-        dtype=dtype,
-    )
-    # --seed may exceed 31 bits: fold the high part in.
-    key = jax.random.fold_in(
-        jax.random.PRNGKey(seed & 0x7FFFFFFF), seed >> 31
-    )
-
-    @jax.jit
-    def init(key):
-        tree = lm.graph.init(key, jnp.zeros((1, 8), jnp.int32))
-        return jax.tree.map(lambda x: x.astype(dtype), tree)
-
-    variables = jax.block_until_ready(init(key))
-    return lm, variables
-
-
 class Driver:
     """Submits requests, ticks the server, and keeps the books the
     metrics are read from."""
@@ -119,6 +85,8 @@ class Driver:
         #: draw their next request from (None: open loop).
         self.stream = None
         self._refilled = 0
+        #: Told when a tick starts and ends (``stall.StallWatch``).
+        self.watch = None
 
     def submit(self, req: tg.Request, due: float, client=None):
         ids = tg.token_ids(self.seed, self.submitted, req.prompt_len,
@@ -172,9 +140,13 @@ class Driver:
             for r in self.live.values() if r["emitted"]
         )
         t0 = time.perf_counter()
+        if self.watch is not None:
+            self.watch.tick_t0 = t0
         with self.annotate("chipbench.tick"):
             n = self.srv.tick()
         self.ticks.append((t0, time.perf_counter(), n, ctx))
+        if self.watch is not None:
+            self.watch.tick_t0 = None
         if self.sample_pool:
             self.pool_peak = max(
                 self.pool_peak, self.srv.stats().get("pages_in_use", 0)
@@ -199,13 +171,13 @@ def _sample_prompts(chunk: int, max_len: int) -> list[int]:
 
 
 def correctness_sample(drv: Driver, variables, serving: dict, max_len: int,
-                       fault: str = ""):
+                       reference, tol: float, fault: str = ""):
     """Three seeded requests served outside the window, one of them
     through chunked prefill, all decoding through the paged kernel;
-    their served logprobs against the plain reference's."""
+    their served logprobs against those of ``reference`` (the plain
+    reference the configuration names), held to the file's ``tol``.
+    ``fault`` goes to the reference, which knows its own tree."""
     import jax.numpy as jnp
-
-    from chipbench.reference import next_token_logprobs
 
     steps = SAMPLE_STEPS
     lens = _sample_prompts(serving["prefill_chunk"], max_len)
@@ -222,11 +194,7 @@ def correctness_sample(drv: Driver, variables, serving: dict, max_len: int,
         info = drv.reqs[rid]
         seq = np.concatenate([info["ids"], np.asarray(info["tokens"])])
         ids[row, : len(seq)] = seq  # causal: the padding is never read
-    if fault == "drop_block":  # the self-test of this comparison
-        variables = {
-            k: v for k, v in variables.items() if k != "decoder_block_0"
-        }
-    want = np.asarray(next_token_logprobs(variables, jnp.asarray(ids)))
+    want = np.asarray(reference(variables, jnp.asarray(ids), fault=fault))
     worst = 0.0
     for row, rid in enumerate(rids):
         n = lens[row]
@@ -235,7 +203,7 @@ def correctness_sample(drv: Driver, variables, serving: dict, max_len: int,
         if got.shape != ref.shape or not np.isfinite(got).all():
             return False, float("nan")
         worst = max(worst, float(np.max(np.abs(got - ref))))
-    return worst <= LOGPROB_TOL, worst
+    return worst <= tol, worst
 
 
 def warm_up(drv: Driver, pairs) -> int:
@@ -366,6 +334,14 @@ def run_cell(cell: dict, config: dict, traffic: dict, opts) -> dict:
 
     phases = Phases(opts.clock0)
     phases.mark("imports")
+    tol = config.get("correct", {}).get("logprob_tol")
+    if tol is None:
+        raise KeyError(
+            f"configuration {config.get('name')!r} states no "
+            "correct.logprob_tol: the tolerance belongs to the architecture "
+            "and its precision, so the file gives it, with its reason"
+        )
+    reference = mf.part_of(config, "reference")
     model = dict(config["model"])
     # The deployment: the configuration's serving block, then what the
     # traffic mix says of it (a mix is served by as many slots as its
@@ -374,24 +350,15 @@ def run_cell(cell: dict, config: dict, traffic: dict, opts) -> dict:
     if opts.rehearse:
         model.update(config["rehearse"]["model"])
         serving.update(config["rehearse"]["serving"])
-    max_total = min(model["n_positions"], serving["prompt_buckets"][-1])
-    pairs = tg.templates(traffic, max_total)
-    serving["pool_pages"] = pool_pages(serving, pairs, model["n_positions"])
     compiles = CompileCounter()
-    lm, variables = build_model(model, config["dtype"], opts.seed)
+    lm, variables, shape = mf.part_of(config, "builder")(
+        model, config["dtype"], opts.seed
+    )
     phases.mark("weights")
+    max_total = min(shape["max_len"], serving["prompt_buckets"][-1])
+    pairs = tg.templates(traffic, max_total)
+    serving["pool_pages"] = pool_pages(serving, pairs, shape["max_len"])
     itemsize = jax.numpy.dtype(config["dtype"]).itemsize
-    page_bytes = (
-        2 * model["n_layer"] * model["n_embd"] * serving["page_size"]
-        * itemsize
-    )
-    print(
-        f"deployment: slots {serving['slots']}  pool {serving['pool_pages']}"
-        f" pages x {page_bytes} B = {serving['pool_pages'] * page_bytes} B"
-        f"  weights "
-        f"{sum(x.nbytes for x in jax.tree.leaves(variables))} B",
-        flush=True,
-    )
     srv = ContinuousBatcher(
         lm, variables,
         slots=serving["slots"], chunk=serving["chunk"],
@@ -401,7 +368,7 @@ def run_cell(cell: dict, config: dict, traffic: dict, opts) -> dict:
         prompt_buckets=tuple(serving["prompt_buckets"]),
     )
     phases.mark("batcher")
-    drv = Driver(srv, model["vocab_size"], opts.seed, opts.annotate)
+    drv = Driver(srv, shape["vocab"], opts.seed, opts.annotate)
     clients = traffic.get("clients")
     if clients == "slots":
         clients = serving["slots"]
@@ -411,11 +378,11 @@ def run_cell(cell: dict, config: dict, traffic: dict, opts) -> dict:
     standing = tg.standing_population(pairs, n_standing)
 
     ok, worst = correctness_sample(
-        drv, variables, serving, model["n_positions"], opts.fault
+        drv, variables, serving, shape["max_len"], reference, tol, opts.fault
     )
     print(
         f"correctness: served logprobs vs plain reference, max|err| "
-        f"{worst:.4f} (tolerance {LOGPROB_TOL}) -> {'ok' if ok else 'WRONG'}",
+        f"{worst:.4f} (tolerance {tol}) -> {'ok' if ok else 'WRONG'}",
         flush=True,
     )
     phases.mark("correctness")
@@ -438,6 +405,16 @@ def run_cell(cell: dict, config: dict, traffic: dict, opts) -> dict:
     compiles_before = compiles.count
     snap = global_metrics().snapshot(window=True)
     setup_s = time.perf_counter() - opts.clock0
+    # The pool as the batcher holds it (its memory gauge, summed over
+    # the pool's own arrays), not an arithmetic of the architecture's:
+    # a grouped, latent or windowed cache has other bytes to a page.
+    pool_bytes = int(snap["gauges"].get("memory.pool_bytes", 0))
+    print(
+        f"deployment: slots {serving['slots']}  pool {serving['pool_pages']}"
+        f" pages = {pool_bytes} B  weights "
+        f"{sum(x.nbytes for x in jax.tree.leaves(variables))} B",
+        flush=True,
+    )
     say = _mute if opts.rehearse else print  # a CPU wall is no result
     say(phases.line() + f"  | compiles {compiles.count} "
         f"({compiles.seconds:.1f}s in backend compile or cache load)",
@@ -445,8 +422,15 @@ def run_cell(cell: dict, config: dict, traffic: dict, opts) -> dict:
     if opts.sweep:
         return sweep(drv, traffic, pairs, opts)
 
+    gc_before = [g["collections"] for g in gc.get_stats()]
+    drv.watch = stall.StallWatch()
+    drv.watch.start()
     m = measure(drv, traffic, pairs, opts.seconds, opts.seed,
                 opts.trace_dir if opts.trace else None)
+    drv.watch.stop()
+    gc_runs = [
+        g["collections"] - n for g, n in zip(gc.get_stats(), gc_before)
+    ]
     hist = global_metrics().snapshot(since=snap, reservoirs=True)
     compiled_in_window = compiles.count - compiles_before
     stats = srv.stats()
@@ -483,6 +467,20 @@ def run_cell(cell: dict, config: dict, traffic: dict, opts) -> dict:
         f"{setup_submitted} (failed {setup_failed})",
         flush=True,
     )
+    # A far-off run is as a rule ONE tick that stalled for seconds
+    # (PERF.md section 7): say how long the longest took and when, and
+    # what the interpreter's collector did meanwhile.
+    t0, t1 = max(
+        (t for t in drv.ticks if t_open < t[1] <= t_close),
+        key=lambda t: t[1] - t[0],
+    )[:2]
+    say(
+        f"longest tick {(t1 - t0) * 1e3:.1f} ms at window+{t0 - t_open:.1f}s"
+        f"  | gc collections in the window by generation {gc_runs}",
+        flush=True,
+    )
+    for line in drv.watch.lines(t0, t1, t_open):
+        say(line, flush=True)
     e2e = {
         "ttft_p50_ms": win.percentile(ttft, 50),
         "itl_p95_ms": win.percentile(gaps, 95),
@@ -494,13 +492,17 @@ def run_cell(cell: dict, config: dict, traffic: dict, opts) -> dict:
     say("e2e " + "  ".join(
         f"{k} {v:.4f}" for k, v in e2e.items() if v is not None
     ), flush=True)
+    t0 = time.perf_counter()
+    gc.collect()
+    say(f"gc: a full collection of this process takes "
+        f"{time.perf_counter() - t0:.3f}s (after the window)", flush=True)
     records = dict(
         events=drv.events, ticks=drv.ticks, t_open=t_open, t_close=t_close,
         gaps_ms=gaps, ttft_ms=ttft, late_ms=m["late_ms"], trace=m["trace"],
         reqs=drv.reqs,
         histograms=hist.get("histograms", {}), stats=stats,
-        pool_peak_pages=drv.pool_peak, model=model, serving=serving,
-        itemsize=itemsize,
+        pool_peak_pages=drv.pool_peak, model=model, shape=shape,
+        serving=serving, itemsize=itemsize,
     )
     return dict(
         correct=bool(ok and not compiled_in_window and setup_failed == 0),

@@ -70,3 +70,16 @@ def resolve(dotted: str):
 
 def reader_of(manifest: dict, metric: str, root: Path = ROOT):
     return resolve(_read(find(manifest, "metrics", metric, root))["reader"])
+
+
+def part_of(config: dict, key: str):
+    """The function a configuration file names under ``key``
+    (``engine``, ``builder``, ``reference``), found as a metric's
+    reader is. A file that does not say is an error, never a default:
+    a default would be one architecture's."""
+    if key not in config:
+        raise KeyError(
+            f"configuration {config.get('name')!r} names no {key!r} "
+            "(module:function; see chipbench/README.md)"
+        )
+    return resolve(config[key])

@@ -102,9 +102,10 @@ def prefill_ms_per_ktok(trace, rec, kind):
 
 
 def _attention_shape(rec):
-    m = rec["model"]
-    heads = m["n_head"]
-    return heads, heads, m["n_embd"] // heads, m["n_layer"]
+    """The builder's ``shape``: all a reader may know of the
+    architecture."""
+    s = rec["shape"]
+    return s["heads"], s["kv_heads"], s["head_dim"], s["layers"]
 
 
 def paged_decode_roofline(trace, rec, kind):

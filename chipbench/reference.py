@@ -1,9 +1,11 @@
-"""Plain reference of the decoder both LM configurations are: a GPT-2
+"""Plain reference of the decoder both GPT-2 configurations are: a GPT-2
 block (pre-LayerNorm, fused QKV, tanh-GELU two-matrix MLP, learned
 positions, untied head) in straightforward ``jax.numpy`` at float32
 and ``highest`` matmul precision. No kernels, no cache, no batching
 tricks. It reads the program's parameter tree and nothing else of the
-program.
+program. A configuration names it under ``reference``
+(``chipbench.reference:next_token_logprobs``); another architecture
+brings a reference module of its own with the same signature.
 
 Run block by block from Python: one small jitted block serves every
 layer (same shapes), and only one layer's float32 copy of the weights
@@ -65,17 +67,25 @@ def _head_logprobs(p, h, targets):
     return jnp.take_along_axis(logp, targets[..., None], -1)[..., 0]
 
 
-def next_token_logprobs(variables, ids):
+def next_token_logprobs(variables, ids, fault=""):
     """(b, s - 1): log-probability the model gives ``ids[:, t + 1]``
     after reading ``ids[:, : t + 1]``. ``variables`` is the program's
-    parameter tree (``{"params": {...}}`` per graph node)."""
+    parameter tree (``{"params": {...}}`` per graph node).
+
+    ``fault="drop_block"`` is the self-test of the comparison built on
+    this: the first block is left out, which is what a served model
+    one block short looks like from here, and a sound comparison then
+    answers not correct. Each reference knows its own tree, so the
+    fault lives here and not in the engine."""
+    if fault not in ("", "drop_block"):
+        raise ValueError(f"unknown fault {fault!r}")
     ids = jnp.asarray(ids, jnp.int32)
     h = _embed(variables["embed"]["params"], ids)
     names = sorted(
         (n for n in variables if n.startswith("decoder_block_")),
         key=lambda n: int(n.rsplit("_", 1)[1]),
     )
-    for name in names:
+    for name in names[1:] if fault == "drop_block" else names:
         h = _block(variables[name]["params"], h)
     return _head_logprobs(
         variables["head"]["params"], h[:, :-1], ids[:, 1:]

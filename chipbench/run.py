@@ -27,7 +27,6 @@ ROOT = Path(__file__).resolve().parents[1]
 if str(ROOT) not in sys.path:
     sys.path.insert(0, str(ROOT))
 
-from chipbench import lm_engine  # noqa: E402
 from chipbench import manifest as mf  # noqa: E402
 from chipbench import xtrace  # noqa: E402
 
@@ -73,7 +72,7 @@ def run_one(manifest, name: str, args, root: Path = ROOT):
 
         print(f"compile cache {ensure_compile_cache()}", flush=True)
     if str(root) not in sys.path:
-        sys.path.insert(0, str(root))  # readers a later PR adds
+        sys.path.insert(0, str(root))  # modules a later PR adds
     trace_dir = str(root / ".chipbench_trace" / name)
     if args.trace:
         shutil.rmtree(trace_dir, ignore_errors=True)
@@ -87,7 +86,7 @@ def run_one(manifest, name: str, args, root: Path = ROOT):
             else contextlib.nullcontext
         ),
     )
-    out = lm_engine.run_cell(cell, config, traffic, opts)
+    out = mf.part_of(config, "engine")(cell, config, traffic, opts)
     if out is None:  # a sweep prints its own lines and no result
         return None
     device = _device_block(devices, cell["chips"])

@@ -16,6 +16,7 @@ import dataclasses
 import glob
 import os
 import re
+import warnings
 from collections import defaultdict
 
 _OP = re.compile(r"^%?([A-Za-z_][\w\-]*?)(?:\.\d+)*(?: =|\(|$)")
@@ -49,6 +50,12 @@ class DeviceTrace:
 class Trace:
     devices: list  # DeviceTrace per device plane, in plane order
     host: list  # (start_ns, end_ns, name) of every host-thread event
+    #: ``host_attrs[i]`` is the attributes of ``host[i]`` as a dict (a
+    #: ``TraceAnnotation``'s keyword arguments, e.g. the ``request``,
+    #: ``pos0``, ``chunk_len`` and ``final`` of ``engine.prefill_chunk``;
+    #: empty for most events). Beside the tuples and not in them: every
+    #: reader unpacks ``(start, end, name)``.
+    host_attrs: list = dataclasses.field(default_factory=list)
 
 
 def find_xplane(trace_dir: str) -> str | None:
@@ -62,7 +69,7 @@ def load(path: str) -> Trace:
     from jax.profiler import ProfileData
 
     data = ProfileData.from_file(path)
-    devices, host = [], []
+    devices, host, host_attrs = [], [], []
     for plane in data.planes:
         if plane.name.startswith("/device:TPU:"):
             ops, modules = [], []
@@ -82,12 +89,17 @@ def load(path: str) -> Trace:
             if ops or modules:
                 devices.append(DeviceTrace(ops, modules))
         elif plane.name == "/host:CPU":
-            for line in plane.lines:
-                host.extend(
-                    (e.start_ns, e.start_ns + e.duration_ns, e.name)
-                    for e in line.events
-                )
-    return Trace(devices, host)
+            with warnings.catch_warnings():
+                # jaxlib 0.9's binding warns at every ``.stats`` that
+                # its iterator type has no ``__module__``.
+                warnings.simplefilter("ignore", DeprecationWarning)
+                for line in plane.lines:
+                    for e in line.events:
+                        host.append(
+                            (e.start_ns, e.start_ns + e.duration_ns, e.name)
+                        )
+                        host_attrs.append(dict(e.stats))
+    return Trace(devices, host, host_attrs)
 
 
 def union(intervals) -> list[tuple[float, float]]:

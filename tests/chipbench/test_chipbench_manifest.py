@@ -93,6 +93,10 @@ def test_configs_cells_chips_and_files(bm):
         assert body["source"] == c["source"] and body["reduced"] == c["reduced"]
         for key in ("model", "serving", "memory", "assumed"):
             assert key in body, (c["name"], key)
+        # What belongs to the architecture is named, as a reader is.
+        for key in ("engine", "builder", "reference"):
+            assert ":" in body[key], (c["name"], key)
+        assert body["correct"]["logprob_tol"] > 0 and body["correct"]["why"]
     for w in cells:
         assert any((p / "traffic" / f"{w['traffic']}.json").is_file() for p in paths)
     for m in bm["per_layer"]:
@@ -102,7 +106,9 @@ def test_configs_cells_chips_and_files(bm):
         body = json.loads(found[0].read_text())
         for key in ("layer", "unit", "moves"):
             assert body[key] == m[key], (m["name"], key)
-        assert body["workloads"] == _cells_of(m, bm)
+        # BENCHMARK.json alone says which cells report a metric: a copy
+        # here would make every new cell edit a file that is there.
+        assert "workloads" not in body, m["name"]
         assert ":" in body["reader"]
 
 

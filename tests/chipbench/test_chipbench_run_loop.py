@@ -1,7 +1,9 @@
 """The run loop, reached through the CPU rehearsal at tiny widths, and
-addition by data: a new configuration, traffic mix, per-layer metric
-and cell, found and run with no edit to a file that is there."""
+addition by data: another ARCHITECTURE (its configuration, builder,
+plain reference, traffic mix, per-layer metric and cell), found and
+run with no edit to a file that is there."""
 
+import hashlib
 import json
 import shutil
 from pathlib import Path
@@ -9,8 +11,64 @@ from pathlib import Path
 import pytest
 
 from chipbench import run as bench_run
+from chipbench import xtrace, yardstick
 
 ROOT = Path(__file__).parents[2]
+ADDED_CELL = "tiny_moe_bursts"
+#: Metrics that are there and that the added cell joins by appending
+#: its name to their ``workloads`` in BENCHMARK.json, and nowhere else.
+JOINED = (
+    "tick.host_ms.batch", "model.decode_step_ms.batch",
+    "kernel.paged_decode_batch_roofline",
+)
+NEW_METRIC = "kernel.query_heads_per_kv_head"
+
+
+def _tree_hash(directory: Path) -> str:
+    h = hashlib.sha256()
+    for f in sorted(directory.rglob("*")):
+        if f.is_file() and "__pycache__" not in f.parts:
+            h.update(str(f.relative_to(directory)).encode())
+            h.update(f.read_bytes())
+    return h.hexdigest()
+
+
+@pytest.fixture(scope="module")
+def added(tmp_path_factory):
+    """What a later PR does for an architecture the harness has never
+    seen: a directory of its own beside an untouched ``chipbench/``,
+    and entries in ``BENCHMARK.json``. Returns the root and the hash of
+    the copied ``chipbench/`` as it was before anything ran."""
+    root = tmp_path_factory.mktemp("added")
+    shutil.copytree(
+        ROOT / "chipbench", root / "chipbench",
+        ignore=shutil.ignore_patterns("__pycache__"),
+    )
+    shutil.copytree(
+        Path(__file__).parent / "another_arch", root / "chipbench_more",
+        ignore=shutil.ignore_patterns("__pycache__"),
+    )
+    bm = json.loads((ROOT / "BENCHMARK.json").read_text())
+    bm["paths"].append("chipbench_more")
+    bm["configs"].append({
+        "name": "tiny-moe", "source": "https://example.org/addition-by-data-test",
+        "file": "chipbench_more/configs/tiny-moe.json", "reduced": [],
+        "why": "addition-by-data test: GQA, rotary positions, top-2 mixture",
+    })
+    bm["workloads"].append({
+        "name": ADDED_CELL, "config": "tiny-moe", "traffic": "bursts",
+        "chips": 1, "why": "addition-by-data test",
+    })
+    for m in bm["end_to_end"] + bm["per_layer"]:
+        if m["name"] in ("out_tok_per_s", *JOINED):
+            m["workloads"].append(ADDED_CELL)
+    bm["per_layer"].append({
+        "name": NEW_METRIC, "unit": "heads", "better": "lower",
+        "source": "program_counter", "layer": "attention kernels",
+        "moves": "out_tok_per_s", "workloads": [ADDED_CELL],
+    })
+    (root / "BENCHMARK.json").write_text(json.dumps(bm))
+    return root, _tree_hash(root / "chipbench")
 
 
 def _rehearse(capsys, *argv):
@@ -23,16 +81,25 @@ def _rehearse(capsys, *argv):
     return lines
 
 
+def _cell_argv(request, cell):
+    """--workload, and for the added cell the --root it lives under."""
+    if cell != ADDED_CELL:
+        return ["--workload", cell]
+    root, _ = request.getfixturevalue("added")
+    return ["--root", str(root), "--workload", cell]
+
+
 @pytest.mark.parametrize(
     "cell,e2e",
     [
         ("gpt2xl_chat", "['itl_p95_ms', 'setup_s']"),
         ("cgpt1b3_batchgen", "['out_tok_per_s', 'setup_s']"),
         ("gpt2xl_doc", "['out_tok_per_s', 'setup_s']"),
+        (ADDED_CELL, "['out_tok_per_s', 'setup_s']"),
     ],
 )
-def test_rehearsal_walks_the_cell(capsys, cell, e2e):
-    plain, traced = _rehearse(capsys, "--workload", cell)
+def test_rehearsal_walks_the_cell(request, capsys, cell, e2e):
+    plain, traced = _rehearse(capsys, *_cell_argv(request, cell))
     assert "correct=True" in plain and "failed=0" in plain
     assert f"would report {e2e}" in plain
     # The traced pass reports host-side per-layer metrics only: no
@@ -41,11 +108,13 @@ def test_rehearsal_walks_the_cell(capsys, cell, e2e):
     assert "roofline" not in traced and "decode_step_ms" not in traced
 
 
-def test_a_dropped_block_makes_the_run_incorrect(capsys):
-    """The self-test of `correct`: with one block left out of the
-    plain reference the served logprobs must disagree."""
+@pytest.mark.parametrize("cell", ["gpt2xl_chat", ADDED_CELL])
+def test_a_dropped_block_makes_the_run_incorrect(request, capsys, cell):
+    """The self-test of `correct`: with one block left out of the plain
+    reference THE CONFIGURATION NAMES, the served logprobs must
+    disagree."""
     plain, traced = _rehearse(
-        capsys, "--workload", "gpt2xl_chat", "--fault", "drop_block"
+        capsys, *_cell_argv(request, cell), "--fault", "drop_block"
     )
     assert "correct=False" in plain and "correct=False" in traced
 
@@ -74,53 +143,51 @@ def test_a_run_without_the_chip_fails_and_prints_no_result(capsys):
     assert "{" not in capsys.readouterr().out
 
 
-def test_addition_by_data(tmp_path, capsys):
-    """What a later PR does: add files and entries, edit nothing."""
-    extra = tmp_path / "chipbench_more"
-    for d in ("configs", "traffic", "metrics"):
-        (extra / d).mkdir(parents=True)
-    shutil.copytree(ROOT / "chipbench", tmp_path / "chipbench")
-    bm = json.loads((ROOT / "BENCHMARK.json").read_text())
-    cfg = json.loads((ROOT / "chipbench/configs/gpt2-xl.json").read_text())
-    cfg["name"] = "tiny-new"
-    cfg["rehearse"]["model"]["n_layer"] = 1
-    (extra / "configs/tiny-new.json").write_text(json.dumps(cfg))
-    (extra / "traffic/bursts.json").write_text(json.dumps({
-        "name": "bursts", "loop": "closed", "clients": 3, "cycle": 4,
-        "prompt": {"dist": "uniform", "min": 20, "max": 60},
-        "output": {"dist": "fixed", "value": 6},
-    }))
-    (extra / "new_reader.py").write_text(
-        "def first_tokens(trace, rec, kind):\n"
-        "    return float(len(rec['ttft_ms']))\n"
-    )
-    (extra / "__init__.py").write_text("")
-    (extra / "metrics/client.first_tokens.json").write_text(json.dumps({
-        "name": "client.first_tokens", "layer": "client", "unit": "requests",
-        "moves": "out_tok_per_s", "workloads": ["tiny_bursts"],
-        "reader": "chipbench_more.new_reader:first_tokens",
-    }))
-    bm["paths"].append("chipbench_more")
-    bm["configs"].append({
-        "name": "tiny-new", "source": cfg["source"],
-        "file": "chipbench_more/configs/tiny-new.json", "reduced": [],
-        "why": "addition-by-data test",
-    })
-    bm["workloads"].append({
-        "name": "tiny_bursts", "config": "tiny-new", "traffic": "bursts",
-        "chips": 1, "why": "addition-by-data test",
-    })
-    for m in bm["end_to_end"]:
-        if m["name"] == "out_tok_per_s":
-            m["workloads"].append("tiny_bursts")
-    bm["per_layer"].append({
-        "name": "client.first_tokens", "unit": "requests", "better": "higher",
-        "source": "host_clock", "layer": "client", "moves": "out_tok_per_s",
-        "workloads": ["tiny_bursts"],
-    })
-    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bm))
+@pytest.mark.parametrize("key", ["engine", "builder", "reference", "correct"])
+def test_a_configuration_that_does_not_say_is_an_error(added, key, capsys):
+    """No default engine, builder, reference or tolerance: a default
+    would be one architecture's."""
+    root, _ = added
+    path = root / "chipbench_more/configs/tiny-moe.json"
+    whole = path.read_text()
+    cfg = json.loads(whole)
+    del cfg[key]
+    path.write_text(json.dumps(cfg))
+    try:
+        with pytest.raises(KeyError, match=key):
+            bench_run.main(["--rehearse", "--seconds", "1", "--root",
+                            str(root), "--workload", ADDED_CELL])
+    finally:
+        path.write_text(whole)
+    assert "rehearsal " not in capsys.readouterr().out
+
+
+def test_addition_by_data(added, capsys, monkeypatch):
+    """A GQA + rotary + top-2-mixture decoder is served and held to its
+    own reference, joins metrics that are there through BENCHMARK.json
+    alone, and brings a metric that reads ``records["shape"]``; the
+    copied ``chipbench/`` keeps its hash."""
+    root, before = added
     plain, traced = _rehearse(
-        capsys, "--root", str(tmp_path), "--workload", "tiny_bursts"
+        capsys, "--root", str(root), "--workload", ADDED_CELL
     )
     assert "correct=True" in plain and "failed=0" in plain
-    assert "client.first_tokens" in traced
+    assert NEW_METRIC in traced
+    # The joined metrics read a device plane, and a CPU run has none:
+    # put one in the trace's place (one kernel call, one step program,
+    # one tick span) and the readers that are there answer for the new
+    # cell from its ``shape`` and its records.
+    device = xtrace.DeviceTrace(
+        ops=[(1_000, 9_000, "_paged_impl")],
+        modules=[(0, 10_000, "_step_chunk")],
+    )
+    monkeypatch.setattr(xtrace, "load", lambda path: xtrace.Trace(
+        [device], [(0, 20_000, "chipbench.tick")]
+    ))
+    monkeypatch.setitem(yardstick.PEAKS, "cpu", (1e12, 1e11))
+    _, traced = _rehearse(
+        capsys, "--root", str(root), "--workload", ADDED_CELL
+    )
+    for name in (*JOINED, NEW_METRIC):
+        assert name in traced, name
+    assert _tree_hash(root / "chipbench") == before
