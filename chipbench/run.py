@@ -18,7 +18,6 @@ CLOCK0 = time.perf_counter()  # set-up is timed from process start
 import argparse  # noqa: E402
 import contextlib  # noqa: E402
 import json  # noqa: E402
-import os  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 from pathlib import Path  # noqa: E402
@@ -55,6 +54,14 @@ def run_one(manifest, name: str, args, root: Path = ROOT):
     cell = mf.cell(manifest, name)
     config = mf.config_of(manifest, cell, root)
     traffic = mf.traffic_of(manifest, cell, root)
+    # The faults this configuration's reference knows. Refused here,
+    # before any weight is drawn, if --fault names another.
+    controls = config.get("correct", {}).get("controls", ["drop_block"])
+    if args.fault and args.fault not in controls:
+        raise SystemExit(
+            f"--fault {args.fault}: configuration {cell['config']!r} names "
+            f"the controls {controls}"
+        )
     devices = jax.devices()
     if args.rehearse:
         if devices[0].platform != "cpu":
@@ -134,6 +141,10 @@ def run_one(manifest, name: str, args, root: Path = ROOT):
                  for k, v in xtrace.idle_gaps(dev, trace.host).items()}
             ),
         }
+    # Every number compared beside its limit, as standard error's last
+    # lines too: of a run that is not correct the driver keeps those.
+    for line in out.get("compared", []):
+        print(line, file=sys.stderr, flush=True)
     return result
 
 
@@ -154,9 +165,11 @@ def main(argv=None) -> int:
         "one process; prints a line per rate and no result",
     )
     ap.add_argument(
-        "--fault", default="", choices=("", "drop_block"),
-        help="self-test of `correct`: drop_block leaves one block out of "
-        "the plain reference, so a sound comparison must answer false",
+        "--fault", default="",
+        help="self-test of `correct`: one of the controls the cell's "
+        "configuration names under correct.controls (absent: drop_block, "
+        "one block left out of the plain reference); a sound comparison "
+        "must then answer false",
     )
     ap.add_argument(
         "--root", default=str(ROOT),

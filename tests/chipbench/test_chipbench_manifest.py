@@ -78,6 +78,39 @@ def test_every_cell_reports_what_the_contract_asks(bm):
             assert cell in _cells_of(e2e[m["moves"]], bm), (m["name"], cell)
 
 
+def _check_correct_block(correct):
+    """``correct`` in a configuration file: the tolerance with its
+    readings; where the reference vouches position by position, the
+    least share it must vouch for, never under half; the controls its
+    reference knows."""
+    assert correct["logprob_tol"] > 0 and correct["why"]
+    if "min_vouched" in correct:
+        assert 0.5 <= correct["min_vouched"] <= 1
+    controls = correct.get("controls", ["drop_block"])
+    assert isinstance(controls, list) and controls
+    assert all(isinstance(c, str) and NAME.match(c) for c in controls)
+    assert len(set(controls)) == len(controls)
+
+
+@pytest.mark.parametrize(
+    "file", sorted((ROOT / "tests/chipbench/another_arch/configs").glob("*.json"))
+    + sorted((ROOT / "chipbench/configs").glob("*.json")),
+    ids=lambda f: f.stem,
+)
+def test_correct_block_of_every_configuration_file(file):
+    """The benchmark's files and the added architecture's fixture."""
+    _check_correct_block(json.loads(file.read_text())["correct"])
+
+
+@pytest.mark.parametrize("bad", [
+    {"min_vouched": 0.4}, {"min_vouched": 1.5}, {"controls": []},
+    {"controls": "drop_block"}, {"controls": ["drop block"]},
+])
+def test_correct_block_refuses(bad):
+    with pytest.raises(AssertionError):
+        _check_correct_block({"logprob_tol": 0.05, "why": "readings", **bad})
+
+
 def test_configs_cells_chips_and_files(bm):
     cells = bm["workloads"]
     assert {c["name"] for c in bm["configs"]} == {w["config"] for w in cells}
@@ -96,7 +129,7 @@ def test_configs_cells_chips_and_files(bm):
         # What belongs to the architecture is named, as a reader is.
         for key in ("engine", "builder", "reference"):
             assert ":" in body[key], (c["name"], key)
-        assert body["correct"]["logprob_tol"] > 0 and body["correct"]["why"]
+        _check_correct_block(body["correct"])
     for w in cells:
         assert any((p / "traffic" / f"{w['traffic']}.json").is_file() for p in paths)
     for m in bm["per_layer"]:

@@ -3,6 +3,7 @@ addition by data: another ARCHITECTURE (its configuration, builder,
 plain reference, traffic mix, per-layer metric and cell), found and
 run with no edit to a file that is there."""
 
+import argparse
 import hashlib
 import json
 import shutil
@@ -15,6 +16,10 @@ from chipbench import xtrace, yardstick
 
 ROOT = Path(__file__).parents[2]
 ADDED_CELL = "tiny_moe_bursts"
+#: The same mixture served in bfloat16, held to a reference that says
+#: which positions it vouches for (``another_arch/plain.py``).
+ADDED_BF16 = "tiny_moe_bf16_bursts"
+ADDED = {ADDED_CELL: "tiny-moe", ADDED_BF16: "tiny-moe-bf16"}
 #: Metrics that are there and that the added cell joins by appending
 #: its name to their ``workloads`` in BENCHMARK.json, and nowhere else.
 JOINED = (
@@ -50,22 +55,27 @@ def added(tmp_path_factory):
     )
     bm = json.loads((ROOT / "BENCHMARK.json").read_text())
     bm["paths"].append("chipbench_more")
-    bm["configs"].append({
-        "name": "tiny-moe", "source": "https://example.org/addition-by-data-test",
-        "file": "chipbench_more/configs/tiny-moe.json", "reduced": [],
-        "why": "addition-by-data test: GQA, rotary positions, top-2 mixture",
-    })
-    bm["workloads"].append({
-        "name": ADDED_CELL, "config": "tiny-moe", "traffic": "bursts",
-        "chips": 1, "why": "addition-by-data test",
-    })
+    for cell, config in ADDED.items():
+        body = json.loads(
+            (root / f"chipbench_more/configs/{config}.json").read_text()
+        )
+        bm["configs"].append({
+            "name": config, "source": body["source"],
+            "file": f"chipbench_more/configs/{config}.json", "reduced": [],
+            "why": "addition-by-data test: GQA, rotary positions, top-2 "
+            f"mixture, served in {body['dtype']}",
+        })
+        bm["workloads"].append({
+            "name": cell, "config": config, "traffic": "bursts",
+            "chips": 1, "why": "addition-by-data test",
+        })
     for m in bm["end_to_end"] + bm["per_layer"]:
         if m["name"] in ("out_tok_per_s", *JOINED):
-            m["workloads"].append(ADDED_CELL)
+            m["workloads"].extend(ADDED)
     bm["per_layer"].append({
         "name": NEW_METRIC, "unit": "heads", "better": "lower",
         "source": "program_counter", "layer": "attention kernels",
-        "moves": "out_tok_per_s", "workloads": [ADDED_CELL],
+        "moves": "out_tok_per_s", "workloads": list(ADDED),
     })
     (root / "BENCHMARK.json").write_text(json.dumps(bm))
     return root, _tree_hash(root / "chipbench")
@@ -82,8 +92,8 @@ def _rehearse(capsys, *argv):
 
 
 def _cell_argv(request, cell):
-    """--workload, and for the added cell the --root it lives under."""
-    if cell != ADDED_CELL:
+    """--workload, and for an added cell the --root it lives under."""
+    if cell not in ADDED:
         return ["--workload", cell]
     root, _ = request.getfixturevalue("added")
     return ["--root", str(root), "--workload", cell]
@@ -96,6 +106,7 @@ def _cell_argv(request, cell):
         ("cgpt1b3_batchgen", "['out_tok_per_s', 'setup_s']"),
         ("gpt2xl_doc", "['out_tok_per_s', 'setup_s']"),
         (ADDED_CELL, "['out_tok_per_s', 'setup_s']"),
+        (ADDED_BF16, "['out_tok_per_s', 'setup_s']"),
     ],
 )
 def test_rehearsal_walks_the_cell(request, capsys, cell, e2e):
@@ -108,15 +119,105 @@ def test_rehearsal_walks_the_cell(request, capsys, cell, e2e):
     assert "roofline" not in traced and "decode_step_ms" not in traced
 
 
-@pytest.mark.parametrize("cell", ["gpt2xl_chat", ADDED_CELL])
-def test_a_dropped_block_makes_the_run_incorrect(request, capsys, cell):
-    """The self-test of `correct`: with one block left out of the plain
-    reference THE CONFIGURATION NAMES, the served logprobs must
-    disagree."""
+def _control_cases():
+    """(cell, control): every control of every configuration, the
+    benchmark's and the added ones, once, in its first cell. A file
+    that names none has ``drop_block`` (``run.py``)."""
+    bm = json.loads((ROOT / "BENCHMARK.json").read_text())
+    files = {c["name"]: ROOT / c["file"] for c in bm["configs"]}
+    first = {}
+    for w in bm["workloads"]:
+        first.setdefault(w["config"], w["name"])
+    for cell, config in ADDED.items():
+        first[config] = cell
+        files[config] = (
+            Path(__file__).parent / f"another_arch/configs/{config}.json"
+        )
+    return [
+        (first[config], control)
+        for config, f in files.items()
+        for control in json.loads(f.read_text())["correct"].get(
+            "controls", ["drop_block"])
+    ]
+
+
+@pytest.mark.parametrize("cell,control", _control_cases())
+def test_a_control_makes_the_run_incorrect(request, capsys, cell, control):
+    """The self-test of `correct`: with one block (or, for a mixture,
+    one expert) left out of the plain reference THE CONFIGURATION
+    NAMES, the served logprobs must disagree."""
     plain, traced = _rehearse(
-        capsys, *_cell_argv(request, cell), "--fault", "drop_block"
+        capsys, *_cell_argv(request, cell), "--fault", control
     )
     assert "correct=False" in plain and "correct=False" in traced
+
+
+def test_a_fault_the_configuration_does_not_name_is_refused(capsys):
+    """Before any weight is drawn: the GPT-2 reference knows no
+    expert."""
+    with pytest.raises(SystemExit, match="drop_expert"):
+        bench_run.main(["--rehearse", "--workload", "gpt2xl_chat",
+                        "--fault", "drop_expert"])
+    assert "correctness:" not in capsys.readouterr().out
+
+
+def _one_pass(root, cell, trace=0):
+    """One rehearsal pass of one cell through ``run_one``."""
+    args = argparse.Namespace(
+        seed=0, seconds=1.5, trace=trace, rehearse=True, fault="", sweep="",
+    )
+    return bench_run.run_one(bench_run.mf.load(root), cell, args, root)
+
+
+def test_a_broken_served_path_makes_the_run_incorrect(capsys, monkeypatch):
+    """The fault in the program's place, not the reference's: one served
+    answer altered where the batcher hands it out, the rest of the run
+    as it is (only the look for a chip is the rehearsal's)."""
+    from adapt_tpu.runtime.continuous import ContinuousBatcher
+
+    logprobs = ContinuousBatcher.logprobs
+
+    def altered(self, rid):
+        out = logprobs(self, rid).copy()
+        out[-1] += 0.5
+        return out
+
+    monkeypatch.setattr(ContinuousBatcher, "logprobs", altered)
+    _one_pass(ROOT, "gpt2xl_chat")
+    out = capsys.readouterr().out
+    assert "-> WRONG" in out and "correct=False" in out
+
+
+def test_records_carry_the_windows_counters_and_each_ticks_contexts(
+    added, capsys, monkeypatch
+):
+    """What a new mechanism's readers need and could not reach:
+    the window's counter deltas and gauges, the pool's peak by every
+    ``pages_in_use*`` key of ``stats()``, and each tick's contexts."""
+    from chipbench import lm_engine
+
+    root, _ = added
+    seen = {}
+    run_cell = lm_engine.run_cell
+
+    def keep(*a):
+        seen.update(run_cell(*a))
+        return seen
+
+    monkeypatch.setattr(lm_engine, "run_cell", keep)
+    _one_pass(root, ADDED_BF16, trace=1)
+    assert "vouched 21 of 24 (at least 18)" in capsys.readouterr().out
+    rec = seen["records"]
+    assert rec["counters"]["continuous.ticks"] > 0  # a delta, not a total
+    assert rec["counters"]["continuous.ticks"] < len(rec["ticks"])
+    assert rec["gauges"]["memory.pool_bytes"] > 0
+    assert rec["pool_peaks"] == {"pages_in_use": rec["pool_peak_pages"]}
+    assert rec["pool_peak_pages"] > 0
+    assert len(rec["tick_contexts"]) == len(rec["ticks"]) > 0
+    assert any(len(c) > 1 for c in rec["tick_contexts"])
+    for contexts, tick in zip(rec["tick_contexts"], rec["ticks"]):
+        assert sum(contexts) == tick[3]
+    assert any("vouched 21 of 24" in ln for ln in seen["compared"])
 
 
 @pytest.mark.parametrize(
