@@ -55,24 +55,32 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from adapt_tpu.ops.dispatch import on_tpu, pallas_interpret, resolve_prefer
+from adapt_tpu.ops.dispatch import (
+    device_cores,
+    on_tpu,
+    pallas_interpret,
+    resolve_prefer,
+)
 from adapt_tpu.ops.quantize import unpack_int4
 
 _VMEM = pltpu.VMEM
 _NEG_INF = -1e30
 
 
-def default_decode_split(num_blocks: int) -> int:
+def default_decode_split(num_blocks: int, cores: int = 1) -> int:
     """Auto-derived flash-decoding split factor for a cache of
-    ``num_blocks`` position blocks (pages, for the paged layout): the
-    largest power of two <= 8 that still leaves every split at least
-    two blocks of work. Short caches stay unsplit (the combine pass
-    would cost more than the parallelism buys); long-context slots fan
-    their KV stream across splits so the whole VPU/MXU participates
-    instead of one sequential stream. ``config.KernelConfig.
-    decode_split`` overrides it."""
+    ``num_blocks`` position blocks (pages, for the paged layout) on a
+    device of ``cores`` TensorCores: the largest power of two <= 8 and
+    <= ``cores`` that still leaves every split at least two blocks of
+    work. A split's axis is ``parallel``, which only another core can
+    take up: on ONE core the grid runs in order whatever its axes are
+    called, and a split adds a ragged step, three float32 partial
+    outputs and the combine pass (timed on a v5e at the benchmark's
+    three shapes, PERF.md section 6, PR 28: split 2 lost to 1 at every
+    one). Short caches stay unsplit on any device. ``config.
+    KernelConfig.decode_split`` overrides it."""
     s = 1
-    while s < 8 and num_blocks >= 4 * s:
+    while s < min(8, cores) and num_blocks >= 4 * s:
         s *= 2
     return s
 
@@ -81,11 +89,12 @@ def resolve_decode_split(num_blocks: int, split: int | None) -> int:
     """THE split-resolution rule every kernel dispatcher shares (decode
     / paged decode / paged verify — one definition, so the auto rule
     cannot fork across them): an explicit ``split`` wins; None
-    auto-derives on real TPUs and stays 1 off-TPU, where the
-    interpreter gains nothing from fan-out."""
+    auto-derives on real TPUs from the block count and the cores the
+    device reports, and stays 1 off-TPU, where the interpreter gains
+    nothing from fan-out."""
     if split is not None:
         return split
-    return default_decode_split(num_blocks) if on_tpu() else 1
+    return default_decode_split(num_blocks, device_cores()) if on_tpu() else 1
 
 #: Cache-position block per grid step for QUANTIZED caches. 1024 = 8
 #: sublanes x 128 lanes of the chunked scale view, the smallest block
@@ -150,7 +159,7 @@ def _supported(cache_len: int, block_k: int, quantized: bool) -> bool:
 
 
 def _attend_tile(q, k, v, ksc, vsc, live, m_scr, l_scr, acc_scr,
-                 sm_scale, packed):
+                 sm_scale, packed, kv_transposed=False):
     """One cache tile's online-softmax update — THE shared step body of
     every decode/verify/chunk kernel (split or not), so the int8 fused
     dequant, the int4 nibble unpack and the masking discipline cannot
@@ -158,22 +167,34 @@ def _attend_tile(q, k, v, ksc, vsc, live, m_scr, l_scr, acc_scr,
     native/int8, or (block_k, hd // 2) packed int4 (``packed``);
     ``ksc``/``vsc`` (1, block_k) f32 column scales or None; ``live``
     (gq, block_k) bool mask. Mutates the (gq, 1)/(gq, 1)/(gq, hd)
-    scratch refs in place."""
+    scratch refs in place. Every operand but ``live`` may carry LEADING
+    axes (the paged decode kernel's block of heads): the two products
+    batch over them and the softmax is elementwise, so a block of heads
+    is one call whose independent rows the scheduler overlaps — a loop
+    of single-head calls runs each head's chain (product, reduce, exp,
+    product) behind the last one's, at 4x the time on a v5e.
+    ``kv_transposed``: ``k``/``v`` arrive as (hd, block_k), positions on
+    the lanes (how a pool of rows narrower than a lane tile lives in
+    HBM); the two products contract the other axis and nothing else
+    changes."""
     if packed:
         # Unpack two nibbles per streamed int8 lane in VMEM — the HBM
         # stream stays 4-bit; only the registers see head_dim lanes.
         k = unpack_int4(k)
         v = unpack_int4(v)
+    lead = tuple(range(q.ndim - 2))
+    row, col = q.ndim - 2, q.ndim - 1
+    k_hd, v_pos = (row, col) if kv_transposed else (col, row)
     q = q.astype(jnp.float32)
     k = k.astype(jnp.float32)
     v = v.astype(jnp.float32)
     s = (
         jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
+            q, k, (((col,), (k_hd,)), (lead, lead)),
             preferred_element_type=jnp.float32,
         )
         * sm_scale
-    )  # (gq, block_k)
+    )  # (..., gq, block_k)
     if ksc is not None:
         # One f32 scale per column of this block: the per-vector scale
         # factors exactly OUT of the dot, applied to the small score
@@ -188,7 +209,7 @@ def _attend_tile(q, k, v, ksc, vsc, live, m_scr, l_scr, acc_scr,
     l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
     pv = p * vsc if vsc is not None else p
     acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-        pv, v, (((1,), (0,)), ((), ())),
+        pv, v, (((col,), (v_pos,)), (lead, lead)),
         preferred_element_type=jnp.float32,
     )
 
@@ -211,17 +232,13 @@ def _decode_kernel(
     quantized,
     has_vf,
     packed=False,
-    lead=(0,),
 ):
     """One (batch, kv_head) row: stream cache blocks innermost, online
     softmax in scratch. ``q_ref`` (1, gq, hd) — gq = GQA group rows,
     sublane-padded; ``k_ref``/``v_ref`` (1, block_k, hd) int8 or native
     (``packed``: (1, block_k, hd // 2) int4 nibbles, unpacked in VMEM);
     scale tiles (1, 8, 128) f32 chunked views covering this block's
-    positions row-major. ``lead`` indexes the unit leading axes off the
-    K/V/scale tiles — ``(0, 0)`` for the paged pools' (1, 1, page, hd)
-    blocks (a LOAD with indices; Mosaic refuses a ``ref.at`` view whose
-    lane width is under a tile, which head_dim 64 is).
+    positions row-major.
     ``idx_ref``/``vf_ref`` whole (b * kv_h,) SMEM
     vectors, this row's scalar read by ``program_id(0)`` (Mosaic
     refuses a (1,) block of a longer rank-1 array)."""
@@ -247,9 +264,9 @@ def _decode_kernel(
         if has_vf:
             live = jnp.logical_and(live, cols >= vf)
         _attend_tile(
-            q_ref[0], k_ref[lead], v_ref[lead],
-            ksc_ref[lead].reshape(1, block_k) if quantized else None,
-            vsc_ref[lead].reshape(1, block_k) if quantized else None,
+            q_ref[0], k_ref[0], v_ref[0],
+            ksc_ref[0].reshape(1, block_k) if quantized else None,
+            vsc_ref[0].reshape(1, block_k) if quantized else None,
             live, m_scr, l_scr, acc_scr, sm_scale, packed,
         )
 
@@ -280,7 +297,6 @@ def _decode_split_kernel(
     quantized,
     has_vf,
     packed=False,
-    lead=(0,),
 ):
     """Flash-decoding split variant of :func:`_decode_kernel`: grid
     (b * kv_h, split, bps) — each (row, split) streams ITS ``bps``
@@ -317,9 +333,9 @@ def _decode_split_kernel(
         if has_vf:
             live = jnp.logical_and(live, cols >= vf)
         _attend_tile(
-            q_ref[0], k_ref[lead], v_ref[lead],
-            ksc_ref[lead].reshape(1, block_k) if quantized else None,
-            vsc_ref[lead].reshape(1, block_k) if quantized else None,
+            q_ref[0], k_ref[0], v_ref[0],
+            ksc_ref[0].reshape(1, block_k) if quantized else None,
+            vsc_ref[0].reshape(1, block_k) if quantized else None,
             live, m_scr, l_scr, acc_scr, sm_scale, packed,
         )
 

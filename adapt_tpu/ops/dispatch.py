@@ -24,6 +24,12 @@ def on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
+def device_cores() -> int:
+    """TensorCores the first device reports (a v5e: 1; 1 where the
+    backend does not say)."""
+    return getattr(jax.devices()[0], "num_cores", None) or 1
+
+
 def pallas_interpret() -> bool:
     """THE ``interpret=`` decision for every ``pallas_call``: compiled
     on a TPU, interpreted on the CPU backend, an error anywhere else —
@@ -40,14 +46,25 @@ def pallas_interpret() -> bool:
     )
 
 
+def _books(op: str) -> dict[str, float]:
+    return _KERNEL_DISPATCHES.setdefault(
+        op, {"pallas": 0.0, "xla": 0.0, "last": 0.0}
+    )
+
+
 def record_kernel_dispatch(op: str, path: str) -> None:
     """Record one dispatch resolution for ``op`` (``"pallas"`` or
     ``"xla"``)."""
-    d = _KERNEL_DISPATCHES.setdefault(
-        op, {"pallas": 0.0, "xla": 0.0, "last": 0.0}
-    )
+    d = _books(op)
     d[path] += 1.0
     d["last"] = 1.0 if path == "pallas" else 0.0
+
+
+def record_kernel_choice(op: str, **choices: float) -> None:
+    """Book what a kernel dispatcher derived for ``op`` from its
+    operands (heads a grid step, the split), beside the path it took:
+    the newest resolution's values, so a run can say what engaged."""
+    _books(op).update({k: float(v) for k, v in choices.items()})
 
 
 def kernel_dispatch_stats() -> dict[str, dict[str, float]]:

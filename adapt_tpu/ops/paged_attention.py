@@ -12,24 +12,44 @@ with ``slots x max_len``.
 The TPU part: attention over a paged cache must NOT gather pages into a
 contiguous buffer first (that would write + re-read the whole window,
 doubling HBM traffic — the exact cost paging exists to avoid). The
-Pallas kernel here streams pages directly: the page table rides as a
+Pallas kernels here stream pages directly: the page table rides as a
 SCALAR-PREFETCH operand (``pltpu.PrefetchScalarGridSpec``), and the K/V
 ``index_map`` consults it to pick each grid step's physical page — the
 DMA engine fetches pool blocks in table order while the online-softmax
-state carries across them. The kernel body is ``ops/decode_attention``'s
-(same masks, same skip of dead blocks past ``index``); only the block
-FETCH differs, which is the whole point: one attention discipline, two
-memory layouts.
+state carries across them. The step body is ``ops/decode_attention``'s
+``_attend_tile`` (same masks, same float32 softmax state, same fused
+dequant); only the block FETCH differs, which is the whole point: one
+attention discipline, two memory layouts.
+
+The DECODE kernel's grid is ``(slots, head blocks, pages a slot)`` and
+one step covers one page of EVERY KV head of its block — a
+``(1, heads, page, head_dim)`` block of K and of V, ``heads`` the
+largest divisor of the head count that fits a stated VMEM budget
+(``decode_heads_per_step``: 16 of 16 at head_dim 128, 25 of 25 at 64;
+derived from the operands, the per-shard ones under tensor
+parallelism). A grid step has a price before it moves a byte and its
+body is a chain of dependent operations (product, reduce, exp,
+product); a block of heads pays the price once per ~1 MB and gives the
+scheduler independent chains to overlap, in ONE batched
+``_attend_tile`` (measured on a v5e, PERF.md section 6, PR 28). The
+slots' live positions are prefetched beside the table and the index
+map CLAMPS the page axis to each slot's live window: a step past the
+last live page (or before the first, for ragged rows) names the block
+already resident, so the pipeline issues no copy for it, and the body
+skips it (``pl.when``). The verify and chunk kernels keep one head a
+step (``_verify_impl``, ``_chunk_impl``).
 
 The grid's page axis can additionally FLASH-SPLIT (``split`` on every
 dispatcher, ``config.KernelConfig.decode_split``): each (row, split)
 grid point streams its own run of the slot's pages with independent
 online-softmax scratch and emits unnormalized partials (accumulator +
 running max + denominator); a single-pass rescale combine reduces them
-— so a long-context slot's KV stream fans across compute units instead
-of one sequential page walk. ``split=1`` is the original kernel
-bit-exactly; the last split may be ragged (clamped in the index maps,
-masked in the kernel).
+— so a long-context slot's KV stream can fan across TensorCores. On a
+one-core chip the automatic split is 1 (``decode_attention.
+default_decode_split``: the split's axis is ``parallel``, and only
+another core can take it up). ``split=1`` is the unsplit kernel; the
+last split may be ragged (clamped in the index maps, masked in the
+kernel).
 
 Layouts:
 - pool: (num_pages, kv_heads, page_size, head_dim) in the native dtype
@@ -59,7 +79,8 @@ Layouts:
   sizes.
 - page table: (slots, pages_per_slot) int32 physical page ids; entries
   past a slot's live window may be ANY valid page id (their positions
-  are masked, their blocks' compute skipped — point them at page 0).
+  are masked, their blocks' compute skipped; the decode kernel does not
+  even read them — point them at page 0).
 - q: (slots, kv_heads, g, head_dim) group-folded, as in
   ``decode_attention``.
 
@@ -85,13 +106,16 @@ from adapt_tpu.ops.decode_attention import (
     DECODE_BLOCK_K,
     _attend_tile,
     _combine_splits,
-    _decode_kernel,
-    _decode_split_kernel,
     _init_softmax_scratch,
     check_head_parity,
     resolve_decode_split,
 )
-from adapt_tpu.ops.dispatch import on_tpu, pallas_interpret, resolve_prefer
+from adapt_tpu.ops.dispatch import (
+    on_tpu,
+    pallas_interpret,
+    record_kernel_choice,
+    resolve_prefer,
+)
 from adapt_tpu.ops.quantize import unpack_int4
 
 _VMEM = pltpu.VMEM
@@ -134,7 +158,10 @@ def append_kv_paged(pool, new, phys, off):
       buffer lives with the page axis on the lanes, and that scatter
       costs two relayouts. A ``dynamic_update_slice`` of one
       (1, kv_h, 1, w) slab per token under a ``fori_loop`` is in place
-      in ANY layout; one relayout to the kernel's stays (ROADMAP). The
+      in ANY layout. The decode kernel reads that layout as it is
+      (``_paged_impl``: pages swapped to (w, page), a bitcast); the
+      verify and chunk kernels still pin row-major and cost their
+      programs one relayout a plane (ROADMAP A2). The
       slab is sliced straight out of ``new`` — a transposed or reshaped
       update operand drags the carry's layout with it and the copies
       come back.
@@ -271,9 +298,62 @@ def paged_attention_reference(q, k_pool, v_pool, page_table, index,
     )
 
 
-@functools.partial(jax.jit, static_argnames=("split",))
+#: What one grid step of the decode kernel may hold in VMEM. Mosaic
+#: scopes a kernel to 16 MB on a TensorCore; the step plans for half of
+#: it. What Mosaic itself counts against the 16 MB, read from its
+#: refusals of oversized blocks when compiling for a described v5e, is
+#: the pipelined blocks plus 0.2-0.7 MB; the other half is for what
+#: neither sum sees (the compiler's own scratch and spills).
+DECODE_STEP_VMEM_BUDGET = 8 * 2 ** 20
+
+
+def _lanes(width: int) -> int:
+    """A row's width in VMEM: whole 128-lane tiles."""
+    return -(-width // 128) * 128
+
+
+def decode_step_vmem_bytes(heads, page, row_width, itemsize, scales,
+                           gq=8, head_dim=None) -> int:
+    """VMEM one grid step of the decode kernel asks for when it covers
+    ``heads`` KV heads of one page: the K and V blocks (and their scale
+    tiles), each double-buffered by the pipeline; q and the output
+    block, double-buffered; the per-head softmax state; and the body's
+    float32 working set (one head's K and V widened on their way into
+    the products, the block's score-shaped rows). K/V rows narrower
+    than a lane tile (head_dim 64) arrive transposed, the page on the
+    lanes, and pad nothing; q, the output and the state do."""
+    hd = _lanes(head_dim or row_width)
+    stream = 2 * 2 * heads * page * row_width * itemsize
+    if scales:
+        stream += 2 * 2 * heads * max(page // 128, 8) * 128 * 4
+    rows = 2 * 2 * heads * gq * hd * 4
+    state = heads * (2 * 8 * 128 + gq * hd) * 4
+    working = (2 * page * hd + heads * 6 * gq * page) * 4
+    return stream + rows + state + working
+
+
+def decode_heads_per_step(kv_heads, page, row_width, itemsize, scales,
+                          gq=8, head_dim=None) -> int:
+    """KV heads one grid step of the decode kernel covers: the largest
+    divisor of ``kv_heads`` (the per-shard count under tensor
+    parallelism) whose step fits ``DECODE_STEP_VMEM_BUDGET``. A grid
+    step costs 0.15-0.25 us on a v5e before it moves a byte and its
+    body is a chain of dependent operations, so a step should cover as
+    many independent heads as fit: 16 heads of a bf16 page at head_dim
+    128 are 1 MB of K and V, 25 heads at head_dim 64 0.8 MB; an int8
+    pool at 1024-position pages comes out at 8 of 16 (5 of 25) by the
+    same sum. Derived from the operands, never set."""
+    for heads in range(kv_heads, 1, -1):
+        if kv_heads % heads == 0 and decode_step_vmem_bytes(
+            heads, page, row_width, itemsize, scales, gq, head_dim
+        ) <= DECODE_STEP_VMEM_BUDGET:
+            return heads
+    return 1
+
+
+@functools.partial(jax.jit, static_argnames=("heads", "split"))
 def _paged_impl(q, k_pool, v_pool, k_scales, v_scales, page_table, index,
-                valid_from, split=1):
+                valid_from, heads=1, split=1):
     b, kvh, g, hd = q.shape
     page = k_pool.shape[2]
     hdk = k_pool.shape[3]  # head_dim // 2 for packed int4 pools
@@ -285,39 +365,65 @@ def _paged_impl(q, k_pool, v_pool, k_scales, v_scales, page_table, index,
     if pad_g:
         q = jnp.pad(q, ((0, 0), (0, 0), (0, pad_g), (0, 0)))
     gq = g + pad_g
-    qf = q.reshape(b * kvh, gq, hd)
-    idx = jnp.repeat(
-        jnp.broadcast_to(jnp.asarray(index, jnp.int32).reshape(-1), (b,)),
-        kvh,
-    )
-    sm_scale = 1.0 / (hd ** 0.5)
     bps = -(-pages_per_slot // split)  # pages per split (last may be ragged)
 
-    def blk(bh, *js):
-        if split == 1:
-            (j,) = js
-            return j
-        s_id, j = js
-        # Ragged tail clamps to a valid table column (masked in-kernel).
-        return jnp.minimum(s_id * bps + j, pages_per_slot - 1)
-
-    # Scalar-prefetch operand 0 is the page table (2-D, consumed by the
-    # index maps); the per-row idx / valid_from vectors are whole-array
-    # SMEM inputs the kernel indexes by program_id(0).
-    def q_map(bh, *js_table):
-        return (bh, 0, 0)
-
-    def kv_map(bh, *js_table):
-        *js, table_ref = js_table
-        return (table_ref[bh // kvh, blk(bh, *js)], bh % kvh, 0, 0)
-
-    in_specs = [
-        pl.BlockSpec((1, gq, hd), q_map, memory_space=_VMEM),
-        pl.BlockSpec((1, 1, page, hdk), kv_map, memory_space=_VMEM),
-        pl.BlockSpec((1, 1, page, hdk), kv_map, memory_space=_VMEM),
-        pl.BlockSpec(memory_space=pltpu.SMEM),
+    # Scalar prefetch: the page table, each slot's newest live position
+    # and, for ragged rows, its oldest. The index maps read all three;
+    # the body reads the positions again for its masks.
+    prefetch = [
+        jnp.asarray(page_table, jnp.int32),
+        jnp.broadcast_to(jnp.asarray(index, jnp.int32).reshape(-1), (b,)),
     ]
-    operands = [qf, k_pool, v_pool, idx]
+    if has_vf:
+        prefetch.append(jnp.asarray(valid_from, jnp.int32))
+
+    def row_map(s, hb, *rest):
+        return (s, hb, 0, 0)
+
+    def kv_map(s, hb, *rest):
+        # rest: the page axis (split: the split and its page), then the
+        # prefetched refs.
+        n = 1 if split == 1 else 2
+        js, (table_ref, idx_ref, *vf_ref) = rest[:n], rest[n:]
+        jg = js[0] if split == 1 else js[0] * bps + js[1]
+        # The walk stops at the slot's live window: a step past its last
+        # live page (or before its first) names the block the step
+        # before it held, so the pipeline issues no copy for it; a dead
+        # row (negative index) names its first table entry throughout.
+        last = jnp.minimum(
+            jnp.maximum(idx_ref[s], 0) // page, pages_per_slot - 1
+        )
+        first = jnp.clip(vf_ref[0][s] // page, 0, last) if has_vf else 0
+        return (table_ref[s, jnp.clip(jg, first, last)], hb, 0, 0)
+
+    # A pool of rows narrower than a lane tile (head_dim 64) lives in
+    # HBM with the page axis on the lanes (``append_kv_paged``), and a
+    # Mosaic operand is pinned row-major: read as (pages, heads, page,
+    # hd) the whole plane was relaid out at every step (30-36% of the
+    # device's time in the GPT-2-XL cells on a v5e). Swapped to (pages,
+    # heads, hd, page) row-major IS the layout the plane has, so the
+    # swap moves nothing, a block's rows fill their lanes, and the body
+    # contracts the other axis (``kv_transposed``). Packed int4 rows
+    # unpack along their lanes and stay as stored.
+    transposed = hdk % 128 != 0 and not packed
+    kv_block = (1, heads, hdk, page) if transposed else (1, heads, page, hdk)
+    if transposed:
+        k_pool, v_pool = jnp.swapaxes(k_pool, 2, 3), jnp.swapaxes(v_pool, 2, 3)
+    in_specs = [
+        pl.BlockSpec((1, heads, gq, hd), row_map, memory_space=_VMEM),
+        pl.BlockSpec(kv_block, kv_map, memory_space=_VMEM),
+        pl.BlockSpec(kv_block, kv_map, memory_space=_VMEM),
+    ]
+    # The pools stay in HBM and the kernel streams them from there. Left
+    # to itself XLA may park a plane small enough in fast memory on its
+    # way in (it did GPT-2-XL's 23 MB planes while they were still
+    # relaid out: the kernel then read at 107% of its bytes floor on a
+    # v5e, its time having left the fetch to a copy). (The interpreter
+    # knows no memory spaces.)
+    in_hbm = (lambda x: x) if pallas_interpret() else functools.partial(
+        pltpu.with_memory_space_constraint, memory_space=pltpu.HBM
+    )
+    operands = [q, in_hbm(k_pool), in_hbm(v_pool)]
     if quantized:
         # (pages, kvh, P, 1) f32 scale pools -> (pages, kvh, P/128,
         # 128) CHUNKED views (position = row*128 + lane — the dense
@@ -327,145 +433,157 @@ def _paged_impl(q, k_pool, v_pool, k_scales, v_scales, page_table, index,
         # its bytes (one f32 per int8 vector).
         for s in (k_scales, v_scales):
             operands.append(
-                s.reshape(s.shape[0], kvh, page // 128, 128)
+                in_hbm(s.reshape(s.shape[0], kvh, page // 128, 128))
             )
             in_specs.append(
                 pl.BlockSpec(
-                    (1, 1, page // 128, 128), kv_map, memory_space=_VMEM
+                    (1, heads, page // 128, 128), kv_map,
+                    memory_space=_VMEM,
                 )
             )
-    if has_vf:
-        operands.append(jnp.repeat(jnp.asarray(valid_from, jnp.int32), kvh))
-        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
-
-    scratch = [
-        pltpu.VMEM((gq, 1), jnp.float32),
-        pltpu.VMEM((gq, 1), jnp.float32),
-        pltpu.VMEM((gq, hd), jnp.float32),
-    ]
-    if split == 1:
-        kernel = functools.partial(
-            _paged_kernel,
-            block_k=page,
-            num_kv=pages_per_slot,
-            sm_scale=sm_scale,
-            quantized=quantized,
-            has_vf=has_vf,
-            packed=packed,
-        )
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(b * kvh, pages_per_slot),
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, gq, hd), q_map, memory_space=_VMEM),
-            scratch_shapes=scratch,
-        )
-        out = pl.pallas_call(
-            kernel,
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((b * kvh, gq, hd), q.dtype),
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "arbitrary")
-            ),
-            interpret=pallas_interpret(),
-        )(jnp.asarray(page_table, jnp.int32), *operands)
-        return out.reshape(b, kvh, gq, hd)[:, :, :g, :]
-
-    # Flash-decoding split over the slot's page list: each (row, split)
-    # streams its own run of table entries and emits partials; the
-    # single-pass rescale combine reduces them (dense discipline).
-    def part_map(bh, s_id, j, table_ref):
-        del j, table_ref
-        return (bh, s_id, 0, 0)
 
     kernel = functools.partial(
-        _paged_split_kernel,
-        block_k=page,
-        num_kv=pages_per_slot,
-        bps=bps,
-        sm_scale=sm_scale,
+        _paged_kernel,
+        page=page,
+        num_pages=pages_per_slot,
+        bps=None if split == 1 else bps,
+        sm_scale=1.0 / (hd ** 0.5),
         quantized=quantized,
         has_vf=has_vf,
         packed=packed,
+        transposed=transposed,
     )
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b * kvh, split, bps),
-        in_specs=in_specs,
-        out_specs=(
-            pl.BlockSpec((1, 1, gq, hd), part_map, memory_space=_VMEM),
-            pl.BlockSpec((1, 1, gq, hd), part_map, memory_space=_VMEM),
-            pl.BlockSpec((1, 1, gq, hd), part_map, memory_space=_VMEM),
-        ),
-        scratch_shapes=scratch,
-    )
+    scratch = [
+        pltpu.VMEM((heads, gq, 1), jnp.float32),
+        pltpu.VMEM((heads, gq, 1), jnp.float32),
+        pltpu.VMEM((heads, gq, hd), jnp.float32),
+    ]
+    head_blocks = kvh // heads
+    if split == 1:
+        out = pl.pallas_call(
+            kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=len(prefetch),
+                grid=(b, head_blocks, pages_per_slot),
+                in_specs=in_specs,
+                out_specs=pl.BlockSpec(
+                    (1, heads, gq, hd), row_map, memory_space=_VMEM
+                ),
+                scratch_shapes=scratch,
+            ),
+            out_shape=jax.ShapeDtypeStruct((b, kvh, gq, hd), q.dtype),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary")
+            ),
+            interpret=pallas_interpret(),
+        )(*prefetch, *operands)
+        return out[:, :, :g, :]
+
+    # Flash-decoding split over the slot's page list: each (slot, head
+    # block, split) streams its own run of table entries and emits
+    # partials; the single-pass rescale combine reduces them (dense
+    # discipline).
+    def part_map(s, hb, s_id, *rest):
+        return (s, s_id, hb, 0, 0)
+
+    part = pl.BlockSpec((1, 1, heads, gq, hd), part_map, memory_space=_VMEM)
+    part_shape = jax.ShapeDtypeStruct((b, split, kvh, gq, hd), jnp.float32)
     o_p, m_p, l_p = pl.pallas_call(
         kernel,
-        grid_spec=grid_spec,
-        out_shape=(
-            jax.ShapeDtypeStruct((b * kvh, split, gq, hd), jnp.float32),
-            jax.ShapeDtypeStruct((b * kvh, split, gq, hd), jnp.float32),
-            jax.ShapeDtypeStruct((b * kvh, split, gq, hd), jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch),
+            grid=(b, head_blocks, split, bps),
+            in_specs=in_specs,
+            out_specs=(part, part, part),
+            scratch_shapes=scratch,
         ),
+        out_shape=(part_shape, part_shape, part_shape),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
+            dimension_semantics=(
+                "parallel", "parallel", "parallel", "arbitrary"
+            )
         ),
         interpret=pallas_interpret(),
-    )(jnp.asarray(page_table, jnp.int32), *operands)
-    out = _combine_splits(o_p, m_p, l_p, q.dtype)
-    return out.reshape(b, kvh, gq, hd)[:, :, :g, :]
+    )(*prefetch, *operands)
+    return _combine_splits(o_p, m_p, l_p, q.dtype)[:, :, :g, :]
 
 
-def _paged_kernel(table_ref, q_ref, k_ref, v_ref, idx_ref, *refs, block_k,
-                  num_kv, sm_scale, quantized, has_vf, packed=False):
-    """Scalar-prefetch wrapper: the table ref arrives first (consumed by
-    the index_maps, unused in the body) and the K/V tiles arrive as
-    (1, 1, page, hd) — ``lead=(0, 0)`` loads them past the page and head
-    axes and the contiguous decode kernel body does the rest (one
-    attention discipline, two layouts). Quantized pools add chunked
-    (1, 1, page/128, 128) f32 scale tiles, table-addressed like the int8
-    payload; ``_decode_kernel``'s quantized branch applies them to the
-    score/probability columns in VMEM — the fused dequant (``packed``:
-    int4 nibble pools, unpacked there too)."""
-    del table_ref
-    _decode_kernel(
-        q_ref,
-        k_ref,
-        v_ref,
-        idx_ref,
-        *refs,
-        block_k=block_k,
-        num_kv=num_kv,
-        sm_scale=sm_scale,
-        quantized=quantized,
-        has_vf=has_vf,
-        packed=packed,
-        lead=(0, 0),
-    )
+def _paged_kernel(table_ref, idx_ref, *refs, page, num_pages, bps, sm_scale,
+                  quantized, has_vf, packed=False, transposed=False):
+    """One page of ``heads`` KV heads of one slot per grid step: grid
+    (slots, head blocks, pages), or (slots, head blocks, split, bps)
+    when ``bps`` is set — the FLASH-SPLIT form, which emits each
+    split's unnormalized partials (accumulator, running max,
+    denominator) for ``_combine_splits`` instead of a normalized
+    output. The scalar-prefetched table is consumed by the index maps;
+    the prefetched positions give this slot's live window
+    ``[valid_from, index]``. K/V arrive as (1, heads, page, hd) blocks
+    ((1, heads, hd, page) when ``transposed``: head_dim 64) and the body is ONE ``decode_attention._attend_tile`` over the
+    block, the head axis leading (one attention discipline: its masks,
+    its float32 softmax state, its fused int8 dequant and int4 unpack),
+    with per-head state in (heads, gq, .) scratch. Quantized pools add chunked
+    (1, heads, page/128, 128) f32 scale tiles, table-addressed like the
+    payload. A page outside the live window — every page of a dead row
+    (negative index), the ragged tail of the last split — skips the
+    body, and its step fetched nothing (``_paged_impl``'s ``kv_map``)."""
+    del table_ref  # consumed by the index maps
+    refs = list(refs)
+    vf_ref = refs.pop(0) if has_vf else None
+    q_ref, k_ref, v_ref = refs[:3]
+    del refs[:3]
+    ksc_ref = refs.pop(0) if quantized else None
+    vsc_ref = refs.pop(0) if quantized else None
+    if bps is None:
+        o_ref, m_scr, l_scr, acc_scr = refs
+        j = pl.program_id(2)
+        jg, last_j = j, num_pages - 1
+    else:
+        o_ref, m_ref, l_ref, m_scr, l_scr, acc_scr = refs
+        j = pl.program_id(3)
+        jg, last_j = pl.program_id(2) * bps + j, bps - 1
+    heads, gq = q_ref.shape[1], q_ref.shape[2]
+    slot = pl.program_id(0)
+    idx = idx_ref[slot]
+    vf = vf_ref[slot] if has_vf else None
 
+    @pl.when(j == 0)
+    def _init():
+        _init_softmax_scratch(m_scr, l_scr, acc_scr)
 
-def _paged_split_kernel(table_ref, q_ref, k_ref, v_ref, idx_ref, *refs,
-                        block_k, num_kv, bps, sm_scale, quantized, has_vf,
-                        packed=False):
-    """Flash-split scalar-prefetch wrapper: grid (b * kv_h, split, bps)
-    — drop the table ref, load past the page/head axes and delegate to
-    the dense split kernel (partial emission + masked ragged tail)."""
-    del table_ref
-    _decode_split_kernel(
-        q_ref,
-        k_ref,
-        v_ref,
-        idx_ref,
-        *refs,
-        block_k=block_k,
-        num_kv=num_kv,
-        bps=bps,
-        sm_scale=sm_scale,
-        quantized=quantized,
-        has_vf=has_vf,
-        packed=packed,
-        lead=(0, 0),
-    )
+    def _step():
+        cols = jg * page + jax.lax.broadcasted_iota(
+            jnp.int32, (gq, page), 1
+        )
+        live = cols <= idx
+        if has_vf:
+            live = jnp.logical_and(live, cols >= vf)
+
+        _attend_tile(
+            q_ref[0], k_ref[0], v_ref[0],
+            ksc_ref[0].reshape(heads, 1, page) if quantized else None,
+            vsc_ref[0].reshape(heads, 1, page) if quantized else None,
+            live, m_scr, l_scr, acc_scr, sm_scale, packed, transposed,
+        )
+
+    live_block = jg * page <= idx
+    if bps is not None:
+        live_block = jnp.logical_and(live_block, jg < num_pages)
+    if has_vf:
+        live_block = jnp.logical_and(live_block, (jg + 1) * page > vf)
+    pl.when(live_block)(_step)
+
+    @pl.when(j == last_j)
+    def _emit():
+        if bps is None:
+            o_ref[0] = (
+                acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
+            ).astype(o_ref.dtype)
+        else:
+            # m/l broadcast across the lane axis so the partials share
+            # the accumulator's tiling; the combine reads lane 0.
+            o_ref[0, 0] = acc_scr[...]
+            m_ref[0, 0] = jnp.broadcast_to(m_scr[...], acc_scr.shape)
+            l_ref[0, 0] = jnp.broadcast_to(l_scr[...], acc_scr.shape)
 
 
 def _chunk_kernel(pages_ref, q_ref, k_ref, v_ref, pos0_ref, *refs,
@@ -1016,21 +1134,33 @@ def paged_attention(
     a ``shard_map`` (``_head_sharded``); the oracle needs none — GSPMD
     partitions its einsums. ``split`` is
     the flash-decoding split along the slot's page list (None = auto:
-    ``decode_attention.default_decode_split`` of pages_per_slot on a
-    real TPU, 1 off-TPU; 1 = the original single-stream kernel,
-    bit-exact). Grids/folds
-    derive from the given (per-shard, under TP) head count — q and pool
-    must agree (``decode_attention.check_head_parity``)."""
+    ``decode_attention.default_decode_split`` of pages_per_slot and the
+    device's cores on a real TPU — 1 on a one-core chip — and 1
+    off-TPU; 1 = the single-stream kernel). The heads one grid step
+    covers (``decode_heads_per_step``), the grid and the GQA fold
+    derive from the given (per-shard, under TP) operands — q and pool
+    must agree (``decode_attention.check_head_parity``) — and the
+    books say what was derived (``kernel_dispatch_stats()
+    ["paged_decode"]``: ``heads_per_step``, ``split``)."""
     check_head_parity(q.shape[1], pool_values(k_pool).shape[1])
     if resolve_prefer(
         "paged_decode", prefer, kernel_unsupported(q, k_pool), on_tpu()
     ):
         kv, vv, ks, vs = _split_pools(k_pool, v_pool)
+        heads = q.shape[1]
+        if head_shard is not None:
+            mesh, axis = head_shard
+            heads //= mesh.shape[axis]
+        heads = decode_heads_per_step(
+            heads, kv.shape[2], kv.shape[3], kv.dtype.itemsize,
+            ks is not None, q.shape[2] + (-q.shape[2]) % 8, q.shape[3],
+        )
+        split = resolve_decode_split(page_table.shape[1], split)
+        record_kernel_choice(
+            "paged_decode", heads_per_step=heads, split=split
+        )
         return _head_sharded(
-            functools.partial(
-                _paged_impl,
-                split=resolve_decode_split(page_table.shape[1], split),
-            ),
+            functools.partial(_paged_impl, heads=heads, split=split),
             head_shard,
             (q, kv, vv, ks, vs),
             (jnp.asarray(page_table, jnp.int32),

@@ -656,7 +656,14 @@ def main() -> int:
     print("\n".join(report))
     print("kernel dispatch table (trace-time resolutions, pallas/xla):")
     for op, d in sorted(kernel_dispatch_stats().items()):
-        print(f"  {op:<14} pallas {d['pallas']:.0f}  xla {d['xla']:.0f}")
+        derived = "".join(
+            f"  {k} {v:.0f}" for k, v in sorted(d.items())
+            if k not in ("pallas", "xla", "last")
+        )
+        print(
+            f"  {op:<14} pallas {d['pallas']:.0f}  xla {d['xla']:.0f}"
+            + derived
+        )
     if args.rehearse_cpu:
         print("rehearsal complete: no device was measured, no result line")
         return 0

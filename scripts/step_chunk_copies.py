@@ -73,7 +73,7 @@ def main(argv=None) -> int:
         from adapt_tpu.utils.compile_cache import ensure_compile_cache
 
         ensure_compile_cache()
-    lm, variables = lm_engine.build_model(model, config["dtype"], 1)
+    lm, variables, _ = mf.part_of(config, "builder")(model, config["dtype"], 1)
     srv = ContinuousBatcher(
         lm, variables,
         slots=serving["slots"], chunk=serving["chunk"],

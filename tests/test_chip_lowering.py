@@ -102,6 +102,39 @@ def test_paged_decode_lowers(as_tpu, split, dtype, page):
     )
 
 
+#: slots, kv heads, head_dim, pages a slot, pool pages: the deployments
+#: of ``cgpt1b3_batchgen`` and ``gpt2xl_chat`` (PERF.md section 4), the
+#: two widths the decode kernel folds (16 heads of 128, 25 of 64).
+_CELL_SHAPES = {
+    "cgpt1b3_batchgen": (24, 16, 128, 7, 169),
+    "gpt2xl_chat": (32, 25, 64, 3, 97),
+}
+
+
+def _cell_args(cell):
+    b, kvh, hd, pps, npages = _CELL_SHAPES[cell]
+    k = sds((npages, kvh, 128, hd))
+    return (
+        sds((b, kvh, 1, hd)), k, k, sds((b, pps), jnp.int32),
+        sds((b,), jnp.int32),
+    )
+
+
+@pytest.mark.parametrize("split", [1, None])
+@pytest.mark.parametrize("cell", sorted(_CELL_SHAPES))
+def test_paged_decode_lowers_at_cell_shapes(as_tpu, cell, split):
+    """The folded kernel at the cells' own shapes: every KV head of a
+    page in one grid step (16 and 25, the books say), ragged left or
+    not."""
+    args = _cell_args(cell)
+    for vf in ((), args[-1:]):
+        lower_for_tpu(
+            lambda *a: paged_attention(*a, split=split), *args, *vf
+        )
+    books = kernel_dispatch_stats()["paged_decode"]
+    assert books["heads_per_step"] == _CELL_SHAPES[cell][1]
+
+
 @pytest.mark.parametrize("tree_tail", [0, 2])
 @pytest.mark.parametrize("dtype,page", [("native", 128), ("int8", 1024)])
 def test_paged_verify_lowers(as_tpu, tree_tail, dtype, page):
@@ -249,6 +282,25 @@ def test_tp4_paged_decode_lowers_under_shard_map(as_tpu, devices):
         bare.trace(*args).lower(lowering_platforms=("tpu",))
 
 
+def test_tp4_paged_decode_lowers_at_cell_shape(as_tpu, devices):
+    """``cgpt1b3_batchgen``'s shape under tp=4: each shard's kernel sees
+    4 of the 16 heads and folds those."""
+    mesh = _tp_mesh(devices)
+    heads = NamedSharding(mesh, P(None, "tp"))
+    repl = NamedSharding(mesh, P())
+    sharded = jax.jit(
+        lambda q, k, v, t, i: paged_attention(
+            q, k, v, t, i, head_shard=(mesh, "tp")
+        ),
+        in_shardings=(heads, heads, heads, repl, repl), out_shardings=heads,
+    )
+    text = sharded.trace(*_cell_args("cgpt1b3_batchgen")).lower(
+        lowering_platforms=("tpu",)
+    ).as_text()
+    assert "tpu_custom_call" in text
+    assert kernel_dispatch_stats()["paged_decode"]["heads_per_step"] == 4.0
+
+
 def test_head_sharded_kernels_match_oracles(devices):
     """Interpreter parity of the shard_map route itself: per-shard
     kernels over a tp=4 head split equal the single-device oracles
@@ -342,6 +394,43 @@ def no_persistent_cache():
     compilation_cache.reset_cache()
 
 
+@pytest.mark.parametrize("split", [1, 2])
+@pytest.mark.parametrize("shape", [
+    (24, 16, 128, 7, 169, 128, "native"),  # cgpt1b3_batchgen
+    (8, 25, 64, 7, 57, 128, "native"),  # gpt2xl_doc
+    (32, 25, 64, 3, 97, 128, "native"),  # gpt2xl_chat
+    (8, 16, 128, 2, 17, 1024, "int8"),  # 8 of 16 heads a step
+], ids=["cgpt1b3_batchgen", "gpt2xl_doc", "gpt2xl_chat", "int8-p1024"])
+def test_folded_paged_decode_compiles_for_v5e(
+    as_tpu, one_chip, no_persistent_cache, shape, split
+):
+    """Mosaic's own compile of the folded decode kernel (the lowering
+    above stops before it): the block of every head that
+    ``decode_heads_per_step`` derives fits the scoped VMEM of a v5e,
+    head_dim 64 loads with indices, and the operation keeps the name
+    the benchmark's readers sum (``_paged_impl``)."""
+    b, kvh, hd, pps, npages, page, dtype = shape
+
+    def on_chip(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    k = on_chip((npages, kvh, page, hd))
+    if dtype == "int8":
+        k = (
+            on_chip((npages, kvh, page, hd), jnp.int8),
+            on_chip((npages, kvh, page, 1), jnp.float32),
+        )
+    text = jax.jit(
+        lambda q, k, v, t, i, vf: paged_attention(
+            q, k, v, t, i, vf, split=split
+        )
+    ).lower(
+        on_chip((b, kvh, 1, hd)), k, k, on_chip((b, pps), jnp.int32),
+        on_chip((b,), jnp.int32), on_chip((b,), jnp.int32),
+    ).compile().as_text()
+    assert re.search(r"%_paged_impl[.\d]* = .*tpu_custom_call", text)
+
+
 def _pool_copies(text, shape):
     """``(relayouts, moves)`` of pool-shaped buffers in a compiled
     program's text: a ``copy`` (or a ``copy-start`` whose two layouts
@@ -384,9 +473,10 @@ _XL = ((57, 25, 128, 64), 1600, 25, 6400, 8, 7)  # gpt2xl_doc
     (_CGPT, "scan8", 0),
     (_CGPT, "verify", 0),
     # head_dim 64 lives with the 128-wide page axis on the lanes and
-    # takes the row loop; one relayout per plane to the kernel's
-    # row-major stays (ROADMAP).
-    (_XL, "step", 1),
+    # takes the row loop; the decode kernel reads that layout as it is
+    # (pages swapped to (hd, page): a bitcast), so nothing is relaid
+    # out (one relayout a plane until PR 28).
+    (_XL, "step", 0),
 ], ids=["hd128-step", "hd128-scan8", "hd128-verify", "hd64-step"])
 def test_pool_write_compiles_without_pool_relayout(
     as_tpu, one_chip, no_persistent_cache, deploy, form, relayouts_per_plane

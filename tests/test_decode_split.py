@@ -86,10 +86,38 @@ def test_int4_quantize_kv_vectors_shapes_and_error():
         quantize_kv_vectors(t[..., :15], "int4")
 
 
-def test_default_decode_split_rule():
-    assert [default_decode_split(n) for n in (1, 2, 3, 4, 8, 16, 64)] == [
-        1, 1, 1, 2, 4, 8, 8,
-    ]
+@pytest.mark.parametrize("cores,want", [
+    # One core (a v5e): the chip's A/B kept the kernels unsplit (PERF.md
+    # section 6, PR 28).
+    (1, [1, 1, 1, 1, 1, 1, 1]),
+    (2, [1, 1, 1, 2, 2, 2, 2]),
+    # Cores to spare: every split keeps at least two blocks, 8 at most.
+    (8, [1, 1, 1, 2, 4, 8, 8]),
+    (16, [1, 1, 1, 2, 4, 8, 8]),
+])
+def test_default_decode_split_rule(cores, want):
+    assert [
+        default_decode_split(n, cores) for n in (1, 2, 3, 4, 8, 16, 64)
+    ] == want
+
+
+def test_resolve_decode_split_reads_blocks_and_cores(monkeypatch):
+    """An explicit split wins; the automatic one is 1 off-TPU, and on a
+    TPU ``default_decode_split`` of the block count and the cores the
+    device reports (the benchmark's 7 pages a slot: 1 on one core)."""
+    import importlib
+
+    # ``adapt_tpu.ops`` exports the dispatcher under the module's name.
+    da = importlib.import_module("adapt_tpu.ops.decode_attention")
+    assert da.resolve_decode_split(7, 4) == 4
+    assert da.resolve_decode_split(64, None) == 1  # the CPU backend
+    monkeypatch.setattr(da, "on_tpu", lambda: True)
+    for cores, want in ((1, 1), (2, 2), (8, 8)):
+        monkeypatch.setattr(da, "device_cores", lambda cores=cores: cores)
+        assert da.resolve_decode_split(64, None) == want
+    monkeypatch.setattr(da, "device_cores", lambda: 1)
+    assert da.resolve_decode_split(7, None) == 1
+    assert da.resolve_decode_split(7, 2) == 2
 
 
 # -- ops: interpreter parity, every new branch -------------------------------

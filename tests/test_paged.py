@@ -188,7 +188,7 @@ def _quantized_pool(key, npages, kvh, page, hd):
 def test_paged_kernel_quantized_matches_oracle(rng):
     """Quantized ``_paged_kernel``: scale tiles ride the scalar-prefetch
     pipeline (table-addressed like the int8 payload) into the shared
-    ``_decode_kernel`` quantized branch — interpreter parity vs the
+    ``_attend_tile`` quantized branch — interpreter parity vs the
     gather oracle (which itself reduces to the contiguous quantized
     decode oracle), with and without ragged valid_from."""
     b, kvh, g, hd, page, npages = 2, 2, 3, 64, 128, 16
@@ -256,6 +256,112 @@ def test_paged_chunk_kernel_quantized_matches_oracle(rng):
             np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5,
             err_msg=f"pos0={pos0}",
         )
+
+
+# One page of every head that fits: the folded decode kernel. Rows of
+# one call: ragged, ending exactly on a page's last position, starting
+# a page, one token, and dead (negative index; its table maps live
+# pages of other rows, which must not leak in).
+_FOLD_SHAPES = {  # kv_heads, g, head_dim
+    "h16-hd128": (16, 1, 128),
+    "h25-hd64": (25, 1, 64),
+    "gqa4x4-hd128": (4, 4, 128),
+}
+
+
+@pytest.mark.parametrize("ragged_left", [False, True], ids=["vf0", "vf"])
+@pytest.mark.parametrize("split", [1, 2, None], ids=["s1", "s2", "auto"])
+@pytest.mark.parametrize("pool", ["native", "int8-p1024"])
+@pytest.mark.parametrize("shape", sorted(_FOLD_SHAPES))
+def test_folded_paged_kernel_matches_oracle(shape, pool, split, ragged_left):
+    kvh, g, hd = _FOLD_SHAPES[shape]
+    page, pps = (128, 4) if pool == "native" else (1024, 2)
+    span = page * pps
+    index = np.asarray(
+        [span - page // 2 - 3, page - 1, page, 0, -1], np.int32
+    )
+    b = len(index)
+    npages = b * pps + 1
+    rs = np.random.RandomState(kvh + hd + pps)
+    table = jnp.asarray(
+        1 + rs.permutation(npages - 1).reshape(b, pps), jnp.int32
+    )
+    key = jax.random.PRNGKey(kvh * hd + pps)
+    q = jax.random.normal(key, (b, kvh, g, hd))
+    kp = jax.random.normal(jax.random.fold_in(key, 1), (npages, kvh, page, hd))
+    vp = jax.random.normal(jax.random.fold_in(key, 2), (npages, kvh, page, hd))
+    if pool != "native":
+        kp, vp = quantize_kv_vectors(kp), quantize_kv_vectors(vp)
+    # Row 0's window starts inside its second page (the first is dead
+    # from the left), row 2's on its own last position.
+    vf = jnp.asarray([page + 7, 5, page, 0, 0], jnp.int32) if ragged_left else None
+    idx = jnp.asarray(index)
+    ref = paged_attention_reference(q, kp, vp, table, idx, vf)
+    out = paged_attention(
+        q, kp, vp, table, idx, vf, prefer="pallas", split=split
+    )
+    live = index >= 0
+    np.testing.assert_allclose(
+        np.asarray(out)[live], np.asarray(ref)[live], rtol=2e-5, atol=2e-5
+    )
+    assert np.isfinite(np.asarray(out)).all()  # the dead row: finite
+
+
+#: slots, kv_heads, head_dim, pages a slot, page, itemsize, scale planes
+#: -> heads a grid step. The three cells' deployments (PERF.md section
+#: 4) take every head; an int8 pool at 1024-position pages halves by the
+#: same sum.
+_HEADS_PER_STEP = {
+    "cgpt1b3_batchgen": ((16, 128, 128, 2, False), 16),
+    "gpt2xl_doc": ((25, 128, 64, 2, False), 25),
+    "gpt2xl_chat": ((25, 128, 64, 2, False), 25),
+    "int8-p1024-hd128": ((16, 1024, 128, 1, True), 8),
+    "int8-p1024-hd64": ((25, 1024, 64, 1, True), 5),
+    "tp4-shard-of-16": ((4, 128, 128, 2, False), 4),
+    "prime-heads-too-wide": ((7, 4096, 128, 2, False), 1),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_HEADS_PER_STEP))
+def test_decode_heads_per_step_is_derived_from_the_operands(case):
+    from adapt_tpu.ops.paged_attention import (
+        DECODE_STEP_VMEM_BUDGET,
+        decode_heads_per_step,
+        decode_step_vmem_bytes,
+    )
+
+    (kvh, page, width, itemsize, scales), want = _HEADS_PER_STEP[case]
+    heads = decode_heads_per_step(kvh, page, width, itemsize, scales)
+    assert heads == want and kvh % heads == 0
+    used = decode_step_vmem_bytes(heads, page, width, itemsize, scales)
+    # Under the budget, which is itself half of Mosaic's scoped 16 MB —
+    # unless not even one head fits, which the kernel then tries anyway.
+    assert DECODE_STEP_VMEM_BUDGET == 8 * 2 ** 20
+    assert used <= DECODE_STEP_VMEM_BUDGET or heads == 1
+    # The K and V blocks alone (double-buffered) are most of it.
+    blocks = 2 * 2 * heads * page * width * itemsize
+    assert blocks <= used
+    # The next divisor up would not have fit.
+    bigger = [h for h in range(heads + 1, kvh + 1) if kvh % h == 0]
+    if bigger:
+        assert decode_step_vmem_bytes(
+            bigger[0], page, width, itemsize, scales
+        ) > DECODE_STEP_VMEM_BUDGET
+
+
+def test_paged_decode_books_heads_per_step_and_split(rng):
+    """``kernel_dispatch_stats()["paged_decode"]`` says what engaged:
+    the heads a grid step and the split of the newest resolution."""
+    from adapt_tpu.ops.dispatch import kernel_dispatch_stats
+
+    b, kvh, g, hd, page, npages = 1, 6, 1, 64, 128, 4
+    q = jax.random.normal(rng, (b, kvh, g, hd))
+    kp = jax.random.normal(jax.random.fold_in(rng, 1), (npages, kvh, page, hd))
+    table = jnp.asarray([[2, 1]], jnp.int32)
+    paged_attention(q, kp, kp, table, 130, prefer="pallas", split=2)
+    books = kernel_dispatch_stats()["paged_decode"]
+    assert (books["heads_per_step"], books["split"]) == (6.0, 2.0)
+    assert books["last"] == 1.0
 
 
 def test_paged_kernel_unsupported_page_size_raises_when_forced(rng):
