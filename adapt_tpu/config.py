@@ -373,14 +373,15 @@ class PrefillConfig:
     ``Pager.adopt_cached`` / ``_adopt_pages`` path as a disaggregated
     handoff (head-resharded sender-side, per 2211.05322), so the
     request then admits as an ordinary prefix-cache hit and decode
-    stays tp-sharded and untouched; pages are byte-equal to what the
-    single-device chunked prefill would have written (pinned).
+    stays tp-sharded and untouched; pages equal what the single-device
+    chunked prefill would have written up to the rounding of one
+    reordered sum, and greedy streams are bit-identical (pinned).
 
     Wired at both entry points: ``ContinuousBatcher`` collocated
     admission and the ``runtime/disagg.PrefillWorker`` tier (whose
     ``step()`` dispatches sp-eligible jobs to the sp program instead
-    of the chunk loop). Requires ``kv_layout='paged'`` — the landing
-    path IS the paged prefix cache."""
+    of the chunk loop). The landing path IS the paged prefix
+    cache."""
 
     #: Prompts with at least this many tokens prefill sp-sharded
     #: (``None`` disables the sp path entirely). Keep it well above a

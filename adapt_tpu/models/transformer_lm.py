@@ -479,10 +479,10 @@ class CausalSelfAttention(nn.Module):
         in one layer-synchronous pass, written so every per-row
         operation mirrors the computation :meth:`prefill_chunk_paged`
         runs for that row, op for op — the sp-sharded prefill program
-        (``parallel/sp_prefill``) is byte-equal to the single-device
-        chunked prefill at the pinned test shapes, and shares chunked
-        prefill's documented ulp fine print beyond them (see the
-        sp_prefill module docstring).
+        (``parallel/sp_prefill``) equals the single-device chunked
+        prefill up to the rounding of the row reductions, whose width
+        differs between the two (chunked prefill's documented ulp fine
+        print; see the sp_prefill module docstring).
 
         ``x`` is (1, S, d) with the S axis sp-sharded under GSPMD
         (projections, rope, quantization and the MLP are all
@@ -515,7 +515,7 @@ class CausalSelfAttention(nn.Module):
         score block — every rank computing every row — which is
         numerically identical but forfeits exactly the O(S^2/P)
         compute split this path exists for. Resharding never changes
-        values, so the byte-equality contract is constraint-blind."""
+        values, so the page contract is constraint-blind."""
         b, s, d = x.shape
         q, k, v = self._project(x)
         q, k = self._rope_qk(q, k, jnp.arange(s))

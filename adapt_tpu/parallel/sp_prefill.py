@@ -13,28 +13,33 @@ pattern of ``parallel/ring_attention``) while each chip computes only
 its own chunk's attention-score rows — so the prefill wall drops
 ~linearly with the ring size.
 
-**The byte-equality contract.** Serving demands more than numerical
-closeness: the sp-prefilled pages must be BYTE-EQUAL to what the
-single-device chunked prefill would have written, so a request landed
-through the prefix cache decodes bit-identically to the collocated
-path. The online-softmax accumulation of classic ring attention
+**The page contract.** Serving demands more than numerical
+closeness: the sp-prefilled pages must be what the single-device
+chunked prefill would have written, so a request landed through the
+prefix cache decodes bit-identically to the collocated path. The
+online-softmax accumulation of classic ring attention
 (``ring_attention.ring_attention``) re-orders the softmax reduction
-per ring step and cannot satisfy that pin. This module keeps the ring
+per ring step and is far from that. This module keeps the ring
 TRANSPORT but not the online-softmax arithmetic: each rank ACCUMULATES
 the rotating pool-representation K/V blocks into its full window
 (:func:`ring_collect` — P-1 neighbor hops, no global gather primitive)
 and then computes its rows' attention with exactly the chunk oracle's
 op order (``models.transformer_lm.CausalSelfAttention.prefill_sp``
-mirrors ``paged_chunk_attention_reference``). Byte-equality holds at
-MATCHED decode-tier tp (an sp x tp prefill compares against the tp-
-sharded chunked prefill — tp math was never bitwise-equal across tp
-widths, only stream-identical, the PR-5 pin) and is PINNED at the
-repo's test shapes for native/int8/int4 pools and sp in {2, 4},
-sp x tp — the same scale every existing bit-identity pin runs at. At
-larger shapes the sp pass joins chunked prefill's documented
-equivalence class: XLA's matmul strategy varies with the row-block
-shape, so pages can differ at ulp across SCHEDULES (exactly as
-chunk-size choice already does, module docstring of
+mirrors ``paged_chunk_attention_reference``). What the tests pin
+(``tests/test_sp_prefill.py``, native/int8/int4 pools, sp in {2, 4},
+sp x tp at MATCHED decode-tier tp — tp math was never bitwise-equal
+across tp widths, only stream-identical, the PR-5 pin): the first
+block's pages — projection, rope, quantisation and the ring transport,
+everything token-local — are byte-equal; pages of later blocks sit
+downstream of attention, where the sp pass reduces a row's softmax sum
+and its p @ V product over the whole span and a chunk pass over its
+own power-of-two window. The masked columns add exact zeros, but XLA
+orders a reduction by its width, so the two differ by a rounding
+(under 3 float32 ulps of a plane's largest value; int8 values equal,
+their scales an ulp apart; a page whose chunk window IS the span is
+byte-equal): the sp pass joins chunked prefill's documented
+equivalence class, in which pages differ at ulp across SCHEDULES
+(exactly as chunk-size choice already does, module docstring of
 ``runtime/continuous``), and the serving-level pin is greedy-stream
 bit-identity — an argmax flip needs an exact fp tie. Per-chip window
 memory is O(S) — the explicit trade against the online-softmax
@@ -146,8 +151,9 @@ class SPPrefiller:
     """The sequence-parallel prefill program family: one jitted,
     sp-sharded whole-span pass per power-of-two page bucket, producing
     page-major host K/V blocks in the decode pool's representation —
-    the payload of a :class:`runtime.disagg.KVHandoff`, byte-equal to
-    what the single-device chunked prefill would have written.
+    the payload of a :class:`runtime.disagg.KVHandoff`, equal to what
+    the single-device chunked prefill would have written up to the
+    rounding of one reordered sum (module docstring).
 
     Owns its OWN mesh (axes ``(sp,)`` or ``(sp, tp)``) and weight
     placement (tp rules over ``tp_axis``, replicated over the ring) —
@@ -207,16 +213,12 @@ class SPPrefiller:
         self._blocks = [g.node(n).module for n in lm.block_names]
         block0 = self._blocks[0]
         self._heads = block0.cache_heads
-        self._head_dim = block0.head_dim
-        if kv_cache_dtype == "int4" and self._head_dim % 2:
-            raise ValueError(
-                f"kv_cache_dtype='int4' needs an even head_dim, got "
-                f"{self._head_dim}"
-            )
-        self._kv_width = (
-            self._head_dim // 2 if kv_cache_dtype == "int4" else
-            self._head_dim
-        )
+        # The pool's format has one owner; this module only has to
+        # refuse what that format refuses (int4 at an odd head_dim).
+        # Imported here: runtime/ imports this module.
+        from adapt_tpu.runtime.paged import kv_value_width
+
+        kv_value_width(block0.head_dim, kv_cache_dtype)
         #: The ORIGINAL variables as given — a post-recovery rebuild
         #: re-places from here, not from a possibly-dead placement.
         self._src_variables = variables
@@ -344,8 +346,8 @@ class SPPrefiller:
         host array (or a ``(values, scales)`` tuple of them for
         quantized pools): exactly the payload
         ``ContinuousBatcher.adopt_prefill_pages`` /
-        :class:`runtime.disagg.KVHandoff` expect, byte-equal to the
-        single-device chunked prefill's pages."""
+        :class:`runtime.disagg.KVHandoff` expect (the page contract:
+        module docstring)."""
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         s0 = prompt.shape[0]
         Pg = self.page_size

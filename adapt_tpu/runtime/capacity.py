@@ -522,14 +522,12 @@ class CapacityModel:
             "degradation_level": level,
             "degradation_rung": rung,
         }
-        sketch = {"v": BOOK_V, "page_tokens": 0, "entries": []}
-        if bat._pager is not None:
-            ps = bat._pager.stats()
-            headroom["pages_free"] = ps.free
-            headroom["pages_in_use"] = ps.in_use
-            headroom["pages_cached"] = ps.cached
-            headroom["pages_total"] = ps.num_pages
-            sketch = sketch_from_pager(bat._pager, self.cfg.sketch_k)
+        ps = bat._pager.stats()
+        headroom["pages_free"] = ps.free
+        headroom["pages_in_use"] = ps.in_use
+        headroom["pages_cached"] = ps.cached
+        headroom["pages_total"] = ps.num_pages
+        sketch = sketch_from_pager(bat._pager, self.cfg.sketch_k)
         # -- health target from existing signals -------------------------
         recovering = bool(bat._lost_pending)
         sentinel_events = int(bat._sentinel.events)

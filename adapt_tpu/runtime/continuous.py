@@ -3,21 +3,23 @@
 ``generate()`` serves one static batch: every row starts together and the
 program runs to the longest request's end — a short request pays for the
 longest, and a request arriving mid-flight waits for the whole batch.
-This module is the slot-based serving loop modern LM servers run instead:
-a fixed number of SLOTS decode in lockstep as ONE compiled step per tick
-(static shapes — XLA-friendly), and each slot independently admits a new
-request the moment its current one finishes. No reference analog (the
-reference is CNN-only request/response, SURVEY.md §2.2); this is the
-"request-level concurrency" column (§2.2) applied to autoregressive
-serving, TPU-first:
+This module is the serving loop modern LM servers run instead: a fixed
+number of SLOTS (the lockstep decode width) decode as ONE compiled step
+per tick (static shapes — XLA-friendly) over a shared PAGED KV pool,
+and each slot independently admits a new request the moment its
+current one finishes. No reference analog (the reference is CNN-only
+request/response, SURVEY.md §2.2); this is the "request-level
+concurrency" column (§2.2) applied to autoregressive serving,
+TPU-first:
 
 - **One compiled decode step for any slot mix.** Per-slot sequence
-  lengths ride as a (B,) position vector; `decode_step`'s per-row cache
-  write (a vmapped dynamic_update_slice — one scatter) puts each slot's
-  token at its own position, and the live mask `positions <= pos[row]`
-  keeps every slot's attention window independent. Inactive slots point
-  at a trash cache slot (``max_len``) and compute garbage that nothing
-  reads — branchless, so the step never recompiles as slots churn.
+  lengths ride as a (B,) position vector; `decode_step_paged`'s per-row
+  append (``ops/paged_attention.append_kv_paged``) puts each slot's
+  token into its own page at its own offset, and the kernel's per-slot
+  live window keeps every slot's attention independent. Inactive slots
+  sit at a negative position sentinel: their writes route to the trash
+  page and they compute garbage that nothing reads — branchless, so
+  the step never recompiles as slots churn.
 - **Chunked ticks.** One tick runs a fixed CHUNK of decode steps as a
   single compiled ``lax.scan`` with ONE host sync at the end — the
   per-token host round trip that makes naive continuous batching lose
@@ -29,8 +31,8 @@ serving, TPU-first:
 - **Bucketed prefill.** Prompts compile per bucket length (powers of two
   by default), not per prompt length: a new request pads to the smallest
   bucket, runs the full causal prefill (the measured flash dispatch),
-  and its K/V insert into the slot caches is one compiled
-  dynamic_update_slice per block.
+  and its K/V lands in the request's pages as one compiled scatter per
+  pool (``runtime/paged.insert_prefill_pages``).
 - **Exact per-request streams.** Sampling uses each request's OWN key
   schedule (the same split/fold pattern as ``generate``), so a request
   served through the batcher emits token-for-token what ``generate``
@@ -39,13 +41,13 @@ serving, TPU-first:
 
 ``kv_cache_dtype="int8"`` stores KV caches quantized (absmax per K/V
 vector, the same scheme as ``generate``): ~2-4x the resident context
-per slot and proportionally less per-step cache traffic. Quantization
-is a CACHE-LAYOUT property, not a mode of one path — it composes with
-every layout and decode family: dense strips and paged pools both
-become ``(int8 values, f32 scales)`` pytree pairs (the scale plane is
-one f32 per vector, page-addressed by the same table, so prefix-shared
-pages carry their scales), speculative verify quantizes its
-multi-token appends through the same scheme, and under tensor
+per page and proportionally less per-step cache traffic. Quantization
+is a property of the POOL's format (``runtime/paged.alloc_kv_pools``),
+not a mode of one path — it composes with every decode family: a pool
+becomes an ``(int8 values, f32 scales)`` pytree pair (the scale plane
+is one f32 per vector, page-addressed by the same table, so
+prefix-shared pages carry their scales), speculative verify quantizes
+its multi-token appends through the same scheme, and under tensor
 parallelism both members head-shard together. Greedy quantized streams
 are bit-identical to the same-quantized solo
 ``generate(kv_cache_dtype="int8")`` on the whole-prompt prefill paths;
@@ -56,16 +58,19 @@ token's logits — the same class of fine print as chunk fp contraction
 widths, one quantization step coarser (tested via top-1-agreement
 bounds vs fp32 rather than exact equality).
 
-``kv_layout="paged"`` swaps the per-slot ``max_len`` strips for a shared
-page POOL (``runtime/paged`` allocator + ``ops/paged_attention``'s
-scalar-prefetch kernel): each request reserves just the pages its
-window needs and frees them on retirement, so HBM scales with resident
-tokens instead of ``slots x max_len`` — size it with ``pool_pages``
-(default: worst case, i.e. no saving until you lower it). Admission is
-FIFO all-or-nothing: a request that doesn't fit waits (head-of-line, no
-preemption in v1); one that can NEVER fit raises at ``submit``.
+**The KV cache is a shared page POOL** (``runtime/paged``: the pool's
+format and the allocator; ``ops/paged_attention``: how a plane is
+appended to and read, and the scalar-prefetch kernels). Each request
+reserves just the pages its window needs and frees them on retirement,
+so HBM scales with resident tokens instead of ``slots x max_len`` —
+size it with ``pool_pages`` (default: worst case, i.e. no saving until
+you lower it). Admission is FIFO all-or-nothing: a request that
+doesn't fit waits (head-of-line); one that can NEVER fit raises at
+``submit``. There is no other layout: per-slot dense strips left in
+PR 29 (``kv_layout=`` is accepted only as ``"paged"``); the one dense
+family left is the speculative DRAFT model's strips, below.
 
-``prefill_chunk`` (paged only, a multiple of ``page_size``) turns a
+``prefill_chunk`` (a multiple of ``page_size``) turns a
 long prompt's admission into CHUNKED PREFILL: one page-aligned chunk
 pass per tick, interleaved with the decode batch, so a long admission
 never stalls the requests already decoding (the Sarathi-style
@@ -76,7 +81,7 @@ differ at ulp scale from the one-pass values — a high-temperature
 categorical draw at an exact tie may pick differently (equivalence is
 distributional there, not bitwise).
 
-Paged slots get PREFIX CACHING for free: a full page of prompt K/V is
+Slots get PREFIX CACHING for free: a full page of prompt K/V is
 content-addressed (hash of the whole token prefix it depends on) and
 refcounted, so a request whose prompt starts with an already-resident
 prefix — the shared-system-prompt workload — shares those pages (live
@@ -146,11 +151,11 @@ Rows DESYNCHRONIZE — slot A commits 5 tokens this tick while slot B
 commits 1 — but positions, page tables and cache write masks are all
 per-slot device vectors, so the two compiled programs never change
 shape and nothing recompiles (guarded by a compile-count test).
-Rejected speculation needs no rollback on either cache: each layout
-carries ``draft_k`` SLACK positions (dense strips grow by ``draft_k``,
-paged admissions reserve the slack pages), so overshoot writes land
-past every slot's accepted position and are overwritten by later
-rounds — the same trash-slot/masked-write discipline as the rest of
+Rejected speculation needs no rollback on either model's cache: each
+carries ``draft_k`` SLACK positions (the draft's strips grow by
+``draft_k``, admissions reserve the slack pages), so overshoot writes
+land past every slot's accepted position and are overwritten by later
+rounds — the same trash-page/masked-write discipline as the rest of
 this module. Per-row greedy LOSSLESSNESS is the tested contract: each
 request's stream equals its solo ``generate()`` token-for-token
 whatever the draft proposes and however acceptance staggers across
@@ -182,8 +187,8 @@ runs SPMD over a mesh's ``tp`` axis. Weights place by the megatron-style
 rules in ``parallel/sharding.lm_tp_rules`` (qkv/mlp-in column-split,
 attn-out/mlp-out row-split — exactly ONE psum pair per block per token,
 so the decode tick's latency does not drown in ICI hops), and the KV
-caches — dense slot strips and paged pools alike — shard on their HEAD
-axis (GQA-aware: kv_heads % tp == 0), so per-device KV bytes are the
+pools shard on their HEAD axis (GQA-aware: kv_heads % tp == 0), so
+per-device KV bytes are the
 logical bytes / tp: models whose weights + KV exceed one chip's HBM
 serve, and models that fit stop leaving N-1 chips idle. Everything the
 host touches stays REPLICATED — page tables, the device-resident
@@ -192,7 +197,7 @@ prefix caching and the pager are sharding-blind, and all the hot-path
 invariants survive unchanged and re-pinned by tests: zero host arrays
 per steady-state tick, the two-program compile footprint, buffer
 donation, and per-row greedy losslessness vs single-device
-``generate()`` on both layouts including speculative mode (the draft
+``generate()``, speculative mode included (the draft
 model deliberately replicates — it is small by construction and a
 replicated draft scan is collective-free). ``stats()`` reports
 ``cache_bytes`` (logical) next to ``cache_bytes_per_device``; the
@@ -217,7 +222,7 @@ uninterrupted run. Requests that do not migrate (mid-chunked-prefill,
 or ``policy="replay"``) REPLAY from the journal (``journal=`` — a
 ``control.journal.DispatcherJournal`` that records every submit's
 payload + sampling knobs and every finish's done mark), re-entering
-through the paged prefix cache when the prompt pages are still
+through the prefix cache when the prompt pages are still
 resident — identical tokens, paid by a suffix prefill instead of
 state migration. Lifecycle: ``device_lost`` / ``mesh_reshard`` /
 ``kv_migrated`` / ``replayed_from_journal`` flight events,
@@ -305,7 +310,9 @@ from adapt_tpu.runtime.capacity import CapacityModel
 from adapt_tpu.runtime.paged import (
     HostKVTier,
     Pager,
+    alloc_kv_pools,
     insert_prefill_pages,
+    pool_geometry,
 )
 from adapt_tpu.runtime.scheduler import (
     AdmissionQueue,
@@ -564,8 +571,8 @@ class _InFlight:
 
 
 class ContinuousBatcher:
-    """Slot-based continuous batching over one LM — on one device, or
-    tensor-parallel over a mesh's ``tp`` axis (``mesh=`` +
+    """Continuous batching over one LM and one paged KV pool — on one
+    device, or tensor-parallel over a mesh's ``tp`` axis (``mesh=`` +
     ``config.ParallelConfig``; weights and KV head-sharded, control
     plane replicated — see the module docstring).
 
@@ -587,7 +594,7 @@ class ContinuousBatcher:
         prompt_buckets: tuple[int, ...] | None = None,
         chunk: int = 8,
         kv_cache_dtype: str = "native",
-        kv_layout: str = "slots",
+        kv_layout: str = "paged",
         page_size: int = 128,
         pool_pages: int | None = None,
         prefill_chunk: int | None = None,
@@ -668,8 +675,7 @@ class ContinuousBatcher:
                 #: (prompt ids, fused admission vectors, page tables,
                 #: _dstate) — admission/commit logic is sharding-blind.
                 self._repl = NamedSharding(mesh, P())
-                #: KV caches shard on the HEAD axis (dim 1 of both the
-                #: dense (slots, kvh, L, hd) strips and the paged
+                #: KV pools shard on the HEAD axis (dim 1 of the
                 #: (pages, kvh, P, hd) pools — and of the int8 scale
                 #: planes: both members of a quantized (values, scales)
                 #: pair pin to the SAME spec, parallel.sharding's one
@@ -753,36 +759,31 @@ class ContinuousBatcher:
                 f"kv_cache_dtype={kv_cache_dtype!r}: expected 'native', "
                 "'int8' or 'int4'"
             )
-        if kv_layout not in ("slots", "paged"):
+        if kv_layout != "paged":
+            # The keyword outlives the layout only because callers
+            # outside this package still pass it (ROADMAP C1d).
             raise ValueError(
-                f"kv_layout={kv_layout!r}: expected 'slots' or 'paged'"
+                f"kv_layout={kv_layout!r}: the per-slot dense layout "
+                "left in PR 29; 'paged' is the only KV layout"
             )
-        #: Quantized KV caches: absmax per K/V vector, same scheme as
+        #: Quantized KV pools: absmax per K/V vector, same scheme as
         #: generate(kv_cache_dtype=...) — ~2-4x (int8) / ~4-8x (int4,
         #: two nibbles packed per int8 lane) more resident context per
-        #: slot and correspondingly less per-step cache traffic vs
-        #: native. Composes with EVERY layout and mode: dense strips
-        #: and paged pools both become (values, scales) pytree pairs,
-        #: speculative verify quantizes its multi-token appends, and
-        #: under TP both members head-shard together — quantization is
-        #: a cache-layout property, not a special mode of one path.
+        #: page and correspondingly less per-step cache traffic vs
+        #: native. Composes with every mode: a pool becomes a (values,
+        #: scales) pytree pair, speculative verify quantizes its
+        #: multi-token appends, and under TP both members head-shard
+        #: together — quantization is a property of the pool's format,
+        #: not a special mode of one path.
         self._kv_dtype = kv_cache_dtype
         self._kv_quant = kv_cache_dtype != "native"
-        #: paged caches: per-block page POOLS + a shared page table
-        #: (``runtime/paged`` allocator, ``ops/paged_attention`` kernel)
-        #: — HBM scales with resident tokens, not slots x max_len.
-        self._paged = kv_layout == "paged"
-        if prefill_chunk is not None:
-            if not self._paged:
-                raise ValueError(
-                    "prefill_chunk requires kv_layout='paged' (chunk "
-                    "passes run over the page-strip machinery)"
-                )
-            if prefill_chunk < page_size or prefill_chunk % page_size:
-                raise ValueError(
-                    f"prefill_chunk must be a positive multiple of "
-                    f"page_size {page_size}, got {prefill_chunk}"
-                )
+        if prefill_chunk is not None and (
+            prefill_chunk < page_size or prefill_chunk % page_size
+        ):
+            raise ValueError(
+                f"prefill_chunk must be a positive multiple of "
+                f"page_size {page_size}, got {prefill_chunk}"
+            )
         self._prefill_chunk = prefill_chunk
         if top_k is not None and not (1 <= top_k <= lm.vocab):
             raise ValueError(f"top_k {top_k} outside [1, {lm.vocab}]")
@@ -801,95 +802,29 @@ class ContinuousBatcher:
         #: Sliding-window models: decode masking lives in the model;
         #: the batcher's job is page RECYCLING behind the window.
         self._window = getattr(block0, "window", None)
-        # One trash slot for idle rows, plus draft_k (+ tree_width leaf
-        # rows) SLACK positions in speculative mode: a verify chunk
-        # writes draft_k + 1 + tree_width tokens from each slot's
-        # position (trash included), and the rejected overshoot must
-        # land in masked space, never shift onto live rows (append_kv
-        # clamps).
-        self._cache_len = lm.max_len + 1 + self._spec_k + self._spec_w
-        self._trash = lm.max_len
-        # Slot caches hold KV heads: fewer than query heads under GQA
-        # (the whole point — slots cost kv_heads/heads the HBM).
+        # Pools hold KV heads: fewer than query heads under GQA (the
+        # whole point — a page costs kv_heads/heads the HBM).
         heads, head_dim = block0.cache_heads, block0.head_dim
-        if kv_cache_dtype == "int4" and head_dim % 2:
-            raise ValueError(
-                f"kv_cache_dtype='int4' packs two nibbles per int8 "
-                f"lane and needs an even head_dim, got {head_dim}"
-            )
-        #: VALUE-plane lane width: head_dim, halved for packed int4.
-        self._kv_width = (
-            head_dim // 2 if kv_cache_dtype == "int4" else head_dim
+        self._page = page_size
+        # The table width covers max_len plus the speculative overshoot
+        # slack: a verify chunk writes draft_k + 1 + tree_width tokens
+        # from each slot's position, and the rejected overshoot must
+        # land in reserved, masked space.
+        pps, worst = pool_geometry(
+            slots, lm.max_len, page_size,
+            slack=self._spec_k + self._spec_w,
         )
-
-        if self._paged:
-            if page_size < 1:
-                raise ValueError(f"page_size must be >= 1, got {page_size}")
-            self._page = page_size
-            # Table width covers max_len plus the speculative overshoot
-            # slack (verify writes reach position + draft_k +
-            # tree_width).
-            pps = -(-(lm.max_len + self._spec_k + self._spec_w)
-                    // page_size)
-            worst = slots * pps + 1  # every slot full + trash page
-            if pool_pages is None:
-                pool_pages = worst
-            if pool_pages < 2:
-                raise ValueError(
-                    f"pool_pages must be >= 2, got {pool_pages}"
-                )
-            self._pager = Pager(
-                pool_pages, slots, pps, page_tokens=page_size
-            )
-            self._pool_pages = pool_pages
-
-            def one_cache():
-                if self._kv_quant:
-                    # (values, scales) POOL pair: the scale plane is one
-                    # f32 per cached vector, page-addressed by the SAME
-                    # table — prefix-shared pages carry their scales.
-                    # int4 pools halve the value plane's lane width
-                    # (two nibbles per int8 lane).
-                    return (
-                        jnp.zeros(
-                            (pool_pages, heads, page_size,
-                             self._kv_width),
-                            jnp.int8,
-                        ),
-                        jnp.zeros(
-                            (pool_pages, heads, page_size, 1), jnp.float32
-                        ),
-                    )
-                return jnp.zeros(
-                    (pool_pages, heads, page_size, head_dim), block0.dtype
-                )
-
-        else:
-            self._pager = None
-
-            def one_cache():
-                if self._kv_quant:
-                    return (
-                        jnp.zeros(
-                            (slots, heads, self._cache_len,
-                             self._kv_width),
-                            jnp.int8,
-                        ),
-                        jnp.zeros(
-                            (slots, heads, self._cache_len, 1), jnp.float32
-                        ),
-                    )
-                return jnp.zeros(
-                    (slots, heads, self._cache_len, head_dim), block0.dtype
-                )
-
+        if pool_pages is None:
+            pool_pages = worst
+        if pool_pages < 2:
+            raise ValueError(f"pool_pages must be >= 2, got {pool_pages}")
+        #: Per-block page POOLS + a shared page table (``runtime/paged``:
+        #: format and allocator; ``ops/paged_attention``: append, read,
+        #: kernels) — HBM scales with resident tokens, not slots x
+        #: max_len.
+        self._pager = Pager(pool_pages, slots, pps, page_tokens=page_size)
+        self._pool_pages = pool_pages
         # -- hierarchical KV cache tier (docs/SERVING.md §3) ---------------
-        if cache_tier is not None and not self._paged:
-            raise ValueError(
-                "cache_tier requires kv_layout='paged' (the spill "
-                "tier lives under the paged prefix cache — dense slot "
-                "strips have no page unit to spill)"
-            )
         #: Host-DRAM spill tier under the prefix LRU: evicted rc=0
         #: pages spill (budgeted per tick) instead of dying, and the
         #: admission probe consults the tier before declaring a prefix
@@ -906,11 +841,10 @@ class ContinuousBatcher:
         self._readmit_budget = (
             cache_tier.readmit_pages_per_tick if cache_tier else 0
         )
-        if self._paged:
-            # Always installed, tier or not: the hook records the
-            # radix_evict flight event for every cached-prefix death
-            # (spill/drop routing inside it stays tier-gated).
-            self._pager.evict_hook = self._on_page_evict
+        # Always installed, tier or not: the hook records the
+        # radix_evict flight event for every cached-prefix death
+        # (spill/drop routing inside it stays tier-gated).
+        self._pager.evict_hook = self._on_page_evict
         #: Instance-lifetime tier books (stats() mirrors of the
         #: cache_tier.* registry counters).
         self._tier_spilled = 0
@@ -919,31 +853,33 @@ class ContinuousBatcher:
         #: High-water of the tier's own overflow-drop count already
         #: bridged to cache_tier.dropped_total (flushed per tick).
         self._tier_drop_seen = 0
-        self._caches = [(one_cache(), one_cache()) for _ in lm.block_names]
+        self._caches = [
+            alloc_kv_pools(
+                pool_pages, heads, page_size, head_dim, block0.dtype,
+                kv_cache_dtype,
+            )
+            for _ in lm.block_names
+        ]
         if mesh is not None:
             # Head-sharded KV: each device holds kv_heads / tp of every
-            # slot strip (or pool page) — THE capacity win TP buys.
+            # pool page — THE capacity win TP buys.
             self._caches = jax.device_put(self._caches, self._kv_sharding)
-        #: What the SAME cache geometry would cost in the native dtype
+        #: What the SAME pool geometry would cost in the native dtype
         #: — the denominator of the memory.kv_bytes_ratio gauge, so the
         #: int8 capacity win (values + scale planes vs native) is
         #: directly observable on dashboards. Native batchers read 1.0.
-        if self._paged:
-            cache_positions = pool_pages * page_size
-        else:
-            cache_positions = slots * self._cache_len
         self._native_cache_bytes = (
             2
             * len(lm.block_names)
-            * cache_positions
+            * pool_pages
+            * page_size
             * heads
             * head_dim
             * jnp.dtype(block0.dtype).itemsize
         )
-        #: Idle-row cache position: slot layout parks garbage writes at
-        #: the trash strip; paged layout uses a negative sentinel that
-        #: stays negative across a whole tick's position advance
-        #: (chunk steps, or the spec tick's up-to-draft_k+1(+1 with a
+        #: Idle-row cache position: a negative sentinel that stays
+        #: negative across a whole tick's position advance (chunk
+        #: steps, or the spec tick's up-to-draft_k+1(+1 with a
         #: tree-draft bonus) commit), routing every garbage write to
         #: the trash page.
         adv = (
@@ -951,7 +887,7 @@ class ContinuousBatcher:
             if self._spec
             else self.chunk
         )
-        self._idle_pos = -(adv + 1) if self._paged else self._trash
+        self._idle_pos = -(adv + 1)
         #: Draft-model slot caches (speculative mode): dense per-slot
         #: strips with the same draft_k + 1 slack as the single-request
         #: loop — the draft is small by construction, so slots x max_len
@@ -1044,9 +980,11 @@ class ContinuousBatcher:
         #: window, chunk-oracle attention) and their pages land through
         #: :meth:`adopt_prefill_pages` exactly like a disaggregated
         #: handoff, so the request then admits as a prefix-cache hit
-        #: and the decode tier's mesh/programs are untouched. Byte-
-        #: equal to the collocated chunked prefill (pinned), so greedy
-        #: streams stay bit-identical. The prefiller's tp must MATCH
+        #: and the decode tier's mesh/programs are untouched. Greedy
+        #: streams stay bit-identical to the collocated chunked
+        #: prefill's (pinned; the pages differ from its by at most the
+        #: rounding of one reordered sum, parallel/sp_prefill). The
+        #: prefiller's tp must MATCH
         #: this batcher's (its pages must be what THIS batcher's own
         #: chunked prefill would write, which is tp-sharded math for
         #: tp > 1).
@@ -1059,12 +997,6 @@ class ContinuousBatcher:
         #: event will ever recover) until a recovery rebuilds it.
         self._sp_failures = 0
         if prefill is not None and prefill.enabled:
-            if not self._paged:
-                raise ValueError(
-                    "PrefillConfig sp prefill requires "
-                    "kv_layout='paged' (the sp pages land through the "
-                    "paged prefix cache)"
-                )
             mesh_sp = sp_mesh
             if mesh_sp is None:
                 mesh_sp = build_sp_mesh(
@@ -1108,11 +1040,7 @@ class ContinuousBatcher:
         #: preemption and the degradation controller.
         self._sched = scheduler
         self._queue: AdmissionQueue = AdmissionQueue(scheduler)
-        if (
-            self._paged
-            and scheduler is not None
-            and scheduler.cache_aware
-        ):
+        if scheduler is not None and scheduler.cache_aware:
             # Cache-aware admission ordering (SchedulerConfig
             # .cache_aware): among one class's queued candidates, the
             # queue prefers the request with the longest (then hottest)
@@ -1262,19 +1190,21 @@ class ContinuousBatcher:
         self._sentinel.register(
             "continuous.clear_slot", type(self)._clear_slot
         )
-        self._sentinel.register("continuous.insert", type(self)._insert)
-        if self._paged:
-            # Disaggregated-handoff landing program (adopt_prefill_pages
-            # — dispatched only when a prefill tier streams pages in).
-            self._sentinel.register(
-                "continuous.adopt_pages", type(self)._adopt_pages
-            )
-            # Copy-on-write fan-out fork (one variant ever: no static
-            # shape axis — dispatched only by submit_fanout siblings).
-            self._sentinel.register(
-                "continuous.fork_page", type(self)._fork_page
-            )
+        # Disaggregated-handoff landing program (adopt_prefill_pages —
+        # dispatched only when a prefill tier streams pages in).
+        self._sentinel.register(
+            "continuous.adopt_pages", type(self)._adopt_pages
+        )
+        # Copy-on-write fan-out fork (one variant ever: no static shape
+        # axis — dispatched only by submit_fanout siblings).
+        self._sentinel.register(
+            "continuous.fork_page", type(self)._fork_page
+        )
         if self._spec:
+            # The draft's dense strips are the only _insert target.
+            self._sentinel.register(
+                "continuous.insert", type(self)._insert
+            )
             self._sentinel.register(
                 "continuous.spec_verify", type(self)._spec_verify
             )
@@ -1290,8 +1220,8 @@ class ContinuousBatcher:
             "continuous.prefill",
             size_fn=aggregate_size_fn(_LIVE_BATCHERS, _prefill_family_size),
         )
-        #: Pull-style memory accounting: dense strip / pool / draft
-        #: bytes and paged occupancy served as memory.* gauges at every
+        #: Pull-style memory accounting: pool / draft-strip bytes and
+        #: page occupancy served as memory.* gauges at every
         #: exporter scrape (weakly held — see utils.profiling).
         register_memory_source("continuous", self)
         #: Roofline source: XLA cost_analysis of the decode-path
@@ -1421,7 +1351,7 @@ class ContinuousBatcher:
 
     def _shard_kv(self, caches):
         """Explicit in/out cache sharding for the compiled programs:
-        pin every KV leaf (dense strips, pools, int8 scale planes) to
+        pin every KV leaf (pools, int8 scale planes) to
         the head-axis sharding so GSPMD partitions the decode math and
         inserts the block psums, instead of falling back to whatever
         propagation guesses. No-mesh batchers pay one branch.
@@ -1540,7 +1470,7 @@ class ContinuousBatcher:
         static_argnames=("truncate", "nucleus", "epoch"),
         donate_argnums=(2, 3),
     )
-    def _step_chunk(self, variables, caches, dstate, table=None, *,
+    def _step_chunk(self, variables, caches, dstate, table, *,
                     truncate, nucleus, epoch=0):
         """``chunk`` lockstep decode steps as one compiled scan over the
         DEVICE-RESIDENT slot state.
@@ -1555,16 +1485,13 @@ class ContinuousBatcher:
         truncation never reads). Greedy selection derives from
         ``temp == 0`` (submit's normalization). Static ``truncate`` /
         ``nucleus`` elide the top-k/top-p sorts when no active request
-        needs them (at most 2x2 compiled variants). ``table`` (paged
-        layout only) addresses each block's (k_pool, v_pool) through the
-        shared page table — the cache plumbing is the ONLY thing that
-        differs between layouts; the sampling schedule is this one body.
+        needs them (at most 2x2 compiled variants). ``table`` addresses
+        each block's (k_pool, v_pool) through the shared page table.
         Inactive rows re-park at the idle sentinel after the chunk's
         optimistic pos advance; rows whose request retires mid-chunk are
         cleared host-side (``_clear_slot``) before the next tick.
         Returns ((chunk, B) emitted tokens, logprobs, caches, dstate);
         ONE host sync per call, not per token."""
-        paged = table is not None
         caches = self._shard_kv(caches)
         dstate = self._repl_state(dstate)
         C = self.chunk
@@ -1593,28 +1520,17 @@ class ContinuousBatcher:
                 method="embed_positions",
             )
             new_caches = []
-            for name, block, cache in zip(
+            for name, block, (kp, vp) in zip(
                 self.lm.block_names, self._blocks, caches
             ):
-                if paged:
-                    kp, vp = cache
-                    x, kp, vp = block.apply(
-                        variables[name], x, kp, vp, table, pos, None,
-                        self._kernel.attn_impl,
-                        self._kernel.decode_split,
-                        self._head_shard(),
-                        method="decode_step_paged",
-                    )
-                    new_caches.append((kp, vp))
-                else:
-                    ck, cv = cache
-                    x, ck, cv = block.apply(
-                        variables[name], x, ck, cv, pos, None,
-                        self._kv_quant, self._kernel.attn_impl,
-                        self._kernel.decode_split,
-                        method="decode_step",
-                    )
-                    new_caches.append((ck, cv))
+                x, kp, vp = block.apply(
+                    variables[name], x, kp, vp, table, pos, None,
+                    self._kernel.attn_impl,
+                    self._kernel.decode_split,
+                    self._head_shard(),
+                    method="decode_step_paged",
+                )
+                new_caches.append((kp, vp))
             logits = self._head.apply(variables["head"], x)[:, 0]  # (B, V)
             pick_greedy = jnp.argmax(logits, axis=-1)
             lg = logits / jnp.maximum(temps, 1e-6)[:, None]
@@ -1638,8 +1554,8 @@ class ContinuousBatcher:
         # tokens (any mid-chunk finish retires it and the host clears
         # its row), so pos/kbase/tok land exactly on the next tick's
         # entry invariants. Idle rows re-park at the sentinel — without
-        # this, the scan's pos+1 increments would walk a retired paged
-        # row's sentinel up into real page territory.
+        # this, the scan's pos+1 increments would walk a retired row's
+        # sentinel up into real page territory.
         new = dict(dstate)
         new["pos"] = jnp.where(active, dstate["pos"] + C, self._idle_pos)
         new["tok"] = jnp.where(active, toks[-1], 0)
@@ -1655,7 +1571,7 @@ class ContinuousBatcher:
         static_argnames=("sample", "truncate", "nucleus", "epoch"),
         donate_argnums=(2, 3),
     )
-    def _spec_verify(self, variables, caches, dstate, dtoks, table=None,
+    def _spec_verify(self, variables, caches, dstate, dtoks, table,
                      cands=None, *, sample=False, truncate=False,
                      nucleus=False, epoch=0):
         """The speculative tick's VERIFY program — the second of its
@@ -1679,7 +1595,7 @@ class ContinuousBatcher:
 
         Builds every slot's (draft_k + 1) chunk ``[last_token,
         proposals]`` ON DEVICE from the draft scan's output, runs one
-        fused ``verify_chunk`` / ``verify_chunk_paged`` pass over all
+        fused ``verify_chunk_paged`` pass over all
         slots at their own positions (rows desynchronize; the program
         does not), reduces each row's longest agreeing prefix
         (``accept_speculation``), and advances the donated device state
@@ -1703,7 +1619,6 @@ class ContinuousBatcher:
         AFTER that leaf commits as a BONUS token: up to d + 2 commits
         per verify pass. Outputs then carry d + 2 token rows and
         ``acc`` counts the bonus (commit limit stays ``acc + 1``)."""
-        paged = table is not None
         tree = cands is not None
         w = cands.shape[1] if tree else 0
         caches = self._shard_kv(caches)
@@ -1734,26 +1649,17 @@ class ContinuousBatcher:
             variables["embed"], chunk, pos_ids, method="embed_positions"
         )
         new_caches = []
-        for name, block, cache in zip(
+        for name, block, (kp, vp) in zip(
             self.lm.block_names, self._blocks, caches
         ):
-            if paged:
-                kp, vp = cache
-                x, kp, vp = block.apply(
-                    variables[name], x, kp, vp, table, pos,
-                    self._kernel.attn_impl, w,
-                    self._kernel.decode_split,
-                    self._head_shard(),
-                    method="verify_chunk_paged",
-                )
-                new_caches.append((kp, vp))
-            else:
-                ck, cv = cache
-                x, ck, cv = block.apply(
-                    variables[name], x, ck, cv, pos, w,
-                    method="verify_chunk",
-                )
-                new_caches.append((ck, cv))
+            x, kp, vp = block.apply(
+                variables[name], x, kp, vp, table, pos,
+                self._kernel.attn_impl, w,
+                self._kernel.decode_split,
+                self._head_shard(),
+                method="verify_chunk_paged",
+            )
+            new_caches.append((kp, vp))
         logits = self._head.apply(variables["head"], x)  # (B, kc, V)
         preds = jnp.argmax(logits, axis=-1).astype(tok.dtype)
         lps = chosen_logprob(
@@ -1867,41 +1773,26 @@ class ContinuousBatcher:
             # from physical pos + d + 1 + s to pos + d + 1. Rows with
             # s == 0, no hit, or inactive reduce to an identity
             # self-copy at a safe position (dead rows target the trash
-            # page / trash strip — the ordinary garbage discipline).
+            # page — the ordinary garbage discipline).
             do = jnp.logical_and(hit, jnp.logical_and(s > 0, active))
             base = jnp.maximum(pos, 0) + d + 1
             p_dst = jnp.where(do, base, 0)
             p_src = jnp.where(do, base + s, 0)
-            if paged:
-                pg = self._page
-                phys_dst = jnp.take_along_axis(
-                    table, (p_dst // pg)[:, None], axis=1
-                )[:, 0]
-                phys_src = jnp.take_along_axis(
-                    table, (p_src // pg)[:, None], axis=1
-                )[:, 0]
-                off_dst, off_src = p_dst % pg, p_src % pg
+            pg = self._page
+            phys_dst = jnp.take_along_axis(
+                table, (p_dst // pg)[:, None], axis=1
+            )[:, 0]
+            phys_src = jnp.take_along_axis(
+                table, (p_src // pg)[:, None], axis=1
+            )[:, 0]
+            off_dst, off_src = p_dst % pg, p_src % pg
 
-                def fix(pool):
-                    vec = pool[phys_src, :, off_src, :]  # (B, kvh, wd)
-                    return append_kv_paged(
-                        pool, vec[:, :, None, :], phys_dst[:, None],
-                        off_dst[:, None],
-                    )
-
-            else:
-
-                def fix(cache):
-                    vec = jax.vmap(
-                        lambda c, i: lax.dynamic_slice(
-                            c, (0, i, 0), (c.shape[0], 1, c.shape[2])
-                        )
-                    )(cache, p_src)
-                    return jax.vmap(
-                        lambda c, v, i: lax.dynamic_update_slice(
-                            c, v, (0, i, 0)
-                        )
-                    )(cache, vec, p_dst)
+            def fix(pool):
+                vec = pool[phys_src, :, off_src, :]  # (B, kvh, wd)
+                return append_kv_paged(
+                    pool, vec[:, :, None, :], phys_dst[:, None],
+                    off_dst[:, None],
+                )
 
             new_caches = [
                 jax.tree.map(fix, pair) for pair in new_caches
@@ -2011,7 +1902,7 @@ class ContinuousBatcher:
         keys dedupe (first writer won), and pool pressure adopts
         NOTHING (all-or-nothing, like admission) — the caller just
         submits and the request collocates its own prefill. Raises
-        ``ValueError`` on geometry mismatches (layout, page size,
+        ``ValueError`` on geometry mismatches (page size,
         quantization, block count/shapes) — a malformed handoff must
         fail by name, never scatter garbage into live pages."""
         # The device-lost gate tick() runs: a handoff landing between
@@ -2019,11 +1910,6 @@ class ContinuousBatcher:
         # dispatch the adoption program at a stale mesh epoch (the
         # disaggregated server lands handoffs BEFORE its decode tick).
         self._ensure_mesh()
-        if not self._paged:
-            raise ValueError(
-                "adopt_prefill_pages requires kv_layout='paged' (the "
-                "handoff lands through the paged prefix cache)"
-            )
         if page_size != self._page:
             raise ValueError(
                 f"handoff page size {page_size} != pool page size "
@@ -2061,8 +1947,8 @@ class ContinuousBatcher:
         # adopt_cached registers prefix keys, and raising after it
         # would leave content keys pointing at never-written pages —
         # the next same-prefix admission would prefix-hit garbage.
-        for b, (block, pair) in enumerate(zip(self._blocks, blocks)):
-            for mname, member in zip(("K", "V"), pair):
+        for b, (pools, pair) in enumerate(zip(self._caches, blocks)):
+            for mname, pool, member in zip(("K", "V"), pools, pair):
                 if isinstance(member, tuple) != self._kv_quant:
                     raise ValueError(
                         f"handoff block {b} {mname}: "
@@ -2072,16 +1958,17 @@ class ContinuousBatcher:
                         " pool"
                     )
                 leaves = member if isinstance(member, tuple) else (member,)
-                for li, leaf in enumerate(leaves):
-                    # Value plane carries the POOL's lane width (packed
-                    # for int4), the scale plane one f32 per vector.
-                    if li == 0:
-                        width = block.head_dim // (
-                            2 if self._kv_dtype == "int4" else 1
-                        )
-                    else:
-                        width = 1
-                    want = (n, block.cache_heads, self._page, width)
+                planes = pool if isinstance(pool, tuple) else (pool,)
+                if len(leaves) != len(planes):
+                    raise ValueError(
+                        f"handoff block {b} {mname}: {len(leaves)} "
+                        f"planes, the pool has {len(planes)}"
+                    )
+                for li, (plane, leaf) in enumerate(zip(planes, leaves)):
+                    # A handed page is one page of the pool's own plane
+                    # (runtime/paged.alloc_kv_pools: packed value lanes
+                    # for int4, one f32 per vector in a scale plane).
+                    want = (n,) + tuple(plane.shape[1:])
                     if tuple(np.shape(leaf)) != want:
                         raise ValueError(
                             f"handoff block {b} {mname}[{li}] shape "
@@ -2388,8 +2275,6 @@ class ContinuousBatcher:
         or capacity audit wants (``benchmarks/load/tier_smoke``
         measures the host tier's servable-prefix multiplier with
         it)."""
-        if not self._paged:
-            return 0
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         n = 0
         for j in range((prompt.shape[0] - 1) // self._page):
@@ -2570,20 +2455,16 @@ class ContinuousBatcher:
         kvs = self._draft_prefill_fn(bucket)(
             self._draft_variables, self._h2d(ids)
         )
-        # Draft K/V shapes differ from the target's, so a draft bucket
-        # is its own _insert variant even at the same prompt length.
-        self._variants.setdefault("continuous.insert", set()).add(
-            ("draft", bucket)
-        )
+        self._variants.setdefault("continuous.insert", set()).add(bucket)
         self._draft_caches = self._insert(
             self._draft_caches, self._h2d(np.int32(slot_idx)), kvs
         )
 
     @partial(jax.jit, static_argnums=(0,), donate_argnums=(1,))
     def _insert(self, caches, slot, kvs):
-        """Write a prefilled request's K/V into slot row ``slot``
-        (tree.map reaches the (values, scales) leaves of int8 caches and
-        the plain arrays of native ones alike)."""
+        """Write a prefilled request's DRAFT K/V into row ``slot`` of
+        the draft's dense strips (the target's K/V lands in pages:
+        ``_insert_paged``)."""
         return [
             jax.tree.map(
                 lambda c, n: lax.dynamic_update_slice(
@@ -2632,18 +2513,17 @@ class ContinuousBatcher:
                 f"prompt {s0} exceeds largest bucket "
                 f"{self.prompt_buckets[-1]}"
             )
-        if self._paged:
-            bucket = next(b for b in self.prompt_buckets if b >= s0)
-            need = -(
-                -max(bucket, s0 + steps + self._spec_k + self._spec_w)
-                // self._page
+        bucket = next(b for b in self.prompt_buckets if b >= s0)
+        need = -(
+            -max(bucket, s0 + steps + self._spec_k + self._spec_w)
+            // self._page
+        )
+        if need > self._pool_pages - 1:  # page 0 is trash
+            # Would queue forever: the pool can never cover it.
+            raise ValueError(
+                f"request needs {need} pages but the pool holds "
+                f"{self._pool_pages - 1} allocatable"
             )
-            if need > self._pool_pages - 1:  # page 0 is trash
-                # Would queue forever: the pool can never cover it.
-                raise ValueError(
-                    f"request needs {need} pages but the pool holds "
-                    f"{self._pool_pages - 1} allocatable"
-                )
         if temperature > 0.0 and rng is None:
             raise ValueError("temperature > 0 requires an rng key")
         top_k_eff = top_k if top_k is not None else self.top_k
@@ -2779,11 +2659,8 @@ class ContinuousBatcher:
             # prefix_cached), and the forecaster feeds are per-field
             # scalar reads. Stored on the request; its realized TTFT
             # closes the calibration loop at first-token commit.
-            hit_tokens = 0
-            if self._paged:
-                hit_tokens = self._pager.radix_probe(prompt)[1]
             req.ttft_forecast_s = self._capacity.forecast_ttft(
-                s0, hit_tokens
+                s0, self._pager.radix_probe(prompt)[1]
             )
 
         def _reject(e: QueueFullError, journaled: bool) -> None:
@@ -2875,7 +2752,7 @@ class ContinuousBatcher:
         semantics — the streams diverge by design, so sampled
         siblings run the ordinary suffix pass for their own
         first-token logits and share only the full prefix pages).
-        Dense layouts and ``n == 1`` degrade to plain serial submits.
+        ``n == 1`` degrades to a plain submit.
         On a mid-group :class:`QueueFullError` the already-queued
         siblings STAY queued (their ids are lost with the raise — a
         caller that must know them should submit serially); the group
@@ -2890,7 +2767,7 @@ class ContinuousBatcher:
         elif rng is not None:
             sib_rngs = [rng] * n
         gid = -1
-        if self._paged and n > 1:
+        if n > 1:
             with self._cv:
                 gid = self._fanout_next
                 self._fanout_next += 1
@@ -3077,7 +2954,7 @@ class ContinuousBatcher:
         """Decode-slot preemption (ticking thread, start of admission):
         when the queue's top priority class has a request whose TTFT
         budget has burned past ``preempt_ttft_fraction`` waiting and
-        neither a slot nor (paged) the pages it needs can free
+        neither a slot nor the pages it needs can free
         otherwise, preempt the LOWEST-priority active decode slot
         through the replay path (:meth:`_replay_slot` — prompt pages
         into the prefix LRU, journal-requeue, exactly-once
@@ -3096,7 +2973,7 @@ class ContinuousBatcher:
         req, prio = cand
         if any(s.req is None for s in self.slots):
             # A free slot exists — ordinary admission serves the head,
-            # UNLESS it is PAGE-starved: paged admission is
+            # UNLESS it is PAGE-starved: admission is
             # all-or-nothing, and a head whose worst-case reservation
             # the pool cannot cover even after evicting every cold
             # page (can_alloc counts the LRU) waits at the free slot
@@ -3105,8 +2982,6 @@ class ContinuousBatcher:
             # set. The need bound is conservative — prefix sharing
             # only shrinks it, so can_alloc(need) true means ordinary
             # admission will succeed.
-            if not self._paged:
-                return
             s0 = req.prompt.shape[0]
             bucket = next(b for b in self.prompt_buckets if b >= s0)
             need = -(
@@ -3249,7 +3124,7 @@ class ContinuousBatcher:
            directly; a real deployment re-streams from checkpoint);
         3. **migrate live state** via an explicit
            ``parallel.sharding.KVReshardPlan``: head-sharded KV
-           (dense strips, paged (values, scales) pools) moves
+           (pools, both members of a (values, scales) pair) moves
            per-shard — device-to-device where the shard survives,
            host-staged for the lost shard's heads — and replicated
            state (sampling ``_dstate``, draft weights/caches) re-places
@@ -3369,7 +3244,7 @@ class ContinuousBatcher:
                 self._draft_caches, repl
             )
         # Install the shrunk layout; the page table re-uploads on the
-        # first post-recovery paged tick (placement changed even where
+        # first post-recovery tick (placement changed even where
         # the host table did not).
         self._mesh = new_mesh
         self._tp = new_tp
@@ -3407,8 +3282,8 @@ class ContinuousBatcher:
         # variant per STATIC-VARIANT KEY this batcher dispatched under
         # the old epoch (every variant in use re-traces after the epoch
         # bump — a mixed-traffic batcher holds several: step_chunk's
-        # (truncate, nucleus) combos, stage_slot's key buckets,
-        # _insert's prompt buckets), plus one per dropped prefill
+        # (truncate, nucleus) combos, stage_slot's key buckets, the
+        # draft _insert's prompt buckets), plus one per dropped prefill
         # executable. Variants never re-used leave allowance slack on
         # the shared watch (the cost of not knowing future traffic, as
         # with prefill); anything beyond the allowance is still the
@@ -3419,8 +3294,7 @@ class ContinuousBatcher:
         def nvar(prog: str) -> int:
             # No floor: a family never dispatched under the old epoch
             # had no executable to re-lower, and a banked allowance
-            # would mask one future REAL phantom variant (the same rule
-            # plain-paged _insert follows below).
+            # would mask one future REAL phantom variant.
             return len(self._variants.get(prog, ()))
 
         # _clear_slot re-lowers if it compiled under the old epoch, or
@@ -3437,23 +3311,15 @@ class ContinuousBatcher:
             ),
             "continuous.prefill": prefill_dropped,
         }
-        if not self._paged or self._spec:
-            # _insert dispatches only for dense admissions and the
-            # (always-dense) draft admission — a plain paged batcher
-            # inserts via _insert_paged and must not bank an allowance
-            # that would mask a later real phantom variant.
-            expected["continuous.insert"] = nvar("continuous.insert")
-        if self._paged:
-            # Handoff-adoption variants re-lower like every other
-            # sharding-constrained program (nvar rule: only buckets
-            # actually dispatched under the old epoch).
-            expected["continuous.adopt_pages"] = nvar(
-                "continuous.adopt_pages"
-            )
-            expected["continuous.fork_page"] = nvar(
-                "continuous.fork_page"
-            )
+        # Handoff-adoption and fork variants re-lower like every other
+        # sharding-constrained program (nvar rule: only buckets
+        # actually dispatched under the old epoch).
+        expected["continuous.adopt_pages"] = nvar("continuous.adopt_pages")
+        expected["continuous.fork_page"] = nvar("continuous.fork_page")
         if self._spec:
+            # _insert dispatches only for the draft admission (its
+            # dense strips); the target inserts via _insert_paged.
+            expected["continuous.insert"] = nvar("continuous.insert")
             # One re-lower per speculation DEPTH dispatched under the
             # old epoch (the degradation ladder's set_draft_k makes
             # several possible); a spec batcher that never ticked
@@ -3653,7 +3519,7 @@ class ContinuousBatcher:
         extra: dict | None = None,
     ) -> None:
         """Replay one slot's request instead of migrating it: free the
-        slot (paged: its registered prompt pages drop into the prefix
+        slot (its registered prompt pages drop into the prefix
         LRU, so the re-admission re-enters through the prefix cache —
         a suffix-only prefill instead of a full one), discard the
         partial stream, and re-queue the request reconstructed from
@@ -3913,14 +3779,13 @@ class ContinuousBatcher:
         slot.slo_ok = True
         slot.t_first = 0.0
         slot.obs_count = 0
-        if self._paged:
-            self._pager.free_slot(slot.idx)
+        self._pager.free_slot(slot.idx)
 
     def _park_slot_row(self, idx: int) -> None:
         """Park a retired slot's device row (one donated setter
         dispatch, outside the lock): active mask off + idle-sentinel
         position, so the next chunk's garbage writes route to the
-        trash strip / trash page again. The SINGLE ``_clear_slot``
+        trash page again. The SINGLE ``_clear_slot``
         dispatch site ``_finish`` / ``_replay_slot`` / ``_drop_slot``
         share — it also books the family into ``_variants`` so
         ``recover()`` knows an old-epoch executable exists to
@@ -4131,14 +3996,13 @@ class ContinuousBatcher:
         # headroom may free a slot here (replay-path preemption); the
         # loop below then admits it first (popleft is priority-first).
         self._maybe_preempt()
-        if self._paged:
-            # Drain page claims parked by client-thread fan-out group
-            # deaths (cancel / mid-group rejection): only this thread
-            # may move pager rc.
-            with self._cv:
-                rel, self._fanout_release = self._fanout_release, []
-            for pg in rel:
-                self._pager.release_claim(pg)
+        # Drain page claims parked by client-thread fan-out group
+        # deaths (cancel / mid-group rejection): only this thread may
+        # move pager rc.
+        with self._cv:
+            rel, self._fanout_release = self._fanout_release, []
+        for pg in rel:
+            self._pager.release_claim(pg)
         for i, slot in enumerate(self.slots):
             if slot.req is not None:
                 continue
@@ -4157,46 +4021,45 @@ class ContinuousBatcher:
                 # pass is all that runs on the decode mesh.
                 self._sp_admit(req)
             m = 0
-            if self._paged:
-                # Prefix probe: acquire (rc+1) every already-cached FULL
-                # prompt page, longest run first-miss-stops. Cap at the
-                # page before the last prompt token so the suffix
-                # forward is never empty (the first sampled token needs
-                # a live last-position hidden state).
-                P = self._page
-                if self._tier is not None:
-                    # Consult the host tier BEFORE the probe declares
-                    # any miss: host-resident prefix pages readmit
-                    # (budgeted) through the adopt_cached landing path
-                    # and then share below as ordinary hits.
-                    self._maybe_readmit(req)
-                for j in range((s0 - 1) // P):
-                    key = Pager.prefix_key(req.prompt, (j + 1) * P)
-                    if self._pager.lookup_share(i, key) is None:
-                        break
-                    m += 1
-                # All-or-nothing reservation for the REST of the window
-                # (prefill writes `bucket` positions; decode reaches
-                # s0 + steps - 1). FIFO head-of-line: if the pool can't
-                # cover the next request, admission stops — later
-                # (smaller) requests do not jump it.
-                # Speculative mode reserves draft_k SLACK pages: the
-                # verify chunk's rejected overshoot writes land there,
-                # masked, instead of off the end of the window.
-                span = max(
-                    bucket, s0 + req.steps + self._spec_k + self._spec_w
-                )
-                n_pages = -(-span // P) - m
-                if not self._pager.alloc(i, n_pages):
-                    self._pager.free_slot(i)  # releases the shares too
-                    with self._cv:
-                        self._queue.appendleft(req)
-                        self._admitting = None
-                    return
-                # Radix books: token-weighted hit accounting for this
-                # admission (partial-hit counting when the match stops
-                # short of the last full prompt page).
-                self._pager.record_prefix_match(m, s0)
+            # Prefix probe: acquire (rc+1) every already-cached FULL
+            # prompt page, longest run first-miss-stops. Cap at the
+            # page before the last prompt token so the suffix
+            # forward is never empty (the first sampled token needs
+            # a live last-position hidden state).
+            P = self._page
+            if self._tier is not None:
+                # Consult the host tier BEFORE the probe declares
+                # any miss: host-resident prefix pages readmit
+                # (budgeted) through the adopt_cached landing path
+                # and then share below as ordinary hits.
+                self._maybe_readmit(req)
+            for j in range((s0 - 1) // P):
+                key = Pager.prefix_key(req.prompt, (j + 1) * P)
+                if self._pager.lookup_share(i, key) is None:
+                    break
+                m += 1
+            # All-or-nothing reservation for the REST of the window
+            # (prefill writes `bucket` positions; decode reaches
+            # s0 + steps - 1). FIFO head-of-line: if the pool can't
+            # cover the next request, admission stops — later
+            # (smaller) requests do not jump it.
+            # Speculative mode reserves draft_k SLACK pages: the
+            # verify chunk's rejected overshoot writes land there,
+            # masked, instead of off the end of the window.
+            span = max(
+                bucket, s0 + req.steps + self._spec_k + self._spec_w
+            )
+            n_pages = -(-span // P) - m
+            if not self._pager.alloc(i, n_pages):
+                self._pager.free_slot(i)  # releases the shares too
+                with self._cv:
+                    self._queue.appendleft(req)
+                    self._admitting = None
+                return
+            # Radix books: token-weighted hit accounting for this
+            # admission (partial-hit counting when the match stops
+            # short of the last full prompt page).
+            self._pager.record_prefix_match(m, s0)
             # Copy-on-write fork eligibility: a greedy fan-out sibling
             # whose probe matched EVERY page before the last prompt
             # token, with the group's source page claimed and its first
@@ -4204,8 +4067,7 @@ class ContinuousBatcher:
             # (the source page already holds the K/V of every prompt
             # position, the last one included).
             cow = (
-                self._paged
-                and fg is not None
+                fg is not None
                 and fg.greedy
                 and fg.page is not None
                 and fg.first is not None
@@ -4213,8 +4075,7 @@ class ContinuousBatcher:
                 and m == (s0 - 1) // self._page
             )
             chunked = (
-                self._paged
-                and not cow
+                not cow
                 and self._prefill_chunk is not None
                 and s0 - m * self._page > self._prefill_chunk
             )
@@ -4325,25 +4186,14 @@ class ContinuousBatcher:
                     truncate=req.top_k < self.lm.vocab,
                     nucleus=req.top_p < 1.0,
                 )
-                if self._paged:
-                    self._caches = self._insert_paged(
-                        self._caches,
-                        self._h2d(np.asarray(self._pager.owned(i), np.int32)),
-                        kvs,
-                    )
-                else:
-                    # Pad each block's (1, h, bucket, hd) K/V to the
-                    # cache length happens inside _insert via
-                    # dynamic_update_slice bounds.
-                    self._variants.setdefault(
-                        "continuous.insert", set()
-                    ).add(bucket)
-                    self._caches = self._insert(
-                        self._caches, self._h2d(np.int32(i)), kvs
-                    )
+                self._caches = self._insert_paged(
+                    self._caches,
+                    self._h2d(np.asarray(self._pager.owned(i), np.int32)),
+                    kvs,
+                )
                 self._count_prefill(s0)
                 cap_tokens = s0
-            if self._paged and not chunked:
+            if not chunked:
                 # Publish this request's full prompt pages for future
                 # sharing (first writer wins; the shared ones are
                 # already registered). Chunked admissions register on
@@ -4418,14 +4268,13 @@ class ContinuousBatcher:
                     if fg.remaining <= 0:
                         self._fanout_kill_locked(gid, fg, direct=True)
             global_metrics().inc("continuous.admitted")
-            if self._paged:
-                # Prefix-cache effectiveness per admission: prompt pages
-                # REUSED from the content-addressed cache instead of
-                # recomputed (0 on a cold admission). Per-admission, not
-                # per-token — always on, like the flight events.
-                global_metrics().observe(
-                    "paged.pages_reused_per_admission", float(m)
-                )
+            # Prefix-cache effectiveness per admission: prompt pages
+            # REUSED from the content-addressed cache instead of
+            # recomputed (0 on a cold admission). Per-admission, not
+            # per-token — always on, like the flight events.
+            global_metrics().observe(
+                "paged.pages_reused_per_admission", float(m)
+            )
             # A replay's wait measures from its re-queue, not from the
             # original submit (that span is first-life decode plus the
             # recovery wall, not time spent queued).
@@ -4705,7 +4554,7 @@ class ContinuousBatcher:
             self._caches,
             self._dstate,
             dtoks,
-            self._current_table() if self._paged else None,
+            self._current_table(),
             cands,
             sample=sample,
             truncate=truncate,
@@ -4924,7 +4773,7 @@ class ContinuousBatcher:
                     self.variables,
                     self._caches,
                     self._dstate,
-                    self._current_table() if self._paged else None,
+                    self._current_table(),
                     truncate=truncate,
                     nucleus=nucleus,
                     epoch=self._mesh_epoch,
@@ -5086,7 +4935,7 @@ class ContinuousBatcher:
         # "update" = post-commit bookkeeping: window recycling, the
         # batched ITL flush, occupancy gauges, the sentinel sample.
         with eo.region("update"):
-            if self._paged and self._window is not None:
+            if self._window is not None:
                 # Rolling-window recycling: pages wholly behind every future
                 # read ((o+1)*P <= pos - window + 1 — reads from here on
                 # mask positions < index - window + 1 and writes land at
@@ -5158,8 +5007,8 @@ class ContinuousBatcher:
                 # _h2d): the fused-staging contract is ZERO per
                 # steady-state tick, O(1) per admission/retirement.
                 "h2d_transfers": self._h2d_count,
-                # Resident KV bytes across layouts (slot strips, int8
-                # value+scale pairs, or page pools) — the capacity number
+                # Resident KV pool bytes (scale planes of a quantized
+                # pool included) — the capacity number
                 # benches and dashboards report. cache_bytes is the
                 # LOGICAL size; under tensor parallelism each device
                 # holds cache_bytes_per_device == cache_bytes / tp (the
@@ -5215,26 +5064,25 @@ class ContinuousBatcher:
                     x.nbytes
                     for x in jax.tree.leaves(self._draft_caches)
                 )
-            if self._paged:
-                ps = self._pager.stats()
-                out["pool_pages"] = ps.num_pages
-                out["pages_in_use"] = ps.in_use
-                out["pages_free"] = ps.free
-                out["pages_cached"] = ps.cached
-                out["prefix_hits"] = ps.prefix_hits
-                out["prefix_misses"] = ps.prefix_misses
-                out["prefix_capacity_skips"] = ps.prefix_capacity_skips
-                # Radix prefix-cache books: resident token-block tree
-                # size, partial-hit admissions (match stopped short of
-                # the last full prompt page), token-weighted hit mass,
-                # and radix-node evictions.
-                out["radix_nodes"] = ps.radix_nodes
-                out["radix_partial_hits"] = ps.radix_partial_hits
-                out["radix_hit_tokens"] = ps.radix_hit_tokens
-                out["radix_evictions"] = self._pager.radix_evictions
-                # Copy-on-write fan-out books.
-                out["cow_forks"] = ps.cow_forks
-                out["fanout_groups"] = len(self._fanout_groups)
+            ps = self._pager.stats()
+            out["pool_pages"] = ps.num_pages
+            out["pages_in_use"] = ps.in_use
+            out["pages_free"] = ps.free
+            out["pages_cached"] = ps.cached
+            out["prefix_hits"] = ps.prefix_hits
+            out["prefix_misses"] = ps.prefix_misses
+            out["prefix_capacity_skips"] = ps.prefix_capacity_skips
+            # Radix prefix-cache books: resident token-block tree
+            # size, partial-hit admissions (match stopped short of
+            # the last full prompt page), token-weighted hit mass,
+            # and radix-node evictions.
+            out["radix_nodes"] = ps.radix_nodes
+            out["radix_partial_hits"] = ps.radix_partial_hits
+            out["radix_hit_tokens"] = ps.radix_hit_tokens
+            out["radix_evictions"] = self._pager.radix_evictions
+            # Copy-on-write fan-out books.
+            out["cow_forks"] = ps.cow_forks
+            out["fanout_groups"] = len(self._fanout_groups)
             if self._sp_cfg is not None:
                 # Sequence-parallel prefill books: the live ring width
                 # (1 = degraded to the ordinary path) and how many
@@ -5259,25 +5107,21 @@ class ContinuousBatcher:
         locks, tolerant of racing a live tick). Keys are final metric
         names; the collector SUMS across live batchers:
 
-        - dense layout: ``memory.kv_bytes`` (LOGICAL slot strip bytes,
-          int8 value+scale pairs included) and
-          ``memory.kv_bytes_per_device`` (the per-chip resident bytes —
-          == kv_bytes / tp under a head-sharded mesh; equal otherwise);
-        - paged layout: ``memory.pool_bytes`` /
-          ``memory.pool_bytes_per_device`` (same logical-vs-per-chip
-          split) plus page occupancy —
+        - ``memory.pool_bytes`` (LOGICAL pool bytes, int8 value+scale
+          pairs included) / ``memory.pool_bytes_per_device`` (the
+          per-chip resident bytes — == pool_bytes / tp under a
+          head-sharded mesh; equal otherwise) plus page occupancy —
           ``memory.pages_used + pages_free + pages_cached ==
           memory.pool_pages`` (allocatable pool, trash page excluded) —
           and the pager's prefix-cache effectiveness counters
           (``paged.prefix_{hits,misses,capacity_skips}``);
-        - speculative mode: ``memory.draft_cache_bytes`` (the draft
-          replicates under TP, so its per-device bytes ARE its logical
-          bytes);
-        - both layouts: ``memory.kv_bytes_ratio`` — actual cache bytes
-          (scale planes INCLUDED) over what the same geometry would
-          cost in the native dtype. 1.0 native; ~(hd + 4)/(hd *
-          itemsize) quantized — the 2-4x capacity win as a dashboard
-          number.
+        - speculative mode: ``memory.draft_cache_bytes`` (the draft's
+          dense strips; it replicates under TP, so its per-device
+          bytes ARE its logical bytes);
+        - ``memory.kv_bytes_ratio`` — actual pool bytes (scale planes
+          INCLUDED) over what the same geometry would cost in the
+          native dtype. 1.0 native; ~(hd + 4)/(hd * itemsize)
+          quantized — the 2-4x capacity win as a dashboard number.
         """
         cache_bytes = float(
             sum(x.nbytes for x in jax.tree.leaves(self._caches))
@@ -5289,43 +5133,39 @@ class ContinuousBatcher:
             )
         )
         out: dict[str, float] = {}
-        if self._paged:
-            ps = self._pager.stats()
-            out["memory.pool_bytes"] = cache_bytes
-            out["memory.pool_bytes_per_device"] = per_device
-            out["memory.pool_pages"] = float(self._pager.num_allocatable)
-            out["memory.pages_used"] = float(ps.in_use)
-            out["memory.pages_cached"] = float(ps.cached)
-            # PagerStats.free counts evictable cached pages as free
-            # (allocator view); the gauges partition instead.
-            out["memory.pages_free"] = float(ps.free - ps.cached)
-            out["paged.prefix_hits"] = float(ps.prefix_hits)
-            out["paged.prefix_misses"] = float(ps.prefix_misses)
-            out["paged.prefix_capacity_skips"] = float(
-                ps.prefix_capacity_skips
-            )
-            # Radix prefix cache + copy-on-write fan-out gauges
-            # (docs/OBSERVABILITY.md "Paged KV"): resident radix-tree
-            # size, partial-hit admissions, token-weighted hit mass,
-            # and the cumulative fork count (also an inc'd counter at
-            # the fork site — the gauge makes it scrape-visible even
-            # between exporter windows).
-            out["paged.radix_nodes"] = float(ps.radix_nodes)
-            out["paged.radix_partial_hits"] = float(ps.radix_partial_hits)
-            out["paged.radix_hit_tokens"] = float(ps.radix_hit_tokens)
-            out["paged.cow_forks_total"] = float(ps.cow_forks)
-            if self._tier is not None:
-                # Host-tier occupancy: pages_spilled counts pages
-                # RESIDENT in host memory (warm + cold), host_bytes
-                # their post-codec footprint. The HBM partition above
-                # (used + free + cached == pool_pages) is untouched —
-                # the tier is a copy below it, never double-counted.
-                ts = self._tier.stats()
-                out["memory.host_bytes"] = float(ts.host_bytes)
-                out["memory.pages_spilled"] = float(ts.pages)
-        else:
-            out["memory.kv_bytes"] = cache_bytes
-            out["memory.kv_bytes_per_device"] = per_device
+        ps = self._pager.stats()
+        out["memory.pool_bytes"] = cache_bytes
+        out["memory.pool_bytes_per_device"] = per_device
+        out["memory.pool_pages"] = float(self._pager.num_allocatable)
+        out["memory.pages_used"] = float(ps.in_use)
+        out["memory.pages_cached"] = float(ps.cached)
+        # PagerStats.free counts evictable cached pages as free
+        # (allocator view); the gauges partition instead.
+        out["memory.pages_free"] = float(ps.free - ps.cached)
+        out["paged.prefix_hits"] = float(ps.prefix_hits)
+        out["paged.prefix_misses"] = float(ps.prefix_misses)
+        out["paged.prefix_capacity_skips"] = float(
+            ps.prefix_capacity_skips
+        )
+        # Radix prefix cache + copy-on-write fan-out gauges
+        # (docs/OBSERVABILITY.md "Paged KV"): resident radix-tree size,
+        # partial-hit admissions, token-weighted hit mass, and the
+        # cumulative fork count (also an inc'd counter at the fork
+        # site — the gauge makes it scrape-visible even between
+        # exporter windows).
+        out["paged.radix_nodes"] = float(ps.radix_nodes)
+        out["paged.radix_partial_hits"] = float(ps.radix_partial_hits)
+        out["paged.radix_hit_tokens"] = float(ps.radix_hit_tokens)
+        out["paged.cow_forks_total"] = float(ps.cow_forks)
+        if self._tier is not None:
+            # Host-tier occupancy: pages_spilled counts pages RESIDENT
+            # in host memory (warm + cold), host_bytes their post-codec
+            # footprint. The HBM partition above (used + free + cached
+            # == pool_pages) is untouched — the tier is a copy below
+            # it, never double-counted.
+            ts = self._tier.stats()
+            out["memory.host_bytes"] = float(ts.host_bytes)
+            out["memory.pages_spilled"] = float(ts.pages)
         out["memory.kv_bytes_ratio"] = cache_bytes / float(
             self._native_cache_bytes
         )
@@ -5354,12 +5194,8 @@ class ContinuousBatcher:
             (self.variables, self._caches, self._dstate),
         )
         a_vars, a_caches, a_dstate = av
-        a_table = (
-            jax.ShapeDtypeStruct(
-                (len(self.slots), self._pager.pages_per_slot), jnp.int32
-            )
-            if self._paged
-            else None
+        a_table = jax.ShapeDtypeStruct(
+            (len(self.slots), self._pager.pages_per_slot), jnp.int32
         )
         costs: dict[str, dict[str, float]] = {}
         try:

@@ -95,7 +95,7 @@ from adapt_tpu.models.transformer_lm import TransformerLM
 from adapt_tpu.parallel.sp_prefill import SPPrefiller, build_sp_mesh
 from adapt_tpu.runtime.capacity import prefill_tier_book
 from adapt_tpu.runtime.continuous import ContinuousBatcher
-from adapt_tpu.runtime.paged import Pager
+from adapt_tpu.runtime.paged import Pager, alloc_kv_pools, pool_geometry
 from adapt_tpu.runtime.scheduler import QueueFullError
 from adapt_tpu.utils.logging import get_logger
 from adapt_tpu.utils.metrics import global_metrics
@@ -503,33 +503,18 @@ class PrefillWorker:
         block0 = self._blocks[0]
         self._heads = block0.cache_heads
         self._head_dim = block0.head_dim
-        pps = -(-lm.max_len // page_size)
+        pps, worst = pool_geometry(slots, lm.max_len, page_size)
         if pool_pages is None:
-            pool_pages = slots * pps + 1
+            pool_pages = worst
         self._pager = Pager(pool_pages, slots, pps)
-        heads, hd = self._heads, self._head_dim
-
-        if kv_cache_dtype == "int4" and hd % 2:
-            raise ValueError(
-                f"kv_cache_dtype='int4' needs an even head_dim, got {hd}"
+        # The decode batcher's pool format, by the same definition.
+        self._pools = [
+            alloc_kv_pools(
+                pool_pages, self._heads, page_size, self._head_dim,
+                block0.dtype, kv_cache_dtype,
             )
-        vw = hd // 2 if kv_cache_dtype == "int4" else hd
-
-        def one_pool():
-            if self.quantized:
-                return (
-                    jnp.zeros(
-                        (pool_pages, heads, page_size, vw), jnp.int8
-                    ),
-                    jnp.zeros(
-                        (pool_pages, heads, page_size, 1), jnp.float32
-                    ),
-                )
-            return jnp.zeros(
-                (pool_pages, heads, page_size, hd), block0.dtype
-            )
-
-        self._pools = [(one_pool(), one_pool()) for _ in lm.block_names]
+            for _ in lm.block_names
+        ]
         self._queue: collections.deque[_PrefillJob] = collections.deque()
         self._slots: list[_PrefillJob | None] = [None] * slots
         self._table_dev = None
@@ -875,9 +860,8 @@ class _Routed:
 class DisaggServer:
     """The disaggregated submit path: one placement policy in front of
     a :class:`PrefillWorker` and a decode-side
-    :class:`~adapt_tpu.runtime.continuous.ContinuousBatcher` (which
-    must run ``kv_layout="paged"`` — the handoff lands through the
-    paged prefix cache).
+    :class:`~adapt_tpu.runtime.continuous.ContinuousBatcher` (the
+    handoff lands through its paged prefix cache).
 
     Mirrors the batcher's synchronous driver surface (``submit`` /
     ``tick`` / ``cancel`` / ``run`` / ``result`` / ``stats``), so the
@@ -899,12 +883,6 @@ class DisaggServer:
         telemetry_url: str | None = None,
         wire_codec: str | None = None,
     ):
-        if not decode._paged:
-            raise ValueError(
-                "DisaggServer requires a paged decode batcher "
-                "(kv_layout='paged') — the handoff lands through the "
-                "prefix cache"
-            )
         if prefill.page_size != decode._page:
             raise ValueError(
                 f"prefill page size {prefill.page_size} != decode page "

@@ -1,11 +1,16 @@
 """Host-side page bookkeeping for the paged KV cache.
 
-The device side is ``ops/paged_attention`` (pools + the scalar-prefetch
-kernel); this module owns the ALLOCATOR: a free list of physical pages,
-per-slot page ownership, and the (slots, pages_per_slot) page table the
-compiled step consumes. All of it is plain numpy/python on the serving
-control path — page churn is a few integers per request, never worth a
-device round trip.
+The device side is ``ops/paged_attention`` (how a pool plane is appended
+to and read, and the scalar-prefetch kernels); this module owns the
+ALLOCATOR — a free list of physical pages, per-slot page ownership, and
+the (slots, pages_per_slot) page table the compiled step consumes — and
+the pool's FORMAT: :func:`alloc_kv_pools` is the one place a block's
+K/V planes are shaped (native array, or quantized ``(values, scales)``
+pair), :func:`pool_geometry` the one place the table width and the
+default pool size are computed. The batcher, the disaggregated prefill
+worker and the sp prefiller all call them. The bookkeeping is plain
+numpy/python on the serving control path — page churn is a few integers
+per request, never worth a device round trip.
 
 Prefix caching rides on the same bookkeeping: a FULL page of prompt
 K/V is immutable once written (position p's K/V depend only on tokens
@@ -706,6 +711,66 @@ class HostKVTier:
             dropped=self.dropped,
             codec_bytes_saved=self.codec_bytes_saved,
         )
+
+
+def kv_value_width(head_dim: int, kv_cache_dtype: str) -> int:
+    """Lane width of a pool's VALUE plane: ``head_dim``, halved for
+    int4 (two nibbles packed per int8 lane — which needs an even
+    ``head_dim``)."""
+    if kv_cache_dtype != "int4":
+        return head_dim
+    if head_dim % 2:
+        raise ValueError(
+            f"kv_cache_dtype='int4' packs two nibbles per int8 lane "
+            f"and needs an even head_dim, got {head_dim}"
+        )
+    return head_dim // 2
+
+
+def alloc_kv_pools(
+    pool_pages: int,
+    kv_heads: int,
+    page_size: int,
+    head_dim: int,
+    dtype,
+    kv_cache_dtype: str = "native",
+):
+    """One decoder block's zeroed ``(K, V)`` page pools — THE definition
+    of what a pool is. Native: one ``(pool_pages, kv_heads, page_size,
+    head_dim)`` array of the block's ``dtype`` per member. Quantized
+    (``"int8"`` / ``"int4"``): a ``(values, scales)`` pair per member —
+    int8 values at :func:`kv_value_width` lanes plus one float32 scale
+    per cached vector, ``(pool_pages, kv_heads, page_size, 1)``,
+    page-addressed by the SAME table, so a shared page always carries
+    the scales its values were written with. How a plane is appended
+    to and read is ``ops/paged_attention``'s (``append_kv_paged``,
+    ``kv_transposed``)."""
+    width = kv_value_width(head_dim, kv_cache_dtype)
+
+    def one_pool():
+        if kv_cache_dtype == "native":
+            return jnp.zeros(
+                (pool_pages, kv_heads, page_size, head_dim), dtype
+            )
+        return (
+            jnp.zeros((pool_pages, kv_heads, page_size, width), jnp.int8),
+            jnp.zeros((pool_pages, kv_heads, page_size, 1), jnp.float32),
+        )
+
+    return (one_pool(), one_pool())
+
+
+def pool_geometry(
+    slots: int, max_len: int, page_size: int, slack: int = 0
+) -> tuple[int, int]:
+    """``(pages_per_slot, default_pool_pages)``: the page-table width
+    that covers ``max_len`` positions plus ``slack`` (the speculative
+    verify's overshoot: ``draft_k + tree_width``), and the worst-case
+    pool — every slot's row full, plus the trash page."""
+    if page_size < 1:
+        raise ValueError(f"page_size must be >= 1, got {page_size}")
+    pages_per_slot = -(-(max_len + slack) // page_size)
+    return pages_per_slot, slots * pages_per_slot + 1
 
 
 @partial(jax.jit, donate_argnums=(0,))

@@ -239,11 +239,6 @@ class FleetRouter:
     def _check_compat(self, name: str, engine) -> None:
         if self.prefill is None:
             return
-        if not getattr(engine, "_paged", False):
-            raise ValueError(
-                f"replica {name!r} is not paged — a prefill-tier "
-                "router lands handoffs through the prefix cache"
-            )
         if self.prefill.page_size != engine._page:
             raise ValueError(
                 f"prefill page size {self.prefill.page_size} != "

@@ -609,7 +609,7 @@ class DegradationController:
                     srv.cfg,
                     busy_prompt_threshold=srv.cfg.prompt_threshold,
                 )
-        elif step == "evict_cached" and bat._paged:
+        elif step == "evict_cached":
             # ONE-SHOT sweep at escalation, deliberately not re-run
             # while the rung holds: allocation already evicts cold
             # pages on demand (Pager.can_alloc counts the LRU), so

@@ -37,24 +37,24 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from benchmarks.common import int_flag, out_path, str_flag  # noqa: E402  (no JAX)
+from benchmarks.common import int_flag, out_path  # noqa: E402  (no JAX)
 
 VOCAB, DIM, DEPTH, HEADS, MLP = 50257, 768, 12, 12, 3072
 PROMPT_LEN, MAX_LEN = 32, 256
 
 
-def metric_name(slots: int, layout: str) -> str:
+def metric_name(slots: int) -> str:
     """ONE metric-name builder for parent and child (the parent's
     error-row metric on child failure must equal the child's success
-    metric — same rule as lm_decode.metric_suffix)."""
-    suffix = "_paged" if layout == "paged" else ""
-    return f"continuous_serve_slots{slots}{suffix}_tokens_per_sec"
+    metric — same rule as lm_decode.metric_suffix). The ``_paged``
+    suffix stays: rows without it in older artifacts are the dense
+    per-slot layout, which left in PR 29."""
+    return f"continuous_serve_slots{slots}_paged_tokens_per_sec"
 STEP_MIX = (16, 96, 32, 128)  # short/long interleave — the convoy case
 OUT = out_path("continuous_serve.json")
 
 
-def _child(slots: int, n_requests: int, small: bool, chunk: int,
-           layout: str) -> None:
+def _child(slots: int, n_requests: int, small: bool, chunk: int) -> None:
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -86,18 +86,13 @@ def _child(slots: int, n_requests: int, small: bool, chunk: int,
     total_tokens = sum(steps)
 
     # -- continuous ------------------------------------------------------
-    # layout="paged": the page-pool cache + scalar-prefetch kernels
-    # (worst-case pool so the A/B vs the slot layout is throughput
-    # apples-to-apples; capacity sizing is a separate knob). At this
-    # workload's geometry (max_len 256, page 128) every request needs
-    # its full 2 pages, so the interesting number is kernel-path
-    # throughput vs the slot layout's einsum, on identical traffic.
-    kw = (
-        {"kv_layout": "paged", "page_size": 128}
-        if layout == "paged"
-        else {}
+    # The page-pool cache + scalar-prefetch kernels at a worst-case
+    # pool (capacity sizing is a separate knob): at this workload's
+    # geometry (max_len 256, page 128) every request needs its full 2
+    # pages.
+    bat = ContinuousBatcher(
+        lm, variables, slots=slots, chunk=chunk, page_size=128
     )
-    bat = ContinuousBatcher(lm, variables, slots=slots, chunk=chunk, **kw)
     cache_bytes = bat.stats()["cache_bytes"]
     # Warm the compiled pieces (bucket prefill + step) out of the timed
     # region, mirroring generate()'s warmup below.
@@ -135,7 +130,7 @@ def _child(slots: int, n_requests: int, small: bool, chunk: int,
     print(
         json.dumps(
             {
-                "metric": metric_name(slots, layout),
+                "metric": metric_name(slots),
                 "value": round(cont_tps, 2),
                 "unit": "tokens/sec",
                 "vs_baseline": round(cont_tps / sync_tps, 4),
@@ -146,7 +141,7 @@ def _child(slots: int, n_requests: int, small: bool, chunk: int,
                 "requests": n_requests,
                 "slots": slots,
                 "chunk": chunk,
-                "kv_layout": layout,
+                "kv_layout": "paged",
                 "cache_bytes": cache_bytes,
                 "step_mix": list(STEP_MIX),
                 "continuous_s": round(cont_s, 3),
@@ -167,19 +162,17 @@ def main() -> int:
     slots = int_flag(sys.argv, "--slots", 8)
     n_requests = int_flag(sys.argv, "--requests", 32)
     chunk = int_flag(sys.argv, "--chunk", 8)
-    layout = str_flag(sys.argv, "--layout", "slots",
-                      choices=("slots", "paged"))
     cpu = "--cpu" in sys.argv
     if "--child" in sys.argv:
-        _child(slots, n_requests, cpu, chunk, layout)
+        _child(slots, n_requests, cpu, chunk)
         return 0
     env = dict(os.environ)
     if cpu:
         env["JAX_PLATFORMS"] = "cpu"
-    metric = metric_name(slots, layout)
+    metric = metric_name(slots)
     cmd = [sys.executable, os.path.abspath(__file__), "--child",
            "--slots", str(slots), "--requests", str(n_requests),
-           "--chunk", str(chunk), "--layout", layout]
+           "--chunk", str(chunk)]
     if cpu:
         cmd.append("--cpu")
     try:

@@ -138,7 +138,7 @@ def main() -> int:
         sketch_k = CapacityConfig().sketch_k
         resident = build_batcher(
             cspec.vocab, cspec.prompt_max + cspec.steps_max + 8,
-            slots=2, chunk=4, layout="paged", page_size=PAGE,
+            slots=2, chunk=4, page_size=PAGE,
         )
         warmup(resident, cspec.vocab, cspec.steps_max, cspec.prompt_max)
         drive_phase(resident, build_schedule(cspec, seed), cspec)
@@ -160,7 +160,7 @@ def main() -> int:
         # what a fresh pager exports — free slots, no affinity.
         cold = build_batcher(
             cspec.vocab, cspec.prompt_max + cspec.steps_max + 8,
-            slots=2, chunk=4, layout="paged", page_size=PAGE,
+            slots=2, chunk=4, page_size=PAGE,
         )
         cold_sketch = sketch_from_pager(cold._pager, sketch_k)
         score_cold = max(

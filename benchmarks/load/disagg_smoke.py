@@ -186,8 +186,7 @@ def main() -> int:
         # -- collocated arm: identical decode config, whole-prompt
         # admission (the documented pathology) --------------------------
         bat = build_batcher(
-            spec.vocab, max_len, SLOTS, CHUNK, layout="paged",
-            page_size=PAGE,
+            spec.vocab, max_len, SLOTS, CHUNK, page_size=PAGE,
         )
         warmup(bat, spec.vocab, spec.steps_max, spec.prompt_max)
         colo = drive_phase(bat, schedule, spec)

@@ -82,8 +82,7 @@ def main() -> int:
         arms: dict[str, dict] = {}
         for arm in ("serial", "fanout"):
             bat = build_batcher(
-                spec.vocab, max_len, SLOTS, CHUNK, layout="paged",
-                page_size=PAGE, pool_pages=POOL_PAGES,
+                spec.vocab, max_len, SLOTS, CHUNK, page_size=PAGE, pool_pages=POOL_PAGES,
             )
             warmup(bat, spec.vocab, spec.steps_max, spec.prompt_max)
             pf0 = bat.stats()["prefill_tokens"]

@@ -500,7 +500,6 @@ def build_batcher(
     max_len: int,
     slots: int,
     chunk: int,
-    layout: str = "slots",
     page_size: int = 128,
     scheduler=None,
     pool_pages: int | None = None,
@@ -514,10 +513,10 @@ def build_batcher(
     the backend is whatever the caller's environment selects: the CPU
     gates export ``JAX_PLATFORMS=cpu``). ``scheduler`` (a ``config.SchedulerConfig``) turns the
     traffic-control tier on — the quota-on arm of an overload A/B.
-    ``cache_tier`` (a ``config.CacheTierConfig``; paged only) turns
+    ``cache_tier`` (a ``config.CacheTierConfig``) turns
     the host-DRAM spill tier on — the tier-on arm of the corpus A/B —
     and ``pool_pages`` pins the HBM budget so both arms run flat.
-    ``prefill`` (a ``config.PrefillConfig``; paged only) turns the
+    ``prefill`` (a ``config.PrefillConfig``) turns the
     sequence-parallel long-context prefill path on — the sp-on arm of
     the long_context A/B (the caller must provision
     ``sp_width`` virtual devices first, e.g.
@@ -534,21 +533,11 @@ def build_batcher(
     variables = lm.graph.init(
         jax.random.PRNGKey(0), jnp.zeros((1, 4), jnp.int32)
     )
-    kw = {"page_size": page_size} if layout == "paged" else {}
-    if pool_pages is not None and layout == "paged":
-        kw["pool_pages"] = pool_pages
-    if cache_tier is not None:
-        kw["cache_tier"] = cache_tier
-    if scheduler is not None:
-        kw["scheduler"] = scheduler
-    if prefill is not None and layout == "paged":
-        kw["prefill"] = prefill
-    if prefill_chunk is not None and layout == "paged":
-        kw["prefill_chunk"] = prefill_chunk
-    if runtime is not None:
-        kw["runtime"] = runtime
     return ContinuousBatcher(
-        lm, variables, slots=slots, chunk=chunk, kv_layout=layout, **kw
+        lm, variables, slots=slots, chunk=chunk, page_size=page_size,
+        pool_pages=pool_pages, cache_tier=cache_tier,
+        scheduler=scheduler, prefill=prefill,
+        prefill_chunk=prefill_chunk, runtime=runtime,
     )
 
 
@@ -572,7 +561,7 @@ def build_disagg(
     either placement for an apples-to-apples curve. ``prefill_chunk``
     defaults to two pages (the per-tick stall bound)."""
     decode = build_batcher(
-        vocab, max_len, slots, chunk, layout="paged",
+        vocab, max_len, slots, chunk,
         page_size=page_size, scheduler=scheduler, runtime=runtime,
     )
     from adapt_tpu.config import DisaggConfig
@@ -611,9 +600,6 @@ def main() -> int:
     chunk = int_flag(sys.argv, "--chunk", 8)
     duration = int_flag(sys.argv, "--duration", 3)
     cancel_pct = int_flag(sys.argv, "--cancel-pct", 0)
-    layout = str_flag(
-        sys.argv, "--layout", "slots", choices=("slots", "paged")
-    )
     preset_name = str_flag(sys.argv, "--preset", "")
     placement = str_flag(
         sys.argv, "--placement", "collocated",
@@ -630,20 +616,19 @@ def main() -> int:
     # paged prefix cache (default CacheTierConfig) so the SAME seeded
     # schedule drives tier-on vs tier-off arms — e.g.
     # `--preset corpus --cache-tier on` vs `--cache-tier off`
-    # (implies --layout paged; the tier has no dense analog).
     tier_arg = str_flag(
         sys.argv, "--cache-tier", "off", choices=("off", "on")
     )
     # Sequence-parallel prefill: "on" routes prompts of at least
     # --sp-threshold tokens through the sp-sharded prefill program at
-    # --sp-width ring ranks (implies --layout paged) — the sp-on arm
+    # --sp-width ring ranks — the sp-on arm
     # of the long_context A/B, e.g.
     # `--preset long_context --sp on` vs `--sp off`. Virtual CPU
     # devices are provisioned automatically (force_cpu_mesh).
     # Copy-on-write fan-out: "on" submits each same-group run of
     # arrivals (the agent_trace preset's branches) through ONE
     # submit_fanout call — shared prefix pages, CoW forks on
-    # divergence (implies --layout paged); "off" submits the identical
+    # divergence; "off" submits the identical
     # schedule serially. `--preset agent_trace --fanout on` vs
     # `--fanout off` is the pair benchmarks/load/fanout_smoke.py gates.
     fanout_arg = str_flag(
@@ -688,9 +673,6 @@ def main() -> int:
             from adapt_tpu.config import CacheTierConfig
 
             cache_tier = CacheTierConfig()
-            layout = "paged"
-        if fanout_arg == "on":
-            layout = "paged"
         sp_cfg = None
         if sp_arg == "on":
             from benchmarks.common import force_cpu_mesh
@@ -701,7 +683,6 @@ def main() -> int:
             sp_cfg = PrefillConfig(
                 sp_threshold=sp_threshold, sp_width=sp_width
             )
-            layout = "paged"
         runtime = None
         if runtime_arg == "async":
             from adapt_tpu.config import RuntimeConfig
@@ -726,7 +707,6 @@ def main() -> int:
                 spec.prompt_max + spec.steps_max + 8,
                 slots,
                 chunk,
-                layout,
                 scheduler=scheduler,
                 cache_tier=cache_tier,
                 prefill=sp_cfg,
@@ -755,7 +735,6 @@ def main() -> int:
             "seed": seed,
             "slots": slots,
             "chunk": chunk,
-            "layout": layout,
             "placement": placement,
             "scheduler": sched_arg,
             "fanout": fanout_arg,

@@ -84,8 +84,7 @@ def main() -> int:
                                  sp_width=SP_WIDTH)),
         ):
             bat = build_batcher(
-                spec.vocab, max_len, SLOTS, CHUNK, layout="paged",
-                page_size=PAGE, prefill=cfg, prefill_chunk=2 * PAGE,
+                spec.vocab, max_len, SLOTS, CHUNK, page_size=PAGE, prefill=cfg, prefill_chunk=2 * PAGE,
             )
             warmup(bat, spec.vocab, spec.steps_max, spec.prompt_max)
             report = drive_phase(bat, schedule, spec)

@@ -144,8 +144,7 @@ def main() -> int:
         arms: dict[str, dict] = {}
         for arm, cfg in (("off", None), ("on", tier)):
             bat = build_batcher(
-                spec.vocab, max_len, SLOTS, CHUNK, layout="paged",
-                page_size=PAGE, pool_pages=POOL_PAGES, cache_tier=cfg,
+                spec.vocab, max_len, SLOTS, CHUNK, page_size=PAGE, pool_pages=POOL_PAGES, cache_tier=cfg,
             )
             warmup(bat, spec.vocab, spec.steps_max, spec.prompt_max)
             report = drive_phase(bat, schedule, spec)

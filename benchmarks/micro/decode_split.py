@@ -10,7 +10,7 @@ Two claims ride this driver:
    must keep every hot-path contract: greedy streams BIT-IDENTICAL
    across split in {1, 2, 4} and vs the XLA oracle, 0 h2d per steady
    tick, and 0 compile growth across churn. The grid runs split x
-   layout (dense/paged) x dtype (native/int8/int4) through the Pallas
+   dtype (native/int8/int4) through the Pallas
    INTERPRETER on CPU — wall numbers are schedule-sanity only (the
    interpreter is orders of magnitude off hardware; the TPU win is the
    parallel split fan-out the partials + rescale combine buy), but the
@@ -74,26 +74,23 @@ def main() -> int:
         extras: dict = {}
 
         # -- 1) split grid ---------------------------------------------
-        # max_len chosen so BOTH layouts hit supported kernel blocks:
-        # dense strips need cache_len % 256 == 0 (cache_len =
-        # max_len + 1 -> max_len 255 at chunk granularity), paged pools
-        # use 128-token pages. Requests outlive the measured window.
+        # 128-token pages: the block the kernels support. Requests
+        # outlive the measured window.
         steps = 2 * (n_ticks + 2) + 2
         chunk = 2
         rng = np.random.RandomState(0)
         prompts = [rng.randint(0, 41, size=5).astype(np.int32)
                    for _ in range(slots)]
 
-        def run_grid(layout, dtype, split):
-            max_len = 255 if layout == "dense" else 256
-            lm = transformer_lm(41, 32, 2, 2, 64, max_len=max_len)
+        def run_grid(dtype, split):
+            lm = transformer_lm(41, 32, 2, 2, 64, max_len=256)
             variables = lm.graph.init(
                 jax.random.PRNGKey(0), jnp.zeros((1, 4), jnp.int32)
             )
-            kw: dict = dict(kv_cache_dtype=dtype, chunk=chunk)
-            if layout == "paged":
-                kw.update(kv_layout="paged", page_size=128,
-                          pool_pages=slots * 3 + 1)
+            kw: dict = dict(
+                kv_cache_dtype=dtype, chunk=chunk, page_size=128,
+                pool_pages=slots * 3 + 1,
+            )
             kern = (
                 None if split == "xla"
                 else KernelConfig(attn_impl="pallas", decode_split=split)
@@ -117,19 +114,11 @@ def main() -> int:
             return out, h2d, wall, grew
 
         worst_h2d = 0.0
-        # Dense int8/int4 need cache_len % 1024 == 0 for the scale-tile
-        # block — out of range for this tiny config, so the quantized
-        # dense cells run the ORACLE fallback (dispatch-gauge territory,
-        # not an error); the paged cells drive the quantized kernels.
-        grid = (
-            [("dense", "native"), ("paged", "native"),
-             ("paged", "int8"), ("paged", "int4")]
-        )
-        for layout, dtype in grid:
+        for dtype in ("native", "int8", "int4"):
             base = None
             for split in ("xla", 1, 2, 4):
-                tag = f"{layout}_{dtype}_s{split}"
-                out, h2d, wall, grew = run_grid(layout, dtype, split)
+                tag = f"paged_{dtype}_s{split}"
+                out, h2d, wall, grew = run_grid(dtype, split)
                 extras[f"{tag}_tick_ms"] = round(wall, 3)
                 extras[f"{tag}_h2d_per_tick"] = h2d
                 worst_h2d = max(worst_h2d, h2d)
@@ -144,7 +133,7 @@ def main() -> int:
                         if not np.array_equal(out[rid], base[rid]):
                             errors.append(
                                 f"{tag}: stream diverged from the "
-                                f"{layout}/{dtype} baseline"
+                                f"{dtype} baseline"
                             )
                             break
 

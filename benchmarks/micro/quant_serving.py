@@ -1,20 +1,19 @@
-"""Quantized KV serving across the layout/mode grid: per-slot KV bytes
-ratio, tick wall, h2d/tick, churn compiles.
+"""Quantized KV serving across the dtype/mode grid: pool bytes ratio,
+tick wall, h2d/tick, churn compiles.
 
 The capacity claim int8 KV makes is STRUCTURAL, like tp_decode's: the
-batcher's caches — dense slot strips AND paged pools — become
-``(int8 values, f32 scales)`` pairs, so resident cache bytes drop to
-``(hd + 4) / (hd * native_itemsize)`` of the native layout (0.3125 at
-f32/hd=16) whatever the traffic, and the counter-based hot-path
-contracts must survive the composition. This driver runs the full
-dense/paged x native/int8/int4 x plain/spec grid (one small model,
+batcher's pools become ``(int8 values, f32 scales)`` pairs, so
+resident cache bytes drop to ``(hd + 4) / (hd * native_itemsize)`` of
+the native pool (0.3125 at f32/hd=16) whatever the traffic, and the
+counter-based hot-path contracts must survive the composition. This
+driver runs the full native/int8/int4 x plain/spec grid (one small model,
 identical traffic; int4 packs two nibbles per int8 lane for
 ``(hd/2 + 4) / (hd * 4)`` = 0.1875 at f32/hd=16, gated as a second
 record ``micro_quant_int4_kv_bytes_ratio`` <= 0.2) and reports per
 config:
 
 - ``<cfg>_kv_bytes`` — ``stats()["cache_bytes"]`` (scale planes
-  INCLUDED — the honest number the memory.kv_bytes gauges serve);
+  INCLUDED — the honest number the memory.pool_bytes gauge serves);
 - ``<cfg>_tick_ms`` — decode tick wall (CPU-noisy; the interpreter-mode
   attention oracle is the schedule-sanity number, not the TPU win);
 - ``<cfg>_h2d_per_tick`` — the fused-staging contract under
@@ -25,7 +24,7 @@ config:
 Structural violations (h2d > 0, compile growth, int8 not actually
 smaller, int8/native ratio off the analytic value) become ``error``
 records the gate always fails. The headline ``value`` is the WORST
-(largest) int8/native cache-bytes ratio across layouts and modes —
+(largest) int8/native cache-bytes ratio across modes —
 gated ``<= 0.55`` in ``benchmarks/baselines/seed.json`` (analytic:
 0.3125 at f32/hd=16). A bf16-native model's ratio would be 0.625 and
 fail the gate by design — the scale-plane overhead is relatively
@@ -94,65 +93,60 @@ def main() -> int:
         errors: list[str] = []
         extras: dict = {}
         kv_bytes: dict[tuple, int] = {}
-        for layout in ("slots", "paged"):
-            for dtype in ("native", "int8", "int4"):
-                for spec in (False, True):
-                    tag = (
-                        f"{'paged' if layout == 'paged' else 'dense'}"
-                        f"_{dtype}{'_spec' if spec else ''}"
+        for dtype in ("native", "int8", "int4"):
+            for spec in (False, True):
+                tag = f"paged_{dtype}{'_spec' if spec else ''}"
+                kw: dict = dict(
+                    kv_cache_dtype=dtype, chunk=8, page_size=16
+                )
+                prog = "continuous.step_chunk"
+                if spec:
+                    # Self-draft: perfect acceptance, no second
+                    # model's compile bill — the quantization
+                    # composition is what's measured here.
+                    kw.update(
+                        draft_lm=lm, draft_variables=variables,
+                        speculative=SpeculativeConfig(draft_k=3),
                     )
-                    kw: dict = dict(kv_cache_dtype=dtype, chunk=8)
-                    if layout == "paged":
-                        kw.update(kv_layout="paged", page_size=16)
-                    prog = "continuous.step_chunk"
-                    if spec:
-                        # Self-draft: perfect acceptance, no second
-                        # model's compile bill — the quantization
-                        # composition is what's measured here.
-                        kw.update(
-                            draft_lm=lm, draft_variables=variables,
-                            speculative=SpeculativeConfig(draft_k=3),
-                        )
-                        prog = "continuous.spec_verify"
-                    bat = ContinuousBatcher(
-                        lm, variables, slots=slots, **kw
+                    prog = "continuous.spec_verify"
+                bat = ContinuousBatcher(
+                    lm, variables, slots=slots, **kw
+                )
+                tick_ms, h2d = _measure(bat, slots, n_ticks, steps)
+                st = bat.stats()
+                kv_bytes[(dtype, spec)] = st["cache_bytes"]
+                extras[f"{tag}_kv_bytes"] = st["cache_bytes"]
+                extras[f"{tag}_tick_ms"] = round(tick_ms, 3)
+                extras[f"{tag}_h2d_per_tick"] = h2d
+                if h2d != 0:
+                    errors.append(f"{tag}: steady tick staged {h2d}")
+                entries = sentinel.compiles(prog)
+                bat.submit(np.arange(1, 6, dtype=np.int32), 4)
+                bat.run()
+                grew = sentinel.compiles(prog) - entries
+                if grew:
+                    errors.append(
+                        f"{tag}: churn compiled {grew} variants"
                     )
-                    tick_ms, h2d = _measure(bat, slots, n_ticks, steps)
-                    st = bat.stats()
-                    kv_bytes[(layout, dtype, spec)] = st["cache_bytes"]
-                    extras[f"{tag}_kv_bytes"] = st["cache_bytes"]
-                    extras[f"{tag}_tick_ms"] = round(tick_ms, 3)
-                    extras[f"{tag}_h2d_per_tick"] = h2d
-                    if h2d != 0:
-                        errors.append(f"{tag}: steady tick staged {h2d}")
-                    entries = sentinel.compiles(prog)
-                    bat.submit(np.arange(1, 6, dtype=np.int32), 4)
-                    bat.run()
-                    grew = sentinel.compiles(prog) - entries
-                    if grew:
-                        errors.append(
-                            f"{tag}: churn compiled {grew} variants"
-                        )
-                    bat.close()
+                bat.close()
         ratios = []
         ratios4 = []
-        for layout in ("slots", "paged"):
-            for spec in (False, True):
-                n = kv_bytes[(layout, "native", spec)]
-                q = kv_bytes[(layout, "int8", spec)]
-                q4 = kv_bytes[(layout, "int4", spec)]
-                ratios.append(q / n)
-                ratios4.append(q4 / n)
-                if q >= n:
-                    errors.append(
-                        f"{layout}{'_spec' if spec else ''}: int8 cache "
-                        f"{q} not smaller than native {n}"
-                    )
-                if q4 >= q:
-                    errors.append(
-                        f"{layout}{'_spec' if spec else ''}: int4 cache "
-                        f"{q4} not smaller than int8 {q}"
-                    )
+        for spec in (False, True):
+            n = kv_bytes[("native", spec)]
+            q = kv_bytes[("int8", spec)]
+            q4 = kv_bytes[("int4", spec)]
+            ratios.append(q / n)
+            ratios4.append(q4 / n)
+            if q >= n:
+                errors.append(
+                    f"{'spec' if spec else 'plain'}: int8 cache "
+                    f"{q} not smaller than native {n}"
+                )
+            if q4 >= q:
+                errors.append(
+                    f"{'spec' if spec else 'plain'}: int4 cache "
+                    f"{q4} not smaller than int8 {q}"
+                )
         ratio = max(ratios)
         ratio4 = max(ratios4)
         extras["kv_bytes_ratio_min"] = round(min(ratios), 4)

@@ -2,7 +2,7 @@
 wall time at tp = {1, 2, 4}.
 
 The capacity claim TP serving makes is STRUCTURAL: the batcher's KV
-caches (dense slot strips here) shard on their head axis over the
+pools shard on their head axis over the
 mesh's ``tp`` axis, so each device holds exactly ``logical / tp`` bytes
 — a model whose KV residency busts one chip's HBM fits a tp-group, and
 like the other micro drivers that counter transfers to the TPU run

@@ -247,9 +247,9 @@ def kernel_cases(size, prefer):
             routed,
         )
 
-    # Dense slot-layout decode (KernelConfig.attn_impl="pallas" selects
-    # it; auto keeps XLA until its A/B) and ragged flash prefill (auto
-    # only past the score budget) are forced: reachable, not default.
+    # The dense decode kernel (generate()'s and the draft's strips;
+    # auto keeps XLA, ROADMAP C1b) and ragged flash prefill (auto only
+    # past the score budget) are forced: reachable, not default.
     cache_len = lmc["max_len"]
     q = _normal(jax.random.fold_in(key, 6), (SLOTS, kvh, 1, hd))
     idx = jnp.asarray(rng.randint(1, cache_len, size=SLOTS), jnp.int32)

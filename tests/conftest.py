@@ -124,14 +124,19 @@ def spawn_worker_proc(*cli_args: str) -> "subprocess.Popen":
     )
 
 
-def chain_cfg(configure_timeout_s: float = 60.0):
+def chain_cfg(configure_timeout_s: float = 60.0, lease_ttl_s: float = 2.0):
     """ServeConfig used by the chain-forwarding tests (shared by
-    test_comm and test_control)."""
+    test_comm and test_control). ``lease_ttl_s``: a test whose subject
+    is a death learnt from the LINK (drop -> deregister) passes a lease
+    no starved child can lapse — under the suite's six workers a worker
+    process compiling its stage has gone 2 s without a ping, its lease
+    lapsed before the test's kill, and the kill then had no lease left
+    to revoke."""
     from adapt_tpu.config import FaultConfig, ServeConfig
 
     return ServeConfig(
         fault=FaultConfig(
-            lease_ttl_s=2.0,
+            lease_ttl_s=lease_ttl_s,
             heartbeat_s=0.2,
             task_deadline_s=30.0,
             watchdog_period_s=0.2,

@@ -100,26 +100,24 @@ def test_runtime_config_validation():
 
 
 @pytest.mark.parametrize(
-    "layout",
+    "page_size",
     [
-        # Tier-1 budget: the paged variant carries the identity pin
-        # (the richer layout — pages, window recycling, prefix cache);
-        # the dense-strip variant re-proves the same invariant and
-        # rides tier 2 (the composed spec×int8×tp slots variant below
-        # is slow-marked for the same reason).
-        pytest.param("slots", marks=pytest.mark.slow),
-        "paged",
+        # Tier-1 budget: small pages carry the identity pin (requests
+        # span pages, decode crosses boundaries, the prefix cache has
+        # full pages to share); one page a request re-proves the same
+        # invariant and rides tier 2 (the composed spec×int8×tp
+        # variant below is slow-marked for the same reason).
+        pytest.param(128, marks=pytest.mark.slow),
+        8,
     ],
 )
-def test_async_bit_identical_staggered(lm_setup, layout):
+def test_async_bit_identical_staggered(lm_setup, page_size):
     """THE identity pin: the same staggered workload (admits,
     retirements, mid-stream EOS-by-steps) under depth 1 and depth 2
-    yields bit-identical streams on both layouts, each equal to solo
-    generate(); books balance and the pipeline drains empty."""
+    yields bit-identical streams at both page sizes, each equal to
+    solo generate(); books balance and the pipeline drains empty."""
     lm, variables = lm_setup
-    kw = dict(slots=3, chunk=2)
-    if layout == "paged":
-        kw.update(kv_layout="paged", page_size=8)
+    kw = dict(slots=3, chunk=2, page_size=page_size)
     outs = {}
     for depth in (1, 2):
         bat = ContinuousBatcher(
@@ -211,38 +209,37 @@ def test_async_zero_h2d_and_compile_footprint(lm_setup):
     bat.close()
 
 
-@pytest.mark.parametrize("layout", ["paged"])
+@pytest.mark.parametrize("page_size", [8])
 def test_async_spec_int8_tp2_bit_identical(
-    lm_setup, draft_setup, sim_mesh, layout
+    lm_setup, draft_setup, sim_mesh, page_size
 ):
     """The composed pin: speculative + int8 KV + tp=2, depth 1 vs
     depth 2 — streams bit-identical to each other and to solo
     generate(kv_cache_dtype='int8'); exactly ONE verify variant
     compiles per batcher (two-program footprint under the async
     loop)."""
-    _async_spec_int8_tp2(lm_setup, draft_setup, sim_mesh, layout)
+    _async_spec_int8_tp2(lm_setup, draft_setup, sim_mesh, page_size)
 
 
 @pytest.mark.slow
-@pytest.mark.parametrize("layout", ["slots"])
+@pytest.mark.parametrize("page_size", [128])
 def test_async_spec_int8_tp2_bit_identical_slow(
-    lm_setup, draft_setup, sim_mesh, layout
+    lm_setup, draft_setup, sim_mesh, page_size
 ):
-    """Second layout of the composed pin (slow: tier-1 carries the
-    paged variant; the dense-strip layout re-pays the GSPMD compiles
+    """Second page size of the composed pin (slow: tier-1 carries the
+    small-page variant; one page a request re-pays the GSPMD compiles
     for the same claim)."""
-    _async_spec_int8_tp2(lm_setup, draft_setup, sim_mesh, layout)
+    _async_spec_int8_tp2(lm_setup, draft_setup, sim_mesh, page_size)
 
 
-def _async_spec_int8_tp2(lm_setup, draft_setup, sim_mesh, layout):
+def _async_spec_int8_tp2(lm_setup, draft_setup, sim_mesh, page_size):
     lm, variables = lm_setup
     draft, dvars = draft_setup
     sentinel = global_compile_sentinel()
     kw = dict(slots=2, kv_cache_dtype="int8", draft_lm=draft,
               draft_variables=dvars,
-              speculative=SpeculativeConfig(draft_k=3))
-    if layout == "paged":
-        kw.update(kv_layout="paged", page_size=8)
+              speculative=SpeculativeConfig(draft_k=3),
+              page_size=page_size)
     prompts, steps = PROMPTS[:3], [7, 9, 5]
     outs = {}
     for depth in (1, 2):

@@ -467,13 +467,24 @@ def test_chain_failure_falls_back_to_hub_exactly_once(devices):
     cuts = vit_block_cuts(4, 3)
     plan = partition(g, cuts)
     y_ref = np.asarray(g.apply(variables, x))
-    cfg = chain_cfg()
+    # The death below is learnt from the link, not from the lease: a
+    # 2 s lease lapsed under load while the worker compiled (conftest).
+    cfg = chain_cfg(lease_ttl_s=120.0)
     disp = Dispatcher(plan, variables, config=cfg)
     # Local fallback capacity for after the kill.
     disp.spawn_workers(devices[:2])
     procs, proxies = chain_pool(disp, cfg, cuts, [17631, 17632, 17633])
+    mid_left = threading.Event()
     try:
         disp.start()
+        # Registered after the dispatcher's own watcher (start() adds
+        # it): when this one fires, the dispatcher has already handled
+        # the same leave.
+        disp.registry.watch(
+            lambda event, wid: mid_left.set()
+            if (event, wid) == ("leave", proxies[1].worker_id)
+            else None
+        )
         for pr in proxies:
             pr.start()
         disp.setup_chain([pr.worker_id for pr in proxies])
@@ -484,11 +495,9 @@ def test_chain_failure_falls_back_to_hub_exactly_once(devices):
             )
         proxies[1].kill("crash")
         # Membership notices (link drop -> deregister) and the chain
-        # disables itself.
-        deadline = time.monotonic() + 10.0
-        while disp._chain is not None:
-            assert time.monotonic() < deadline, "chain never disabled"
-            time.sleep(0.05)
+        # disables itself: wait on that event, with a limit of its own.
+        assert mid_left.wait(60.0), "mid-chain death never reached membership"
+        assert disp._chain is None, "chain still enabled after its member left"
         outs2 = disp.serve_stream([x] * 4, timeout_per_request=120.0)
         for y in outs2:
             np.testing.assert_allclose(
@@ -840,7 +849,11 @@ def test_worker_joins_running_pipeline_via_gateway(devices):
 
     cfg = ServeConfig(
         fault=FaultConfig(
-            lease_ttl_s=1.0,
+            # Every death here is learnt from an event (the local
+            # workers' crash eviction), none from a lapsed lease: at
+            # 1 s a joiner compiling its stage under the suite's six
+            # workers lost its lease and the last assertion with it.
+            lease_ttl_s=120.0,
             heartbeat_s=0.2,
             task_deadline_s=30.0,
             watchdog_period_s=0.1,

@@ -1,7 +1,8 @@
-"""Continuous batching: requests served through the slot-based batcher
-must emit token-for-token what single-program ``generate()`` emits for
-each request ALONE — slot scheduling, bucketed prefill, admission order,
-and lockstep ticking must be invisible in outputs."""
+"""Continuous batching: requests served through the batcher must emit
+token-for-token what single-program ``generate()`` emits for each
+request ALONE — slot scheduling, bucketed prefill, admission order,
+lockstep ticking and where a request's pages end must be invisible in
+outputs."""
 
 import jax
 import jax.numpy as jnp
@@ -21,14 +22,22 @@ def lm_setup():
     return lm, variables
 
 
+#: The stream-identity tests run at both ends of the page axis: at the
+#: default page (128) every request of this 48-position model lives
+#: inside one partial page; at 8 a request spans several pages and its
+#: decode crosses page boundaries mid-stream.
+PAGE_SIZES = pytest.mark.parametrize("page_size", [8, 128])
+
+
 def _solo(lm, variables, prompt, steps, **kw):
     return np.asarray(
         generate(lm, variables, jnp.asarray(prompt)[None], steps, **kw)
     )[0]
 
 
+@PAGE_SIZES
 @pytest.mark.parametrize("chunk", [1, 8])
-def test_staggered_greedy_requests_match_generate(lm_setup, chunk):
+def test_staggered_greedy_requests_match_generate(lm_setup, chunk, page_size):
     """Requests of different lengths arriving at different times (some
     mid-decode of others) each match their solo generate() output —
     whether ticks run one step (fully reactive) or a compiled 8-step
@@ -39,7 +48,9 @@ def test_staggered_greedy_requests_match_generate(lm_setup, chunk):
                for n in (3, 9, 5, 12, 7)]
     steps = [6, 4, 8, 3, 5]
 
-    bat = ContinuousBatcher(lm, variables, slots=3, chunk=chunk)
+    bat = ContinuousBatcher(
+        lm, variables, slots=3, chunk=chunk, page_size=page_size
+    )
     ids = {}
     for i in range(2):
         ids[bat.submit(prompts[i], steps[i])] = i
@@ -54,14 +65,17 @@ def test_staggered_greedy_requests_match_generate(lm_setup, chunk):
         np.testing.assert_array_equal(out[rid], want, err_msg=f"req {i}")
 
 
-def test_sampled_requests_match_generate(lm_setup):
+@PAGE_SIZES
+def test_sampled_requests_match_generate(lm_setup, page_size):
     """Per-request key schedules reproduce generate()'s sampled streams
     even when greedy and sampled requests share the lockstep batch."""
     lm, variables = lm_setup
     p1 = np.asarray([1, 2, 3, 4], np.int32)
     p2 = np.asarray([5, 6, 7], np.int32)
     p3 = np.asarray([8, 9, 10, 11, 12], np.int32)
-    bat = ContinuousBatcher(lm, variables, slots=2, top_k=5)
+    bat = ContinuousBatcher(
+        lm, variables, slots=2, top_k=5, page_size=page_size
+    )
     r1 = bat.submit(p1, 6, temperature=0.9, rng=jax.random.PRNGKey(7))
     r2 = bat.submit(p2, 5)  # greedy, same batch
     r3 = bat.submit(p3, 4, temperature=1.3, rng=jax.random.PRNGKey(9))
@@ -79,7 +93,8 @@ def test_sampled_requests_match_generate(lm_setup):
     )
 
 
-def test_eos_frees_slot_stream_matches_prefix(lm_setup):
+@PAGE_SIZES
+def test_eos_frees_slot_stream_matches_prefix(lm_setup, page_size):
     """EOS finishes a request early: the emitted stream equals
     generate()'s output up to and including the first EOS (generate pads
     with EOS after; a server frees the slot instead)."""
@@ -88,7 +103,7 @@ def test_eos_frees_slot_stream_matches_prefix(lm_setup):
     full = _solo(lm, variables, p, 8)
     eos = int(full[1])  # the second greedy token -> finishes after 2
     padded = _solo(lm, variables, p, 8, eos_id=eos)
-    bat = ContinuousBatcher(lm, variables, slots=2)
+    bat = ContinuousBatcher(lm, variables, slots=2, page_size=page_size)
     rid = bat.submit(p, 8, eos_id=eos)
     out = bat.run()
     n = len(out[rid])
@@ -96,13 +111,14 @@ def test_eos_frees_slot_stream_matches_prefix(lm_setup):
     np.testing.assert_array_equal(out[rid], padded[:n])
 
 
-def test_more_requests_than_slots(lm_setup):
+@PAGE_SIZES
+def test_more_requests_than_slots(lm_setup, page_size):
     """Slots recycle: 7 requests drain through 2 slots."""
     lm, variables = lm_setup
     rng = np.random.RandomState(3)
     reqs = [rng.randint(0, 37, size=rng.randint(2, 10)).astype(np.int32)
             for _ in range(7)]
-    bat = ContinuousBatcher(lm, variables, slots=2)
+    bat = ContinuousBatcher(lm, variables, slots=2, page_size=page_size)
     ids = {bat.submit(p, 4): p for p in reqs}
     out = bat.run()
     assert set(out) == set(ids)
@@ -138,8 +154,9 @@ def test_per_request_top_k_matches_generate(lm_setup):
                        rng=jax.random.PRNGKey(23)))
 
 
-def test_int8_slot_caches_match_generate_int8(lm_setup):
-    """Quantized slot caches reproduce generate(kv_cache_dtype="int8")
+@PAGE_SIZES
+def test_int8_pools_match_generate_int8(lm_setup, page_size):
+    """Quantized pools reproduce generate(kv_cache_dtype="int8")
     exactly — same absmax-per-vector scheme, so the only difference is
     where the cache lives."""
     lm, variables = lm_setup
@@ -147,7 +164,8 @@ def test_int8_slot_caches_match_generate_int8(lm_setup):
     prompts = [rng.randint(0, 37, size=n).astype(np.int32)
                for n in (4, 7, 3)]
     bat = ContinuousBatcher(
-        lm, variables, slots=2, kv_cache_dtype="int8", chunk=4
+        lm, variables, slots=2, kv_cache_dtype="int8", chunk=4,
+        page_size=page_size,
     )
     ids = {bat.submit(p, 6): p for p in prompts}
     out = bat.run()
@@ -274,7 +292,7 @@ def test_threaded_serving_matches_generate(lm_setup):
 
 
 def test_gqa_requests_match_generate():
-    """A GQA model serves through the batcher: slot caches allocate the
+    """A GQA model serves through the batcher: the pools allocate the
     smaller kv_heads layout and every stream still matches its solo
     generate()."""
     from adapt_tpu.models.transformer_lm import transformer_lm
@@ -290,9 +308,10 @@ def test_gqa_requests_match_generate():
                for n in (3, 7, 5)]
     steps = [6, 4, 5]
 
-    bat = ContinuousBatcher(lm, variables, slots=2, chunk=1)
-    # 2 kv heads, head_dim 8, max_len+1 cache rows.
-    assert bat._caches[0][0].shape == (2, 2, 49, 8)
+    bat = ContinuousBatcher(lm, variables, slots=2, chunk=1, page_size=8)
+    # 2 slots x 6 pages + trash; 2 kv heads (of 4 query heads), page 8,
+    # head_dim 8.
+    assert bat._caches[0][0].shape == (13, 2, 8, 8)
     ids = {bat.submit(p, s): i
            for i, (p, s) in enumerate(zip(prompts, steps))}
     out = bat.run()
@@ -301,7 +320,8 @@ def test_gqa_requests_match_generate():
         np.testing.assert_array_equal(out[rid], want, err_msg=f"req {i}")
 
 
-def test_stop_sequences_truncate_at_first_match(lm_setup):
+@PAGE_SIZES
+def test_stop_sequences_truncate_at_first_match(lm_setup, page_size):
     """A stop sequence ends the stream at its first occurrence
     (inclusive); the emitted prefix equals solo generate()'s prefix."""
     lm, variables = lm_setup
@@ -309,7 +329,7 @@ def test_stop_sequences_truncate_at_first_match(lm_setup):
     full = _solo(lm, variables, p, 12)
     # Pick the stop sequence FROM the greedy stream so it must trigger.
     stop_seq = [int(full[4]), int(full[5])]
-    bat = ContinuousBatcher(lm, variables, slots=2)
+    bat = ContinuousBatcher(lm, variables, slots=2, page_size=page_size)
     rid = bat.submit(p, 12, stop=[stop_seq, [999]])
     out = bat.run()
     got = out[rid]
@@ -323,11 +343,14 @@ def test_stop_sequences_truncate_at_first_match(lm_setup):
     np.testing.assert_array_equal(out2[rid2], full)
 
 
-def test_cancel_queued_and_midflight(lm_setup):
+@PAGE_SIZES
+def test_cancel_queued_and_midflight(lm_setup, page_size):
     lm, variables = lm_setup
     p1 = np.asarray([4, 5, 6, 7], np.int32)
     p2 = np.asarray([8, 9], np.int32)
-    bat = ContinuousBatcher(lm, variables, slots=1, chunk=2)
+    bat = ContinuousBatcher(
+        lm, variables, slots=1, chunk=2, page_size=page_size
+    )
     r1 = bat.submit(p1, 30)
     r2 = bat.submit(p2, 5)  # waits in queue (1 slot)
     bat.tick()
@@ -344,6 +367,7 @@ def test_cancel_queued_and_midflight(lm_setup):
     )
     assert bat.stats()["active"] == 0
     assert not bat._cancelled  # no leaked cancel markers
+    assert bat.stats()["pages_in_use"] == 0  # the cancel freed its pages
 
 
 def test_cancel_finished_request_returns_false(lm_setup):
@@ -477,3 +501,17 @@ def test_fused_staging_transfer_counts(lm_setup):
     )
     out = bat.run()
     assert set(out) == {r1, r2}
+
+
+def test_slots_layout_left_in_pr29(lm_setup):
+    """``kv_layout=`` survives only as a keyword callers outside the
+    package still pass: the default batcher is paged, ``"paged"`` is
+    accepted, and the per-slot dense layout raises by name."""
+    lm, variables = lm_setup
+    bat = ContinuousBatcher(lm, variables, slots=2)
+    assert bat.stats()["pool_pages"] == 2 * 1 + 1  # ceil(48 / 128) a slot
+    assert not hasattr(bat, "_paged")
+    ContinuousBatcher(lm, variables, slots=2, kv_layout="paged")
+    for layout in ("slots", "vram"):
+        with pytest.raises(ValueError, match="PR 29"):
+            ContinuousBatcher(lm, variables, slots=2, kv_layout=layout)

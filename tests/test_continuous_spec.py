@@ -320,27 +320,22 @@ def test_spec_validation(lm_setup, draft_setup):
 
 
 @pytest.mark.slow
-@pytest.mark.parametrize("layout", ["slots", "paged"])
+@pytest.mark.parametrize("page_size", [128, 8])
 @pytest.mark.parametrize("perfect", [True, False])
-def test_spec_fuzz_staggered_lossless(lm_setup, draft_setup, layout,
+def test_spec_fuzz_staggered_lossless(lm_setup, draft_setup, page_size,
                                       perfect):
     """Randomized serving traffic against the speculative tick:
     staggered admits, retirements, cancels, mixed prompt lengths and
-    step counts, perfect and adversarial drafts, dense and paged
-    layouts — every surviving stream token-for-token equals its solo
+    step counts, perfect and adversarial drafts, one page a request
+    and several — every surviving stream token-for-token equals its solo
     generate()."""
     lm, variables = lm_setup
     draft, dvars = draft_setup
     d_lm, d_vars = (lm, variables) if perfect else (draft, dvars)
     rng = np.random.RandomState(17 if perfect else 18)
-    kw = (
-        dict(kv_layout="paged", page_size=8)
-        if layout == "paged"
-        else {}
-    )
     bat = ContinuousBatcher(
         lm, variables, slots=3, draft_lm=d_lm, draft_variables=d_vars,
-        speculative=SpeculativeConfig(draft_k=3), **kw,
+        speculative=SpeculativeConfig(draft_k=3), page_size=page_size,
     )
     want, cancelled = {}, set()
     pending = []

@@ -311,17 +311,15 @@ def test_roofline_peaks_per_generation(monkeypatch):
 
 
 @pytest.mark.slow
-@pytest.mark.parametrize("layout", ["dense", "paged"])
-def test_batcher_split_streams_bit_identical(layout):
+def test_batcher_split_streams_bit_identical():
     """Greedy streams are BIT-IDENTICAL across split in {1, 2, 4} and
-    vs the default XLA path on both layouts, across staggered
+    vs the default XLA path, across staggered
     admits/retires/cancels; 0 h2d per steady tick and a frozen compile
     footprint hold under the split kernels (sentinel-pinned)."""
     from adapt_tpu.utils.profiling import global_compile_sentinel
 
-    max_len = 255 if layout == "dense" else 256
-    lm = transformer_lm(VOCAB, 32, 2, 2, 64, max_len=max_len,
-                        name=f"split_{layout}")
+    lm = transformer_lm(VOCAB, 32, 2, 2, 64, max_len=256,
+                        name="split_paged")
     variables = lm.graph.init(
         jax.random.PRNGKey(0), jnp.zeros((1, 4), jnp.int32)
     )
@@ -336,11 +334,9 @@ def test_batcher_split_streams_bit_identical(layout):
         ("s2", KernelConfig(attn_impl="pallas", decode_split=2)),
         ("s4", KernelConfig(attn_impl="pallas", decode_split=4)),
     ):
-        kw: dict = dict(chunk=2)
-        if layout == "paged":
-            kw.update(kv_layout="paged", page_size=128, pool_pages=9)
         bat = ContinuousBatcher(
-            lm, variables, slots=2, kernel=kern, **kw
+            lm, variables, slots=2, kernel=kern, chunk=2,
+            page_size=128, pool_pages=9,
         )
         # staggered admits, then a steady-state window with BOTH slots
         # mid-flight (steps sized to outlive it — a retirement's
@@ -365,7 +361,7 @@ def test_batcher_split_streams_bit_identical(layout):
         for i in (0, 1):
             np.testing.assert_array_equal(
                 streams[tag][i], streams["xla"][i],
-                err_msg=f"{layout}/{tag} req {i} diverged",
+                err_msg=f"{tag} req {i} diverged",
             )
 
 
@@ -401,22 +397,22 @@ def test_batcher_split_speculative_int8():
 # -- tree drafts -------------------------------------------------------------
 
 
-@pytest.mark.parametrize("layout", ["dense", "paged"])
-def test_tree_spec_lossless_and_beats_chain(lm_setup, layout):
+@pytest.mark.parametrize("page_size", [8, 128])
+def test_tree_spec_lossless_and_beats_chain(lm_setup, page_size):
     """tree_width=1: the emitted stream is STILL exactly the target's
     greedy stream (lossless, staggered admits + a cancel), and the
     perfect-draft arm commits > 5.0 tokens per verify pass at
-    draft_k=4 (the chain's ceiling)."""
+    draft_k=4 (the chain's ceiling) — with the leaf rows and the
+    accepted leaf's move inside one page (128) and across page
+    boundaries (8)."""
     lm, variables = lm_setup
     rng = np.random.RandomState(3)
     prompts = [rng.randint(0, VOCAB, size=n).astype(np.int32)
                for n in (4, 6)]
-    kw: dict = {}
-    if layout == "paged":
-        kw.update(kv_layout="paged", page_size=8)
     bat = ContinuousBatcher(
         lm, variables, slots=2, draft_lm=lm, draft_variables=variables,
-        speculative=SpeculativeConfig(draft_k=4, tree_width=1), **kw,
+        speculative=SpeculativeConfig(draft_k=4, tree_width=1),
+        page_size=page_size,
     )
     r1 = bat.submit(prompts[0], 40)
     bat.tick()
@@ -463,16 +459,23 @@ def test_tree_spec_adversarial_draft_still_lossless(lm_setup):
 # -- int4 composition --------------------------------------------------------
 
 
-def test_int4_top1_agreement_vs_int8():
-    """Teacher-forced per-step top-1 agreement between int4 and int8
-    caches >= 0.95: both caches serve the SAME committed stream (the
-    int8 greedy stream) and the next-token argmaxes are compared at
-    every step — the quantization perturbation alone, no free-running
-    divergence compounding. Seeds are PINNED (untrained toy models'
-    argmax gaps vary widely across inits; this deterministic
-    configuration measures 1.0/0.988 across the two pinned prompts —
-    the gate guards the quantization scheme, i.e. a packing or scale
-    regression would crater it, not the toy model's luck)."""
+def test_int4_logits_close_to_int8():
+    """Teacher-forced next-token LOGITS of the int4 and int8 caches
+    stay within a stated tolerance: both caches serve the SAME
+    committed stream (the int8 greedy stream), so the numbers compared
+    carry the quantization perturbation alone, no free-running
+    divergence. Logits, not argmax tokens: on random weights the top
+    two logits sit 0.15-0.19 apart (median), closer than int4's honest
+    error, so top-1 agreement (0.85 here) measures the toy model's
+    luck and not the scheme (``model-configs`` guide: compare logits
+    within a tolerance).
+
+    The tolerance: int4's absmax step is a vector's largest entry over
+    7, int8's over 127 — 18 times coarser. int8 reads within 0.019 of
+    the native cache here (asserted under 0.05), so int4 is allowed
+    0.5 of int8 (read: 0.24 and 0.33 on logits of spread 1.0-1.1 and
+    range 4.8-5.3). A packing or scale regression (nibbles swapped, a
+    scale of the wrong vector) moves logits by their whole range."""
     lm = transformer_lm(13, 64, 2, 2, 128, max_len=96, name="i4_agree")
     variables = lm.graph.init(
         jax.random.PRNGKey(0), jnp.zeros((1, 4), jnp.int32)
@@ -483,7 +486,7 @@ def test_int4_top1_agreement_vs_int8():
     head = g.node("head").module
     blocks = [g.node(n).module for n in lm.block_names]
 
-    def preds(dt, prompt, stream):
+    def logits(dt, prompt, stream):
         quant = dt if dt != "native" else False
         h = embed.apply(variables["embed"], prompt)
         caches = []
@@ -493,9 +496,7 @@ def test_int4_top1_agreement_vs_int8():
                 method="prefill",
             )
             caches.append((ck, cv))
-        out = [int(jnp.argmax(
-            head.apply(variables["head"], h[:, -1:, :])[:, 0], -1
-        )[0])]
+        out = [head.apply(variables["head"], h[:, -1:, :])[0, 0]]
         idx = prompt.shape[1]
         for t in stream:
             x = embed.apply(
@@ -512,40 +513,42 @@ def test_int4_top1_agreement_vs_int8():
                 )
                 new.append((ck, cv))
             caches = new
-            out.append(int(jnp.argmax(
-                head.apply(variables["head"], x)[:, 0], -1
-            )[0]))
+            out.append(head.apply(variables["head"], x)[0, 0])
             idx += 1
-        return out
+        return np.stack([np.asarray(o) for o in out])
 
-    agree = total = 0
     for trial in range(2):
         p = jnp.asarray(rng.randint(0, lm.vocab, (1, 6)), jnp.int32)
         stream = [int(t) for t in np.asarray(
             generate(lm, variables, p, 20, kv_cache_dtype="int8")
         )[0][:-1]]
-        a = preds("int8", p, stream)
-        b = preds("int4", p, stream)
-        agree += sum(x == y for x, y in zip(a, b))
-        total += len(a)
-    assert agree / total >= 0.95, f"top-1 agreement {agree}/{total}"
+        native = logits("native", p, stream)
+        int8 = logits("int8", p, stream)
+        int4 = logits("int4", p, stream)
+        assert np.isfinite(int4).all()
+        err8 = float(np.abs(int8 - native).max())
+        err4 = float(np.abs(int4 - int8).max())
+        assert err8 <= 0.05, f"trial {trial}: int8 vs native {err8}"
+        assert err4 <= 0.5, f"trial {trial}: int4 vs int8 {err4}"
 
 
 def test_int4_batcher_lossless_and_prefix_cache(lm_setup):
     """int4 batcher streams equal solo generate(kv_cache_dtype='int4')
-    on both layouts, and a re-submitted prompt enters through the
-    prefix cache (its int4 pages + scale planes are reused)."""
+    inside one page (128) and across pages (8), and a re-submitted
+    prompt with full pages enters through the prefix cache (its int4
+    pages + scale planes are reused)."""
     lm, variables = lm_setup
     p = np.asarray(list(range(1, 19)), np.int32)  # 2 full 8-pages
     solo = _solo(lm, variables, p, 6, kv_cache_dtype="int4")
-    for kw in ({}, {"kv_layout": "paged", "page_size": 8}):
+    for page_size in (128, 8):
         bat = ContinuousBatcher(
-            lm, variables, slots=2, kv_cache_dtype="int4", **kw
+            lm, variables, slots=2, kv_cache_dtype="int4",
+            page_size=page_size,
         )
         r1 = bat.submit(p, 6)
         out1 = bat.run()[r1]
         np.testing.assert_array_equal(out1, solo)
-        if kw:
+        if page_size == 8:
             hits0 = bat._pager.prefix_hits
             r2 = bat.submit(p, 6)
             out2 = bat.run()[r2]

@@ -246,15 +246,6 @@ def test_fetch_head_shards_matches_logical(sim_mesh):
     np.testing.assert_array_equal(got, np.asarray(x[1]))
 
 
-def test_cache_tier_requires_paged(lm_setup):
-    lm, variables = lm_setup
-    with pytest.raises(ValueError, match="paged"):
-        ContinuousBatcher(
-            lm, variables, slots=1, kv_layout="slots",
-            cache_tier=CacheTierConfig(),
-        )
-
-
 # -- the serving path --------------------------------------------------------
 
 

@@ -2,11 +2,11 @@
 
 The contract stack, bottom-up: the ``Pager`` free-list bookkeeping, the
 ``ops/paged_attention`` kernel against its gather oracle (which itself
-reduces to the contiguous decode oracle), and the ``ContinuousBatcher``
-with ``kv_layout="paged"`` emitting token-for-token what ``generate()``
-emits for each request alone — the same invisibility bar the slot
-layout is held to — including under a pool small enough to force
-requests to wait for pages."""
+reduces to the contiguous decode oracle), the pool's format
+(``alloc_kv_pools`` / ``pool_geometry``, the one definition three
+callers share), and the ``ContinuousBatcher`` emitting token-for-token
+what ``generate()`` emits for each request alone — including under a
+pool small enough to force requests to wait for pages."""
 
 import jax
 import jax.numpy as jnp
@@ -25,7 +25,13 @@ from adapt_tpu.ops.paged_attention import (
 )
 from adapt_tpu.ops.quantize import quantize_kv_vectors
 from adapt_tpu.runtime.continuous import ContinuousBatcher
-from adapt_tpu.runtime.paged import Pager, insert_prefill_pages
+from adapt_tpu.runtime.paged import (
+    Pager,
+    alloc_kv_pools,
+    insert_prefill_pages,
+    kv_value_width,
+    pool_geometry,
+)
 
 
 # -- allocator ---------------------------------------------------------------
@@ -52,6 +58,100 @@ def test_pager_validation():
     p = Pager(8, 2, 2)
     with pytest.raises(ValueError, match="table width"):
         p.alloc(0, 3)
+
+
+# -- the pool's format: one owner --------------------------------------------
+
+#: What ContinuousBatcher, disagg.PrefillWorker and SPPrefiller each
+#: spelled out for themselves until PR 29, per member of a block's
+#: (K, V): [(shape, dtype), ...] at 9 pages, 2 kv heads, page 16,
+#: head_dim 8.
+_POOL_FORMATS = {
+    "native": [((9, 2, 16, 8), jnp.float32)],
+    "int8": [((9, 2, 16, 8), jnp.int8), ((9, 2, 16, 1), jnp.float32)],
+    "int4": [((9, 2, 16, 4), jnp.int8), ((9, 2, 16, 1), jnp.float32)],
+}
+
+
+@pytest.mark.parametrize("kv_dtype", sorted(_POOL_FORMATS))
+def test_alloc_kv_pools_is_the_format_the_callers_built(kv_dtype):
+    """A block's pools: a (K, V) pair of zeroed planes — a native
+    array, or (int8 values, float32 scales) with int4 at half the lane
+    width — and the batcher's and the prefill worker's pools for the
+    same arguments are that, leaf for leaf."""
+    from adapt_tpu.runtime.disagg import PrefillWorker
+
+    want = _POOL_FORMATS[kv_dtype]
+    pair = alloc_kv_pools(9, 2, 16, 8, jnp.float32, kv_dtype)
+    assert isinstance(pair, tuple) and len(pair) == 2
+    for member in pair:
+        leaves = list(member) if kv_dtype != "native" else [member]
+        assert isinstance(member, tuple) == (kv_dtype != "native")
+        assert [(x.shape, x.dtype) for x in leaves] == want
+        assert all(not np.asarray(x).any() for x in leaves)
+    assert kv_value_width(8, kv_dtype) == want[0][0][-1]
+
+    # The callers: 4 query heads, 2 kv heads, head_dim 8; 2 slots x 4
+    # pages + the trash page = 9.
+    from adapt_tpu.models.transformer_lm import transformer_lm
+
+    lm = transformer_lm(vocab=31, dim=32, depth=2, heads=4, mlp_dim=48,
+                        max_len=64, kv_heads=2)
+    variables = lm.graph.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 4), jnp.int32)
+    )
+    bat = ContinuousBatcher(
+        lm, variables, slots=2, page_size=16, kv_cache_dtype=kv_dtype
+    )
+    worker = PrefillWorker(
+        lm, variables, slots=2, page_size=16, kv_cache_dtype=kv_dtype
+    )
+    def fmt(tree):
+        return [(x.shape, x.dtype) for x in jax.tree.leaves(tree)]
+
+    assert len(bat._caches) == len(worker._pools) == 2  # one per block
+    assert jax.tree.structure(bat._caches) == jax.tree.structure(
+        worker._pools
+    )
+    assert fmt(bat._caches) == fmt(worker._pools) == want * 4
+    assert bat._pager.num_pages == worker._pager.num_pages == 9
+    assert bat._pager.pages_per_slot == worker._pager.pages_per_slot == 4
+    bat.close()
+
+
+def test_int4_pool_needs_an_even_head_dim():
+    with pytest.raises(ValueError, match="even head_dim"):
+        alloc_kv_pools(3, 2, 8, 7, jnp.float32, "int4")
+    with pytest.raises(ValueError, match="even head_dim"):
+        kv_value_width(7, "int4")
+    assert kv_value_width(7, "int8") == 7  # only the nibble pack cares
+
+
+def test_pool_geometry_table_width_and_default_pool():
+    """Table width = ceil((max_len + slack) / page); the default pool
+    is every row full plus the trash page. The batcher's speculative
+    slack (draft_k + tree_width) widens the table through it."""
+    assert pool_geometry(8, 48, 128) == (1, 9)
+    assert pool_geometry(3, 48, 8) == (6, 19)
+    assert pool_geometry(3, 48, 8, slack=1) == (7, 22)
+    assert pool_geometry(32, 1024, 128) == (8, 257)
+    with pytest.raises(ValueError, match="page_size"):
+        pool_geometry(2, 48, 0)
+    from adapt_tpu.config import SpeculativeConfig
+
+    lm = lm_tiny(vocab=37, max_len=48)
+    variables = lm.graph.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 4), jnp.int32)
+    )
+    bat = ContinuousBatcher(
+        lm, variables, slots=3, page_size=8, draft_lm=lm,
+        draft_variables=variables,
+        speculative=SpeculativeConfig(draft_k=3, tree_width=2),
+    )
+    want = pool_geometry(3, 48, 8, slack=5)
+    assert (bat._pager.pages_per_slot, bat._pager.num_pages) == want
+    assert want == (7, 22)
+    bat.close()
 
 
 def test_pager_radix_probe_and_books():
@@ -793,7 +893,8 @@ def test_decode_during_chunked_prefill_cannot_corrupt_prompt_pages(
 
 def test_chunked_prefill_validation(lm_setup):
     lm, variables = lm_setup
-    with pytest.raises(ValueError, match="paged"):
+    with pytest.raises(ValueError, match="multiple"):
+        # under one (default, 128-position) page
         ContinuousBatcher(lm, variables, prefill_chunk=16)
     with pytest.raises(ValueError, match="multiple"):
         ContinuousBatcher(lm, variables, kv_layout="paged", page_size=16,

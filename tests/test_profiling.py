@@ -388,49 +388,88 @@ def test_paged_memory_gauges_partition_with_cache_tier(
     bat.close()
 
 
-def test_dense_memory_gauges_match_strip_shapes(
+def test_pool_memory_gauges_match_pool_shapes(
     lm_setup, isolated_memory_sources
 ):
-    """Dense KV bytes must equal the configured strip shapes exactly:
-    layers x (K,V) x slots x kv_heads x (max_len + 1 trash) x head_dim
-    x itemsize."""
+    """Pool bytes must equal the configured pool's shapes exactly:
+    layers x (K,V) x (slots x pages_per_slot + trash) x kv_heads x
+    page x head_dim x itemsize; a second batcher SUMS, and close()
+    retires one from the gauges."""
     lm, variables = lm_setup
-    slots = 3
-    bat = ContinuousBatcher(lm, variables, slots=slots, chunk=2)
+    slots, page = 3, 8
+    bat = ContinuousBatcher(
+        lm, variables, slots=slots, chunk=2, page_size=page
+    )
     register_memory_source("continuous", bat)
     block0 = lm.graph.node(lm.block_names[0]).module
     expected = (
         len(lm.block_names)
         * 2
-        * slots
+        * (slots * -(-lm.max_len // page) + 1)
         * block0.cache_heads
-        * (lm.max_len + 1)
+        * page
         * block0.head_dim
         * jnp.dtype(block0.dtype).itemsize
     )
-    assert bat._memory_stats()["memory.kv_bytes"] == float(expected)
+    ms = bat._memory_stats()
+    assert ms["memory.pool_bytes"] == float(expected)
+    assert ms["memory.kv_bytes_ratio"] == 1.0
+    assert "memory.kv_bytes" not in ms  # went with the dense layout
     reg = MetricsRegistry()
     reg.register_collector(engine_collector)
-    assert reg.snapshot()["gauges"]["memory.kv_bytes"] == float(expected)
+    assert reg.snapshot()["gauges"]["memory.pool_bytes"] == float(expected)
     # A second batcher SUMS; close() retires it from the gauges even
     # though its jit caches pin the instance alive (GC never fires).
-    bat2 = ContinuousBatcher(lm, variables, slots=slots, chunk=2)
+    bat2 = ContinuousBatcher(
+        lm, variables, slots=slots, chunk=2, page_size=page
+    )
     register_memory_source("continuous", bat2)
     assert (
-        reg.snapshot()["gauges"]["memory.kv_bytes"] == 2.0 * expected
+        reg.snapshot()["gauges"]["memory.pool_bytes"] == 2.0 * expected
     )
     bat2.close()
-    assert reg.snapshot()["gauges"]["memory.kv_bytes"] == float(expected)
-    # Gauges whose every source retired are REMOVED, not served stale:
-    # a paged batcher's pool gauges disappear once it is closed.
-    bat3 = ContinuousBatcher(
-        lm, variables, slots=2, chunk=2, kv_layout="paged", page_size=8,
-    )
-    assert "memory.pool_pages" in reg.snapshot()["gauges"]
-    bat3.close()
-    gauges = reg.snapshot()["gauges"]
-    assert "memory.pool_pages" not in gauges
-    assert gauges["memory.kv_bytes"] == float(expected)  # bat remains
+    assert reg.snapshot()["gauges"]["memory.pool_bytes"] == float(expected)
+    # Gauges whose every source retired are REMOVED, not served stale.
+    bat.close()
+    assert "memory.pool_pages" not in reg.snapshot()["gauges"]
+
+
+def test_draft_cache_gauge_matches_draft_strips(
+    lm_setup, isolated_memory_sources
+):
+    """The one dense family left: the speculative draft's per-slot
+    strips. ``memory.draft_cache_bytes`` is exactly their bytes —
+    layers x (K,V) x slots x kv_heads x (max_len + draft_k + 1) x
+    head_dim x itemsize, one more position with a tree draft — and a
+    batcher without a draft serves no such gauge."""
+    from adapt_tpu.config import SpeculativeConfig
+
+    lm, variables = lm_setup
+    slots, k = 2, 3
+    block0 = lm.graph.node(lm.block_names[0]).module
+    for tree_width in (0, 1):
+        bat = ContinuousBatcher(
+            lm, variables, slots=slots, draft_lm=lm,
+            draft_variables=variables,
+            speculative=SpeculativeConfig(
+                draft_k=k, tree_width=tree_width
+            ),
+        )
+        strip = lm.max_len + k + 1 + (1 if tree_width else 0)
+        expected = (
+            len(lm.block_names) * 2 * slots * block0.cache_heads
+            * strip * block0.head_dim * jnp.dtype(block0.dtype).itemsize
+        )
+        ms = bat._memory_stats()
+        assert ms["memory.draft_cache_bytes"] == float(expected)
+        assert bat.stats()["draft_cache_bytes"] == expected
+        assert bat._draft_caches[0][0].shape == (
+            slots, block0.cache_heads, strip, block0.head_dim
+        )
+        bat.close()
+    plain = ContinuousBatcher(lm, variables, slots=slots)
+    assert "memory.draft_cache_bytes" not in plain._memory_stats()
+    plain.close()
 
 
 # -- regression gate --------------------------------------------------------
@@ -549,7 +588,7 @@ def test_exporter_scrape_concurrent_with_ticking_batcher(lm_setup):
                 g.startswith("engine.compiles.continuous.")
                 for g in snap["gauges"]
             )
-            assert "memory.kv_bytes" in snap["gauges"]
+            assert "memory.pool_bytes" in snap["gauges"]
             for rid in ids:
                 bat.result(rid, timeout=120.0)
     finally:

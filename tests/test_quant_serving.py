@@ -1,12 +1,12 @@
-"""Quantized KV serving everywhere: int8 caches are a cache-layout
-property every program family composes with — paged pools, speculative
-verify, prefix caching, chunked prefill, tensor parallelism — not a
-special mode of the dense slot path.
+"""Quantized KV serving everywhere: int8 is a property of the pool's
+format that every program family composes with — speculative verify,
+prefix caching, chunked prefill, tensor parallelism — not a special
+mode of one path.
 
 The contract stack: quantized batcher streams are bit-identical to the
 same-quantized solo path (``generate(kv_cache_dtype="int8")``) on the
-whole-prompt-prefill paths across staggered admits/retires/cancels on
-BOTH layouts including speculative mode; top-1 agreement vs native fp32
+whole-prompt-prefill paths across staggered admits/retires/cancels,
+inside one page and across pages, including speculative mode; top-1 agreement vs native fp32
 stays above a bound; the hot-path invariants (zero h2d per steady tick,
 two-program compile footprint) survive quantization; and the memory
 gauges report the capacity win honestly (scale planes counted,
@@ -68,9 +68,7 @@ def _solo(lm, variables, prompt, steps, **kw):
 
 def test_int8_paged_staggered_matches_generate_int8(lm_setup):
     """Quantized PAGED pools reproduce generate(kv_cache_dtype="int8")
-    exactly across staggered admits/retires/cancels — the same
-    invisibility bar the dense int8 layout is held to, now on the
-    production layout."""
+    exactly across staggered admits/retires/cancels."""
     lm, variables = lm_setup
     rng = np.random.RandomState(3)
     prompts = [rng.randint(0, 37, size=n).astype(np.int32)
@@ -170,18 +168,19 @@ def test_int8_chunked_prefill_matches_generate_int8(lm_setup):
     )
 
 
-def test_int8_top1_agreement_vs_fp32_both_layouts(lm_setup):
+def test_int8_top1_agreement_vs_fp32_both_page_sizes(lm_setup):
     """Quantization is allowed to perturb logits, not to wreck them:
     served int8 greedy streams agree with the native fp32 stream on the
-    overwhelming majority of positions, on both layouts."""
+    overwhelming majority of positions, at both page sizes."""
     lm, variables = lm_setup
     rng = np.random.RandomState(5)
     prompts = [rng.randint(0, 37, size=n).astype(np.int32)
                for n in (4, 7, 3)]
     agree, total = 0, 0
-    for kw in ({}, {"kv_layout": "paged", "page_size": 16}):
+    for page_size in (128, 16):
         bat = ContinuousBatcher(
-            lm, variables, slots=2, kv_cache_dtype="int8", **kw
+            lm, variables, slots=2, kv_cache_dtype="int8",
+            page_size=page_size,
         )
         ids = {bat.submit(p, 8): p for p in prompts}
         out = bat.run()
@@ -226,33 +225,32 @@ def test_int8_paged_hot_path_invariants(lm_setup):
 # -- quantized speculative verify --------------------------------------------
 
 
-@pytest.mark.parametrize("layout", ["slots", "paged"])
-def test_int8_spec_lossless_vs_generate_int8(spec_setup, layout):
-    """Speculative decoding over int8 caches: the verify chunk
+@pytest.mark.parametrize("page_size", [128, 8])
+def test_int8_spec_lossless_vs_generate_int8(spec_setup, page_size):
+    """Speculative decoding over int8 pools: the verify chunk
     quantizes its multi-token appends through the shared absmax scheme,
     so every stream equals the solo quantized greedy path
-    token-for-token — whatever the draft proposes, on both layouts."""
+    token-for-token — whatever the draft proposes, at both page
+    sizes."""
     lm, variables, draft, dvars = spec_setup
     rng = np.random.RandomState(1)
     prompts = [rng.randint(0, 37, size=n).astype(np.int32)
                for n in (3, 9, 5)]
     steps = [9, 14, 8]
-    kw = (
-        dict(kv_layout="paged", page_size=8) if layout == "paged" else {}
-    )
-    # Adversarial independent draft on both layouts; the perfect draft
-    # (the target itself — exercises acceptance > 0, multi-token
-    # commits) rides the dense layout only: acceptance depth is
-    # layout-blind, and each extra spec batcher is a full compile bill
-    # against the tier-1 wall-time budget.
+    # Adversarial independent draft at both page sizes; the perfect
+    # draft (the target itself — exercises acceptance > 0, multi-token
+    # commits) rides one only: acceptance depth is page-blind, and each
+    # extra spec batcher is a full compile bill against the tier-1
+    # wall-time budget.
     drafts = [(draft, dvars)]
-    if layout == "slots":
+    if page_size == 128:
         drafts.append((lm, variables))
     for d_lm, d_vars in drafts:
         bat = ContinuousBatcher(
             lm, variables, slots=2, kv_cache_dtype="int8",
             draft_lm=d_lm, draft_variables=d_vars,
-            speculative=SpeculativeConfig(draft_k=3), **kw,
+            speculative=SpeculativeConfig(draft_k=3),
+            page_size=page_size,
         )
         ids = {bat.submit(p, s): (p, s)
                for p, s in zip(prompts, steps)}
@@ -261,7 +259,7 @@ def test_int8_spec_lossless_vs_generate_int8(spec_setup, layout):
             np.testing.assert_array_equal(
                 out[rid],
                 _solo(lm, variables, p, s, kv_cache_dtype="int8"),
-                err_msg=f"layout={layout} "
+                err_msg=f"page_size={page_size} "
                         f"draft={'self' if d_lm is lm else 'adv'}",
             )
         assert 0.0 <= bat.stats()["spec_acceptance"] <= 1.0
@@ -359,54 +357,41 @@ def test_int8_draft_weights_serving_lossless(spec_setup):
 
 
 def test_memory_kv_bytes_ratio_gauge(lm_setup):
-    """memory.kv_bytes / pool_bytes count the scale planes, and
-    memory.kv_bytes_ratio reports quantized ÷ native-equivalent on both
-    layouts (1.0 for native batchers)."""
+    """memory.pool_bytes counts the scale planes, and
+    memory.kv_bytes_ratio reports quantized ÷ native-equivalent (1.0
+    for native batchers) whatever the page size."""
     lm, variables = lm_setup
     hd = lm.graph.node(lm.block_names[0]).module.head_dim
     want_ratio = (hd + 4) / (4 * hd)  # int8 + f32 scales vs f32 native
 
-    native = ContinuousBatcher(lm, variables, slots=2)
-    assert native._memory_stats()["memory.kv_bytes_ratio"] == 1.0
-
-    dense = ContinuousBatcher(lm, variables, slots=2, kv_cache_dtype="int8")
-    ms = dense._memory_stats()
-    assert ms["memory.kv_bytes_ratio"] == pytest.approx(want_ratio)
-    # Scale planes are INSIDE kv_bytes: values alone would be hd/(4hd).
-    values_only = sum(
-        x.nbytes for x in jax.tree.leaves(dense._caches)
-        if x.dtype == jnp.int8
-    )
-    assert ms["memory.kv_bytes"] > values_only
-
-    paged = ContinuousBatcher(
-        lm, variables, slots=2, kv_layout="paged", page_size=16,
-        kv_cache_dtype="int8",
-    )
-    ms = paged._memory_stats()
-    assert ms["memory.kv_bytes_ratio"] == pytest.approx(want_ratio)
-    assert "memory.pool_bytes" in ms
-    native_paged = ContinuousBatcher(
-        lm, variables, slots=2, kv_layout="paged", page_size=16
-    )
-    assert (
-        native_paged._memory_stats()["memory.kv_bytes_ratio"] == 1.0
-    )
-    assert ms["memory.pool_bytes"] == pytest.approx(
-        native_paged._memory_stats()["memory.pool_bytes"] * want_ratio
-    )
+    for kw in ({}, {"page_size": 16}):
+        native = ContinuousBatcher(lm, variables, slots=2, **kw)
+        assert native._memory_stats()["memory.kv_bytes_ratio"] == 1.0
+        quant = ContinuousBatcher(
+            lm, variables, slots=2, kv_cache_dtype="int8", **kw
+        )
+        ms = quant._memory_stats()
+        assert ms["memory.kv_bytes_ratio"] == pytest.approx(want_ratio)
+        # Scale planes are INSIDE pool_bytes: values alone would be
+        # hd/(4hd).
+        values_only = sum(
+            x.nbytes for x in jax.tree.leaves(quant._caches)
+            if x.dtype == jnp.int8
+        )
+        assert ms["memory.pool_bytes"] > values_only
+        assert ms["memory.pool_bytes"] == pytest.approx(
+            native._memory_stats()["memory.pool_bytes"] * want_ratio
+        )
 
 
 # -- tensor parallelism ------------------------------------------------------
 
 
 def test_tp4_quantized_pool_bytes_and_stream(sim_mesh):
-    """tp=4 quantized POOLS (the paged layout — where both pytree
-    members, int8 values and f32 scale planes, must head-shard
-    together): per-device bytes == logical/4 exactly, and the quantized
-    stream still equals the single-device solo quantized path. (The
-    dense int8 strips ride the same ``_shard_kv`` tree.map — a second
-    GSPMD batcher here would only re-pay its compiles.)"""
+    """tp=4 quantized POOLS (both pytree members, int8 values and f32
+    scale planes, must head-shard together): per-device bytes ==
+    logical/4 exactly, and the quantized stream still equals the
+    single-device solo quantized path."""
     lm = transformer_lm(37, 32, 2, 8, 64, max_len=48, kv_heads=4,
                         name="q_tp_target")
     variables = lm.graph.init(

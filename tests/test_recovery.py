@@ -119,17 +119,15 @@ def _run_workload(bat, kill_device=None, monitor=None):
     return [out[r] for r in ids]
 
 
-@pytest.mark.parametrize("layout", ["slots", "paged"])
-def test_kill_midstream_bit_identical(lm_setup, sim_mesh, layout):
+@pytest.mark.parametrize("page_size", [128, 8])
+def test_kill_midstream_bit_identical(lm_setup, sim_mesh, page_size):
     """THE acceptance pin: kill one device of the tp=4 mesh mid-stream;
     every surviving in-flight greedy request finishes bit-identical to
-    the uninterrupted tp=4 run AND to solo generate(), on both KV
-    layouts; per-device KV bytes land at logical/2 on the shrunk
+    the uninterrupted tp=4 run AND to solo generate(), inside one
+    page and across pages; per-device KV bytes land at logical/2 on the shrunk
     mesh."""
     lm, variables = lm_setup
-    kw = dict(slots=3, chunk=2)
-    if layout == "paged":
-        kw.update(kv_layout="paged", page_size=8)
+    kw = dict(slots=3, chunk=2, page_size=page_size)
     base_bat = _tp4(lm, variables, sim_mesh, **kw)
     base = _run_workload(base_bat)
     base_bat.close()
@@ -155,20 +153,19 @@ def test_kill_midstream_bit_identical(lm_setup, sim_mesh, layout):
     bat.close()
 
 
-@pytest.mark.parametrize("layout", ["slots", "paged"])
-def test_kill_speculative_int8(lm_setup, draft_setup, sim_mesh, layout):
+@pytest.mark.parametrize("page_size", [128, 8])
+def test_kill_speculative_int8(lm_setup, draft_setup, sim_mesh, page_size):
     """Recovery composes with the full stack: speculative mode + int8
-    caches/pools. The killed run stays lossless vs solo
-    generate(kv_cache_dtype='int8') on both layouts, the draft state
+    pools. The killed run stays lossless vs solo
+    generate(kv_cache_dtype='int8') at both page sizes, the draft state
     re-replicates, and both quantized pytree members land at
     logical/2 per device."""
     lm, variables = lm_setup
     draft, dvars = draft_setup
     kw = dict(slots=2, kv_cache_dtype="int8", draft_lm=draft,
               draft_variables=dvars,
-              speculative=SpeculativeConfig(draft_k=3))
-    if layout == "paged":
-        kw.update(kv_layout="paged", page_size=8)
+              speculative=SpeculativeConfig(draft_k=3),
+              page_size=page_size)
     mon = DeviceHealthMonitor()
     bat = _tp4(lm, variables, sim_mesh, health=mon, **kw)
     r1 = bat.submit(PROMPTS[0], 9)

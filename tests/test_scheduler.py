@@ -14,9 +14,9 @@ Five layers, one file:
   ``result()`` (a rejected request has no id to wait on);
 - decode-slot preemption — the acceptance pin: a preempted request's
   final stream is BIT-IDENTICAL to an unpreempted run of the same
-  request on BOTH layouts, ``on_token`` delivery stays exactly-once
-  across the preemption, the paged re-admission re-enters through the
-  prefix cache, and the victim is the lowest-priority slot;
+  request (inside one page and across pages), ``on_token`` delivery
+  stays exactly-once across the preemption, a re-admission with full
+  prompt pages re-enters through the prefix cache, and the victim is the lowest-priority slot;
 - the degradation ladder — escalation under backlog walks
   draft_k -> evict-cached -> reject-best-effort (events, counters,
   gauge), de-escalation restores on drain;
@@ -79,15 +79,13 @@ class _Req:
 def batcher_factory():
     made = []
 
-    def make(layout="slots", draft=False, scheduler=None, **kw):
+    def make(draft=False, scheduler=None, **kw):
         lm = lm_tiny(vocab=29, max_len=64)
         variables = lm.graph.init(
             jax.random.PRNGKey(0), jnp.zeros((1, 4), jnp.int32)
         )
         if draft:
             kw.update(draft_lm=lm, draft_variables=variables)
-        if layout == "paged":
-            kw.update(kv_layout="paged", page_size=8)
         bat = ContinuousBatcher(
             lm, variables, chunk=4, scheduler=scheduler, **kw
         )
@@ -282,7 +280,7 @@ def test_cache_aware_admission_prefers_resident_prefix(
         [warm, rng.randint(0, 29, size=5).astype(np.int32)]
     )
     bat = batcher_factory(
-        layout="paged", slots=1,
+        page_size=8, slots=1,
         scheduler=SchedulerConfig(cache_aware=aware),
     )
     bat.submit(warm, 3)
@@ -337,25 +335,25 @@ def test_submit_rejects_synchronously_and_books_it(
 # -- decode-slot preemption --------------------------------------------------
 
 
-@pytest.mark.parametrize("layout", ["slots", "paged"])
+@pytest.mark.parametrize("page_size", [128, 8])
 def test_preemption_bit_identical_and_exactly_once(
-    clean_slate, batcher_factory, layout
+    clean_slate, batcher_factory, page_size
 ):
     """The acceptance pin: a preempted request's final stream is
-    bit-identical to an unpreempted run of the same request, on both
-    layouts, with on_token delivery exactly-once across the
+    bit-identical to an unpreempted run of the same request, inside
+    one page and across pages, with on_token delivery exactly-once across the
     preemption (stream_skip suppresses the regenerated prefix)."""
     p_low = np.arange(10, dtype=np.int32) % 29
     p_hi = (np.arange(7, dtype=np.int32) * 3) % 29
     # Reference: each request alone on an unpreempted batcher.
-    ref = batcher_factory(layout=layout, slots=1)
+    ref = batcher_factory(page_size=page_size, slots=1)
     r_low = ref.submit(p_low, 20)
     ref_low = ref.run()[r_low]
     r_hi = ref.submit(p_hi, 10)
     ref_hi = ref.run()[r_hi]
 
     bat = batcher_factory(
-        layout=layout,
+        page_size=page_size,
         slots=1,
         scheduler=SchedulerConfig(
             preempt=True, preempt_ttft_fraction=0.5, degrade=False
@@ -399,7 +397,7 @@ def test_preemption_bit_identical_and_exactly_once(
     idxs = [i for i, _ in delivered[low]]
     assert idxs == list(range(len(ref_low)))
     assert [t for _, t in delivered[low]] == list(ref_low)
-    if layout == "paged":
+    if page_size == 8:
         # The victim re-admitted THROUGH the prefix cache: its prompt
         # pages dropped into the LRU at preemption and were shared
         # back on re-admission.
@@ -414,7 +412,7 @@ def test_preemption_fires_on_page_starvation_with_a_free_slot(
     (even after evicting every cold page) must still preempt — the
     lower-priority decode's pages are what it is waiting for."""
     bat = batcher_factory(
-        layout="paged",
+        page_size=8,
         slots=2,
         pool_pages=10,  # 9 allocatable: low takes 6, gold needs 5
         scheduler=SchedulerConfig(
@@ -505,7 +503,7 @@ def test_degradation_ladder_escalates_and_recovers(
         preempt=False,
     )
     bat = batcher_factory(
-        layout="paged", draft=True, slots=2,
+        page_size=8, draft=True, slots=2,
         speculative=SpeculativeConfig(draft_k=4), scheduler=cfg,
     )
     rng = np.random.RandomState(0)

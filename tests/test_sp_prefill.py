@@ -8,11 +8,22 @@ Pinned contracts:
   KV), causal masking whose live/dead split spans multiple ring
   steps, and the odd-last-chunk recipe (pad to the ring size under a
   causal mask, slice the real prefix).
-- **Byte-equality**: ``parallel.sp_prefill.SPPrefiller`` pages equal
-  the single-device chunked prefill's pages BIT FOR BIT — native,
-  int8 and int4 pools, sp in {2, 4}, GQA + rope models, and the
-  sp x tp composed mesh against the tp-sharded chunked prefill (tp
-  math is compared at matched tp, the PR-5 discipline).
+- **Page equality, to what a reordered sum allows**:
+  ``parallel.sp_prefill.SPPrefiller`` pages equal the single-device
+  chunked prefill's pages — native, int8 and int4 pools, sp in
+  {2, 4}, GQA + rope models, and the sp x tp composed mesh against
+  the tp-sharded chunked prefill (tp math is compared at matched tp,
+  the PR-5 discipline). The FIRST block's pages (projection, rope,
+  quantisation, the ring transport: everything token-local) are
+  byte-equal. Later blocks sit downstream of attention, where the sp
+  pass reduces each row's softmax sum and its p @ V product over the
+  whole span (``nb`` pages of columns) and a chunk pass over its own
+  power-of-two window: masked columns add exact zeros, but XLA orders
+  a reduction by its width, so the two sums differ by a rounding
+  (read on the CPU, PR 29: 9.5e-7 on values up to 3.4, pages whose
+  chunk window IS the span byte-equal). Those pages are held to
+  ``_PAGE_ULPS`` float32 ulps of the plane's largest magnitude, int8
+  values to one quantum.
 - **Serving**: greedy streams through an sp-enabled batcher are
   bit-identical to the plain batcher's; admissions land through the
   prefix cache (suffix-only pass); steady decode ticks stay at ZERO
@@ -88,6 +99,36 @@ def _assert_tree_equal(a, b):
     assert len(la) == len(lb)
     for x, y in zip(la, lb):
         np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
+#: Float32 ulps (of a plane's largest magnitude) a page downstream of
+#: attention may differ by: the two passes round one sum differently
+#: (module docstring). Read: under 3 of these; a wrong mask, position
+#: or scale is off by thousands.
+_PAGE_ULPS = 16
+
+
+def _assert_pages_match(ref, got):
+    """Block 0 byte-equal; later blocks within ``_PAGE_ULPS`` ulps
+    (float planes) and one quantum (int8 value planes)."""
+    assert len(ref) == len(got)
+    _assert_tree_equal(ref[0], got[0])
+    for b, (rp, gp) in enumerate(zip(ref[1:], got[1:]), start=1):
+        la, lb = jax.tree.leaves(rp), jax.tree.leaves(gp)
+        assert len(la) == len(lb)
+        for x, y in zip(la, lb):
+            x, y = np.asarray(x), np.asarray(y)
+            assert x.shape == y.shape and x.dtype == y.dtype
+            if x.dtype == np.int8:
+                gap = np.abs(x.astype(np.int16) - y.astype(np.int16))
+                assert gap.max() <= 1, f"block {b}: {gap.max()} quanta"
+            else:
+                tol = (
+                    _PAGE_ULPS * np.finfo(np.float32).eps
+                    * float(np.abs(x).max())
+                )
+                gap = float(np.abs(x - y).max())
+                assert gap <= tol, f"block {b}: {gap} > {tol}"
 
 
 # -- ring attention parity at serving shapes (satellite) -------------------
@@ -184,7 +225,7 @@ def test_ring_collect_is_exact_concatenation(sim_mesh):
     np.testing.assert_array_equal(np.asarray(out), np.asarray(x))
 
 
-# -- sp prefill byte-equality ----------------------------------------------
+# -- sp prefill page equality ----------------------------------------------
 
 
 @pytest.mark.parametrize(
@@ -198,10 +239,10 @@ def test_ring_collect_is_exact_concatenation(sim_mesh):
         pytest.param("int4", marks=pytest.mark.slow),
     ],
 )
-def test_sp_pages_byte_equal_chunked_prefill(lm_setup, sim_mesh, dtype):
-    """The tentpole pin: sp-prefilled pages are byte-equal to the
-    single-device chunked prefill's — native, int8 and packed-int4
-    pools, sp=2 (and sp=4 on the native arm)."""
+def test_sp_pages_match_chunked_prefill(lm_setup, sim_mesh, dtype):
+    """The tentpole pin: sp-prefilled pages are the single-device
+    chunked prefill's (``_assert_pages_match``) — native, int8 and
+    packed-int4 pools, sp=2 (and sp=4 on the native arm)."""
     lm, variables = lm_setup
     prompt = np.random.default_rng(7).integers(
         1, VOCAB, size=41
@@ -214,13 +255,19 @@ def test_sp_pages_byte_equal_chunked_prefill(lm_setup, sim_mesh, dtype):
         )
         m, blocks = pf.prefill(prompt)
         assert m == 5
-        _assert_tree_equal(ref, blocks)
+        _assert_pages_match(ref, blocks)
+        # The last page's chunk pass attends 8 pages of columns, the
+        # sp pass's whole span: same width, same sum, same bytes.
+        _assert_tree_equal(
+            jax.tree.map(lambda t: t[4], ref),
+            jax.tree.map(lambda t: t[4], blocks),
+        )
         pf.close()
 
 
-def test_sp_pages_byte_equal_gqa_rope(gqa_lm_setup, sim_mesh):
+def test_sp_pages_match_gqa_rope(gqa_lm_setup, sim_mesh):
     """GQA + rope at sp=2: the grouped-query fold and the rotary
-    positions survive the sequence split bit-exactly."""
+    positions survive the sequence split."""
     lm, variables = gqa_lm_setup
     prompt = np.random.default_rng(8).integers(
         1, VOCAB, size=37
@@ -231,15 +278,15 @@ def test_sp_pages_byte_equal_gqa_rope(gqa_lm_setup, sim_mesh):
     )
     m, blocks = pf.prefill(prompt)
     assert m == 4
-    _assert_tree_equal(ref, blocks)
+    _assert_pages_match(ref, blocks)
     pf.close()
 
 
-def test_sp_tp_composed_pages_byte_equal(lm_setup, sim_mesh):
-    """sp x tp composition: a (sp=2, tp=2) prefiller's pages equal the
-    tp=2 batcher's OWN chunked prefill bit for bit (tp math compares
-    at matched tp — the PR-5 discipline; tp=2 vs tp=1 was never
-    bitwise, only stream-identical)."""
+def test_sp_tp_composed_pages_match(lm_setup, sim_mesh):
+    """sp x tp composition: a (sp=2, tp=2) prefiller's pages are the
+    tp=2 batcher's OWN chunked prefill's (tp math compares at matched
+    tp — the PR-5 discipline; tp=2 vs tp=1 was never bitwise, only
+    stream-identical)."""
     lm, variables = lm_setup
     mesh = sim_mesh(2, axis="tp")
     prompt = np.random.default_rng(9).integers(
@@ -268,7 +315,7 @@ def test_sp_tp_composed_pages_byte_equal(lm_setup, sim_mesh):
     )
     m, blocks = pf.prefill(prompt)
     assert m == 5
-    _assert_tree_equal(ref, blocks)
+    _assert_pages_match(ref, blocks)
     pf.close()
     bat.close()
 
@@ -319,15 +366,6 @@ def test_sp_batcher_streams_bit_identical(lm_setup, sim_mesh):
     assert bat.stats()["h2d_transfers"] == h2d0
     bat.run()
     bat.close()
-
-
-def test_sp_requires_paged_layout(lm_setup, sim_mesh):
-    lm, variables = lm_setup
-    with pytest.raises(ValueError, match="paged"):
-        ContinuousBatcher(
-            lm, variables, slots=2, kv_layout="slots",
-            prefill=PrefillConfig(sp_threshold=24, sp_width=2),
-        )
 
 
 def test_prefill_config_validation():

@@ -670,11 +670,16 @@ def test_two_process_fleet_metrics_forensics_and_staleness(
         fut.result(timeout=60.0)
         rid = fut.request_id
 
-        # Wait for at least one post-exec report from the worker
-        # (pushes every ~0.3s on the dispatcher link's ping thread).
-        deadline = time.monotonic() + 20.0
+        # Wait for at least one POST-EXEC report from the worker
+        # (pushes every ~0.3s on the dispatcher link's ping thread —
+        # which a loaded machine starves: under the suite's six
+        # workers the first such report took over 20 s, the loop gave
+        # up with a pre-exec report in hand, and the assertions below
+        # failed on counters that had not arrived yet).
+        deadline = time.monotonic() + 120.0
         wkey = None
-        while time.monotonic() < deadline:
+        post_exec = False
+        while time.monotonic() < deadline and not post_exec:
             for key, s in gstore.sources().items():
                 if s["role"] == "stage" and s["worker"] == (
                     "fleet-remote-0"
@@ -683,10 +688,13 @@ def test_two_process_fleet_metrics_forensics_and_staleness(
             if wkey is not None:
                 fl = gstore.fleet_snapshot()
                 src = fl["sources"][wkey]
-                if src["counters"].get("remote.stage_execs"):
-                    break
-            time.sleep(0.1)
+                post_exec = bool(
+                    src["counters"].get("remote.stage_execs")
+                )
+            if not post_exec:
+                time.sleep(0.1)
         assert wkey is not None, "no telemetry report arrived"
+        assert post_exec, "no post-exec telemetry report arrived"
         worker_pid = fl["sources"][wkey]["pid"]
         assert worker_pid != os.getpid()
 

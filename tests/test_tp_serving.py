@@ -1,7 +1,7 @@
 """Tensor-parallel continuous serving: a batcher sharded over a 4-device
 sim mesh must be INVISIBLE in outputs — bit-identical greedy streams vs
 the tp=1 batcher and the single-device ``generate()`` across staggered
-admits/retires/cancels, on both KV layouts, including speculative mode —
+admits/retires/cancels, inside one page and across pages, including speculative mode —
 while per-device KV bytes shrink to logical/tp, the two-program compile
 footprint holds, and a steady-state tick still stages zero host arrays."""
 
@@ -69,8 +69,8 @@ def _staggered_run(bat, prompts, steps, cancel_idx=None):
     return ids, cancelled, bat.run()
 
 
-@pytest.mark.parametrize("layout", ["slots", "paged"])
-def test_tp4_bit_identical_to_tp1_staggered(lm_setup, sim_mesh, layout):
+@pytest.mark.parametrize("page_size", [128, 8])
+def test_tp4_bit_identical_to_tp1_staggered(lm_setup, sim_mesh, page_size):
     """tp=4 and tp=1 batchers run the same staggered workload (admits,
     retirements, a mid-flight cancel): every stream is bit-identical
     between them AND equals its solo single-device generate(); the tp=4
@@ -83,9 +83,7 @@ def test_tp4_bit_identical_to_tp1_staggered(lm_setup, sim_mesh, layout):
     prompts = [rng.randint(0, 37, size=n).astype(np.int32)
                for n in (3, 9, 5, 12, 7)]
     steps = [20, 4, 8, 3, 6]
-    kw = dict(slots=3, chunk=2)
-    if layout == "paged":
-        kw.update(kv_layout="paged", page_size=8)
+    kw = dict(slots=3, chunk=2, page_size=page_size)
     outs = {}
     for tp in (1, 4):
         bat = _bat(lm, variables, sim_mesh, tp, **kw)
@@ -112,11 +110,11 @@ def test_tp4_bit_identical_to_tp1_staggered(lm_setup, sim_mesh, layout):
             )
 
 
-@pytest.mark.parametrize("layout", ["slots", "paged"])
-def test_tp4_speculative_lossless(lm_setup, draft_setup, sim_mesh, layout):
+@pytest.mark.parametrize("page_size", [128, 8])
+def test_tp4_speculative_lossless(lm_setup, draft_setup, sim_mesh, page_size):
     """Batched speculation under tp=4 (target sharded, draft replicated)
-    stays per-row lossless vs solo single-device generate() on both KV
-    layouts, and the whole workload compiles exactly ONE verify variant
+    stays per-row lossless vs solo single-device generate() inside one
+    page and across pages, and the whole workload compiles exactly ONE verify variant
     (the tp4-vs-tp1 bitwise claim is pinned by the non-spec test above;
     a tp=1 spec batcher here would only re-pay its compiles)."""
     from adapt_tpu.utils.profiling import global_compile_sentinel
@@ -128,9 +126,8 @@ def test_tp4_speculative_lossless(lm_setup, draft_setup, sim_mesh, layout):
                for n in (4, 7, 2)]
     steps = [7, 9, 5]
     kw = dict(slots=2, draft_lm=draft, draft_variables=dvars,
-              speculative=SpeculativeConfig(draft_k=3))
-    if layout == "paged":
-        kw.update(kv_layout="paged", page_size=8)
+              speculative=SpeculativeConfig(draft_k=3),
+              page_size=page_size)
     sentinel = global_compile_sentinel()
     bat = _bat(lm, variables, sim_mesh, 4, **kw)
     before = sentinel.compiles("continuous.spec_verify")
@@ -178,24 +175,21 @@ def test_tp4_two_programs_and_zero_h2d(lm_setup, sim_mesh):
 
 
 def test_tp_memory_gauges_per_device(lm_setup, sim_mesh):
-    """The memory sources split logical vs per-device bytes: dense
-    memory.kv_bytes_per_device == kv_bytes / tp; paged
-    memory.pool_bytes_per_device == pool_bytes / tp; the replicated
-    draft's bytes stay logical."""
+    """The memory sources split logical vs per-device bytes:
+    memory.pool_bytes_per_device == pool_bytes / tp under a
+    head-sharded mesh, and equal to it on one device."""
     lm, variables = lm_setup
-    dense = _bat(lm, variables, sim_mesh, 4, slots=2)
-    ms = dense._memory_stats()
-    assert ms["memory.kv_bytes_per_device"] * 4 == ms["memory.kv_bytes"]
-    paged = _bat(lm, variables, sim_mesh, 4, slots=2, kv_layout="paged",
-                 page_size=8)
-    ms = paged._memory_stats()
-    assert (
-        ms["memory.pool_bytes_per_device"] * 4 == ms["memory.pool_bytes"]
-    )
+    for kw in ({}, {"page_size": 8}):
+        sharded = _bat(lm, variables, sim_mesh, 4, slots=2, **kw)
+        ms = sharded._memory_stats()
+        assert (
+            ms["memory.pool_bytes_per_device"] * 4
+            == ms["memory.pool_bytes"]
+        )
     # tp=1 (and no-mesh) batchers report per-device == logical.
     flat = ContinuousBatcher(lm, variables, slots=2)
     ms = flat._memory_stats()
-    assert ms["memory.kv_bytes_per_device"] == ms["memory.kv_bytes"]
+    assert ms["memory.pool_bytes_per_device"] == ms["memory.pool_bytes"]
     assert flat.stats()["tp"] == 1
 
 
