@@ -41,6 +41,7 @@ from adapt_tpu.ops.decode_attention import (
 )
 from adapt_tpu.ops.paged_attention import (
     append_kv_paged,
+    fuse_kv,
     paged_attention,
     paged_chunk_attention,
     paged_verify_attention,
@@ -244,33 +245,45 @@ class CausalSelfAttention(nn.Module):
     # same function, so the definition cannot fork).
     _quantize_kv = staticmethod(quantize_kv_vectors)
 
-    def _write_kv_pair(self, cache_k, cache_v, k, v, write):
-        """Fan one K/V cache write out over the cache's representation:
-        quantized ``(values, scales)`` pairs quantize ``k``/``v`` with
-        the shared absmax scheme and apply ``write`` to BOTH members;
-        native caches write directly. The cache's VALUE width is
-        authoritative for the quantized dtype: a ``head_dim // 2`` lane
-        member is an int4-PACKED pool (two nibbles per int8 lane —
+    def _cache_repr(self, width, k, v):
+        """``(k, v)`` in the representation of the cache they are about
+        to be written to. ``width`` = the lanes ONE vector takes in a
+        quantized cache's value plane, None for a native cache: native
+        K/V pass as given; a quantized cache gets ``(values, scales)``
+        pairs by the shared absmax scheme, and its width is
+        authoritative for the dtype — ``head_dim // 2`` lanes a vector
+        is int4-PACKED (two nibbles per int8 lane —
         ``ops.quantize.quantize_kv_vectors(..., "int4")``), so every
-        write packs to match without any extra plumbing.
-        ``write(member, new)`` is each
-        call site's own primitive (page scatter, chunk scatter,
-        ``append_kv``) — this is THE one quantize-then-write-both
-        definition, so the decode/prefill/verify paths cannot
-        diverge."""
-        if isinstance(cache_k, tuple):
-            dt = (
-                "int4"
-                if cache_k[0].shape[-1] * 2 == k.shape[-1]
-                else "int8"
-            )
-            kq, ks = self._quantize_kv(k, dt)
-            vq, vs = self._quantize_kv(v, dt)
-            return (
-                (write(cache_k[0], kq), write(cache_k[1], ks)),
-                (write(cache_v[0], vq), write(cache_v[1], vs)),
-            )
-        return write(cache_k, k), write(cache_v, v)
+        write packs to match without any extra plumbing. THE one
+        quantize-before-write definition, so the decode/prefill/verify
+        paths cannot diverge."""
+        if width is None:
+            return k, v
+        dt = "int4" if width * 2 == k.shape[-1] else "int8"
+        return self._quantize_kv(k, dt), self._quantize_kv(v, dt)
+
+    def _write_kv_pair(self, cache_k, cache_v, k, v, write):
+        """Fan one K/V write out over a DENSE cache's representation
+        (two strips, ``(values, scales)`` pairs when quantized):
+        ``write(member, new)`` — the call site's own primitive
+        (``append_kv``) — runs on every member."""
+        quantized = isinstance(cache_k, tuple)
+        k, v = self._cache_repr(
+            cache_k[0].shape[-1] if quantized else None, k, v
+        )
+        return jax.tree.map(write, (cache_k, cache_v), (k, v))
+
+    def _write_kv_pool(self, pool, k, v, write):
+        """The paged twin: a block's POOL holds K and V of a position
+        as ONE fused row (``ops.paged_attention.fuse_kv``; beside it,
+        when quantized, the two scale planes), so ``write(plane, new)``
+        — page scatter, chunk scatter, ``append_kv_paged`` — runs once
+        on the fused rows and once per scale plane."""
+        quantized = isinstance(pool, tuple)
+        k, v = self._cache_repr(
+            pool[0].shape[-1] // 2 if quantized else None, k, v
+        )
+        return jax.tree.map(write, pool, fuse_kv(k, v))
 
     def prefill(self, x, max_len: int, valid_from=None, quantize_cache=False):
         """Full causal attention over the prompt, returning output plus
@@ -375,25 +388,27 @@ class CausalSelfAttention(nn.Module):
 
 
     def decode_step_paged(
-        self, x_t, k_pool, v_pool, page_table, index, valid_from=None,
+        self, x_t, pool, page_table, index, valid_from=None,
         attn_impl=None, split=None, head_shard=None,
     ):
         """One token against a PAGED cache (``ops/paged_attention``):
-        write this step's K/V into the slot's physical page at
+        write this step's K|V row into the slot's physical page at
         ``index``'s (page, offset), then attend over the table-mapped
-        window. ``index`` scalar or (b,) as in ``decode_step``; pools
-        are (num_pages, kv_h, P, hd) arrays or quantized ``(int8
-        values, f32 scales)`` PAIRS of pools (scales (num_pages, kv_h,
-        P, 1); this step's K/V quantize via the shared absmax scheme
-        before the scatter, and dequant fuses into the attention — see
-        ``ops/paged_attention``); ``page_table`` (b, pages_per_slot)
+        window. ``index`` scalar or (b,) as in ``decode_step``;
+        ``pool`` is the block's pool (``runtime/paged.alloc_kv_pools``):
+        the fused (num_pages, kv_h, P, 2 * hd) plane, or a quantized
+        ``(int8 values, k_scales, v_scales)`` triple (scales
+        (num_pages, kv_h, P, 1); this step's K/V quantize via the
+        shared absmax scheme before the scatter, and dequant fuses into
+        the attention — see ``ops/paged_attention``). Returns ``(out,
+        pool)``. ``page_table`` (b, pages_per_slot)
         int32 (idle rows may map everything to the trash page — their
         writes land there, unread). ``head_shard`` = ``(mesh, axis)``
         when the caller's program is tp-partitioned: the Pallas kernel
         then runs per head shard (``ops.paged_attention._head_sharded``)
         — here and in the chunk/verify twins below."""
         b = x_t.shape[0]
-        page = pool_values(k_pool).shape[2]
+        page = pool_values(pool).shape[2]
         q, k, v = self._project(x_t)  # q (b, h, 1, hd); k/v (b, kv_h, 1, hd)
         idx = jnp.broadcast_to(
             jnp.asarray(index, jnp.int32).reshape(-1), (b,)
@@ -415,22 +430,21 @@ class CausalSelfAttention(nn.Module):
         phys = jnp.where(live_row, phys, 0)
         off = safe % page
 
-        def write(pool, t):  # rows (phys[i], :, off[i], :) <- token i
-            return append_kv_paged(pool, t, phys[:, None], off[:, None])
+        def write(plane, t):  # rows (phys[i], :, off[i], :) <- token i
+            return append_kv_paged(plane, t, phys[:, None], off[:, None])
 
-        k_pool, v_pool = self._write_kv_pair(k_pool, v_pool, k, v, write)
+        pool = self._write_kv_pool(pool, k, v, write)
         o = paged_attention(
-            q, k_pool, v_pool, page_table, index,
+            q, pool, page_table, index,
             self._window_from(index, b, valid_from), prefer=attn_impl,
             split=split, head_shard=head_shard,
         ).astype(x_t.dtype)
         o = self._ungroup_o(o, 1)
         o = jnp.swapaxes(o, 1, 2).reshape(b, 1, self.dim)
-        return self.out(o), k_pool, v_pool
+        return self.out(o), pool
 
     def prefill_chunk_paged(
-        self, x, k_pool, v_pool, pages, pos0, attn_impl=None,
-        head_shard=None,
+        self, x, pool, pages, pos0, attn_impl=None, head_shard=None,
     ):
         """Incremental prefill of a CHUNK of positions [pos0, pos0 + C)
         directly against a paged window: write the chunk's K/V into its
@@ -440,14 +454,14 @@ class CausalSelfAttention(nn.Module):
         ``decode_step_paged``). ``pages`` (n,) covers [0, pos0 + C)
         (pow2 trash padding allowed); ``pos0`` is page-aligned and C is
         a whole number of pages. Batch 1 (prefill is per request).
-        Quantized ``(values, scales)`` pool pairs quantize the chunk's
+        Quantized pools quantize the chunk's
         K/V before the page scatter — note the chunk then ATTENDS the
         already-quantized earlier window, so a chunked/suffix prefill
         over int8 pools carries the cache's quantization error into the
         chunk's hidden states (same fine print as chunk fp contraction
         widths, one quantization step coarser)."""
         b, c, d = x.shape
-        page = pool_values(k_pool).shape[2]
+        page = pool_values(pool).shape[2]
         q, k, v = self._project(x)  # q (1, h, C, hd); k/v (1, kv_h, C, hd)
         q, k = self._rope_qk(q, k, pos0 + jnp.arange(c))
         q = self._group_q(q)  # (1, kv_h, g*C, hd)
@@ -455,24 +469,26 @@ class CausalSelfAttention(nn.Module):
         chunk_pages = lax.dynamic_slice(
             jnp.asarray(pages, jnp.int32), (pos0 // page,), (n_chunk,)
         )
-        kvh, hd = k.shape[1], k.shape[3]
+        kvh = k.shape[1]
 
         def to_pages(t):  # (1, kv_h, C, w) -> (n_chunk, kv_h, page, w)
             return jnp.swapaxes(
                 t[0].reshape(kvh, n_chunk, page, t.shape[3]), 0, 1
             )
 
-        def write(pool, t):
-            return pool.at[chunk_pages].set(to_pages(t).astype(pool.dtype))
+        def write(plane, t):
+            return plane.at[chunk_pages].set(
+                to_pages(t).astype(plane.dtype)
+            )
 
-        k_pool, v_pool = self._write_kv_pair(k_pool, v_pool, k, v, write)
+        pool = self._write_kv_pool(pool, k, v, write)
         o = paged_chunk_attention(
-            q, k_pool, v_pool, pages, pos0, c, prefer=attn_impl,
+            q, pool, pages, pos0, c, prefer=attn_impl,
             window=self.window, head_shard=head_shard,
         ).astype(x.dtype)
         o = self._ungroup_o(o, c)  # (1, h, C, hd)
         o = jnp.swapaxes(o, 1, 2).reshape(b, c, self.dim)
-        return self.out(o), k_pool, v_pool
+        return self.out(o), pool
 
     def prefill_sp(self, x, gather, quantize_cache=False, constrain=None):
         """SEQUENCE-PARALLEL prefill body: the whole span's attention
@@ -615,7 +631,7 @@ class CausalSelfAttention(nn.Module):
         return self.out(o), cache_k, cache_v
 
     def verify_chunk_paged(
-        self, x, k_pool, v_pool, page_table, index, attn_impl=None,
+        self, x, pool, page_table, index, attn_impl=None,
         tree_tail=0, split=None, head_shard=None,
     ):
         """Batched verify over a PAGED cache: scatter each slot's K
@@ -626,12 +642,12 @@ class CausalSelfAttention(nn.Module):
         semantics over ``decode_step_paged``'s layout. ``index`` (b,);
         a negative row is dead (idle or mid-chunked-prefill slot): its
         writes route to the trash page and its positions all mask.
-        Quantized ``(values, scales)`` pool pairs scatter the chunk's
-        quantized K/V into both members (the scale plane rides the
-        same page table). ``tree_tail``/``split`` as in
+        A quantized ``(values, k_scales, v_scales)`` pool takes the
+        chunk's quantized rows into all three planes (the scale planes
+        ride the same page table). ``tree_tail``/``split`` as in
         ``verify_chunk`` / ``decode_step_paged``."""
         b, kc, _ = x.shape
-        page = pool_values(k_pool).shape[2]
+        page = pool_values(pool).shape[2]
         q, k, v = self._project(x)  # q (b, h, K, hd); k/v (b, kv_h, K, hd)
         idx = jnp.broadcast_to(
             jnp.asarray(index, jnp.int32).reshape(-1), (b,)
@@ -650,18 +666,18 @@ class CausalSelfAttention(nn.Module):
         # (phys[b,t], :, off[b,t], :) <- token t of slot b. Dead rows'
         # K writes pile onto the trash page — never read (their masks
         # are empty).
-        def write(pool, t):
-            return append_kv_paged(pool, t, phys, off)
+        def write(plane, t):
+            return append_kv_paged(plane, t, phys, off)
 
-        k_pool, v_pool = self._write_kv_pair(k_pool, v_pool, k, v, write)
+        pool = self._write_kv_pool(pool, k, v, write)
         o = paged_verify_attention(
-            q, k_pool, v_pool, page_table, idx, kc, prefer=attn_impl,
+            q, pool, page_table, idx, kc, prefer=attn_impl,
             window=self.window, tree_tail=tree_tail, split=split,
             head_shard=head_shard,
         ).astype(x.dtype)
         o = self._ungroup_o(o, kc)
         o = jnp.swapaxes(o, 1, 2).reshape(b, kc, self.dim)
-        return self.out(o), k_pool, v_pool
+        return self.out(o), pool
 
 
 class DecoderBlock(nn.Module):
@@ -743,25 +759,24 @@ class DecoderBlock(nn.Module):
         return x_t + self._mlp(self.ln2(x_t)), ck, cv
 
     def decode_step_paged(
-        self, x_t, k_pool, v_pool, page_table, index, valid_from=None,
+        self, x_t, pool, page_table, index, valid_from=None,
         attn_impl=None, split=None, head_shard=None,
     ):
-        a, kp, vp = self.attn.decode_step_paged(
-            self.ln1(x_t), k_pool, v_pool, page_table, index, valid_from,
+        a, pool = self.attn.decode_step_paged(
+            self.ln1(x_t), pool, page_table, index, valid_from,
             attn_impl, split, head_shard,
         )
         x_t = x_t + a
-        return x_t + self._mlp(self.ln2(x_t)), kp, vp
+        return x_t + self._mlp(self.ln2(x_t)), pool
 
     def prefill_chunk_paged(
-        self, x, k_pool, v_pool, pages, pos0, attn_impl=None,
-        head_shard=None,
+        self, x, pool, pages, pos0, attn_impl=None, head_shard=None,
     ):
-        a, kp, vp = self.attn.prefill_chunk_paged(
-            self.ln1(x), k_pool, v_pool, pages, pos0, attn_impl, head_shard
+        a, pool = self.attn.prefill_chunk_paged(
+            self.ln1(x), pool, pages, pos0, attn_impl, head_shard
         )
         x = x + a
-        return x + self._mlp(self.ln2(x)), kp, vp
+        return x + self._mlp(self.ln2(x)), pool
 
     def prefill_sp(self, x, gather, quantize_cache=False, constrain=None):
         a, ck, cv = self.attn.prefill_sp(
@@ -778,15 +793,15 @@ class DecoderBlock(nn.Module):
         return x + self._mlp(self.ln2(x)), ck, cv
 
     def verify_chunk_paged(
-        self, x, k_pool, v_pool, page_table, index, attn_impl=None,
+        self, x, pool, page_table, index, attn_impl=None,
         tree_tail=0, split=None, head_shard=None,
     ):
-        a, kp, vp = self.attn.verify_chunk_paged(
-            self.ln1(x), k_pool, v_pool, page_table, index, attn_impl,
+        a, pool = self.attn.verify_chunk_paged(
+            self.ln1(x), pool, page_table, index, attn_impl,
             tree_tail, split, head_shard,
         )
         x = x + a
-        return x + self._mlp(self.ln2(x)), kp, vp
+        return x + self._mlp(self.ln2(x)), pool
 
 
 class TokenEmbed(nn.Module):

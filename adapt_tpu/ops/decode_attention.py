@@ -176,7 +176,10 @@ def _attend_tile(q, k, v, ksc, vsc, live, m_scr, l_scr, acc_scr,
     ``kv_transposed``: ``k``/``v`` arrive as (hd, block_k), positions on
     the lanes (how a pool of rows narrower than a lane tile lives in
     HBM); the two products contract the other axis and nothing else
-    changes."""
+    changes. ``k`` and ``v`` may be ONE array — a paged pool's fused
+    K|V row read whole (``paged_attention._attend_fused``) — and are
+    then widened once."""
+    fused = v is k
     if packed:
         # Unpack two nibbles per streamed int8 lane in VMEM — the HBM
         # stream stays 4-bit; only the registers see head_dim lanes.
@@ -187,7 +190,7 @@ def _attend_tile(q, k, v, ksc, vsc, live, m_scr, l_scr, acc_scr,
     k_hd, v_pos = (row, col) if kv_transposed else (col, row)
     q = q.astype(jnp.float32)
     k = k.astype(jnp.float32)
-    v = v.astype(jnp.float32)
+    v = k if fused else v.astype(jnp.float32)
     s = (
         jax.lax.dot_general(
             q, k, (((col,), (k_hd,)), (lead, lead)),

@@ -13,8 +13,8 @@ The TPU part: attention over a paged cache must NOT gather pages into a
 contiguous buffer first (that would write + re-read the whole window,
 doubling HBM traffic — the exact cost paging exists to avoid). The
 Pallas kernels here stream pages directly: the page table rides as a
-SCALAR-PREFETCH operand (``pltpu.PrefetchScalarGridSpec``), and the K/V
-``index_map`` consults it to pick each grid step's physical page — the
+SCALAR-PREFETCH operand (``pltpu.PrefetchScalarGridSpec``), and the
+pool's ``index_map`` consults it to pick each grid step's physical page — the
 DMA engine fetches pool blocks in table order while the online-softmax
 state carries across them. The step body is ``ops/decode_attention``'s
 ``_attend_tile`` (same masks, same float32 softmax state, same fused
@@ -23,7 +23,7 @@ attention discipline, two memory layouts.
 
 The DECODE kernel's grid is ``(slots, head blocks, pages a slot)`` and
 one step covers one page of EVERY KV head of its block — a
-``(1, heads, page, head_dim)`` block of K and of V, ``heads`` the
+``(1, heads, page, 2 * head_dim)`` block of fused K|V rows, ``heads`` the
 largest divisor of the head count that fits a stated VMEM budget
 (``decode_heads_per_step``: 16 of 16 at head_dim 128, 25 of 25 at 64;
 derived from the operands, the per-shard ones under tensor
@@ -52,19 +52,31 @@ last split may be ragged (clamped in the index maps, masked in the
 kernel).
 
 Layouts:
-- pool: (num_pages, kv_heads, page_size, head_dim) in the native dtype
-  (bf16/f32), OR an ``(int8 values, f32 scales)`` PAIR of pools —
-  values (num_pages, kv_heads, page_size, head_dim) int8, scales
+- pool (one per decoder block; ``runtime/paged.alloc_kv_pools`` is the
+  definition): ONE plane (num_pages, kv_heads, page_size, 2 * head_dim)
+  in the native dtype (bf16/f32) — a position's K on lanes
+  ``[0, head_dim)`` and its V on ``[head_dim, 2 * head_dim)`` of the
+  same row (:func:`fuse_kv` / :func:`split_kv`). At head_dim 64 a row
+  is exactly one 128-lane tile and at 128 two, so the plane lives
+  row-major in HBM, the per-token append is one in-place scatter a
+  block (``append_kv_paged``) and every kernel reads the plane as it
+  lives. Inside a kernel K and V are the row's two halves: cut at a
+  tile edge when head_dim fills whole tiles; otherwise the row is not
+  cut at all — q is zero-padded over V's lanes, both products run over
+  the whole row, and V's half of the accumulator is the output
+  (``_acc_width``, ``_attend_fused``). OR a quantized ``(int8 values,
+  k_scales, v_scales)`` TRIPLE — values fused the same way
+  (num_pages, kv_heads, page_size, 2 * head_dim) int8, each scale plane
   (num_pages, kv_heads, page_size, 1) f32, one absmax scale per cached
   K/V vector (``ops/quantize.quantize_kv_vectors``, the same scheme as
-  the dense int8 strips). int4 pools keep the pair shape with the
-  VALUE plane packed two nibbles per int8 lane (width head_dim // 2,
-  ``quantize_kv_vectors(..., "int4")``); the kernels detect the packed
-  width against q's head_dim and unpack in VMEM, so the HBM stream is
-  4-bit. Quantized pools compose paging's
+  the dense int8 strips). int4 pools keep the triple with the
+  VALUE plane packed two nibbles per int8 lane (a row is head_dim
+  lanes, ``quantize_kv_vectors(..., "int4")``); the kernels detect the
+  packed width against q's head_dim and unpack in VMEM, so the HBM
+  stream is 4-bit. Quantized pools compose paging's
   resident-token capacity with int8's ~2-4x byte shrink: the scale
-  plane rides the SAME page table (page id addresses both pools), and
-  the kernels stream it as one chunked (page/128, 128) f32 tile per
+  planes ride the SAME page table (a page id addresses all three), and
+  the kernels stream each as one chunked (page/128, 128) f32 tile per
   page — 4/head_dim of the int8 payload's bytes (one f32 per vector)
   — applying scales to the score/probability
   COLUMNS so the big cache operand stays int8 end to end (dequant fused
@@ -123,11 +135,48 @@ DEFAULT_PAGE_SIZE = 128
 
 
 def pool_values(pool):
-    """The VALUE array of a pool operand: the int8 member of a
-    quantized ``(values, scales)`` pair, the pool itself otherwise —
-    the one place shape/head/page derivation looks, so every entry
-    point sees through the tuple identically."""
+    """The VALUE plane of a block's pool: the int8 member of a
+    quantized ``(values, k_scales, v_scales)`` triple, the pool itself
+    otherwise — the one place shape/head/page derivation looks, so
+    every entry point sees through the tuple identically. Its last
+    dimension is the FUSED row, K's lanes then V's."""
     return pool[0] if isinstance(pool, tuple) else pool
+
+
+def pool_planes(pool) -> tuple:
+    """The planes a block's pool (or a page-major chunk of one) is
+    made of, in its own order: the fused plane alone, or ``(values,
+    k_scales, v_scales)``."""
+    return pool if isinstance(pool, tuple) else (pool,)
+
+
+def fuse_kv(k, v):
+    """K and V of the same positions -> the pool's representation of
+    them: ONE row per position, K on lanes ``[0, w)`` and V on lanes
+    ``[w, 2w)``. ``k``/``v`` are native arrays (..., w) or quantized
+    ``(values, scales)`` pairs (``ops.quantize.quantize_kv_vectors``);
+    a quantized pair fuses its VALUE rows and keeps the two scale
+    columns beside them: ``(values (..., 2w), k_scales (..., 1),
+    v_scales (..., 1))``. Every writer of a pool (the per-token append,
+    the chunk and whole-prompt page scatters, the sp prefiller's page
+    blocks) builds its rows here, so the lane convention has one
+    definition."""
+    if isinstance(k, tuple):
+        return (jnp.concatenate([k[0], v[0]], axis=-1), k[1], v[1])
+    return jnp.concatenate([k, v], axis=-1)
+
+
+def split_kv(pool):
+    """Inverse of :func:`fuse_kv`: a block's pool (or any array in its
+    representation) -> ``(k, v)``, native arrays or ``(values,
+    scales)`` pairs — the two-operand form the contiguous oracles
+    (``decode_attention_reference``, ``verify_attention``) take."""
+    vals = pool_values(pool)
+    w = vals.shape[-1] // 2
+    k, v = vals[..., :w], vals[..., w:]
+    if isinstance(pool, tuple):
+        return (k, pool[1]), (v, pool[2])
+    return k, v
 
 
 def append_kv_paged(pool, new, phys, off):
@@ -135,8 +184,9 @@ def append_kv_paged(pool, new, phys, off):
     write into a page pool, shared by ``decode_step_paged`` (K == 1) and
     ``verify_chunk_paged`` (K chunk tokens per row).
 
-    pool (num_pages, kv_h, P, w) — a native pool or ONE member of a
-    quantized pair (the scale plane has w == 1); new (b, kv_h, K, w);
+    pool (num_pages, kv_h, P, w) — ONE plane of a block's pool: the
+    fused K|V plane (w == 2 * head_dim, :func:`fuse_kv`) or a scale
+    plane of a quantized pool (w == 1); new (b, kv_h, K, w);
     ``phys``/``off`` (b, K) int32: token (i, t) lands on
     ``pool[phys[i, t], :, off[i, t], :]``. Dead rows arrive routed to
     the trash page by the caller; rows that collide there overwrite
@@ -149,22 +199,24 @@ def append_kv_paged(pool, new, phys, off):
     around it — two to three copies of every plane at every decode step
     on a v5e. Which write is in place depends on where the lanes are,
     so it is chosen from the row width (measured on the chip, PERF.md
-    section 6, PR 25):
+    section 6, PR 25 and PR 30):
 
-    - ``w`` fills whole 128-lane tiles: the resident layout is already
+    - ``w`` fills whole 128-lane tiles — every fused native or int8
+      plane at head_dim >= 64: the resident layout is already
       row-major, and ONE scatter indexed over (page, head, offset) with
-      only ``w`` as its window updates it in place.
-    - ``w`` narrower than a lane tile (head_dim 64, scale planes): the
-      buffer lives with the page axis on the lanes, and that scatter
-      costs two relayouts. A ``dynamic_update_slice`` of one
-      (1, kv_h, 1, w) slab per token under a ``fori_loop`` is in place
-      in ANY layout. The decode kernel reads that layout as it is
+      only ``w`` as its window updates it in place. K and V of a token
+      are one row, so a block costs one scatter a step.
+    - ``w`` narrower than a lane tile (scale planes; a fused row at
+      head_dim under 64, or packed int4 at 64): the buffer lives with
+      the page axis on the lanes, and that scatter costs two
+      relayouts. A ``dynamic_update_slice`` of one (1, kv_h, 1, w) slab
+      per token under a ``fori_loop`` is in place in ANY layout, at 4-5
+      us a token. The decode kernel reads that layout as it is
       (``_paged_impl``: pages swapped to (w, page), a bitcast); the
-      verify and chunk kernels still pin row-major and cost their
-      programs one relayout a plane (ROADMAP A2). The
-      slab is sliced straight out of ``new`` — a transposed or reshaped
-      update operand drags the carry's layout with it and the copies
-      come back.
+      verify and chunk kernels pin row-major and cost their programs
+      one relayout a plane. The slab is sliced straight out of ``new``
+      — a transposed or reshaped update operand drags the carry's
+      layout with it and the copies come back.
 
     ``tests/test_chip_lowering.py`` counts the copies both leave in a
     program compiled for a v5e."""
@@ -186,18 +238,22 @@ def append_kv_paged(pool, new, phys, off):
     return lax.fori_loop(0, b * kc, write, pool)
 
 
-def _split_pools(k_pool, v_pool):
-    """Split possibly-quantized pool operands into ``(k_vals, v_vals,
-    k_scales, v_scales)`` — scales ``None`` for native pools. THE one
-    unpack the three kernel dispatchers share, so a future change to
-    the pair representation lands in one place."""
-    if isinstance(k_pool, tuple):
-        (kv, ks), (vv, vs) = k_pool, v_pool
-        return kv, vv, ks, vs
-    return k_pool, v_pool, None, None
+def _pool_planes(pool):
+    """A block's pool as the kernels' operands: ``(kv, k_scales,
+    v_scales)`` — scales ``None`` for native pools. THE one unpack the
+    three kernel dispatchers share, so a future change to the pool's
+    representation lands in one place."""
+    planes = pool_planes(pool)
+    return planes + (None,) * (3 - len(planes))
 
 
-def kernel_unsupported(q, k_pool) -> str | None:
+def _packed(q, kv, quantized) -> bool:
+    """Whether a quantized value plane is int4-PACKED: its fused row
+    holds ``2 * (head_dim // 2)`` lanes, q's own width."""
+    return quantized and kv.shape[3] == q.shape[-1]
+
+
+def kernel_unsupported(q, pool) -> str | None:
     """Shared pallas-dispatch gate for the three paged kernels: None
     when they can serve these operands, else the constraint broken (the
     reason auto dispatch routes to the XLA oracle, and the error a
@@ -212,13 +268,13 @@ def kernel_unsupported(q, k_pool) -> str | None:
     against Mosaic's 16 MB (ROADMAP A1 carries the lane-dense rewrite).
     The INTERPRETER has no tiling, so off-TPU the CI parity tests still
     drive every kernel body at ordinary page sizes."""
-    vals = pool_values(k_pool)
+    vals = pool_values(pool)
     page = vals.shape[2]
-    quantized = isinstance(k_pool, tuple)
+    quantized = isinstance(pool, tuple)
     if page % 128:
         return f"page_size {page} is not a multiple of 128"
     if quantized and on_tpu():
-        if vals.shape[3] * 2 == q.shape[-1]:
+        if _packed(q, vals, quantized):
             return (
                 "int4 pools: the in-VMEM nibble unpack exceeds Mosaic's "
                 "scoped VMEM limit on a TPU"
@@ -267,32 +323,36 @@ def _head_sharded(fn, head_shard, sharded, replicated):
     )(*(args[i] for i in live))
 
 
-def paged_attention_reference(q, k_pool, v_pool, page_table, index,
-                              valid_from=None):
+def _gather_window(pool, page_table):
+    """Each slot's pages of every plane of ``pool`` as one contiguous
+    window, split into the oracles' two operands: ``(k, v)``, each
+    (b, kvh, pages * P, w) or a ``(values, scales)`` pair of them."""
+    b = page_table.shape[0]
+
+    def gather(plane):
+        g_ = plane[page_table]  # (b, pages, kvh, P, w)
+        g_ = jnp.moveaxis(g_, 2, 1)
+        return g_.reshape(b, plane.shape[1], -1, plane.shape[3])
+
+    return split_kv(jax.tree.map(gather, pool))
+
+
+def paged_attention_reference(q, pool, page_table, index, valid_from=None):
     """jnp oracle: gather each slot's pages into a contiguous window,
-    then run the contiguous decode-attention oracle (which owns the
-    quantized score/probability-column scale application — one
-    definition, so paged int8 decode matches the dense int8 slot path
+    split the fused rows into K and V, then run the contiguous
+    decode-attention oracle (which owns the quantized
+    score/probability-column scale application — one definition, so
+    paged int8 decode matches the dense int8 slot path
     value-for-value). This is the semantics definition AND the
     materializing schedule the kernel exists to beat.
 
-    q (b, kvh, g, hd); pools (num_pages, kvh, P, hd) or ``(int8 values,
-    f32 scales)`` pairs; page_table (b, pages_per_slot) int32; index
+    q (b, kvh, g, hd); ``pool`` a block's pool — the fused (num_pages,
+    kvh, P, 2 * hd) plane or a quantized ``(values, k_scales,
+    v_scales)`` triple; page_table (b, pages_per_slot) int32; index
     scalar or (b,)."""
     from adapt_tpu.ops.decode_attention import decode_attention_reference
 
-    b = q.shape[0]
-    # (b, pages, kvh, P, hd) -> (b, kvh, pages*P, hd)
-    def gather(pool):
-        g_ = pool[page_table]  # (b, pages, kvh, P, hd)
-        g_ = jnp.moveaxis(g_, 2, 1)
-        return g_.reshape(b, pool.shape[1], -1, pool.shape[3])
-
-    if isinstance(k_pool, tuple):
-        cache_k = (gather(k_pool[0]), gather(k_pool[1]))
-        cache_v = (gather(v_pool[0]), gather(v_pool[1]))
-    else:
-        cache_k, cache_v = gather(k_pool), gather(v_pool)
+    cache_k, cache_v = _gather_window(pool, page_table)
     return decode_attention_reference(
         q, cache_k, cache_v, index, valid_from
     )
@@ -312,23 +372,39 @@ def _lanes(width: int) -> int:
     return -(-width // 128) * 128
 
 
+def _acc_width(head_dim: int) -> int:
+    """Lanes of the kernels' accumulator (and of q as they read it)
+    over a fused K|V row. A head_dim of whole lane tiles splits the row
+    at a tile edge, for nothing, and the accumulator is V's width. A
+    narrower one (64: the row is ONE tile) is not split at all: q
+    arrives zero-padded to the row, the score product contracts the
+    whole row (V's lanes meet exact zeros), the probabilities weight
+    the whole row, and V's half of the accumulator is the output — two
+    products a lane tile wide either way on a 128-wide MXU, and no
+    lane shuffle of the streamed block (slicing the block at lane 64
+    instead read 11% slower on a v5e: PERF.md section 6, PR 30)."""
+    return head_dim if head_dim % 128 == 0 else 2 * head_dim
+
+
 def decode_step_vmem_bytes(heads, page, row_width, itemsize, scales,
                            gq=8, head_dim=None) -> int:
     """VMEM one grid step of the decode kernel asks for when it covers
-    ``heads`` KV heads of one page: the K and V blocks (and their scale
-    tiles), each double-buffered by the pipeline; q and the output
-    block, double-buffered; the per-head softmax state; and the body's
-    float32 working set (one head's K and V widened on their way into
-    the products, the block's score-shaped rows). K/V rows narrower
-    than a lane tile (head_dim 64) arrive transposed, the page on the
-    lanes, and pad nothing; q, the output and the state do."""
-    hd = _lanes(head_dim or row_width)
-    stream = 2 * 2 * heads * page * row_width * itemsize
+    ``heads`` KV heads of one page of a fused plane (``row_width`` =
+    the plane's last dimension, K|V): the block (and its two scale
+    tiles), double-buffered by the pipeline; q and the output block,
+    double-buffered; the per-head softmax state; and the body's float32
+    working set (one head's rows widened on their way into the
+    products, the block's score-shaped rows). Rows narrower than a lane
+    tile arrive transposed, the page on the lanes, and pad nothing; q,
+    the output and the state do."""
+    hd = head_dim or row_width // 2
+    acc = _lanes(_acc_width(hd))
+    stream = 2 * heads * page * row_width * itemsize
     if scales:
         stream += 2 * 2 * heads * max(page // 128, 8) * 128 * 4
-    rows = 2 * 2 * heads * gq * hd * 4
-    state = heads * (2 * 8 * 128 + gq * hd) * 4
-    working = (2 * page * hd + heads * 6 * gq * page) * 4
+    rows = 2 * heads * gq * (acc + _lanes(hd)) * 4
+    state = heads * (2 * 8 * 128 + gq * acc) * 4
+    working = (page * _lanes(row_width) + heads * 6 * gq * page) * 4
     return stream + rows + state + working
 
 
@@ -340,7 +416,7 @@ def decode_heads_per_step(kv_heads, page, row_width, itemsize, scales,
     step costs 0.15-0.25 us on a v5e before it moves a byte and its
     body is a chain of dependent operations, so a step should cover as
     many independent heads as fit: 16 heads of a bf16 page at head_dim
-    128 are 1 MB of K and V, 25 heads at head_dim 64 0.8 MB; an int8
+    128 are 1 MB of fused rows, 25 heads at head_dim 64 0.8 MB; an int8
     pool at 1024-position pages comes out at 8 of 16 (5 of 25) by the
     same sum. Derived from the operands, never set."""
     for heads in range(kv_heads, 1, -1):
@@ -351,20 +427,56 @@ def decode_heads_per_step(kv_heads, page, row_width, itemsize, scales,
     return 1
 
 
+def _attend_fused(q, kv, ksc, vsc, live, m_scr, l_scr, acc_scr, sm_scale,
+                  packed, transposed=False):
+    """One block of a FUSED K|V plane through
+    ``decode_attention._attend_tile`` — the step body of the three
+    paged kernels. ``kv`` is (..., block_k, 2w) — (..., 2w, block_k)
+    when ``transposed`` — ``w`` the head_dim, halved when ``packed``
+    (int4 nibbles; unpacked here, in VMEM, K's lanes then V's). ``q``
+    and the accumulator are ``_acc_width(head_dim)`` lanes wide: at
+    whole lane tiles K and V are the row's two halves, cut at a tile
+    edge; narrower, the SAME block is both operands (``_acc_width``)
+    and the kernel emits the accumulator's last head_dim lanes."""
+    if packed:
+        kv = unpack_int4(kv)
+    hd = kv.shape[-2 if transposed else -1] // 2
+    if q.shape[-1] == hd:  # cut at a tile edge (never a transposed row)
+        assert not transposed
+        k, v = kv[..., :hd], kv[..., hd:]
+    else:  # q zero-padded over V's lanes: contract the whole row
+        k = v = kv
+    _attend_tile(
+        q, k, v, ksc, vsc, live, m_scr, l_scr, acc_scr, sm_scale, False,
+        transposed,
+    )
+
+
+def _pad_q_lanes(q, hd):
+    """q zero-padded from head_dim to the accumulator's width
+    (``_acc_width``): nothing at whole lane tiles."""
+    pad = _acc_width(hd) - hd
+    if not pad:
+        return q
+    return jnp.pad(q, [(0, 0)] * (q.ndim - 1) + [(0, pad)])
+
+
 @functools.partial(jax.jit, static_argnames=("heads", "split"))
-def _paged_impl(q, k_pool, v_pool, k_scales, v_scales, page_table, index,
+def _paged_impl(q, kv_pool, k_scales, v_scales, page_table, index,
                 valid_from, heads=1, split=1):
     b, kvh, g, hd = q.shape
-    page = k_pool.shape[2]
-    hdk = k_pool.shape[3]  # head_dim // 2 for packed int4 pools
+    page = kv_pool.shape[2]
+    row = kv_pool.shape[3]  # K|V: 2 * head_dim, head_dim for packed int4
     quantized = k_scales is not None
-    packed = quantized and hdk * 2 == hd
+    packed = _packed(q, kv_pool, quantized)
     pages_per_slot = page_table.shape[1]
     has_vf = valid_from is not None
     pad_g = (-g) % 8
     if pad_g:
         q = jnp.pad(q, ((0, 0), (0, 0), (0, pad_g), (0, 0)))
     gq = g + pad_g
+    aw = _acc_width(hd)
+    q = _pad_q_lanes(q, hd)
     bps = -(-pages_per_slot // split)  # pages per split (last may be ragged)
 
     # Scalar prefetch: the page table, each slot's newest live position
@@ -396,25 +508,24 @@ def _paged_impl(q, k_pool, v_pool, k_scales, v_scales, page_table, index,
         first = jnp.clip(vf_ref[0][s] // page, 0, last) if has_vf else 0
         return (table_ref[s, jnp.clip(jg, first, last)], hb, 0, 0)
 
-    # A pool of rows narrower than a lane tile (head_dim 64) lives in
-    # HBM with the page axis on the lanes (``append_kv_paged``), and a
-    # Mosaic operand is pinned row-major: read as (pages, heads, page,
-    # hd) the whole plane was relaid out at every step (30-36% of the
-    # device's time in the GPT-2-XL cells on a v5e). Swapped to (pages,
-    # heads, hd, page) row-major IS the layout the plane has, so the
-    # swap moves nothing, a block's rows fill their lanes, and the body
-    # contracts the other axis (``kv_transposed``). Packed int4 rows
-    # unpack along their lanes and stay as stored.
-    transposed = hdk % 128 != 0 and not packed
-    kv_block = (1, heads, hdk, page) if transposed else (1, heads, page, hdk)
+    # A fused row of whole lane tiles (head_dim >= 64) lives row-major
+    # and is read as it lives. A NARROWER row (head_dim under 64) lives
+    # in HBM with the page axis on the lanes (``append_kv_paged``), and
+    # a Mosaic operand is pinned row-major: read as (pages, heads,
+    # page, row) the whole plane was relaid out at every step. Swapped
+    # to (pages, heads, row, page) row-major IS the layout the plane
+    # has, so the swap moves nothing, a block's rows fill their lanes,
+    # and the body contracts the other axis (``kv_transposed``). Packed
+    # int4 rows unpack along their lanes and stay as stored.
+    transposed = row % 128 != 0 and not packed
+    kv_block = (1, heads, row, page) if transposed else (1, heads, page, row)
     if transposed:
-        k_pool, v_pool = jnp.swapaxes(k_pool, 2, 3), jnp.swapaxes(v_pool, 2, 3)
+        kv_pool = jnp.swapaxes(kv_pool, 2, 3)
     in_specs = [
-        pl.BlockSpec((1, heads, gq, hd), row_map, memory_space=_VMEM),
-        pl.BlockSpec(kv_block, kv_map, memory_space=_VMEM),
+        pl.BlockSpec((1, heads, gq, aw), row_map, memory_space=_VMEM),
         pl.BlockSpec(kv_block, kv_map, memory_space=_VMEM),
     ]
-    # The pools stay in HBM and the kernel streams them from there. Left
+    # The pool stays in HBM and the kernel streams it from there. Left
     # to itself XLA may park a plane small enough in fast memory on its
     # way in (it did GPT-2-XL's 23 MB planes while they were still
     # relaid out: the kernel then read at 107% of its bytes floor on a
@@ -423,7 +534,7 @@ def _paged_impl(q, k_pool, v_pool, k_scales, v_scales, page_table, index,
     in_hbm = (lambda x: x) if pallas_interpret() else functools.partial(
         pltpu.with_memory_space_constraint, memory_space=pltpu.HBM
     )
-    operands = [q, in_hbm(k_pool), in_hbm(v_pool)]
+    operands = [q, in_hbm(kv_pool)]
     if quantized:
         # (pages, kvh, P, 1) f32 scale pools -> (pages, kvh, P/128,
         # 128) CHUNKED views (position = row*128 + lane — the dense
@@ -456,7 +567,7 @@ def _paged_impl(q, k_pool, v_pool, k_scales, v_scales, page_table, index,
     scratch = [
         pltpu.VMEM((heads, gq, 1), jnp.float32),
         pltpu.VMEM((heads, gq, 1), jnp.float32),
-        pltpu.VMEM((heads, gq, hd), jnp.float32),
+        pltpu.VMEM((heads, gq, aw), jnp.float32),
     ]
     head_blocks = kvh // heads
     if split == 1:
@@ -508,6 +619,28 @@ def _paged_impl(q, k_pool, v_pool, k_scales, v_scales, page_table, index,
     return _combine_splits(o_p, m_p, l_p, q.dtype)[:, :, :g, :]
 
 
+def _emit_softmax(o_ref, parts, m_scr, l_scr, acc_scr):
+    """The kernels' last step for a row: the normalized output, or —
+    ``parts`` = the split form's ``(m_ref, l_ref)`` — the unnormalized
+    partials for ``_combine_splits``. ``o_ref``'s block ends in
+    (rows, head_dim); the accumulator may be wider (``_acc_width``):
+    V's lanes are its last head_dim."""
+    hd = o_ref.shape[-1]
+    acc = acc_scr[...][..., acc_scr.shape[-1] - hd:]
+    lead = (0,) * (len(o_ref.shape) - acc.ndim)
+    if parts is None:
+        o_ref[lead] = (
+            acc / jnp.maximum(l_scr[...], 1e-30)
+        ).astype(o_ref.dtype)
+        return
+    # m/l broadcast across the lane axis so the partials share the
+    # accumulator's tiling; the combine reads lane 0.
+    m_ref, l_ref = parts
+    o_ref[lead] = acc
+    m_ref[lead] = jnp.broadcast_to(m_scr[...], acc.shape)
+    l_ref[lead] = jnp.broadcast_to(l_scr[...], acc.shape)
+
+
 def _paged_kernel(table_ref, idx_ref, *refs, page, num_pages, bps, sm_scale,
                   quantized, has_vf, packed=False, transposed=False):
     """One page of ``heads`` KV heads of one slot per grid step: grid
@@ -517,28 +650,31 @@ def _paged_kernel(table_ref, idx_ref, *refs, page, num_pages, bps, sm_scale,
     denominator) for ``_combine_splits`` instead of a normalized
     output. The scalar-prefetched table is consumed by the index maps;
     the prefetched positions give this slot's live window
-    ``[valid_from, index]``. K/V arrive as (1, heads, page, hd) blocks
-    ((1, heads, hd, page) when ``transposed``: head_dim 64) and the body is ONE ``decode_attention._attend_tile`` over the
+    ``[valid_from, index]``. The fused rows arrive as a (1, heads,
+    page, 2 * hd) block ((1, heads, 2 * hd, page) when ``transposed``:
+    head_dim under 64) and the body is ONE ``_attend_fused`` over the
     block, the head axis leading (one attention discipline: its masks,
-    its float32 softmax state, its fused int8 dequant and int4 unpack),
-    with per-head state in (heads, gq, .) scratch. Quantized pools add chunked
-    (1, heads, page/128, 128) f32 scale tiles, table-addressed like the
-    payload. A page outside the live window — every page of a dead row
-    (negative index), the ragged tail of the last split — skips the
-    body, and its step fetched nothing (``_paged_impl``'s ``kv_map``)."""
+    its float32 softmax state, its fused int8 dequant and int4
+    unpack), with per-head state in (heads, gq, .) scratch. Quantized
+    pools add chunked (1, heads, page/128, 128) f32 scale tiles of K
+    and of V, table-addressed like the payload. A page outside the live
+    window — every page of a dead row (negative index), the ragged
+    tail of the last split — skips the body, and its step fetched
+    nothing (``_paged_impl``'s ``kv_map``)."""
     del table_ref  # consumed by the index maps
     refs = list(refs)
     vf_ref = refs.pop(0) if has_vf else None
-    q_ref, k_ref, v_ref = refs[:3]
-    del refs[:3]
+    q_ref, kv_ref = refs[:2]
+    del refs[:2]
     ksc_ref = refs.pop(0) if quantized else None
     vsc_ref = refs.pop(0) if quantized else None
     if bps is None:
         o_ref, m_scr, l_scr, acc_scr = refs
+        parts = None
         j = pl.program_id(2)
         jg, last_j = j, num_pages - 1
     else:
-        o_ref, m_ref, l_ref, m_scr, l_scr, acc_scr = refs
+        o_ref, *parts, m_scr, l_scr, acc_scr = refs
         j = pl.program_id(3)
         jg, last_j = pl.program_id(2) * bps + j, bps - 1
     heads, gq = q_ref.shape[1], q_ref.shape[2]
@@ -558,8 +694,8 @@ def _paged_kernel(table_ref, idx_ref, *refs, page, num_pages, bps, sm_scale,
         if has_vf:
             live = jnp.logical_and(live, cols >= vf)
 
-        _attend_tile(
-            q_ref[0], k_ref[0], v_ref[0],
+        _attend_fused(
+            q_ref[0], kv_ref[0],
             ksc_ref[0].reshape(heads, 1, page) if quantized else None,
             vsc_ref[0].reshape(heads, 1, page) if quantized else None,
             live, m_scr, l_scr, acc_scr, sm_scale, packed, transposed,
@@ -574,19 +710,10 @@ def _paged_kernel(table_ref, idx_ref, *refs, page, num_pages, bps, sm_scale,
 
     @pl.when(j == last_j)
     def _emit():
-        if bps is None:
-            o_ref[0] = (
-                acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
-            ).astype(o_ref.dtype)
-        else:
-            # m/l broadcast across the lane axis so the partials share
-            # the accumulator's tiling; the combine reads lane 0.
-            o_ref[0, 0] = acc_scr[...]
-            m_ref[0, 0] = jnp.broadcast_to(m_scr[...], acc_scr.shape)
-            l_ref[0, 0] = jnp.broadcast_to(l_scr[...], acc_scr.shape)
+        _emit_softmax(o_ref, parts, m_scr, l_scr, acc_scr)
 
 
-def _chunk_kernel(pages_ref, q_ref, k_ref, v_ref, pos0_ref, *refs,
+def _chunk_kernel(pages_ref, q_ref, kv_ref, pos0_ref, *refs,
                   block_k, num_kv, sm_scale, chunk, window=None,
                   quantized=False, packed=False):
     """Chunk-query paged attention: q rows are a CHUNK of positions
@@ -622,8 +749,8 @@ def _chunk_kernel(pages_ref, q_ref, k_ref, v_ref, pos0_ref, *refs,
             # Sliding window: row at absolute position p attends
             # (p - window, p].
             live = jnp.logical_and(live, cols > pos0 + rows - window)
-        _attend_tile(
-            q_ref[0], k_ref[0, 0], v_ref[0, 0],
+        _attend_fused(
+            q_ref[0], kv_ref[0, 0],
             ksc_ref[0, 0].reshape(1, block_k) if quantized else None,
             vsc_ref[0, 0].reshape(1, block_k) if quantized else None,
             live, m_scr, l_scr, acc_scr, sm_scale, packed,
@@ -641,32 +768,23 @@ def _chunk_kernel(pages_ref, q_ref, k_ref, v_ref, pos0_ref, *refs,
 
     @pl.when(j == num_kv - 1)
     def _emit():
-        o_ref[0] = (
-            acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
-        ).astype(o_ref.dtype)
+        _emit_softmax(o_ref, None, m_scr, l_scr, acc_scr)
 
 
-def paged_chunk_attention_reference(q, k_pool, v_pool, pages, pos0,
-                                    chunk: int, window: int | None = None):
-    """jnp oracle for the chunk-query kernel: gather the window, mask
-    ``col <= pos0 + row % chunk`` (banded by ``window`` when set),
-    softmax, weight. q is (1, kv_h, g*C, hd) GROUP-FOLDED (row =
-    member*C + position), pages (n,). Quantized ``(values, scales)``
-    pool pairs apply scales to the score/probability columns, in
-    ``decode_attention_reference``'s op order."""
-    quantized = isinstance(k_pool, tuple)
-    kv = pool_values(k_pool)
-    kvh, hd = kv.shape[1], kv.shape[3]
-
-    def gather(pool):
-        return jnp.moveaxis(pool[pages], 1, 0).reshape(
-            1, kvh, -1, pool.shape[3]
-        )
-
+def paged_chunk_attention_reference(q, pool, pages, pos0, chunk: int,
+                                    window: int | None = None):
+    """jnp oracle for the chunk-query kernel: gather the window, split
+    the fused rows, mask ``col <= pos0 + row % chunk`` (banded by
+    ``window`` when set), softmax, weight. q is (1, kv_h, g*C, hd)
+    GROUP-FOLDED (row = member*C + position), pages (n,). A quantized
+    ``(values, k_scales, v_scales)`` pool applies its scales to the
+    score/probability columns, in ``decode_attention_reference``'s op
+    order."""
+    quantized = isinstance(pool, tuple)
+    k, v = _gather_window(pool, jnp.asarray(pages)[None])
     sm = 1.0 / jnp.sqrt(jnp.asarray(q.shape[-1], jnp.float32))
     if quantized:
-        k, ksc = gather(k_pool[0]), gather(k_pool[1])
-        v, vsc = gather(v_pool[0]), gather(v_pool[1])
+        (k, ksc), (v, vsc) = k, v
         if k.shape[-1] * 2 == q.shape[-1]:  # packed int4 nibbles
             k, v = unpack_int4(k), unpack_int4(v)
         s = jnp.einsum(
@@ -675,7 +793,6 @@ def paged_chunk_attention_reference(q, k_pool, v_pool, pages, pos0,
             k.astype(jnp.float32),
         ) * jnp.swapaxes(ksc, 2, 3) * sm
     else:
-        k, v = gather(k_pool), gather(v_pool)
         s = jnp.einsum(
             "bhqd,bhkd->bhqk", q.astype(jnp.float32), k.astype(jnp.float32)
         ) * sm
@@ -694,19 +811,20 @@ def paged_chunk_attention_reference(q, k_pool, v_pool, pages, pos0,
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "window"))
-def _chunk_impl(q, k_pool, v_pool, k_scales, v_scales, pages, pos0, chunk,
+def _chunk_impl(q, kv_pool, k_scales, v_scales, pages, pos0, chunk,
                 window=None):
     _, kvh, gc, hd = q.shape
-    page = k_pool.shape[2]
-    hdk = k_pool.shape[3]  # head_dim // 2 for packed int4 pools
+    page = kv_pool.shape[2]
+    row = kv_pool.shape[3]  # K|V: 2 * head_dim, head_dim for packed int4
     n = pages.shape[0]
     quantized = k_scales is not None
-    packed = quantized and hdk * 2 == hd
+    packed = _packed(q, kv_pool, quantized)
     pad_g = (-gc) % 8
     if pad_g:
         q = jnp.pad(q, ((0, 0), (0, 0), (0, pad_g), (0, 0)))
     gcp = gc + pad_g
-    qf = q.reshape(kvh, gcp, hd)
+    aw = _acc_width(hd)
+    qf = _pad_q_lanes(q, hd).reshape(kvh, gcp, aw)
     pos0v = jnp.reshape(jnp.asarray(pos0, jnp.int32), (1,))
 
     def q_map(h, j, pages_ref):
@@ -717,14 +835,13 @@ def _chunk_impl(q, k_pool, v_pool, k_scales, v_scales, pages, pos0, chunk,
         return (pages_ref[j], h, 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, gcp, hd), q_map, memory_space=_VMEM),
-        pl.BlockSpec((1, 1, page, hdk), kv_map, memory_space=_VMEM),
-        pl.BlockSpec((1, 1, page, hdk), kv_map, memory_space=_VMEM),
+        pl.BlockSpec((1, gcp, aw), q_map, memory_space=_VMEM),
+        pl.BlockSpec((1, 1, page, row), kv_map, memory_space=_VMEM),
         pl.BlockSpec(memory_space=pltpu.SMEM),
     ]
-    operands = [qf, k_pool, v_pool, pos0v]
+    operands = [qf, kv_pool, pos0v]
     if quantized:
-        # Kernel arg order is q, k, v, pos0, THEN the scale tiles (the
+        # Kernel arg order is q, kv, pos0, THEN the scale tiles (the
         # kernel pops them off *refs after the SMEM scalar); chunked
         # (P/128, 128) scale views as in _paged_impl.
         for s in (k_scales, v_scales):
@@ -744,7 +861,7 @@ def _chunk_impl(q, k_pool, v_pool, k_scales, v_scales, pages, pos0, chunk,
         scratch_shapes=[
             pltpu.VMEM((gcp, 1), jnp.float32),
             pltpu.VMEM((gcp, 1), jnp.float32),
-            pltpu.VMEM((gcp, hd), jnp.float32),
+            pltpu.VMEM((gcp, aw), jnp.float32),
         ],
     )
     out = pl.pallas_call(
@@ -770,8 +887,7 @@ def _chunk_impl(q, k_pool, v_pool, k_scales, v_scales, pages, pos0, chunk,
 
 def paged_chunk_attention(
     q: jax.Array,
-    k_pool: jax.Array,
-    v_pool: jax.Array,
+    pool,
     pages: jax.Array,
     pos0,
     chunk: int,
@@ -781,61 +897,50 @@ def paged_chunk_attention(
 ) -> jax.Array:
     """Chunk-prefill attention over a paged window, in place — the
     incremental-prefill counterpart of :func:`paged_attention` (no
-    gathered strip, no scatter-back; the caller writes the chunk's K/V
-    pages first, this reads the window page by page).
+    gathered strip, no scatter-back; the caller writes the chunk's
+    fused K|V pages first, this reads the window page by page).
 
     q (1, kv_h, g*chunk, hd) group-folded; ``pages`` (n,) covers the
     whole live window [0, pos0 + chunk) (pow2 padding to the trash page
-    is fine — those positions are past every row's mask). Pools may be
-    quantized ``(int8 values, f32 scales)`` pairs. Dispatch and
-    ``head_shard`` as :func:`paged_attention`."""
-    check_head_parity(q.shape[1], pool_values(k_pool).shape[1])
+    is fine — those positions are past every row's mask). ``pool`` is a
+    block's pool: the fused plane or a quantized ``(values, k_scales,
+    v_scales)`` triple. Dispatch and ``head_shard`` as
+    :func:`paged_attention`."""
+    check_head_parity(q.shape[1], pool_values(pool).shape[1])
     if resolve_prefer(
-        "paged_chunk", prefer, kernel_unsupported(q, k_pool), on_tpu()
+        "paged_chunk", prefer, kernel_unsupported(q, pool), on_tpu()
     ):
-        kv, vv, ks, vs = _split_pools(k_pool, v_pool)
         return _head_sharded(
             functools.partial(_chunk_impl, chunk=chunk, window=window),
             head_shard,
-            (q, kv, vv, ks, vs),
+            (q, *_pool_planes(pool)),
             (jnp.asarray(pages, jnp.int32), jnp.asarray(pos0, jnp.int32)),
         )
     return paged_chunk_attention_reference(
-        q, k_pool, v_pool, pages, pos0, chunk, window
+        q, pool, pages, pos0, chunk, window
     )
 
 
-def paged_verify_attention_reference(q, k_pool, v_pool, page_table, index,
+def paged_verify_attention_reference(q, pool, page_table, index,
                                      chunk: int, window: int | None = None,
                                      tree_tail: int = 0):
     """jnp oracle for the batched paged VERIFY: gather each slot's pages
-    into a contiguous window and run the contiguous verify oracle
-    (``ops/decode_attention.verify_attention``, which owns the
-    quantized scale application for ``(int8 values, f32 scales)``
-    pools) — per-row diagonal ``col <= index[b] + row % chunk``. q
-    (b, kv_h, g*chunk, hd) group-folded K-major; ``index`` (b,)
-    per-slot base positions (negative = dead row, fully masked)."""
+    into a contiguous window, split the fused rows and run the
+    contiguous verify oracle (``ops/decode_attention.verify_attention``,
+    which owns the quantized scale application) — per-row diagonal
+    ``col <= index[b] + row % chunk``. q (b, kv_h, g*chunk, hd)
+    group-folded K-major; ``index`` (b,) per-slot base positions
+    (negative = dead row, fully masked)."""
     from adapt_tpu.ops.decode_attention import verify_attention
 
-    b = q.shape[0]
-
-    def gather(pool):
-        g_ = pool[page_table]  # (b, pages, kvh, P, hd)
-        g_ = jnp.moveaxis(g_, 2, 1)
-        return g_.reshape(b, pool.shape[1], -1, pool.shape[3])
-
-    if isinstance(k_pool, tuple):
-        cache_k = (gather(k_pool[0]), gather(k_pool[1]))
-        cache_v = (gather(v_pool[0]), gather(v_pool[1]))
-    else:
-        cache_k, cache_v = gather(k_pool), gather(v_pool)
+    cache_k, cache_v = _gather_window(pool, page_table)
     return verify_attention(
         q, cache_k, cache_v, index, chunk, window=window,
         tree_tail=tree_tail,
     )
 
 
-def _verify_kernel(table_ref, q_ref, k_ref, v_ref, idx_ref, *refs,
+def _verify_kernel(table_ref, q_ref, kv_ref, idx_ref, *refs,
                    block_k, num_kv, sm_scale, chunk, window=None,
                    quantized=False, packed=False, tree_tail=0, bps=None):
     """Batched chunk-query paged attention: one (batch, kv_head) row of
@@ -860,12 +965,13 @@ def _verify_kernel(table_ref, q_ref, k_ref, v_ref, idx_ref, *refs,
     ksc_ref = refs.pop(0) if quantized else None
     vsc_ref = refs.pop(0) if quantized else None
     if split_mode:
-        o_ref, m_ref, l_ref, m_scr, l_scr, acc_scr = refs
+        o_ref, *parts, m_scr, l_scr, acc_scr = refs
         j = pl.program_id(2)
         jg = pl.program_id(1) * bps + j  # global page index (clamped map)
         last_j = bps - 1
     else:
         o_ref, m_scr, l_scr, acc_scr = refs
+        parts = None
         j = pl.program_id(1)
         jg = j
         last_j = num_kv - 1
@@ -892,8 +998,8 @@ def _verify_kernel(table_ref, q_ref, k_ref, v_ref, idx_ref, *refs,
             # A leaf row's own physical slot is live even though it sits
             # past the chain edge; siblings' slots stay masked.
             live = jnp.logical_or(live, cols == idx + rows)
-        _attend_tile(
-            q_ref[0], k_ref[0, 0], v_ref[0, 0],
+        _attend_fused(
+            q_ref[0], kv_ref[0, 0],
             ksc_ref[0, 0].reshape(1, block_k) if quantized else None,
             vsc_ref[0, 0].reshape(1, block_k) if quantized else None,
             live, m_scr, l_scr, acc_scr, sm_scale, packed,
@@ -914,33 +1020,26 @@ def _verify_kernel(table_ref, q_ref, k_ref, v_ref, idx_ref, *refs,
 
     @pl.when(j == last_j)
     def _emit():
-        if split_mode:
-            hd = o_ref.shape[-1]
-            o_ref[0, 0] = acc_scr[...]
-            m_ref[0, 0] = jnp.broadcast_to(m_scr[...], (gc, hd))
-            l_ref[0, 0] = jnp.broadcast_to(l_scr[...], (gc, hd))
-        else:
-            o_ref[0] = (
-                acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
-            ).astype(o_ref.dtype)
+        _emit_softmax(o_ref, parts, m_scr, l_scr, acc_scr)
 
 
 @functools.partial(
     jax.jit, static_argnames=("chunk", "window", "tree_tail", "split")
 )
-def _verify_impl(q, k_pool, v_pool, k_scales, v_scales, page_table, index,
+def _verify_impl(q, kv_pool, k_scales, v_scales, page_table, index,
                  chunk, window=None, tree_tail=0, split=1):
     b, kvh, gc, hd = q.shape
-    page = k_pool.shape[2]
-    hdk = k_pool.shape[3]  # head_dim // 2 for packed int4 pools
+    page = kv_pool.shape[2]
+    row = kv_pool.shape[3]  # K|V: 2 * head_dim, head_dim for packed int4
     pages_per_slot = page_table.shape[1]
     quantized = k_scales is not None
-    packed = quantized and hdk * 2 == hd
+    packed = _packed(q, kv_pool, quantized)
     pad_g = (-gc) % 8
     if pad_g:
         q = jnp.pad(q, ((0, 0), (0, 0), (0, pad_g), (0, 0)))
     gcp = gc + pad_g
-    qf = q.reshape(b * kvh, gcp, hd)
+    aw = _acc_width(hd)
+    qf = _pad_q_lanes(q, hd).reshape(b * kvh, gcp, aw)
     idx = jnp.repeat(
         jnp.broadcast_to(jnp.asarray(index, jnp.int32).reshape(-1), (b,)),
         kvh,
@@ -962,12 +1061,11 @@ def _verify_impl(q, k_pool, v_pool, k_scales, v_scales, page_table, index,
         return (table_ref[bh // kvh, blk(bh, *js)], bh % kvh, 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, gcp, hd), q_map, memory_space=_VMEM),
-        pl.BlockSpec((1, 1, page, hdk), kv_map, memory_space=_VMEM),
-        pl.BlockSpec((1, 1, page, hdk), kv_map, memory_space=_VMEM),
+        pl.BlockSpec((1, gcp, aw), q_map, memory_space=_VMEM),
+        pl.BlockSpec((1, 1, page, row), kv_map, memory_space=_VMEM),
         pl.BlockSpec(memory_space=pltpu.SMEM),
     ]
-    operands = [qf, k_pool, v_pool, idx]
+    operands = [qf, kv_pool, idx]
     if quantized:
         # Chunked (P/128, 128) scale views as in _paged_impl.
         for s in (k_scales, v_scales):
@@ -982,7 +1080,7 @@ def _verify_impl(q, k_pool, v_pool, k_scales, v_scales, page_table, index,
     scratch = [
         pltpu.VMEM((gcp, 1), jnp.float32),
         pltpu.VMEM((gcp, 1), jnp.float32),
-        pltpu.VMEM((gcp, hd), jnp.float32),
+        pltpu.VMEM((gcp, aw), jnp.float32),
     ]
     if split == 1:
         grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -1058,8 +1156,7 @@ def _verify_impl(q, k_pool, v_pool, k_scales, v_scales, page_table, index,
 
 def paged_verify_attention(
     q: jax.Array,
-    k_pool: jax.Array,
-    v_pool: jax.Array,
+    pool,
     page_table: jax.Array,
     index,
     chunk: int,
@@ -1074,21 +1171,21 @@ def paged_verify_attention(
     rows per slot, each masked to its own ``index[b] + t`` diagonal;
     the caller has already scattered the chunk's K/V into the pages).
 
-    Pools are native arrays or quantized ``(int8 values, f32 scales)``
-    pairs (the caller scattered the chunk's quantized K/V into BOTH
-    members; int4-PACKED pairs carry ``head_dim // 2`` nibble lanes).
-    ``tree_tail`` marks the chunk's last w rows as tree-draft leaves
+    ``pool`` is a block's pool: the fused plane or a quantized
+    ``(values, k_scales, v_scales)`` triple (the caller scattered the
+    chunk's quantized rows into all three; an int4-PACKED value plane
+    carries ``head_dim`` nibble lanes for K|V). ``tree_tail`` marks the
+    chunk's last w rows as tree-draft leaves
     (``decode_attention.verify_attention``'s mask). ``split`` is the
     flash-decoding page-axis split (None = auto on TPU, 1 off-TPU).
     Dispatch and ``head_shard`` as :func:`paged_attention`. Grids and
     the GQA fold derive from the shapes given — the per-shard head
     count under tensor parallelism — so q and pool must carry the same
     head count (``decode_attention.check_head_parity``)."""
-    check_head_parity(q.shape[1], pool_values(k_pool).shape[1])
+    check_head_parity(q.shape[1], pool_values(pool).shape[1])
     if resolve_prefer(
-        "paged_verify", prefer, kernel_unsupported(q, k_pool), on_tpu()
+        "paged_verify", prefer, kernel_unsupported(q, pool), on_tpu()
     ):
-        kv, vv, ks, vs = _split_pools(k_pool, v_pool)
         return _head_sharded(
             functools.partial(
                 _verify_impl, chunk=chunk, window=window,
@@ -1096,19 +1193,18 @@ def paged_verify_attention(
                 split=resolve_decode_split(page_table.shape[1], split),
             ),
             head_shard,
-            (q, kv, vv, ks, vs),
+            (q, *_pool_planes(pool)),
             (jnp.asarray(page_table, jnp.int32),
              jnp.asarray(index, jnp.int32)),
         )
     return paged_verify_attention_reference(
-        q, k_pool, v_pool, page_table, index, chunk, window, tree_tail
+        q, pool, page_table, index, chunk, window, tree_tail
     )
 
 
 def paged_attention(
     q: jax.Array,
-    k_pool: jax.Array,
-    v_pool: jax.Array,
+    pool,
     page_table: jax.Array,
     index,
     valid_from=None,
@@ -1118,9 +1214,10 @@ def paged_attention(
 ) -> jax.Array:
     """Decode attention over a paged KV cache.
 
-    Pools are native arrays or ``(int8 values, f32 scales)`` pairs (one
-    scale per cached vector — the module-docstring layout); both pools
-    must agree on quantization.
+    ``pool`` is a block's pool (``runtime/paged.alloc_kv_pools``): the
+    fused K|V plane, or a quantized ``(int8 values, k_scales,
+    v_scales)`` triple (one scale per cached vector — the
+    module-docstring layout).
 
     ``prefer``: None = auto — the kernel on a real TPU whenever the page
     size is a lane multiple (the gather oracle materializes the whole
@@ -1142,11 +1239,11 @@ def paged_attention(
     must agree (``decode_attention.check_head_parity``) — and the
     books say what was derived (``kernel_dispatch_stats()
     ["paged_decode"]``: ``heads_per_step``, ``split``)."""
-    check_head_parity(q.shape[1], pool_values(k_pool).shape[1])
+    check_head_parity(q.shape[1], pool_values(pool).shape[1])
     if resolve_prefer(
-        "paged_decode", prefer, kernel_unsupported(q, k_pool), on_tpu()
+        "paged_decode", prefer, kernel_unsupported(q, pool), on_tpu()
     ):
-        kv, vv, ks, vs = _split_pools(k_pool, v_pool)
+        kv, ks, vs = _pool_planes(pool)
         heads = q.shape[1]
         if head_shard is not None:
             mesh, axis = head_shard
@@ -1162,12 +1259,12 @@ def paged_attention(
         return _head_sharded(
             functools.partial(_paged_impl, heads=heads, split=split),
             head_shard,
-            (q, kv, vv, ks, vs),
+            (q, kv, ks, vs),
             (jnp.asarray(page_table, jnp.int32),
              jnp.asarray(index, jnp.int32),
              None if valid_from is None
              else jnp.asarray(valid_from, jnp.int32)),
         )
     return paged_attention_reference(
-        q, k_pool, v_pool, page_table, index, valid_from
+        q, pool, page_table, index, valid_from
     )

@@ -79,6 +79,7 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from adapt_tpu.models.transformer_lm import TransformerLM, validate_tp
+from adapt_tpu.ops.paged_attention import fuse_kv
 from adapt_tpu.parallel.sharding import lm_tp_rules, replicate, tree_shardings
 from adapt_tpu.utils.logging import get_logger
 from adapt_tpu.utils.profiling import aggregate_size_fn, global_compile_sentinel
@@ -320,10 +321,12 @@ class SPPrefiller:
                     method="prefill_sp",
                 )
                 h = lax.with_sharding_constraint(h, h_sh)
+                # The pool's rows (K|V fused on the last axis, which no
+                # mesh axis shards) — what the landing path scatters.
                 outs.append(
                     jax.tree.map(
                         lambda t: lax.with_sharding_constraint(t, kv_sh),
-                        (ck, cv),
+                        fuse_kv(ck, cv),
                     )
                 )
             return outs
@@ -341,10 +344,10 @@ class SPPrefiller:
 
     def prefill(self, prompt) -> tuple[int, list]:
         """Run the sp-sharded prefill of ``prompt``'s full pages.
-        Returns ``(n_pages, blocks)`` — one page-major ``(K, V)`` pair
-        per decoder block, each member an ``(n_pages, kv_h, page, w)``
-        host array (or a ``(values, scales)`` tuple of them for
-        quantized pools): exactly the payload
+        Returns ``(n_pages, blocks)`` — per decoder block, the pool's
+        own planes page-major: an ``(n_pages, kv_h, page, 2 * w)`` host
+        array of fused K|V rows (or a ``(values, k_scales, v_scales)``
+        tuple for quantized pools): exactly the payload
         ``ContinuousBatcher.adopt_prefill_pages`` /
         :class:`runtime.disagg.KVHandoff` expect (the page contract:
         module docstring)."""
@@ -378,7 +381,7 @@ class SPPrefiller:
             a = a.reshape(kvh, nb, Pg, a.shape[-1])
             return np.ascontiguousarray(np.swapaxes(a, 0, 1)[:m])
 
-        blocks = [jax.tree.map(page_major, pair) for pair in outs]
+        blocks = jax.tree.map(page_major, outs)
         self.prefill_tokens += m * Pg
         self.prefills += 1
         return m, blocks

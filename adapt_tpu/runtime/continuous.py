@@ -44,11 +44,11 @@ vector, the same scheme as ``generate``): ~2-4x the resident context
 per page and proportionally less per-step cache traffic. Quantization
 is a property of the POOL's format (``runtime/paged.alloc_kv_pools``),
 not a mode of one path — it composes with every decode family: a pool
-becomes an ``(int8 values, f32 scales)`` pytree pair (the scale plane
-is one f32 per vector, page-addressed by the same table, so
-prefix-shared pages carry their scales), speculative verify quantizes
-its multi-token appends through the same scheme, and under tensor
-parallelism both members head-shard together. Greedy quantized streams
+becomes an ``(int8 values, f32 K scales, f32 V scales)`` pytree triple
+(a scale plane is one f32 per vector, page-addressed by the same
+table, so prefix-shared pages carry their scales), speculative verify
+quantizes its multi-token appends through the same scheme, and under
+tensor parallelism every plane head-shards together. Greedy quantized streams
 are bit-identical to the same-quantized solo
 ``generate(kv_cache_dtype="int8")`` on the whole-prompt prefill paths;
 prefix-cache suffix passes and chunked prefill attend the
@@ -59,8 +59,10 @@ widths, one quantization step coarser (tested via top-1-agreement
 bounds vs fp32 rather than exact equality).
 
 **The KV cache is a shared page POOL** (``runtime/paged``: the pool's
-format and the allocator; ``ops/paged_attention``: how a plane is
-appended to and read, and the scalar-prefetch kernels). Each request
+format — ONE plane a decoder block, a position's K and V side by side
+on the lanes of one row — and the allocator; ``ops/paged_attention``:
+how a plane is appended to and read, and the scalar-prefetch kernels).
+Each request
 reserves just the pages its window needs and frees them on retirement,
 so HBM scales with resident tokens instead of ``slots x max_len`` —
 size it with ``pool_pages`` (default: worst case, i.e. no saving until
@@ -295,7 +297,12 @@ from adapt_tpu.models.transformer_lm import (
     validate_tp,
 )
 from adapt_tpu.ops.decode_attention import check_head_parity
-from adapt_tpu.ops.paged_attention import append_kv_paged
+from adapt_tpu.ops.paged_attention import (
+    append_kv_paged,
+    fuse_kv,
+    pool_planes,
+    pool_values,
+)
 from adapt_tpu.ops.quantize import dequantize_params, quantize_params
 from adapt_tpu.parallel.sharding import (
     fetch_head_shards,
@@ -676,10 +683,10 @@ class ContinuousBatcher:
                 #: _dstate) — admission/commit logic is sharding-blind.
                 self._repl = NamedSharding(mesh, P())
                 #: KV pools shard on the HEAD axis (dim 1 of the
-                #: (pages, kvh, P, hd) pools — and of the int8 scale
-                #: planes: both members of a quantized (values, scales)
-                #: pair pin to the SAME spec, parallel.sharding's one
-                #: definition).
+                #: (pages, kvh, P, 2 * hd) planes — and of the scale
+                #: planes: every member of a quantized (values,
+                #: k_scales, v_scales) triple pins to the SAME spec,
+                #: parallel.sharding's one definition).
                 self._kv_sharding = kv_head_sharding(mesh, axis)
                 variables = jax.device_put(
                     variables,
@@ -771,10 +778,10 @@ class ContinuousBatcher:
         #: two nibbles packed per int8 lane) more resident context per
         #: page and correspondingly less per-step cache traffic vs
         #: native. Composes with every mode: a pool becomes a (values,
-        #: scales) pytree pair, speculative verify quantizes its
-        #: multi-token appends, and under TP both members head-shard
-        #: together — quantization is a property of the pool's format,
-        #: not a special mode of one path.
+        #: k_scales, v_scales) pytree triple, speculative verify
+        #: quantizes its multi-token appends, and under TP every plane
+        #: head-shards together — quantization is a property of the
+        #: pool's format, not a special mode of one path.
         self._kv_dtype = kv_cache_dtype
         self._kv_quant = kv_cache_dtype != "native"
         if prefill_chunk is not None and (
@@ -1486,7 +1493,7 @@ class ContinuousBatcher:
         ``temp == 0`` (submit's normalization). Static ``truncate`` /
         ``nucleus`` elide the top-k/top-p sorts when no active request
         needs them (at most 2x2 compiled variants). ``table`` addresses
-        each block's (k_pool, v_pool) through the shared page table.
+        each block's pool through the shared page table.
         Inactive rows re-park at the idle sentinel after the chunk's
         optimistic pos advance; rows whose request retires mid-chunk are
         cleared host-side (``_clear_slot``) before the next tick.
@@ -1520,17 +1527,17 @@ class ContinuousBatcher:
                 method="embed_positions",
             )
             new_caches = []
-            for name, block, (kp, vp) in zip(
+            for name, block, pool in zip(
                 self.lm.block_names, self._blocks, caches
             ):
-                x, kp, vp = block.apply(
-                    variables[name], x, kp, vp, table, pos, None,
+                x, pool = block.apply(
+                    variables[name], x, pool, table, pos, None,
                     self._kernel.attn_impl,
                     self._kernel.decode_split,
                     self._head_shard(),
                     method="decode_step_paged",
                 )
-                new_caches.append((kp, vp))
+                new_caches.append(pool)
             logits = self._head.apply(variables["head"], x)[:, 0]  # (B, V)
             pick_greedy = jnp.argmax(logits, axis=-1)
             lg = logits / jnp.maximum(temps, 1e-6)[:, None]
@@ -1649,17 +1656,17 @@ class ContinuousBatcher:
             variables["embed"], chunk, pos_ids, method="embed_positions"
         )
         new_caches = []
-        for name, block, (kp, vp) in zip(
+        for name, block, pool in zip(
             self.lm.block_names, self._blocks, caches
         ):
-            x, kp, vp = block.apply(
-                variables[name], x, kp, vp, table, pos,
+            x, pool = block.apply(
+                variables[name], x, pool, table, pos,
                 self._kernel.attn_impl, w,
                 self._kernel.decode_split,
                 self._head_shard(),
                 method="verify_chunk_paged",
             )
-            new_caches.append((kp, vp))
+            new_caches.append(pool)
         logits = self._head.apply(variables["head"], x)  # (B, kc, V)
         preds = jnp.argmax(logits, axis=-1).astype(tok.dtype)
         lps = chosen_logprob(
@@ -1794,9 +1801,7 @@ class ContinuousBatcher:
                     off_dst[:, None],
                 )
 
-            new_caches = [
-                jax.tree.map(fix, pair) for pair in new_caches
-            ]
+            new_caches = jax.tree.map(fix, new_caches)
             acc = acc + hit.astype(acc.dtype)
         ncommit = acc + 1
         last = jnp.take_along_axis(out_preds, acc[:, None], axis=1)[:, 0]
@@ -1827,7 +1832,7 @@ class ContinuousBatcher:
         disaggregated-handoff landing program (``runtime/disagg`` ->
         :meth:`adopt_prefill_pages`). ``pages`` (nb,) physical page
         ids (power-of-two padded; pad entries point at the trash
-        page), ``kvs`` mirrors ``caches``' per-block (K, V) structure
+        page), ``kvs`` mirrors ``caches``' per-block structure
         with leaves ``(nb, kvh, page, w)`` already PLACED to the
         pool's sharding by the ``KVHandoffPlan`` — so under a
         head-sharded mesh this scatter is fully shard-local (each
@@ -1836,14 +1841,11 @@ class ContinuousBatcher:
         per page-count bucket (log2 variants)."""
         caches = self._shard_kv(caches)
         kvs = self._shard_kv(kvs)
-        out = [
-            jax.tree.map(
-                lambda pool, kv: pool.at[pages].set(kv.astype(pool.dtype)),
-                c_pair,
-                n_pair,
-            )
-            for c_pair, n_pair in zip(caches, kvs)
-        ]
+        out = jax.tree.map(
+            lambda pool, kv: pool.at[pages].set(kv.astype(pool.dtype)),
+            caches,
+            kvs,
+        )
         return self._shard_kv(out)
 
     @partial(
@@ -1854,8 +1856,9 @@ class ContinuousBatcher:
     )
     def _fork_page(self, caches, srcdst, *, epoch=0):
         """Copy-on-write fork: duplicate ONE physical page — every
-        block, both members of a quantized ``(values, scales)`` pair,
-        so the copy's scales travel with its int8 values — from
+        block, every plane of a quantized ``(values, k_scales,
+        v_scales)`` pool, so the copy's scales travel with its int8
+        values — from
         ``srcdst[0]`` into ``srcdst[1]``. The destination is a fan-out
         sibling's freshly allocated private copy of its group's last
         shared prompt page, taken at admission because the sibling's
@@ -1867,23 +1870,21 @@ class ContinuousBatcher:
         static shape axis."""
         caches = self._shard_kv(caches)
         src, dst = srcdst[0], srcdst[1]
-        out = [
-            jax.tree.map(
-                lambda pool: pool.at[dst].set(pool[src]), c_pair
-            )
-            for c_pair in caches
-        ]
+        out = jax.tree.map(
+            lambda pool: pool.at[dst].set(pool[src]), caches
+        )
         return self._shard_kv(out)
 
     def adopt_prefill_pages(self, prompt, blocks, page_size: int,
                             quantized) -> int:
         """Land a disaggregated prefill's KV pages in this batcher's
         pool THROUGH THE PREFIX CACHE — the decode-side half of the
-        ``runtime/disagg`` handoff. ``blocks`` is one ``(K, V)`` pair
-        per decoder block, each member a page-major ``(n, kvh, page,
-        hd)`` host array (or a ``(values, scales)`` tuple of them for
-        int8 pools), holding the K/V of ``prompt``'s first ``n`` FULL
-        pages exactly as this batcher's own chunked prefill would have
+        ``runtime/disagg`` handoff. ``blocks`` is one entry per decoder
+        block in the pool's own format (``runtime/paged.
+        alloc_kv_pools``): a page-major ``(n, kvh, page, 2 * hd)`` host
+        array of fused K|V rows (or a ``(values, k_scales, v_scales)``
+        tuple of them for quantized pools), holding the K/V of
+        ``prompt``'s first ``n`` FULL pages exactly as this batcher's own chunked prefill would have
         written them.
 
         Pages register under the same content keys the admission
@@ -1934,9 +1935,7 @@ class ContinuousBatcher:
                 f"{len(self._blocks)}"
             )
         prompt = np.asarray(prompt, np.int32).reshape(-1)
-        k0 = blocks[0][0]
-        leaf0 = k0[0] if isinstance(k0, tuple) else k0
-        n = int(leaf0.shape[0])
+        n = int(np.shape(pool_values(blocks[0]))[0])
         if n < 1 or n > (prompt.shape[0] - 1) // self._page:
             raise ValueError(
                 f"handoff covers {n} pages; prompt of "
@@ -1947,33 +1946,32 @@ class ContinuousBatcher:
         # adopt_cached registers prefix keys, and raising after it
         # would leave content keys pointing at never-written pages —
         # the next same-prefix admission would prefix-hit garbage.
-        for b, (pools, pair) in enumerate(zip(self._caches, blocks)):
-            for mname, pool, member in zip(("K", "V"), pools, pair):
-                if isinstance(member, tuple) != self._kv_quant:
+        for b, (pool, block) in enumerate(zip(self._caches, blocks)):
+            if isinstance(block, tuple) != self._kv_quant:
+                raise ValueError(
+                    f"handoff block {b}: "
+                    f"{'tuple' if isinstance(block, tuple) else 'array'}"
+                    f" in a "
+                    f"{'quantized' if self._kv_quant else 'native'}"
+                    " pool"
+                )
+            leaves, planes = pool_planes(block), pool_planes(pool)
+            if len(leaves) != len(planes):
+                raise ValueError(
+                    f"handoff block {b}: {len(leaves)} planes, the "
+                    f"pool has {len(planes)}"
+                )
+            for li, (plane, leaf) in enumerate(zip(planes, leaves)):
+                # A handed page is one page of the pool's own plane
+                # (runtime/paged.alloc_kv_pools: the fused K|V row,
+                # packed lanes for int4; one f32 per vector in a scale
+                # plane).
+                want = (n,) + tuple(plane.shape[1:])
+                if tuple(np.shape(leaf)) != want:
                     raise ValueError(
-                        f"handoff block {b} {mname}: "
-                        f"{'tuple' if isinstance(member, tuple) else 'array'}"
-                        f" member in a "
-                        f"{'quantized' if self._kv_quant else 'native'}"
-                        " pool"
+                        f"handoff block {b}[{li}] shape "
+                        f"{tuple(np.shape(leaf))} != expected {want}"
                     )
-                leaves = member if isinstance(member, tuple) else (member,)
-                planes = pool if isinstance(pool, tuple) else (pool,)
-                if len(leaves) != len(planes):
-                    raise ValueError(
-                        f"handoff block {b} {mname}: {len(leaves)} "
-                        f"planes, the pool has {len(planes)}"
-                    )
-                for li, (plane, leaf) in enumerate(zip(planes, leaves)):
-                    # A handed page is one page of the pool's own plane
-                    # (runtime/paged.alloc_kv_pools: packed value lanes
-                    # for int4, one f32 per vector in a scale plane).
-                    want = (n,) + tuple(plane.shape[1:])
-                    if tuple(np.shape(leaf)) != want:
-                        raise ValueError(
-                            f"handoff block {b} {mname}[{li}] shape "
-                            f"{tuple(np.shape(leaf))} != expected {want}"
-                        )
         keys = [
             Pager.prefix_key(prompt, (j + 1) * self._page)
             for j in range(n)
@@ -2107,10 +2105,9 @@ class ContinuousBatcher:
         reads the page's last-written bytes even when the allocator
         is about to hand the page to a new owner."""
         idx = int(page)
-        return [
-            jax.tree.map(lambda pool: fetch_head_shards(pool, idx), pair)
-            for pair in self._caches
-        ]
+        return jax.tree.map(
+            lambda pool: fetch_head_shards(pool, idx), self._caches
+        )
 
     def _spill_page(self, page: int, key: bytes) -> bool:
         """Capture one rc=0 page into the host tier (budget already
@@ -2289,19 +2286,17 @@ class ContinuousBatcher:
 
     def _insert_paged(self, caches, pages, kvs):
         """Scatter a prefilled request's per-block K/V into its pages
-        (``runtime/paged.insert_prefill_pages`` per pool). tree.map
-        reaches the (values, scales) members of quantized pools and the
-        plain arrays of native ones alike — the scale plane scatters by
-        the same page list, so the pages' scales always travel with
-        their int8 values (prefix sharing included)."""
-        return [
-            jax.tree.map(
-                lambda pool, kv: insert_prefill_pages(pool, pages, kv),
-                c_pair,
-                n_pair,
-            )
-            for c_pair, n_pair in zip(caches, kvs)
-        ]
+        (``runtime/paged.insert_prefill_pages`` per plane). tree.map
+        reaches the (values, k_scales, v_scales) planes of quantized
+        pools and the one fused plane of native ones alike — a scale
+        plane scatters by the same page list, so the pages' scales
+        always travel with their int8 values (prefix sharing
+        included)."""
+        return jax.tree.map(
+            lambda pool, kv: insert_prefill_pages(pool, pages, kv),
+            caches,
+            kvs,
+        )
 
     def _first_pick(self, h_last, variables, keys, temp, top_k, top_p,
                     greedy, truncate, nucleus):
@@ -2343,7 +2338,7 @@ class ContinuousBatcher:
                     self._kv_dtype if self._kv_quant else False,
                     method="prefill",
                 )
-                kvs.append((ck, cv))
+                kvs.append(fuse_kv(ck, cv))  # the pool's rows
             h_last = lax.dynamic_index_in_dim(h, ints[0] - 1, 1)
             first, first_lp = self._first_pick(
                 h_last, variables, keys, floats[0], ints[1], floats[1],
@@ -2390,15 +2385,15 @@ class ContinuousBatcher:
                 variables["embed"], ids, pos_ids, method="embed_positions"
             )
             new_caches = []
-            for name, block, (kp, vp) in zip(
+            for name, block, pool in zip(
                 self.lm.block_names, self._blocks, caches
             ):
-                h, kp, vp = block.apply(
-                    variables[name], h, kp, vp, pages, pos0,
+                h, pool = block.apply(
+                    variables[name], h, pool, pages, pos0,
                     head_shard=self._head_shard(),
                     method="prefill_chunk_paged",
                 )
-                new_caches.append((kp, vp))
+                new_caches.append(pool)
             new_caches = self._shard_kv(new_caches)
             if not sample:  # mid-prefill pass: no token yet
                 return (jnp.zeros((1,), jnp.int32),
@@ -3124,7 +3119,7 @@ class ContinuousBatcher:
            directly; a real deployment re-streams from checkpoint);
         3. **migrate live state** via an explicit
            ``parallel.sharding.KVReshardPlan``: head-sharded KV
-           (pools, both members of a (values, scales) pair) moves
+           (pools, every plane of a quantized triple) moves
            per-shard — device-to-device where the shard survives,
            host-staged for the lost shard's heads — and replicated
            state (sampling ``_dstate``, draft weights/caches) re-places
@@ -3224,7 +3219,7 @@ class ContinuousBatcher:
         # Live-state migration: KV on the head axis per the plan;
         # replicated members from a surviving replica.
         self._caches = plan.migrate_tree(self._caches, kv_sh)
-        for name, block, (ck, _) in zip(
+        for name, block, pool in zip(
             self.lm.block_names, self._blocks, self._caches
         ):
             # The partial-TP-migration check, by name, on per-SHARD
@@ -3232,7 +3227,7 @@ class ContinuousBatcher:
             # leaf.shape[1] can never disagree — what a plan bug
             # produces is a shard holding the wrong head span. Each of
             # the new_tp shards must carry exactly heads/new_tp rows.
-            leaf = ck[0] if isinstance(ck, tuple) else ck
+            leaf = pool_values(pool)
             shard_heads = leaf.addressable_shards[0].data.shape[1]
             check_head_parity(block.cache_heads, shard_heads * new_tp)
         self._dstate = plan.migrate_replicated(self._dstate, repl)

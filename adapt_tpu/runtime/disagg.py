@@ -95,6 +95,7 @@ from adapt_tpu.models.transformer_lm import TransformerLM
 from adapt_tpu.parallel.sp_prefill import SPPrefiller, build_sp_mesh
 from adapt_tpu.runtime.capacity import prefill_tier_book
 from adapt_tpu.runtime.continuous import ContinuousBatcher
+from adapt_tpu.ops.paged_attention import pool_planes
 from adapt_tpu.runtime.paged import Pager, alloc_kv_pools, pool_geometry
 from adapt_tpu.runtime.scheduler import QueueFullError
 from adapt_tpu.utils.logging import get_logger
@@ -130,18 +131,20 @@ def _worker_family_size(w: "PrefillWorker") -> int:
 @dataclasses.dataclass
 class KVHandoff:
     """One prefilled request's streamable state: the prompt, the page
-    geometry, and per-block page-major K/V chunks covering the
-    prompt's first ``n_pages`` FULL pages (``(n_pages, kv_heads,
-    page_size, head_dim)`` per member; quantized pools carry
-    ``(values, scales)`` tuples — the scale plane is part of the
-    page)."""
+    geometry, and per-block page-major chunks of the pool's own planes
+    (``runtime/paged.alloc_kv_pools``) covering the prompt's first
+    ``n_pages`` FULL pages: ``(n_pages, kv_heads, page_size, 2 *
+    head_dim)`` fused K|V rows; quantized pools carry ``(values,
+    k_scales, v_scales)`` tuples — the scale planes are part of the
+    page."""
 
     req_id: int
     prompt: np.ndarray
     page_size: int
     n_pages: int
     quantized: bool
-    #: One ``(K, V)`` pair per decoder block.
+    #: One entry per decoder block: the fused plane's pages, or the
+    #: quantized ``(values, k_scales, v_scales)`` tuple of them.
     blocks: list
     #: KV dtype on the wire: "native", "int8" or "int4" (int4 members
     #: carry PACKED ``head_dim // 2`` value lanes — the width is part
@@ -156,15 +159,11 @@ class KVHandoff:
 
 def _leaves(handoff: KVHandoff) -> list[np.ndarray]:
     """The handoff's tensors in WIRE ORDER: prompt first, then each
-    block's K members then V members (quantized pairs flatten to
-    values, scales)."""
+    block's planes (quantized blocks flatten to values, k_scales,
+    v_scales)."""
     out: list[np.ndarray] = [np.ascontiguousarray(handoff.prompt, np.int32)]
-    for k, v in handoff.blocks:
-        for member in (k, v):
-            if isinstance(member, tuple):
-                out.extend(member)
-            else:
-                out.append(member)
+    for block in handoff.blocks:
+        out.extend(pool_planes(block))
     return out
 
 
@@ -345,7 +344,7 @@ def unpack_handoff(msg: Message) -> KVHandoff:
                 off += ln
         else:
             arrs = codec.unpack_many(msg.payload, meta["frame_lens"])
-        per_block = 4 if quantized else 2
+        per_block = 3 if quantized else 1
         ranges = meta.get("head_ranges")
         if ranges:
             # Sender-side-resharded wire: each KV tensor arrived as
@@ -388,11 +387,9 @@ def unpack_handoff(msg: Message) -> KVHandoff:
         it = iter(arrs[1:])
         for _ in range(n_blocks):
             if quantized:
-                blocks.append(
-                    ((next(it), next(it)), (next(it), next(it)))
-                )
+                blocks.append((next(it), next(it), next(it)))
             else:
-                blocks.append((next(it), next(it)))
+                blocks.append(next(it))
         return KVHandoff(
             req_id=int(meta["req_id"]),
             prompt=prompt,
@@ -580,14 +577,14 @@ class PrefillWorker:
                 method="embed_positions",
             )
             new_pools = []
-            for name, block, (kp, vp) in zip(
+            for name, block, pool in zip(
                 self.lm.block_names, self._blocks, pools
             ):
-                h, kp, vp = block.apply(
-                    variables[name], h, kp, vp, pages, pos0,
+                h, pool = block.apply(
+                    variables[name], h, pool, pages, pos0,
                     method="prefill_chunk_paged",
                 )
-                new_pools.append((kp, vp))
+                new_pools.append(pool)
             return new_pools
 
         self._fn_cache[key] = chunkfn
@@ -602,10 +599,7 @@ class PrefillWorker:
 
         @jax.jit
         def gather(pools, pages):
-            return [
-                jax.tree.map(lambda pool: pool[pages], pair)
-                for pair in pools
-            ]
+            return jax.tree.map(lambda pool: pool[pages], pools)
 
         self._fn_cache[key] = gather
         return gather
@@ -779,10 +773,7 @@ class PrefillWorker:
         pages = np.asarray(owned + [0] * (nb - m), np.int32)
         gathered = self._gather_fn(nb)(self._pools, jnp.asarray(pages))
         host = jax.device_get(gathered)  # ONE fused fetch
-        blocks = [
-            jax.tree.map(lambda x: np.asarray(x)[:m], pair)
-            for pair in host
-        ]
+        blocks = jax.tree.map(lambda x: np.asarray(x)[:m], host)
         self._pager.free_slot(job.slot)
         self._slots[job.slot] = None
         self.handoffs += 1

@@ -5,8 +5,9 @@ to and read, and the scalar-prefetch kernels); this module owns the
 ALLOCATOR — a free list of physical pages, per-slot page ownership, and
 the (slots, pages_per_slot) page table the compiled step consumes — and
 the pool's FORMAT: :func:`alloc_kv_pools` is the one place a block's
-K/V planes are shaped (native array, or quantized ``(values, scales)``
-pair), :func:`pool_geometry` the one place the table width and the
+pool is shaped (ONE fused K|V plane, or a quantized ``(values,
+k_scales, v_scales)`` triple), :func:`pool_geometry` the one place the
+table width and the
 default pool size are computed. The batcher, the disaggregated prefill
 worker and the sp prefiller all call them. The bookkeeping is plain
 numpy/python on the serving control path — page churn is a few integers
@@ -54,14 +55,14 @@ page — the table, the free list, refcounts and prefix keys are logical
 bookkeeping, identical on every shard, so the allocator never changes
 with the mesh (``table()`` is uploaded replicated).
 
-Quantization is equally invisible: an int8 batcher keeps TWO pools per
-K/V (``(int8 values, f32 scales)`` — ``ops/paged_attention``'s
-quantized layout) addressed by ONE page id space, so every allocator
-decision (alloc/free/recycle/prefix-share) applies to a page's values
-and its scale plane atomically — a prefix-shared page always carries
-the scales its int8 payload was written with. ``insert_prefill_pages``
-scatters either member (``kv`` trailing dim is head_dim for values, 1
-for scale planes).
+Quantization is equally invisible: an int8 batcher keeps THREE planes
+per block (``(int8 values, k_scales, v_scales)`` —
+``ops/paged_attention``'s quantized layout) addressed by ONE page id
+space, so every allocator decision (alloc/free/recycle/prefix-share)
+applies to a page's values and its scale planes atomically — a
+prefix-shared page always carries the scales its int8 payload was
+written with. ``insert_prefill_pages`` scatters any plane (``kv``
+trailing dim is the fused row for values, 1 for scale planes).
 
 No reference analog (SURVEY.md §2.2) — serving-memory frontier.
 """
@@ -509,9 +510,9 @@ class HostTierStats:
 
 @dataclasses.dataclass
 class _HostPage:
-    """One spilled page: per-block (K, V) members, each member a tuple
-    of ``(payload, meta)`` encoded leaves (one leaf for native pools,
-    ``(values, scales)`` for quantized ones)."""
+    """One spilled page: per block, a tuple of ``(payload, meta)``
+    encoded planes in the pool's own order (one fused plane for native
+    pools, ``(values, k_scales, v_scales)`` for quantized ones)."""
 
     blocks: list
     nbytes: int  # encoded bytes (payload sum)
@@ -564,20 +565,16 @@ class HostKVTier:
         from adapt_tpu.ops.quantize import encode_page
 
         enc, nbytes, raw = [], 0, 0
-        for k, v in blocks:
-            pair = []
-            for member in (k, v):
-                leaves = (
-                    member if isinstance(member, tuple) else (member,)
-                )
-                out = []
-                for leaf in leaves:
-                    payload, meta = encode_page(np.asarray(leaf), codec)
-                    nbytes += len(payload)
-                    raw += meta["raw_nbytes"]
-                    out.append((payload, meta))
-                pair.append(tuple(out))
-            enc.append(tuple(pair))
+        from adapt_tpu.ops.paged_attention import pool_planes
+
+        for block in blocks:
+            out = []
+            for plane in pool_planes(block):
+                payload, meta = encode_page(np.asarray(plane), codec)
+                nbytes += len(payload)
+                raw += meta["raw_nbytes"]
+                out.append((payload, meta))
+            enc.append(tuple(out))
         return _HostPage(blocks=enc, nbytes=nbytes, raw_nbytes=raw)
 
     @staticmethod
@@ -585,14 +582,9 @@ class HostKVTier:
         from adapt_tpu.ops.quantize import decode_page
 
         blocks = []
-        for km, vm in entry.blocks:
-            pair = []
-            for member in (km, vm):
-                leaves = [decode_page(p, m) for p, m in member]
-                pair.append(
-                    leaves[0] if len(leaves) == 1 else tuple(leaves)
-                )
-            blocks.append(tuple(pair))
+        for enc in entry.blocks:
+            planes = [decode_page(p, m) for p, m in enc]
+            blocks.append(planes[0] if len(planes) == 1 else tuple(planes))
         return blocks
 
     def _book(self, entry: _HostPage, sign: int) -> None:
@@ -606,8 +598,8 @@ class HostKVTier:
         )
 
     def put(self, key: bytes, blocks) -> tuple[int, int]:
-        """Spill one page (per-block ``(K, V)`` host leaves, pool
-        shapes ``(kvh, page, w)``) into the WARM sub-tier under its
+        """Spill one page (per block, the pool's host planes, shapes
+        ``(kvh, page, w)``) into the WARM sub-tier under its
         content key. Idempotent for resident keys (MRU touch only).
         Returns ``(raw_bytes, encoded_bytes)`` for the caller's
         accounting."""
@@ -667,8 +659,9 @@ class HostKVTier:
         self._disk[key] = (path, None)
 
     def get(self, key: bytes):
-        """Decoded per-block ``(K, V)`` host arrays for ``key``, or
-        None. MRU-touches the entry (it stays host-resident after a
+        """Decoded per-block host planes for ``key`` (the pool's own
+        structure, as :meth:`put` took them), or None. MRU-touches the
+        entry (it stays host-resident after a
         readmit: the HBM copy is rc=0 evictable and may bounce right
         back)."""
         entry = self._warm.get(key)
@@ -714,9 +707,10 @@ class HostKVTier:
 
 
 def kv_value_width(head_dim: int, kv_cache_dtype: str) -> int:
-    """Lane width of a pool's VALUE plane: ``head_dim``, halved for
-    int4 (two nibbles packed per int8 lane — which needs an even
-    ``head_dim``)."""
+    """Lanes ONE cached vector takes in a pool's value plane:
+    ``head_dim``, halved for int4 (two nibbles packed per int8 lane —
+    which needs an even ``head_dim``). A row of the plane is two of
+    them, K then V."""
     if kv_cache_dtype != "int4":
         return head_dim
     if head_dim % 2:
@@ -735,29 +729,32 @@ def alloc_kv_pools(
     dtype,
     kv_cache_dtype: str = "native",
 ):
-    """One decoder block's zeroed ``(K, V)`` page pools — THE definition
-    of what a pool is. Native: one ``(pool_pages, kv_heads, page_size,
-    head_dim)`` array of the block's ``dtype`` per member. Quantized
-    (``"int8"`` / ``"int4"``): a ``(values, scales)`` pair per member —
-    int8 values at :func:`kv_value_width` lanes plus one float32 scale
-    per cached vector, ``(pool_pages, kv_heads, page_size, 1)``,
+    """One decoder block's zeroed page pool — THE definition of what a
+    pool is. A position's K and V live side by side on the lanes of ONE
+    row: lanes ``[0, w)`` hold K, lanes ``[w, 2w)`` V, ``w`` =
+    :func:`kv_value_width`. Native: one ``(pool_pages, kv_heads,
+    page_size, 2 * head_dim)`` plane of the block's ``dtype`` — at
+    head_dim 64 a row is exactly one 128-lane tile, so the plane lives
+    row-major on a TPU, the per-token write is one in-place scatter and
+    the kernels read it as it lives (``ops/paged_attention``:
+    ``append_kv_paged``, ``_attend_fused``). Quantized (``"int8"`` /
+    ``"int4"``): a ``(values, k_scales, v_scales)`` triple — the int8
+    value plane fused the same way, plus one float32 scale per cached
+    vector, ``(pool_pages, kv_heads, page_size, 1)`` for K and for V,
     page-addressed by the SAME table, so a shared page always carries
-    the scales its values were written with. How a plane is appended
-    to and read is ``ops/paged_attention``'s (``append_kv_paged``,
-    ``kv_transposed``)."""
+    the scales its values were written with. Every consumer reads the
+    format off the operand (tuple or not, the last dimension); the
+    KV-head axis is dim 1 of every plane, which is what tensor
+    parallelism shards."""
     width = kv_value_width(head_dim, kv_cache_dtype)
-
-    def one_pool():
-        if kv_cache_dtype == "native":
-            return jnp.zeros(
-                (pool_pages, kv_heads, page_size, head_dim), dtype
-            )
-        return (
-            jnp.zeros((pool_pages, kv_heads, page_size, width), jnp.int8),
-            jnp.zeros((pool_pages, kv_heads, page_size, 1), jnp.float32),
-        )
-
-    return (one_pool(), one_pool())
+    plane = (pool_pages, kv_heads, page_size)
+    if kv_cache_dtype == "native":
+        return jnp.zeros(plane + (2 * head_dim,), dtype)
+    return (
+        jnp.zeros(plane + (2 * width,), jnp.int8),
+        jnp.zeros(plane + (1,), jnp.float32),
+        jnp.zeros(plane + (1,), jnp.float32),
+    )
 
 
 def pool_geometry(
@@ -775,8 +772,10 @@ def pool_geometry(
 
 @partial(jax.jit, donate_argnums=(0,))
 def insert_prefill_pages(pool, pages, kv):
-    """Scatter a prefilled request's contiguous (1, kv_h, S, hd) K or V
-    into its physical ``pages`` ((n,) int32, logical order). S pads up
+    """Scatter a prefilled request's contiguous (1, kv_h, S, w) rows —
+    one plane of the pool's representation (``fuse_kv``): the fused K|V
+    rows, or a scale column — into its physical ``pages`` ((n,) int32,
+    logical order) of that plane. S pads up
     to n*page positions — pad columns hold zeros that sit beyond the
     prompt (masked until decode overwrites them). One scatter on the
     page axis; jit specializes per (n, S), both bucket-bounded."""

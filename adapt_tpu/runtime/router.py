@@ -90,6 +90,7 @@ import numpy as np
 from adapt_tpu.comm.framing import frame_parts
 from adapt_tpu.config import DisaggConfig, RouterConfig, SLOSpec
 from adapt_tpu.control.registry import weak_watch
+from adapt_tpu.ops.paged_attention import pool_values
 from adapt_tpu.parallel.sharding import head_tiles
 from adapt_tpu.runtime.capacity import (
     affinity_score,
@@ -720,10 +721,7 @@ class FleetRouter:
         tp = int(dict(mesh.shape).get("tp", 1))
         if tp <= 1 or not handoff.blocks:
             return None
-        k0 = handoff.blocks[0][0]
-        kv_heads = int(
-            (k0[0] if isinstance(k0, tuple) else k0).shape[1]
-        )
+        kv_heads = int(pool_values(handoff.blocks[0]).shape[1])
         if kv_heads % tp:
             return None
         return head_tiles(kv_heads, tp)

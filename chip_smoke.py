@@ -61,6 +61,7 @@ from adapt_tpu.ops.decode_attention import (
 )
 from adapt_tpu.ops.dispatch import kernel_dispatch_stats
 from adapt_tpu.ops.paged_attention import (
+    fuse_kv,
     paged_attention,
     paged_attention_reference,
     paged_chunk_attention,
@@ -195,8 +196,10 @@ def kernel_cases(size, prefer):
     ):
         pps = max(lmc["max_len"] // page, 2)
         n = SLOTS * pps + 1
-        kp = _pool(jax.random.fold_in(key, 1), page, n, kvh, hd, kv_dtype)
-        vp = _pool(jax.random.fold_in(key, 2), page, n, kvh, hd, kv_dtype)
+        pool = fuse_kv(
+            _pool(jax.random.fold_in(key, 1), page, n, kvh, hd, kv_dtype),
+            _pool(jax.random.fold_in(key, 2), page, n, kvh, hd, kv_dtype),
+        )
         table = jnp.asarray(
             1 + rng.permutation(n - 1).reshape(SLOTS, pps), jnp.int32
         )
@@ -205,14 +208,14 @@ def kernel_cases(size, prefer):
             rng.randint(1, span - 8, size=SLOTS), jnp.int32
         ).at[0].set(span - 8)
         q = _normal(jax.random.fold_in(key, 3), (SLOTS, kvh, 1, hd))
-        routed = kernel_unsupported(q, kp)
+        routed = kernel_unsupported(q, pool)
         for split in (1, None):
             yield (
                 f"paged_decode {kv_dtype} split={split}",
                 lambda split=split: paged_attention(
-                    q, kp, vp, table, index, prefer=prefer, split=split
+                    q, pool, table, index, prefer=prefer, split=split
                 ),
-                highest(paged_attention_reference, q, kp, vp, table, index),
+                highest(paged_attention_reference, q, pool, table, index),
                 KERNEL_TOL,
                 routed,
             )
@@ -221,11 +224,11 @@ def kernel_cases(size, prefer):
             yield (
                 f"paged_verify {kv_dtype} tree={tree_tail}",
                 lambda tree_tail=tree_tail: paged_verify_attention(
-                    qv, kp, vp, table, index, 5, prefer=prefer,
+                    qv, pool, table, index, 5, prefer=prefer,
                     tree_tail=tree_tail,
                 ),
                 highest(
-                    paged_verify_attention_reference, qv, kp, vp, table,
+                    paged_verify_attention_reference, qv, pool, table,
                     index, 5, tree_tail=tree_tail,
                 ),
                 KERNEL_TOL,
@@ -237,10 +240,10 @@ def kernel_cases(size, prefer):
         yield (
             f"paged_chunk {kv_dtype} chunk={chunk}",
             lambda: paged_chunk_attention(
-                qc, kp, vp, pages, chunk, chunk, prefer=prefer
+                qc, pool, pages, chunk, chunk, prefer=prefer
             ),
             highest(
-                paged_chunk_attention_reference, qc, kp, vp, pages, chunk,
+                paged_chunk_attention_reference, qc, pool, pages, chunk,
                 chunk,
             ),
             KERNEL_TOL,

@@ -16,7 +16,9 @@ operations are listed by the name the benchmark's readers look for.
 
 ``--root`` imports ``adapt_tpu`` from another checkout (a ``git
 archive`` of the parent in an ignored directory), so both sides of an
-A/B are timed by the same code on the same chip. ``--heads`` times
+A/B are timed by the same code on the same chip: a checkout whose pool
+is one fused K|V plane a block (``fuse_kv``, PR 30 on) gets that, an
+older one its two planes. ``--heads`` times
 ``_paged_impl`` at heads-a-step other than the derived one (this
 tree's kernel only): it is how the derivation was checked, not an
 option of the program. ``--dead`` times every row dead: what the grid
@@ -73,24 +75,35 @@ def main() -> int:
     pa = importlib.import_module("adapt_tpu.ops.paged_attention")
     dec = importlib.import_module("adapt_tpu.ops.decode_attention")
     assert os.path.abspath(pa.__file__).startswith(root), pa.__file__
+    fused = hasattr(pa, "fuse_kv")
     dev = jax.devices()[0]
     kind = dev.device_kind
     print(json.dumps({
         "root": root, "device_kind": kind,
         "num_cores": getattr(dev, "num_cores", None),
         "derives_heads": hasattr(pa, "decode_heads_per_step"),
+        "fused_plane": fused,
     }))
     rng = np.random.RandomState(args.seed)
     for name in args.shapes.split(","):
         b, kvh, hd, pps, npages, live_slots, (lo, hi) = SHAPES[name]
         key = jax.random.PRNGKey(args.seed)
-        pools = [
+        # Four planes of the same bytes on both sides: a block's pool
+        # is planes (2i, 2i + 1) side by side on the lanes, or the two.
+        planes = [
             jax.random.normal(
                 jax.random.fold_in(key, i), (npages, kvh, PAGE, hd),
                 jnp.bfloat16,
             )
             for i in range(4)
         ]
+        if fused:
+            pools = [
+                (pa.fuse_kv(planes[i], planes[(i + 1) % 4]),)
+                for i in range(4)
+            ]
+        else:
+            pools = [(planes[i], planes[(i + 1) % 4]) for i in range(4)]
         q = jax.random.normal(
             jax.random.fold_in(key, 9), (b, kvh, 1, hd), jnp.bfloat16
         )
@@ -113,7 +126,7 @@ def main() -> int:
         if not args.dead:
             with jax.default_matmul_precision("highest"):
                 ref = np.asarray(pa.paged_attention_reference(
-                    q, pools[0], pools[1], table, index
+                    q, *pools[0], table, index
                 ).astype(jnp.float32))
 
         variants = [("auto" if s == "auto" else int(s), None)
@@ -128,14 +141,14 @@ def main() -> int:
             resolved = dec.resolve_decode_split(pps, s_val)
 
             if heads is None:
-                def call(kp, vp, q=q, s_val=s_val):
+                def call(pool, q=q, s_val=s_val):
                     return pa.paged_attention(
-                        q, kp, vp, table, index, prefer="pallas", split=s_val
+                        q, *pool, table, index, prefer="pallas", split=s_val
                     )
             else:
-                def call(kp, vp, q=q, heads=heads):
+                def call(pool, q=q, heads=heads):
                     return pa._paged_impl(
-                        q, kp, vp, None, None, table, index, None,
+                        q, *pool, None, None, table, index, None,
                         heads=heads, split=1,
                     )
 
@@ -146,8 +159,7 @@ def main() -> int:
                 acc = jnp.zeros(q.shape, jnp.float32)
                 for i in range(args.layers):
                     out = call(
-                        pools[i % 4], pools[(i + 1) % 4],
-                        q=q + (1e-3 * acc).astype(q.dtype),
+                        pools[i % 4], q=q + (1e-3 * acc).astype(q.dtype),
                     )
                     acc += out
                 return acc
@@ -157,7 +169,7 @@ def main() -> int:
             compile_s = time.perf_counter() - t0
             err = None
             if ref is not None:
-                got = np.asarray(call(pools[0], pools[1]).astype(jnp.float32))
+                got = np.asarray(call(pools[0]).astype(jnp.float32))
                 live = np.asarray(ctx) > 0
                 err = float(np.abs(got - ref)[live].max())
             t0 = time.perf_counter()
@@ -167,7 +179,9 @@ def main() -> int:
             per_call = (time.perf_counter() - t0) / args.iters / args.layers
             h = heads
             if h is None and hasattr(pa, "decode_heads_per_step"):
-                h = pa.decode_heads_per_step(kvh, PAGE, hd, 2, False, 8, hd)
+                h = pa.decode_heads_per_step(
+                    kvh, PAGE, 2 * hd if fused else hd, 2, False, 8, hd
+                )
             h = h or 1
             per_row = resolved * -(-pps // resolved)
             steps = b * (kvh // h) * per_row

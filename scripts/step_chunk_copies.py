@@ -1,4 +1,5 @@
-"""Count whole-pool copies in a benchmark cell's compiled decode program.
+"""Count whole-pool copies in a benchmark cell's compiled decode and
+chunk-prefill programs.
 
     chiprun -- python3 scripts/step_chunk_copies.py <cell>      # on the chip
     JAX_PLATFORMS=cpu python3 scripts/step_chunk_copies.py <cell> --describe
@@ -7,8 +8,10 @@ Builds the cell's ``ContinuousBatcher`` as ``chipbench/lm_engine.py``
 does (same model, slots and pool pages), lowers the batcher's own
 ``_step_chunk`` on ``ShapeDtypeStruct``s, compiles it, and counts the
 ``copy`` / ``copy-start`` operations of the pool's shape in
-``compiled.as_text()`` by result layout. On the chip it compiles for
-the attached device; ``--describe`` compiles for a described v5e from
+``compiled.as_text()`` by result layout; then does the same for every
+chunked-prefill pass the cell's longest prompt takes (the batcher's
+``_prefill_suffix_fn``; none where the traffic prefills whole
+prompts). On the chip it compiles for the attached device; ``--describe`` compiles for a described v5e from
 the CPU (nothing runs; weights and pools are still allocated on the
 host). PERF.md section 5 quotes these counts; ``tests/
 test_chip_lowering.py`` guards a two-block version of them in tier 1.
@@ -35,7 +38,11 @@ def main(argv=None) -> int:
         "--describe", action="store_true",
         help="compile for a described v5e:2x2 chip from the CPU",
     )
-    ap.add_argument("--out", help="also write the compiled text here")
+    ap.add_argument(
+        "--out",
+        help="also write the compiled texts here (decode; OUT.passN for"
+        " the chunk-prefill passes)",
+    )
     args = ap.parse_args(argv)
 
     import jax
@@ -100,38 +107,87 @@ def main(argv=None) -> int:
         )
     )
     planes = jax.tree.leaves(srv._caches)
-    compiled = type(srv)._step_chunk.lower(
-        srv, a_vars, a_caches, a_dstate, a_table,
-        truncate=False, nucleus=False, epoch=srv._mesh_epoch,
-    ).compile()
-    text = compiled.as_text()
-    srv.close()
     dims = re.escape(",".join(map(str, planes[0].shape)))
-    pat = re.compile(
-        r"= \(?\w+\[" + dims + r"\](\{[^}]*\})?.*? (copy|copy-start)\("
-    )
-    kinds: dict[str, int] = {}
-    for line in text.splitlines():
-        if m := pat.search(line):
-            key = f"{m.group(2)} -> {m.group(1) or ''}"
-            kinds[key] = kinds.get(key, 0) + 1
-    total = sum(kinds.values())
+    buf = r"\w+\[" + dims + r"\](\{[^}]*\})"
+    sync = re.compile(r"= " + buf + r" copy\(")
+    start = re.compile(r"= \(" + buf + ", " + buf + r".*\) copy-start\(")
+
+    def tiles(layout):
+        return re.sub(r"S\(\d+\)", "", layout)
+
     where = (
         "described v5e, no chip" if args.describe
         else jax.devices()[0].device_kind
     )
-    print(
-        f"STEP_COPIES {args.cell}: pool {planes[0].shape} x {len(planes)} "
-        f"planes, slots {len(srv.slots)}, chunk {srv.chunk}: pool-shaped "
-        f"copy/copy-start ops {total} ({total / len(planes):.2f} a plane); "
-        f"Mosaic calls {text.count('tpu_custom_call')}; temporaries "
-        f"{compiled.memory_analysis().temp_size_in_bytes} B ({where})",
-        flush=True,
+
+    def report(tag, what, compiled):
+        text = compiled.as_text()
+        # As tests/test_chip_lowering._pool_copies: a ``copy``, or a
+        # ``copy-start`` between two layouts, relays the plane out; a
+        # ``copy-start`` between equal layouts stages it through fast
+        # memory (``S(1)``) as it is.
+        kinds: dict[str, int] = {}
+        for line in text.splitlines():
+            if m := sync.search(line):
+                key = f"relayout: copy -> {m.group(1)}"
+            elif m := start.search(line):
+                same = tiles(m.group(1)) == tiles(m.group(2))
+                key = (
+                    f"{'move' if same else 'relayout'}: copy-start "
+                    f"{m.group(2)} -> {m.group(1)}"
+                )
+            else:
+                continue
+            kinds[key] = kinds.get(key, 0) + 1
+        total = sum(kinds.values())
+        print(
+            f"{tag} {args.cell}: pool {planes[0].shape} x {len(planes)} "
+            f"planes, {what}: pool-shaped copy/copy-start ops {total} "
+            f"({total / len(planes):.2f} a plane); Mosaic calls "
+            f"{text.count('tpu_custom_call')}; temporaries "
+            f"{compiled.memory_analysis().temp_size_in_bytes} B ({where})",
+            flush=True,
+        )
+        for key, n in sorted(kinds.items()):
+            print(f"    {n:4d} x {key}", flush=True)
+        return text
+
+    text = report(
+        "STEP_COPIES", f"slots {len(srv.slots)}, chunk {srv.chunk}",
+        type(srv)._step_chunk.lower(
+            srv, a_vars, a_caches, a_dstate, a_table,
+            truncate=False, nucleus=False, epoch=srv._mesh_epoch,
+        ).compile(),
     )
-    for key, n in sorted(kinds.items()):
-        print(f"    {n:4d} x {key}", flush=True)
     if args.out:
         Path(args.out).write_text(text)
+    # The chunked-prefill passes of the longest prompt the mix sends
+    # (all but the last: the pass that samples adds the LM head, not a
+    # pool operation), at the window widths the batcher pads them to.
+    longest = max(p for p, _ in pairs)
+    chunk, page = serving["prefill_chunk"], serving["page_size"]
+    passes = -(-longest // chunk) if longest > chunk else 0
+    for i in range(passes - 1):
+        n_pad = 1
+        while n_pad < (i + 1) * chunk // page:
+            n_pad *= 2
+
+        def staged(shape, dtype):
+            return abstract(jax.ShapeDtypeStruct(shape, dtype))
+
+        text = report(
+            "CHUNK_PREFILL_COPIES",
+            f"pass {i + 1} of {passes}, window {n_pad} pages",
+            srv._prefill_suffix_fn(chunk, n_pad, sample=False).lower(
+                a_vars, a_caches, staged((n_pad,), jnp.int32),
+                staged((1, chunk), jnp.int32), staged((3,), jnp.int32),
+                staged((2,), jnp.float32), staged((1, 2), jnp.uint32),
+                truncate=False, nucleus=False,
+            ).compile(),
+        )
+        if args.out:
+            Path(f"{args.out}.pass{i + 1}").write_text(text)
+    srv.close()
     return 0
 
 

@@ -12,9 +12,9 @@ lowering and the partitioning check run without a chip. What Mosaic's
 own compiler accepts is ``chip_smoke.py``'s job.
 
 The last section goes one step further and COMPILES, for a v5e that is
-described and not attached, the decode step's write into the paged
-pool, and counts the whole-pool copies the compiler scheduled around
-it.
+described and not attached, the decode step's, the verify chunk's and
+the prefill chunk's writes into the paged pool, and counts the
+whole-pool copies the compiler scheduled around them.
 """
 
 import functools
@@ -38,6 +38,8 @@ from adapt_tpu.ops.attention import flash_attention, flash_attention_with_lse
 from adapt_tpu.ops.decode_attention import decode_attention
 from adapt_tpu.ops.dispatch import kernel_dispatch_stats
 from adapt_tpu.ops.paged_attention import (
+    decode_heads_per_step,
+    fuse_kv,
     paged_attention,
     paged_attention_reference,
     paged_chunk_attention,
@@ -65,13 +67,15 @@ def sds(shape, dtype=jnp.bfloat16):
 
 
 def pool(page, dtype):
-    """An abstract K or V pool: native, or the quantized
-    ``(values, scales)`` pair (int4 packs two nibbles per lane)."""
+    """An abstract block pool: the fused K|V plane, or the quantized
+    ``(values, k_scales, v_scales)`` triple (int4 packs two nibbles per
+    lane)."""
     if dtype == "native":
-        return sds((NPAGES, KVH, page, HD))
+        return sds((NPAGES, KVH, page, 2 * HD))
     width = HD // 2 if dtype == "int4" else HD
     return (
-        sds((NPAGES, KVH, page, width), jnp.int8),
+        sds((NPAGES, KVH, page, 2 * width), jnp.int8),
+        sds((NPAGES, KVH, page, 1), jnp.float32),
         sds((NPAGES, KVH, page, 1), jnp.float32),
     )
 
@@ -93,12 +97,9 @@ INDEX = sds((B,), jnp.int32)
 @pytest.mark.parametrize("split", [1, 2, None])
 @pytest.mark.parametrize("dtype,page", [("native", 128), ("int8", 1024)])
 def test_paged_decode_lowers(as_tpu, split, dtype, page):
-    k = pool(page, dtype)
     lower_for_tpu(
-        lambda q, k, v, t, i, vf: paged_attention(
-            q, k, v, t, i, vf, split=split
-        ),
-        sds((B, KVH, 1, HD)), k, k, TABLE, INDEX, INDEX,
+        lambda q, kv, t, i, vf: paged_attention(q, kv, t, i, vf, split=split),
+        sds((B, KVH, 1, HD)), pool(page, dtype), TABLE, INDEX, INDEX,
     )
 
 
@@ -113,10 +114,9 @@ _CELL_SHAPES = {
 
 def _cell_args(cell):
     b, kvh, hd, pps, npages = _CELL_SHAPES[cell]
-    k = sds((npages, kvh, 128, hd))
     return (
-        sds((b, kvh, 1, hd)), k, k, sds((b, pps), jnp.int32),
-        sds((b,), jnp.int32),
+        sds((b, kvh, 1, hd)), sds((npages, kvh, 128, 2 * hd)),
+        sds((b, pps), jnp.int32), sds((b,), jnp.int32),
     )
 
 
@@ -135,16 +135,26 @@ def test_paged_decode_lowers_at_cell_shapes(as_tpu, cell, split):
     assert books["heads_per_step"] == _CELL_SHAPES[cell][1]
 
 
+@pytest.mark.parametrize("cell", sorted(_CELL_SHAPES))
+def test_cell_planes_take_every_head_a_step(cell):
+    """16 of 16 and 25 of 25: the fused plane's block (one stream of
+    the bytes the two planes' blocks were) leaves the derivation where
+    PR 28 measured it."""
+    _, kvh, hd, _, _ = _CELL_SHAPES[cell]
+    assert decode_heads_per_step(
+        kvh, 128, 2 * hd, 2, False, 8, hd
+    ) == kvh
+
+
 @pytest.mark.parametrize("tree_tail", [0, 2])
 @pytest.mark.parametrize("dtype,page", [("native", 128), ("int8", 1024)])
 def test_paged_verify_lowers(as_tpu, tree_tail, dtype, page):
-    k = pool(page, dtype)
     for split in (1, None):
         lower_for_tpu(
-            lambda q, k, v, t, i: paged_verify_attention(
-                q, k, v, t, i, 5, tree_tail=tree_tail, split=split
+            lambda q, kv, t, i: paged_verify_attention(
+                q, kv, t, i, 5, tree_tail=tree_tail, split=split
             ),
-            sds((B, KVH, 5, HD)), k, k, TABLE, INDEX,
+            sds((B, KVH, 5, HD)), pool(page, dtype), TABLE, INDEX,
         )
 
 
@@ -152,13 +162,12 @@ def test_paged_verify_lowers(as_tpu, tree_tail, dtype, page):
     ("native", 128, None), ("native", 128, 300), ("int8", 1024, None),
 ])
 def test_paged_chunk_lowers(as_tpu, dtype, page, window):
-    k = pool(page, dtype)
     chunk = max(256, page)
     lower_for_tpu(
-        lambda q, k, v, p, pos0: paged_chunk_attention(
-            q, k, v, p, pos0, chunk, window=window
+        lambda q, kv, p, pos0: paged_chunk_attention(
+            q, kv, p, pos0, chunk, window=window
         ),
-        sds((1, KVH, chunk, HD)), k, k, sds((4,), jnp.int32),
+        sds((1, KVH, chunk, HD)), pool(page, dtype), sds((4,), jnp.int32),
         sds((), jnp.int32),
     )
 
@@ -234,8 +243,7 @@ def test_stated_rules_route_to_xla_on_tpu(as_tpu, dtype, page, why):
     """What the kernels cannot serve on hardware is routed by a rule
     the books report; forcing the kernel raises instead of serving the
     oracle under its name."""
-    k = pool(page, dtype)
-    args = (sds((B, KVH, 1, HD)), k, k, TABLE, INDEX)
+    args = (sds((B, KVH, 1, HD)), pool(page, dtype), TABLE, INDEX)
     before = kernel_dispatch_stats().get("paged_decode", {"xla": 0.0})
     text = jax.jit(paged_attention).trace(*args).lower(
         lowering_platforms=("tpu",)
@@ -260,12 +268,11 @@ def test_tp4_paged_decode_lowers_under_shard_map(as_tpu, devices):
     heads = NamedSharding(mesh, P(None, "tp"))
     repl = NamedSharding(mesh, P())
 
-    def step(q, k, v, t, i, head_shard):
-        return paged_attention(q, k, v, t, i, head_shard=head_shard)
+    def step(q, kv, t, i, head_shard):
+        return paged_attention(q, kv, t, i, head_shard=head_shard)
 
-    k = pool(128, "native")
-    args = (sds((B, KVH, 1, HD)), k, k, TABLE, INDEX)
-    shardings = (heads, heads, heads, repl, repl)
+    args = (sds((B, KVH, 1, HD)), pool(128, "native"), TABLE, INDEX)
+    shardings = (heads, heads, repl, repl)
     sharded = jax.jit(
         functools.partial(step, head_shard=(mesh, "tp")),
         in_shardings=shardings, out_shardings=heads,
@@ -289,10 +296,10 @@ def test_tp4_paged_decode_lowers_at_cell_shape(as_tpu, devices):
     heads = NamedSharding(mesh, P(None, "tp"))
     repl = NamedSharding(mesh, P())
     sharded = jax.jit(
-        lambda q, k, v, t, i: paged_attention(
-            q, k, v, t, i, head_shard=(mesh, "tp")
+        lambda q, kv, t, i: paged_attention(
+            q, kv, t, i, head_shard=(mesh, "tp")
         ),
-        in_shardings=(heads, heads, heads, repl, repl), out_shardings=heads,
+        in_shardings=(heads, heads, repl, repl), out_shardings=heads,
     )
     text = sharded.trace(*_cell_args("cgpt1b3_batchgen")).lower(
         lowering_platforms=("tpu",)
@@ -326,15 +333,16 @@ def test_head_sharded_kernels_match_oracles(devices):
         )
 
     q = jnp.asarray(rng.randn(b, kvh, g, hd), jnp.float32)
-    kq, vq = quantize_kv_vectors(kp), quantize_kv_vectors(vp)
+    kv = fuse_kv(kp, vp)
+    kvq = fuse_kv(quantize_kv_vectors(kp), quantize_kv_vectors(vp))
     vf = jnp.asarray([3, 0], jnp.int32)
     close(
         jax.jit(
             lambda *a: paged_attention(
                 *a, prefer="pallas", split=2, head_shard=shard
             )
-        )(q, kq, vq, table, index, vf),
-        paged_attention_reference(q, kq, vq, table, index, vf),
+        )(q, kvq, table, index, vf),
+        paged_attention_reference(q, kvq, table, index, vf),
     )
     qv = jnp.asarray(rng.randn(b, kvh, g * 5, hd), jnp.float32)
     close(
@@ -343,9 +351,9 @@ def test_head_sharded_kernels_match_oracles(devices):
                 *a, 5, prefer="pallas", tree_tail=2, split=2,
                 head_shard=shard,
             )
-        )(qv, kp, vp, table, index),
+        )(qv, kv, table, index),
         paged_verify_attention_reference(
-            qv, kp, vp, table, index, 5, tree_tail=2
+            qv, kv, table, index, 5, tree_tail=2
         ),
     )
     qc = jnp.asarray(rng.randn(1, kvh, g * page, hd), jnp.float32)
@@ -355,8 +363,8 @@ def test_head_sharded_kernels_match_oracles(devices):
             lambda *a: paged_chunk_attention(
                 *a, page, prefer="pallas", head_shard=shard
             )
-        )(qc, kp, vp, pages, jnp.int32(page)),
-        paged_chunk_attention_reference(qc, kp, vp, pages, page, page),
+        )(qc, kv, pages, jnp.int32(page)),
+        paged_chunk_attention_reference(qc, kv, pages, page, page),
     )
 
 
@@ -407,25 +415,25 @@ def test_folded_paged_decode_compiles_for_v5e(
     """Mosaic's own compile of the folded decode kernel (the lowering
     above stops before it): the block of every head that
     ``decode_heads_per_step`` derives fits the scoped VMEM of a v5e,
-    head_dim 64 loads with indices, and the operation keeps the name
-    the benchmark's readers sum (``_paged_impl``)."""
+    head_dim 64 reads its fused row whole and emits the accumulator's
+    upper lanes, and the operation keeps the name the benchmark's
+    readers sum (``_paged_impl``)."""
     b, kvh, hd, pps, npages, page, dtype = shape
 
     def on_chip(shape, dt=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
-    k = on_chip((npages, kvh, page, hd))
+    kv = on_chip((npages, kvh, page, 2 * hd))
     if dtype == "int8":
-        k = (
-            on_chip((npages, kvh, page, hd), jnp.int8),
+        kv = (
+            on_chip((npages, kvh, page, 2 * hd), jnp.int8),
+            on_chip((npages, kvh, page, 1), jnp.float32),
             on_chip((npages, kvh, page, 1), jnp.float32),
         )
     text = jax.jit(
-        lambda q, k, v, t, i, vf: paged_attention(
-            q, k, v, t, i, vf, split=split
-        )
+        lambda q, kv, t, i, vf: paged_attention(q, kv, t, i, vf, split=split)
     ).lower(
-        on_chip((b, kvh, 1, hd)), k, k, on_chip((b, pps), jnp.int32),
+        on_chip((b, kvh, 1, hd)), kv, on_chip((b, pps), jnp.int32),
         on_chip((b,), jnp.int32), on_chip((b,), jnp.int32),
     ).compile().as_text()
     assert re.search(r"%_paged_impl[.\d]* = .*tpu_custom_call", text)
@@ -461,36 +469,46 @@ def _pool_copies(text, shape):
 _LAYERS = 2
 
 #: (pool shape, model dim, heads, mlp, slots, pages per slot): the
-#: benchmark's two deployments at their published widths.
-_CGPT = ((169, 16, 128, 128), 2048, 16, 8192, 24, 7)  # cgpt1b3_batchgen
-_XL = ((57, 25, 128, 64), 1600, 25, 6400, 8, 7)  # gpt2xl_doc
+#: benchmark's two deployments at their published widths. A block's
+#: pool is ONE plane, K|V fused on the lanes.
+_CGPT = ((169, 16, 128, 256), 2048, 16, 8192, 24, 7)  # cgpt1b3_batchgen
+_XL = ((57, 25, 128, 128), 1600, 25, 6400, 8, 7)  # gpt2xl_doc
 
 
-@pytest.mark.parametrize("deploy,form,relayouts_per_plane", [
-    # head_dim 128: the pool's resident layout IS the kernel's, and
-    # ``append_kv_paged`` takes its head-indexed scatter.
-    (_CGPT, "step", 0),
-    (_CGPT, "scan8", 0),
-    (_CGPT, "verify", 0),
-    # head_dim 64 lives with the 128-wide page axis on the lanes and
-    # takes the row loop; the decode kernel reads that layout as it is
-    # (pages swapped to (hd, page): a bitcast), so nothing is relaid
-    # out (one relayout a plane until PR 28).
-    (_XL, "step", 0),
-], ids=["hd128-step", "hd128-scan8", "hd128-verify", "hd64-step"])
+@pytest.mark.parametrize("deploy,form", [
+    # head_dim 128: two lane tiles a row.
+    (_CGPT, "step"),
+    (_CGPT, "scan8"),
+    (_CGPT, "verify"),
+    # head_dim 64: the fused row is exactly ONE lane tile, so the plane
+    # lives row-major like head_dim 128's, ``append_kv_paged`` takes its
+    # head-indexed scatter and all three kernels read it as it lives
+    # (until PR 30 a K and a V plane of 64-lane rows lived with the page
+    # axis on the lanes: a row loop to write them, a transposed read in
+    # the decode kernel, and one relayout a plane around the other two).
+    (_XL, "step"),
+    (_XL, "scan8"),
+    (_XL, "verify"),
+    (_XL, "chunk"),
+], ids=["hd128-step", "hd128-scan8", "hd128-verify", "hd64-step",
+        "hd64-scan8", "hd64-verify", "hd64-chunk"])
 def test_pool_write_compiles_without_pool_relayout(
-    as_tpu, one_chip, no_persistent_cache, deploy, form, relayouts_per_plane
+    as_tpu, one_chip, no_persistent_cache, deploy, form
 ):
     """Two real ``DecoderBlock``s, pools donated, compiled for the
-    described chip: the per-token write plus the Mosaic call schedule
-    no whole-pool relayout (the advanced-index scatter this replaced
-    cost 2 per plane at head_dim 128, 3 inside a scan's body + entry +
-    exit, and 3 at head_dim 64). Layout assignment is a heuristic —
-    how the update operand is produced decides the pool's layout — so
-    this count is the guard."""
+    described chip: the write (per token; a chunk's pages in ``chunk``,
+    through ``prefill_chunk_paged``) plus the Mosaic call schedule no
+    whole-pool relayout and no staging move (the advanced-index scatter
+    this replaced cost 2 per plane at head_dim 128, 3 inside a scan's
+    body + entry + exit, and 3 at head_dim 64; the two-plane head_dim-64
+    pool cost its chunk-prefill program 2 a plane a pass). Layout
+    assignment is a heuristic — how the update operand is produced
+    decides the pool's layout — so this count is the guard."""
     shape, dim, heads, mlp, slots, pps = deploy
     block = DecoderBlock(dim, heads, mlp, dtype=jnp.bfloat16)
-    kc = 4 if form == "verify" else 1
+    rows, kc = slots, {"verify": 4, "chunk": 256}.get(form, 1)
+    if form == "chunk":
+        rows = 1  # prefill is per request: 256 positions, two pages
 
     def on_chip(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -506,18 +524,23 @@ def test_pool_write_compiles_without_pool_relayout(
 
     def step(params, x, pools, table, index):
         out = []
-        for kp, vp in pools:
+        for kv in pools:
             if form == "verify":
-                x, kp, vp = block.apply(
-                    params, x, kp, vp, table, index, "pallas",
+                x, kv = block.apply(
+                    params, x, kv, table, index, "pallas",
                     method="verify_chunk_paged",
                 )
+            elif form == "chunk":  # table: the window's pages; index: pos0
+                x, kv = block.apply(
+                    params, x, kv, table, index, "pallas",
+                    method="prefill_chunk_paged",
+                )
             else:
-                x, kp, vp = block.apply(
-                    params, x, kp, vp, table, index, None, "pallas",
+                x, kv = block.apply(
+                    params, x, kv, table, index, None, "pallas",
                     method="decode_step_paged",
                 )
-            out.append((kp, vp))
+            out.append(kv)
         return x, out
 
     def program(params, x, pools, table, index):
@@ -534,18 +557,25 @@ def test_pool_write_compiles_without_pool_relayout(
         )
         return x, pools
 
+    if form == "chunk":
+        table, index = on_chip((4,), jnp.int32), on_chip((), jnp.int32)
+    else:
+        table = on_chip((slots, pps), jnp.int32)
+        index = on_chip((slots,), jnp.int32)
     compiled = jax.jit(program, donate_argnums=(2,)).lower(
-        params, on_chip((slots, kc, dim)),
-        [(on_chip(shape), on_chip(shape)) for _ in range(_LAYERS)],
-        on_chip((slots, pps), jnp.int32), on_chip((slots,), jnp.int32),
+        params, on_chip((rows, kc, dim)),
+        [on_chip(shape) for _ in range(_LAYERS)], table, index,
     ).compile()
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= _LAYERS
-    relayouts, moves = _pool_copies(text, shape)
-    planes = 2 * _LAYERS
-    assert relayouts <= relayouts_per_plane * planes, (relayouts, moves)
-    if relayouts_per_plane == 0:
-        assert moves == 0, moves
+    assert _pool_copies(text, shape) == (0, 0)
+    # And no row loop: neither deployment's plane takes
+    # ``append_kv_paged``'s narrow arm (one pool-shaped
+    # ``dynamic-update-slice`` a token, 4-5 us each on a v5e).
+    dims = re.escape(",".join(map(str, shape)))
+    assert not re.search(
+        r"= \w+\[" + dims + r"\]\S* dynamic-update-slice\(", text
+    )
 
 
 def test_pool_copies_counts_what_the_scatter_cost():

@@ -310,8 +310,8 @@ def test_gqa_requests_match_generate():
 
     bat = ContinuousBatcher(lm, variables, slots=2, chunk=1, page_size=8)
     # 2 slots x 6 pages + trash; 2 kv heads (of 4 query heads), page 8,
-    # head_dim 8.
-    assert bat._caches[0][0].shape == (13, 2, 8, 8)
+    # head_dim 8: K|V fused on 16 lanes.
+    assert bat._caches[0].shape == (13, 2, 8, 16)
     ids = {bat.submit(p, s): i
            for i, (p, s) in enumerate(zip(prompts, steps))}
     out = bat.run()

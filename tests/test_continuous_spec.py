@@ -311,7 +311,7 @@ def test_spec_validation(lm_setup, draft_setup):
         lm, variables, slots=2, kv_cache_dtype="int8",
         draft_lm=draft, draft_variables=dvars,
     )
-    assert isinstance(bat._caches[0][0], tuple)
+    assert isinstance(bat._caches[0], tuple)  # (values, K scales, V scales)
     with pytest.raises(ValueError, match="draft_k"):
         SpeculativeConfig(draft_k=0)
 

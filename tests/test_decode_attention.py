@@ -193,11 +193,11 @@ def test_head_parity_guard_names_the_tp_mistake(rng):
         decode_attention(q, cache, cache, 3)
     with pytest.raises(ValueError, match="head count"):
         verify_attention(q, cache, cache, jnp.zeros((2,), jnp.int32), 2)
-    pool = jnp.zeros((4, 2, 8, 8))
+    pool = jnp.zeros((4, 2, 8, 16))  # fused K|V rows
     table = jnp.zeros((2, 2), jnp.int32)
     with pytest.raises(ValueError, match="head count"):
-        paged_attention(q, pool, pool, table, 3)
+        paged_attention(q, pool, table, 3)
     with pytest.raises(ValueError, match="head count"):
         paged_verify_attention(
-            q, pool, pool, table, jnp.zeros((2,), jnp.int32), 2
+            q, pool, table, jnp.zeros((2,), jnp.int32), 2
         )

@@ -33,6 +33,7 @@ from adapt_tpu.ops.decode_attention import (
 )
 from adapt_tpu.ops.dispatch import kernel_dispatch_stats
 from adapt_tpu.ops.paged_attention import (
+    fuse_kv,
     paged_attention,
     paged_attention_reference,
     paged_verify_attention,
@@ -180,9 +181,10 @@ def test_split_decode_paged_parity(dtype, split):
     idx = jnp.asarray([500, 60], jnp.int32)
     if dtype != "native":
         kp, vp = _quant(kp, dtype), _quant(vp, dtype)
-    ref = paged_attention_reference(q, kp, vp, table, idx)
+    pool = fuse_kv(kp, vp)
+    ref = paged_attention_reference(q, pool, table, idx)
     out = paged_attention(
-        q, kp, vp, table, idx, prefer="pallas", split=split
+        q, pool, table, idx, prefer="pallas", split=split
     )
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(ref), atol=2e-5
@@ -207,11 +209,12 @@ def test_split_verify_paged_parity(dtype, split, tree_tail):
     idx = jnp.asarray([300, -7], jnp.int32)  # row 1 dead
     if dtype != "native":
         kp, vp = _quant(kp, dtype), _quant(vp, dtype)
+    pool = fuse_kv(kp, vp)
     ref = paged_verify_attention_reference(
-        q, kp, vp, table, idx, K, tree_tail=tree_tail
+        q, pool, table, idx, K, tree_tail=tree_tail
     )
     out = paged_verify_attention(
-        q, kp, vp, table, idx, K, prefer="pallas",
+        q, pool, table, idx, K, prefer="pallas",
         tree_tail=tree_tail, split=split,
     )
     np.testing.assert_allclose(
@@ -266,14 +269,14 @@ def test_kernel_dispatch_gauges_surface_fallback():
     kp = jnp.asarray(rng.randn(5, 2, 8, 16), jnp.float32)  # page 8:
     vp = jnp.asarray(rng.randn(5, 2, 8, 16), jnp.float32)  # unsupported
     table = jnp.asarray([[1, 2]], jnp.int32)
-    paged_attention(q, kp, vp, table, jnp.asarray([9], jnp.int32))
+    paged_attention(q, fuse_kv(kp, vp), table, jnp.asarray([9], jnp.int32))
     st = kernel_dispatch_stats()
     assert st["paged_decode"]["last"] == 0.0  # oracle (page not lane-mult)
     assert st["paged_decode"]["xla"] >= 1
     kp2 = jnp.asarray(rng.randn(3, 2, 128, 16), jnp.float32)
     vp2 = jnp.asarray(rng.randn(3, 2, 128, 16), jnp.float32)
     paged_attention(
-        q, kp2, vp2, jnp.asarray([[1, 2]], jnp.int32),
+        q, fuse_kv(kp2, vp2), jnp.asarray([[1, 2]], jnp.int32),
         jnp.asarray([100], jnp.int32), prefer="pallas",
     )
     st = kernel_dispatch_stats()
@@ -623,8 +626,8 @@ def test_int4_tp2_and_recovery_migration(sim_mesh):
         kv_layout="paged", page_size=8, health=mon,
     )
     # sharded: both members at logical/2 per device
-    for ck, cv in bat._caches:
-        for member in (*ck, *cv):
+    for pool in bat._caches:
+        for member in pool:
             assert device_local_nbytes(member) * 2 == member.nbytes
     r = bat.submit(p, 10)
     bat.tick()

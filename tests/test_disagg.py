@@ -91,15 +91,18 @@ def _mk_pair(lm, variables, dtype="native", mesh=None, tp=1, spec=None,
 
 
 def _rand_handoff(rng, quantized=False, blocks=2, n=3, kvh=2, hd=4):
-    def member():
+    def block():
+        # A block's pool pages: fused K|V rows (2 * hd lanes), beside
+        # them the K and the V scale planes when quantized.
         if quantized:
             return (
-                rng.randint(-127, 127, size=(n, kvh, PAGE, hd)).astype(
+                rng.randint(-127, 127, size=(n, kvh, PAGE, 2 * hd)).astype(
                     np.int8
                 ),
                 rng.rand(n, kvh, PAGE, 1).astype(np.float32),
+                rng.rand(n, kvh, PAGE, 1).astype(np.float32),
             )
-        return rng.rand(n, kvh, PAGE, hd).astype(np.float32)
+        return rng.rand(n, kvh, PAGE, 2 * hd).astype(np.float32)
 
     return KVHandoff(
         req_id=7,
@@ -107,7 +110,7 @@ def _rand_handoff(rng, quantized=False, blocks=2, n=3, kvh=2, hd=4):
         page_size=PAGE,
         n_pages=n,
         quantized=quantized,
-        blocks=[(member(), member()) for _ in range(blocks)],
+        blocks=[block() for _ in range(blocks)],
     )
 
 
@@ -127,13 +130,12 @@ def test_handoff_wire_roundtrip_zero_copy(quantized):
     assert got.n_pages == h.n_pages and got.quantized == quantized
     np.testing.assert_array_equal(got.prompt, h.prompt)
     wire_arr = np.frombuffer(wire, np.uint8)
-    for (hk, hv), (gk, gv) in zip(h.blocks, got.blocks):
-        for ours, theirs in ((hk, gk), (hv, gv)):
-            for a, b in zip(jax.tree.leaves(ours), jax.tree.leaves(theirs)):
-                np.testing.assert_array_equal(a, b)
-                assert np.shares_memory(b, wire_arr), (
-                    "received tensor does not view the wire buffer"
-                )
+    assert jax.tree.structure(got.blocks) == jax.tree.structure(h.blocks)
+    for a, b in zip(jax.tree.leaves(h.blocks), jax.tree.leaves(got.blocks)):
+        np.testing.assert_array_equal(a, b)
+        assert np.shares_memory(b, wire_arr), (
+            "received tensor does not view the wire buffer"
+        )
 
 
 def test_corrupt_and_truncated_handoff_raise():
@@ -233,19 +235,17 @@ def test_handoff_pages_equal_inplace_chunked_prefill(lm_setup):
     key = Pager.prefix_key(prompt, m * PAGE)
     for bat in (colo, decode):
         assert bat._pager._by_key.get(key) is not None
-    for b in range(len(colo._caches)):
-        for member in range(2):
-            cpool = colo._caches[b][member]
-            dpool = decode._caches[b][member]
-            for j in range(m):
-                pkey = Pager.prefix_key(prompt, (j + 1) * PAGE)
-                cpage = colo._pager._by_key[pkey]
-                dpage = decode._pager._by_key[pkey]
-                np.testing.assert_array_equal(
-                    np.asarray(cpool[cpage]),
-                    np.asarray(dpool[dpage]),
-                    err_msg=f"block {b} member {member} page {j}",
-                )
+    # A native block's pool is ONE plane, K|V fused on the lanes.
+    for b, (cpool, dpool) in enumerate(zip(colo._caches, decode._caches)):
+        for j in range(m):
+            pkey = Pager.prefix_key(prompt, (j + 1) * PAGE)
+            cpage = colo._pager._by_key[pkey]
+            dpage = decode._pager._by_key[pkey]
+            np.testing.assert_array_equal(
+                np.asarray(cpool[cpage]),
+                np.asarray(dpool[dpage]),
+                err_msg=f"block {b} page {j}",
+            )
 
 
 def test_corrupt_wire_fails_request_cleanly(lm_setup, monkeypatch):

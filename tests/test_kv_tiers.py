@@ -147,15 +147,18 @@ def test_page_codec_meta_and_errors():
 
 
 def _blocks(rng, kvh=2, hd=4, quant=False):
-    def member():
+    """One page of two blocks' pools: fused K|V rows, beside them the K
+    and the V scale planes when quantized."""
+    def block():
         if quant:
             return (
-                rng.randint(-127, 127, (kvh, PAGE, hd)).astype(np.int8),
+                rng.randint(-127, 127, (kvh, PAGE, 2 * hd)).astype(np.int8),
+                rng.rand(kvh, PAGE, 1).astype(np.float32),
                 rng.rand(kvh, PAGE, 1).astype(np.float32),
             )
-        return rng.randn(kvh, PAGE, hd).astype(np.float32)
+        return rng.randn(kvh, PAGE, 2 * hd).astype(np.float32)
 
-    return [(member(), member()) for _ in range(2)]
+    return [block() for _ in range(2)]
 
 
 def test_host_tier_warm_cold_demotion_and_drop():
@@ -186,8 +189,8 @@ def test_host_tier_warm_cold_demotion_and_drop():
 
 
 def test_host_tier_quantized_members_and_saved_bytes():
-    """int8-pool pages carry (values, scales) members; lossy cold
-    codecs must pass the int8 value plane through bit-exact."""
+    """int8-pool pages carry (values, K scales, V scales) planes; lossy
+    cold codecs must pass the int8 value plane through bit-exact."""
     cfg = CacheTierConfig(
         host_capacity_pages=2, warm_capacity_pages=0, cold_codec="int4"
     )
@@ -196,12 +199,13 @@ def test_host_tier_quantized_members_and_saved_bytes():
     blocks = _blocks(rng, quant=True)
     tier.put(b"q", blocks)
     got = tier.get(b"q")
-    for (k, v), (gk, gv) in zip(blocks, got):
-        # value planes (int8) are bit-exact even under a lossy codec
-        np.testing.assert_array_equal(k[0], gk[0])
-        np.testing.assert_array_equal(v[0], gv[0])
+    for want, have in zip(blocks, got):
+        assert isinstance(have, tuple) and len(have) == 3
+        # the value plane (int8) is bit-exact even under a lossy codec
+        np.testing.assert_array_equal(want[0], have[0])
         # scale planes (f32) may quantize, but keep shape/dtype
-        assert gk[1].dtype == np.float32 and gk[1].shape == k[1].shape
+        for w, h in zip(want[1:], have[1:]):
+            assert h.dtype == np.float32 and h.shape == w.shape
 
 
 def test_host_tier_disk_backing(tmp_path):
@@ -446,23 +450,23 @@ def test_wire_codec_roundtrip_and_crc_on_compressed():
 
     rng = np.random.RandomState(3)
 
-    def member():
-        return rng.rand(3, 2, PAGE, 4).astype(np.float32)
+    def block():  # a native pool's pages: fused K|V rows
+        return rng.rand(3, 2, PAGE, 8).astype(np.float32)
 
     h = KVHandoff(
         req_id=7,
         prompt=rng.randint(0, VOCAB, size=3 * PAGE + 3).astype(np.int32),
         page_size=PAGE, n_pages=3, quantized=False,
-        blocks=[(member(), member()) for _ in range(2)],
+        blocks=[block() for _ in range(2)],
     )
     got = unpack_handoff(loopback(pack_handoff(h, wire_codec="lz")))
     np.testing.assert_array_equal(got.prompt, h.prompt)
-    for (hk, hv), (gk, gv) in zip(h.blocks, got.blocks):
-        np.testing.assert_array_equal(hk, gk)
-        np.testing.assert_array_equal(hv, gv)
+    assert len(got.blocks) == len(h.blocks) == 2
+    for ours, theirs in zip(h.blocks, got.blocks):
+        np.testing.assert_array_equal(ours, theirs)
     lossy = unpack_handoff(loopback(pack_handoff(h, wire_codec="int8")))
     np.testing.assert_array_equal(lossy.prompt, h.prompt)  # int: exact
-    assert np.allclose(lossy.blocks[0][0], h.blocks[0][0], atol=0.02)
+    assert np.allclose(lossy.blocks[0], h.blocks[0], atol=0.02)
     # crc runs on the compressed payload: flip a late (payload) byte.
     msg = pack_handoff(h, wire_codec="lz")
     wire = bytearray(b"".join(frame_parts(msg)))

@@ -20,8 +20,10 @@ from adapt_tpu.models.transformer_lm import (
 )
 from adapt_tpu.ops.paged_attention import (
     append_kv_paged,
+    fuse_kv,
     paged_attention,
     paged_attention_reference,
+    split_kv,
 )
 from adapt_tpu.ops.quantize import quantize_kv_vectors
 from adapt_tpu.runtime.continuous import ContinuousBatcher
@@ -62,34 +64,39 @@ def test_pager_validation():
 
 # -- the pool's format: one owner --------------------------------------------
 
-#: What ContinuousBatcher, disagg.PrefillWorker and SPPrefiller each
-#: spelled out for themselves until PR 29, per member of a block's
-#: (K, V): [(shape, dtype), ...] at 9 pages, 2 kv heads, page 16,
-#: head_dim 8.
+#: A block's pool, plane by plane: [(shape, dtype), ...] at 9 pages, 2
+#: kv heads, page 16, head_dim 8. K and V of a position are ONE row of
+#: the value plane (K's lanes, then V's); a quantized pool keeps the two
+#: scale planes beside it, and int4 packs two nibbles a lane.
 _POOL_FORMATS = {
-    "native": [((9, 2, 16, 8), jnp.float32)],
-    "int8": [((9, 2, 16, 8), jnp.int8), ((9, 2, 16, 1), jnp.float32)],
-    "int4": [((9, 2, 16, 4), jnp.int8), ((9, 2, 16, 1), jnp.float32)],
+    "native": [((9, 2, 16, 16), jnp.float32)],
+    "int8": [((9, 2, 16, 16), jnp.int8), ((9, 2, 16, 1), jnp.float32),
+             ((9, 2, 16, 1), jnp.float32)],
+    "int4": [((9, 2, 16, 8), jnp.int8), ((9, 2, 16, 1), jnp.float32),
+             ((9, 2, 16, 1), jnp.float32)],
 }
 
 
 @pytest.mark.parametrize("kv_dtype", sorted(_POOL_FORMATS))
 def test_alloc_kv_pools_is_the_format_the_callers_built(kv_dtype):
-    """A block's pools: a (K, V) pair of zeroed planes — a native
-    array, or (int8 values, float32 scales) with int4 at half the lane
-    width — and the batcher's and the prefill worker's pools for the
-    same arguments are that, leaf for leaf."""
+    """A block's pool: ONE zeroed fused K|V plane — a native array, or
+    (int8 values, float32 K scales, float32 V scales) with int4 at half
+    the lane width — and the batcher's and the prefill worker's pools
+    for the same arguments are that, leaf for leaf."""
     from adapt_tpu.runtime.disagg import PrefillWorker
 
     want = _POOL_FORMATS[kv_dtype]
-    pair = alloc_kv_pools(9, 2, 16, 8, jnp.float32, kv_dtype)
-    assert isinstance(pair, tuple) and len(pair) == 2
-    for member in pair:
-        leaves = list(member) if kv_dtype != "native" else [member]
-        assert isinstance(member, tuple) == (kv_dtype != "native")
-        assert [(x.shape, x.dtype) for x in leaves] == want
-        assert all(not np.asarray(x).any() for x in leaves)
-    assert kv_value_width(8, kv_dtype) == want[0][0][-1]
+    pool = alloc_kv_pools(9, 2, 16, 8, jnp.float32, kv_dtype)
+    assert isinstance(pool, tuple) == (kv_dtype != "native")
+    leaves = list(pool) if kv_dtype != "native" else [pool]
+    assert [(x.shape, x.dtype) for x in leaves] == want
+    assert all(not np.asarray(x).any() for x in leaves)
+    assert 2 * kv_value_width(8, kv_dtype) == want[0][0][-1]
+    # The same bytes as the two planes a block held until PR 30.
+    two = 2 * 9 * 2 * 16 * kv_value_width(8, kv_dtype) * (
+        4 if kv_dtype == "native" else 1
+    ) + (0 if kv_dtype == "native" else 2 * 9 * 2 * 16 * 4)
+    assert sum(x.nbytes for x in leaves) == two
 
     # The callers: 4 query heads, 2 kv heads, head_dim 8; 2 slots x 4
     # pages + the trash page = 9.
@@ -113,7 +120,7 @@ def test_alloc_kv_pools_is_the_format_the_callers_built(kv_dtype):
     assert jax.tree.structure(bat._caches) == jax.tree.structure(
         worker._pools
     )
-    assert fmt(bat._caches) == fmt(worker._pools) == want * 4
+    assert fmt(bat._caches) == fmt(worker._pools) == want * 2
     assert bat._pager.num_pages == worker._pager.num_pages == 9
     assert bat._pager.pages_per_slot == worker._pager.pages_per_slot == 4
     bat.close()
@@ -200,43 +207,68 @@ def test_pager_radix_probe_and_books():
 # -- kernel vs oracle --------------------------------------------------------
 
 
-def test_paged_kernel_matches_oracle(rng):
-    b, kvh, g, hd, page, npages, pps = 2, 2, 3, 64, 128, 16, 4
+def _pool(key, npages, kvh, page, hd, quantized=False):
+    """A random block pool: K and V drawn apart and fused — native, or
+    quantized with THE shared per-vector scheme ((int8 values, K
+    scales, V scales), scales (npages, kvh, page, 1))."""
+    k = jax.random.normal(jax.random.fold_in(key, 1), (npages, kvh, page, hd))
+    v = jax.random.normal(jax.random.fold_in(key, 2), (npages, kvh, page, hd))
+    if quantized:
+        k, v = quantize_kv_vectors(k), quantize_kv_vectors(v)
+    return fuse_kv(k, v)
+
+
+# head_dim 64: the fused row is one lane tile, read whole; 128: cut at
+# a tile edge (``paged_attention._acc_width``); 32: half a tile, read
+# whole, and by the decode kernel transposed (the page on the lanes).
+_HD = pytest.mark.parametrize("hd", [32, 64, 128])
+_QUANT = pytest.mark.parametrize(
+    "quantized", [False, True], ids=["native", "int8"]
+)
+
+
+@_QUANT
+@_HD
+def test_paged_kernel_matches_oracle(rng, hd, quantized):
+    """``_paged_kernel`` against the gather oracle (which itself reduces
+    to the contiguous decode oracle), with and without ragged
+    valid_from; quantized, the scale tiles ride the scalar-prefetch
+    pipeline (table-addressed like the int8 payload) into the shared
+    ``_attend_tile`` quantized branch."""
+    b, kvh, g, page, npages = 2, 2, 3, 128, 16
     q = jax.random.normal(rng, (b, kvh, g, hd))
-    kp = jax.random.normal(jax.random.fold_in(rng, 1), (npages, kvh, page, hd))
-    vp = jax.random.normal(jax.random.fold_in(rng, 2), (npages, kvh, page, hd))
+    pool = _pool(rng, npages, kvh, page, hd, quantized)
     table = jnp.asarray([[3, 7, 1, 0], [5, 2, 9, 4]], jnp.int32)
     index = jnp.asarray([300, 200], jnp.int32)
     for vf in (None, jnp.asarray([10, 0], jnp.int32)):
-        ref = paged_attention_reference(q, kp, vp, table, index, vf)
-        out = paged_attention(q, kp, vp, table, index, vf, prefer="pallas")
+        ref = paged_attention_reference(q, pool, table, index, vf)
+        out = paged_attention(q, pool, table, index, vf, prefer="pallas")
         np.testing.assert_allclose(
             np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5
         )
 
 
-def test_paged_chunk_kernel_matches_oracle(rng):
+@_QUANT
+@_HD
+def test_paged_chunk_kernel_matches_oracle(rng, hd, quantized):
     """Chunk-query kernel (per-row causal over a paged window) vs its
-    gather oracle: GQA folding, non-zero pos0, and pow2 trash padding."""
+    gather oracle: GQA folding, non-zero pos0, and pow2 trash padding;
+    quantized, the chunk's rows attend the int8 window with fused scale
+    application."""
     from adapt_tpu.ops.paged_attention import (
         paged_chunk_attention,
         paged_chunk_attention_reference,
     )
 
-    kvh, g, chunk, hd, page, npages = 2, 3, 32, 64, 128, 12
+    kvh, g, chunk, page, npages = 2, 3, 32, 128, 12
     q = jax.random.normal(rng, (1, kvh, g * chunk, hd))
-    kp = jax.random.normal(
-        jax.random.fold_in(rng, 1), (npages, kvh, page, hd)
-    )
-    vp = jax.random.normal(
-        jax.random.fold_in(rng, 2), (npages, kvh, page, hd)
-    )
+    pool = _pool(rng, npages, kvh, page, hd, quantized)
     for pos0, pages in [(128, [3, 7, 0, 0]), (0, [5, 0]),
                         (256, [2, 4, 9, 0])]:
         pages = jnp.asarray(pages, jnp.int32)
-        ref = paged_chunk_attention_reference(q, kp, vp, pages, pos0, chunk)
+        ref = paged_chunk_attention_reference(q, pool, pages, pos0, chunk)
         out = paged_chunk_attention(
-            q, kp, vp, pages, pos0, chunk, prefer="pallas"
+            q, pool, pages, pos0, chunk, prefer="pallas"
         )
         np.testing.assert_allclose(
             np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5,
@@ -244,7 +276,9 @@ def test_paged_chunk_kernel_matches_oracle(rng):
         )
 
 
-def test_paged_verify_kernel_matches_oracle(rng):
+@_QUANT
+@_HD
+def test_paged_verify_kernel_matches_oracle(rng, hd, quantized):
     """Batched verify kernel (per-SLOT base positions, per-row causal
     diagonal — the speculative tick's attention) vs its gather oracle:
     desynchronized indices, GQA folding, and a sliding window."""
@@ -253,23 +287,17 @@ def test_paged_verify_kernel_matches_oracle(rng):
         paged_verify_attention_reference,
     )
 
-    b, kvh, g, chunk, hd, page, npages = 2, 2, 2, 5, 64, 128, 16
+    b, kvh, g, chunk, page, npages = 2, 2, 2, 5, 128, 16
     q = jax.random.normal(rng, (b, kvh, g * chunk, hd))
-    kp = jax.random.normal(
-        jax.random.fold_in(rng, 1), (npages, kvh, page, hd)
-    )
-    vp = jax.random.normal(
-        jax.random.fold_in(rng, 2), (npages, kvh, page, hd)
-    )
+    pool = _pool(rng, npages, kvh, page, hd, quantized)
     table = jnp.asarray([[3, 7, 1, 0], [5, 2, 9, 4]], jnp.int32)
     index = jnp.asarray([301, 77], jnp.int32)  # rows desynchronized
     for window in (None, 130):
         ref = paged_verify_attention_reference(
-            q, kp, vp, table, index, chunk, window=window
+            q, pool, table, index, chunk, window=window
         )
         out = paged_verify_attention(
-            q, kp, vp, table, index, chunk, prefer="pallas",
-            window=window,
+            q, pool, table, index, chunk, prefer="pallas", window=window,
         )
         np.testing.assert_allclose(
             np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5,
@@ -277,85 +305,26 @@ def test_paged_verify_kernel_matches_oracle(rng):
         )
 
 
-def _quantized_pool(key, npages, kvh, page, hd):
-    """Random native pool quantized with THE shared per-vector scheme —
-    (int8 values, f32 scales (npages, kvh, page, 1)) pair."""
-    return quantize_kv_vectors(
-        jax.random.normal(key, (npages, kvh, page, hd))
-    )
-
-
-def test_paged_kernel_quantized_matches_oracle(rng):
-    """Quantized ``_paged_kernel``: scale tiles ride the scalar-prefetch
-    pipeline (table-addressed like the int8 payload) into the shared
-    ``_attend_tile`` quantized branch — interpreter parity vs the
-    gather oracle (which itself reduces to the contiguous quantized
-    decode oracle), with and without ragged valid_from."""
-    b, kvh, g, hd, page, npages = 2, 2, 3, 64, 128, 16
-    q = jax.random.normal(rng, (b, kvh, g, hd))
-    kp = _quantized_pool(jax.random.fold_in(rng, 1), npages, kvh, page, hd)
-    vp = _quantized_pool(jax.random.fold_in(rng, 2), npages, kvh, page, hd)
-    table = jnp.asarray([[3, 7, 1, 0], [5, 2, 9, 4]], jnp.int32)
-    index = jnp.asarray([300, 200], jnp.int32)
-    for vf in (None, jnp.asarray([10, 0], jnp.int32)):
-        ref = paged_attention_reference(q, kp, vp, table, index, vf)
-        out = paged_attention(q, kp, vp, table, index, vf, prefer="pallas")
-        np.testing.assert_allclose(
-            np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5
-        )
-
-
-def test_paged_verify_kernel_quantized_matches_oracle(rng):
-    """Quantized ``_verify_kernel`` (the int8 speculative verify over a
-    paged cache): desynchronized per-slot indices, GQA folding, sliding
-    window — interpreter parity vs the gather oracle."""
-    from adapt_tpu.ops.paged_attention import (
-        paged_verify_attention,
-        paged_verify_attention_reference,
-    )
-
-    b, kvh, g, chunk, hd, page, npages = 2, 2, 2, 5, 64, 128, 16
-    q = jax.random.normal(rng, (b, kvh, g * chunk, hd))
-    kp = _quantized_pool(jax.random.fold_in(rng, 1), npages, kvh, page, hd)
-    vp = _quantized_pool(jax.random.fold_in(rng, 2), npages, kvh, page, hd)
-    table = jnp.asarray([[3, 7, 1, 0], [5, 2, 9, 4]], jnp.int32)
-    index = jnp.asarray([301, 77], jnp.int32)
-    for window in (None, 130):
-        ref = paged_verify_attention_reference(
-            q, kp, vp, table, index, chunk, window=window
-        )
-        out = paged_verify_attention(
-            q, kp, vp, table, index, chunk, prefer="pallas", window=window
-        )
-        np.testing.assert_allclose(
-            np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5,
-            err_msg=f"window={window}",
-        )
-
-
-def test_paged_chunk_kernel_quantized_matches_oracle(rng):
-    """Quantized ``_chunk_kernel`` (int8 chunked prefill): the chunk's
-    rows attend the quantized window with fused scale application —
-    interpreter parity vs the gather oracle, incl. trash padding."""
-    from adapt_tpu.ops.paged_attention import (
-        paged_chunk_attention,
-        paged_chunk_attention_reference,
-    )
-
-    kvh, g, chunk, hd, page, npages = 2, 3, 32, 64, 128, 12
-    q = jax.random.normal(rng, (1, kvh, g * chunk, hd))
-    kp = _quantized_pool(jax.random.fold_in(rng, 1), npages, kvh, page, hd)
-    vp = _quantized_pool(jax.random.fold_in(rng, 2), npages, kvh, page, hd)
-    for pos0, pages in [(128, [3, 7, 0, 0]), (0, [5, 0])]:
-        pages = jnp.asarray(pages, jnp.int32)
-        ref = paged_chunk_attention_reference(q, kp, vp, pages, pos0, chunk)
-        out = paged_chunk_attention(
-            q, kp, vp, pages, pos0, chunk, prefer="pallas"
-        )
-        np.testing.assert_allclose(
-            np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5,
-            err_msg=f"pos0={pos0}",
-        )
+def test_fuse_and_split_kv_are_inverse_and_say_where_the_lanes_are(rng):
+    """``fuse_kv``: K on lanes [0, w), V on [w, 2w) of one row; a
+    quantized pair fuses its value rows and keeps both scale columns.
+    ``split_kv`` gives the two operands back, bit for bit."""
+    k = jax.random.normal(rng, (3, 2, 8, 4))
+    v = -k
+    fused = fuse_kv(k, v)
+    assert fused.shape == (3, 2, 8, 8)
+    np.testing.assert_array_equal(np.asarray(fused[..., :4]), np.asarray(k))
+    np.testing.assert_array_equal(np.asarray(fused[..., 4:]), np.asarray(v))
+    for dt, w in (("int8", 4), ("int4", 2)):
+        kq, vq = quantize_kv_vectors(k, dt), quantize_kv_vectors(v, dt)
+        vals, ks, vs = fuse_kv(kq, vq)
+        assert vals.shape == (3, 2, 8, 2 * w) and vals.dtype == jnp.int8
+        assert ks.shape == vs.shape == (3, 2, 8, 1)
+        for got, want in zip(
+            jax.tree.leaves(split_kv((vals, ks, vs))),
+            jax.tree.leaves((kq, vq)),
+        ):
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 # One page of every head that fits: the folded decode kernel. Rows of
@@ -388,17 +357,14 @@ def test_folded_paged_kernel_matches_oracle(shape, pool, split, ragged_left):
     )
     key = jax.random.PRNGKey(kvh * hd + pps)
     q = jax.random.normal(key, (b, kvh, g, hd))
-    kp = jax.random.normal(jax.random.fold_in(key, 1), (npages, kvh, page, hd))
-    vp = jax.random.normal(jax.random.fold_in(key, 2), (npages, kvh, page, hd))
-    if pool != "native":
-        kp, vp = quantize_kv_vectors(kp), quantize_kv_vectors(vp)
+    kv = _pool(key, npages, kvh, page, hd, pool != "native")
     # Row 0's window starts inside its second page (the first is dead
     # from the left), row 2's on its own last position.
     vf = jnp.asarray([page + 7, 5, page, 0, 0], jnp.int32) if ragged_left else None
     idx = jnp.asarray(index)
-    ref = paged_attention_reference(q, kp, vp, table, idx, vf)
+    ref = paged_attention_reference(q, kv, table, idx, vf)
     out = paged_attention(
-        q, kp, vp, table, idx, vf, prefer="pallas", split=split
+        q, kv, table, idx, vf, prefer="pallas", split=split
     )
     live = index >= 0
     np.testing.assert_allclose(
@@ -407,18 +373,18 @@ def test_folded_paged_kernel_matches_oracle(shape, pool, split, ragged_left):
     assert np.isfinite(np.asarray(out)).all()  # the dead row: finite
 
 
-#: slots, kv_heads, head_dim, pages a slot, page, itemsize, scale planes
-#: -> heads a grid step. The three cells' deployments (PERF.md section
-#: 4) take every head; an int8 pool at 1024-position pages halves by the
-#: same sum.
+#: kv_heads, page, the fused row's width (2 x head_dim), itemsize, scale
+#: planes -> heads a grid step. The three cells' deployments (PERF.md
+#: section 4) take every head; an int8 pool at 1024-position pages
+#: halves by the same sum.
 _HEADS_PER_STEP = {
-    "cgpt1b3_batchgen": ((16, 128, 128, 2, False), 16),
-    "gpt2xl_doc": ((25, 128, 64, 2, False), 25),
-    "gpt2xl_chat": ((25, 128, 64, 2, False), 25),
-    "int8-p1024-hd128": ((16, 1024, 128, 1, True), 8),
-    "int8-p1024-hd64": ((25, 1024, 64, 1, True), 5),
-    "tp4-shard-of-16": ((4, 128, 128, 2, False), 4),
-    "prime-heads-too-wide": ((7, 4096, 128, 2, False), 1),
+    "cgpt1b3_batchgen": ((16, 128, 256, 2, False), 16),
+    "gpt2xl_doc": ((25, 128, 128, 2, False), 25),
+    "gpt2xl_chat": ((25, 128, 128, 2, False), 25),
+    "int8-p1024-hd128": ((16, 1024, 256, 1, True), 8),
+    "int8-p1024-hd64": ((25, 1024, 128, 1, True), 5),
+    "tp4-shard-of-16": ((4, 128, 256, 2, False), 4),
+    "prime-heads-too-wide": ((7, 4096, 256, 2, False), 1),
 }
 
 
@@ -438,8 +404,8 @@ def test_decode_heads_per_step_is_derived_from_the_operands(case):
     # unless not even one head fits, which the kernel then tries anyway.
     assert DECODE_STEP_VMEM_BUDGET == 8 * 2 ** 20
     assert used <= DECODE_STEP_VMEM_BUDGET or heads == 1
-    # The K and V blocks alone (double-buffered) are most of it.
-    blocks = 2 * 2 * heads * page * width * itemsize
+    # The fused block alone (double-buffered) is most of it.
+    blocks = 2 * heads * page * width * itemsize
     assert blocks <= used
     # The next divisor up would not have fit.
     bigger = [h for h in range(heads + 1, kvh + 1) if kvh % h == 0]
@@ -456,9 +422,9 @@ def test_paged_decode_books_heads_per_step_and_split(rng):
 
     b, kvh, g, hd, page, npages = 1, 6, 1, 64, 128, 4
     q = jax.random.normal(rng, (b, kvh, g, hd))
-    kp = jax.random.normal(jax.random.fold_in(rng, 1), (npages, kvh, page, hd))
+    kv = _pool(rng, npages, kvh, page, hd)
     table = jnp.asarray([[2, 1]], jnp.int32)
-    paged_attention(q, kp, kp, table, 130, prefer="pallas", split=2)
+    paged_attention(q, kv, table, 130, prefer="pallas", split=2)
     books = kernel_dispatch_stats()["paged_decode"]
     assert (books["heads_per_step"], books["split"]) == (6.0, 2.0)
     assert books["last"] == 1.0
@@ -469,13 +435,12 @@ def test_paged_kernel_unsupported_page_size_raises_when_forced(rng):
     # forced prefer="pallas" raises instead of serving it silently.
     b, kvh, g, hd, page, npages = 1, 2, 1, 64, 16, 8
     q = jax.random.normal(rng, (b, kvh, g, hd))
-    kp = jax.random.normal(jax.random.fold_in(rng, 1), (npages, kvh, page, hd))
-    vp = jax.random.normal(jax.random.fold_in(rng, 2), (npages, kvh, page, hd))
+    kv = _pool(rng, npages, kvh, page, hd)
     table = jnp.asarray([[2, 5, 1]], jnp.int32)
     with pytest.raises(ValueError, match="page_size 16"):
-        paged_attention(q, kp, vp, table, 30, prefer="pallas")
-    out = paged_attention(q, kp, vp, table, 30)
-    ref = paged_attention_reference(q, kp, vp, table, 30)
+        paged_attention(q, kv, table, 30, prefer="pallas")
+    out = paged_attention(q, kv, table, 30)
+    ref = paged_attention_reference(q, kv, table, 30)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-6)
 
 
@@ -493,7 +458,7 @@ def test_insert_prefill_pages_roundtrip(rng):
     assert (np.asarray(pool)[[0, 1, 3, 5, 6, 8, 9]] == 0).all()
 
 
-# -- the per-token pool write --------------------------------------------------
+# -- the pool writes: K on lanes [0, hd), V on [hd, 2hd), bit for bit ---------
 
 
 def _scatter_oracle(pool, new, phys, off):
@@ -505,17 +470,45 @@ def _scatter_oracle(pool, new, phys, off):
     )
 
 
+def _two_planes(kv_dtype, npages, kvh, page, hd):
+    """A block's K and V as the TWO planes a pool held until PR 30
+    (arrays, or (values, scales) pairs): what the fused plane's two
+    lane halves must equal after every write."""
+
+    def fresh(seed):
+        vals = jax.random.normal(
+            jax.random.PRNGKey(seed), (npages, kvh, page, hd)
+        )
+        if kv_dtype == "native":
+            return vals
+        return quantize_kv_vectors(vals, kv_dtype)
+
+    return fresh(1), fresh(2)
+
+
+def _assert_halves_equal(got_pool, want_k, want_v):
+    """``got_pool``'s lanes [0, w) == ``want_k`` and [w, 2w) ==
+    ``want_v``, every plane, dtype and bit."""
+    for got, want in zip(
+        jax.tree.leaves(split_kv(got_pool)),
+        jax.tree.leaves((want_k, want_v)),
+    ):
+        assert got.dtype == want.dtype and got.shape == want.shape
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 @pytest.mark.parametrize("mode", ["decode", "verify"])
 @pytest.mark.parametrize("kv_dtype", ["native", "int8", "int4"])
 @pytest.mark.parametrize("hd", [16, 128])
 def test_paged_write_equals_scatter_oracle(rng, mode, kv_dtype, hd):
-    """``decode_step_paged`` / ``verify_chunk_paged`` leave the pools
-    bit-equal to the old scatter's: distinct rows, a dead row
-    (``idx < 0``) whose table maps real pages landing on the trash
-    page, two rows writing one physical page, a verify chunk crossing
-    a page edge — native pools and int8 / int4-packed (values, scales)
-    pairs, at a row width under a lane tile (the row loop) and at a
-    whole one (the head-indexed scatter)."""
+    """``decode_step_paged`` / ``verify_chunk_paged`` leave the fused
+    plane's K lanes and V lanes bit-equal to what the old scatter left
+    in a K plane and a V plane: distinct rows, a dead row (``idx < 0``)
+    whose table maps real pages landing on the trash page, two rows
+    writing one physical page, a verify chunk crossing a page edge —
+    native pools and int8 / int4-packed (values, K scales, V scales)
+    triples, at a fused row under a lane tile (the row loop) and at
+    whole ones (the head-indexed scatter)."""
     heads, kvh, page, npages = 4, 2, 8, 9
     dim = heads * hd
     kc = 1 if mode == "decode" else 5
@@ -529,45 +522,105 @@ def test_paged_write_equals_scatter_oracle(rng, mode, kv_dtype, hd):
     table = jnp.asarray([[5, 1], [2, 6], [7, 8], [3, 5]], jnp.int32)
     index = jnp.asarray([1, page - 2, -1, page + 2], jnp.int32)
 
-    def fresh(seed):
-        vals = jax.random.normal(
-            jax.random.PRNGKey(seed), (npages, kvh, page, hd)
-        )
-        if kv_dtype == "native":
-            return vals
-        return quantize_kv_vectors(vals, kv_dtype)
-
-    k_pool, v_pool = fresh(1), fresh(2)
+    k_plane, v_plane = _two_planes(kv_dtype, npages, kvh, page, hd)
+    pool = fuse_kv(k_plane, v_plane)
     if mode == "decode":
-        _, k_new, v_new = attn.apply(
-            params, x, k_pool, v_pool, table, index, None, "xla",
+        _, got = attn.apply(
+            params, x, pool, table, index, None, "xla",
             method="decode_step_paged",
         )
     else:
-        _, k_new, v_new = attn.apply(
-            params, x, k_pool, v_pool, table, index, "xla",
+        _, got = attn.apply(
+            params, x, pool, table, index, "xla",
             method="verify_chunk_paged",
         )
+    assert jax.tree.structure(got) == jax.tree.structure(pool)
     _, k, v = attn.apply(params, x, method="_project")  # (b, kvh, K, hd)
     pos = jnp.maximum(index, 0)[:, None] + jnp.arange(kc)[None, :]
     phys = jnp.take_along_axis(table, pos // page, axis=1)
     phys = jnp.where((index >= 0)[:, None], phys, 0)
     off = pos % page
-    for pool, new, got in ((k_pool, k, k_new), (v_pool, v, v_new)):
-        if kv_dtype == "native":
-            pool, new, got = (pool,), (new,), (got,)
-        else:
+    want = []
+    for plane, new in ((k_plane, k), (v_plane, v)):
+        if kv_dtype != "native":
             new = quantize_kv_vectors(new, kv_dtype)
-        for member, t, g in zip(pool, new, got):
-            want = _scatter_oracle(member, t, phys, off)
-            assert g.dtype == member.dtype and g.shape == member.shape
-            np.testing.assert_array_equal(np.asarray(g), np.asarray(want))
-            # The dead row's own pages are untouched; its write sits on
-            # the trash page.
-            np.testing.assert_array_equal(
-                np.asarray(g)[7:], np.asarray(member)[7:]
-            )
-            assert (np.asarray(g)[0] != np.asarray(member)[0]).any()
+        want.append(jax.tree.map(
+            lambda m, t: _scatter_oracle(m, t, phys, off), plane, new
+        ))
+    _assert_halves_equal(got, *want)
+    for g, before in zip(jax.tree.leaves(got), jax.tree.leaves(pool)):
+        # The dead row's own pages are untouched; its write sits on the
+        # trash page.
+        np.testing.assert_array_equal(
+            np.asarray(g)[7:], np.asarray(before)[7:]
+        )
+        assert (np.asarray(g)[0] != np.asarray(before)[0]).any()
+
+
+@pytest.mark.parametrize("mode", ["chunk", "insert"])
+@pytest.mark.parametrize("kv_dtype", ["native", "int8", "int4"])
+@pytest.mark.parametrize("hd", [16, 64])
+def test_paged_page_writes_land_k_and_v_on_their_lanes(
+    rng, mode, kv_dtype, hd
+):
+    """The page-granular writers — ``prefill_chunk_paged`` (a chunk of
+    two pages at a page-aligned ``pos0`` inside a pow2-padded window)
+    and ``insert_prefill_pages`` over a whole prompt's rows (ragged
+    last page, zero padded) — leave each lane half of every page they
+    name equal to what the same write left in a K plane and a V plane,
+    and touch no other page."""
+    heads, kvh, page, npages = 4, 2, 8, 9
+    dim = heads * hd
+    attn = CausalSelfAttention(dim, heads, kv_heads=kvh)
+    kx, kp = jax.random.split(rng)
+    k_plane, v_plane = _two_planes(kv_dtype, npages, kvh, page, hd)
+    pool = fuse_kv(k_plane, v_plane)
+    if mode == "chunk":
+        x = jax.random.normal(kx, (1, 2 * page, dim))
+        params = attn.init(kp, x)
+        pages = jnp.asarray([3, 5, 7, 0], jnp.int32)
+        _, got = attn.apply(
+            params, x, pool, pages, page, method="prefill_chunk_paged"
+        )
+        _, k, v = attn.apply(params, x, method="_project")
+        written = [5, 7]
+
+        def write(plane, t):  # (1, kvh, 2 * page, w) -> pages 5 and 7
+            t = jnp.swapaxes(t[0].reshape(kvh, 2, page, -1), 0, 1)
+            return plane.at[jnp.asarray(written)].set(t.astype(plane.dtype))
+    else:
+        x = jax.random.normal(kx, (1, 2 * page + 3, dim))
+        params = attn.init(kp, x)
+        quant = False if kv_dtype == "native" else kv_dtype
+        _, ck, cv = attn.apply(
+            params, x, 3 * page, None, quant, method="prefill"
+        )
+        written = [4, 1, 6]
+        pages = jnp.asarray(written, jnp.int32)
+        # The fused pool is donated to the insert: compare with copies.
+        got = jax.tree.map(
+            lambda plane, rows: insert_prefill_pages(
+                jnp.array(plane), pages, rows
+            ),
+            pool, fuse_kv(ck, cv),
+        )
+        k, v = ck, cv
+
+        def write(plane, t):
+            return insert_prefill_pages(jnp.array(plane), pages, t)
+
+    want = []
+    for plane, new in ((k_plane, k), (v_plane, v)):
+        if mode == "chunk" and kv_dtype != "native":
+            new = quantize_kv_vectors(new, kv_dtype)
+        want.append(jax.tree.map(write, plane, new))
+    _assert_halves_equal(got, *want)
+    untouched = [p for p in range(npages) if p not in written]
+    for g, before in zip(jax.tree.leaves(got), jax.tree.leaves(pool)):
+        np.testing.assert_array_equal(
+            np.asarray(g)[untouched], np.asarray(before)[untouched]
+        )
+        assert (np.asarray(g)[written] != np.asarray(before)[written]).any()
 
 
 @pytest.mark.parametrize("w", [4, 128])
@@ -910,7 +963,8 @@ def test_paged_validation(lm_setup):
     q = ContinuousBatcher(
         lm, variables, slots=2, kv_layout="paged", kv_cache_dtype="int8"
     )
-    assert isinstance(q._caches[0][0], tuple)  # (int8 values, f32 scales)
+    # (int8 values, f32 K scales, f32 V scales)
+    assert isinstance(q._caches[0], tuple) and len(q._caches[0]) == 3
     bat = ContinuousBatcher(
         lm, variables, slots=2, kv_layout="paged", page_size=16,
         pool_pages=2,  # one allocatable page = 16 positions

@@ -127,7 +127,7 @@ def test_int8_paged_prefix_cache_reuses_quantized_pages(lm_setup):
     # The shared pages' SCALE plane is live (registered pages hold real
     # quantized prompt K/V, not zeros) — the reuse-stays-exact
     # precondition.
-    k_scales = np.asarray(bat._caches[0][0][1])
+    k_scales = np.asarray(bat._caches[0][1])  # (values, K scales, V scales)
     shared = [p for p in range(1, bat._pool_pages)
               if p in bat._pager._key_of]
     assert shared and all(k_scales[p].any() for p in shared)

@@ -178,11 +178,10 @@ def test_kill_speculative_int8(lm_setup, draft_setup, sim_mesh, page_size):
     assert st["cache_bytes_per_device"] * 2 == st["cache_bytes"]
     # Both pytree members (int8 values AND f32 scales) head-shard to
     # exactly half per device after the reshard.
-    for ck, cv in bat._caches:
-        for member in (*ck, *cv) if isinstance(ck, tuple) else (ck, cv):
-            from adapt_tpu.utils.profiling import device_local_nbytes
+    for member in jax.tree.leaves(bat._caches):
+        from adapt_tpu.utils.profiling import device_local_nbytes
 
-            assert device_local_nbytes(member) * 2 == member.nbytes
+        assert device_local_nbytes(member) * 2 == member.nbytes
     for r, (p, s) in ((r1, (PROMPTS[0], 9)), (r2, (PROMPTS[1], 7))):
         np.testing.assert_array_equal(
             out[r],
@@ -433,10 +432,8 @@ def test_post_reshard_invariants(lm_setup, sim_mesh, quant):
     st = bat.stats()
     assert st["tp"] == 2
     assert st["cache_bytes_per_device"] * 2 == st["cache_bytes"]
-    for ck, cv in bat._caches:
-        members = (*ck, *cv) if isinstance(ck, tuple) else (ck, cv)
-        for member in members:
-            assert device_local_nbytes(member) * 2 == member.nbytes
+    for member in jax.tree.leaves(bat._caches):
+        assert device_local_nbytes(member) * 2 == member.nbytes
     bat.tick()  # settle: first post-recovery tick re-uploads the table
     h0 = bat.stats()["h2d_transfers"]
     for _ in range(3):

@@ -124,15 +124,18 @@ def test_head_tiles():
 
 
 def _rand_handoff(rng, quantized=False, blocks=2, n=3, kvh=4, hd=4):
-    def member():
+    def block():
+        # A block's pool pages: fused K|V rows (2 * hd lanes), beside
+        # them the K and the V scale planes when quantized.
         if quantized:
             return (
-                rng.randint(-127, 127, size=(n, kvh, PAGE, hd)).astype(
+                rng.randint(-127, 127, size=(n, kvh, PAGE, 2 * hd)).astype(
                     np.int8
                 ),
                 rng.rand(n, kvh, PAGE, 1).astype(np.float32),
+                rng.rand(n, kvh, PAGE, 1).astype(np.float32),
             )
-        return rng.rand(n, kvh, PAGE, hd).astype(np.float32)
+        return rng.rand(n, kvh, PAGE, 2 * hd).astype(np.float32)
 
     return KVHandoff(
         req_id=7,
@@ -140,7 +143,7 @@ def _rand_handoff(rng, quantized=False, blocks=2, n=3, kvh=4, hd=4):
         page_size=PAGE,
         n_pages=n,
         quantized=quantized,
-        blocks=[(member(), member()) for _ in range(blocks)],
+        blocks=[block() for _ in range(blocks)],
     )
 
 
@@ -157,20 +160,15 @@ def test_ranged_handoff_wire_roundtrip(quantized):
     msg = pack_handoff(h, head_ranges=ranges)
     meta = json.loads(msg.page_annex.decode())
     assert meta["head_ranges"] == [[0, 2], [2, 4]]
-    leaves = 2 * 2 * (2 if quantized else 1)  # blocks * (K,V) * planes
+    leaves = 2 * (3 if quantized else 1)  # blocks * planes of a pool
     assert len(meta["frame_lens"]) == 1 + leaves * 2
     wire = bytearray(b"".join(frame_parts(msg)))
     got = unpack_handoff(parse_frame(memoryview(wire)[8:]))
     assert got.n_pages == h.n_pages and got.quantized == quantized
     np.testing.assert_array_equal(got.prompt, h.prompt)
-    for (hk, hv), (gk, gv) in zip(h.blocks, got.blocks):
-        if quantized:
-            for (a, b), (c, d) in ((hk, gk), (hv, gv)):
-                np.testing.assert_array_equal(a, c)
-                np.testing.assert_array_equal(b, d)
-        else:
-            np.testing.assert_array_equal(hk, gk)
-            np.testing.assert_array_equal(hv, gv)
+    assert jax.tree.structure(got.blocks) == jax.tree.structure(h.blocks)
+    for a, b in zip(jax.tree.leaves(h.blocks), jax.tree.leaves(got.blocks)):
+        np.testing.assert_array_equal(a, b)
 
 
 def test_ranged_handoff_bad_tiling_raises():

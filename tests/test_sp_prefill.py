@@ -303,12 +303,9 @@ def test_sp_tp_composed_pages_match(lm_setup, sim_mesh):
         if bat.slots[0].req is not None and bat.slots[0].pf_done < 0:
             break
     owned = bat._pager.owned(0)[:5]
-    ref = [
-        jax.tree.map(
-            lambda pool: np.asarray(pool[np.asarray(owned)]), pair
-        )
-        for pair in bat._caches
-    ]
+    ref = jax.tree.map(
+        lambda pool: np.asarray(pool[np.asarray(owned)]), bat._caches
+    )
     pf = SPPrefiller(
         lm, variables, build_sp_mesh(2, 2), PAGE, tp_axis="tp",
         name="ttp",
