@@ -23,11 +23,15 @@ collectives).
 
 from __future__ import annotations
 
+import dataclasses
+import functools
 import math
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+
+from adapt_tpu.ops.dispatch import on_tpu, pallas_interpret, resolve_prefer
 
 
 def _expert_params(mod: nn.Module, d: int, e: int, hidden: int):
@@ -139,14 +143,12 @@ class MoEDecoderMlp(nn.Module):
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
-        if self.top_k not in (1, 2):
-            raise ValueError(f"top_k must be 1 or 2, got {self.top_k}")
-        if self.top_k > self.num_experts:
-            # A third pick over a fully-masked gate row would re-select
+        if not 1 <= self.top_k <= self.num_experts:
+            # A pick over a fully-masked gate row would re-select
             # expert 0 and silently double its weight.
             raise ValueError(
-                f"top_k {self.top_k} exceeds num_experts "
-                f"{self.num_experts}"
+                f"top_k {self.top_k} outside [1, num_experts="
+                f"{self.num_experts}]"
             )
         b, s, d = x.shape
         tokens = x.reshape(b * s, d)
@@ -174,6 +176,209 @@ class MoEDecoderMlp(nn.Module):
         out = jnp.einsum(
             "ned,ne->nd", out_e, combine.astype(self.dtype)
         )
+        return out.reshape(b, s, d).astype(x.dtype)
+
+
+@dataclasses.dataclass(frozen=True)
+class ExpertSpec:
+    """One expert layer as a model's configuration states it: how a
+    token is routed over ``num_experts``, and WHICH of them this chip
+    holds."""
+
+    num_experts: int  # the router's width: every expert of the layer
+    hidden_dim: int  # one expert's gated-SiLU width
+    top_k: int
+    #: ``"softmax"`` over the router's logits, or ``"sigmoid"`` of each.
+    score: str = "softmax"
+    #: Divide the chosen experts' scores by their sum.
+    normalize: bool = False
+    #: ``routed_scaling_factor``: multiplies the (normalised) weights.
+    scale: float = 1.0
+    #: A per-expert bias added to the scores for the CHOICE only (the
+    #: weights do not carry it): DeepSeek-V3's ``e_score_correction_bias``.
+    select_bias: bool = False
+    #: Width of the one shared expert every token also passes through;
+    #: None: no shared expert.
+    shared_dim: int | None = None
+    #: ``(first, count)``: the experts held here, one chip's share of an
+    #: expert-parallel layer. The layer routes over all ``num_experts``
+    #: and adds only its own experts' terms of the sum (plus the shared
+    #: expert); what the absent experts would add is left out — no code
+    #: stands in for the other chips or their exchange. None: all.
+    held: tuple[int, int] | None = None
+
+    def __post_init__(self):
+        if not 1 <= self.top_k <= self.num_experts:
+            raise ValueError(
+                f"top_k {self.top_k} outside [1, num_experts="
+                f"{self.num_experts}]"
+            )
+        if self.score not in ("softmax", "sigmoid"):
+            raise ValueError(f"score={self.score!r}")
+        first, count = self.held_range
+        if first < 0 or count < 1 or first + count > self.num_experts:
+            raise ValueError(
+                f"held={self.held}: outside the layer's "
+                f"{self.num_experts} experts"
+            )
+
+    @property
+    def held_range(self) -> tuple[int, int]:
+        return self.held or (0, self.num_experts)
+
+
+def route(spec: ExpertSpec, logits: jax.Array, bias: jax.Array | None):
+    """Router logits (n, E) float32 -> the chosen experts (n, k) int32
+    and their weights (n, k) float32. The choice is by score plus
+    ``bias`` (where the spec has one), the weights by score alone."""
+    if spec.score == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    else:
+        scores = jax.nn.softmax(logits, axis=-1)
+    chosen = scores if bias is None else scores + bias
+    _, idx = jax.lax.top_k(chosen, spec.top_k)
+    w = jnp.take_along_axis(scores, idx, axis=-1)
+    if spec.normalize:
+        w = w / jnp.sum(w, axis=-1, keepdims=True)
+    return idx, w * spec.scale
+
+
+#: Rows a grid step of the grouped kernel covers, and the most
+#: columns of a weight tile in each direction (a 1024 x 2048 bf16 tile
+#: is 4 MiB, double-buffered inside the 16 MiB a kernel may use):
+#: picked on the chip, PERF.md section 6 (PR 31).
+_GMM_ROWS = 128
+_GMM_TILE = (1024, 2048)
+
+
+def _tile(dim: int, cap: int) -> int | None:
+    """The largest multiple of 128 that divides ``dim`` and is at most
+    ``cap``; None where there is none."""
+    for t in range(min(cap, dim) // 128 * 128, 0, -128):
+        if dim % t == 0:
+            return t
+    return None
+
+
+def grouped_matmul(x, w, sizes, prefer=None, tiling=None):
+    """Rows of ``x`` (m, k), sorted by group, each against ITS group's
+    matrix of ``w`` (groups, k, n): ``sizes[g]`` rows belong to group
+    ``g``; rows past their sum are nobody's and come back UNDEFINED
+    (zero from one path, what the buffer held from the other: the
+    kernel never visits a row tile that holds no group's rows, and
+    within its last one writes only the groups' own). On a TPU the Pallas
+    grouped matmul JAX ships (``megablox.gmm``: a grid step a (row
+    tile, group) pair that holds rows, so a group's matrix is read once
+    a row tile it touches and not once a row); elsewhere
+    ``jax.lax.ragged_dot``. ``prefer`` as everywhere in ``ops``
+    (``dispatch.resolve_prefer``)."""
+    m, k = x.shape
+    n = w.shape[2]
+    tiles = tiling or (_GMM_ROWS, _tile(k, _GMM_TILE[0]), _tile(n, _GMM_TILE[1]))
+    unsupported = None if all(tiles[1:]) else (
+        f"widths ({k}, {n}) have no tile that is a multiple of 128"
+    )
+    if not resolve_prefer("expert_product", prefer, unsupported, on_tpu()):
+        return jax.lax.ragged_dot(x, w, sizes)
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+
+    pad = -m % tiles[0]
+    return gmm(
+        jnp.pad(x, ((0, pad), (0, 0))), w, sizes, x.dtype, tuple(tiles),
+        interpret=pallas_interpret(),
+    )[:m]
+
+
+@functools.partial(jax.jit, static_argnames=("prefer",))
+def expert_product(x, w_gate, w_up, w_down, sizes, prefer=None):
+    """The grouped product of the experts held: rows of ``x`` (m, d),
+    sorted by expert, against each expert's gated SiLU MLP —
+    ``sizes[e]`` rows belong to expert ``e``, rows past their sum are
+    nobody's and come back zero. Three :func:`grouped_matmul`: every
+    row meets ONE expert's matrices. A jitted function of its own so
+    that a device trace shows its operations under one name."""
+    gate = grouped_matmul(x, w_gate, sizes, prefer)
+    up = grouped_matmul(x, w_up, sizes, prefer)
+    out = grouped_matmul(jax.nn.silu(gate) * up, w_down, sizes, prefer)
+    mine = jnp.arange(x.shape[0])[:, None] < jnp.sum(sizes)
+    return jnp.where(mine, out, 0)
+
+
+class RoutedExperts(nn.Module):
+    """The expert layer of the serving paths: route every token over
+    all of the layer's experts (:func:`route`), sort the (token,
+    expert) assignments that fell on the experts HELD by expert, one
+    grouped product over them (:func:`expert_product`), weighted
+    scatter-add back, plus the shared expert. Dropless and
+    token-independent — a token's output is a function of its own
+    hidden state alone — so prefill, chunked prefill, decode and
+    verify agree, as :class:`MoEDecoderMlp`'s contract says.
+
+    Sows ``intermediates/held_tokens``: (n, held) int32, how many of
+    token n's assignments each held expert got (0 or 1). The batcher
+    sums it over its live rows (``moe.tokens.*`` counters)."""
+
+    spec: ExpertSpec
+    dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        spec = self.spec
+        b, s, d = x.shape
+        n, k, hid = b * s, spec.top_k, spec.hidden_dim
+        first, held = spec.held_range
+        init = nn.initializers.lecun_normal()
+        router = self.param("router", init, (d, spec.num_experts),
+                            jnp.float32)
+        bias = (
+            self.param("router_bias", nn.initializers.zeros,
+                       (spec.num_experts,), jnp.float32)
+            if spec.select_bias else None
+        )
+        # (E, in, out): fan-in is dim -2, as for a Dense kernel.
+        stacked = nn.initializers.lecun_normal(in_axis=-2, out_axis=-1)
+        w_gate = self.param("w_gate", stacked, (held, d, hid), jnp.float32)
+        w_up = self.param("w_up", stacked, (held, d, hid), jnp.float32)
+        w_down = self.param("w_down", stacked, (held, hid, d), jnp.float32)
+
+        tokens = x.reshape(n, d)
+        # Scores in float32 (a near-tie decides which experts run).
+        idx, w = route(
+            spec, tokens.astype(jnp.float32) @ router.astype(jnp.float32),
+            None if bias is None else bias.astype(jnp.float32),
+        )
+        # Assignments on experts held elsewhere take the sentinel id
+        # ``held``: they sort past every group and meet no matrix.
+        local = idx - first
+        eid = jnp.where((local >= 0) & (local < held), local, held)
+        self.sow(
+            "intermediates", "held_tokens",
+            jnp.sum(eid[:, :, None] == jnp.arange(held), axis=1,
+                    dtype=jnp.int32),
+        )
+        flat = eid.reshape(n * k)
+        order = jnp.argsort(flat, stable=True)
+        sizes = jnp.sum(
+            flat[:, None] == jnp.arange(held), axis=0, dtype=jnp.int32
+        )
+        tok = order // k  # the token of each sorted assignment
+        xt = tokens.astype(self.dtype)
+        y = expert_product(
+            xt[tok], w_gate.astype(self.dtype), w_up.astype(self.dtype),
+            w_down.astype(self.dtype), sizes,
+        )
+        y = y.astype(jnp.float32) * w.reshape(n * k)[order][:, None]
+        out = jnp.zeros((n, d), jnp.float32).at[tok].add(y)
+        if spec.shared_dim is not None:
+            def dense(m, name):
+                return nn.Dense(
+                    m, dtype=self.dtype, use_bias=False, name=name
+                )
+
+            out = out + dense(d, "shared_down")(
+                nn.silu(dense(spec.shared_dim, "shared_gate")(xt))
+                * dense(spec.shared_dim, "shared_up")(xt)
+            ).astype(jnp.float32)
         return out.reshape(b, s, d).astype(x.dtype)
 
 

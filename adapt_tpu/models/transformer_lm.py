@@ -25,6 +25,7 @@ the same weights, never a different model.
 from __future__ import annotations
 
 import dataclasses
+from collections.abc import Sequence
 from functools import partial
 
 import flax.linen as nn
@@ -47,7 +48,7 @@ from adapt_tpu.ops.paged_attention import (
     paged_verify_attention,
     pool_values,
 )
-from adapt_tpu.models.moe import MoEDecoderMlp
+from adapt_tpu.models.moe import ExpertSpec, MoEDecoderMlp, RoutedExperts
 from adapt_tpu.ops.quantize import quantize_kv_vectors, unpack_int4
 
 _NEG_INF = -1e30
@@ -62,6 +63,94 @@ def chosen_logprob(logits: jax.Array, tokens: jax.Array) -> jax.Array:
     return jnp.take_along_axis(
         lp, tokens[:, None].astype(jnp.int32), axis=-1
     )[:, 0]
+
+
+@dataclasses.dataclass(frozen=True)
+class BlockSpec:
+    """What ONE decoder block is, read from a model's configuration —
+    the single description the block, its attention, the KV cache
+    (``runtime/paged.cache_groups``) and the TP rules key off. The
+    defaults are the GPT-2 block (pre-LayerNorm, fused biased
+    projections, tanh-GELU two-matrix MLP, ``head_dim = dim // heads``,
+    learned positions outside the block); every other field is a
+    departure some published decoder makes from it."""
+
+    dim: int
+    heads: int
+    mlp_dim: int
+    kv_heads: int | None = None
+    #: Its own number: a model may project to ``heads * head_dim`` wider
+    #: (or narrower) than ``dim``. None: ``dim // heads``.
+    head_dim: int | None = None
+    #: ``"layernorm"`` or ``"rmsnorm"`` (learned scale, no bias).
+    norm: str = "layernorm"
+    norm_eps: float = 1e-6
+    #: False: the norm on each sub-layer's INPUT (``x + f(norm(x))``).
+    #: True: on its OUTPUT (``x + norm(f(x))``).
+    post_norm: bool = False
+    #: RMSNorm over head_dim (learned scale) on q and on k, before any
+    #: rotation.
+    qk_norm: bool = False
+    #: Biases on the attention and MLP projections.
+    bias: bool = True
+    #: ``"gelu"`` (two matrices), ``"gated_silu"`` (gate, up, down),
+    #: ``"experts"`` (``experts`` says which: routed + shared, sorted
+    #: grouped product) or ``"moe_dense"`` (the masked-dense GELU
+    #: mixture, ``moe_experts`` / ``moe_top_k``).
+    mlp: str = "gelu"
+    experts: ExpertSpec | None = None
+    moe_experts: int | None = None
+    moe_top_k: int = 1
+    #: Rotate-half base of this block's q/k rotation; None: no
+    #: rotation (learned positions, or none at all).
+    rope_base: float | None = None
+    #: Attend the previous ``window`` positions only; None: all.
+    window: int | None = None
+
+    def __post_init__(self):
+        if self.head_dim is None and self.dim % self.heads:
+            raise ValueError(
+                f"model dim {self.dim} not divisible by {self.heads} heads"
+            )
+        if self.rope_base is not None and self.attn_head_dim % 2:
+            raise ValueError(
+                f"rope needs an even head_dim, got {self.attn_head_dim}"
+            )
+        kvh = self.kv_heads
+        if kvh is not None:
+            if not 1 <= kvh <= self.heads:
+                raise ValueError(
+                    f"kv_heads {kvh} outside [1, heads={self.heads}]"
+                )
+            if self.heads % kvh:
+                raise ValueError(
+                    f"heads {self.heads} not divisible by kv_heads {kvh}"
+                )
+        if self.window is not None and self.window < 1:
+            raise ValueError(f"window must be >= 1, got {self.window}")
+        if self.norm not in ("layernorm", "rmsnorm"):
+            raise ValueError(f"norm={self.norm!r}")
+        if self.mlp not in ("gelu", "gated_silu", "experts", "moe_dense"):
+            raise ValueError(f"mlp={self.mlp!r}")
+        if (self.mlp == "experts") != (self.experts is not None):
+            raise ValueError("mlp='experts' goes with an ExpertSpec")
+
+    @property
+    def attn_head_dim(self) -> int:
+        return self.head_dim or self.dim // self.heads
+
+    @property
+    def cache_heads(self) -> int:
+        """Head count of the K/V cache: kv_heads under GQA."""
+        return self.kv_heads or self.heads
+
+
+def _norm(kind: str, eps: float, dtype):
+    """A norm module by its spec's ``norm`` and ``norm_eps`` (one
+    definition for blocks and head)."""
+    if kind == "rmsnorm":
+        return nn.RMSNorm(epsilon=eps, dtype=dtype)
+    return nn.LayerNorm(epsilon=eps, dtype=dtype)
 
 
 def apply_rope(x: jax.Array, positions: jax.Array,
@@ -108,60 +197,59 @@ class CausalSelfAttention(nn.Module):
     fold into extra query ROWS over the (b, kv_heads, L, hd) cache, so
     the HBM traffic is the small cache, not a broadcast copy."""
 
-    dim: int
-    heads: int
+    #: What the block is (``BlockSpec``): widths, GQA, ``head_dim``, QK
+    #: norm, the rotation's base (None: no rotation; the cache stores
+    #: POST-rotation K, so every cached decode path works unchanged) and
+    #: the sliding window (decode-side just a dynamic ``valid_from``:
+    #: the kernels need no change, and paged serving can RECYCLE pages
+    #: behind the window; full-sequence forwards band the causal mask).
+    spec: BlockSpec
     dtype: jnp.dtype = jnp.float32
-    kv_heads: int | None = None
-    #: Sliding-window attention (Mistral-style): each position attends
-    #: the previous ``window`` positions only. Decode-side this is just
-    #: a dynamic ``valid_from`` (the kernels need no change, and paged
-    #: serving can RECYCLE pages behind the window); full-sequence
-    #: forwards band the causal mask.
-    window: int | None = None
-    #: Rotary position embeddings: q/k rotate by their LOGICAL position
-    #: (buffer position minus ragged left padding, so padded rows equal
-    #: their solo runs bitwise); the cache stores POST-rotation K, so
-    #: every cached decode path works unchanged.
-    rope: bool = False
 
     def setup(self):
-        if self.dim % self.heads:
-            raise ValueError(
-                f"model dim {self.dim} not divisible by {self.heads} heads"
-            )
-        if self.rope and (self.dim // self.heads) % 2:
-            raise ValueError(
-                f"rope needs an even head_dim, got {self.dim // self.heads}"
-            )
-        head_dim = self.dim // self.heads
-        kvh = self.kv_heads
-        if kvh is not None:
-            if not 1 <= kvh <= self.heads:
-                raise ValueError(
-                    f"kv_heads {kvh} outside [1, heads={self.heads}]"
-                )
-            if self.heads % kvh:
-                raise ValueError(
-                    f"heads {self.heads} not divisible by kv_heads {kvh}"
-                )
+        spec, head_dim = self.spec, self.head_dim
         if self._group == 1:
             # MHA: one fused projection (unchanged param structure).
             self.qkv = nn.DenseGeneral(
-                (3, self.heads, head_dim), dtype=self.dtype, name="qkv"
+                (3, self.heads, head_dim), dtype=self.dtype, name="qkv",
+                use_bias=spec.bias,
             )
         else:
             self.q_proj = nn.DenseGeneral(
-                (self.heads, head_dim), dtype=self.dtype, name="q"
+                (self.heads, head_dim), dtype=self.dtype, name="q",
+                use_bias=spec.bias,
             )
             self.kv_proj = nn.DenseGeneral(
-                (2, kvh, head_dim), dtype=self.dtype, name="kv"
+                (2, self.cache_heads, head_dim), dtype=self.dtype,
+                name="kv", use_bias=spec.bias,
             )
-        self.out = nn.Dense(self.dim, dtype=self.dtype, name="out")
+        if spec.qk_norm:
+            self.q_norm = nn.RMSNorm(epsilon=spec.norm_eps, dtype=self.dtype)
+            self.k_norm = nn.RMSNorm(epsilon=spec.norm_eps, dtype=self.dtype)
+        self.out = nn.Dense(
+            self.dim, dtype=self.dtype, name="out", use_bias=spec.bias
+        )
+
+    @property
+    def dim(self) -> int:
+        return self.spec.dim
+
+    @property
+    def heads(self) -> int:
+        return self.spec.heads
+
+    @property
+    def window(self) -> int | None:
+        return self.spec.window
+
+    @property
+    def rope(self) -> bool:
+        return self.spec.rope_base is not None
 
     @property
     def _group(self) -> int:
         """Query heads per KV head (1 = plain MHA)."""
-        return self.heads // (self.kv_heads or self.heads)
+        return self.heads // self.cache_heads
 
     @property
     def cache_heads(self) -> int:
@@ -169,11 +257,11 @@ class CausalSelfAttention(nn.Module):
         otherwise. External cache allocators MUST use this (not
         ``heads``) or GQA models get heads-sized buffers and shape
         errors at runtime."""
-        return self.kv_heads or self.heads
+        return self.spec.cache_heads
 
     @property
     def head_dim(self) -> int:
-        return self.dim // self.heads
+        return self.spec.attn_head_dim
 
     def _project(self, x):
         """-> q (b, h, s, hd); k, v (b, kv_h, s, hd) (kv_h == h for
@@ -184,8 +272,18 @@ class CausalSelfAttention(nn.Module):
         else:
             q = self.q_proj(x)  # (b, s, h, hd)
             k, v = jnp.moveaxis(self.kv_proj(x), 2, 0)  # (b, s, kv_h, hd)
+        if self.spec.qk_norm:  # over head_dim, before any rotation
+            q, k = self.q_norm(q), self.k_norm(k)
         # -> (b, heads-axis, s, hd)
         return tuple(jnp.swapaxes(t, 1, 2) for t in (q, k, v))
+
+    def _merge_heads(self, o, s):
+        """(b, h, s, hd) attention output -> (b, s, h * hd), the out
+        projection's input (h * hd is ``dim`` only where head_dim is
+        ``dim // heads``)."""
+        return jnp.swapaxes(o, 1, 2).reshape(
+            o.shape[0], s, self.heads * self.head_dim
+        )
 
     def _repeat_kv(self, t):
         """Expand (b, kv_h, s, hd) -> (b, h, s, hd) for the full-sequence
@@ -212,9 +310,10 @@ class CausalSelfAttention(nn.Module):
         """Rotate q and k by ``positions`` when rope is on (no-op
         otherwise). Runs BEFORE GQA group folding / caching, so the
         cache holds post-rotation K."""
-        if not self.rope:
+        base = self.spec.rope_base
+        if base is None:
             return q, k
-        return apply_rope(q, positions), apply_rope(k, positions)
+        return apply_rope(q, positions, base), apply_rope(k, positions, base)
 
     def __call__(self, x):
         b, s, d = x.shape
@@ -224,7 +323,7 @@ class CausalSelfAttention(nn.Module):
             q, self._repeat_kv(k), self._repeat_kv(v), causal=True,
             window=self.window,
         )
-        return self.out(jnp.swapaxes(o, 1, 2).reshape(b, s, d))
+        return self.out(self._merge_heads(o, s))
 
     def _window_from(self, index, b, valid_from):
         """Effective ``valid_from`` for cached decode under a sliding
@@ -322,7 +421,7 @@ class CausalSelfAttention(nn.Module):
             causal=True, valid_from=valid_from, window=self.window,
         )
         pad = ((0, 0), (0, 0), (0, max_len - s), (0, 0))
-        out = self.out(jnp.swapaxes(o, 1, 2).reshape(b, s, d))
+        out = self.out(self._merge_heads(o, s))
         if quantize_cache:
             dt = "int4" if quantize_cache == "int4" else "int8"
             kv_, ks = self._quantize_kv(k, dt)
@@ -383,8 +482,7 @@ class CausalSelfAttention(nn.Module):
             split=split,
         ).astype(x_t.dtype)
         o = self._ungroup_o(o, 1)  # (b, h, 1, hd)
-        o = jnp.swapaxes(o, 1, 2).reshape(b, 1, self.dim)
-        return self.out(o), cache_k, cache_v
+        return self.out(self._merge_heads(o, 1)), cache_k, cache_v
 
 
     def decode_step_paged(
@@ -440,8 +538,7 @@ class CausalSelfAttention(nn.Module):
             split=split, head_shard=head_shard,
         ).astype(x_t.dtype)
         o = self._ungroup_o(o, 1)
-        o = jnp.swapaxes(o, 1, 2).reshape(b, 1, self.dim)
-        return self.out(o), pool
+        return self.out(self._merge_heads(o, 1)), pool
 
     def prefill_chunk_paged(
         self, x, pool, pages, pos0, attn_impl=None, head_shard=None,
@@ -487,8 +584,7 @@ class CausalSelfAttention(nn.Module):
             window=self.window, head_shard=head_shard,
         ).astype(x.dtype)
         o = self._ungroup_o(o, c)  # (1, h, C, hd)
-        o = jnp.swapaxes(o, 1, 2).reshape(b, c, self.dim)
-        return self.out(o), pool
+        return self.out(self._merge_heads(o, c)), pool
 
     def prefill_sp(self, x, gather, quantize_cache=False, constrain=None):
         """SEQUENCE-PARALLEL prefill body: the whole span's attention
@@ -576,8 +672,7 @@ class CausalSelfAttention(nn.Module):
             "bhqk,bhkd->bhqd", p, vv_.astype(jnp.float32)
         ).astype(q.dtype))
         o = self._ungroup_o(o, s)  # (1, h, S, hd)
-        o = jnp.swapaxes(o, 1, 2).reshape(b, s, d)
-        return self.out(o), ck, cv
+        return self.out(self._merge_heads(o, s)), ck, cv
 
     def verify_chunk(self, x, cache_k, cache_v, index, tree_tail=0):
         """Append a CHUNK of ``K`` tokens at positions
@@ -627,8 +722,7 @@ class CausalSelfAttention(nn.Module):
             tree_tail=tree_tail,
         ).astype(x.dtype)
         o = self._ungroup_o(o, kc)  # (b, h, K, hd)
-        o = jnp.swapaxes(o, 1, 2).reshape(b, kc, self.dim)
-        return self.out(o), cache_k, cache_v
+        return self.out(self._merge_heads(o, kc)), cache_k, cache_v
 
     def verify_chunk_paged(
         self, x, pool, page_table, index, attn_impl=None,
@@ -676,132 +770,154 @@ class CausalSelfAttention(nn.Module):
             head_shard=head_shard,
         ).astype(x.dtype)
         o = self._ungroup_o(o, kc)
-        o = jnp.swapaxes(o, 1, 2).reshape(b, kc, self.dim)
-        return self.out(o), pool
+        return self.out(self._merge_heads(o, kc)), pool
 
 
 class DecoderBlock(nn.Module):
-    """Pre-LN decoder block; residuals stay inside the node so block
-    boundaries are clean pipeline cuts (same contract as ViT's
-    ``EncoderBlock``).
+    """One decoder block, built from its :class:`BlockSpec`; residuals
+    stay inside the node so block boundaries are clean pipeline cuts
+    (same contract as ViT's ``EncoderBlock``).
 
-    ``moe_experts`` swaps the dense MLP for a dropless per-token MoE
-    (:class:`adapt_tpu.models.moe.MoEDecoderMlp`) — the Mixtral-shaped
-    decoder. ``_mlp`` is the ONE touch point every schedule shares
-    (full forward, prefill, decode_step, verify_chunk, paged chunk
-    prefill), so the MoE block serves through every decode path —
-    generate, continuous batching, speculative, pipelined — with the
-    exact cache-parity contract of the dense block, and its
-    expert-stacked params EP-shard via ``parallel.expert`` unchanged."""
+    ``_attn_res`` and ``_mlp_res`` are the TWO touch points every
+    schedule shares (full forward, prefill, decode_step, verify_chunk,
+    paged chunk prefill): where the norms sit, and which MLP runs — the
+    dense ones, the masked-dense mixture
+    (:class:`adapt_tpu.models.moe.MoEDecoderMlp`) or the routed experts
+    (:class:`adapt_tpu.models.moe.RoutedExperts`). Every MLP is
+    token-independent, so a mixture serves through every decode path
+    with the exact cache-parity contract of the dense block."""
 
-    dim: int
-    heads: int
-    mlp_dim: int
+    spec: BlockSpec
     dtype: jnp.dtype = jnp.float32
-    kv_heads: int | None = None
-    moe_experts: int | None = None
-    moe_top_k: int = 1
-    window: int | None = None
-    rope: bool = False
+
+    @property
+    def dim(self) -> int:
+        return self.spec.dim
+
+    @property
+    def heads(self) -> int:
+        return self.spec.heads
+
+    @property
+    def mlp_dim(self) -> int:
+        return self.spec.mlp_dim
+
+    @property
+    def window(self) -> int | None:
+        return self.spec.window
 
     @property
     def cache_heads(self) -> int:
         """Cache-buffer head count (see ``CausalSelfAttention.cache_heads``)."""
-        return self.kv_heads or self.heads
+        return self.spec.cache_heads
 
     @property
     def head_dim(self) -> int:
-        return self.dim // self.heads
+        return self.spec.attn_head_dim
 
     def setup(self):
-        self.ln1 = nn.LayerNorm(dtype=self.dtype)
-        self.attn = CausalSelfAttention(
-            self.dim, self.heads, dtype=self.dtype, kv_heads=self.kv_heads,
-            window=self.window, rope=self.rope,
-        )
-        self.ln2 = nn.LayerNorm(dtype=self.dtype)
-        if self.moe_experts is not None:
+        spec = self.spec
+        self.ln1 = _norm(spec.norm, spec.norm_eps, self.dtype)
+        self.attn = CausalSelfAttention(spec, dtype=self.dtype)
+        self.ln2 = _norm(spec.norm, spec.norm_eps, self.dtype)
+        if spec.mlp == "experts":
+            self.experts = RoutedExperts(spec.experts, dtype=self.dtype)
+        elif spec.mlp == "moe_dense":
             self.moe = MoEDecoderMlp(
-                num_experts=self.moe_experts,
-                hidden_dim=self.mlp_dim,
-                top_k=self.moe_top_k,
+                num_experts=spec.moe_experts,
+                hidden_dim=spec.mlp_dim,
+                top_k=spec.moe_top_k,
                 dtype=self.dtype,
             )
         else:
-            self.mlp_in = nn.Dense(self.mlp_dim, dtype=self.dtype)
-            self.mlp_out = nn.Dense(self.dim, dtype=self.dtype)
+            def dense(n):
+                return nn.Dense(n, dtype=self.dtype, use_bias=spec.bias)
+
+            if spec.mlp == "gated_silu":
+                self.mlp_gate = dense(spec.mlp_dim)
+            self.mlp_in = dense(spec.mlp_dim)
+            self.mlp_out = dense(spec.dim)
 
     def _mlp(self, x):
-        if self.moe_experts is not None:
+        kind = self.spec.mlp
+        if kind == "experts":
+            return self.experts(x)
+        if kind == "moe_dense":
             return self.moe(x)
+        if kind == "gated_silu":
+            return self.mlp_out(nn.silu(self.mlp_gate(x)) * self.mlp_in(x))
         return self.mlp_out(nn.gelu(self.mlp_in(x)))
 
-    def __call__(self, x):
-        x = x + self.attn(self.ln1(x))
+    def _attn_in(self, x):
+        return x if self.spec.post_norm else self.ln1(x)
+
+    def _attn_res(self, x, a):
+        return x + (self.ln1(a) if self.spec.post_norm else a)
+
+    def _mlp_res(self, x):
+        if self.spec.post_norm:
+            return x + self.ln2(self._mlp(x))
         return x + self._mlp(self.ln2(x))
+
+    def __call__(self, x):
+        x = self._attn_res(x, self.attn(self._attn_in(x)))
+        return self._mlp_res(x)
 
     def prefill(self, x, max_len: int, valid_from=None, quantize_cache=False):
         a, ck, cv = self.attn.prefill(
-            self.ln1(x), max_len, valid_from, quantize_cache
+            self._attn_in(x), max_len, valid_from, quantize_cache
         )
-        x = x + a
-        return x + self._mlp(self.ln2(x)), ck, cv
+        return self._mlp_res(self._attn_res(x, a)), ck, cv
 
     def decode_step(
         self, x_t, cache_k, cache_v, index, valid_from=None, quantized=False,
         attn_impl=None, split=None,
     ):
         a, ck, cv = self.attn.decode_step(
-            self.ln1(x_t), cache_k, cache_v, index, valid_from, quantized,
-            attn_impl, split,
+            self._attn_in(x_t), cache_k, cache_v, index, valid_from,
+            quantized, attn_impl, split,
         )
-        x_t = x_t + a
-        return x_t + self._mlp(self.ln2(x_t)), ck, cv
+        return self._mlp_res(self._attn_res(x_t, a)), ck, cv
 
     def decode_step_paged(
         self, x_t, pool, page_table, index, valid_from=None,
         attn_impl=None, split=None, head_shard=None,
     ):
         a, pool = self.attn.decode_step_paged(
-            self.ln1(x_t), pool, page_table, index, valid_from,
+            self._attn_in(x_t), pool, page_table, index, valid_from,
             attn_impl, split, head_shard,
         )
-        x_t = x_t + a
-        return x_t + self._mlp(self.ln2(x_t)), pool
+        return self._mlp_res(self._attn_res(x_t, a)), pool
 
     def prefill_chunk_paged(
         self, x, pool, pages, pos0, attn_impl=None, head_shard=None,
     ):
         a, pool = self.attn.prefill_chunk_paged(
-            self.ln1(x), pool, pages, pos0, attn_impl, head_shard
+            self._attn_in(x), pool, pages, pos0, attn_impl, head_shard
         )
-        x = x + a
-        return x + self._mlp(self.ln2(x)), pool
+        return self._mlp_res(self._attn_res(x, a)), pool
 
     def prefill_sp(self, x, gather, quantize_cache=False, constrain=None):
         a, ck, cv = self.attn.prefill_sp(
-            self.ln1(x), gather, quantize_cache, constrain
+            self._attn_in(x), gather, quantize_cache, constrain
         )
-        x = x + a
-        return x + self._mlp(self.ln2(x)), ck, cv
+        return self._mlp_res(self._attn_res(x, a)), ck, cv
 
     def verify_chunk(self, x, cache_k, cache_v, index, tree_tail=0):
         a, ck, cv = self.attn.verify_chunk(
-            self.ln1(x), cache_k, cache_v, index, tree_tail
+            self._attn_in(x), cache_k, cache_v, index, tree_tail
         )
-        x = x + a
-        return x + self._mlp(self.ln2(x)), ck, cv
+        return self._mlp_res(self._attn_res(x, a)), ck, cv
 
     def verify_chunk_paged(
         self, x, pool, page_table, index, attn_impl=None,
         tree_tail=0, split=None, head_shard=None,
     ):
         a, pool = self.attn.verify_chunk_paged(
-            self.ln1(x), pool, page_table, index, attn_impl,
+            self._attn_in(x), pool, page_table, index, attn_impl,
             tree_tail, split, head_shard,
         )
-        x = x + a
-        return x + self._mlp(self.ln2(x)), pool
+        return self._mlp_res(self._attn_res(x, a)), pool
 
 
 class TokenEmbed(nn.Module):
@@ -854,14 +970,21 @@ class TokenEmbed(nn.Module):
 
 
 class LMHead(nn.Module):
-    """Final LN + vocab projection (logits in f32 for stable sampling)."""
+    """Final norm + untied vocab projection (logits in f32 for stable
+    sampling). ``norm`` / ``norm_eps`` / ``bias`` follow the blocks'
+    spec."""
 
     vocab: int
     dtype: jnp.dtype = jnp.float32
+    norm: str = "layernorm"
+    norm_eps: float = 1e-6
+    bias: bool = True
 
     def setup(self):
-        self.ln = nn.LayerNorm(dtype=self.dtype)
-        self.logits = nn.Dense(self.vocab, dtype=jnp.float32)
+        self.ln = _norm(self.norm, self.norm_eps, self.dtype)
+        self.logits = nn.Dense(
+            self.vocab, dtype=jnp.float32, use_bias=self.bias
+        )
 
     def __call__(self, x):
         return self.logits(self.ln(x).astype(jnp.float32))
@@ -889,10 +1012,10 @@ class TransformerLM:
 
 def transformer_lm(
     vocab: int,
-    dim: int,
-    depth: int,
-    heads: int,
-    mlp_dim: int,
+    dim: int | None = None,
+    depth: int | None = None,
+    heads: int | None = None,
+    mlp_dim: int | None = None,
     max_len: int = 1024,
     dtype: jnp.dtype = jnp.float32,
     name: str = "transformer_lm",
@@ -901,8 +1024,19 @@ def transformer_lm(
     moe_top_k: int = 1,
     window: int | None = None,
     pos: str = "learned",
+    blocks: Sequence[BlockSpec] | None = None,
 ) -> TransformerLM:
-    """``kv_heads < heads`` builds a grouped-query (GQA) decoder: KV
+    """A decoder is a LIST OF BLOCK SPECS (:class:`BlockSpec`) between
+    an embedding and a head. ``blocks=`` gives the list as a model's
+    configuration states it, one spec a layer (a per-layer pattern of
+    window / full attention, dense / expert MLPs, rotations on some
+    layers and none on others); ``pos`` is then ``"learned"`` or
+    ``"none"`` (no position table: positions live in the blocks'
+    rotations or nowhere), and the head's norm and bias follow the
+    last block's spec. Without ``blocks`` the keywords below build
+    ``depth`` copies of one spec — the GPT-2 block and its variants:
+
+    ``kv_heads < heads`` builds a grouped-query (GQA) decoder: KV
     caches shrink by ``heads // kv_heads`` (``kv_heads=1`` = MQA), the
     serving-era cache-capacity knob — see ``CausalSelfAttention``.
 
@@ -924,27 +1058,42 @@ def transformer_lm(
     batcher RECYCLES pages that fall wholly behind it mid-request —
     pool usage bounds by the window, not the sequence.
     """
-    if window is not None and window < 1:
-        raise ValueError(f"window must be >= 1, got {window}")
-    if pos not in ("learned", "rope"):
-        raise ValueError(f"pos={pos!r}: expected 'learned' or 'rope'")
-    rope = pos == "rope"
+    if blocks is None:
+        if pos not in ("learned", "rope"):
+            raise ValueError(f"pos={pos!r}: expected 'learned' or 'rope'")
+        blocks = [BlockSpec(
+            dim, heads, mlp_dim, kv_heads=kv_heads,
+            mlp="gelu" if moe_experts is None else "moe_dense",
+            moe_experts=moe_experts, moe_top_k=moe_top_k, window=window,
+            rope_base=10000.0 if pos == "rope" else None,
+        )] * depth
+    else:
+        if pos not in ("learned", "none"):
+            raise ValueError(
+                f"pos={pos!r}: with blocks= expected 'learned' or 'none' "
+                "(a rotation is a block's rope_base)"
+            )
+        blocks = list(blocks)
+        dim = blocks[0].dim
+    last = blocks[-1]
     g = LayerGraph(name)
     prev = g.add(
         "embed",
-        TokenEmbed(vocab, dim, max_len, dtype=dtype, use_pos=not rope),
+        TokenEmbed(vocab, dim, max_len, dtype=dtype,
+                   use_pos=pos == "learned"),
         INPUT,
     )
-    for i in range(depth):
+    for i, spec in enumerate(blocks):
         prev = g.add(
-            f"decoder_block_{i}",
-            DecoderBlock(dim, heads, mlp_dim, dtype=dtype,
-                         kv_heads=kv_heads, moe_experts=moe_experts,
-                         moe_top_k=moe_top_k, window=window, rope=rope),
-            prev,
+            f"decoder_block_{i}", DecoderBlock(spec, dtype=dtype), prev
         )
-    g.add("head", LMHead(vocab, dtype=dtype), prev)
-    return TransformerLM(graph=g, depth=depth, max_len=max_len)
+    g.add(
+        "head",
+        LMHead(vocab, dtype=dtype, norm=last.norm, norm_eps=last.norm_eps,
+               bias=last.bias),
+        prev,
+    )
+    return TransformerLM(graph=g, depth=len(blocks), max_len=max_len)
 
 
 def lm_tiny(vocab: int = 256, max_len: int = 64) -> TransformerLM:
@@ -966,6 +1115,12 @@ def validate_tp(lm: TransformerLM, tp: int) -> None:
         return
     for name in lm.block_names:
         block = lm.graph.node(name).module
+        if block.spec.mlp == "experts":
+            raise ValueError(
+                f"{name}: routed experts do not split over tp (a chip "
+                "holds whole experts: ExpertSpec.held); the exchange "
+                "across chips is not built"
+            )
         for what, n in (
             ("heads", block.heads),
             ("cache (KV) heads", block.cache_heads),

@@ -318,6 +318,8 @@ from adapt_tpu.runtime.paged import (
     HostKVTier,
     Pager,
     alloc_kv_pools,
+    cache_groups,
+    group_pool_pages,
     insert_prefill_pages,
     pool_geometry,
 )
@@ -805,13 +807,48 @@ class ContinuousBatcher:
         self._embed = g.node("embed").module
         self._head = g.node("head").module
         self._blocks = [g.node(n).module for n in lm.block_names]
-        block0 = self._blocks[0]
+        #: CACHE GROUPS (``runtime/paged.cache_groups``): blocks with the
+        #: same (window, kv_heads, head_dim) share a pool geometry, a
+        #: ``Pager`` and a page table. The FIRST group is the one
+        #: ``pool_pages=`` sizes and every request reserves whole (the
+        #: only group of a model whose blocks are all alike: today's
+        #: path, unchanged); each further group grants pages pass by
+        #: pass (``Pager.hold``) from a pool derived from what one
+        #: request can hold there, so a window layer keeps its window
+        #: and not the sequence.
+        specs = [b.spec for b in self._blocks]
+        groups = cache_groups(specs)
+        # The group that reserves whole: the full-attention one if any.
+        groups.sort(key=lambda g: g.window is not None)
+        self._groups = groups
+        self._group_of = [0] * len(specs)
+        for gi, g in enumerate(groups):
+            for bi in g.blocks:
+                self._group_of[bi] = gi
+        if len(groups) > 1:
+            unsupported = {
+                "a draft model (speculative decoding)": draft_lm,
+                "a tp mesh": mesh,
+                "a host cache tier": cache_tier,
+                "sequence-parallel prefill": prefill,
+                "cache-aware admission (the radix prefix cache)": (
+                    scheduler is not None and scheduler.cache_aware
+                ) or None,
+                "a pipelined tick (pipeline_depth 2)": (
+                    runtime is not None and runtime.pipeline_depth > 1
+                ) or None,
+            }
+            for what, given in unsupported.items():
+                if given is not None:
+                    self._one_cache_group(what)
+        #: Blocks whose MLP is the routed-expert layer: their per-expert
+        #: token counts leave ``_step_chunk`` with the step's tokens.
+        self._moe_blocks = tuple(
+            i for i, sp in enumerate(specs) if sp.mlp == "experts"
+        )
         #: Sliding-window models: decode masking lives in the model;
         #: the batcher's job is page RECYCLING behind the window.
-        self._window = getattr(block0, "window", None)
-        # Pools hold KV heads: fewer than query heads under GQA (the
-        # whole point — a page costs kv_heads/heads the HBM).
-        heads, head_dim = block0.cache_heads, block0.head_dim
+        self._window = groups[0].window
         self._page = page_size
         # The table width covers max_len plus the speculative overshoot
         # slack: a verify chunk writes draft_k + 1 + tree_width tokens
@@ -831,6 +868,16 @@ class ContinuousBatcher:
         #: max_len.
         self._pager = Pager(pool_pages, slots, pps, page_tokens=page_size)
         self._pool_pages = pool_pages
+        #: One pager a group, the first group's being ``_pager``.
+        self._pagers = [self._pager] + [
+            Pager(
+                group_pool_pages(
+                    g, slots, pps, page_size, chunk, prefill_chunk
+                ),
+                slots, pps, page_tokens=page_size,
+            )
+            for g in groups[1:]
+        ]
         # -- hierarchical KV cache tier (docs/SERVING.md §3) ---------------
         #: Host-DRAM spill tier under the prefix LRU: evicted rc=0
         #: pages spill (budgeted per tick) instead of dying, and the
@@ -860,12 +907,15 @@ class ContinuousBatcher:
         #: High-water of the tier's own overflow-drop count already
         #: bridged to cache_tier.dropped_total (flushed per tick).
         self._tier_drop_seen = 0
+        # Pools hold KV heads: fewer than query heads under GQA (the
+        # whole point — a page costs kv_heads/heads the HBM).
         self._caches = [
             alloc_kv_pools(
-                pool_pages, heads, page_size, head_dim, block0.dtype,
+                self._pagers[gi].num_pages, groups[gi].kv_heads,
+                page_size, groups[gi].head_dim, block.dtype,
                 kv_cache_dtype,
             )
-            for _ in lm.block_names
+            for gi, block in zip(self._group_of, self._blocks)
         ]
         if mesh is not None:
             # Head-sharded KV: each device holds kv_heads / tp of every
@@ -875,14 +925,11 @@ class ContinuousBatcher:
         #: — the denominator of the memory.kv_bytes_ratio gauge, so the
         #: int8 capacity win (values + scale planes vs native) is
         #: directly observable on dashboards. Native batchers read 1.0.
-        self._native_cache_bytes = (
-            2
-            * len(lm.block_names)
-            * pool_pages
-            * page_size
-            * heads
-            * head_dim
-            * jnp.dtype(block0.dtype).itemsize
+        self._native_cache_bytes = sum(
+            2 * self._pagers[gi].num_pages * page_size
+            * groups[gi].kv_heads * groups[gi].head_dim
+            * jnp.dtype(block.dtype).itemsize
+            for gi, block in zip(self._group_of, self._blocks)
         )
         #: Idle-row cache position: a negative sentinel that stays
         #: negative across a whole tick's position advance (chunk
@@ -979,6 +1026,8 @@ class ContinuousBatcher:
         #: recycling) — a steady-state paged tick stages nothing.
         self._table_dev = None
         self._table_snapshot = None
+        #: Per further cache group: (host table, its device copy).
+        self._group_tables: list = [None] * len(groups[1:])
         # -- sequence-parallel long-context prefill ------------------------
         #: ``config.PrefillConfig{sp_threshold, sp_width}``: admissions
         #: of at least the threshold prefill SP-SHARDED across a
@@ -1493,12 +1542,15 @@ class ContinuousBatcher:
         ``temp == 0`` (submit's normalization). Static ``truncate`` /
         ``nucleus`` elide the top-k/top-p sorts when no active request
         needs them (at most 2x2 compiled variants). ``table`` addresses
-        each block's pool through the shared page table.
+        each block's pool through its cache group's page table (one
+        array; a tuple of them, one a group, for a model with several).
         Inactive rows re-park at the idle sentinel after the chunk's
         optimistic pos advance; rows whose request retires mid-chunk are
         cleared host-side (``_clear_slot``) before the next tick.
-        Returns ((chunk, B) emitted tokens, logprobs, caches, dstate);
-        ONE host sync per call, not per token."""
+        Returns ((chunk, B) emitted tokens, logprobs, caches, dstate,
+        moe); ONE host sync per call, not per token. ``moe`` is None
+        for a model without routed experts, else one int32 vector the
+        tick fetches with the tokens (``_moe_counts``)."""
         caches = self._shard_kv(caches)
         dstate = self._repl_state(dstate)
         C = self.chunk
@@ -1527,16 +1579,26 @@ class ContinuousBatcher:
                 method="embed_positions",
             )
             new_caches = []
-            for name, block, pool in zip(
+            held = []  # per expert block: (B, held) assignments a row
+            for i, (name, block, pool) in enumerate(zip(
                 self.lm.block_names, self._blocks, caches
-            ):
-                x, pool = block.apply(
-                    variables[name], x, pool, table, pos, None,
+            )):
+                out = block.apply(
+                    variables[name], x, pool, self._table_of(table, i),
+                    pos, None,
                     self._kernel.attn_impl,
                     self._kernel.decode_split,
                     self._head_shard(),
                     method="decode_step_paged",
+                    mutable=["intermediates"] if i in self._moe_blocks
+                    else False,
                 )
+                if i in self._moe_blocks:
+                    out, sown = out
+                    held.append(
+                        sown["intermediates"]["experts"]["held_tokens"][0]
+                    )
+                x, pool = out
                 new_caches.append(pool)
             logits = self._head.apply(variables["head"], x)[:, 0]  # (B, V)
             pick_greedy = jnp.argmax(logits, axis=-1)
@@ -1552,11 +1614,29 @@ class ContinuousBatcher:
             # One cheap (B, V) reduction per step, always emitted;
             # chosen_logprob is THE shared scoring convention.
             lp = chosen_logprob(logits, nxt)
-            return (nxt, pos + 1, tuple(new_caches)), (nxt, lp)
+            moe = None
+            if held:
+                # Live rows only: an idle row routes garbage.
+                live = pos >= 0
+                moe = (
+                    jnp.sum(
+                        jnp.where(live[None, :, None], jnp.stack(held), 0),
+                        axis=1,
+                    ),  # (expert blocks, held)
+                    jnp.sum(live, dtype=jnp.int32),
+                )
+            return (nxt, pos + 1, tuple(new_caches)), (nxt, lp, moe)
 
-        (_, _, caches), (toks, lps) = lax.scan(
+        (_, _, caches), (toks, lps, moe) = lax.scan(
             body, (dstate["tok"], dstate["pos"], tuple(caches)), keys
         )
+        if moe is not None:
+            per_step, rows = moe  # (C, blocks, held), (C,)
+            moe = jnp.concatenate([
+                jnp.sum(per_step, axis=0).reshape(-1),
+                jnp.sum(per_step > 0, dtype=jnp.int32)[None],
+                jnp.sum(rows)[None],
+            ])
         # Optimistic device-side advance: a surviving slot commits all C
         # tokens (any mid-chunk finish retires it and the host clears
         # its row), so pos/kbase/tok land exactly on the next tick's
@@ -1569,8 +1649,35 @@ class ContinuousBatcher:
         new["kbase"] = jnp.where(active, kbase + C, 0)
         return (
             toks, lps, self._shard_kv(list(caches)),
-            self._repl_state(new),
+            self._repl_state(new), moe,
         )
+
+    def _table_of(self, table, block: int):
+        """Block ``block``'s page table (or page list) among a
+        program's: the one array of a model with one cache group, else
+        its group's entry of the tuple."""
+        if len(self._groups) == 1:
+            return table
+        return table[self._group_of[block]]
+
+    def _moe_counts(self, moe) -> None:
+        """Book one chunk's expert counts (``_step_chunk``'s last
+        output, landed with the tokens): per expert block and held
+        expert the tokens it was given, then how many (step, expert)
+        pairs got at least one, then the live rows summed over steps."""
+        m = global_metrics()
+        n = len(self._moe_blocks)
+        tokens = moe[:-2].reshape(n, -1)
+        for row, block in zip(tokens, self._moe_blocks):
+            first = self._blocks[block].spec.experts.held_range[0]
+            for e, count in enumerate(row):
+                if count:
+                    m.inc(f"moe.tokens.{block}.{first + e}", float(count))
+        top_k = self._blocks[self._moe_blocks[0]].spec.experts.top_k
+        m.inc("moe.steps", float(self.chunk))
+        m.inc("moe.experts_hit", float(moe[-2]))
+        m.inc("moe.assignments_held", float(tokens.sum()))
+        m.inc("moe.assignments_total", float(moe[-1]) * top_k * n)
 
     @partial(
         jax.jit,
@@ -1906,6 +2013,7 @@ class ContinuousBatcher:
         ``ValueError`` on geometry mismatches (page size,
         quantization, block count/shapes) — a malformed handoff must
         fail by name, never scatter garbage into live pages."""
+        self._one_cache_group("a handoff of prefilled pages")
         # The device-lost gate tick() runs: a handoff landing between
         # ticks must not device_put shard slices onto a dead device or
         # dispatch the adoption program at a stale mesh epoch (the
@@ -2272,6 +2380,7 @@ class ContinuousBatcher:
         or capacity audit wants (``benchmarks/load/tier_smoke``
         measures the host tier's servable-prefix multiplier with
         it)."""
+        self._one_cache_group("the radix prefix cache")
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         n = 0
         for j in range((prompt.shape[0] - 1) // self._page):
@@ -2286,17 +2395,22 @@ class ContinuousBatcher:
 
     def _insert_paged(self, caches, pages, kvs):
         """Scatter a prefilled request's per-block K/V into its pages
-        (``runtime/paged.insert_prefill_pages`` per plane). tree.map
+        (``runtime/paged.insert_prefill_pages`` per plane; ``pages`` is
+        one list, or one a cache group). tree.map
         reaches the (values, k_scales, v_scales) planes of quantized
         pools and the one fused plane of native ones alike — a scale
         plane scatters by the same page list, so the pages' scales
         always travel with their int8 values (prefix sharing
         included)."""
-        return jax.tree.map(
-            lambda pool, kv: insert_prefill_pages(pool, pages, kv),
-            caches,
-            kvs,
-        )
+        return [
+            jax.tree.map(
+                lambda pool, kv, i=i: insert_prefill_pages(
+                    pool, self._table_of(pages, i), kv
+                ),
+                cache, kv,
+            )
+            for i, (cache, kv) in enumerate(zip(caches, kvs))
+        ]
 
     def _first_pick(self, h_last, variables, keys, temp, top_k, top_p,
                     greedy, truncate, nucleus):
@@ -2389,7 +2503,8 @@ class ContinuousBatcher:
                 self.lm.block_names, self._blocks, caches
             ):
                 h, pool = block.apply(
-                    variables[name], h, pool, pages, pos0,
+                    variables[name], h, pool,
+                    self._table_of(pages, len(new_caches)), pos0,
                     head_shard=self._head_shard(),
                     method="prefill_chunk_paged",
                 )
@@ -2752,6 +2867,7 @@ class ContinuousBatcher:
         siblings STAY queued (their ids are lost with the raise — a
         caller that must know them should submit serially); the group
         shrinks to the survivors."""
+        self._one_cache_group("copy-on-write fan-out")
         if n < 1:
             raise ValueError(f"fan-out width must be >= 1, got {n}")
         sib_rngs: list = [None] * n
@@ -3774,7 +3890,8 @@ class ContinuousBatcher:
         slot.slo_ok = True
         slot.t_first = 0.0
         slot.obs_count = 0
-        self._pager.free_slot(slot.idx)
+        for pager in self._pagers:
+            pager.free_slot(slot.idx)
 
     def _park_slot_row(self, idx: int) -> None:
         """Park a retired slot's device row (one donated setter
@@ -4028,7 +4145,9 @@ class ContinuousBatcher:
                 # (budgeted) through the adopt_cached landing path
                 # and then share below as ordinary hits.
                 self._maybe_readmit(req)
-            for j in range((s0 - 1) // P):
+            # (A model with several cache groups shares nothing: a hit
+            # would need every window layer's last positions too.)
+            for j in range((s0 - 1) // P if len(self._groups) == 1 else 0):
                 key = Pager.prefix_key(req.prompt, (j + 1) * P)
                 if self._pager.lookup_share(i, key) is None:
                     break
@@ -4170,6 +4289,10 @@ class ContinuousBatcher:
             else:
                 ids = np.zeros((1, bucket), np.int32)
                 ids[0, :s0] = req.prompt
+                # The further groups hold the prompt's tail only; their
+                # other ordinals point at the trash page, which takes
+                # (and never gives back) the rest of the bucket.
+                self._hold_groups(i, s0, s0)
                 first, first_lp, kvs = self._prefill_fn(bucket)(
                     self.variables,
                     self._h2d(ids),
@@ -4183,12 +4306,12 @@ class ContinuousBatcher:
                 )
                 self._caches = self._insert_paged(
                     self._caches,
-                    self._h2d(np.asarray(self._pager.owned(i), np.int32)),
+                    self._pages_of(i, len(self._pager.owned(i))),
                     kvs,
                 )
                 self._count_prefill(s0)
                 cap_tokens = s0
-            if not chunked:
+            if not chunked and len(self._groups) == 1:
                 # Publish this request's full prompt pages for future
                 # sharing (first writer wins; the shared ones are
                 # already registered). Chunked admissions register on
@@ -4382,7 +4505,54 @@ class ContinuousBatcher:
         ):
             self._table_snapshot = np.array(t, copy=True)
             self._table_dev = self._h2d(self._table_snapshot)
-        return self._table_dev
+        if len(self._groups) == 1:
+            return self._table_dev
+        # One table a cache group, each re-uploaded when IT changed (a
+        # window group's does whenever a row crosses a page edge).
+        for gi, pager in enumerate(self._pagers[1:]):
+            t = pager.table()
+            held = self._group_tables[gi]
+            if held is None or not np.array_equal(t, held[0]):
+                self._group_tables[gi] = (t, self._h2d(t))
+        return (self._table_dev,) + tuple(
+            dev for _, dev in self._group_tables
+        )
+
+    def _one_cache_group(self, what: str) -> None:
+        """Refuse ``what`` for a model whose cache is in several layer
+        groups: a shared prompt page would need every window layer's
+        last positions beside it."""
+        if len(self._groups) > 1:
+            raise ValueError(
+                f"{what} does not run over a cache in "
+                f"{len(self._groups)} layer groups "
+                f"({', '.join(g.name for g in self._groups)}) yet"
+            )
+
+    def _hold_groups(self, slot: int, lo_pos: int, hi_pos: int) -> None:
+        """Before a pass that writes positions ``[lo_pos, hi_pos)`` of
+        ``slot`` (or, ``lo_pos == hi_pos``, one that only needs what
+        came before): every cache group after the first holds exactly
+        the pages the pass touches (``Pager.hold``) — from the window
+        behind the first write, or the whole context where the group
+        has no window, through the last write."""
+        P = self._page
+        for g, pager in zip(self._groups[1:], self._pagers[1:]):
+            lo = 0 if g.window is None else max(0, lo_pos - g.window + 1)
+            pager.hold(slot, lo // P, -(-hi_pos // P))
+
+    def _pages_of(self, slot: int, n: int, pad: int | None = None):
+        """``slot``'s first ``n`` logical pages as a program takes
+        them, staged: one (pad or n,) list where the model has one
+        cache group, else one a group (ordinals a group does not hold
+        point at the trash page: behind its window, masked). ``pad``
+        (>= n) fills with the trash page."""
+        out = []
+        for pager in self._pagers:
+            row = np.zeros((pad or n,), np.int32)
+            row[:n] = pager.table_row(slot)[:n]
+            out.append(self._h2d(row))
+        return out[0] if len(out) == 1 else tuple(out)
 
     def _prefill_step(self, slot: _Slot) -> None:
         """One chunked-prefill pass for ``slot``: write positions
@@ -4415,7 +4585,7 @@ class ContinuousBatcher:
             n_pad = 1
             while n_pad < n_strip:
                 n_pad *= 2
-            pages = owned[:n_strip] + [0] * (n_pad - n_strip)
+            self._hold_groups(slot.idx, pos0, pos0 + cbucket)
             ids = np.zeros((1, cbucket), np.int32)
             ids[0, :clen] = req.prompt[pos0:pos0 + clen]
             first, first_lp, self._caches = self._prefill_suffix_fn(
@@ -4423,7 +4593,7 @@ class ContinuousBatcher:
             )(
                 self.variables,
                 self._caches,
-                self._h2d(np.asarray(pages, np.int32)),
+                self._pages_of(slot.idx, n_strip, n_pad),
                 self._h2d(ids),
                 self._h2d(np.array([pos0, clen, req.top_k], np.int32)),
                 self._h2d(np.array(
@@ -4448,7 +4618,9 @@ class ContinuousBatcher:
                     final=final,
                 )
             if final:
-                for j in range(s0 // P):  # register() skips known keys
+                # register() skips known keys (one cache group only:
+                # several share nothing).
+                for j in range(s0 // P if len(self._groups) == 1 else 0):
                     self._pager.register(
                         owned[j], Pager.prefix_key(req.prompt, (j + 1) * P)
                     )
@@ -4764,7 +4936,16 @@ class ContinuousBatcher:
                     "continuous.step_chunk", set()
                 ).add((truncate, nucleus))
                 t_chunk = tracer.now() if tracer.enabled else 0.0
-                toks, lps, self._caches, self._dstate = self._step_chunk(
+                if len(self._groups) > 1:
+                    # The scan writes [pos, pos + chunk), within the
+                    # request's span (what overshoots a finishing
+                    # request goes to the trash page).
+                    for sl in active:
+                        self._hold_groups(sl.idx, sl.pos, min(
+                            sl.pos + self.chunk, sl.s0 + sl.req.steps
+                        ))
+                (toks, lps, self._caches, self._dstate,
+                 moe) = self._step_chunk(
                     self.variables,
                     self._caches,
                     self._dstate,
@@ -4779,7 +4960,9 @@ class ContinuousBatcher:
                 # The chunk's ONE host fetch covers both arrays —
                 # started here (async), landed at commit.
                 fl = _InFlight(
-                    fetch=_AsyncFetch((toks, lps)),
+                    fetch=_AsyncFetch(
+                        (toks, lps) if moe is None else (toks, lps, moe)
+                    ),
                     reqs=[],
                     lives=[],
                     t_span=t_chunk,
@@ -4828,7 +5011,9 @@ class ContinuousBatcher:
             host = fl.fetch.commit()
         tracer = global_tracer()
         if fl.spec is None:
-            toks, lps = host
+            toks, lps = host[:2]
+            if len(host) > 2:
+                self._moe_counts(host[2])
             limits = np.full((toks.shape[1],), self.chunk, np.int64)
             if tracer.enabled and fl.t_span:
                 # Dispatch -> results-landed of one compiled decode
@@ -5059,6 +5244,14 @@ class ContinuousBatcher:
                     x.nbytes
                     for x in jax.tree.leaves(self._draft_caches)
                 )
+            if len(self._groups) > 1:
+                # A pool and a page table a cache group; the unsuffixed
+                # keys below are the first group's, as ever.
+                for g, pager in zip(self._groups, self._pagers):
+                    gs = pager.stats()
+                    out[f"pool_pages.{g.name}"] = gs.num_pages
+                    out[f"pages_in_use.{g.name}"] = gs.in_use
+                out["prefix_cache"] = "off: several cache groups"
             ps = self._pager.stats()
             out["pool_pages"] = ps.num_pages
             out["pages_in_use"] = ps.in_use
@@ -5192,6 +5385,8 @@ class ContinuousBatcher:
         a_table = jax.ShapeDtypeStruct(
             (len(self.slots), self._pager.pages_per_slot), jnp.int32
         )
+        if len(self._groups) > 1:
+            a_table = (a_table,) * len(self._groups)
         costs: dict[str, dict[str, float]] = {}
         try:
             if self._spec is not None:

@@ -273,8 +273,36 @@ class Pager:
     def base(self, slot: int) -> int:
         return self._base[slot]
 
+    def hold(self, slot: int, lo: int, hi: int) -> None:
+        """Make ``slot`` own exactly the logical pages its next pass
+        touches, ordinals ``[lo, hi)``: release what fell behind ``lo``
+        and grant up to ``hi`` (capped at the table's width). For a
+        pool sized ``slots x the widest hold + 1``
+        (:func:`group_pool_pages`) the grant cannot fail."""
+        hi = min(hi, self.pages_per_slot)
+        self.release_prefix(
+            slot, min(max(lo - self._base[slot], 0), len(self._owned[slot]))
+        )
+        if not self._owned[slot]:
+            # Nothing held: the ordinals before ``lo`` are never backed
+            # (a prompt longer than the window keeps only its tail).
+            self._base[slot] = max(self._base[slot], lo)
+        need = hi - self._base[slot] - len(self._owned[slot])
+        if need > 0 and not self.alloc(slot, need):
+            raise RuntimeError(
+                f"slot {slot}: a cache group's pool cannot cover pages "
+                f"[{lo}, {hi}) — sized below group_pool_pages"
+            )
+
     def owned(self, slot: int) -> list[int]:
         return list(self._owned[slot])
+
+    def table_row(self, slot: int) -> np.ndarray:
+        """``slot``'s row of :meth:`table`."""
+        row = np.zeros((self.pages_per_slot,), np.int32)
+        b = self._base[slot]
+        row[b: b + len(self._owned[slot])] = self._owned[slot]
+        return row
 
     def table(self) -> np.ndarray:
         """(slots, pages_per_slot) int32; unallocated (and released)
@@ -704,6 +732,69 @@ class HostKVTier:
             dropped=self.dropped,
             codec_bytes_saved=self.codec_bytes_saved,
         )
+
+
+@dataclasses.dataclass(frozen=True)
+class CacheGroup:
+    """The blocks of a model that share ONE pool geometry and ONE page
+    table: same ``(window, kv_heads, head_dim)``. A window group
+    recycles behind its window; a full group keeps the whole request."""
+
+    name: str  # "full", "window", or "<kind><n>" where a kind repeats
+    window: int | None
+    kv_heads: int
+    head_dim: int
+    blocks: tuple[int, ...]  # indices into the model's block list
+
+
+def cache_groups(specs) -> list[CacheGroup]:
+    """Group a model's blocks (their ``BlockSpec``) by cache geometry,
+    in order of first appearance. One group: every block alike, which
+    is every model before per-layer patterns."""
+    keys: dict[tuple, list[int]] = {}
+    for i, spec in enumerate(specs):
+        keys.setdefault(
+            (spec.window, spec.cache_heads, spec.attn_head_dim), []
+        ).append(i)
+    kinds = ["full" if k[0] is None else "window" for k in keys]
+    out = []
+    for n, (key, blocks) in enumerate(keys.items()):
+        kind = kinds[n]
+        if kinds.count(kind) > 1:
+            kind += str(kinds[:n].count(kind))
+        out.append(CacheGroup(kind, *key, blocks=tuple(blocks)))
+    return out
+
+
+def window_hold_pages(
+    window: int, page_size: int, chunk: int, prefill_chunk: int | None
+) -> int:
+    """The most pages of ONE request a window layer holds at a time:
+    a span of L consecutive positions touches at most
+    ``ceil((L - 1) / page) + 1`` pages, and a decode scan of ``chunk``
+    steps touches the window behind its first write through its last
+    write (L = window + chunk - 1); a chunked-prefill pass starts on a
+    page edge, so it touches the window behind it plus its own pages."""
+    hold = -(-(window + chunk - 2) // page_size) + 1
+    if prefill_chunk is not None:
+        hold = max(
+            hold, -(-(window - 1) // page_size) + prefill_chunk // page_size
+        )
+    return hold
+
+
+def group_pool_pages(
+    group: CacheGroup, slots: int, pages_per_slot: int, page_size: int,
+    chunk: int, prefill_chunk: int | None,
+) -> int:
+    """Pool of a group that grants pages pass by pass (``Pager.hold``):
+    every slot's widest hold, plus the trash page."""
+    hold = pages_per_slot
+    if group.window is not None:
+        hold = min(hold, window_hold_pages(
+            group.window, page_size, chunk, prefill_chunk
+        ))
+    return slots * hold + 1
 
 
 def kv_value_width(head_dim: int, kv_cache_dtype: str) -> int:

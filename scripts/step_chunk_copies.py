@@ -59,12 +59,6 @@ def main(argv=None) -> int:
     traffic = mf.traffic_of(manifest, cell, ROOT)
     model = dict(config["model"])
     serving = {**config["serving"], **traffic.get("serving", {})}
-    pairs = tg.templates(
-        traffic, min(model["n_positions"], serving["prompt_buckets"][-1])
-    )
-    serving["pool_pages"] = lm_engine.pool_pages(
-        serving, pairs, model["n_positions"]
-    )
     sharding = None
     if args.describe:
         from jax.experimental import topologies
@@ -80,7 +74,15 @@ def main(argv=None) -> int:
         from adapt_tpu.utils.compile_cache import ensure_compile_cache
 
         ensure_compile_cache()
-    lm, variables, _ = mf.part_of(config, "builder")(model, config["dtype"], 1)
+    lm, variables, shape = mf.part_of(config, "builder")(
+        model, config["dtype"], 1
+    )
+    pairs = tg.templates(
+        traffic, min(shape["max_len"], serving["prompt_buckets"][-1])
+    )
+    serving["pool_pages"] = lm_engine.pool_pages(
+        serving, pairs, shape["max_len"]
+    )
     srv = ContinuousBatcher(
         lm, variables,
         slots=serving["slots"], chunk=serving["chunk"],
@@ -106,6 +108,8 @@ def main(argv=None) -> int:
             (len(srv.slots), srv._pager.pages_per_slot), jnp.int32
         )
     )
+    if len(srv._groups) > 1:  # a page table a cache group
+        a_table = (a_table,) * len(srv._groups)
     planes = jax.tree.leaves(srv._caches)
     dims = re.escape(",".join(map(str, planes[0].shape)))
     buf = r"\w+\[" + dims + r"\](\{[^}]*\})"

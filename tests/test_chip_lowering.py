@@ -33,7 +33,7 @@ from jax.sharding import (
     SingleDeviceSharding,
 )
 
-from adapt_tpu.models.transformer_lm import DecoderBlock
+from adapt_tpu.models.transformer_lm import BlockSpec, DecoderBlock
 from adapt_tpu.ops.attention import flash_attention, flash_attention_with_lse
 from adapt_tpu.ops.decode_attention import decode_attention
 from adapt_tpu.ops.dispatch import kernel_dispatch_stats
@@ -505,7 +505,7 @@ def test_pool_write_compiles_without_pool_relayout(
     assignment is a heuristic — how the update operand is produced
     decides the pool's layout — so this count is the guard."""
     shape, dim, heads, mlp, slots, pps = deploy
-    block = DecoderBlock(dim, heads, mlp, dtype=jnp.bfloat16)
+    block = DecoderBlock(BlockSpec(dim, heads, mlp), dtype=jnp.bfloat16)
     rows, kc = slots, {"verify": 4, "chunk": 256}.get(form, 1)
     if form == "chunk":
         rows = 1  # prefill is per request: 256 positions, two pages
@@ -620,3 +620,104 @@ def test_compile_cache_default_is_fixed_under_checkout(monkeypatch):
         ("jax_compilation_cache_dir", want),
         ("jax_persistent_cache_min_compile_time_secs", 0.0),
     ] * 2
+
+
+# -- what K-EXAONE's path reaches (PR 31): 8 query heads a KV head, a
+# -- window, a page table a cache group, the grouped expert product ----
+
+#: slots, kv heads, query heads a KV head, head_dim, pages a slot, the
+#: full and the window group's pool pages: ``kexaone_longgen``.
+_KEX = (128, 8, 8, 128, 16, 1921, 385)
+
+
+def test_kexaone_attention_entries_lower(as_tpu):
+    b, kvh, g, hd, pps, full, win = _KEX
+    q = sds((b, kvh, g, hd))
+    table, index = sds((b, pps), jnp.int32), sds((b,), jnp.int32)
+    for pages in (full, win):  # a pool and a table a group
+        lower_for_tpu(
+            paged_attention, q, sds((pages, kvh, 128, 2 * hd)), table,
+            index, index,
+        )
+    for window in (None, 128):
+        lower_for_tpu(
+            lambda q, kv, p, pos0: paged_chunk_attention(
+                q, kv, p, pos0, 256, window=window
+            ),
+            sds((1, kvh, g * 256, hd)), sds((win, kvh, 128, 2 * hd)),
+            sds((4,), jnp.int32), sds((), jnp.int32),
+        )
+
+
+def _expert_operands(on=sds):
+    rows, d, h, held = 1024, 6144, 2048, 16  # 128 rows x top-8
+    return (
+        on((rows, d)), on((held, d, h)), on((held, d, h)), on((held, h, d)),
+        on((held,), jnp.int32),
+    )
+
+
+def test_expert_product_lowers(as_tpu):
+    from adapt_tpu.models.moe import expert_product
+
+    # Gate and up share one lowered kernel (equal shapes), down its own.
+    lower_for_tpu(expert_product, *_expert_operands(), kernels=2)
+    assert kernel_dispatch_stats()["expert_product"]["last"] == 1.0
+
+
+def test_expert_product_compiles_for_v5e(as_tpu, one_chip, no_persistent_cache):
+    """Mosaic's own compile of the grouped matmul at the published
+    widths and the tiles ``models/moe`` picked (a 1024 x 2048 weight
+    tile, double-buffered, inside a v5e's scoped VMEM), under the name
+    the benchmark's reader sums (``gmm``)."""
+    from adapt_tpu.models.moe import expert_product
+
+    def on_chip(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    text = expert_product.lower(*_expert_operands(on_chip)).compile().as_text()
+    assert len(re.findall(r"%gmm[.\d]* = .*tpu_custom_call", text)) == 3
+
+
+def test_step_chunk_with_a_table_a_group_lowers(as_tpu):
+    """The batcher's own decode program for a two-group model (window
+    and full layers, routed experts): every block's paged kernel takes
+    its group's table, the expert blocks their grouped product."""
+    from adapt_tpu.models.moe import ExpertSpec
+    from adapt_tpu.models.transformer_lm import transformer_lm
+    from adapt_tpu.runtime.continuous import ContinuousBatcher
+
+    experts = ExpertSpec(16, 128, 4, score="sigmoid", normalize=True,
+                         select_bias=True, shared_dim=128, held=(0, 4))
+
+    def spec(window, sparse):
+        return BlockSpec(
+            256, 8, 256, kv_heads=1, head_dim=128, norm="rmsnorm",
+            post_norm=True, qk_norm=True, bias=False,
+            mlp="experts" if sparse else "gated_silu",
+            experts=experts if sparse else None,
+            rope_base=1e6 if window else None, window=window,
+        )
+
+    lm = transformer_lm(
+        512, blocks=[spec(128, False), spec(128, True), spec(None, True)],
+        pos="none", max_len=512, dtype=jnp.bfloat16,
+    )
+    variables = jax.eval_shape(
+        lm.graph.init, jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
+    )
+    variables = jax.tree.map(
+        lambda s: jnp.zeros(s.shape, jnp.bfloat16), variables
+    )
+    srv = ContinuousBatcher(lm, variables, slots=8, chunk=2, page_size=128)
+    assert [g.name for g in srv._groups] == ["full", "window"]
+    tables = srv._current_table()
+    assert isinstance(tables, tuple) and len(tables) == 2
+    text = type(srv)._step_chunk.trace(
+        srv, srv.variables, srv._caches, srv._dstate, tables,
+        truncate=False, nucleus=False, epoch=0,
+    ).lower(lowering_platforms=("tpu",)).as_text()
+    srv.close()
+    # The paged decode kernel (full and window tables) and the grouped
+    # matmul (in and out widths): each lowered once, called a block.
+    assert text.count("tpu_custom_call") >= 3

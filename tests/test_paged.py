@@ -14,6 +14,7 @@ import numpy as np
 import pytest
 
 from adapt_tpu.models.transformer_lm import (
+    BlockSpec,
     CausalSelfAttention,
     generate,
     lm_tiny,
@@ -512,7 +513,7 @@ def test_paged_write_equals_scatter_oracle(rng, mode, kv_dtype, hd):
     heads, kvh, page, npages = 4, 2, 8, 9
     dim = heads * hd
     kc = 1 if mode == "decode" else 5
-    attn = CausalSelfAttention(dim, heads, kv_heads=kvh)
+    attn = CausalSelfAttention(BlockSpec(dim, heads, 0, kv_heads=kvh))
     kx, kp = jax.random.split(rng)
     x = jax.random.normal(kx, (4, kc, dim))
     params = attn.init(kp, x)
@@ -571,7 +572,7 @@ def test_paged_page_writes_land_k_and_v_on_their_lanes(
     and touch no other page."""
     heads, kvh, page, npages = 4, 2, 8, 9
     dim = heads * hd
-    attn = CausalSelfAttention(dim, heads, kv_heads=kvh)
+    attn = CausalSelfAttention(BlockSpec(dim, heads, 0, kv_heads=kvh))
     kx, kp = jax.random.split(rng)
     k_plane, v_plane = _two_planes(kv_dtype, npages, kvh, page, hd)
     pool = fuse_kv(k_plane, v_plane)
