@@ -928,31 +928,44 @@ class RuntimeConfig:
     """Tick-runtime pipelining (``runtime/continuous.py`` "Pipelined
     async runtime", docs/SERVING.md §3 "Async runtime").
 
-    ``pipeline_depth=1`` (the default) is the synchronous loop: each
-    ``tick()`` dispatches the decode/verify programs, blocks on the
-    one-fetch D2H, and commits the results before returning —
-    byte-for-byte the historical behavior. ``pipeline_depth=2``
-    overlaps host and device: while tick *t*'s programs execute on
-    device, the host runs tick *t+1*'s scheduler pass and fused
-    admission/staging, and tick *t*'s results commit one call LATER
-    (the one-tick commit lag — EOS/stop/cancel/SLO bookkeeping and
-    ``on_token`` delivery operate on tick *t−1*'s results while *t*
-    runs). Greedy streams stay bit-identical between depths; delivery
-    timing (TTFT/ITL stamps, cancel consumption) measures commit, not
-    device completion. Depths beyond 2 buy nothing on a
-    one-program-per-tick engine (the device queue is already full with
-    one tick in flight), so they are rejected eagerly rather than
-    silently behaving like 2."""
+    ``pipeline_depth`` left unset (``None``, the default) means THE
+    BATCHER DECIDES, once, in its constructor
+    (``stats()["pipeline_depth"]`` reports what it resolved): **2** for
+    a model with one cache group, **1** where the model has several
+    (the window group grants and recycles pages pass by pass from
+    committed positions, which an in-flight tick has not yet moved).
+    An explicit 1 or 2 means what it says; an explicit 2 under cache
+    groups is refused with a message.
 
+    ``pipeline_depth=2`` overlaps host and device: while tick *t*'s
+    programs execute on device, the host runs tick *t+1*'s scheduler
+    pass and fused admission/staging, and tick *t*'s results commit
+    one call LATER (the one-tick commit lag — EOS/stop/cancel/SLO
+    bookkeeping and ``on_token`` delivery operate on tick *t−1*'s
+    results while *t* runs; a caller of manual ``tick()`` sees a
+    request's tokens one call after the call that dispatched them, and
+    ``drain()`` is the boundary that lands what is in flight).
+    ``pipeline_depth=1`` is the synchronous loop: each ``tick()``
+    dispatches the decode/verify programs, blocks on the one-fetch
+    D2H, and commits the results before returning — what a caller that
+    asserts per-tick state asks for by name. Greedy streams stay
+    bit-identical between depths; delivery timing (TTFT/ITL stamps,
+    cancel consumption) measures commit, not device completion. Depths
+    beyond 2 buy nothing on a one-program-per-tick engine (the device
+    queue is already full with one tick in flight), so they are
+    rejected eagerly rather than silently behaving like 2."""
+
+    #: None = the batcher decides (2 with one cache group, else 1);
     #: 1 = synchronous tick loop; 2 = one tick in flight (dispatch t
     #: while committing t-1).
-    pipeline_depth: int = 1
+    pipeline_depth: int | None = None
 
     def __post_init__(self):
-        if self.pipeline_depth not in (1, 2):
+        if self.pipeline_depth not in (None, 1, 2):
             raise ValueError(
-                "pipeline_depth must be 1 (synchronous) or 2 "
-                f"(one tick in flight), got {self.pipeline_depth}"
+                "pipeline_depth must be 1 (synchronous), 2 (one tick "
+                "in flight) or None (the batcher decides), got "
+                f"{self.pipeline_depth}"
             )
 
 

@@ -835,7 +835,7 @@ class ContinuousBatcher:
                     scheduler is not None and scheduler.cache_aware
                 ) or None,
                 "a pipelined tick (pipeline_depth 2)": (
-                    runtime is not None and runtime.pipeline_depth > 1
+                    runtime is not None and runtime.pipeline_depth == 2
                 ) or None,
             }
             for what, given in unsupported.items():
@@ -1176,9 +1176,16 @@ class ContinuousBatcher:
         # dispatches tick t, then commits tick t-1's _InFlight while t
         # runs on device — one tick of results stays in flight between
         # calls, drained at every pipeline boundary (run() exit,
-        # recover(), drain(), server-loop stop).
+        # recover(), drain(), server-loop stop). Left unset, the depth
+        # is decided HERE, once, from what the constructor sees: the
+        # overlapped order wherever the model has one cache group; the
+        # synchronous one under several (a further group grants and
+        # recycles pages pass by pass from COMMITTED positions, which
+        # an in-flight tick has not yet moved).
         self._runtime = runtime or RuntimeConfig()
-        self._depth = self._runtime.pipeline_depth
+        self._depth = self._runtime.pipeline_depth or (
+            2 if len(groups) == 1 else 1
+        )
         self._inflight: _InFlight | None = None
         #: SLO accounting (docs/OBSERVABILITY.md "Workload telemetry").
         #: Hot path touches only these plain ints (one attribute inc
@@ -4756,15 +4763,16 @@ class ContinuousBatcher:
         (``_tick_dispatch``: scheduler/admission/prefill + the decode
         dispatch, with the D2H fetch started asynchronously) and a
         **commit** half (``_tick_commit``: land the fetch, apply
-        per-slot commits, flush telemetry). At
-        ``RuntimeConfig.pipeline_depth=1`` the halves run back to back
-        — the historical synchronous loop, except the fetch now
-        overlaps the tracer/phase bookkeeping between them. At
-        ``depth=2`` this call dispatches tick *t* and then commits
-        tick *t−1* while *t* runs on device: the host's scheduler pass
-        overlaps the device wall, and every result is delivered with a
-        one-tick lag (drained at :meth:`drain` / :meth:`run` exit /
-        :meth:`recover`).
+        per-slot commits, flush telemetry). At depth 2 — what an
+        unset ``RuntimeConfig.pipeline_depth`` resolves to for a model
+        with one cache group (``stats()["pipeline_depth"]``) — this
+        call dispatches tick *t* and then commits tick *t−1* while *t*
+        runs on device: the host's fetch, commits, callbacks and the
+        caller's own work between calls overlap the device wall, and
+        every result is delivered with a one-tick lag (drained at
+        :meth:`drain` / :meth:`run` exit / :meth:`recover`). At depth
+        1 (explicit, or resolved under several cache groups) the
+        halves run back to back: the synchronous loop.
 
         Phases (``utils.profiling.EngineObs``): the call is one
         ``engine.tick`` region holding ``engine.admit`` /
@@ -4795,7 +4803,7 @@ class ContinuousBatcher:
             fl = self._tick_dispatch()
             prev, self._inflight = self._inflight, fl
             if prev is not None:
-                return self._tick_commit(prev)
+                return self._tick_commit(prev, overlapped=fl is not None)
             return 0
 
     def drain(self) -> int:
@@ -4990,14 +4998,17 @@ class ContinuousBatcher:
         fl.t_dispatched = time.perf_counter()
         return fl
 
-    def _tick_commit(self, fl: "_InFlight") -> int:
+    def _tick_commit(self, fl: "_InFlight", overlapped: bool = False) -> int:
         """Commit half of one tick: land ``fl``'s async fetch, close
         the decode/verify spans it opened, apply per-slot token
         commits (skipping slots whose binding changed since dispatch —
         their columns are a bounded garbage tail nobody reads), then
         window recycling, the telemetry flush, and the compile-
         sentinel sample. Runs in the same :meth:`tick` call at depth
-        1; one tick later at depth 2."""
+        1; one tick later at depth 2, where ``overlapped`` says another
+        decode dispatch followed ``fl``'s before this commit
+        (``runtime.ticks_overlapped`` counts those commits,
+        ``runtime.ticks_synchronous`` the rest)."""
         eo = self._eobs
         eo_on = eo.enabled
         if eo_on and fl.t_dispatched:
@@ -5087,6 +5098,7 @@ class ContinuousBatcher:
                     "runtime.overlap_ratio",
                     max(0.0, 1.0 - fl.fetch.wait_s / wall),
                 )
+        past_end = 0
         with eo.region("commit"):
             for i, slot in enumerate(self.slots):
                 req = fl.reqs[i]
@@ -5100,6 +5112,7 @@ class ContinuousBatcher:
                     # The binding moved since dispatch (retire + re-admit,
                     # preempt + replay — possible only under the one-tick
                     # lag): this column belongs to a dead life. Drop it.
+                    past_end += 1
                     continue
                 # limits[i] is the slot's committable token count this tick:
                 # the full chunk in lockstep mode, the accepted prefix + 1
@@ -5115,6 +5128,16 @@ class ContinuousBatcher:
         # "update" = post-commit bookkeeping: window recycling, the
         # batched ITL flush, occupancy gauges, the sentinel sample.
         with eo.region("update"):
+            # How often the overlapped order engaged, and its known
+            # waste: rows this tick decoded for a request the commit
+            # before it had already retired (at most one tick each).
+            m = global_metrics()
+            m.inc(
+                "runtime.ticks_overlapped" if overlapped
+                else "runtime.ticks_synchronous"
+            )
+            if past_end:
+                m.inc("runtime.rows_past_end", float(past_end))
             if self._window is not None:
                 # Rolling-window recycling: pages wholly behind every future
                 # read ((o+1)*P <= pos - window + 1 — reads from here on
@@ -5170,7 +5193,8 @@ class ContinuousBatcher:
                 "admitted": self._admitted,
                 "completed": self._completed,
                 "ticks": self._ticks,
-                # Tick-runtime shape (config.RuntimeConfig): depth 1 =
+                # Tick-runtime shape AS RESOLVED (config.RuntimeConfig;
+                # unset: the constructor decided): depth 1 =
                 # synchronous dispatch+commit; depth 2 = one tick in
                 # flight between calls (inflight reports whether one is
                 # pending right now).

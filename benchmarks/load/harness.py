@@ -683,11 +683,13 @@ def main() -> int:
             sp_cfg = PrefillConfig(
                 sp_threshold=sp_threshold, sp_width=sp_width
             )
-        runtime = None
-        if runtime_arg == "async":
-            from adapt_tpu.config import RuntimeConfig
+        from adapt_tpu.config import RuntimeConfig
 
-            runtime = RuntimeConfig(pipeline_depth=2)
+        # Each arm names its order: left unset the batcher would
+        # resolve the overlapped one for both (docs/SERVING.md §3).
+        runtime = RuntimeConfig(
+            pipeline_depth=2 if runtime_arg == "async" else 1
+        )
         if placement == "disagg":
             # Same schedule, disaggregated serving path (paged decode +
             # prefill tier) — the apples-to-apples arm of the

@@ -90,7 +90,7 @@ def main() -> int:
         jax.config.update("jax_platforms", "cpu")
         import jax.numpy as jnp
 
-        from adapt_tpu.config import SLOSpec
+        from adapt_tpu.config import RuntimeConfig, SLOSpec
         from adapt_tpu.models.transformer_lm import lm_tiny
         from adapt_tpu.runtime.continuous import ContinuousBatcher
         from adapt_tpu.utils.tracing import global_tracer
@@ -107,7 +107,12 @@ def main() -> int:
         variables = lm.graph.init(
             jax.random.PRNGKey(0), jnp.zeros((1, 4), jnp.int32)
         )
-        bat = ContinuousBatcher(lm, variables, slots=slots, chunk=chunk)
+        # The synchronous arm, by name (left unset the batcher would
+        # resolve the overlapped order, measured separately below).
+        bat = ContinuousBatcher(
+            lm, variables, slots=slots, chunk=chunk,
+            runtime=RuntimeConfig(pipeline_depth=1),
+        )
         rng = np.random.RandomState(0)
         # Generous budgets that never miss: the measured cost is the
         # EVALUATION (two comparisons per commit + the per-tick flush),
@@ -222,8 +227,6 @@ def main() -> int:
         # regression on the deferred seam can't hide behind the sync
         # numbers above. Same lm (its max_len covers this shorter
         # plan); fresh batcher so jit caches and KV state don't cross.
-        from adapt_tpu.config import RuntimeConfig
-
         bat.close()
         abat = ContinuousBatcher(
             lm, variables, slots=slots, chunk=chunk,

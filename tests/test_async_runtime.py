@@ -10,6 +10,10 @@ compile footprint) survive the overlapped loop."""
 
 from __future__ import annotations
 
+import contextlib
+import json
+from pathlib import Path
+
 import numpy as np
 import pytest
 
@@ -25,7 +29,11 @@ from adapt_tpu.config import (
     SpeculativeConfig,
 )
 from adapt_tpu.control.registry import DeviceHealthMonitor
-from adapt_tpu.models.transformer_lm import generate, transformer_lm
+from adapt_tpu.models.transformer_lm import (
+    BlockSpec,
+    generate,
+    transformer_lm,
+)
 from adapt_tpu.runtime.continuous import ContinuousBatcher
 from adapt_tpu.utils.metrics import global_metrics
 from adapt_tpu.utils.profiling import global_compile_sentinel
@@ -90,13 +98,62 @@ def _staggered(bat, cancel_idx=None):
 
 
 def test_runtime_config_validation():
-    """Depths outside {1, 2} fail eagerly, by name; the ServeConfig
-    default is the synchronous loop."""
-    assert RuntimeConfig().pipeline_depth == 1
-    assert ServeConfig().runtime.pipeline_depth == 1
+    """Depths outside {1, 2} fail eagerly, by name; left unset the
+    depth is None: the batcher decides."""
+    assert RuntimeConfig().pipeline_depth is None
+    assert ServeConfig().runtime.pipeline_depth is None
     for bad in (0, 3, -1):
         with pytest.raises(ValueError, match="pipeline_depth"):
             RuntimeConfig(pipeline_depth=bad)
+
+
+def _two_group_lm():
+    """K-EXAONE's cache shape at toy widths: window layers and a full
+    one, so two cache groups."""
+    def spec(window):
+        return BlockSpec(dim=32, heads=4, kv_heads=2, head_dim=8,
+                         mlp_dim=64, window=window)
+
+    lm = transformer_lm(
+        37, blocks=[spec(8), spec(None)], max_len=64, pos="none",
+        name="async_two_groups",
+    )
+    variables = lm.graph.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 4), jnp.int32)
+    )
+    return lm, variables
+
+
+@pytest.mark.parametrize(
+    "model, runtime, depth",
+    [
+        ("one_group", None, 2),
+        ("one_group", RuntimeConfig(), 2),
+        ("one_group", _depth(1), 1),
+        ("two_groups", None, 1),
+        ("two_groups", _depth(1), 1),
+    ],
+)
+def test_unset_depth_is_resolved_by_the_batcher(
+    lm_setup, model, runtime, depth
+):
+    """The default is decided by the batcher from what its constructor
+    sees: one cache group takes the overlapped order, several the
+    synchronous one, silently; an explicit depth means what it says
+    (explicit 2 under cache groups stays refused)."""
+    lm, variables = lm_setup if model == "one_group" else _two_group_lm()
+    kw = dict(slots=2, chunk=2, page_size=8)
+    bat = ContinuousBatcher(lm, variables, runtime=runtime, **kw)
+    assert bat.stats()["pipeline_depth"] == depth
+    rid = bat.submit(PROMPTS[0], 6)
+    bat.tick()
+    bat.tick()
+    assert bat.stats()["inflight"] == (depth == 2)
+    assert len(bat.run()[rid]) == 6
+    bat.close()
+    if model == "two_groups":
+        with pytest.raises(ValueError, match="pipeline_depth 2"):
+            ContinuousBatcher(lm, variables, runtime=_depth(2), **kw)
 
 
 @pytest.mark.parametrize(
@@ -378,3 +435,143 @@ def test_async_kill_midstream_recovery_drains_pipeline(
             np.asarray([t for _, t in delivered[rid]], np.int32), solo
         )
     bat.close()
+
+
+# -- the benchmark's own driving pattern ----------------------------------
+
+#: (configuration file, traffic laid over the rehearsal): the two
+#: serving blocks the GPT-2 cells run (chunk 1 with chunked prefill,
+#: and a scanned chunk of 8), at their files' rehearsal widths, with
+#: lengths short enough for the CPU. `doc`-like: two and three
+#: 256-wide chunk passes, short answers; `batchgen`-like: whole-prompt
+#: prefill, answers of a few chunks, some ending mid-chunk.
+BENCH_PATTERNS = {
+    "gpt2-xl": dict(
+        loop="closed", cycle=8,
+        prompt=dict(dist="uniform", min=300, max=600),
+        output=dict(dist="uniform", min=3, max=12),
+    ),
+    "cerebras-gpt-1.3b": dict(
+        loop="closed", cycle=8,
+        prompt=dict(dist="uniform", min=64, max=250),
+        output=dict(dist="uniform", min=9, max=45),
+    ),
+}
+
+
+def _drive_like_the_benchmark(config_name, runtime):
+    """What ``chipbench/lm_engine.py`` does to a batcher, with its own
+    ``Driver`` and ``warm_up``, counted in requests and never timed:
+    the correctness sample's three requests and their ``logprobs``,
+    warm-up (cancel after the first token, tick to ``active == 0``),
+    a closed loop refilled from the callback's bookkeeping, a pause
+    with the last tick still in flight, an arrival after the pause.
+    Returns per submission index (tokens, logprobs), the batcher's
+    final stats and the window's counters."""
+    from chipbench import builders, lm_engine
+    from chipbench import traffic as tg
+
+    root = Path(__file__).parents[1]
+    config = json.loads(
+        (root / f"chipbench/configs/{config_name}.json").read_text()
+    )
+    model = {**config["model"], **config["rehearse"]["model"]}
+    serving = {
+        **config["serving"], **config["rehearse"]["serving"], "slots": 4,
+    }
+    traffic = BENCH_PATTERNS[config_name]
+    lm, variables, shape = builders.gpt2(model, config["dtype"], seed=5)
+    pairs = tg.templates(traffic, shape["max_len"])
+    kw = {} if runtime is None else dict(runtime=runtime)
+    srv = ContinuousBatcher(
+        lm, variables, slots=serving["slots"], chunk=serving["chunk"],
+        kv_layout=serving["kv_layout"], page_size=serving["page_size"],
+        pool_pages=lm_engine.pool_pages(serving, pairs, shape["max_len"]),
+        prefill_chunk=serving["prefill_chunk"],
+        prompt_buckets=tuple(serving["prompt_buckets"]), **kw,
+    )
+    snap = global_metrics().snapshot(window=True)
+    drv = lm_engine.Driver(srv, shape["vocab"], 5, contextlib.nullcontext)
+    # The correctness sample: two whole-prompt prefills and a chunked one.
+    sample = [
+        drv.submit(tg.Request(n, lm_engine.SAMPLE_STEPS), 0.0)
+        for n in lm_engine._sample_prompts(
+            serving["prefill_chunk"], shape["max_len"]
+        )
+    ]
+    drv.run_until(lambda: all(r not in drv.live for r in sample))
+    lps = {r: srv.logprobs(r) for r in sample}  # right after the callback
+    assert lm_engine.warm_up(drv, pairs) == len(set(pairs))
+    assert srv.stats()["active"] == 0
+    # Closed loop, a caller a slot: every caller sends two more.
+    drv.stream = tg.template_stream(pairs, 5)
+    for k, req in enumerate(tg.standing_population(pairs, serving["slots"])):
+        drv.submit(req, 0.0, client=k)
+    drv.run_until(lambda: len(drv.finished) >= 3 + 3 * serving["slots"])
+    # The callers stop; the loop ticks only while it holds a live
+    # request, like chat's open loop, so the last tick stays in flight.
+    drv.stream = None
+    drv.run_until(lambda: not drv.live)
+    paused = srv.stats()
+    assert paused["active"] == 0
+    assert paused["inflight"] == (paused["pipeline_depth"] == 2)
+    # The next arrival lands the stale tick with its own first one.
+    late = drv.submit(tg.Request(*pairs[0]), 0.0)
+    drv.run_until(lambda: not drv.live)
+    for info in drv.finished:
+        if info["rid"] not in lps:
+            lps[info["rid"]] = srv.logprobs(info["rid"])
+    stats = srv.stats()
+    counters = global_metrics().snapshot(since=snap)["counters"]
+    srv.close()
+    cancelled = [r for r, info in drv.reqs.items() if info not in drv.finished]
+    assert late in lps and drv.failed == 0
+    for info in drv.finished:  # no token lost, duplicated or reordered
+        assert info["emitted"] == info["out_len"] == len(info["tokens"])
+        assert len(lps[info["rid"]]) == info["out_len"]
+    streams = {
+        rid: (info["tokens"], lps.get(rid)) for rid, info in drv.reqs.items()
+        if rid not in cancelled
+    }
+    return streams, stats, counters, len(cancelled)
+
+
+@pytest.mark.parametrize("config_name", sorted(BENCH_PATTERNS))
+def test_the_benchmarks_driving_pattern_is_bit_identical_overlapped(
+    config_name,
+):
+    """The paths the benchmark drives and no test had run overlapped
+    (manual ``tick()`` with callbacks, refill between ticks, cancel
+    then tick to empty, blocking first-token reads in the dispatch
+    half, ``logprobs`` after the last callback, a pause with a tick in
+    flight): a batcher built with no ``runtime=`` serves them in the
+    overlapped order, token for token and logprob for logprob what the
+    explicit synchronous order serves. The three counters say how
+    often the order engaged and what it wasted."""
+    sync, st1, c1, n1 = _drive_like_the_benchmark(config_name, _depth(1))
+    over, st2, c2, n2 = _drive_like_the_benchmark(config_name, None)
+    assert (st1["pipeline_depth"], st2["pipeline_depth"]) == (1, 2)
+    # Submission k is the same request under both orders (the driver
+    # draws ids and lengths by submission index).
+    assert sync.keys() == over.keys() and n1 == n2
+    for rid in sync:
+        assert over[rid][0] == sync[rid][0], f"request {rid}: tokens"
+        np.testing.assert_array_equal(
+            over[rid][1], sync[rid][1], err_msg=f"request {rid}: logprobs"
+        )
+    assert st2["completed"] == st1["completed"]
+    assert st2["active"] == st2["queued"] == 0
+    # Synchronous: every commit lands before the next dispatch, and no
+    # row is decoded for a request already finished.
+    assert c1.get("runtime.ticks_overlapped", 0) == 0
+    assert c1.get("runtime.rows_past_end", 0) == 0
+    assert c1["runtime.ticks_synchronous"] == c1["continuous.ticks"]
+    # Overlapped: nearly every commit had a dispatch behind it (the
+    # ones before a pause or an idle tick had not), and the waste is
+    # at most one tick's row a request that left (finished or cancelled).
+    assert c2["runtime.ticks_overlapped"] > 0.8 * c2["continuous.ticks"]
+    assert (
+        c2["runtime.ticks_overlapped"] + c2.get("runtime.ticks_synchronous", 0)
+        <= c2["continuous.ticks"]
+    )
+    assert 0 < c2["runtime.rows_past_end"] <= st2["completed"]

@@ -10,6 +10,7 @@ import numpy as np
 import pytest
 from jax.profiler import TraceAnnotation
 
+from adapt_tpu.config import RuntimeConfig
 from adapt_tpu.models.transformer_lm import lm_tiny
 from adapt_tpu.runtime.continuous import ContinuousBatcher
 from adapt_tpu.utils.metrics import global_metrics
@@ -53,6 +54,9 @@ def _paged_batcher(lm_setup):
     return ContinuousBatcher(
         lm, variables, slots=2, chunk=2, kv_layout="paged", page_size=8,
         pool_pages=20, prefill_chunk=8, prompt_buckets=(8, 16, 32),
+        # These tests count spans and samples PER TICK (a launch and
+        # its commit half in the same call): the synchronous order.
+        runtime=RuntimeConfig(pipeline_depth=1),
     )
 
 

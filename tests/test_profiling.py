@@ -15,6 +15,7 @@ import numpy as np
 import pytest
 
 from benchmarks import ci_gate
+from adapt_tpu.config import RuntimeConfig
 from adapt_tpu.models.transformer_lm import lm_tiny
 from adapt_tpu.runtime.continuous import ContinuousBatcher
 from adapt_tpu.utils import profiling
@@ -220,7 +221,13 @@ def test_batcher_forced_shape_change_fires_sentinel(lm_setup):
     old_warmup = sent.warmup_samples
     sent.warmup_samples = 3
     try:
-        bat = ContinuousBatcher(lm, variables, slots=2, chunk=2)
+        # One sample per phase PER TICK is the assertion below: the
+        # synchronous order (overlapped, a tick's decode and commit
+        # samples land one call later).
+        bat = ContinuousBatcher(
+            lm, variables, slots=2, chunk=2,
+            runtime=RuntimeConfig(pipeline_depth=1),
+        )
         prompt = np.asarray([1, 2, 3], np.int32)
         r1 = bat.submit(prompt, 40)
 
