@@ -49,6 +49,7 @@ from adapt_tpu.ops.paged_attention import (
     pool_values,
 )
 from adapt_tpu.models.moe import ExpertSpec, MoEDecoderMlp, RoutedExperts
+from adapt_tpu.models.ssm import Mamba2Mixer, SsmSpec, scaled
 from adapt_tpu.ops.quantize import quantize_kv_vectors, unpack_int4
 
 _NEG_INF = -1e30
@@ -106,6 +107,21 @@ class BlockSpec:
     rope_base: float | None = None
     #: Attend the previous ``window`` positions only; None: all.
     window: int | None = None
+    #: A state-space mixer IN PARALLEL with the attention, on the same
+    #: normed input, both added to the residual (``models/ssm``): the
+    #: block then owns a recurrent state beside its KV pages, and only
+    #: the schedules that carry one serve it (the full forward,
+    #: ``prefill``, ``prefill_chunk_paged``, ``decode_step_paged``).
+    ssm: SsmSpec | None = None
+    #: Scalar multipliers a family puts on its branches (muP-style):
+    #: on the attention's input, on K after its projection, on the
+    #: attention's output; on the gate before its activation and on the
+    #: MLP's output. The mixer's own are in ``ssm``.
+    attn_in_mult: float = 1.0
+    key_mult: float = 1.0
+    attn_out_mult: float = 1.0
+    mlp_gate_mult: float = 1.0
+    mlp_out_mult: float = 1.0
 
     def __post_init__(self):
         if self.head_dim is None and self.dim % self.heads:
@@ -134,6 +150,11 @@ class BlockSpec:
             raise ValueError(f"mlp={self.mlp!r}")
         if (self.mlp == "experts") != (self.experts is not None):
             raise ValueError("mlp='experts' goes with an ExpertSpec")
+        if self.ssm is not None and self.post_norm:
+            raise ValueError(
+                "a mixer beside the attention reads the block's normed "
+                "INPUT: post_norm has none"
+            )
 
     @property
     def attn_head_dim(self) -> int:
@@ -274,6 +295,7 @@ class CausalSelfAttention(nn.Module):
             k, v = jnp.moveaxis(self.kv_proj(x), 2, 0)  # (b, s, kv_h, hd)
         if self.spec.qk_norm:  # over head_dim, before any rotation
             q, k = self.q_norm(q), self.k_norm(k)
+        k = scaled(k, self.spec.key_mult)
         # -> (b, heads-axis, s, hd)
         return tuple(jnp.swapaxes(t, 1, 2) for t in (q, k, v))
 
@@ -820,6 +842,8 @@ class DecoderBlock(nn.Module):
         self.ln1 = _norm(spec.norm, spec.norm_eps, self.dtype)
         self.attn = CausalSelfAttention(spec, dtype=self.dtype)
         self.ln2 = _norm(spec.norm, spec.norm_eps, self.dtype)
+        if spec.ssm is not None:
+            self.ssm = Mamba2Mixer(spec.ssm, spec.dim, dtype=self.dtype)
         if spec.mlp == "experts":
             self.experts = RoutedExperts(spec.experts, dtype=self.dtype)
         elif spec.mlp == "moe_dense":
@@ -845,14 +869,46 @@ class DecoderBlock(nn.Module):
         if kind == "moe_dense":
             return self.moe(x)
         if kind == "gated_silu":
-            return self.mlp_out(nn.silu(self.mlp_gate(x)) * self.mlp_in(x))
+            gate = scaled(self.mlp_gate(x), self.spec.mlp_gate_mult)
+            return scaled(
+                self.mlp_out(nn.silu(gate) * self.mlp_in(x)),
+                self.spec.mlp_out_mult,
+            )
         return self.mlp_out(nn.gelu(self.mlp_in(x)))
 
     def _attn_in(self, x):
+        """The normed input the block's mixers read (the attention's
+        own multiplier goes on in ``_attn``)."""
         return x if self.spec.post_norm else self.ln1(x)
 
-    def _attn_res(self, x, a):
+    def _attn_res(self, x, a, s=None):
+        """The residual after the token mixers: the attention's output
+        ``a`` and, beside it, the state-space mixer's ``s``."""
+        a = scaled(a, self.spec.attn_out_mult)
+        if s is not None:
+            a = a + scaled(s, self.spec.ssm.out_mult)
         return x + (self.ln1(a) if self.spec.post_norm else a)
+
+    def _mixers(self, x, attend, mix=None):
+        """The one shape every schedule of a block has: ``attend(u)``
+        -> ``(a, *cache)`` is the schedule's attention call on the
+        normed input ``u``; a block with a state-space mixer also runs
+        ``mix(ssm, u)`` -> ``(s, carried')`` on the SAME ``u`` and
+        returns ``carried'`` last."""
+        u = self._attn_in(x)
+        a, *cache = attend(scaled(u, self.spec.attn_in_mult))
+        if self.spec.ssm is None:
+            return (self._mlp_res(self._attn_res(x, a)), *cache)
+        s, carried = mix(self.ssm, u)
+        return (self._mlp_res(self._attn_res(x, a, s)), *cache, carried)
+
+    def _no_state(self, schedule: str):
+        if self.spec.ssm is not None:
+            raise NotImplementedError(
+                f"{schedule} carries no recurrent state: a block with a "
+                "state-space mixer serves through prefill, "
+                "prefill_chunk_paged and decode_step_paged"
+            )
 
     def _mlp_res(self, x):
         if self.spec.post_norm:
@@ -860,64 +916,92 @@ class DecoderBlock(nn.Module):
         return x + self._mlp(self.ln2(x))
 
     def __call__(self, x):
-        x = self._attn_res(x, self.attn(self._attn_in(x)))
-        return self._mlp_res(x)
+        return self._mixers(
+            x, lambda u: (self.attn(u),), lambda ssm, u: (ssm(u), None)
+        )[0]
 
-    def prefill(self, x, max_len: int, valid_from=None, quantize_cache=False):
-        a, ck, cv = self.attn.prefill(
-            self._attn_in(x), max_len, valid_from, quantize_cache
+    def prefill(self, x, max_len: int, valid_from=None, quantize_cache=False,
+                length=None):
+        """``length`` (a block with a state-space mixer; batch 1): the
+        prompt's real length inside ``x``'s bucket; the recurrent
+        ``(state, tail)`` after its last real position comes back
+        last."""
+        if self.spec.ssm is not None and valid_from is not None:
+            self._no_state("a left-padded (ragged) prefill")
+        return self._mixers(
+            x,
+            lambda u: self.attn.prefill(u, max_len, valid_from, quantize_cache),
+            lambda ssm, u: ssm.scan(u, None, length),
         )
-        return self._mlp_res(self._attn_res(x, a)), ck, cv
 
     def decode_step(
         self, x_t, cache_k, cache_v, index, valid_from=None, quantized=False,
         attn_impl=None, split=None,
     ):
-        a, ck, cv = self.attn.decode_step(
-            self._attn_in(x_t), cache_k, cache_v, index, valid_from,
-            quantized, attn_impl, split,
-        )
-        return self._mlp_res(self._attn_res(x_t, a)), ck, cv
+        self._no_state("decode_step over dense cache strips")
+        return self._mixers(x_t, lambda u: self.attn.decode_step(
+            u, cache_k, cache_v, index, valid_from, quantized, attn_impl,
+            split,
+        ))
 
     def decode_step_paged(
         self, x_t, pool, page_table, index, valid_from=None,
-        attn_impl=None, split=None, head_shard=None,
+        attn_impl=None, split=None, head_shard=None, carried=None,
     ):
-        a, pool = self.attn.decode_step_paged(
-            self._attn_in(x_t), pool, page_table, index, valid_from,
-            attn_impl, split, head_shard,
+        """``carried``: the rows' recurrent ``(state, tail)`` where the
+        block has a state-space mixer; advanced for the rows whose
+        ``index`` is not negative and returned last."""
+        return self._mixers(
+            x_t,
+            lambda u: self.attn.decode_step_paged(
+                u, pool, page_table, index, valid_from, attn_impl, split,
+                head_shard,
+            ),
+            lambda ssm, u: ssm.step(
+                u, carried,
+                jnp.broadcast_to(jnp.asarray(index).reshape(-1) >= 0,
+                                 (x_t.shape[0],)),
+            ),
         )
-        return self._mlp_res(self._attn_res(x_t, a)), pool
 
     def prefill_chunk_paged(
         self, x, pool, pages, pos0, attn_impl=None, head_shard=None,
+        carried=None, length=None,
     ):
-        a, pool = self.attn.prefill_chunk_paged(
-            self._attn_in(x), pool, pages, pos0, attn_impl, head_shard
+        """``carried`` / ``length`` as in ``decode_step_paged`` /
+        ``prefill``: the pass starts from the state the pass before
+        left and returns the one after its last real position."""
+        return self._mixers(
+            x,
+            lambda u: self.attn.prefill_chunk_paged(
+                u, pool, pages, pos0, attn_impl, head_shard
+            ),
+            lambda ssm, u: ssm.scan(u, carried, length),
         )
-        return self._mlp_res(self._attn_res(x, a)), pool
 
     def prefill_sp(self, x, gather, quantize_cache=False, constrain=None):
-        a, ck, cv = self.attn.prefill_sp(
-            self._attn_in(x), gather, quantize_cache, constrain
-        )
-        return self._mlp_res(self._attn_res(x, a)), ck, cv
+        self._no_state("sequence-parallel prefill")
+        return self._mixers(x, lambda u: self.attn.prefill_sp(
+            u, gather, quantize_cache, constrain
+        ))
 
     def verify_chunk(self, x, cache_k, cache_v, index, tree_tail=0):
-        a, ck, cv = self.attn.verify_chunk(
-            self._attn_in(x), cache_k, cache_v, index, tree_tail
-        )
-        return self._mlp_res(self._attn_res(x, a)), ck, cv
+        self._no_state("verify_chunk (a rejected token cannot be un-stepped)")
+        return self._mixers(x, lambda u: self.attn.verify_chunk(
+            u, cache_k, cache_v, index, tree_tail
+        ))
 
     def verify_chunk_paged(
         self, x, pool, page_table, index, attn_impl=None,
         tree_tail=0, split=None, head_shard=None,
     ):
-        a, pool = self.attn.verify_chunk_paged(
-            self._attn_in(x), pool, page_table, index, attn_impl,
-            tree_tail, split, head_shard,
+        self._no_state(
+            "verify_chunk_paged (a rejected token cannot be un-stepped)"
         )
-        return self._mlp_res(self._attn_res(x, a)), pool
+        return self._mixers(x, lambda u: self.attn.verify_chunk_paged(
+            u, pool, page_table, index, attn_impl, tree_tail, split,
+            head_shard,
+        ))
 
 
 class TokenEmbed(nn.Module):
@@ -933,6 +1017,8 @@ class TokenEmbed(nn.Module):
     max_len: int
     dtype: jnp.dtype = jnp.float32
     use_pos: bool = True
+    #: On the looked-up rows (a family's embedding multiplier).
+    scale: float = 1.0
 
     def setup(self):
         self.tok = nn.Embed(self.vocab, self.dim, dtype=self.dtype)
@@ -946,14 +1032,14 @@ class TokenEmbed(nn.Module):
 
     def __call__(self, ids):
         s = ids.shape[1]
-        out = self.tok(ids)
+        out = scaled(self.tok(ids), self.scale)
         if self.use_pos:
             out = out + self.pos[:s].astype(self.dtype)
         return out
 
     def embed_at(self, ids_t, index):
         """Embed a single token column at traced position ``index``."""
-        out = self.tok(ids_t)
+        out = scaled(self.tok(ids_t), self.scale)
         if self.use_pos:
             p = lax.dynamic_slice(self.pos, (index, 0), (1, self.dim))
             out = out + p.astype(self.dtype)
@@ -963,7 +1049,7 @@ class TokenEmbed(nn.Module):
         """Embed with explicit per-row position ids (ragged batches:
         a left-padded row's logical positions start at 0 at its first
         real token, not at buffer column 0)."""
-        out = self.tok(ids)
+        out = scaled(self.tok(ids), self.scale)
         if self.use_pos:
             out = out + self.pos[jnp.clip(pos_ids, 0)].astype(self.dtype)
         return out
@@ -979,6 +1065,8 @@ class LMHead(nn.Module):
     norm: str = "layernorm"
     norm_eps: float = 1e-6
     bias: bool = True
+    #: On the logits (a family's head multiplier).
+    scale: float = 1.0
 
     def setup(self):
         self.ln = _norm(self.norm, self.norm_eps, self.dtype)
@@ -987,7 +1075,8 @@ class LMHead(nn.Module):
         )
 
     def __call__(self, x):
-        return self.logits(self.ln(x).astype(jnp.float32))
+        out = self.logits(self.ln(x).astype(jnp.float32))
+        return out if self.scale == 1.0 else out * self.scale
 
 
 @dataclasses.dataclass(frozen=True)
@@ -1025,6 +1114,8 @@ def transformer_lm(
     window: int | None = None,
     pos: str = "learned",
     blocks: Sequence[BlockSpec] | None = None,
+    embed_scale: float = 1.0,
+    head_scale: float = 1.0,
 ) -> TransformerLM:
     """A decoder is a LIST OF BLOCK SPECS (:class:`BlockSpec`) between
     an embedding and a head. ``blocks=`` gives the list as a model's
@@ -1057,6 +1148,9 @@ def transformer_lm(
     changes; blocks behind the window skip compute), and the paged
     batcher RECYCLES pages that fall wholly behind it mid-request —
     pool usage bounds by the window, not the sequence.
+
+    ``embed_scale`` / ``head_scale``: a family's multipliers on the
+    embedded rows and on the logits.
     """
     if blocks is None:
         if pos not in ("learned", "rope"):
@@ -1080,7 +1174,7 @@ def transformer_lm(
     prev = g.add(
         "embed",
         TokenEmbed(vocab, dim, max_len, dtype=dtype,
-                   use_pos=pos == "learned"),
+                   use_pos=pos == "learned", scale=embed_scale),
         INPUT,
     )
     for i, spec in enumerate(blocks):
@@ -1090,7 +1184,7 @@ def transformer_lm(
     g.add(
         "head",
         LMHead(vocab, dtype=dtype, norm=last.norm, norm_eps=last.norm_eps,
-               bias=last.bias),
+               bias=last.bias, scale=head_scale),
         prev,
     )
     return TransformerLM(graph=g, depth=len(blocks), max_len=max_len)
@@ -1115,6 +1209,12 @@ def validate_tp(lm: TransformerLM, tp: int) -> None:
         return
     for name in lm.block_names:
         block = lm.graph.node(name).module
+        if block.spec.ssm is not None:
+            raise ValueError(
+                f"{name}: a state-space mixer does not split over tp (its "
+                "recurrent state would shard by head beside the KV heads; "
+                "no rule places it)"
+            )
         if block.spec.mlp == "experts":
             raise ValueError(
                 f"{name}: routed experts do not split over tp (a chip "
@@ -1230,6 +1330,12 @@ def validate_generate_args(
     decoder: returns ``(lengths, rng, do_sample)`` with every constraint
     checked eagerly (clear ValueErrors instead of opaque trace errors)."""
     b, s0 = prompt.shape
+    if any(lm.graph.node(n).module.spec.ssm for n in lm.block_names):
+        raise ValueError(
+            "generate() decodes over dense cache strips, which carry no "
+            "recurrent state: a model with state-space mixers serves "
+            "through ContinuousBatcher"
+        )
     if steps < 1:
         raise ValueError(f"steps must be >= 1, got {steps}")
     if s0 + steps > lm.max_len:

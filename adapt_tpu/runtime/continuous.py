@@ -290,6 +290,7 @@ from adapt_tpu.config import (
 )
 from adapt_tpu.control.registry import weak_watch
 from adapt_tpu.models.speculative import accept_speculation, draft_chunk
+from adapt_tpu.models.ssm import zero_state
 from adapt_tpu.models.transformer_lm import (
     TransformerLM,
     chosen_logprob,
@@ -846,6 +847,37 @@ class ContinuousBatcher:
         self._moe_blocks = tuple(
             i for i, sp in enumerate(specs) if sp.mlp == "experts"
         )
+        #: RECURRENT STATE beside the pages: a block with a state-space
+        #: mixer (``BlockSpec.ssm``) keeps, per SLOT, a ``(state, tail)``
+        #: pair (``models/ssm``). Not paged: every request holds exactly
+        #: one, overwritten in every step and written WHOLE at admission
+        #: (so whatever a dead row's steps left in a retired slot never
+        #: reaches its next tenant). Device-resident and donated through
+        #: every program that advances it, like the pools.
+        self._ssm_blocks = tuple(
+            i for i, sp in enumerate(specs) if sp.ssm is not None
+        )
+        self._states = tuple(
+            zero_state(specs[i].ssm, slots, self._blocks[i].dtype)
+            for i in self._ssm_blocks
+        ) or None
+        if self._ssm_blocks:
+            unsupported = {
+                "a draft model (speculative decoding: a rejected token "
+                "cannot be un-stepped)": draft_lm,
+                "a host cache tier": cache_tier,
+                "sequence-parallel prefill": prefill,
+                "cache-aware admission (the radix prefix cache)": (
+                    scheduler is not None and scheduler.cache_aware
+                ) or None,
+                "elastic recovery (health=)": health,
+                "a quantized KV pool beside it (untested)": (
+                    kv_cache_dtype != "native"
+                ) or None,
+            }
+            for what, given in unsupported.items():
+                if given is not None:
+                    self._pages_only(what)
         #: Sliding-window models: decode masking lives in the model;
         #: the batcher's job is page RECYCLING behind the window.
         self._window = groups[0].window
@@ -1531,10 +1563,10 @@ class ContinuousBatcher:
         jax.jit,
         static_argnums=(0,),
         static_argnames=("truncate", "nucleus", "epoch"),
-        donate_argnums=(2, 3),
+        donate_argnums=(2, 3, 5),
     )
-    def _step_chunk(self, variables, caches, dstate, table, *,
-                    truncate, nucleus, epoch=0):
+    def _step_chunk(self, variables, caches, dstate, table, states=None,
+                    *, truncate, nucleus, epoch=0):
         """``chunk`` lockstep decode steps as one compiled scan over the
         DEVICE-RESIDENT slot state.
 
@@ -1555,9 +1587,14 @@ class ContinuousBatcher:
         optimistic pos advance; rows whose request retires mid-chunk are
         cleared host-side (``_clear_slot``) before the next tick.
         Returns ((chunk, B) emitted tokens, logprobs, caches, dstate,
-        moe); ONE host sync per call, not per token. ``moe`` is None
-        for a model without routed experts, else one int32 vector the
-        tick fetches with the tokens (``_moe_counts``)."""
+        moe, states); ONE host sync per call, not per token. ``moe`` is
+        None for a model without routed experts, else one int32 vector
+        the tick fetches with the tokens (``_moe_counts``). ``states``
+        (None for a model without recurrent state): per block with a
+        state-space mixer the slots' ``(state, tail)``, donated in,
+        advanced by every live row in every step, returned out; a dead
+        row's (negative position: idle, or mid-chunked-prefill INTO
+        this state) is left as it was."""
         caches = self._shard_kv(caches)
         dstate = self._repl_state(dstate)
         C = self.chunk
@@ -1580,12 +1617,12 @@ class ContinuousBatcher:
         )
 
         def body(carry, step_keys):
-            tokens, pos, caches = carry
+            tokens, pos, caches, states = carry
             x = self._embed.apply(
                 variables["embed"], tokens[:, None], pos[:, None],
                 method="embed_positions",
             )
-            new_caches = []
+            new_caches, new_states = [], []
             held = []  # per expert block: (B, held) assignments a row
             for i, (name, block, pool) in enumerate(zip(
                 self.lm.block_names, self._blocks, caches
@@ -1599,14 +1636,16 @@ class ContinuousBatcher:
                     method="decode_step_paged",
                     mutable=["intermediates"] if i in self._moe_blocks
                     else False,
+                    **self._carried(states, i),
                 )
                 if i in self._moe_blocks:
                     out, sown = out
                     held.append(
                         sown["intermediates"]["experts"]["held_tokens"][0]
                     )
-                x, pool = out
+                x, pool, *carried = out
                 new_caches.append(pool)
+                new_states.extend(carried)
             logits = self._head.apply(variables["head"], x)[:, 0]  # (B, V)
             pick_greedy = jnp.argmax(logits, axis=-1)
             lg = logits / jnp.maximum(temps, 1e-6)[:, None]
@@ -1632,10 +1671,16 @@ class ContinuousBatcher:
                     ),  # (expert blocks, held)
                     jnp.sum(live, dtype=jnp.int32),
                 )
-            return (nxt, pos + 1, tuple(new_caches)), (nxt, lp, moe)
+            return (
+                (nxt, pos + 1, tuple(new_caches), tuple(new_states)),
+                (nxt, lp, moe),
+            )
 
-        (_, _, caches), (toks, lps, moe) = lax.scan(
-            body, (dstate["tok"], dstate["pos"], tuple(caches)), keys
+        (_, _, caches, states), (toks, lps, moe) = lax.scan(
+            body,
+            (dstate["tok"], dstate["pos"], tuple(caches),
+             tuple(states or ())),
+            keys,
         )
         if moe is not None:
             per_step, rows = moe  # (C, blocks, held), (C,)
@@ -1656,8 +1701,17 @@ class ContinuousBatcher:
         new["kbase"] = jnp.where(active, kbase + C, 0)
         return (
             toks, lps, self._shard_kv(list(caches)),
-            self._repl_state(new), moe,
+            self._repl_state(new), moe, states or None,
         )
+
+    def _carried(self, states, block: int) -> dict:
+        """``decode_step_paged`` / ``prefill_chunk_paged``'s keyword
+        for block ``block``: its ``(state, tail)`` among a program's
+        ``states`` (one a block with a state-space mixer, in block
+        order), nothing for a block without one."""
+        if block not in self._ssm_blocks:
+            return {}
+        return {"carried": states[self._ssm_blocks.index(block)]}
 
     def _table_of(self, table, block: int):
         """Block ``block``'s page table (or page list) among a
@@ -2021,6 +2075,7 @@ class ContinuousBatcher:
         quantization, block count/shapes) — a malformed handoff must
         fail by name, never scatter garbage into live pages."""
         self._one_cache_group("a handoff of prefilled pages")
+        self._pages_only("a handoff of prefilled pages")
         # The device-lost gate tick() runs: a handoff landing between
         # ticks must not device_put shard slices onto a dead device or
         # dispatch the adoption program at a stale mesh epoch (the
@@ -2388,6 +2443,7 @@ class ContinuousBatcher:
         measures the host tier's servable-prefix multiplier with
         it)."""
         self._one_cache_group("the radix prefix cache")
+        self._pages_only("the radix prefix cache")
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         n = 0
         for j in range((prompt.shape[0] - 1) // self._page):
@@ -2438,7 +2494,9 @@ class ContinuousBatcher:
     def _prefill_fn(self, bucket: int):
         """Jitted prefill for one prompt bucket: full causal forward over
         (1, bucket), logits at the TRUE last position, per-block K/V to
-        insert into a slot."""
+        insert into a slot and, last, the recurrent ``(state, tail)`` of
+        each block that has one (``_insert_state``; an empty tuple
+        otherwise)."""
         if bucket in self._prefill_cache:
             return self._prefill_cache[bucket]
 
@@ -2452,20 +2510,26 @@ class ContinuousBatcher:
         def prefill(variables, ids, ints, floats, keys, *, truncate,
                     nucleus):
             h = self._embed.apply(variables["embed"], ids)
-            kvs = []
-            for name, block in zip(self.lm.block_names, self._blocks):
-                h, ck, cv = block.apply(
+            kvs, states = [], []
+            for i, (name, block) in enumerate(
+                zip(self.lm.block_names, self._blocks)
+            ):
+                # A block with recurrent state also returns the
+                # (state, tail) its LAST REAL position left.
+                h, ck, cv, *carried = block.apply(
                     variables[name], h, bucket, None,
                     self._kv_dtype if self._kv_quant else False,
                     method="prefill",
+                    **({"length": ints[0]} if i in self._ssm_blocks else {}),
                 )
                 kvs.append(fuse_kv(ck, cv))  # the pool's rows
+                states.extend(carried)
             h_last = lax.dynamic_index_in_dim(h, ints[0] - 1, 1)
             first, first_lp = self._first_pick(
                 h_last, variables, keys, floats[0], ints[1], floats[1],
                 floats[0] == 0.0, truncate, nucleus,
             )
-            return first, first_lp, self._shard_kv(kvs)
+            return first, first_lp, self._shard_kv(kvs), tuple(states)
 
         self._prefill_cache[bucket] = prefill
         return prefill
@@ -2496,36 +2560,52 @@ class ContinuousBatcher:
         # are donated (they alias in place); ids staging is not (int32
         # can't alias the outputs — donation would only warn).
         @partial(jax.jit, static_argnames=("truncate", "nucleus"),
-                 donate_argnums=(1,))
+                 donate_argnums=(1, 7))
         def prefill(variables, caches, pages, ids, ints, floats, keys,
-                    *, truncate, nucleus):
+                    states=None, *, truncate, nucleus):
+            # ``states`` (a model with recurrent state; ints then ends
+            # with the SLOT): the pass starts from the slot's own
+            # (state, tail), an empty one at position 0, and leaves the
+            # one after its last real position there.
             caches = self._shard_kv(caches)
             pos0 = ints[0]
             pos_ids = pos0 + jnp.arange(sbucket)[None]
             h = self._embed.apply(
                 variables["embed"], ids, pos_ids, method="embed_positions"
             )
-            new_caches = []
-            for name, block, pool in zip(
+            new_caches, new_states = [], []
+            for i, (name, block, pool) in enumerate(zip(
                 self.lm.block_names, self._blocks, caches
-            ):
-                h, pool = block.apply(
+            )):
+                kw = {}
+                if i in self._ssm_blocks:
+                    kw = {"length": ints[1], "carried": jax.tree.map(
+                        lambda s: jnp.where(
+                            pos0 == 0, jnp.zeros((), s.dtype),
+                            lax.dynamic_slice_in_dim(s, ints[3], 1),
+                        ),
+                        self._carried(states, i)["carried"],
+                    )}
+                h, pool, *carried = block.apply(
                     variables[name], h, pool,
-                    self._table_of(pages, len(new_caches)), pos0,
+                    self._table_of(pages, i), pos0,
                     head_shard=self._head_shard(),
-                    method="prefill_chunk_paged",
+                    method="prefill_chunk_paged", **kw,
                 )
                 new_caches.append(pool)
+                new_states.extend(carried)
             new_caches = self._shard_kv(new_caches)
+            if states is not None:
+                states = self._write_state(states, ints[3], new_states)
             if not sample:  # mid-prefill pass: no token yet
                 return (jnp.zeros((1,), jnp.int32),
-                        jnp.zeros((1,), jnp.float32), new_caches)
+                        jnp.zeros((1,), jnp.float32), new_caches, states)
             h_last = lax.dynamic_index_in_dim(h, ints[1] - 1, 1)
             first, first_lp = self._first_pick(
                 h_last, variables, keys, floats[0], ints[2], floats[1],
                 floats[0] == 0.0, truncate, nucleus,
             )
-            return first, first_lp, new_caches
+            return first, first_lp, new_caches, states
 
         self._prefill_cache[key] = prefill
         return prefill
@@ -2592,6 +2672,31 @@ class ContinuousBatcher:
             )
             for c_pair, n_pair in zip(caches, kvs)
         ]
+
+    @staticmethod
+    def _write_state(states, slot, new):
+        """Row ``slot`` of every ``(state, tail)`` <- ``new``'s one
+        row, whole."""
+        return jax.tree.map(
+            lambda s, n: lax.dynamic_update_slice_in_dim(
+                s, n.astype(s.dtype), slot, 0
+            ),
+            tuple(states), tuple(new),
+        )
+
+    @partial(jax.jit, static_argnums=(0,), donate_argnums=(1,))
+    def _insert_state(self, states, slot, new):
+        """Write a prefilled request's recurrent state into its slot
+        (the whole-prompt admission's twin of ``_insert_paged``)."""
+        return self._write_state(states, slot, new)
+
+    def _count_state_write(self, carried: bool) -> None:
+        """Book one write of a slot's recurrent state (an admission or
+        a chunk pass) and whether the pass began from a carried one."""
+        if self._ssm_blocks:
+            global_metrics().inc("ssm.state_writes")
+            if carried:
+                global_metrics().inc("ssm.chunks_carried")
 
     # -- request lifecycle -------------------------------------------------
 
@@ -2875,6 +2980,7 @@ class ContinuousBatcher:
         caller that must know them should submit serially); the group
         shrinks to the survivors."""
         self._one_cache_group("copy-on-write fan-out")
+        self._pages_only("copy-on-write fan-out")
         if n < 1:
             raise ValueError(f"fan-out width must be >= 1, got {n}")
         sib_rngs: list = [None] * n
@@ -4153,8 +4259,10 @@ class ContinuousBatcher:
                 # and then share below as ordinary hits.
                 self._maybe_readmit(req)
             # (A model with several cache groups shares nothing: a hit
-            # would need every window layer's last positions too.)
-            for j in range((s0 - 1) // P if len(self._groups) == 1 else 0):
+            # would need every window layer's last positions too; one
+            # with recurrent state nothing either: a hit grants pages
+            # and no state.)
+            for j in range((s0 - 1) // P if self._shares_pages else 0):
                 key = Pager.prefix_key(req.prompt, (j + 1) * P)
                 if self._pager.lookup_share(i, key) is None:
                     break
@@ -4274,7 +4382,7 @@ class ContinuousBatcher:
                 pages = owned[:n_strip] + [0] * (n_pad - n_strip)
                 ids = np.zeros((1, sbucket), np.int32)
                 ids[0, :slen] = req.prompt[m * self._page:]
-                first, first_lp, self._caches = self._prefill_suffix_fn(
+                first, first_lp, self._caches, _ = self._prefill_suffix_fn(
                     sbucket, n_pad
                 )(
                     self.variables,
@@ -4300,7 +4408,7 @@ class ContinuousBatcher:
                 # other ordinals point at the trash page, which takes
                 # (and never gives back) the rest of the bucket.
                 self._hold_groups(i, s0, s0)
-                first, first_lp, kvs = self._prefill_fn(bucket)(
+                first, first_lp, kvs, carried = self._prefill_fn(bucket)(
                     self.variables,
                     self._h2d(ids),
                     self._h2d(np.array([s0, req.top_k], np.int32)),
@@ -4316,9 +4424,14 @@ class ContinuousBatcher:
                     self._pages_of(i, len(self._pager.owned(i))),
                     kvs,
                 )
+                if carried:
+                    self._states = self._insert_state(
+                        self._states, self._h2d(np.int32(i)), carried
+                    )
+                    self._count_state_write(False)
                 self._count_prefill(s0)
                 cap_tokens = s0
-            if not chunked and len(self._groups) == 1:
+            if not chunked and self._shares_pages:
                 # Publish this request's full prompt pages for future
                 # sharing (first writer wins; the shared ones are
                 # already registered). Chunked admissions register on
@@ -4536,6 +4649,23 @@ class ContinuousBatcher:
                 f"({', '.join(g.name for g in self._groups)}) yet"
             )
 
+    def _pages_only(self, what: str) -> None:
+        """Refuse ``what`` for a model with recurrent state: it moves
+        or shares PAGES, and a request's pages without the state that
+        belongs to the same position are half a cache."""
+        if self._ssm_blocks:
+            raise ValueError(
+                f"{what} does not run for a model with recurrent state "
+                f"({len(self._ssm_blocks)} blocks keep a state-space "
+                "mixer's state a slot beside their pages) yet"
+            )
+
+    @property
+    def _shares_pages(self) -> bool:
+        """Whether a prompt page may be shared between requests (the
+        radix prefix cache): one cache group and no recurrent state."""
+        return len(self._groups) == 1 and not self._ssm_blocks
+
     def _hold_groups(self, slot: int, lo_pos: int, hi_pos: int) -> None:
         """Before a pass that writes positions ``[lo_pos, hi_pos)`` of
         ``slot`` (or, ``lo_pos == hi_pos``, one that only needs what
@@ -4595,23 +4725,31 @@ class ContinuousBatcher:
             self._hold_groups(slot.idx, pos0, pos0 + cbucket)
             ids = np.zeros((1, cbucket), np.int32)
             ids[0, :clen] = req.prompt[pos0:pos0 + clen]
-            first, first_lp, self._caches = self._prefill_suffix_fn(
+            # A model with recurrent state names the slot whose state
+            # the pass carries on from.
+            ints = [pos0, clen, req.top_k] + (
+                [slot.idx] if self._ssm_blocks else []
+            )
+            (first, first_lp, self._caches,
+             self._states) = self._prefill_suffix_fn(
                 cbucket, n_pad, sample=final
             )(
                 self.variables,
                 self._caches,
                 self._pages_of(slot.idx, n_strip, n_pad),
                 self._h2d(ids),
-                self._h2d(np.array([pos0, clen, req.top_k], np.int32)),
+                self._h2d(np.array(ints, np.int32)),
                 self._h2d(np.array(
                     [req.temperature, req.top_p], np.float32
                 )),
                 self._h2d(req.folded_keys[0][None]),
+                self._states,
                 # Only the final pass samples; mid-prefill passes must not
                 # fork compile variants over sampling flags they never use.
                 truncate=final and req.top_k < self.lm.vocab,
                 nucleus=final and req.top_p < 1.0,
             )
+            self._count_state_write(pos0 > 0)
             slot.pf_done = pos0 + clen
             self._count_prefill(clen)
             if tracer.enabled:
@@ -4627,7 +4765,7 @@ class ContinuousBatcher:
             if final:
                 # register() skips known keys (one cache group only:
                 # several share nothing).
-                for j in range(s0 // P if len(self._groups) == 1 else 0):
+                for j in range(s0 // P if self._shares_pages else 0):
                     self._pager.register(
                         owned[j], Pager.prefix_key(req.prompt, (j + 1) * P)
                     )
@@ -4953,11 +5091,12 @@ class ContinuousBatcher:
                             sl.pos + self.chunk, sl.s0 + sl.req.steps
                         ))
                 (toks, lps, self._caches, self._dstate,
-                 moe) = self._step_chunk(
+                 moe, self._states) = self._step_chunk(
                     self.variables,
                     self._caches,
                     self._dstate,
                     self._current_table(),
+                    self._states,
                     truncate=truncate,
                     nucleus=nucleus,
                     epoch=self._mesh_epoch,
@@ -5276,6 +5415,14 @@ class ContinuousBatcher:
                     out[f"pool_pages.{g.name}"] = gs.num_pages
                     out[f"pages_in_use.{g.name}"] = gs.in_use
                 out["prefix_cache"] = "off: several cache groups"
+            #: Recurrent state beside the pages (0 where the model has
+            #: none): bytes resident, and the slots that hold one each.
+            out["state_bytes"] = sum(
+                x.nbytes for x in jax.tree.leaves(self._states)
+            )
+            out["state_slots"] = len(self.slots) if self._ssm_blocks else 0
+            if self._ssm_blocks:
+                out["prefix_cache"] = "off: recurrent state"
             ps = self._pager.stats()
             out["pool_pages"] = ps.num_pages
             out["pages_in_use"] = ps.in_use
@@ -5385,6 +5532,10 @@ class ContinuousBatcher:
             out["memory.draft_cache_bytes"] = float(
                 sum(x.nbytes for x in jax.tree.leaves(self._draft_caches))
             )
+        if self._states is not None:
+            out["memory.state_bytes"] = float(
+                sum(x.nbytes for x in jax.tree.leaves(self._states))
+            )
         return out
 
     def _program_costs(self) -> dict[str, dict[str, float]]:
@@ -5403,9 +5554,9 @@ class ContinuousBatcher:
             return self._roofline_costs
         av = jax.tree.map(
             lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
-            (self.variables, self._caches, self._dstate),
+            (self.variables, self._caches, self._dstate, self._states),
         )
-        a_vars, a_caches, a_dstate = av
+        a_vars, a_caches, a_dstate, a_states = av
         a_table = jax.ShapeDtypeStruct(
             (len(self.slots), self._pager.pages_per_slot), jnp.int32
         )
@@ -5435,7 +5586,7 @@ class ContinuousBatcher:
             else:
                 costs["decode"] = program_cost_analysis(
                     type(self)._step_chunk,
-                    self, a_vars, a_caches, a_dstate, a_table,
+                    self, a_vars, a_caches, a_dstate, a_table, a_states,
                     truncate=False, nucleus=False,
                     epoch=self._mesh_epoch,
                 )

@@ -10,8 +10,8 @@ does (same model, slots and pool pages), lowers the batcher's own
 ``copy`` / ``copy-start`` operations of the pool's shape in
 ``compiled.as_text()`` by result layout; then does the same for every
 chunked-prefill pass the cell's longest prompt takes (the batcher's
-``_prefill_suffix_fn``; none where the traffic prefills whole
-prompts). On the chip it compiles for the attached device; ``--describe`` compiles for a described v5e from
+``_prefill_suffix_fn``; where the traffic prefills whole prompts, its
+one ``_prefill_fn`` program instead). On the chip it compiles for the attached device; ``--describe`` compiles for a described v5e from
 the CPU (nothing runs; weights and pools are still allocated on the
 host). PERF.md section 5 quotes these counts; ``tests/
 test_chip_lowering.py`` guards a two-block version of them in tier 1.
@@ -100,8 +100,8 @@ def main(argv=None) -> int:
     def abstract(x):
         return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
 
-    a_vars, a_caches, a_dstate = jax.tree.map(
-        abstract, (srv.variables, srv._caches, srv._dstate)
+    a_vars, a_caches, a_dstate, a_states = jax.tree.map(
+        abstract, (srv.variables, srv._caches, srv._dstate, srv._states)
     )
     a_table = abstract(
         jax.ShapeDtypeStruct(
@@ -159,34 +159,48 @@ def main(argv=None) -> int:
     text = report(
         "STEP_COPIES", f"slots {len(srv.slots)}, chunk {srv.chunk}",
         type(srv)._step_chunk.lower(
-            srv, a_vars, a_caches, a_dstate, a_table,
+            srv, a_vars, a_caches, a_dstate, a_table, a_states,
             truncate=False, nucleus=False, epoch=srv._mesh_epoch,
         ).compile(),
     )
     if args.out:
         Path(args.out).write_text(text)
+
+    def staged(shape, dtype):
+        return abstract(jax.ShapeDtypeStruct(shape, dtype))
+
     # The chunked-prefill passes of the longest prompt the mix sends
     # (all but the last: the pass that samples adds the LM head, not a
     # pool operation), at the window widths the batcher pads them to.
     longest = max(p for p, _ in pairs)
     chunk, page = serving["prefill_chunk"], serving["page_size"]
     passes = -(-longest // chunk) if longest > chunk else 0
+    if not passes:  # whole prompts: the one prefill program of the mix
+        bucket = next(b for b in srv.prompt_buckets if b >= longest)
+        text = report(
+            "PREFILL_COPIES", f"whole prompt, bucket {bucket}",
+            srv._prefill_fn(bucket).lower(
+                a_vars, staged((1, bucket), jnp.int32),
+                staged((2,), jnp.int32), staged((2,), jnp.float32),
+                staged((1, 2), jnp.uint32), truncate=False, nucleus=False,
+            ).compile(),
+        )
+        if args.out:
+            Path(f"{args.out}.prefill").write_text(text)
     for i in range(passes - 1):
         n_pad = 1
         while n_pad < (i + 1) * chunk // page:
             n_pad *= 2
-
-        def staged(shape, dtype):
-            return abstract(jax.ShapeDtypeStruct(shape, dtype))
-
         text = report(
             "CHUNK_PREFILL_COPIES",
             f"pass {i + 1} of {passes}, window {n_pad} pages",
             srv._prefill_suffix_fn(chunk, n_pad, sample=False).lower(
                 a_vars, a_caches, staged((n_pad,), jnp.int32),
-                staged((1, chunk), jnp.int32), staged((3,), jnp.int32),
+                staged((1, chunk), jnp.int32),
+                # (a model with recurrent state names the slot too)
+                staged((4 if a_states else 3,), jnp.int32),
                 staged((2,), jnp.float32), staged((1, 2), jnp.uint32),
-                truncate=False, nucleus=False,
+                a_states, truncate=False, nucleus=False,
             ).compile(),
         )
         if args.out:

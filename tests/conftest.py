@@ -63,6 +63,37 @@ def _clear_jax_caches_per_module():
     yield
 
 
+def _memory_maps() -> int:
+    try:
+        with open("/proc/self/maps") as f:
+            return sum(1 for _ in f)
+    except OSError:  # no procfs: nothing to bound
+        return 0
+
+
+try:
+    with open("/proc/sys/vm/max_map_count") as _f:
+        _MAP_BUDGET = int(_f.read()) // 2
+except (OSError, ValueError):
+    _MAP_BUDGET = 32_000
+
+
+@pytest.fixture(autouse=True)
+def _clear_jax_caches_before_the_map_limit():
+    """The cause of that segfault, measured (PR 33): every compiled
+    XLA:CPU executable holds ~37 memory mappings, the jit caches never
+    let one go (a batcher's programs are keyed on the batcher), and a
+    process may hold ``vm.max_map_count`` of them (65,530): one
+    benchmark rehearsal leaves ~7,600, and a MODULE that walks some
+    twenty-five of them (``tests/chipbench/test_chipbench_run_loop.py``)
+    runs into the limit, where the next compile's mmap fails inside
+    LLVM. So within a module too: past half the limit, let them go
+    before the next test."""
+    if _memory_maps() > _MAP_BUDGET:
+        jax.clear_caches()
+    yield
+
+
 @pytest.fixture(scope="session")
 def devices():
     devs = jax.devices()

@@ -721,3 +721,100 @@ def test_step_chunk_with_a_table_a_group_lowers(as_tpu):
     # The paged decode kernel (full and window tables) and the grouped
     # matmul (in and out widths): each lowered once, called a block.
     assert text.count("tpu_custom_call") >= 3
+
+
+# -- Falcon-H1: the state-space mixer's decode step ----------------------------
+
+# falconh1_longgen: 128 rows, 32 heads of (256, 128) in 2 groups.
+_SSM = (128, 32, 256, 128, 2)
+
+
+def _ssm_operands(on=sds):
+    rows, heads, n, p, groups = _SSM
+    return (
+        on((rows, heads, n, p), jnp.float32), on((rows, heads, p)),
+        on((rows, heads), jnp.float32), on((heads,), jnp.float32),
+        on((rows, groups, n)), on((rows, groups, n)),
+    )
+
+
+def test_ssm_step_lowers(as_tpu):
+    from adapt_tpu.ops.ssm_step import heads_per_step, ssm_step
+
+    lower_for_tpu(ssm_step, *_ssm_operands())
+    assert kernel_dispatch_stats()["ssm_step"]["last"] == 1.0
+    # 8 of a group's 16 heads a grid step: 1 MiB of float32 state
+    assert heads_per_step(16, 256, 128) == 8
+
+
+def test_ssm_step_compiles_for_v5e_in_place(
+    as_tpu, one_chip, no_persistent_cache
+):
+    """Mosaic's own compile at the published widths (the in-kernel
+    transpose that turns B and C into columns, four 1 MiB blocks in
+    VMEM), under the name the benchmark's reader sums
+    (``_ssm_step_impl``), and the donated state aliased to the output:
+    no second copy of 537 MB."""
+    from adapt_tpu.ops.ssm_step import ssm_step
+
+    def on_chip(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    compiled = jax.jit(ssm_step, donate_argnums=(0,)).lower(
+        *_ssm_operands(on_chip)
+    ).compile()
+    assert re.search(
+        r"%_ssm_step_impl[.\d]* = .*tpu_custom_call", compiled.as_text()
+    )
+    mem = compiled.memory_analysis()
+    state = 128 * 32 * 256 * 128 * 4
+    assert mem.alias_size_in_bytes == state
+    assert mem.temp_size_in_bytes < state // 16
+
+
+def test_ssm_step_on_narrow_heads_routes_to_xla_on_tpu(as_tpu):
+    """A head's state that is not whole (128, 128) tiles cannot take
+    the in-kernel transpose: auto dispatch books the plain arm, a
+    forced kernel raises."""
+    from adapt_tpu.ops.ssm_step import ssm_step
+
+    args = (
+        sds((4, 4, 32, 16), jnp.float32), sds((4, 4, 16)),
+        sds((4, 4), jnp.float32), sds((4,), jnp.float32),
+        sds((4, 2, 32)), sds((4, 2, 32)),
+    )
+    jax.jit(ssm_step).trace(*args).lower(lowering_platforms=("tpu",))
+    assert kernel_dispatch_stats()["ssm_step"]["last"] == 0.0
+    with pytest.raises(ValueError, match="whole .128, 128. tiles"):
+        jax.jit(lambda *a: ssm_step(*a, prefer="pallas")).trace(*args)
+
+
+def test_step_chunk_with_recurrent_state_lowers(as_tpu):
+    """The batcher's own decode program for a hybrid model: every
+    block's paged kernel (5 query heads a KV head) and its mixer's
+    state update, the states carried through the scan."""
+    from adapt_tpu.models.ssm import SsmSpec
+    from adapt_tpu.models.transformer_lm import transformer_lm
+    from adapt_tpu.runtime.continuous import ContinuousBatcher
+
+    spec = BlockSpec(
+        256, 5, 256, kv_heads=1, head_dim=128, norm="rmsnorm", bias=False,
+        mlp="gated_silu", rope_base=1e11, attn_out_mult=0.0375,
+        ssm=SsmSpec(heads=16, head_dim=128, d_state=128, groups=2, chunk=128),
+    )
+    lm = transformer_lm(
+        512, blocks=[spec] * 2, pos="none", max_len=512, dtype=jnp.bfloat16,
+    )
+    variables = jax.eval_shape(
+        lm.graph.init, jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
+    )
+    variables = jax.tree.map(
+        lambda s: jnp.zeros(s.shape, jnp.bfloat16), variables
+    )
+    srv = ContinuousBatcher(lm, variables, slots=8, chunk=2, page_size=128)
+    text = type(srv)._step_chunk.trace(
+        srv, srv.variables, srv._caches, srv._dstate, srv._current_table(),
+        srv._states, truncate=False, nucleus=False, epoch=0,
+    ).lower(lowering_platforms=("tpu",)).as_text()
+    srv.close()
+    assert text.count("tpu_custom_call") >= 2
