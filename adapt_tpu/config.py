@@ -928,14 +928,12 @@ class RuntimeConfig:
     """Tick-runtime pipelining (``runtime/continuous.py`` "Pipelined
     async runtime", docs/SERVING.md §3 "Async runtime").
 
-    ``pipeline_depth`` left unset (``None``, the default) means THE
-    BATCHER DECIDES, once, in its constructor
-    (``stats()["pipeline_depth"]`` reports what it resolved): **2** for
-    a model with one cache group, **1** where the model has several
-    (the window group grants and recycles pages pass by pass from
-    committed positions, which an in-flight tick has not yet moved).
-    An explicit 1 or 2 means what it says; an explicit 2 under cache
-    groups is refused with a message.
+    ``pipeline_depth`` left unset (``None``, the default) resolves to
+    **2** in the batcher's constructor, whatever the model
+    (``stats()["pipeline_depth"]`` reports it): a model with several
+    cache groups grants and recycles its further groups' pages from the
+    position each row has been DISPATCHED to, which an in-flight tick
+    has moved. An explicit 1 or 2 means what it says.
 
     ``pipeline_depth=2`` overlaps host and device: while tick *t*'s
     programs execute on device, the host runs tick *t+1*'s scheduler
@@ -955,16 +953,15 @@ class RuntimeConfig:
     queue is already full with one tick in flight), so they are
     rejected eagerly rather than silently behaving like 2."""
 
-    #: None = the batcher decides (2 with one cache group, else 1);
-    #: 1 = synchronous tick loop; 2 = one tick in flight (dispatch t
-    #: while committing t-1).
+    #: None = 2; 1 = synchronous tick loop (by name only); 2 = one
+    #: tick in flight (dispatch t while committing t-1).
     pipeline_depth: int | None = None
 
     def __post_init__(self):
         if self.pipeline_depth not in (None, 1, 2):
             raise ValueError(
                 "pipeline_depth must be 1 (synchronous), 2 (one tick "
-                "in flight) or None (the batcher decides), got "
+                "in flight) or None (2), got "
                 f"{self.pipeline_depth}"
             )
 

@@ -835,9 +835,6 @@ class ContinuousBatcher:
                 "cache-aware admission (the radix prefix cache)": (
                     scheduler is not None and scheduler.cache_aware
                 ) or None,
-                "a pipelined tick (pipeline_depth 2)": (
-                    runtime is not None and runtime.pipeline_depth == 2
-                ) or None,
             }
             for what, given in unsupported.items():
                 if given is not None:
@@ -1209,15 +1206,12 @@ class ContinuousBatcher:
         # runs on device — one tick of results stays in flight between
         # calls, drained at every pipeline boundary (run() exit,
         # recover(), drain(), server-loop stop). Left unset, the depth
-        # is decided HERE, once, from what the constructor sees: the
-        # overlapped order wherever the model has one cache group; the
-        # synchronous one under several (a further group grants and
-        # recycles pages pass by pass from COMMITTED positions, which
-        # an in-flight tick has not yet moved).
+        # is 2 whatever the model: a further cache group grants and
+        # recycles its pages pass by pass from the position each row
+        # has been DISPATCHED to (_dispatched_pos), which an in-flight
+        # tick has moved though no commit has.
         self._runtime = runtime or RuntimeConfig()
-        self._depth = self._runtime.pipeline_depth or (
-            2 if len(groups) == 1 else 1
-        )
+        self._depth = self._runtime.pipeline_depth or 2
         self._inflight: _InFlight | None = None
         #: SLO accounting (docs/OBSERVABILITY.md "Workload telemetry").
         #: Hot path touches only these plain ints (one attribute inc
@@ -4678,6 +4672,20 @@ class ContinuousBatcher:
             lo = 0 if g.window is None else max(0, lo_pos - g.window + 1)
             pager.hold(slot, lo // P, -(-hi_pos // P))
 
+    def _dispatched_pos(self, sl: _Slot) -> int:
+        """Where ``sl``'s next decode pass starts ON THE DEVICE:
+        ``sl.pos`` is what the last commit left, and a tick still in
+        flight that decodes the row for the same request and life has
+        moved it one chunk on (an overlapped row advances a whole
+        chunk or ends; the host learns which at that tick's commit)."""
+        fl = self._inflight
+        ahead = (
+            fl is not None
+            and fl.reqs[sl.idx] is sl.req
+            and fl.lives[sl.idx] is sl.tokens
+        )
+        return sl.pos + (self.chunk if ahead else 0)
+
     def _pages_of(self, slot: int, n: int, pad: int | None = None):
         """``slot``'s first ``n`` logical pages as a program takes
         them, staged: one (pad or n,) list where the model has one
@@ -4902,15 +4910,15 @@ class ContinuousBatcher:
         dispatch, with the D2H fetch started asynchronously) and a
         **commit** half (``_tick_commit``: land the fetch, apply
         per-slot commits, flush telemetry). At depth 2 — what an
-        unset ``RuntimeConfig.pipeline_depth`` resolves to for a model
-        with one cache group (``stats()["pipeline_depth"]``) — this
+        unset ``RuntimeConfig.pipeline_depth`` resolves to, whatever
+        the model (``stats()["pipeline_depth"]``) — this
         call dispatches tick *t* and then commits tick *t−1* while *t*
         runs on device: the host's fetch, commits, callbacks and the
         caller's own work between calls overlap the device wall, and
         every result is delivered with a one-tick lag (drained at
         :meth:`drain` / :meth:`run` exit / :meth:`recover`). At depth
-        1 (explicit, or resolved under several cache groups) the
-        halves run back to back: the synchronous loop.
+        1 (explicit only) the halves run back to back: the synchronous
+        loop.
 
         Phases (``utils.profiling.EngineObs``): the call is one
         ``engine.tick`` region holding ``engine.admit`` /
@@ -5083,12 +5091,20 @@ class ContinuousBatcher:
                 ).add((truncate, nucleus))
                 t_chunk = tracer.now() if tracer.enabled else 0.0
                 if len(self._groups) > 1:
-                    # The scan writes [pos, pos + chunk), within the
-                    # request's span (what overshoots a finishing
-                    # request goes to the trash page).
+                    # The scan writes [pos, pos + chunk) from where the
+                    # DEVICE will stand, within the request's span
+                    # (what overshoots a finishing request goes to the
+                    # trash page, as does every write of a row that
+                    # ended inside the tick in flight: pos is then at
+                    # or past the span's end and nothing is granted).
+                    # A page released here may still be read by the
+                    # tick in flight: through the table uploaded at ITS
+                    # dispatch, before any program enqueued after this
+                    # point can write the page.
                     for sl in active:
-                        self._hold_groups(sl.idx, sl.pos, min(
-                            sl.pos + self.chunk, sl.s0 + sl.req.steps
+                        pos = self._dispatched_pos(sl)
+                        self._hold_groups(sl.idx, pos, min(
+                            pos + self.chunk, sl.s0 + sl.req.steps
                         ))
                 (toks, lps, self._caches, self._dstate,
                  moe, self._states) = self._step_chunk(
@@ -5333,7 +5349,7 @@ class ContinuousBatcher:
                 "completed": self._completed,
                 "ticks": self._ticks,
                 # Tick-runtime shape AS RESOLVED (config.RuntimeConfig;
-                # unset: the constructor decided): depth 1 =
+                # unset: 2 for every model): depth 1 =
                 # synchronous dispatch+commit; depth 2 = one tick in
                 # flight between calls (inflight reports whether one is
                 # pending right now).

@@ -278,8 +278,12 @@ class Pager:
         touches, ordinals ``[lo, hi)``: release what fell behind ``lo``
         and grant up to ``hi`` (capped at the table's width). For a
         pool sized ``slots x the widest hold + 1``
-        (:func:`group_pool_pages`) the grant cannot fail."""
-        hi = min(hi, self.pages_per_slot)
+        (:func:`group_pool_pages`) the grant cannot fail. ``hi < lo``
+        (a pass dispatched for a row whose request ended, by step
+        count, inside a tick still in flight: its window moved past
+        its last position) releases the same and grants nothing: what
+        the row writes goes to the trash page through a zero entry."""
+        lo, hi = min(lo, self.pages_per_slot), min(hi, self.pages_per_slot)
         self.release_prefix(
             slot, min(max(lo - self._base[slot], 0), len(self._owned[slot]))
         )
@@ -774,7 +778,12 @@ def window_hold_pages(
     ``ceil((L - 1) / page) + 1`` pages, and a decode scan of ``chunk``
     steps touches the window behind its first write through its last
     write (L = window + chunk - 1); a chunked-prefill pass starts on a
-    page edge, so it touches the window behind it plus its own pages."""
+    page edge, so it touches the window behind it plus its own pages.
+    Grant and release are taken from ONE position, the one the pass
+    starts at ON THE DEVICE (``ContinuousBatcher._dispatched_pos``: a
+    chunk past the committed one while a tick is in flight), so the
+    overlapped tick order holds what the synchronous one holds, one
+    chunk further on."""
     hold = -(-(window + chunk - 2) // page_size) + 1
     if prefill_chunk is not None:
         hold = max(

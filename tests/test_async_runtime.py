@@ -99,7 +99,7 @@ def _staggered(bat, cancel_idx=None):
 
 def test_runtime_config_validation():
     """Depths outside {1, 2} fail eagerly, by name; left unset the
-    depth is None: the batcher decides."""
+    depth is None: the batcher resolves it (to 2)."""
     assert RuntimeConfig().pipeline_depth is None
     assert ServeConfig().runtime.pipeline_depth is None
     for bad in (0, 3, -1):
@@ -107,7 +107,7 @@ def test_runtime_config_validation():
             RuntimeConfig(pipeline_depth=bad)
 
 
-def _two_group_lm():
+def _two_group_lm(window=8):
     """K-EXAONE's cache shape at toy widths: window layers and a full
     one, so two cache groups."""
     def spec(window):
@@ -115,7 +115,7 @@ def _two_group_lm():
                          mlp_dim=64, window=window)
 
     lm = transformer_lm(
-        37, blocks=[spec(8), spec(None)], max_len=64, pos="none",
+        37, blocks=[spec(window), spec(None)], max_len=64, pos="none",
         name="async_two_groups",
     )
     variables = lm.graph.init(
@@ -130,17 +130,18 @@ def _two_group_lm():
         ("one_group", None, 2),
         ("one_group", RuntimeConfig(), 2),
         ("one_group", _depth(1), 1),
-        ("two_groups", None, 1),
+        ("two_groups", None, 2),
         ("two_groups", _depth(1), 1),
+        ("two_groups", _depth(2), 2),
     ],
 )
 def test_unset_depth_is_resolved_by_the_batcher(
     lm_setup, model, runtime, depth
 ):
-    """The default is decided by the batcher from what its constructor
-    sees: one cache group takes the overlapped order, several the
-    synchronous one, silently; an explicit depth means what it says
-    (explicit 2 under cache groups stays refused)."""
+    """An unset depth resolves to the overlapped order whatever the
+    model (one cache group or several); an explicit depth means what
+    it says, and an explicit 2 under cache groups is no longer
+    refused."""
     lm, variables = lm_setup if model == "one_group" else _two_group_lm()
     kw = dict(slots=2, chunk=2, page_size=8)
     bat = ContinuousBatcher(lm, variables, runtime=runtime, **kw)
@@ -151,9 +152,6 @@ def test_unset_depth_is_resolved_by_the_batcher(
     assert bat.stats()["inflight"] == (depth == 2)
     assert len(bat.run()[rid]) == 6
     bat.close()
-    if model == "two_groups":
-        with pytest.raises(ValueError, match="pipeline_depth 2"):
-            ContinuousBatcher(lm, variables, runtime=_depth(2), **kw)
 
 
 @pytest.mark.parametrize(
@@ -194,6 +192,96 @@ def test_async_bit_identical_staggered(lm_setup, page_size):
         np.testing.assert_array_equal(
             outs[2][i], _solo(lm, variables, PROMPTS[i], STEPS[i]),
             err_msg=f"req {i}: depth2 != generate",
+        )
+
+
+@pytest.mark.parametrize(
+    "window, page, chunk, prefill_chunk",
+    [
+        # A window over two pages: grants and releases at every edge.
+        (8, 4, 3, 8),
+        # A chunk LONGER than window + page: a row that ended by step
+        # count inside the tick in flight is dispatched once more with
+        # its window wholly past its last position (Pager.hold with
+        # hi < lo: releases, grants nothing).
+        (2, 2, 6, 4),
+    ],
+)
+def test_async_bit_identical_two_cache_groups(
+    window, page, chunk, prefill_chunk
+):
+    """The identity pin under CACHE GROUPS: a window group's pages are
+    granted and recycled from the position each row has been DISPATCHED
+    to, so the overlapped order serves a two-group model the streams
+    the synchronous one does, each equal to solo generate(): staggered
+    admits, more requests than slots, whole-prompt and chunked prefill,
+    retirement by step count mid-chunk and by EOS, every request
+    decoding past the window and across page edges. Afterwards no
+    group holds a page and nothing is in flight."""
+    lm, variables = _two_group_lm(window)
+    rng = np.random.RandomState(5)
+    prompts = [rng.randint(0, 37, size=n).astype(np.int32)
+               for n in (3, 13, 6, 21, 9, 4)]
+    steps = [21, 11, 24, 12, 26, 17]  # none a whole number of chunks + 1
+    solo = [_solo(lm, variables, p, n) for p, n in zip(prompts, steps)]
+    # Requests 2 and 4 end by EOS: the token their own stream reaches
+    # last for the first time.
+    eos = {}
+    for i in (2, 4):
+        at = max(list(solo[i]).index(t) for t in set(solo[i]))
+        eos[i] = int(solo[i][at])
+        solo[i] = solo[i][: at + 1]
+        assert len(prompts[i]) + at > window + page
+    kw = dict(slots=2, chunk=chunk, page_size=page,
+              prefill_chunk=prefill_chunk, prompt_buckets=(8, 16, 32))
+    outs, past_end = {}, {}
+    for depth in (1, 2):
+        bat = ContinuousBatcher(lm, variables, runtime=_depth(depth), **kw)
+        assert [g.window for g in bat._groups] == [None, window]
+        snap = global_metrics().snapshot(window=True)
+        ids = {}
+
+        def submit(i):
+            ids[bat.submit(prompts[i], steps[i], eos_id=eos.get(i))] = i
+
+        bound = bat.stats()["pool_pages.window"] - 1
+        hold, emptied = bat._pagers[1].hold, []
+        bat._pagers[1].hold = lambda slot, lo, hi: (
+            emptied.append(hi < lo), hold(slot, lo, hi)
+        )
+        for i in range(2):
+            submit(i)
+        bat.tick()
+        bat.tick()
+        for i in range(2, 4):
+            submit(i)
+        for _ in range(5):
+            bat.tick()
+            assert bat.stats()["pages_in_use.window"] <= bound
+        for i in range(4, len(prompts)):
+            submit(i)
+        out = bat.run()
+        outs[depth] = {ids[r]: np.asarray(out[r]) for r in ids}
+        st = bat.stats()
+        assert st["pipeline_depth"] == depth and not st["inflight"]
+        assert st["active"] == 0 and st["queued"] == 0
+        assert st["admitted"] == st["completed"] == len(prompts)
+        assert st["pages_in_use.full"] == st["pages_in_use.window"] == 0
+        c = global_metrics().snapshot(since=snap)["counters"]
+        past_end[depth] = c.get("runtime.rows_past_end", 0)
+        if depth == 2:
+            assert c["runtime.ticks_overlapped"] > c[
+                "runtime.ticks_synchronous"
+            ]
+            assert any(emptied) == (chunk > window + page)
+        bat.close()
+    assert past_end[1] == 0 < past_end[2]
+    for i in range(len(prompts)):
+        np.testing.assert_array_equal(
+            outs[2][i], outs[1][i], err_msg=f"req {i}: depth2 != depth1"
+        )
+        np.testing.assert_array_equal(
+            outs[2][i], solo[i], err_msg=f"req {i}: depth2 != generate"
         )
 
 

@@ -135,23 +135,33 @@ def test_a_window_layer_holds_its_window_while_a_full_layer_grows(served):
     assert full_peak == 9 > bound
 
 
-def test_the_expert_counters_say_how_the_routing_fell(built):
+@pytest.mark.parametrize("depth", [1, None])
+def test_the_expert_counters_say_how_the_routing_fell(built, depth):
+    """The counters book what the DEVICE routed: under the overlapped
+    order (the default) that is one chunk more, the one dispatched
+    before the host learned that the request had ended
+    (``runtime.rows_past_end``)."""
+    from adapt_tpu.config import RuntimeConfig
     from adapt_tpu.utils.metrics import global_metrics
 
     lm, variables, _ = built
-    srv = _batcher(lm, variables)
+    srv = _batcher(lm, variables, runtime=RuntimeConfig(pipeline_depth=depth))
     snap = global_metrics().snapshot(window=True)
     srv.submit(np.arange(10, dtype=np.int32), 13)
     srv.run()
     srv.close()
     c = global_metrics().snapshot(since=snap)["counters"]
-    assert c["moe.steps"] == 3 * CHUNK  # 12 tokens after the prefill's
-    # 12 live steps x top-2 x 4 sparse layers; layer 0 is dense.
-    assert c["moe.assignments_total"] == 12 * 2 * 4
+    past_end = c.get("runtime.rows_past_end", 0)
+    assert past_end == (0 if depth == 1 else 1)
+    # 12 tokens after the prefill's, and the chunk past the end.
+    steps = (3 + past_end) * CHUNK
+    assert c["moe.steps"] == steps
+    # Live steps x top-2 x 4 sparse layers; layer 0 is dense.
+    assert c["moe.assignments_total"] == steps * 2 * 4
     per_expert = {k: v for k, v in c.items() if k.startswith("moe.tokens.")}
     assert {k.split(".")[2] for k in per_expert} <= {"1", "2", "3", "4"}
     assert sum(per_expert.values()) == c["moe.assignments_held"]
-    assert 0 < c["moe.experts_hit"] <= 12 * 4 * 2
+    assert 0 < c["moe.experts_hit"] <= steps * 4 * 2
     assert c["moe.assignments_held"] <= c["moe.assignments_total"]
 
 

@@ -29,11 +29,14 @@ from adapt_tpu.ops.paged_attention import (
 from adapt_tpu.ops.quantize import quantize_kv_vectors
 from adapt_tpu.runtime.continuous import ContinuousBatcher
 from adapt_tpu.runtime.paged import (
+    CacheGroup,
     Pager,
     alloc_kv_pools,
+    group_pool_pages,
     insert_prefill_pages,
     kv_value_width,
     pool_geometry,
+    window_hold_pages,
 )
 
 
@@ -61,6 +64,102 @@ def test_pager_validation():
     p = Pager(8, 2, 2)
     with pytest.raises(ValueError, match="table width"):
         p.alloc(0, 3)
+
+
+def test_hold_past_a_rows_end_releases_and_grants_nothing():
+    """``hi < lo``: a pass dispatched for a row whose request ended
+    inside the tick in flight, its window wholly past the last
+    position. What fell behind ``lo`` goes back, nothing is granted
+    (not even from an empty pool), and the row's table points at the
+    trash page from there on."""
+    p = Pager(num_pages=5, slots=2, pages_per_slot=6)
+    p.hold(0, 1, 4)
+    assert len(p.owned(0)) == 3 and p.base(0) == 1
+    assert p.alloc(1, 1) and p.stats().free == 0
+    p.hold(0, 3, 2)  # keeps ordinal 3, which is not behind lo
+    assert len(p.owned(0)) == 1 and p.base(0) == 3
+    p.hold(0, 5, 4)
+    assert p.owned(0) == [] and p.base(0) == 5
+    assert (p.table()[0] == 0).all() and p.stats().free == 3
+    p.hold(0, 9, 7)  # past the table's width: capped, still nothing
+    assert p.owned(0) == [] and p.base(0) == 6 and p.stats().free == 3
+    p.free_slot(0)
+    assert p.base(0) == 0
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+@pytest.mark.parametrize("order", ["synchronous", "overlapped"])
+def test_a_walk_of_holds_stays_within_window_hold_pages(order, seed):
+    """The batcher's protocol over a window group's pager, at random
+    (window, page, chunk, prefill chunk): admission holds the prompt's
+    tail (or a chunked-prefill pass its pages), a decode dispatch holds
+    ``[pos, pos + chunk)`` from the position the row has been
+    DISPATCHED to (a chunk past the committed one under the overlapped
+    order, where a row that ended is dispatched once more and freed a
+    tick late). No slot ever holds more than ``window_hold_pages`` and
+    no grant fails from a pool of ``group_pool_pages``."""
+    rng = np.random.default_rng(seed)
+    lag = order == "overlapped"
+    slots, max_len = 3, 160
+    for _ in range(25):
+        page = int(rng.choice([2, 4, 8, 16]))
+        window = int(rng.integers(1, 40))
+        chunk = int(rng.integers(1, 12))
+        pchunk = page * int(rng.integers(1, 4)) if rng.random() < 0.5 else None
+        pps = -(-max_len // page)
+        bound = min(pps, window_hold_pages(window, page, chunk, pchunk))
+        pager = Pager(
+            group_pool_pages(
+                CacheGroup("window", window, 2, 8, (0,)),
+                slots, pps, page, chunk, pchunk,
+            ),
+            slots, pps, page_tokens=page,
+        )
+        assert pager.num_pages == slots * bound + 1
+
+        def hold(slot, lo_pos, hi_pos):  # ContinuousBatcher._hold_groups
+            pager.hold(
+                slot, max(0, lo_pos - window + 1) // page, -(-hi_pos // page)
+            )
+            assert len(pager.owned(slot)) <= bound
+
+        rows = [None] * slots  # dict(s0, steps, emitted, pf) a request
+        flight = []  # (slot, row) of the dispatch not yet committed
+        for _ in range(60):
+            dispatched = []
+            for i in range(slots):
+                if rows[i] is None:
+                    s0 = int(rng.integers(1, 70))
+                    rows[i] = dict(
+                        s0=s0, steps=int(rng.integers(2, max_len - s0)),
+                        emitted=1,
+                        pf=0 if pchunk and s0 > pchunk else -1,
+                    )
+                    if rows[i]["pf"] < 0:
+                        hold(i, s0, s0)
+                r = rows[i]
+                if r["pf"] >= 0:
+                    clen = min(pchunk, r["s0"] - r["pf"])
+                    hold(i, r["pf"], r["pf"] + -(-clen // page) * page)
+                    r["pf"] += clen
+                    if r["pf"] < r["s0"]:
+                        continue
+                    r["pf"] = -1
+                ahead = any(q is r for _, q in flight)
+                pos = r["s0"] + r["emitted"] - 1 + (chunk if ahead else 0)
+                hold(i, pos, min(pos + chunk, r["s0"] + r["steps"]))
+                dispatched.append((i, r))
+            landing, flight = (flight, dispatched) if lag else (dispatched, [])
+            for i, r in landing:
+                if rows[i] is not r:
+                    continue  # a row past its end: dropped
+                r["emitted"] += chunk
+                if r["emitted"] >= r["steps"] or rng.random() < 0.05:
+                    rows[i] = None  # by step count, or an EOS
+                    pager.free_slot(i)
+        for i in range(slots):
+            pager.free_slot(i)
+        assert pager.stats().in_use == 0
 
 
 # -- the pool's format: one owner --------------------------------------------
