@@ -625,6 +625,7 @@ class ContinuousBatcher:
         observability: ObservabilityConfig | None = None,
         capacity: CapacityConfig | None = None,
     ):
+        t_construct = time.perf_counter()
         self.lm = lm
         # -- tensor parallelism (mesh-native serving) ----------------------
         # ``mesh`` + ``config.ParallelConfig{tp}`` shard the serving tier
@@ -1308,6 +1309,7 @@ class ContinuousBatcher:
         self._sentinel.register(
             "continuous.prefill",
             size_fn=aggregate_size_fn(_LIVE_BATCHERS, _prefill_family_size),
+            names=("prefill", "dprefill"),  # the closures' own names
         )
         #: Pull-style memory accounting: pool / draft-strip bytes and
         #: page occupancy served as memory.* gauges at every
@@ -1419,6 +1421,11 @@ class ContinuousBatcher:
             # every tick dispatches onto the dead chip undetected.
             for did in sorted(health.dead_ids() & self._mesh_device_ids):
                 self._on_device_event("leave", f"device:{did}")
+        # The constructor's own wall (pools, state, tables): start-up's
+        # share that no compile event covers.
+        global_metrics().set_gauge(
+            "engine.construct_s", time.perf_counter() - t_construct
+        )
 
     # -- compiled pieces ---------------------------------------------------
 

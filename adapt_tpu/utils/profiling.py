@@ -19,7 +19,13 @@ silently destroy TPU serving performance without ever failing a test:
   discipline) makes "the cache grew" a precise proxy for "a tick just
   stalled on XLA"; re-registering a program (every batcher constructor
   does) re-arms its warmup, because jit caches key on ``self`` and a
-  new instance legitimately compiles its own first variants.
+  new instance legitimately compiles its own first variants. The
+  process-global sentinel also keeps the COMPILE ACCOUNT: what every
+  program of the process cost to trace, to lower and to compile or
+  load, by watch and by stage, as ``jax.monitoring`` reports it where
+  the work happens (``engine.compile.*`` gauges and spans;
+  :meth:`~CompileSentinel.account`). It only listens: it lowers,
+  compiles and traces nothing itself.
 
 - **Memory accounting** — pull-style: components register themselves as
   weakly-held sources (:func:`register_memory_source`) exposing a
@@ -85,16 +91,89 @@ import time
 import weakref
 from collections.abc import Callable
 
+from jax import monitoring
 from jax.profiler import TraceAnnotation
 
 from adapt_tpu.utils.logging import get_logger, kv
 from adapt_tpu.utils.metrics import MetricsRegistry, global_metrics
-from adapt_tpu.utils.tracing import global_flight_recorder, global_tracer
+from adapt_tpu.utils.tracing import (
+    _EPOCH_OFFSET,
+    global_flight_recorder,
+    global_tracer,
+)
 
 log = get_logger("profiling")
 
 
 # -- compile sentinel -------------------------------------------------------
+
+#: The ``jax.monitoring`` events the compile account listens to (JAX
+#: 0.9.0: ``dispatch.log_elapsed_time`` around tracing, lowering and
+#: ``compile_or_get_cached``; ``compiler.py`` / ``compilation_cache.py``
+#: for the persistent cache) -> the account's stage.
+_STAGE_OF = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+}
+_CACHE_COUNT_OF = {
+    "/jax/compilation_cache/cache_hits": "cache_hits",
+    "/jax/compilation_cache/cache_misses": "cache_misses",
+}
+_CACHE_SECONDS_OF = {
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_load_s",
+    "/jax/compilation_cache/compile_time_saved_sec": "cache_saved_s",
+}
+#: A stage's count in an account row: a ``backend`` event is one more
+#: executable, so a watch's ``variants`` is its jit-cache size.
+_COUNT_OF = {"trace": "traces", "lower": "lowerings", "backend": "variants"}
+#: The account's row for programs no watch names, and how many of
+#: their names it keeps (the smallest folds into ``(rest)``) and shows.
+OTHER = "other"
+_OTHER_KEPT = 64
+_OTHER_SHOWN = 16
+
+
+def _new_last() -> dict:
+    return {"trace_s": 0.0, "lower_s": 0.0, "backend_s": 0.0, "cache": "off"}
+
+
+def _new_row() -> dict:
+    return {
+        "trace_s": 0.0, "lower_s": 0.0, "backend_s": 0.0,
+        "traces": 0, "lowerings": 0, "variants": 0,
+        #: Of its backend events, those the persistent cache served
+        #: and those compiled and written to it.
+        "cache_hits": 0, "cache_misses": 0,
+        #: The newest program of the row, stage by stage (what the
+        #: recompile alarm quotes): reset by its trace event.
+        "last": _new_last(),
+    }
+
+
+def _add_row(into: dict, row: dict) -> None:
+    for k, v in row.items():
+        if k != "last":
+            into[k] += v
+
+
+def _bare_name(fun_name: str) -> str:
+    """``jit(_step_chunk)`` -> ``_step_chunk``: JAX names a program by
+    its function on the trace event and by its module (the function in
+    the API's wrapper) on the other two."""
+    if fun_name.endswith(")"):
+        head, _, inner = fun_name[:-1].partition("(")
+        if inner and head.isidentifier():
+            return inner
+    return fun_name
+
+
+def _row_seconds(row: dict) -> float:
+    return row["trace_s"] + row["lower_s"] + row["backend_s"]
+
+
+def _copy_row(row: dict) -> dict:
+    return {**row, "last": dict(row["last"])}
 
 
 class _Watch:
@@ -142,7 +221,35 @@ class CompileSentinel:
     once, against the sentinel's own cumulative state; every sampling
     registry's ``engine.compile_events`` counter is then synced up to
     that cumulative count, so a custom registry served by the exporter
-    reports the same events as the process registry the ticks drive."""
+    reports the same events as the process registry the ticks drive.
+
+    **The compile account** (the process-global sentinel's: only it is
+    given ``jax.monitoring``'s listeners, once a process). What every
+    program cost, keyed by program and stage: ``trace`` (Python to
+    jaxpr), ``lower`` (jaxpr to an MLIR module) and ``backend`` (XLA's
+    compile OR the load from the persistent cache: JAX reports both
+    under one event), plus the persistent cache's hits, misses and
+    load seconds. A program is a WATCH where the event's function is a
+    registered entry point's (``register`` learns ``fn.__name__``; the
+    ``size_fn=`` form is told by ``names=``), else ``other`` with the
+    function's name kept in a bounded table. JAX reports a function's
+    NAME and no more, so two functions of one name share a row. Stage
+    events nest (every jitted library function a program calls while
+    it is traced fires its own trace event inside the outer one; a
+    Pallas kernel is traced inside its caller's lowering): seconds are
+    booked only for the span that is outermost on its thread, which
+    JAX's start stamps tell exactly, so no second is counted twice and
+    no list of spans is kept. Every backend event counts one program,
+    nested or not. The cache's events carry no name: they are booked
+    by stage only (a thread's open backend span lends them to that
+    program's ``cache=`` attribute and to nothing else). Published at
+    event time as process-cumulative ``engine.compile.*`` gauges on
+    the process registry and, with the tracer on, as
+    ``engine.compile.<stage>`` ring spans placed from JAX's own
+    clock readings. The account LISTENS only: it never lowers,
+    compiles, traces or asks for a cost analysis, so it cannot cost
+    what it measures; a process that compiles nothing fires no
+    listener."""
 
     def __init__(self, warmup_samples: int = 8):
         if warmup_samples < 0:
@@ -163,6 +270,21 @@ class CompileSentinel:
         #: (a retired program must not scrape as still-compiled).
         #: Bounded by the set of program names ever watched.
         self._pruned: set[str] = set()
+        # -- the compile account (class docstring) ----------------------
+        #: Function name (as ``jax.monitoring`` reports it) -> watch.
+        self._programs: dict[str, str] = {}
+        #: Watch (or ``OTHER``) -> row; ``_other``: function name -> row
+        #: for the programs no watch names.
+        self._rows: dict[str, dict] = {}
+        self._other: dict[str, dict] = {}
+        self._totals: dict[str, float] = {
+            "programs": 0, "trace_s": 0.0, "lower_s": 0.0,
+            "backend_s": 0.0, "cache_hits": 0, "cache_misses": 0,
+            "cache_load_s": 0.0, "cache_saved_s": 0.0,
+        }
+        #: Per compiling thread: ``depth`` of open stage spans and the
+        #: open backend span's ``cache`` outcome.
+        self._tls = threading.local()
 
     def register(
         self,
@@ -170,11 +292,16 @@ class CompileSentinel:
         fn=None,
         *,
         size_fn: Callable[[], int] | None = None,
+        names: tuple[str, ...] = (),
     ) -> None:
         """Watch ``name``. Re-registering (same or different fn) re-arms
         the warmup window — constructors re-register their class-level
         jits precisely because a fresh ``self`` legitimately compiles
-        fresh cache entries."""
+        fresh cache entries. ``names``: the functions whose compile
+        events the account books under this watch, beside ``fn``'s own
+        (a ``size_fn=`` family of closures has no ``fn`` to ask)."""
+        if fn is not None and getattr(fn, "__name__", None):
+            names = (fn.__name__, *names)
         if size_fn is None:
             if fn is None or not hasattr(fn, "_cache_size"):
                 raise TypeError(
@@ -193,6 +320,8 @@ class CompileSentinel:
                 w.expected = prev.expected
             self._watches[name] = w
             self._pruned.discard(name)
+            for fun_name in names:
+                self._programs[fun_name] = name
 
     def unregister(self, name: str) -> None:
         with self._lock:
@@ -275,6 +404,164 @@ class CompileSentinel:
         with self._lock:
             return self._events
 
+    # -- the compile account ---------------------------------------------
+    # The four ``jax.monitoring`` callbacks run on the compiling thread
+    # (the server thread under ``start()``), where the work happens.
+
+    def _on_stage_open(self, event: str, value, **_) -> None:
+        """Scalar listener: JAX stamps a stage's START under the
+        stage's own event name."""
+        if event in _STAGE_OF:
+            tls = self._tls
+            tls.depth = getattr(tls, "depth", 0) + 1
+            if _STAGE_OF[event] == "backend":
+                tls.cache = "off"
+
+    def _on_stage_close(
+        self, event: str, start: float, end: float, fun_name: str = "", **_
+    ) -> None:
+        """Time-span listener: the stage's end, with JAX's own two
+        ``time.time()`` readings."""
+        stage = _STAGE_OF.get(event)
+        if stage is None:
+            return
+        tls = self._tls
+        tls.depth = depth = max(getattr(tls, "depth", 1) - 1, 0)
+        if depth == 0:
+            self._book(stage, _bare_name(str(fun_name)), start, end)
+        elif stage == "backend":  # nested: a program, its seconds the outer's
+            with self._lock:
+                self._totals["programs"] += 1
+                programs = self._totals["programs"]
+            global_metrics().set_gauge("engine.compile.programs", programs)
+
+    def _on_cache_seconds(self, event: str, seconds: float, **_) -> None:
+        """Duration listener: the persistent cache's two timings."""
+        key = _CACHE_SECONDS_OF.get(event)
+        if key is not None:
+            self._book_cache(key, seconds)
+
+    def _on_cache_event(self, event: str, **_) -> None:
+        key = _CACHE_COUNT_OF.get(event)
+        if key is not None:
+            self._tls.cache = "hit" if key == "cache_hits" else "miss"
+            self._book_cache(key, 1)
+
+    def _book_cache(self, key: str, amount: float) -> None:
+        with self._lock:
+            self._totals[key] += amount
+            # All of them from the first: a warm run reads 0 misses.
+            gauges = {
+                f"engine.compile.{k}": self._totals[k]
+                for k in ("cache_hits", "cache_misses", "cache_load_s")
+            }
+        reg = global_metrics()
+        for k, v in gauges.items():
+            reg.set_gauge(k, float(v))
+
+    def _book(
+        self, stage: str, program: str, start: float, end: float
+    ) -> None:
+        """One outermost stage span into the account, the gauges and
+        (tracer on) the ring."""
+        seconds = end - start
+        cache = getattr(self._tls, "cache", "off")
+        with self._lock:
+            watch = self._programs.get(program, OTHER)
+            rows = [self._rows.setdefault(watch, _new_row())]
+            if watch == OTHER:
+                rows.append(self._other_row(program))
+            for row in rows:
+                row[f"{stage}_s"] += seconds
+                row[_COUNT_OF[stage]] += 1
+                if stage == "trace":
+                    row["last"] = _new_last()
+                row["last"][f"{stage}_s"] = seconds
+                if stage == "backend":
+                    row["last"]["cache"] = cache
+                    if cache != "off":
+                        row["cache_hits" if cache == "hit"
+                            else "cache_misses"] += 1
+            tot = self._totals
+            tot[f"{stage}_s"] += seconds
+            if stage == "backend":
+                tot["programs"] += 1
+            gauges = {
+                f"engine.compile.{k}": tot[k]
+                for k in ("programs", "trace_s", "lower_s", "backend_s")
+            }
+            if watch != OTHER:
+                gauges[f"engine.compile.seconds.{watch}"] = _row_seconds(
+                    rows[0]
+                )
+                gauges[f"engine.compile.variants.{watch}"] = rows[0][
+                    "variants"
+                ]
+            variant = rows[0][_COUNT_OF[stage]]
+        reg = global_metrics()
+        for k, v in gauges.items():
+            reg.set_gauge(k, float(v))
+        tracer = global_tracer()
+        if tracer.enabled:
+            attrs = {"program": program, "watch": watch, "variant": variant}
+            if stage == "backend":
+                attrs["cache"] = cache
+            # JAX read the epoch clock; the ring is on the perf clock.
+            tracer.add_span(
+                f"engine.compile.{stage}", start=start - _EPOCH_OFFSET,
+                end=end - _EPOCH_OFFSET, **attrs,
+            )
+
+    def _other_row(self, program: str) -> dict:
+        """The named row of a program no watch names; past
+        ``_OTHER_KEPT`` names the smallest folds into ``(rest)``.
+        Caller holds the lock."""
+        row = self._other.get(program)
+        if row is None:
+            if len(self._other) >= _OTHER_KEPT:
+                small = min(
+                    (n for n in self._other if n != "(rest)"),
+                    key=lambda n: _row_seconds(self._other[n]),
+                )
+                _add_row(
+                    self._other.setdefault("(rest)", _new_row()),
+                    self._other.pop(small),
+                )
+            row = self._other[program] = _new_row()
+        return row
+
+    def account(self) -> dict:
+        """The compile account, read only (nothing is lowered, traced
+        or compiled to answer): ``{"totals": {programs, trace_s,
+        lower_s, backend_s, cache_hits, cache_misses, cache_load_s,
+        cache_saved_s}, "programs": {watch | "other": row}, "other":
+        {function name: row}}`` with ``row = {trace_s, lower_s,
+        backend_s, traces, lowerings, variants, cache_hits,
+        cache_misses, last}``. ``other``
+        holds the ``_OTHER_SHOWN`` largest names by seconds, largest
+        first, and ``(rest)`` for all the others."""
+        with self._lock:
+            named = sorted(
+                (n for n in self._other if n != "(rest)"),
+                key=lambda n: -_row_seconds(self._other[n]),
+            )
+            other = {
+                n: _copy_row(self._other[n]) for n in named[:_OTHER_SHOWN]
+            }
+            rest = _new_row()
+            for n in named[_OTHER_SHOWN:] + ["(rest)"]:
+                if n in self._other:
+                    _add_row(rest, self._other[n])
+            if rest["traces"] or rest["lowerings"] or rest["variants"]:
+                other["(rest)"] = rest
+            return {
+                "totals": dict(self._totals),
+                "programs": {
+                    n: _copy_row(r) for n, r in self._rows.items()
+                },
+                "other": other,
+            }
+
     def sample(
         self,
         registry: MetricsRegistry | None = None,
@@ -291,7 +578,8 @@ class CompileSentinel:
         :func:`engine_collector` — detection, the event counter sync
         and the flight/log/tracer side effects still run."""
         reg = registry if registry is not None else global_metrics()
-        fired: list[tuple[str, int, int]] = []  # (name, size, delta)
+        # (name, size, delta, what the watch's newest program cost)
+        fired: list[tuple[str, int, int, dict]] = []
         sizes: list[tuple[str, int]] = []
         dead: list[str] = []
         with self._lock:
@@ -326,7 +614,9 @@ class CompileSentinel:
                     w.expected -= absorbed
                     delta -= absorbed
                 if delta > 0 and warmed:
-                    fired.append((name, size, delta))
+                    row = self._rows.get(name)
+                    cost = dict(row["last"]) if row else {}
+                    fired.append((name, size, delta, cost))
                     self._events += delta
             for name in dead:
                 del self._watches[name]
@@ -352,13 +642,13 @@ class CompileSentinel:
                 # A retired program must not scrape as still-compiled.
                 reg.remove_gauge(f"engine.compiles.{name}")
         tracer = global_tracer()
-        for name, size, delta in fired:
+        for name, size, delta, cost in fired:
             global_flight_recorder().record(
-                "recompile", program=name, compiles=size, new=delta
+                "recompile", program=name, compiles=size, new=delta, **cost
             )
             log.warning(
                 "unexpected recompile %s",
-                kv(program=name, compiles=size, new=delta),
+                kv(program=name, compiles=size, new=delta, **cost),
             )
             if tracer.enabled:
                 tracer.instant("engine.recompile", program=name, new=delta)
@@ -410,9 +700,29 @@ def aggregate_size_fn(owners, extract: Callable) -> Callable:
 
 
 _SENTINEL = CompileSentinel()
+_LISTENING = False
+_LISTEN_LOCK = threading.Lock()
 
 
 def global_compile_sentinel() -> CompileSentinel:
+    """The process's sentinel. The first call hands its compile
+    account to ``jax.monitoring``: one listener of each kind a
+    process, however many batchers are built. The lists are shared
+    (the benchmark's ``CompileCounter`` hangs on one), so nothing here
+    ever clears them."""
+    global _LISTENING
+    if not _LISTENING:
+        with _LISTEN_LOCK:
+            first, _LISTENING = not _LISTENING, True
+        if first:
+            monitoring.register_scalar_listener(_SENTINEL._on_stage_open)
+            monitoring.register_event_time_span_listener(
+                _SENTINEL._on_stage_close
+            )
+            monitoring.register_event_duration_secs_listener(
+                _SENTINEL._on_cache_seconds
+            )
+            monitoring.register_event_listener(_SENTINEL._on_cache_event)
     return _SENTINEL
 
 
@@ -553,6 +863,10 @@ def engine_collector(reg: MetricsRegistry) -> None:
 # whichever registry it actually serves; register_collector is
 # idempotent per function object).
 global_metrics().register_collector(engine_collector)
+# The compile account starts with the process, not with the first
+# batcher: a deployment draws its weights (and the benchmark runs its
+# builder) before one exists, and set-up pays those programs too.
+global_compile_sentinel()
 
 
 # -- roofline accounting ----------------------------------------------------

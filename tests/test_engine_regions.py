@@ -124,8 +124,10 @@ def test_region_is_the_annotation_alone_when_off(obs):
     eo = EngineObs()
     _, tracer = obs
     tracer.enabled = True
-    seq = tracer.spans_since(0)[1]
+    # The snapshot first: a batcher's first scrape asks for its step
+    # program's lowering again, an ``engine.compile.trace`` ring span.
     before = _samples("probe")
+    seq = tracer.spans_since(0)[1]
     site = eo.region("probe", request=7)
     assert type(site) is TraceAnnotation  # no wrapper around it
     with site:
@@ -139,8 +141,8 @@ def test_region_records_what_phase_records_when_on(obs):
     eo.enabled = True
     _, tracer = obs
     tracer.enabled = True
-    seq = tracer.spans_since(0)[1]
     before = _samples("probe")
+    seq = tracer.spans_since(0)[1]
     with eo.region("probe", request=7):
         eo.enabled = False  # read once, at entry: the close still records
     with eo.region("probe"):
