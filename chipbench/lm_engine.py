@@ -170,13 +170,20 @@ class Driver:
             self.tick()
 
 
+#: Served logprobs compared a sampled request, unless the
+#: configuration's ``correct`` block states ``sample_steps``.
 SAMPLE_STEPS = 8
 
 
-def _sample_prompts(chunk: int, max_len: int) -> list[int]:
+def _sample_steps(correct: dict) -> int:
+    return int(correct.get("sample_steps", SAMPLE_STEPS))
+
+
+def _sample_prompts(chunk: int, max_len: int,
+                    steps: int = SAMPLE_STEPS) -> list[int]:
     """Prompt lengths of the correctness sample: two whole-prompt
     prefills and one that goes through chunked prefill."""
-    return [40, chunk - 17, min(chunk + 45, max_len - SAMPLE_STEPS)]
+    return [40, chunk - 17, min(chunk + 45, max_len - steps)]
 
 
 class Compared(NamedTuple):
@@ -207,7 +214,8 @@ def correctness_sample(drv: Driver, variables, serving: dict, max_len: int,
                        reference, correct: dict, fault: str = "") -> Compared:
     """Three seeded requests served outside the window, one of them
     through chunked prefill, all decoding through the paged kernel;
-    their served logprobs against those of ``reference`` (the plain
+    their served logprobs (``SAMPLE_STEPS`` each, or the block's
+    ``sample_steps``) against those of ``reference`` (the plain
     reference the configuration names), held to the file's ``correct``
     block. ``fault`` goes to the reference, which knows its own tree.
 
@@ -227,8 +235,8 @@ def correctness_sample(drv: Driver, variables, serving: dict, max_len: int,
     tol = correct["logprob_tol"]
     nan = float("nan")
     nothing = Compared(False, nan, tol, 0, 0, 0, None)  # no comparison made
-    steps = SAMPLE_STEPS
-    lens = _sample_prompts(serving["prefill_chunk"], max_len)
+    steps = _sample_steps(correct)
+    lens = _sample_prompts(serving["prefill_chunk"], max_len, steps)
     rids = [
         drv.submit(tg.Request(n, steps), due=time.perf_counter())
         for n in lens
@@ -352,7 +360,8 @@ def _mute(*_, **__):
     pass
 
 
-def pool_pages(serving: dict, pairs, max_len: int) -> int:
+def pool_pages(serving: dict, pairs, max_len: int,
+               steps: int = SAMPLE_STEPS) -> int:
     """The pool rule: every slot can hold the longest request this
     traffic sends (or the correctness sample's, in set-up), plus the
     trash page, and not a page more. No request ever waits on pages,
@@ -362,8 +371,8 @@ def pool_pages(serving: dict, pairs, max_len: int) -> int:
     same pages as prompt + answer."""
     longest = max(
         max(p + o for p, o in pairs), serving["prompt_buckets"][0],
-        max(_sample_prompts(serving["prefill_chunk"], max_len))
-        + SAMPLE_STEPS,
+        max(_sample_prompts(serving["prefill_chunk"], max_len, steps))
+        + steps,
     )
     return serving["slots"] * -(-longest // serving["page_size"]) + 1
 
@@ -428,7 +437,9 @@ def run_cell(cell: dict, config: dict, traffic: dict, opts) -> dict:
     phases.mark("weights")
     max_total = min(shape["max_len"], serving["prompt_buckets"][-1])
     pairs = tg.templates(traffic, max_total)
-    serving["pool_pages"] = pool_pages(serving, pairs, shape["max_len"])
+    serving["pool_pages"] = pool_pages(
+        serving, pairs, shape["max_len"], _sample_steps(correct)
+    )
     itemsize = jax.numpy.dtype(config["dtype"]).itemsize
     srv = ContinuousBatcher(
         lm, variables,

@@ -1,6 +1,7 @@
 """BENCHMARK.json against the contract's static rules."""
 
 import json
+import math
 import re
 from pathlib import Path
 
@@ -80,12 +81,18 @@ def test_every_cell_reports_what_the_contract_asks(bm):
 
 def _check_correct_block(correct):
     """``correct`` in a configuration file: the tolerance with its
-    readings; where the reference vouches position by position, the
-    least share it must vouch for, never under half; the controls its
+    readings; how many served logprobs a sampled request is compared
+    over, where the file says; where the reference vouches position by
+    position, the least share it must vouch for: never under 12
+    positions, what half of the engine's own 3 x 8 is (a share under
+    half only where the file compares more); the controls its
     reference knows."""
     assert correct["logprob_tol"] > 0 and correct["why"]
+    steps = correct.get("sample_steps", 8)
+    assert isinstance(steps, int) and 8 <= steps <= 256
     if "min_vouched" in correct:
-        assert 0.5 <= correct["min_vouched"] <= 1
+        assert 0 < correct["min_vouched"] <= 1
+        assert math.ceil(correct["min_vouched"] * 3 * steps) >= 12
     controls = correct.get("controls", ["drop_block"])
     assert isinstance(controls, list) and controls
     assert all(isinstance(c, str) and NAME.match(c) for c in controls)
@@ -104,6 +111,8 @@ def test_correct_block_of_every_configuration_file(file):
 
 @pytest.mark.parametrize("bad", [
     {"min_vouched": 0.4}, {"min_vouched": 1.5}, {"controls": []},
+    {"min_vouched": 0.1, "sample_steps": 32}, {"sample_steps": 4},
+    {"sample_steps": 8.5},
     {"controls": "drop_block"}, {"controls": ["drop block"]},
 ])
 def test_correct_block_refuses(bad):

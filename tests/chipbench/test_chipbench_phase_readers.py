@@ -129,13 +129,21 @@ def test_spans_without_a_gap_read_zero_and_no_spans_read_none(bucket):
 
 
 def test_the_twelve_entries_and_their_files():
+    """The twelve entries this file's readers serve, as PR 24 appended
+    them: in one block, each with its file and its reader. How many
+    entries stand beside them, and which cells later PRs appended to a
+    ``.batch`` entry's ``workloads``, is theirs to say."""
     bm = mf.load(ROOT)
     mine = [m for m in bm["per_layer"] if m["name"].startswith("tick.idle_")]
-    assert bm["per_layer"][-12:] == mine  # appended, in one block
-    assert len(bm["per_layer"]) == 31
+    first = bm["per_layer"].index(mine[0])
+    assert bm["per_layer"][first: first + 12] == mine  # one block of twelve
     cells = {
         ".serve": ("itl_p95_ms", ["gpt2xl_chat"]),
         ".batch": ("out_tok_per_s", ["cgpt1b3_batchgen", "gpt2xl_doc"]),
+    }
+    reports = {
+        e["name"]: e.get("workloads", [w["name"] for w in bm["workloads"]])
+        for e in bm["end_to_end"]
     }
     for bucket, read in READERS.items():
         for suffix, (moves, workloads) in cells.items():
@@ -144,13 +152,21 @@ def test_the_twelve_entries_and_their_files():
             assert m == {
                 "name": name, "unit": "ms", "better": "lower",
                 "source": "device_trace", "layer": "tick loop",
-                "moves": moves, "workloads": workloads,
+                "moves": moves, "workloads": m["workloads"],
             }
+            # The cells it was defined for, first; every cell reports
+            # the judged metric the entry moves.
+            assert m["workloads"][: len(workloads)] == workloads
+            assert set(m["workloads"]) <= set(reports[moves])
             assert mf.reader_of(bm, name, ROOT) is read
             body = json.loads(
                 (ROOT / "chipbench/metrics" / f"{name}.json").read_text()
             )
             assert "workloads" not in body  # BENCHMARK.json alone says
+    # Every entry, whoever added it: its file is there and names a
+    # reader that resolves.
+    for m in bm["per_layer"]:
+        assert callable(mf.reader_of(bm, m["name"], ROOT)), m["name"]
 
 
 def test_rehearsal_walks_the_readers_and_prints_no_device_number(

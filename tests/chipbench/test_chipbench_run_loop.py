@@ -13,13 +13,13 @@ import pytest
 
 from chipbench import run as bench_run
 from chipbench import xtrace, yardstick
+from run_loop_cases import ADDED, ROOT, control_cases
+from run_loop_cases import rehearse as _rehearse
 
-ROOT = Path(__file__).parents[2]
-ADDED_CELL = "tiny_moe_bursts"
-#: The same mixture served in bfloat16, held to a reference that says
-#: which positions it vouches for (``another_arch/plain.py``).
-ADDED_BF16 = "tiny_moe_bf16_bursts"
-ADDED = {ADDED_CELL: "tiny-moe", ADDED_BF16: "tiny-moe-bf16"}
+#: The added cells (``run_loop_cases.ADDED``): the mixture in float32,
+#: and served in bfloat16, held to a reference that says which
+#: positions it vouches for (``another_arch/plain.py``).
+ADDED_CELL, ADDED_BF16 = ADDED
 #: Metrics that are there and that the added cell joins by appending
 #: its name to their ``workloads`` in BENCHMARK.json, and nowhere else.
 JOINED = (
@@ -81,16 +81,6 @@ def added(tmp_path_factory):
     return root, _tree_hash(root / "chipbench")
 
 
-def _rehearse(capsys, *argv):
-    assert bench_run.main(["--rehearse", "--seconds", "1.5", *argv]) == 0
-    out = capsys.readouterr().out
-    lines = [ln for ln in out.splitlines() if ln.startswith("rehearsal ")]
-    # A rehearsal prints no result object and no timing.
-    assert not any(ln.lstrip().startswith("{") for ln in out.splitlines())
-    assert "hist " not in out and "setup:" not in out
-    return lines
-
-
 def _cell_argv(request, cell):
     """--workload, and for an added cell the --root it lives under."""
     if cell not in ADDED:
@@ -119,33 +109,17 @@ def test_rehearsal_walks_the_cell(request, capsys, cell, e2e):
     assert "roofline" not in traced and "decode_step_ms" not in traced
 
 
-def _control_cases():
-    """(cell, control): every control of every configuration, the
-    benchmark's and the added ones, once, in its first cell. A file
-    that names none has ``drop_block`` (``run.py``)."""
-    bm = json.loads((ROOT / "BENCHMARK.json").read_text())
-    files = {c["name"]: ROOT / c["file"] for c in bm["configs"]}
-    first = {}
-    for w in bm["workloads"]:
-        first.setdefault(w["config"], w["name"])
-    for cell, config in ADDED.items():
-        first[config] = cell
-        files[config] = (
-            Path(__file__).parent / f"another_arch/configs/{config}.json"
-        )
-    return [
-        (first[config], control)
-        for config, f in files.items()
-        for control in json.loads(f.read_text())["correct"].get(
-            "controls", ["drop_block"])
-    ]
-
-
-@pytest.mark.parametrize("cell,control", _control_cases())
+@pytest.mark.parametrize(
+    "cell,control", control_cases(but=("k-exaone-236b-a23b",
+                                       "falcon-h1-34b-instruct")),
+)
 def test_a_control_makes_the_run_incorrect(request, capsys, cell, control):
     """The self-test of `correct`: with one block (or, for a mixture,
     one expert) left out of the plain reference THE CONFIGURATION
-    NAMES, the served logprobs must disagree."""
+    NAMES, the served logprobs must disagree. The K-EXAONE and
+    Falcon-H1 files' controls run in files of their own
+    (``test_chipbench_run_loop_*.py``): under ``--dist loadfile`` one
+    file is one worker's."""
     plain, traced = _rehearse(
         capsys, *_cell_argv(request, cell), "--fault", control
     )

@@ -17,6 +17,11 @@ def _load(path):
     return json.loads(path.read_text())
 
 
+def _longest(traffic):
+    """The mix's own longest request: the cap its templates fit under."""
+    return int(traffic["prompt"]["max"] + traffic["output"]["max"])
+
+
 @pytest.mark.parametrize("dist", [
     {"dist": "uniform", "min": 10, "max": 50},
     {"dist": "lognormal", "median": 128, "sigma": 0.7, "min": 32, "max": 512},
@@ -38,7 +43,8 @@ def test_exponential_block_lasts_exactly_its_mean_times_n():
 
 @pytest.mark.parametrize("path", TRAFFIC, ids=lambda p: p.stem)
 def test_every_seed_walks_the_same_multiset_in_another_order(path):
-    pairs = tg.templates(_load(path), 1024)
+    traffic = _load(path)
+    pairs = tg.templates(traffic, _longest(traffic))
     n = len(pairs)
     walks = []
     for seed in (1, 2, 4_000_000_123):
@@ -51,7 +57,7 @@ def test_every_seed_walks_the_same_multiset_in_another_order(path):
 
 def test_open_schedule_same_gaps_and_count_for_every_seed():
     t = _load(Path(__file__).parents[2] / "chipbench/traffic/chat.json")
-    pairs = tg.templates(t, 1024)
+    pairs = tg.templates(t, _longest(t))
     block_s = t["gap_block"] / t["rate_per_s"]
     runs = [tg.open_schedule(t, pairs, seed, 3 * block_s + 1e-9)
             for seed in (7, 8, 2**31 + 5)]
