@@ -16,10 +16,11 @@ Pallas kernels here stream pages directly: the page table rides as a
 SCALAR-PREFETCH operand (``pltpu.PrefetchScalarGridSpec``), and the
 pool's ``index_map`` consults it to pick each grid step's physical page — the
 DMA engine fetches pool blocks in table order while the online-softmax
-state carries across them. The step body is ``ops/decode_attention``'s
-``_attend_tile`` (same masks, same float32 softmax state, same fused
-dequant); only the block FETCH differs, which is the whole point: one
-attention discipline, two memory layouts.
+state carries across them. The decode and verify step body is
+``ops/decode_attention``'s ``_attend_tile`` (same masks, same float32
+softmax state, same fused dequant); only the block FETCH differs,
+which is the whole point: one attention discipline, two memory
+layouts.
 
 The DECODE kernel's grid is ``(slots, head blocks, pages a slot)`` and
 one step covers one page of EVERY KV head of its block — a
@@ -36,8 +37,23 @@ slots' live positions are prefetched beside the table and the index
 map CLAMPS the page axis to each slot's live window: a step past the
 last live page (or before the first, for ragged rows) names the block
 already resident, so the pipeline issues no copy for it, and the body
-skips it (``pl.when``). The verify and chunk kernels keep one head a
-step (``_verify_impl``, ``_chunk_impl``).
+skips it (``pl.when``). The verify kernel keeps one head a step
+(``_verify_impl``).
+
+The CHUNK-PREFILL kernel (``_chunk_impl``) folds heads the same way,
+by its own sum (``chunk_heads_per_step``: a chunk's state is per query
+ROW, so 5 of GPT-2-XL's 25 heads fit a step at 256 rows and one at
+K-EXAONE's 2,048), its dead steps (the page list's power-of-two
+padding, pages wholly under a sliding window) name the nearest live
+block and fetch nothing, and over a native pool its step body is its
+own: ``_attend_rows_on_lanes`` keeps a page's positions on the
+sublanes and the chunk's query rows on the LANES, so both softmax
+reductions run down the sublanes and the per-row state is lane-dense;
+q and K reach the MXU in the pool's dtype. ``_attend_tile``'s layout,
+a row's state on a sublane, is right for decode's 8 rows a head and
+cost a chunk's hundreds three times the vector work (measured on a
+v5e, PERF.md section 6, PR 42). Quantized pools keep ``_attend_tile``
+under the fold.
 
 The grid's page axis can additionally FLASH-SPLIT (``split`` on every
 dispatcher, ``config.KernelConfig.decode_split``): each (row, split)
@@ -116,6 +132,7 @@ from jax.sharding import PartitionSpec as P
 
 from adapt_tpu.ops.decode_attention import (
     DECODE_BLOCK_K,
+    _NEG_INF,
     _attend_tile,
     _combine_splits,
     _init_softmax_scratch,
@@ -419,12 +436,30 @@ def decode_heads_per_step(kv_heads, page, row_width, itemsize, scales,
     128 are 1 MB of fused rows, 25 heads at head_dim 64 0.8 MB; an int8
     pool at 1024-position pages comes out at 8 of 16 (5 of 25) by the
     same sum. Derived from the operands, never set."""
+    return _heads_that_fit(kv_heads, lambda heads: decode_step_vmem_bytes(
+        heads, page, row_width, itemsize, scales, gq, head_dim
+    ))
+
+
+def _heads_that_fit(kv_heads, step_bytes) -> int:
+    """The largest divisor of ``kv_heads`` whose grid step
+    (``step_bytes(heads)``) fits ``DECODE_STEP_VMEM_BUDGET``; 1 where
+    not even one head does, which the kernel then tries anyway."""
     for heads in range(kv_heads, 1, -1):
-        if kv_heads % heads == 0 and decode_step_vmem_bytes(
-            heads, page, row_width, itemsize, scales, gq, head_dim
-        ) <= DECODE_STEP_VMEM_BUDGET:
+        if kv_heads % heads == 0 and (
+            step_bytes(heads) <= DECODE_STEP_VMEM_BUDGET
+        ):
             return heads
     return 1
+
+
+def _shard_heads(q, head_shard) -> int:
+    """KV heads a kernel call sees: q's, or a shard's of them under a
+    ``head_shard`` = ``(mesh, axis)``."""
+    if head_shard is None:
+        return q.shape[1]
+    mesh, axis = head_shard
+    return q.shape[1] // mesh.shape[axis]
 
 
 def _attend_fused(q, kv, ksc, vsc, live, m_scr, l_scr, acc_scr, sm_scale,
@@ -459,6 +494,53 @@ def _pad_q_lanes(q, hd):
     if not pad:
         return q
     return jnp.pad(q, [(0, 0)] * (q.ndim - 1) + [(0, pad)])
+
+
+def _attend_rows_on_lanes(q, kv, live, m_scr, l_scr, acc_scr, sm_scale):
+    """One page of a fused NATIVE K|V plane against a CHUNK of query
+    rows, transposed: positions on the sublanes, query rows on the
+    LANES — the chunk kernel's step body. ``q`` (heads, gc, aw), ``kv``
+    (heads, page, 2 * hd), ``live`` (page, gc) bool, or None for a page
+    every row attends whole; the state is a (heads, 1, gc) running max
+    and denominator and a (heads, aw, gc) accumulator, V's rows its
+    last head_dim (``_acc_width``).
+
+    ``_attend_tile`` keeps a query row's state on a SUBLANE: its
+    (gc, 1) max and denominator fill one lane of a vreg each, and both
+    row reductions cross the lanes. That is the right shape for
+    decode's 8 rows a head. A chunk has hundreds, and its step was all
+    vector work (measured on a v5e, PERF.md section 6, PR 42: a step
+    cost 0.2 us and each head's 256 x 128 tile 0.33 us more, whatever
+    dtype the products' operands had). Here the reductions over a
+    page's positions run DOWN the sublanes, elementwise from vreg to
+    vreg, and the state is lane-dense: 2 vregs where it was 32, at a
+    third of the time a tile. Same masks and the same float32 softmax
+    state; q and K reach the MXU as they are (a bf16 x bf16 product
+    accumulated in float32 is exact product by product) and the
+    probabilities in V's dtype — what Mosaic's default precision made
+    of the float32 operands before, so a bfloat16 pool's results are
+    the ones it had."""
+    hd = kv.shape[-1] // 2
+    if q.shape[-1] == hd:  # cut at a tile edge
+        k, v = kv[..., :hd], kv[..., hd:]
+    else:  # q zero-padded over V's lanes: contract the whole row
+        k = v = kv
+    heads = ((0,), (0,))
+    s = jax.lax.dot_general(
+        k, q, (((2,), (2,)), heads), preferred_element_type=jnp.float32
+    ) * sm_scale  # (heads, page, gc)
+    if live is not None:
+        s = jnp.where(live, s, _NEG_INF)
+    m = m_scr[...]
+    m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+    alpha = jnp.exp(m - m_new)
+    p = jnp.exp(s - m_new)
+    m_scr[...] = m_new
+    l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+    acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
+        v, p.astype(v.dtype), (((1,), (1,)), heads),
+        preferred_element_type=jnp.float32,
+    )  # (heads, aw, gc)
 
 
 @functools.partial(jax.jit, static_argnames=("heads", "split"))
@@ -713,62 +795,120 @@ def _paged_kernel(table_ref, idx_ref, *refs, page, num_pages, bps, sm_scale,
         _emit_softmax(o_ref, parts, m_scr, l_scr, acc_scr)
 
 
-def _chunk_kernel(pages_ref, q_ref, kv_ref, pos0_ref, *refs,
+def _chunk_kernel(pages_ref, pos0_ref, q_ref, kv_ref, *refs,
                   block_k, num_kv, sm_scale, chunk, window=None,
-                  quantized=False, packed=False):
+                  quantized=False, packed=False, lanes=True):
     """Chunk-query paged attention: q rows are a CHUNK of positions
     [pos0, pos0 + chunk) (GQA groups folded in, row = member*chunk + p)
     attending the paged window up to each row's own position — the
-    per-row causal mask ``col <= pos0 + row % chunk``. One (kv_head)
-    program streams the window's pages innermost with online-softmax
-    scratch, exactly the decode kernel's discipline with a row-dependent
-    diagonal instead of a shared index. Quantized pools add chunked
-    (page/128, 128) f32 scale tiles applied to the score/probability
+    per-row causal mask ``col <= pos0 + row % chunk``. Grid (head
+    blocks, pages): one step covers one page of ``heads`` KV heads — a
+    (1, heads, page, 2 * hd) block of fused rows against (heads, gc, aw)
+    query rows — in ONE body with the head axis leading and per-head
+    online-softmax state in scratch. ``lanes`` (every native pool): the
+    body is ``_attend_rows_on_lanes``, the state (heads, 1, gc) and
+    (heads, aw, gc), transposed back once, at the last step; a page
+    every row attends whole skips the mask. Otherwise (quantized
+    pools) it is ``_attend_fused``, the decode kernel's body
+    (``_paged_kernel``) with a row-dependent diagonal instead of a
+    shared index and (heads, gc, .) state, and adds chunked (1, heads,
+    page/128, 128) f32 scale tiles applied to the score/probability
     columns in VMEM (``_decode_kernel``'s fused-dequant discipline;
-    ``packed`` int4 pools unpack their nibbles there too)."""
+    ``packed`` int4 pools unpack their nibbles there too). The page
+    list and ``pos0`` are scalar-prefetched: the index maps read both
+    (``_chunk_impl``'s ``kv_map``), the body reads ``pos0`` for its
+    masks."""
     del pages_ref  # consumed by the index_maps
     refs = list(refs)
     ksc_ref = refs.pop(0) if quantized else None
     vsc_ref = refs.pop(0) if quantized else None
     o_ref, m_scr, l_scr, acc_scr = refs
     j = pl.program_id(1)
-    gc = q_ref.shape[1]
+    heads, gc = q_ref.shape[0], q_ref.shape[1]
     pos0 = pos0_ref[0]
 
     @pl.when(j == 0)
     def _init():
         _init_softmax_scratch(m_scr, l_scr, acc_scr)
 
-    def _step():
-        rows = jax.lax.broadcasted_iota(jnp.int32, (gc, block_k), 0) % chunk
+    def _live(shape, row_axis):
+        rows = jax.lax.broadcasted_iota(jnp.int32, shape, row_axis)
+        if gc != chunk:  # GQA members fold in: position = row % chunk
+            pow2 = chunk & (chunk - 1) == 0
+            rows = rows & (chunk - 1) if pow2 else rows % chunk
         cols = j * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (gc, block_k), 1
+            jnp.int32, shape, 1 - row_axis
         )
         live = cols <= pos0 + rows
         if window is not None:
             # Sliding window: row at absolute position p attends
             # (p - window, p].
             live = jnp.logical_and(live, cols > pos0 + rows - window)
+        return live
+
+    def _step(masked=True):
+        if lanes:
+            _attend_rows_on_lanes(
+                q_ref[...], kv_ref[0],
+                _live((block_k, gc), 1) if masked else None,
+                m_scr, l_scr, acc_scr, sm_scale,
+            )
+            return
         _attend_fused(
-            q_ref[0], kv_ref[0, 0],
-            ksc_ref[0, 0].reshape(1, block_k) if quantized else None,
-            vsc_ref[0, 0].reshape(1, block_k) if quantized else None,
-            live, m_scr, l_scr, acc_scr, sm_scale, packed,
+            q_ref[...], kv_ref[0],
+            ksc_ref[0].reshape(heads, 1, block_k) if quantized else None,
+            vsc_ref[0].reshape(heads, 1, block_k) if quantized else None,
+            _live((gc, block_k), 0), m_scr, l_scr, acc_scr, sm_scale,
+            packed,
         )
 
     # Pages entirely past the chunk's last position are dead (the pow2
     # padding's trash pages land here too); under a sliding window so
     # are pages entirely below EVERY row's window (row 0's is lowest).
-    live_block = j * block_k <= pos0 + chunk - 1
-    if window is not None:
-        live_block = jnp.logical_and(
-            live_block, (j + 1) * block_k - 1 > pos0 - window
+    # A dead step fetched nothing either (``_chunk_live_pages``).
+    first, last = _chunk_live_pages(pos0, chunk, block_k, num_kv, window)
+    live_block = jnp.logical_and(j >= first, j <= last)
+    if lanes:
+        # A page every row attends whole (under the diagonal, inside
+        # the last row's window) needs no mask.
+        whole = (j + 1) * block_k - 1 <= pos0
+        if window is not None:
+            whole = jnp.logical_and(
+                whole, j * block_k > pos0 + chunk - 1 - window
+            )
+        pl.when(jnp.logical_and(live_block, whole))(
+            functools.partial(_step, masked=False)
         )
+        live_block = jnp.logical_and(live_block, jnp.logical_not(whole))
     pl.when(live_block)(_step)
 
     @pl.when(j == num_kv - 1)
     def _emit():
-        _emit_softmax(o_ref, None, m_scr, l_scr, acc_scr)
+        if not lanes:
+            _emit_softmax(o_ref, None, m_scr, l_scr, acc_scr)
+            return
+        hd = o_ref.shape[-1]
+        acc = acc_scr[...][:, acc_scr.shape[1] - hd:, :]
+        out = acc / jnp.maximum(l_scr[...], 1e-30)  # (heads, hd, gc)
+        o_ref[...] = jnp.swapaxes(out, 1, 2).astype(o_ref.dtype)
+
+
+def _chunk_live_pages(pos0, chunk, page, n, window):
+    """``(first, last)`` entries of a chunk's page list that hold a
+    position some row attends: the last is the page of the chunk's last
+    position (the power-of-two padding's trash pages lie past it), the
+    first the page of row 0's lowest position, ``pos0 - window + 1``,
+    under a sliding window. The kernel's body runs on these and its
+    index map names no other, so the steps outside fetch nothing.
+    (``lax.div``: nothing here is negative, and a floor division's sign
+    handling costs a kernel's lowering more than its body does.)"""
+    def page_of(pos):
+        return lax.div(pos, jnp.asarray(page, pos.dtype))
+
+    last = jnp.minimum(page_of(pos0 + chunk - 1), n - 1)
+    if window is None:
+        return 0, last
+    return jnp.minimum(page_of(jnp.maximum(pos0 - window + 1, 0)), last), last
 
 
 def paged_chunk_attention_reference(q, pool, pages, pos0, chunk: int,
@@ -810,58 +950,128 @@ def paged_chunk_attention_reference(q, pool, pages, pos0, chunk: int,
     ).astype(q.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "window"))
+def _chunk_rows(gc: int, lanes: bool) -> int:
+    """A KV head's query rows as the chunk kernel holds them: whole
+    lane tiles where they lie on the lanes, whole sublane tiles
+    otherwise (nothing to pad at a chunk of whole pages)."""
+    return gc + (-gc) % (128 if lanes else 8)
+
+
+def chunk_step_vmem_bytes(heads, gc, page, row_width, itemsize, scales,
+                          head_dim=None, q_itemsize=None) -> int:
+    """VMEM one grid step of the chunk kernel asks for when it covers
+    ``heads`` KV heads of one page: the fused block (and its two scale
+    tiles), q's rows and the output's, each double-buffered by the
+    pipeline; the per-head softmax state; and the body's float32
+    working set. A chunk's state is per query ROW, so ``gc`` (group x
+    chunk rows a KV head) dominates where the decode kernel's sum is
+    all block: the working set is four score-shaped (page, gc) arrays
+    (scores, mask, exponentials, what the second product reads) and
+    that product's accumulator-shaped result. The running max and the
+    denominator are a sublane tile each with the rows on the lanes
+    (native pools); under a quantized pool's body they are (gc, 1) and
+    pad to a lane tile each. Compiling for a described v5e, Mosaic
+    took every step whose sum was under 15.8 MB and refused every one
+    over 20 MB at its scoped 16 MB."""
+    hd = head_dim or row_width // 2
+    acc = _lanes(_acc_width(hd))
+    stream = 2 * heads * page * _lanes(row_width) * itemsize
+    if scales:
+        # the two scale tiles, and the block widened on its way in
+        stream += 2 * 2 * heads * max(page // 128, 8) * 128 * 4
+        stream += heads * page * _lanes(row_width) * 4
+    rows = 2 * heads * gc * (acc + _lanes(hd)) * (q_itemsize or itemsize)
+    state = heads * gc * (2 * (128 if scales else 8) + acc) * 4
+    working = heads * gc * (4 * page + acc) * 4
+    return stream + rows + state + working
+
+
+def chunk_heads_per_step(kv_heads, gc, page, row_width, itemsize, scales,
+                         head_dim=None, q_itemsize=None) -> int:
+    """KV heads one grid step of the chunk kernel covers: the largest
+    divisor of ``kv_heads`` (the per-shard count under tensor
+    parallelism) whose step fits ``DECODE_STEP_VMEM_BUDGET``, by
+    ``decode_heads_per_step``'s rule over the chunk's own sum. A grid
+    step costs 0.2 us on a v5e before it moves a byte: 5 of 25 heads at
+    GPT-2-XL's 256 rows of head_dim 64 (25 do not fit Mosaic's 16 MB);
+    1 at K-EXAONE's 2,048 rows and Falcon-H1's 1,280, where a head's
+    tile is a step's worth of work already. Derived from the operands,
+    never set."""
+    return _heads_that_fit(kv_heads, lambda heads: chunk_step_vmem_bytes(
+        heads, gc, page, row_width, itemsize, scales, head_dim, q_itemsize
+    ))
+
+
+@functools.partial(
+    jax.jit, static_argnames=("chunk", "window", "heads", "lanes")
+)
 def _chunk_impl(q, kv_pool, k_scales, v_scales, pages, pos0, chunk,
-                window=None):
+                window=None, heads=1, lanes=None):
     _, kvh, gc, hd = q.shape
     page = kv_pool.shape[2]
     row = kv_pool.shape[3]  # K|V: 2 * head_dim, head_dim for packed int4
     n = pages.shape[0]
     quantized = k_scales is not None
     packed = _packed(q, kv_pool, quantized)
-    pad_g = (-gc) % 8
-    if pad_g:
-        q = jnp.pad(q, ((0, 0), (0, 0), (0, pad_g), (0, 0)))
-    gcp = gc + pad_g
+    if lanes is None:
+        # A native pool's chunk rows lie on the lanes
+        # (``_attend_rows_on_lanes``); int8 / int4 values are the
+        # scales' arithmetic and keep the decode kernels' body.
+        lanes = not quantized
+    gcp = _chunk_rows(gc, lanes)
+    if gcp != gc:
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, gcp - gc), (0, 0)))
     aw = _acc_width(hd)
     qf = _pad_q_lanes(q, hd).reshape(kvh, gcp, aw)
-    pos0v = jnp.reshape(jnp.asarray(pos0, jnp.int32), (1,))
+    # Scalar prefetch: the page list and the chunk's first position.
+    # The index maps read both; the body reads ``pos0`` for its masks.
+    prefetch = [
+        jnp.asarray(pages, jnp.int32),
+        jnp.reshape(jnp.asarray(pos0, jnp.int32), (1,)),
+    ]
 
-    def q_map(h, j, pages_ref):
-        del j, pages_ref
-        return (h, 0, 0)
+    def q_map(hb, j, pages_ref, pos0_ref):
+        del j, pages_ref, pos0_ref
+        return (hb, 0, 0)
 
-    def kv_map(h, j, pages_ref):
-        return (pages_ref[j], h, 0, 0)
+    def kv_map(hb, j, pages_ref, pos0_ref):
+        # A dead step (a trash page past the chunk's last position, a
+        # page wholly under every row's window) names the nearest live
+        # entry — the block the step beside it holds — so the pipeline
+        # issues no copy for it.
+        first, last = _chunk_live_pages(pos0_ref[0], chunk, page, n, window)
+        return (pages_ref[jnp.clip(j, first, last)], hb, 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, gcp, aw), q_map, memory_space=_VMEM),
-        pl.BlockSpec((1, 1, page, row), kv_map, memory_space=_VMEM),
-        pl.BlockSpec(memory_space=pltpu.SMEM),
+        pl.BlockSpec((heads, gcp, aw), q_map, memory_space=_VMEM),
+        pl.BlockSpec((1, heads, page, row), kv_map, memory_space=_VMEM),
     ]
-    operands = [qf, kv_pool, pos0v]
+    operands = [qf, kv_pool]
     if quantized:
-        # Kernel arg order is q, kv, pos0, THEN the scale tiles (the
-        # kernel pops them off *refs after the SMEM scalar); chunked
-        # (P/128, 128) scale views as in _paged_impl.
+        # Chunked (P/128, 128) scale views as in _paged_impl, addressed
+        # by the payload's own index map.
         for s in (k_scales, v_scales):
             operands.append(
                 s.reshape(s.shape[0], kvh, page // 128, 128)
             )
             in_specs.append(
                 pl.BlockSpec(
-                    (1, 1, page // 128, 128), kv_map, memory_space=_VMEM
+                    (1, heads, page // 128, 128), kv_map,
+                    memory_space=_VMEM,
                 )
             )
+    state = (heads, 1, gcp) if lanes else (heads, gcp, 1)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(kvh, n),
+        num_scalar_prefetch=len(prefetch),
+        grid=(kvh // heads, n),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, gcp, hd), q_map, memory_space=_VMEM),
+        out_specs=pl.BlockSpec((heads, gcp, hd), q_map, memory_space=_VMEM),
         scratch_shapes=[
-            pltpu.VMEM((gcp, 1), jnp.float32),
-            pltpu.VMEM((gcp, 1), jnp.float32),
-            pltpu.VMEM((gcp, aw), jnp.float32),
+            pltpu.VMEM(state, jnp.float32),
+            pltpu.VMEM(state, jnp.float32),
+            pltpu.VMEM(
+                (heads, aw, gcp) if lanes else (heads, gcp, aw), jnp.float32
+            ),
         ],
     )
     out = pl.pallas_call(
@@ -874,6 +1084,7 @@ def _chunk_impl(q, kv_pool, k_scales, v_scales, pages, pos0, chunk,
             window=window,
             quantized=quantized,
             packed=packed,
+            lanes=lanes,
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((kvh, gcp, hd), q.dtype),
@@ -881,7 +1092,7 @@ def _chunk_impl(q, kv_pool, k_scales, v_scales, pages, pos0, chunk,
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=pallas_interpret(),
-    )(jnp.asarray(pages, jnp.int32), *operands)
+    )(*prefetch, *operands)
     return out.reshape(1, kvh, gcp, hd)[:, :, :gc, :]
 
 
@@ -902,18 +1113,32 @@ def paged_chunk_attention(
 
     q (1, kv_h, g*chunk, hd) group-folded; ``pages`` (n,) covers the
     whole live window [0, pos0 + chunk) (pow2 padding to the trash page
-    is fine — those positions are past every row's mask). ``pool`` is a
+    is fine — those positions are past every row's mask, and the
+    kernel neither fetches nor reads them). ``pool`` is a
     block's pool: the fused plane or a quantized ``(values, k_scales,
     v_scales)`` triple. Dispatch and ``head_shard`` as
-    :func:`paged_attention`."""
+    :func:`paged_attention`. The KV heads one grid step covers
+    (``chunk_heads_per_step``) derive from the given (per-shard, under
+    TP) operands, and the books say what was derived
+    (``kernel_dispatch_stats()["paged_chunk"]``: ``heads_per_step``)."""
     check_head_parity(q.shape[1], pool_values(pool).shape[1])
     if resolve_prefer(
         "paged_chunk", prefer, kernel_unsupported(q, pool), on_tpu()
     ):
+        kv, ks, vs = _pool_planes(pool)
+        heads = chunk_heads_per_step(
+            _shard_heads(q, head_shard),
+            _chunk_rows(q.shape[2], ks is None), kv.shape[2],
+            kv.shape[3], kv.dtype.itemsize, ks is not None, q.shape[3],
+            q.dtype.itemsize,
+        )
+        record_kernel_choice("paged_chunk", heads_per_step=heads)
         return _head_sharded(
-            functools.partial(_chunk_impl, chunk=chunk, window=window),
+            functools.partial(
+                _chunk_impl, chunk=chunk, window=window, heads=heads
+            ),
             head_shard,
-            (q, *_pool_planes(pool)),
+            (q, kv, ks, vs),
             (jnp.asarray(pages, jnp.int32), jnp.asarray(pos0, jnp.int32)),
         )
     return paged_chunk_attention_reference(
@@ -1244,12 +1469,9 @@ def paged_attention(
         "paged_decode", prefer, kernel_unsupported(q, pool), on_tpu()
     ):
         kv, ks, vs = _pool_planes(pool)
-        heads = q.shape[1]
-        if head_shard is not None:
-            mesh, axis = head_shard
-            heads //= mesh.shape[axis]
         heads = decode_heads_per_step(
-            heads, kv.shape[2], kv.shape[3], kv.dtype.itemsize,
+            _shard_heads(q, head_shard), kv.shape[2], kv.shape[3],
+            kv.dtype.itemsize,
             ks is not None, q.shape[2] + (-q.shape[2]) % 8, q.shape[3],
         )
         split = resolve_decode_split(page_table.shape[1], split)

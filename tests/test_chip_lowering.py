@@ -38,6 +38,9 @@ from adapt_tpu.ops.attention import flash_attention, flash_attention_with_lse
 from adapt_tpu.ops.decode_attention import decode_attention
 from adapt_tpu.ops.dispatch import kernel_dispatch_stats
 from adapt_tpu.ops.paged_attention import (
+    DECODE_STEP_VMEM_BUDGET,
+    chunk_heads_per_step,
+    chunk_step_vmem_bytes,
     decode_heads_per_step,
     fuse_kv,
     paged_attention,
@@ -158,6 +161,15 @@ def test_paged_verify_lowers(as_tpu, tree_tail, dtype, page):
         )
 
 
+#: kv heads, query heads a KV head, head_dim, window, heads a step:
+#: the chunk shapes the cells compile (chunk 256, page 128).
+_CHUNK_CELL_SHAPES = {
+    "gpt2xl": (25, 1, 64, None, 5),
+    "kexaone-window": (8, 8, 128, 128, 1),
+    "falconh1": (4, 5, 128, None, 1),
+}
+
+
 @pytest.mark.parametrize("dtype,page,window", [
     ("native", 128, None), ("native", 128, 300), ("int8", 1024, None),
 ])
@@ -170,6 +182,22 @@ def test_paged_chunk_lowers(as_tpu, dtype, page, window):
         sds((1, KVH, chunk, HD)), pool(page, dtype), sds((4,), jnp.int32),
         sds((), jnp.int32),
     )
+
+
+@pytest.mark.parametrize("cell", sorted(_CHUNK_CELL_SHAPES))
+def test_paged_chunk_lowers_at_cell_shapes(as_tpu, cell):
+    """The folded chunk kernel at the cells' own shapes (chunk 256,
+    page 128), at each of a document's three pass widths."""
+    kvh, g, hd, window, want = _CHUNK_CELL_SHAPES[cell]
+    for n in (2, 4, 8):
+        lower_for_tpu(
+            lambda q, kv, p, pos0: paged_chunk_attention(
+                q, kv, p, pos0, 256, window=window
+            ),
+            sds((1, kvh, g * 256, hd)), sds((65, kvh, 128, 2 * hd)),
+            sds((n,), jnp.int32), sds((), jnp.int32),
+        )
+    assert kernel_dispatch_stats()["paged_chunk"]["heads_per_step"] == want
 
 
 @pytest.mark.parametrize("quantized", [False, True])
@@ -437,6 +465,44 @@ def test_folded_paged_decode_compiles_for_v5e(
         on_chip((b,), jnp.int32), on_chip((b,), jnp.int32),
     ).compile().as_text()
     assert re.search(r"%_paged_impl[.\d]* = .*tpu_custom_call", text)
+
+
+@pytest.mark.parametrize("cell", sorted(_CHUNK_CELL_SHAPES))
+def test_folded_paged_chunk_compiles_for_v5e(
+    as_tpu, one_chip, no_persistent_cache, cell
+):
+    """Mosaic's own compile of the chunk kernel at the cells' shapes:
+    the heads a step ``chunk_heads_per_step`` derives (the books say
+    which) fit a v5e's scoped VMEM with bfloat16 operands on the MXU,
+    the dead steps' clamped index map compiles, and the operation
+    keeps the name the benchmark's readers sum (``_chunk_impl``). The
+    step's VMEM sum is under the budget it was derived from wherever
+    there was a choice; one head of K-EXAONE's 2,048 rows is over it
+    and under Mosaic's 16 MB, which this compile is the proof of."""
+    kvh, g, hd, window, want = _CHUNK_CELL_SHAPES[cell]
+    chunk, page = 256, 128
+
+    def on_chip(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    for n in (2, 8):
+        text = jax.jit(
+            lambda q, kv, p, pos0: paged_chunk_attention(
+                q, kv, p, pos0, chunk, window=window
+            )
+        ).lower(
+            on_chip((1, kvh, g * chunk, hd)),
+            on_chip((65, kvh, page, 2 * hd)),
+            on_chip((n,), jnp.int32), on_chip((), jnp.int32),
+        ).compile().as_text()
+        assert re.search(r"%_chunk_impl[.\d]* = .*tpu_custom_call", text)
+    heads = kernel_dispatch_stats()["paged_chunk"]["heads_per_step"]
+    args = (g * chunk, page, 2 * hd, 2, False, hd, 2)
+    assert heads == chunk_heads_per_step(kvh, *args) == want
+    used = chunk_step_vmem_bytes(want, *args)
+    assert used <= (
+        DECODE_STEP_VMEM_BUDGET if want > 1 else 2 * DECODE_STEP_VMEM_BUDGET
+    )
 
 
 def _pool_copies(text, shape):

@@ -348,32 +348,139 @@ def test_paged_kernel_matches_oracle(rng, hd, quantized):
         )
 
 
-@_QUANT
-@_HD
-def test_paged_chunk_kernel_matches_oracle(rng, hd, quantized):
+#: A chunk's passes as the batcher hands them: (pos0, page list) with
+#: the power-of-two padding pointed at the trash page 0.
+_CHUNK_PASSES = [(128, [3, 7, 0, 0]), (0, [5, 0]), (256, [2, 4, 9, 0])]
+
+#: id -> (kv_heads, query heads a KV head, chunk, head_dim, pool,
+#: window, passes). The first six are the kernel's cases since PR 21
+#: (their ids and float32 tolerance kept); the rest are what one grid
+#: step covers since PR 42: heads a step > 1 at a head count with a
+#: proper divisor (25 -> 5, GPT-2-XL's own chunk shape) and at a prime
+#: one, the groups of 5 and 8 query heads Falcon-H1 and K-EXAONE fold,
+#: a window with pages wholly under it, and the pools whose operands
+#: reach the MXU as they are (bfloat16) or keep the widening (int8
+#: with scales, packed int4).
+_CHUNK_CASES = {
+    "32-native": (2, 3, 32, 32, "f32", None, _CHUNK_PASSES),
+    "64-native": (2, 3, 32, 64, "f32", None, _CHUNK_PASSES),
+    "128-native": (2, 3, 32, 128, "f32", None, _CHUNK_PASSES),
+    "32-int8": (2, 3, 32, 32, "int8", None, _CHUNK_PASSES),
+    "64-int8": (2, 3, 32, 64, "int8", None, _CHUNK_PASSES),
+    "128-int8": (2, 3, 32, 128, "int8", None, _CHUNK_PASSES),
+    "gpt2xl-25-heads-5-a-step-bf16": (
+        25, 1, 256, 64, "bf16", None, [(256, [2, 4, 9, 1]), (0, [5, 3])],
+    ),
+    "prime-7-heads-f32": (7, 1, 32, 64, "f32", None, _CHUNK_PASSES),
+    "prime-7-heads-bf16": (7, 2, 32, 128, "bf16", None, _CHUNK_PASSES),
+    "gqa5-hd128-bf16": (4, 5, 32, 128, "bf16", None, _CHUNK_PASSES),
+    "gqa5-hd128-f32": (4, 5, 32, 128, "f32", None, _CHUNK_PASSES),
+    "gqa8-hd128-window-bf16": (
+        2, 8, 32, 128, "bf16", 128,
+        [(384, [3, 7, 9, 2]), (0, [5, 0]), (160, [2, 4, 0, 0])],
+    ),
+    "gqa8-hd128-window-f32": (
+        2, 8, 32, 128, "f32", 100,
+        [(384, [3, 7, 9, 2, 0, 0, 0, 0]), (96, [5, 0])],
+    ),
+    "window-shorter-than-chunk-f32": (
+        3, 2, 32, 64, "f32", 20, [(256, [2, 4, 9, 0]), (0, [5, 0])],
+    ),
+    "64-int4": (2, 3, 32, 64, "int4", None, _CHUNK_PASSES),
+    "128-int4": (2, 3, 32, 128, "int4", None, _CHUNK_PASSES),
+    "64-int8-window": (5, 2, 32, 64, "int8", 130, [(384, [3, 7, 9, 2])]),
+    "64-bf16": (2, 3, 32, 64, "bf16", None, _CHUNK_PASSES),
+}
+
+#: Float32 operands reach both products as float32, as they always
+#: did. A bfloat16 pool's scores are the oracle's (a bf16 x bf16
+#: product is exact in float32); its probabilities are rounded to
+#: bfloat16 for the second product and the output is bfloat16, so it
+#: sits within an ulp or two of the oracle's own rounding.
+_CHUNK_TOL = {"bf16": dict(rtol=2e-2, atol=2e-2)}
+
+
+def _chunk_operands(key, kvh, g, chunk, hd, pool, npages=12, page=128):
+    q = jax.random.normal(key, (1, kvh, g * chunk, hd))
+    if pool in ("int8", "int4"):
+        k, v = (
+            quantize_kv_vectors(
+                jax.random.normal(
+                    jax.random.fold_in(key, i), (npages, kvh, page, hd)
+                ),
+                pool,
+            )
+            for i in (1, 2)
+        )
+        return q, fuse_kv(k, v)
+    kv = _pool(key, npages, kvh, page, hd)
+    if pool == "bf16":
+        return q.astype(jnp.bfloat16), kv.astype(jnp.bfloat16)
+    return q, kv
+
+
+@pytest.mark.parametrize("case", list(_CHUNK_CASES))
+def test_paged_chunk_kernel_matches_oracle(rng, case):
     """Chunk-query kernel (per-row causal over a paged window) vs its
     gather oracle: GQA folding, non-zero pos0, and pow2 trash padding;
-    quantized, the chunk's rows attend the int8 window with fused scale
-    application."""
+    quantized, the chunk's rows attend the int8 / int4 window with
+    fused scale application. One grid step covers every KV head that
+    fits (``chunk_heads_per_step``), and one head a step gives the same
+    rows. The pages no row attends (the padding's, those wholly under
+    the window) are poisoned: a dead step's block, fetched or not,
+    never reaches the result."""
+    from adapt_tpu.ops.dispatch import kernel_dispatch_stats
     from adapt_tpu.ops.paged_attention import (
+        _chunk_impl,
+        _chunk_rows,
+        _pool_planes,
+        chunk_heads_per_step,
         paged_chunk_attention,
         paged_chunk_attention_reference,
+        pool_values,
     )
 
-    kvh, g, chunk, page, npages = 2, 3, 32, 128, 12
-    q = jax.random.normal(rng, (1, kvh, g * chunk, hd))
-    pool = _pool(rng, npages, kvh, page, hd, quantized)
-    for pos0, pages in [(128, [3, 7, 0, 0]), (0, [5, 0]),
-                        (256, [2, 4, 9, 0])]:
+    kvh, g, chunk, hd, pool_kind, window, passes = _CHUNK_CASES[case]
+    page = 128
+    q, pool = _chunk_operands(rng, kvh, g, chunk, hd, pool_kind)
+    vals = pool_values(pool)
+    tol = _CHUNK_TOL.get(pool_kind, dict(rtol=2e-5, atol=2e-5))
+    for pos0, pages in passes:
+        n = len(pages)
+        live = [
+            j for j in range(n)
+            if j * page <= pos0 + chunk - 1
+            and (window is None or (j + 1) * page - 1 > pos0 - window)
+        ]
+        dead = sorted({pages[j] for j in range(n)} - {pages[j] for j in live})
         pages = jnp.asarray(pages, jnp.int32)
-        ref = paged_chunk_attention_reference(q, pool, pages, pos0, chunk)
+        ref = paged_chunk_attention_reference(
+            q, pool, pages, pos0, chunk, window
+        )
+        poisoned = pool
+        if dead and pool_kind in ("f32", "bf16"):
+            poisoned = pool.at[jnp.asarray(dead)].set(jnp.nan)
         out = paged_chunk_attention(
-            q, pool, pages, pos0, chunk, prefer="pallas"
+            q, poisoned, pages, pos0, chunk, prefer="pallas", window=window
         )
         np.testing.assert_allclose(
-            np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5,
-            err_msg=f"pos0={pos0}",
+            np.asarray(out, np.float32), np.asarray(ref, np.float32),
+            err_msg=f"pos0={pos0}", **tol,
         )
+        quantized = isinstance(pool, tuple)
+        heads = chunk_heads_per_step(
+            kvh, _chunk_rows(g * chunk, not quantized), page, vals.shape[3],
+            vals.dtype.itemsize, quantized, hd, q.dtype.itemsize,
+        )
+        books = kernel_dispatch_stats()["paged_chunk"]
+        assert books["heads_per_step"] == heads and books["last"] == 1.0
+        if heads > 1:
+            one = _chunk_impl(
+                q, *_pool_planes(poisoned), pages,
+                jnp.asarray(pos0, jnp.int32), chunk=chunk, window=window,
+                heads=1,
+            )
+            np.testing.assert_array_equal(np.asarray(one), np.asarray(out))
 
 
 @_QUANT
@@ -513,6 +620,105 @@ def test_decode_heads_per_step_is_derived_from_the_operands(case):
         assert decode_step_vmem_bytes(
             bigger[0], page, width, itemsize, scales
         ) > DECODE_STEP_VMEM_BUDGET
+
+
+#: kv_heads, rows a KV head (group x chunk), page, the fused row's
+#: width, the pool's itemsize, scale planes, head_dim -> heads a grid
+#: step of the chunk kernel. A chunk's state is per query ROW, so the
+#: answer differs by model: 5 of GPT-2-XL's 25 heads at 256 rows of
+#: head_dim 64; one at K-EXAONE's 2,048 rows and at Falcon-H1's 1,280.
+_CHUNK_HEADS_PER_STEP = {
+    "gpt2xl": ((25, 256, 128, 128, 2, False, 64), 5),
+    "gpt2xl-tp5-shard": ((5, 256, 128, 128, 2, False, 64), 5),
+    "kexaone": ((8, 2048, 128, 256, 2, False, 128), 1),
+    "falconh1": ((4, 1280, 128, 256, 2, False, 128), 1),
+    "cgpt1b3": ((16, 256, 128, 256, 2, False, 128), 4),
+    "prime-heads-all-fit": ((7, 64, 128, 128, 2, False, 64), 7),
+    "prime-heads-too-many-rows": ((7, 1024, 128, 256, 2, False, 128), 1),
+    "int8-p1024-hd64": ((25, 256, 1024, 128, 1, True, 64), 1),
+    "small-chunk-every-head": ((12, 32, 128, 128, 4, False, 64), 12),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_CHUNK_HEADS_PER_STEP))
+def test_chunk_heads_per_step_is_derived_from_the_operands(case):
+    from adapt_tpu.ops.paged_attention import (
+        DECODE_STEP_VMEM_BUDGET,
+        chunk_heads_per_step,
+        chunk_step_vmem_bytes,
+    )
+
+    (kvh, gc, page, width, itemsize, scales, hd), want = (
+        _CHUNK_HEADS_PER_STEP[case]
+    )
+    args = (gc, page, width, itemsize, scales, hd, 2)
+    heads = chunk_heads_per_step(kvh, *args)
+    assert heads == want and kvh % heads == 0
+    used = chunk_step_vmem_bytes(heads, *args)
+    # Under the decode kernel's budget (half of Mosaic's scoped 16 MB),
+    # unless not even one head fits it, which the kernel then tries
+    # anyway: K-EXAONE's 2,048 rows are 10.1 MB by this sum and compile
+    # (``tests/test_chip_lowering.py``).
+    assert used <= DECODE_STEP_VMEM_BUDGET or heads == 1
+    # The (gc, 1) running max and denominator pad to a lane tile each.
+    assert used >= heads * gc * 2 * 128 * 4
+    bigger = [h for h in range(heads + 1, kvh + 1) if kvh % h == 0]
+    if bigger:
+        assert chunk_step_vmem_bytes(bigger[0], *args) > (
+            DECODE_STEP_VMEM_BUDGET
+        )
+
+
+@pytest.mark.parametrize("window", [None, 1, 20, 128, 130, 300])
+def test_chunk_live_pages_are_the_pages_some_row_attends(window):
+    """``_chunk_live_pages``: the run of page-list entries the chunk
+    kernel's body runs on and its index map names, against the mask
+    itself — a page is live iff some row of the chunk attends some
+    position of it."""
+    from adapt_tpu.ops.paged_attention import _chunk_live_pages
+
+    page = 128
+    for chunk in (32, 256):
+        for pos0 in (0, 96, 128, 256, 384, 512, 640):
+            live_n = -(-(pos0 + chunk) // page)
+            n = 1 << (live_n - 1).bit_length()
+            rows = pos0 + np.arange(chunk)[:, None]
+            cols = np.arange(n * page)[None, :]
+            mask = cols <= rows
+            if window is not None:
+                mask &= cols > rows - window
+            want = np.flatnonzero(mask.reshape(chunk, n, page).any((0, 2)))
+            first, last = _chunk_live_pages(
+                jnp.asarray(pos0, jnp.int32), chunk, page, n, window
+            )
+            assert (int(first), int(last)) == (want[0], want[-1]), (
+                chunk, pos0,
+            )
+            assert len(want) == want[-1] - want[0] + 1  # one run
+
+
+def test_paged_chunk_books_heads_per_step(rng):
+    """``kernel_dispatch_stats()["paged_chunk"]["heads_per_step"]`` is
+    what the VMEM sum gives for the newest resolution's operands, per
+    shard under a head shard; the oracle's path books none."""
+    from adapt_tpu.ops.dispatch import kernel_dispatch_stats
+    from adapt_tpu.ops.paged_attention import (
+        chunk_heads_per_step,
+        paged_chunk_attention,
+    )
+
+    kvh, g, chunk, hd, page = 6, 2, 16, 64, 128
+    q, pool = _chunk_operands(rng, kvh, g, chunk, hd, "bf16", npages=4)
+    pages = jnp.asarray([2, 1], jnp.int32)
+    paged_chunk_attention(q, pool, pages, 128, chunk, prefer="pallas")
+    books = kernel_dispatch_stats()["paged_chunk"]
+    assert books["heads_per_step"] == chunk_heads_per_step(
+        kvh, g * chunk, page, 2 * hd, 2, False, hd, 2
+    ) == 6
+    assert books["last"] == 1.0
+    paged_chunk_attention(q, pool, pages, 128, chunk, prefer="xla")
+    books = kernel_dispatch_stats()["paged_chunk"]
+    assert books["last"] == 0.0 and books["heads_per_step"] == 6.0
 
 
 def test_paged_decode_books_heads_per_step_and_split(rng):
