@@ -48,7 +48,10 @@ from adapt_tpu.ops.paged_attention import (
     paged_verify_attention,
     pool_values,
 )
+from adapt_tpu.models.mhc import HyperConnection, HyperSpec, merge
+from adapt_tpu.models.mla import LatentSelfAttention, LatentSpec
 from adapt_tpu.models.moe import ExpertSpec, MoEDecoderMlp, RoutedExperts
+from adapt_tpu.models.rope import apply_rope
 from adapt_tpu.models.ssm import Mamba2Mixer, SsmSpec, scaled
 from adapt_tpu.ops.quantize import quantize_kv_vectors, unpack_int4
 
@@ -113,6 +116,17 @@ class BlockSpec:
     #: the schedules that carry one serve it (the full forward,
     #: ``prefill``, ``prefill_chunk_paged``, ``decode_step_paged``).
     ssm: SsmSpec | None = None
+    #: Multi-head latent attention (``models/mla``) instead of MHA/GQA:
+    #: low-rank q and kv, a rotated key part every head shares
+    #: (``rope_base`` gives the rotation), and a cache of ONE
+    #: ``latent.row``-wide row a position with no head axis
+    #: (``runtime/paged.alloc_kv_pools``). ``kv_heads`` / ``head_dim``
+    #: / ``qk_norm`` / ``window`` do not apply.
+    latent: LatentSpec | None = None
+    #: The residual as ``streams.streams`` streams of ``dim`` mixed
+    #: around every sub-layer (``models/mhc``); the block's input and
+    #: output are then (b, s, streams, dim).
+    streams: HyperSpec | None = None
     #: Scalar multipliers a family puts on its branches (muP-style):
     #: on the attention's input, on K after its projection, on the
     #: attention's output; on the gate before its activation and on the
@@ -155,15 +169,46 @@ class BlockSpec:
                 "a mixer beside the attention reads the block's normed "
                 "INPUT: post_norm has none"
             )
+        if self.latent is not None:
+            if self.rope_base is None:
+                raise ValueError(
+                    "latent attention rotates its shared key part: "
+                    "rope_base says by what"
+                )
+            for field in ("kv_heads", "head_dim", "window", "ssm"):
+                if getattr(self, field) is not None:
+                    raise ValueError(
+                        f"{field} does not apply to a latent-attention "
+                        "block (its widths are in `latent`)"
+                    )
+            if self.qk_norm:
+                raise ValueError(
+                    "qk_norm does not apply to a latent-attention block "
+                    "(its latents are normed)"
+                )
+        if self.streams is not None and (self.post_norm or self.ssm):
+            raise ValueError(
+                "residual streams mix around a sub-layer that reads its "
+                "normed INPUT, and no mixer beside the attention is "
+                "defined over them"
+            )
 
     @property
     def attn_head_dim(self) -> int:
+        if self.latent is not None:
+            return self.latent.qk_dim
         return self.head_dim or self.dim // self.heads
 
     @property
     def cache_heads(self) -> int:
         """Head count of the K/V cache: kv_heads under GQA."""
         return self.kv_heads or self.heads
+
+    @property
+    def cache_row(self) -> int | None:
+        """Width of the ONE row a position stores in a latent cache
+        (no head axis); None: K and V a KV head."""
+        return None if self.latent is None else self.latent.row
 
 
 def _norm(kind: str, eps: float, dtype):
@@ -172,31 +217,6 @@ def _norm(kind: str, eps: float, dtype):
     if kind == "rmsnorm":
         return nn.RMSNorm(epsilon=eps, dtype=dtype)
     return nn.LayerNorm(epsilon=eps, dtype=dtype)
-
-
-def apply_rope(x: jax.Array, positions: jax.Array,
-               base: float = 10000.0) -> jax.Array:
-    """Rotary position embedding over (b, heads, s, head_dim) with
-    explicit ``positions`` ((s,) shared or (b, s) per row — per-row
-    LOGICAL positions keep ragged rows bitwise-equal to their solo
-    runs). Rotate-half convention; head_dim must be even. Computed in
-    f32 and cast back (rotation is a unitary mix — doing it in bf16
-    would cost precision every cached step)."""
-    hd = x.shape[-1]
-    half = hd // 2
-    freqs = base ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
-    pos = jnp.asarray(positions, jnp.float32)
-    if pos.ndim == 1:
-        angles = pos[None, :, None] * freqs  # (1, s, half)
-    else:
-        angles = pos[:, :, None] * freqs  # (b, s, half)
-    cos = jnp.cos(angles)[:, None, :, :]  # (b|1, 1, s, half)
-    sin = jnp.sin(angles)[:, None, :, :]
-    xf = x.astype(jnp.float32)
-    x1, x2 = xf[..., :half], xf[..., half:]
-    return jnp.concatenate(
-        [x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1
-    ).astype(x.dtype)
 
 
 class CausalSelfAttention(nn.Module):
@@ -840,8 +860,19 @@ class DecoderBlock(nn.Module):
     def setup(self):
         spec = self.spec
         self.ln1 = _norm(spec.norm, spec.norm_eps, self.dtype)
-        self.attn = CausalSelfAttention(spec, dtype=self.dtype)
+        attention = (
+            CausalSelfAttention if spec.latent is None
+            else LatentSelfAttention
+        )
+        self.attn = attention(spec, dtype=self.dtype)
         self.ln2 = _norm(spec.norm, spec.norm_eps, self.dtype)
+        if spec.streams is not None:
+            self.hc_attn = HyperConnection(
+                spec.streams, spec.dim, dtype=self.dtype
+            )
+            self.hc_mlp = HyperConnection(
+                spec.streams, spec.dim, dtype=self.dtype
+            )
         if spec.ssm is not None:
             self.ssm = Mamba2Mixer(spec.ssm, spec.dim, dtype=self.dtype)
         if spec.mlp == "experts":
@@ -878,13 +909,23 @@ class DecoderBlock(nn.Module):
 
     def _attn_in(self, x):
         """The normed input the block's mixers read (the attention's
-        own multiplier goes on in ``_attn``)."""
+        own multiplier goes on in ``_mixers``)."""
         return x if self.spec.post_norm else self.ln1(x)
 
-    def _attn_res(self, x, a, s=None):
+    def _attn_in_streams(self, x):
+        """``_attn_in`` where the residual is streams: ``(u, back)``,
+        the normed mix of the streams the mixers read and what
+        ``_attn_res`` writes their output back through."""
+        u, back = self.hc_attn(x)
+        return self.ln1(u), back
+
+    def _attn_res(self, x, a, s=None, back=None):
         """The residual after the token mixers: the attention's output
-        ``a`` and, beside it, the state-space mixer's ``s``."""
+        ``a`` and, beside it, the state-space mixer's ``s``; ``back``
+        (``_attn_in_streams``): the streams' write-back."""
         a = scaled(a, self.spec.attn_out_mult)
+        if back is not None:
+            return merge(x, a, back)
         if s is not None:
             a = a + scaled(s, self.spec.ssm.out_mult)
         return x + (self.ln1(a) if self.spec.post_norm else a)
@@ -895,10 +936,13 @@ class DecoderBlock(nn.Module):
         normed input ``u``; a block with a state-space mixer also runs
         ``mix(ssm, u)`` -> ``(s, carried')`` on the SAME ``u`` and
         returns ``carried'`` last."""
-        u = self._attn_in(x)
+        if self.spec.streams is None:
+            u, back = self._attn_in(x), None
+        else:
+            u, back = self._attn_in_streams(x)
         a, *cache = attend(scaled(u, self.spec.attn_in_mult))
         if self.spec.ssm is None:
-            return (self._mlp_res(self._attn_res(x, a)), *cache)
+            return (self._mlp_res(self._attn_res(x, a, back=back)), *cache)
         s, carried = mix(self.ssm, u)
         return (self._mlp_res(self._attn_res(x, a, s)), *cache, carried)
 
@@ -911,6 +955,9 @@ class DecoderBlock(nn.Module):
             )
 
     def _mlp_res(self, x):
+        if self.spec.streams is not None:
+            u, mix = self.hc_mlp(x)
+            return merge(x, self._mlp(self.ln2(u)), mix)
         if self.spec.post_norm:
             return x + self.ln2(self._mlp(x))
         return x + self._mlp(self.ln2(x))
@@ -1019,6 +1066,13 @@ class TokenEmbed(nn.Module):
     use_pos: bool = True
     #: On the looked-up rows (a family's embedding multiplier).
     scale: float = 1.0
+    #: Residual streams (``models/mhc``): above 1 a token's row is
+    #: copied into that many, (b, s, streams, dim), in FLOAT32: the
+    #: streams are mixed in float32 around every sub-layer, and kept in
+    #: the served type between blocks they would be rounded twice a
+    #: layer (the drift that flips a router near a tie, PERF.md
+    #: section 6, PR 43); a sub-layer still computes in ``dtype``.
+    streams: int = 1
 
     def setup(self):
         self.tok = nn.Embed(self.vocab, self.dim, dtype=self.dtype)
@@ -1030,12 +1084,20 @@ class TokenEmbed(nn.Module):
                 jnp.float32,
             )
 
+    def _expand(self, out):
+        if self.streams == 1:
+            return out
+        return jnp.broadcast_to(
+            out.astype(jnp.float32)[..., None, :],
+            (*out.shape[:-1], self.streams, self.dim),
+        )
+
     def __call__(self, ids):
         s = ids.shape[1]
         out = scaled(self.tok(ids), self.scale)
         if self.use_pos:
             out = out + self.pos[:s].astype(self.dtype)
-        return out
+        return self._expand(out)
 
     def embed_at(self, ids_t, index):
         """Embed a single token column at traced position ``index``."""
@@ -1043,7 +1105,7 @@ class TokenEmbed(nn.Module):
         if self.use_pos:
             p = lax.dynamic_slice(self.pos, (index, 0), (1, self.dim))
             out = out + p.astype(self.dtype)
-        return out
+        return self._expand(out)
 
     def embed_positions(self, ids, pos_ids):
         """Embed with explicit per-row position ids (ragged batches:
@@ -1052,7 +1114,7 @@ class TokenEmbed(nn.Module):
         out = scaled(self.tok(ids), self.scale)
         if self.use_pos:
             out = out + self.pos[jnp.clip(pos_ids, 0)].astype(self.dtype)
-        return out
+        return self._expand(out)
 
 
 class LMHead(nn.Module):
@@ -1067,6 +1129,9 @@ class LMHead(nn.Module):
     bias: bool = True
     #: On the logits (a family's head multiplier).
     scale: float = 1.0
+    #: Residual streams (``models/mhc``): above 1 the input is (b, s,
+    #: streams, dim) and the streams are summed before the norm.
+    streams: int = 1
 
     def setup(self):
         self.ln = _norm(self.norm, self.norm_eps, self.dtype)
@@ -1075,6 +1140,8 @@ class LMHead(nn.Module):
         )
 
     def __call__(self, x):
+        if self.streams > 1:
+            x = x.astype(jnp.float32).sum(-2)
         out = self.logits(self.ln(x).astype(jnp.float32))
         return out if self.scale == 1.0 else out * self.scale
 
@@ -1170,11 +1237,18 @@ def transformer_lm(
         blocks = list(blocks)
         dim = blocks[0].dim
     last = blocks[-1]
+    streams = {b.streams.streams if b.streams else 1 for b in blocks}
+    if len(streams) > 1:
+        raise ValueError(
+            f"blocks disagree on the residual streams: {sorted(streams)}"
+        )
+    streams = streams.pop()
     g = LayerGraph(name)
     prev = g.add(
         "embed",
         TokenEmbed(vocab, dim, max_len, dtype=dtype,
-                   use_pos=pos == "learned", scale=embed_scale),
+                   use_pos=pos == "learned", scale=embed_scale,
+                   streams=streams),
         INPUT,
     )
     for i, spec in enumerate(blocks):
@@ -1184,7 +1258,7 @@ def transformer_lm(
     g.add(
         "head",
         LMHead(vocab, dtype=dtype, norm=last.norm, norm_eps=last.norm_eps,
-               bias=last.bias, scale=head_scale),
+               bias=last.bias, scale=head_scale, streams=streams),
         prev,
     )
     return TransformerLM(graph=g, depth=len(blocks), max_len=max_len)
@@ -1209,6 +1283,12 @@ def validate_tp(lm: TransformerLM, tp: int) -> None:
         return
     for name in lm.block_names:
         block = lm.graph.node(name).module
+        if block.spec.latent is not None:
+            raise ValueError(
+                f"{name}: a latent-attention block does not split over tp "
+                "(its cache has no head axis to shard: every chip keeps "
+                "the whole row and serves its own requests)"
+            )
         if block.spec.ssm is not None:
             raise ValueError(
                 f"{name}: a state-space mixer does not split over tp (its "
@@ -1330,10 +1410,17 @@ def validate_generate_args(
     decoder: returns ``(lengths, rng, do_sample)`` with every constraint
     checked eagerly (clear ValueErrors instead of opaque trace errors)."""
     b, s0 = prompt.shape
-    if any(lm.graph.node(n).module.spec.ssm for n in lm.block_names):
+    specs = [lm.graph.node(n).module.spec for n in lm.block_names]
+    if any(sp.ssm for sp in specs):
         raise ValueError(
             "generate() decodes over dense cache strips, which carry no "
             "recurrent state: a model with state-space mixers serves "
+            "through ContinuousBatcher"
+        )
+    if any(sp.latent for sp in specs):
+        raise ValueError(
+            "generate() decodes over dense per-head cache strips: a "
+            "latent-attention model keeps one row a position and serves "
             "through ContinuousBatcher"
         )
     if steps < 1:

@@ -876,6 +876,31 @@ class ContinuousBatcher:
             for what, given in unsupported.items():
                 if given is not None:
                     self._pages_only(what)
+        #: LATENT blocks (``BlockSpec.latent``) keep one row a position
+        #: with no head axis: the pager, the table and the tick are
+        #: unchanged (a page is a page), and everything that moves or
+        #: shards PER-HEAD pages refuses the model by name.
+        self._latent_blocks = tuple(
+            i for i, sp in enumerate(specs) if sp.latent is not None
+        )
+        #: Blocks whose residual is streams (``BlockSpec.streams``):
+        #: two mixes a block and step, booked as ``mhc.mixes``.
+        self._stream_blocks = sum(
+            1 for sp in specs if sp.streams is not None
+        )
+        if self._latent_blocks:
+            unsupported = {
+                "a draft model (speculative decoding: verify_chunk_paged "
+                "reads per-head pages)": draft_lm,
+                "a tp mesh": mesh,
+                "a host cache tier": cache_tier,
+                "sequence-parallel prefill": prefill,
+                "elastic recovery (health=)": health,
+                "a quantized KV pool": (kv_cache_dtype != "native") or None,
+            }
+            for what, given in unsupported.items():
+                if given is not None:
+                    self._per_head_pages(what)
         #: Sliding-window models: decode masking lives in the model;
         #: the batcher's job is page RECYCLING behind the window.
         self._window = groups[0].window
@@ -943,7 +968,7 @@ class ContinuousBatcher:
             alloc_kv_pools(
                 self._pagers[gi].num_pages, groups[gi].kv_heads,
                 page_size, groups[gi].head_dim, block.dtype,
-                kv_cache_dtype,
+                kv_cache_dtype, row=groups[gi].row,
             )
             for gi, block in zip(self._group_of, self._blocks)
         ]
@@ -956,8 +981,8 @@ class ContinuousBatcher:
         #: int8 capacity win (values + scale planes vs native) is
         #: directly observable on dashboards. Native batchers read 1.0.
         self._native_cache_bytes = sum(
-            2 * self._pagers[gi].num_pages * page_size
-            * groups[gi].kv_heads * groups[gi].head_dim
+            self._pagers[gi].num_pages * page_size
+            * groups[gi].position_values
             * jnp.dtype(block.dtype).itemsize
             for gi, block in zip(self._group_of, self._blocks)
         )
@@ -2077,6 +2102,7 @@ class ContinuousBatcher:
         fail by name, never scatter garbage into live pages."""
         self._one_cache_group("a handoff of prefilled pages")
         self._pages_only("a handoff of prefilled pages")
+        self._per_head_pages("a handoff of prefilled pages")
         # The device-lost gate tick() runs: a handoff landing between
         # ticks must not device_put shard slices onto a dead device or
         # dispatch the adoption program at a stale mesh epoch (the
@@ -2523,7 +2549,8 @@ class ContinuousBatcher:
                     method="prefill",
                     **({"length": ints[0]} if i in self._ssm_blocks else {}),
                 )
-                kvs.append(fuse_kv(ck, cv))  # the pool's rows
+                # The pool's rows (a latent block's are whole: no V).
+                kvs.append(ck if cv is None else fuse_kv(ck, cv))
                 states.extend(carried)
             h_last = lax.dynamic_index_in_dim(h, ints[0] - 1, 1)
             first, first_lp = self._first_pick(
@@ -4661,6 +4688,18 @@ class ContinuousBatcher:
                 "mixer's state a slot beside their pages) yet"
             )
 
+    def _per_head_pages(self, what: str) -> None:
+        """Refuse ``what`` for a model with latent-attention blocks:
+        it moves, shards or re-encodes pages of K and V a KV head, and
+        a latent pool's row has no head axis and no K|V halves."""
+        if self._latent_blocks:
+            raise ValueError(
+                f"{what} does not run for a model with a latent cache "
+                f"({len(self._latent_blocks)} blocks keep one "
+                f"{self._groups[0].row}-value row a position, no head "
+                "axis) yet"
+            )
+
     @property
     def _shares_pages(self) -> bool:
         """Whether a prompt page may be shared between requests (the
@@ -5187,6 +5226,10 @@ class ContinuousBatcher:
             toks, lps = host[:2]
             if len(host) > 2:
                 self._moe_counts(host[2])
+            if self._stream_blocks:
+                global_metrics().inc(
+                    "mhc.mixes", float(2 * self._stream_blocks * self.chunk)
+                )
             limits = np.full((toks.shape[1],), self.chunk, np.int64)
             if tracer.enabled and fl.t_span:
                 # Dispatch -> results-landed of one compiled decode
@@ -5448,6 +5491,13 @@ class ContinuousBatcher:
                 out["prefix_cache"] = "off: recurrent state"
             ps = self._pager.stats()
             out["pool_pages"] = ps.num_pages
+            #: What ONE position stores in a block of the first cache
+            #: group (K and V of every KV head, or a latent row), in
+            #: values and in bytes of the pool's own representation.
+            out["pool_row_values"] = self._groups[0].position_values
+            out["pool_row_bytes"] = sum(
+                x.nbytes for x in jax.tree.leaves(self._caches[0])
+            ) // (ps.num_pages * self._page)
             out["pages_in_use"] = ps.in_use
             out["pages_free"] = ps.free
             out["pages_cached"] = ps.cached

@@ -741,7 +741,7 @@ class HostKVTier:
 @dataclasses.dataclass(frozen=True)
 class CacheGroup:
     """The blocks of a model that share ONE pool geometry and ONE page
-    table: same ``(window, kv_heads, head_dim)``. A window group
+    table: same ``(window, kv_heads, head_dim, row)``. A window group
     recycles behind its window; a full group keeps the whole request."""
 
     name: str  # "full", "window", or "<kind><n>" where a kind repeats
@@ -749,6 +749,16 @@ class CacheGroup:
     kv_heads: int
     head_dim: int
     blocks: tuple[int, ...]  # indices into the model's block list
+    #: A LATENT group (``BlockSpec.cache_row``): a position stores one
+    #: row this wide, with no head axis and no K|V halves; ``kv_heads``
+    #: and ``head_dim`` then say nothing of the pool. None: K and V a
+    #: KV head.
+    row: int | None = None
+
+    @property
+    def position_values(self) -> int:
+        """Values ONE position stores in a block's pool."""
+        return self.row or 2 * self.kv_heads * self.head_dim
 
 
 def cache_groups(specs) -> list[CacheGroup]:
@@ -758,7 +768,8 @@ def cache_groups(specs) -> list[CacheGroup]:
     keys: dict[tuple, list[int]] = {}
     for i, spec in enumerate(specs):
         keys.setdefault(
-            (spec.window, spec.cache_heads, spec.attn_head_dim), []
+            (spec.window, spec.cache_heads, spec.attn_head_dim,
+             spec.cache_row), []
         ).append(i)
     kinds = ["full" if k[0] is None else "window" for k in keys]
     out = []
@@ -766,7 +777,7 @@ def cache_groups(specs) -> list[CacheGroup]:
         kind = kinds[n]
         if kinds.count(kind) > 1:
             kind += str(kinds[:n].count(kind))
-        out.append(CacheGroup(kind, *key, blocks=tuple(blocks)))
+        out.append(CacheGroup(kind, *key[:3], tuple(blocks), row=key[3]))
     return out
 
 
@@ -828,9 +839,19 @@ def alloc_kv_pools(
     head_dim: int,
     dtype,
     kv_cache_dtype: str = "native",
+    row: int | None = None,
 ):
     """One decoder block's zeroed page pool — THE definition of what a
-    pool is. A position's K and V live side by side on the lanes of ONE
+    pool is. ``row`` (a latent-attention block, ``CacheGroup.row``):
+    ONE plane ``(pool_pages, row, page_size)`` of the block's ``dtype``
+    — ``row`` values are what one position stores (``[c_kv | k_r]``),
+    whole: there are no K|V halves and there is no head axis, a
+    page's positions lie on the minor axis (where a TPU puts them for
+    a row that does not fill whole lane tiles), and every consumer
+    knows the format by the plane's three dimensions
+    (``ops/latent_attention``). Otherwise:
+
+    A position's K and V live side by side on the lanes of ONE
     row: lanes ``[0, w)`` hold K, lanes ``[w, 2w)`` V, ``w`` =
     :func:`kv_value_width`. Native: one ``(pool_pages, kv_heads,
     page_size, 2 * head_dim)`` plane of the block's ``dtype`` — at
@@ -846,6 +867,14 @@ def alloc_kv_pools(
     format off the operand (tuple or not, the last dimension); the
     KV-head axis is dim 1 of every plane, which is what tensor
     parallelism shards."""
+    if row is not None:
+        if kv_cache_dtype != "native":
+            raise NotImplementedError(
+                f"kv_cache_dtype={kv_cache_dtype!r}: a latent pool is not "
+                "quantized (one scale a K or V vector has no meaning for "
+                "a [c_kv | k_r] row)"
+            )
+        return jnp.zeros((pool_pages, row, page_size), dtype)
     width = kv_value_width(head_dim, kv_cache_dtype)
     plane = (pool_pages, kv_heads, page_size)
     if kv_cache_dtype == "native":
@@ -878,8 +907,15 @@ def insert_prefill_pages(pool, pages, kv):
     logical order) of that plane. S pads up
     to n*page positions — pad columns hold zeros that sit beyond the
     prompt (masked until decode overwrites them). One scatter on the
-    page axis; jit specializes per (n, S), both bucket-bounded."""
+    page axis; jit specializes per (n, S), both bucket-bounded. A
+    latent pool ``(pages, row, page)`` takes (1, S, row) rows."""
     n = pages.shape[0]
+    if pool.ndim == 3:
+        from adapt_tpu.ops.latent_attention import rows_to_pages
+
+        page = pool.shape[2]
+        kvp = jnp.pad(kv[0], ((0, n * page - kv.shape[1]), (0, 0)))
+        return pool.at[pages].set(rows_to_pages(kvp, page).astype(pool.dtype))
     _, kvh, page, hd = pool.shape
     s = kv.shape[2]
     kvp = jnp.pad(kv[0], ((0, 0), (0, n * page - s), (0, 0)))

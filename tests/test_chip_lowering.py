@@ -884,3 +884,54 @@ def test_step_chunk_with_recurrent_state_lowers(as_tpu):
     ).lower(lowering_platforms=("tpu",)).as_text()
     srv.close()
     assert text.count("tpu_custom_call") >= 2
+
+
+# -- the latent (MLA) cache: decode kernel and per-token write ----------------
+
+
+def _latent_operands(on=sds):
+    b, heads, row, pps, pages, page = 128, 32, 576, 64, 257, 128
+    return (
+        on((b, heads, row)), on((pages, row, page)), on((b, row)),
+        on((b,), jnp.int32), on((b,), jnp.int32), on((b, pps), jnp.int32),
+        on((b,), jnp.int32),
+    )
+
+
+def _latent_step(q, pool, new, phys, off, table, index):
+    from adapt_tpu.ops.latent_attention import (
+        append_latent_paged,
+        latent_paged_attention,
+    )
+
+    pool = append_latent_paged(pool, new, phys, off)
+    return latent_paged_attention(
+        q, pool, table, index, sm_scale=0.14468, v_width=512
+    ), pool
+
+
+def test_latent_decode_and_write_lower(as_tpu):
+    """Xing4.0's shapes: 32 heads against one 576-value row a
+    position, 8 pages a grid step; the write is a kernel too."""
+    lower_for_tpu(_latent_step, *_latent_operands(), kernels=2)
+
+
+def test_latent_decode_and_write_compile_for_v5e_in_place(
+    as_tpu, one_chip, no_persistent_cache
+):
+    """Mosaic's own compile at the cell's shapes, and no copy of the
+    pool around either kernel: a page's positions lie on the lanes,
+    which is the layout the plane has, and the per-token write is a
+    kernel because a scatter of one lane a slot relaid the whole pool
+    out, there and back (PERF.md section 6, PR 43)."""
+    def on_chip(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    compiled = jax.jit(_latent_step, donate_argnums=(1,)).lower(
+        *_latent_operands(on_chip)
+    ).compile()
+    text = compiled.as_text()
+    assert re.search(r"%_latent_impl[.\d]* = .*tpu_custom_call", text)
+    assert re.search(r"%_latent_write_impl[.\d]* = .*tpu_custom_call", text)
+    assert not re.search(r"bf16\[257,576,128\]\S* copy\(", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
