@@ -17,16 +17,19 @@ Every head of a slot therefore attends the SAME rows: the kernel
 (``_latent_impl``) fetches a page once and runs all heads against it,
 the page read once for scores and values.
 
-The kernel's grid is ``(slots, page steps)``, a step covering
-``pages`` table-mapped pages of one slot (the pool is handed to the
-call ``pages`` times, each operand's index map naming its own page of
-the step): one page is 147 KB in bfloat16 at 576 values a position,
-and a grid step costs 0.15-0.35 us on a v5e before it moves a byte, so
-a step of one page would spend as long starting as fetching. The
-page table and the slots' positions ride as scalar prefetch, as in
-``ops/paged_attention``; an operand whose page lies past the slot's
-last live one names the block it held the step before, so the
-pipeline issues no copy for it. The step's pages meet the queries in
+The kernel's grid is ``(slots,)``, and it walks a slot's LIVE pages
+itself: the pool is handed to the call once and stays where it lives,
+the page table and the slots' positions ride as scalar prefetch (as
+in ``ops/paged_attention``), and a loop inside the kernel copies
+``pages`` table-mapped pages an iteration into one of two VMEM buffers
+while the other's are consumed, ``idx // page + 1`` pages in all: a
+page past the slot's newest position is neither copied nor scored,
+and a slot's last iteration has the next live slot's first pages in
+flight before it waits on its own. (A page axis on the grid takes
+its 8 steps a slot whether they move a byte or not, 0.75 us each on a
+v5e: 46% of the bytes floor in ``xing4_longgen8k`` where the walk
+reads 81%, PERF.md section 6, PR 44.) One page is 147 KB in bfloat16
+at 576 values a position; an iteration's pages meet the queries in
 independent products (bfloat16 operands, float32 accumulation) and
 share ONE online-softmax update.
 
@@ -56,7 +59,7 @@ from adapt_tpu.ops.dispatch import (
 
 _VMEM = pltpu.VMEM
 
-#: What the pages of one grid step, double-buffered, may take of VMEM
+#: What the pages of one iteration, double-buffered, may take of VMEM
 #: (a quarter of Mosaic's 16 MB scope: q, the output, the float32
 #: scores of every page and the accumulator need the rest).
 LATENT_STEP_PAGES_BUDGET = 4 * 2 ** 20
@@ -198,11 +201,12 @@ def latent_chunk_attention(q, pool, pages, pos0, sm_scale, v_width):
 
 def latent_pages_per_step(pages_per_slot: int, page: int, row: int,
                           itemsize: int) -> int:
-    """Pages one grid step of the decode kernel covers: the largest
-    power of two, at most the slot's pages, whose double-buffered
-    blocks fit ``LATENT_STEP_PAGES_BUDGET``: 8 of a bfloat16 pool at
-    128 positions a page and 576 values a position. Derived from the
-    operands, never set."""
+    """Pages one iteration of the decode kernel's walk covers: the
+    largest power of two, at most the slot's pages, whose two buffers
+    fit ``LATENT_STEP_PAGES_BUDGET``: 8 of a bfloat16 pool at 128
+    positions a page and 576 values a position (4 read 15% slower a
+    call on a v5e, 16 the same: PERF.md section 6, PR 44). Derived
+    from the operands, never set."""
     block = 2 * page * row * itemsize
     pages = 1
     while (
@@ -223,35 +227,75 @@ def latent_unsupported(pool) -> str | None:
     return None
 
 
-def _latent_kernel(table_ref, idx_ref, q_ref, *refs, page, steps, pages,
-                   sm_scale, v_width):
-    """``pages`` pages of one slot a grid step, grid (slots, steps).
-    ``q_ref`` (1, h, w); each of the ``pages`` pool operands a (1,
-    w, page) block, the page its index map took from the prefetched
-    table. The body scores every page of the step against all heads
-    (independent products), takes ONE online-softmax update over them
-    and weights the positions' first ``v_width`` values. A step wholly past
-    the slot's newest position (every step of a dead row, whose index
-    is negative) skips the body and fetched nothing."""
-    del table_ref  # consumed by the index maps
-    kv_refs, (o_ref, m_scr, l_scr, acc_scr) = refs[:pages], refs[pages:]
-    slot, j = pl.program_id(0), pl.program_id(1)
-    idx = idx_ref[slot]
+def _latent_kernel(table_ref, idx_ref, q_ref, pool_ref, o_ref, buf, sems,
+                   cur_ref, m_scr, l_scr, acc_scr, *, page, pages, sm_scale,
+                   v_width):
+    """One slot a grid step, grid (slots,); the slot's LIVE pages are
+    walked here, ``pages`` an iteration. ``q_ref`` (1, h, w);
+    ``pool_ref`` the whole pool where it lives; ``buf`` (2, pages, w,
+    page) the two buffers the iterations alternate between, ``sems``
+    one DMA semaphore a buffer, ``cur_ref`` (SMEM) the buffer the next
+    iteration to be consumed lands in. Buffers, semaphores and
+    ``cur_ref`` outlive a grid step: a slot's last iteration has the
+    first pages of the next live slot in flight before it waits on its
+    own, so a slot begins with its copies already under way (the first
+    live slot's are started at grid step 0). An iteration of ``pages``
+    live pages scores them all against all heads (independent
+    products), takes ONE online-softmax update over them and weights
+    the positions' first ``v_width`` values; a slot's last, shorter
+    iteration takes its pages in groups of ``pages / 2``, ... , 1 by
+    the bits of their number, an update a group (a page at a time read
+    2.4x a whole iteration's pace a page on a v5e). A page past the
+    slot's newest position is neither copied nor scored; a dead row
+    (negative index) has none and gets zeros."""
+    slot, slots = pl.program_id(0), pl.num_programs(0)
     heads = q_ref.shape[1]
 
-    @pl.when(j == 0)
-    def _init():
-        _init_softmax_scratch(m_scr, l_scr, acc_scr)
+    def live_pages(s):
+        return jnp.maximum(idx_ref[s], -1) // page + 1
 
-    def _step():
-        q = q_ref[0]
+    def copies(s, t, b, then):
+        # ``then`` (start or wait) each live page's copy of slot s's
+        # iteration t into buffer b. The table is read under the
+        # guard: its row ends where the slot's pages may.
+        live = live_pages(s)
+        for i in range(pages):
+            @pl.when(t * pages + i < live)
+            def _(i=i):
+                then(pltpu.make_async_copy(
+                    pool_ref.at[table_ref[s, t * pages + i]],
+                    buf.at[b, i], sems.at[b],
+                ))
+
+    def start(s, t, b):
+        copies(s, t, b, lambda c: c.start())
+
+    def next_live(s):
+        # the first slot after s that has a page, ``slots`` if none
+        return jax.lax.while_loop(
+            lambda n: (n < slots) & (idx_ref[jnp.minimum(n, slots - 1)] < 0),
+            lambda n: n + 1, s + 1,
+        )
+
+    def start_first_of(s, b):
+        pl.when(s < slots)(lambda: start(s, 0, b))
+
+    @pl.when(slot == 0)
+    def _first():
+        cur_ref[0] = 0
+        start_first_of(next_live(-1), 0)
+
+    def attend(kv_refs, first):
+        # kv_refs: (w, page) pages ``first``, ``first + 1``, ... of the
+        # slot, every one live.
+        q, idx = q_ref[0], idx_ref[slot]
         scores = []
         for i, kv_ref in enumerate(kv_refs):
             s = jax.lax.dot_general(
-                q, kv_ref[0], (((1,), (0,)), ((), ())),
+                q, kv_ref[...], (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             ) * sm_scale  # (h, w) x (w, page) -> (h, page)
-            cols = (j * pages + i) * page + jax.lax.broadcasted_iota(
+            cols = (first + i) * page + jax.lax.broadcasted_iota(
                 jnp.int32, (heads, page), 1
             )
             scores.append(jnp.where(cols <= idx, s, _NEG_INF))
@@ -264,20 +308,46 @@ def _latent_kernel(table_ref, idx_ref, q_ref, *refs, page, steps, pages,
         for s, kv_ref in zip(scores, kv_refs):
             p = jnp.exp(s - m_new)
             l_new = l_new + jnp.sum(p, axis=-1, keepdims=True)
-            v = kv_ref[0][:v_width]  # (v_width, page)
+            v = kv_ref[:v_width]  # (v_width, page)
             acc = acc + jax.lax.dot_general(
                 p.astype(v.dtype), v, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
         m_scr[...], l_scr[...], acc_scr[...] = m_new, l_new, acc
 
-    pl.when(j * pages * page <= idx)(_step)
+    _init_softmax_scratch(m_scr, l_scr, acc_scr)
+    groups = [pages >> k for k in range(pages.bit_length())]
+    live = live_pages(slot)
+    iters = (live + pages - 1) // pages
+    base = cur_ref[0]
 
-    @pl.when(j == steps - 1)
-    def _emit():
-        o_ref[0] = (
-            acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
-        ).astype(o_ref.dtype)
+    def iteration(t, _):
+        b = (base + t) % 2
+        # The copies after this iteration's go out before it waits on
+        # its own: the slot's next, or the next live slot's first.
+        pl.when(t + 1 < iters)(lambda: start(slot, t + 1, 1 - b))
+        pl.when(t + 1 == iters)(
+            lambda: start_first_of(next_live(slot), 1 - b)
+        )
+        copies(slot, t, b, lambda c: c.wait())
+        here = jnp.minimum(live - t * pages, pages)
+        # ``here`` live pages, taken in groups of pages, pages / 2, ...,
+        # 1 by its bits: a whole iteration is one group, a slot's last
+        # one at most log2(pages), and no dead page is in any.
+        for g in groups:
+            @pl.when(here & g != 0)
+            def _(g=g):
+                first = 0 if 2 * g >= pages else here - here % (2 * g)
+                attend(
+                    [buf.at[b, first + i] for i in range(g)],
+                    t * pages + first,
+                )
+
+    jax.lax.fori_loop(0, iters, iteration, None)
+    cur_ref[0] = (base + iters) % 2
+    o_ref[0] = (
+        acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
+    ).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -286,67 +356,50 @@ def _latent_kernel(table_ref, idx_ref, q_ref, *refs, page, steps, pages,
 def _latent_impl(q, pool, page_table, index, sm_scale, v_width, pages):
     b, heads, row = q.shape
     page = pool.shape[2]
-    pages_per_slot = page_table.shape[1]
-    steps = -(-pages_per_slot // pages)
+    assert pages & (pages - 1) == 0, pages  # the walk halves its groups
     prefetch = [
         jnp.asarray(page_table, jnp.int32),
         jnp.broadcast_to(jnp.asarray(index, jnp.int32).reshape(-1), (b,)),
     ]
 
-    def row_map(s, j, *_):
+    def row_map(s, *_):
         return (s, 0, 0)
 
-    def kv_map(i, s, j, table_ref, idx_ref):
-        # Operand i holds page j * pages + i of the slot. Past the
-        # slot's last live page it names the page it held the step
-        # before (the last live one congruent to i), so nothing is
-        # copied for it; a slot with no live page for this operand
-        # (or a dead row) names its nearest table entry.
-        last = jnp.minimum(
-            jnp.maximum(idx_ref[s], 0) // page, pages_per_slot - 1
-        )
-        mine = jnp.where(last >= i, last - (last - i) % pages, last)
-        return (table_ref[s, jnp.minimum(j * pages + i, mine)], 0, 0)
-
-    # The pool stays in HBM and the kernel streams it from there
-    # (``_paged_impl``); the interpreter knows no memory spaces.
-    if not pallas_interpret():
-        pool = pltpu.with_memory_space_constraint(
-            pool, memory_space=pltpu.HBM
-        )
     kernel = functools.partial(
-        _latent_kernel, page=page, steps=steps, pages=pages,
-        sm_scale=sm_scale, v_width=v_width,
+        _latent_kernel, page=page, pages=pages, sm_scale=sm_scale,
+        v_width=v_width,
     )
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(prefetch),
-            grid=(b, steps),
+            grid=(b,),
             in_specs=[
-                pl.BlockSpec((1, heads, row), row_map, memory_space=_VMEM)
-            ] + [
-                pl.BlockSpec(
-                    (1, row, page), functools.partial(kv_map, i),
-                    memory_space=_VMEM,
-                )
-                for i in range(pages)
+                pl.BlockSpec((1, heads, row), row_map, memory_space=_VMEM),
+                # The pool stays where it lives; the kernel copies the
+                # pages it reads out of it.
+                pl.BlockSpec(memory_space=pl.ANY),
             ],
             out_specs=pl.BlockSpec(
                 (1, heads, v_width), row_map, memory_space=_VMEM
             ),
             scratch_shapes=[
+                pltpu.VMEM((2, pages, row, page), pool.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SMEM((1,), jnp.int32),
                 pltpu.VMEM((heads, 1), jnp.float32),
                 pltpu.VMEM((heads, 1), jnp.float32),
                 pltpu.VMEM((heads, v_width), jnp.float32),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, heads, v_width), q.dtype),
+        # The buffers carry one slot's look-ahead into the next: the
+        # slots run in order (a v5e has one core to run them on).
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")
+            dimension_semantics=("arbitrary",)
         ),
         interpret=pallas_interpret(),
-    )(*prefetch, q, *([pool] * pages))
+    )(*prefetch, q, pool)
 
 
 def latent_paged_attention(q, pool, page_table, index, *, sm_scale,
@@ -361,8 +414,9 @@ def latent_paged_attention(q, pool, page_table, index, *, sm_scale,
     ``prefer`` as ``ops.paged_attention.paged_attention``: None = the
     kernel on a real TPU, the gather oracle elsewhere; ``"pallas"`` /
     ``"xla"`` force. The books (``kernel_dispatch_stats()
-    ["latent_decode"]``) say which path a program was built on and how
-    many pages a grid step covers."""
+    ["latent_decode"]``) say which path a program was built on, how
+    many pages an iteration of the kernel's walk covers and the grid
+    steps a call takes (the slots)."""
     if resolve_prefer(
         "latent_decode", prefer, latent_unsupported(pool), on_tpu()
     ):
@@ -370,7 +424,9 @@ def latent_paged_attention(q, pool, page_table, index, *, sm_scale,
             page_table.shape[1], pool.shape[2], pool.shape[1],
             pool.dtype.itemsize,
         )
-        record_kernel_choice("latent_decode", pages_per_step=pages)
+        record_kernel_choice(
+            "latent_decode", pages_per_step=pages, grid_steps=q.shape[0]
+        )
         return _latent_impl(
             q, pool, jnp.asarray(page_table, jnp.int32),
             jnp.asarray(index, jnp.int32), sm_scale=float(sm_scale),

@@ -912,7 +912,8 @@ def _latent_step(q, pool, new, phys, off, table, index):
 
 def test_latent_decode_and_write_lower(as_tpu):
     """Xing4.0's shapes: 32 heads against one 576-value row a
-    position, 8 pages a grid step; the write is a kernel too."""
+    position, a slot a grid step and 8 pages an iteration of its walk;
+    the write is a kernel too."""
     lower_for_tpu(_latent_step, *_latent_operands(), kernels=2)
 
 
@@ -923,7 +924,9 @@ def test_latent_decode_and_write_compile_for_v5e_in_place(
     pool around either kernel: a page's positions lie on the lanes,
     which is the layout the plane has, and the per-token write is a
     kernel because a scatter of one lane a slot relaid the whole pool
-    out, there and back (PERF.md section 6, PR 43)."""
+    out, there and back (PERF.md section 6, PR 43). The decode kernel
+    takes the pool ONCE and copies the pages it reads out of it itself
+    (PR 44: it was handed the pool once a page of a grid step)."""
     def on_chip(shape, dt=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
@@ -931,7 +934,8 @@ def test_latent_decode_and_write_compile_for_v5e_in_place(
         *_latent_operands(on_chip)
     ).compile()
     text = compiled.as_text()
-    assert re.search(r"%_latent_impl[.\d]* = .*tpu_custom_call", text)
+    call = re.search(r"%_latent_impl[.\d]* = .*tpu_custom_call.*", text)
+    assert call and call.group(0).count("bf16[257,576,128]") == 1
     assert re.search(r"%_latent_write_impl[.\d]* = .*tpu_custom_call", text)
     assert not re.search(r"bf16\[257,576,128\]\S* copy\(", text)
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20

@@ -127,7 +127,8 @@ def test_the_decode_kernel_reads_ragged_and_dead_rows_like_the_oracle():
     want = latent_attention_reference(q, pool, table, index, **args)
     np.testing.assert_allclose(got[:3], want[:3], atol=1e-5)
     assert not np.asarray(got[3]).any()  # a dead row reads nothing
-    # 4 pages a slot, two a step where the budget holds two
+    # the pages an iteration of the walk covers: what the budget holds,
+    # at most a slot's own
     assert latent_pages_per_step(64, 128, 576, 2) == 8
     assert latent_pages_per_step(4, 128, 576, 2) == 4
 
@@ -149,11 +150,13 @@ def _ragged(rng, slots, pages_per_slot, index):
 def test_the_decode_kernel_carries_its_softmax_across_grid_steps(
     pages_per_slot, pages,
 ):
-    """The path a long context takes and a short one does not: the
-    online-softmax carry past step 0, the skip of steps wholly past a
-    slot's newest position, and the page an operand names past the
-    last live one. Indices that end in step 0, on a step's first and
-    last position, mid-step and in the last step, beside a dead row."""
+    """The path a long context takes and a short one does not (a
+    "step" is an iteration of the kernel's walk since PR 44, ``pages``
+    pages each): the online-softmax carry past iteration 0, the walk
+    ending where the slot's pages end, and the last, shorter iteration
+    taken in halving groups of its live pages. Indices that end in iteration 0, on an
+    iteration's first and last position, mid-way and in the last one,
+    beside a dead row."""
     from adapt_tpu.ops.latent_attention import _latent_impl
 
     rng = np.random.default_rng(pages_per_slot * 8 + pages)
@@ -183,7 +186,7 @@ def test_the_decode_kernel_carries_its_softmax_across_grid_steps(
 
 def test_the_decode_kernel_at_the_cells_row_takes_two_steps_of_eight_pages():
     """576 values a position in bfloat16, 16 pages a slot: the entry
-    point itself picks 8 pages a step, so the second step runs."""
+    point itself picks 8 pages an iteration, so the second one runs."""
     rng = np.random.default_rng(5)
     index = [100, 1023, 1024, 1500, 2047, -3]
     table = _ragged(rng, len(index), 16, index)
@@ -201,6 +204,79 @@ def test_the_decode_kernel_at_the_cells_row_takes_two_steps_of_eight_pages():
         atol=2e-2,
     )
     assert not np.asarray(got[-1], np.float32).any()
+
+
+#: (the slots' newest positions, pages a slot, pages an iteration): what
+#: the walk over LIVE pages has to get right beside the ragged cases
+#: above, 4 pages = 512 positions an iteration where not said.
+WALKS = {
+    "dead-row-first": ([-1, 700, 3], 8, 4),
+    "dead-row-last": ([700, 3, -1], 8, 4),
+    "dead-rows-between-live": ([900, -1, -7, 130, -1, 1023], 8, 4),
+    "dead-rows-only": ([-1, -4, -1], 8, 4),
+    "one-position": ([0, 0, 600], 8, 4),
+    "a-page-more-than-an-iteration": ([4 * PAGE, 5 * PAGE - 1, 640], 8, 4),
+    "a-page-fewer-than-an-iteration": ([3 * PAGE - 1, 2 * PAGE, 383], 8, 4),
+    "whole-iterations-to-the-tables-end": ([1023, 511, 512], 8, 4),
+    "one-slot": ([777], 8, 4),
+    "one-slot-one-page": ([5], 8, 2),
+    "one-dead-slot": ([-1], 8, 4),
+    "a-table-that-ends-mid-iteration": ([6 * PAGE - 1, 5 * PAGE, 0], 6, 4),
+}
+
+
+@pytest.mark.parametrize("walk", WALKS, ids=list(WALKS))
+def test_the_walk_reads_a_slots_live_pages_and_nothing_else(walk):
+    """Every page that is NOT live for its slot (the trash page, the
+    pool's unowned pages, the pages other slots own past their newest
+    position: none here) holds NaN: fetched-and-masked, a dead page
+    still reaches the value product as 0 x NaN, so this shows a read
+    that a loud finite number cannot. The oracle gathers whole windows
+    and reads a clean pool. The look-ahead across slots (the first
+    pages of the next LIVE slot are in flight before a slot's last are
+    consumed) is what the dead rows first, last and between try."""
+    from adapt_tpu.ops.latent_attention import _latent_impl
+
+    index, pages_per_slot, pages = WALKS[walk]
+    rng = np.random.default_rng(zlib.crc32(walk.encode()))
+    table = _ragged(rng, len(index), pages_per_slot, index)
+    clean = rng.normal(size=(len(index) * pages_per_slot + 1, 40, PAGE))
+    owned = np.zeros(len(clean), bool)
+    for row, idx in zip(np.asarray(table), index):
+        owned[row[:max(idx, -1) // PAGE + 1]] = True
+    assert not owned[0]
+    pool = jnp.asarray(
+        np.where(owned[:, None, None], clean, np.nan), jnp.float32
+    )
+    q = jnp.asarray(rng.normal(size=(len(index), 4, 40)), jnp.float32)
+    idx = jnp.asarray(index, jnp.int32)
+    got = np.asarray(_latent_impl(q, pool, table, idx, 0.3, 32, pages))
+    want = np.asarray(latent_attention_reference(
+        q, jnp.asarray(clean, jnp.float32), table, idx, 0.3, 32
+    ))
+    live = np.asarray(index) >= 0
+    np.testing.assert_allclose(got[live], want[live], atol=2e-5)
+    assert not got[~live].any()  # zeros, and no NaN
+
+
+def test_the_books_say_which_walk_a_program_was_built_on():
+    """``grid_steps`` a call is the slots (it was slots x page steps
+    while the page axis was on the grid), beside the pages an
+    iteration covers."""
+    rng = np.random.default_rng(3)
+    index = [300, -1, 1100, 90, 2047]
+    table = _ragged(rng, len(index), 16, index)
+    pool = jnp.asarray(
+        rng.normal(size=(len(index) * 16 + 1, 576, PAGE)), jnp.bfloat16
+    )
+    q = jnp.asarray(rng.normal(size=(len(index), 4, 576)), jnp.bfloat16)
+    latent_paged_attention(
+        q, pool, table, jnp.asarray(index, jnp.int32), sm_scale=0.1,
+        v_width=512, prefer="pallas",
+    )
+    books = kernel_dispatch_stats()["latent_decode"]
+    assert books["grid_steps"] == len(index)
+    assert books["pages_per_step"] == 8
 
 
 def test_the_write_kernel_lays_a_row_over_one_position():
