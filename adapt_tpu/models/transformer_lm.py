@@ -48,6 +48,7 @@ from adapt_tpu.ops.paged_attention import (
     paged_verify_attention,
     pool_values,
 )
+from adapt_tpu.models.kda import KdaMixer, KdaSpec
 from adapt_tpu.models.mhc import HyperConnection, HyperSpec, merge
 from adapt_tpu.models.mla import LatentSelfAttention, LatentSpec
 from adapt_tpu.models.moe import ExpertSpec, MoEDecoderMlp, RoutedExperts
@@ -123,6 +124,15 @@ class BlockSpec:
     #: (``runtime/paged.alloc_kv_pools``). ``kv_heads`` / ``head_dim``
     #: / ``qk_norm`` / ``window`` do not apply.
     latent: LatentSpec | None = None
+    #: A linear-attention mixer (``models/kda``: the gated delta rule)
+    #: IN PLACE OF the attention: the block holds a recurrent state a
+    #: request and NO pages (``runtime/paged.cache_groups`` puts it in
+    #: no group), and only the schedules that carry a state serve it.
+    #: ``heads`` repeats ``linear.heads``; no attention field applies.
+    linear: KdaSpec | None = None
+    #: An output gate on the attention, ``attn * sigmoid(W_gate u)``
+    #: element by element, before the out-projection.
+    attn_gate: bool = False
     #: The residual as ``streams.streams`` streams of ``dim`` mixed
     #: around every sub-layer (``models/mhc``); the block's input and
     #: output are then (b, s, streams, dim).
@@ -138,7 +148,10 @@ class BlockSpec:
     mlp_out_mult: float = 1.0
 
     def __post_init__(self):
-        if self.head_dim is None and self.dim % self.heads:
+        if (
+            self.head_dim is None and self.linear is None
+            and self.dim % self.heads
+        ):
             raise ValueError(
                 f"model dim {self.dim} not divisible by {self.heads} heads"
             )
@@ -186,11 +199,38 @@ class BlockSpec:
                     "qk_norm does not apply to a latent-attention block "
                     "(its latents are normed)"
                 )
-        if self.streams is not None and (self.post_norm or self.ssm):
+        if self.streams is not None and (
+            self.post_norm or self.ssm or self.linear
+        ):
             raise ValueError(
                 "residual streams mix around a sub-layer that reads its "
-                "normed INPUT, and no mixer beside the attention is "
-                "defined over them"
+                "normed INPUT, and no recurrent mixer is defined over them"
+            )
+        if self.linear is not None:
+            for field in (
+                "kv_heads", "head_dim", "window", "ssm", "latent",
+                "rope_base",
+            ):
+                if getattr(self, field) is not None:
+                    raise ValueError(
+                        f"{field} does not apply to a linear-attention "
+                        "block (it has no attention; its widths are in "
+                        "`linear`)"
+                    )
+            if self.qk_norm or self.attn_gate or self.post_norm:
+                raise ValueError(
+                    "qk_norm, attn_gate and post_norm do not apply to a "
+                    "linear-attention block (the mixer normalises q and "
+                    "k, gates its own output and reads the normed INPUT)"
+                )
+            if self.heads != self.linear.heads:
+                raise ValueError(
+                    f"heads {self.heads} != linear.heads "
+                    f"{self.linear.heads}"
+                )
+        if self.attn_gate and self.latent is not None:
+            raise ValueError(
+                "attn_gate is not defined for a latent-attention block"
             )
 
     @property
@@ -203,6 +243,13 @@ class BlockSpec:
     def cache_heads(self) -> int:
         """Head count of the K/V cache: kv_heads under GQA."""
         return self.kv_heads or self.heads
+
+    @property
+    def state_spec(self):
+        """The spec of the recurrent ``(state, tail)`` a request owns
+        of this block (``state_shapes(rows, dtype)``), None where it
+        owns none: a mixer beside the attention, or one in its place."""
+        return self.ssm or self.linear
 
     @property
     def cache_row(self) -> int | None:
@@ -267,6 +314,11 @@ class CausalSelfAttention(nn.Module):
         if spec.qk_norm:
             self.q_norm = nn.RMSNorm(epsilon=spec.norm_eps, dtype=self.dtype)
             self.k_norm = nn.RMSNorm(epsilon=spec.norm_eps, dtype=self.dtype)
+        if spec.attn_gate:
+            self.gate = nn.Dense(
+                self.heads * head_dim, dtype=self.dtype, name="gate",
+                use_bias=False,
+            )
         self.out = nn.Dense(
             self.dim, dtype=self.dtype, name="out", use_bias=spec.bias
         )
@@ -327,6 +379,17 @@ class CausalSelfAttention(nn.Module):
             o.shape[0], s, self.heads * self.head_dim
         )
 
+    def _finish(self, o, x):
+        """The tail every schedule shares: (b, h, s, hd) attention
+        output of the block input ``x`` (b, s, d) -> merged heads,
+        gated where the spec says (the sigmoid in float32), projected
+        out."""
+        o = self._merge_heads(o, x.shape[1])
+        if self.spec.attn_gate:
+            gate = jax.nn.sigmoid(self.gate(x).astype(jnp.float32))
+            o = (o.astype(jnp.float32) * gate).astype(o.dtype)
+        return self.out(o)
+
     def _repeat_kv(self, t):
         """Expand (b, kv_h, s, hd) -> (b, h, s, hd) for the full-sequence
         flash path: repeat is adjacent-block so query head i lines up
@@ -365,7 +428,7 @@ class CausalSelfAttention(nn.Module):
             q, self._repeat_kv(k), self._repeat_kv(v), causal=True,
             window=self.window,
         )
-        return self.out(self._merge_heads(o, s))
+        return self._finish(o, x)
 
     def _window_from(self, index, b, valid_from):
         """Effective ``valid_from`` for cached decode under a sliding
@@ -463,7 +526,7 @@ class CausalSelfAttention(nn.Module):
             causal=True, valid_from=valid_from, window=self.window,
         )
         pad = ((0, 0), (0, 0), (0, max_len - s), (0, 0))
-        out = self.out(self._merge_heads(o, s))
+        out = self._finish(o, x)
         if quantize_cache:
             dt = "int4" if quantize_cache == "int4" else "int8"
             kv_, ks = self._quantize_kv(k, dt)
@@ -524,7 +587,7 @@ class CausalSelfAttention(nn.Module):
             split=split,
         ).astype(x_t.dtype)
         o = self._ungroup_o(o, 1)  # (b, h, 1, hd)
-        return self.out(self._merge_heads(o, 1)), cache_k, cache_v
+        return self._finish(o, x_t), cache_k, cache_v
 
 
     def decode_step_paged(
@@ -580,7 +643,7 @@ class CausalSelfAttention(nn.Module):
             split=split, head_shard=head_shard,
         ).astype(x_t.dtype)
         o = self._ungroup_o(o, 1)
-        return self.out(self._merge_heads(o, 1)), pool
+        return self._finish(o, x_t), pool
 
     def prefill_chunk_paged(
         self, x, pool, pages, pos0, attn_impl=None, head_shard=None,
@@ -626,7 +689,7 @@ class CausalSelfAttention(nn.Module):
             window=self.window, head_shard=head_shard,
         ).astype(x.dtype)
         o = self._ungroup_o(o, c)  # (1, h, C, hd)
-        return self.out(self._merge_heads(o, c)), pool
+        return self._finish(o, x), pool
 
     def prefill_sp(self, x, gather, quantize_cache=False, constrain=None):
         """SEQUENCE-PARALLEL prefill body: the whole span's attention
@@ -714,7 +777,7 @@ class CausalSelfAttention(nn.Module):
             "bhqk,bhkd->bhqd", p, vv_.astype(jnp.float32)
         ).astype(q.dtype))
         o = self._ungroup_o(o, s)  # (1, h, S, hd)
-        return self.out(self._merge_heads(o, s)), ck, cv
+        return self._finish(o, x), ck, cv
 
     def verify_chunk(self, x, cache_k, cache_v, index, tree_tail=0):
         """Append a CHUNK of ``K`` tokens at positions
@@ -764,7 +827,7 @@ class CausalSelfAttention(nn.Module):
             tree_tail=tree_tail,
         ).astype(x.dtype)
         o = self._ungroup_o(o, kc)  # (b, h, K, hd)
-        return self.out(self._merge_heads(o, kc)), cache_k, cache_v
+        return self._finish(o, x), cache_k, cache_v
 
     def verify_chunk_paged(
         self, x, pool, page_table, index, attn_impl=None,
@@ -812,7 +875,7 @@ class CausalSelfAttention(nn.Module):
             head_shard=head_shard,
         ).astype(x.dtype)
         o = self._ungroup_o(o, kc)
-        return self.out(self._merge_heads(o, kc)), pool
+        return self._finish(o, x), pool
 
 
 class DecoderBlock(nn.Module):
@@ -860,11 +923,16 @@ class DecoderBlock(nn.Module):
     def setup(self):
         spec = self.spec
         self.ln1 = _norm(spec.norm, spec.norm_eps, self.dtype)
-        attention = (
-            CausalSelfAttention if spec.latent is None
-            else LatentSelfAttention
-        )
-        self.attn = attention(spec, dtype=self.dtype)
+        if spec.linear is not None:
+            # In PLACE of attention: every schedule goes through
+            # ``_mixers``, which never reaches ``self.attn`` here.
+            self.mixer = KdaMixer(spec.linear, spec.dim, dtype=self.dtype)
+        else:
+            attention = (
+                CausalSelfAttention if spec.latent is None
+                else LatentSelfAttention
+            )
+            self.attn = attention(spec, dtype=self.dtype)
         self.ln2 = _norm(spec.norm, spec.norm_eps, self.dtype)
         if spec.streams is not None:
             self.hc_attn = HyperConnection(
@@ -930,12 +998,18 @@ class DecoderBlock(nn.Module):
             a = a + scaled(s, self.spec.ssm.out_mult)
         return x + (self.ln1(a) if self.spec.post_norm else a)
 
-    def _mixers(self, x, attend, mix=None):
+    def _mixers(self, x, attend, mix=None, caches=1):
         """The one shape every schedule of a block has: ``attend(u)``
         -> ``(a, *cache)`` is the schedule's attention call on the
         normed input ``u``; a block with a state-space mixer also runs
         ``mix(ssm, u)`` -> ``(s, carried')`` on the SAME ``u`` and
-        returns ``carried'`` last."""
+        returns ``carried'`` last. A linear-attention block runs
+        ``mix`` INSTEAD of ``attend`` and returns None in each of the
+        ``caches`` places the schedule's attention would have filled
+        (it has no pages), then ``carried'``."""
+        if self.spec.linear is not None:
+            s, carried = mix(self.mixer, self._attn_in(x))
+            return (self._mlp_res(x + s), *(None,) * caches, carried)
         if self.spec.streams is None:
             u, back = self._attn_in(x), None
         else:
@@ -947,11 +1021,11 @@ class DecoderBlock(nn.Module):
         return (self._mlp_res(self._attn_res(x, a, s)), *cache, carried)
 
     def _no_state(self, schedule: str):
-        if self.spec.ssm is not None:
+        if self.spec.state_spec is not None:
             raise NotImplementedError(
                 f"{schedule} carries no recurrent state: a block with a "
-                "state-space mixer serves through prefill, "
-                "prefill_chunk_paged and decode_step_paged"
+                "state-space or linear-attention mixer serves through "
+                "prefill, prefill_chunk_paged and decode_step_paged"
             )
 
     def _mlp_res(self, x):
@@ -973,12 +1047,13 @@ class DecoderBlock(nn.Module):
         prompt's real length inside ``x``'s bucket; the recurrent
         ``(state, tail)`` after its last real position comes back
         last."""
-        if self.spec.ssm is not None and valid_from is not None:
+        if valid_from is not None:
             self._no_state("a left-padded (ragged) prefill")
         return self._mixers(
             x,
             lambda u: self.attn.prefill(u, max_len, valid_from, quantize_cache),
             lambda ssm, u: ssm.scan(u, None, length),
+            caches=2,
         )
 
     def decode_step(
@@ -1295,6 +1370,12 @@ def validate_tp(lm: TransformerLM, tp: int) -> None:
                 "recurrent state would shard by head beside the KV heads; "
                 "no rule places it)"
             )
+        if block.spec.linear is not None:
+            raise ValueError(
+                f"{name}: a linear-attention mixer does not split over tp "
+                "(its recurrent state would shard by head; no rule places "
+                "it)"
+            )
         if block.spec.mlp == "experts":
             raise ValueError(
                 f"{name}: routed experts do not split over tp (a chip "
@@ -1411,11 +1492,11 @@ def validate_generate_args(
     checked eagerly (clear ValueErrors instead of opaque trace errors)."""
     b, s0 = prompt.shape
     specs = [lm.graph.node(n).module.spec for n in lm.block_names]
-    if any(sp.ssm for sp in specs):
+    if any(sp.state_spec for sp in specs):
         raise ValueError(
             "generate() decodes over dense cache strips, which carry no "
-            "recurrent state: a model with state-space mixers serves "
-            "through ContinuousBatcher"
+            "recurrent state: a model with state-space or linear-attention "
+            "mixers serves through ContinuousBatcher"
         )
     if any(sp.latent for sp in specs):
         raise ValueError(

@@ -820,6 +820,12 @@ class ContinuousBatcher:
         #: and not the sequence.
         specs = [b.spec for b in self._blocks]
         groups = cache_groups(specs)
+        if not groups:
+            raise ValueError(
+                "no block of this model keeps pages: the batcher's "
+                "positions, admission and preemption are the pager's, so "
+                "it serves a model with at least one attention block"
+            )
         # The group that reserves whole: the full-attention one if any.
         groups.sort(key=lambda g: g.window is not None)
         self._groups = groups
@@ -845,21 +851,34 @@ class ContinuousBatcher:
         self._moe_blocks = tuple(
             i for i, sp in enumerate(specs) if sp.mlp == "experts"
         )
-        #: RECURRENT STATE beside the pages: a block with a state-space
-        #: mixer (``BlockSpec.ssm``) keeps, per SLOT, a ``(state, tail)``
-        #: pair (``models/ssm``). Not paged: every request holds exactly
+        #: RECURRENT STATE beside (or in place of) the pages: a block with
+        #: a state-space mixer (``BlockSpec.ssm``, ``models/ssm``) or a
+        #: linear-attention one (``BlockSpec.linear``, ``models/kda``:
+        #: such a block keeps NO pages) keeps, per SLOT, a ``(state,
+        #: tail)`` pair. Not paged: every request holds exactly
         #: one, overwritten in every step and written WHOLE at admission
         #: (so whatever a dead row's steps left in a retired slot never
         #: reaches its next tenant). Device-resident and donated through
         #: every program that advances it, like the pools.
-        self._ssm_blocks = tuple(
-            i for i, sp in enumerate(specs) if sp.ssm is not None
+        self._state_blocks = tuple(
+            i for i, sp in enumerate(specs) if sp.state_spec is not None
+        )
+        #: Of those, the linear-attention ones (``kda.steps``), and the
+        #: counter families a state write is booked under.
+        self._linear_blocks = sum(
+            1 for sp in specs if sp.linear is not None
+        )
+        self._state_families = tuple(
+            family for family, has in (
+                ("ssm", any(sp.ssm for sp in specs)),
+                ("kda", self._linear_blocks),
+            ) if has
         )
         self._states = tuple(
-            zero_state(specs[i].ssm, slots, self._blocks[i].dtype)
-            for i in self._ssm_blocks
+            zero_state(specs[i].state_spec, slots, self._blocks[i].dtype)
+            for i in self._state_blocks
         ) or None
-        if self._ssm_blocks:
+        if self._state_blocks:
             unsupported = {
                 "a draft model (speculative decoding: a rejected token "
                 "cannot be un-stepped)": draft_lm,
@@ -964,12 +983,14 @@ class ContinuousBatcher:
         self._tier_drop_seen = 0
         # Pools hold KV heads: fewer than query heads under GQA (the
         # whole point — a page costs kv_heads/heads the HBM).
+        # A block without pages (linear attention) gets no pool: None
+        # in its place, an empty subtree to every program and donation.
         self._caches = [
             alloc_kv_pools(
                 self._pagers[gi].num_pages, groups[gi].kv_heads,
                 page_size, groups[gi].head_dim, block.dtype,
                 kv_cache_dtype, row=groups[gi].row,
-            )
+            ) if block.spec.linear is None else None
             for gi, block in zip(self._group_of, self._blocks)
         ]
         if mesh is not None:
@@ -985,6 +1006,7 @@ class ContinuousBatcher:
             * groups[gi].position_values
             * jnp.dtype(block.dtype).itemsize
             for gi, block in zip(self._group_of, self._blocks)
+            if block.spec.linear is None
         )
         #: Idle-row cache position: a negative sentinel that stays
         #: negative across a whole tick's position advance (chunk
@@ -1733,11 +1755,11 @@ class ContinuousBatcher:
     def _carried(self, states, block: int) -> dict:
         """``decode_step_paged`` / ``prefill_chunk_paged``'s keyword
         for block ``block``: its ``(state, tail)`` among a program's
-        ``states`` (one a block with a state-space mixer, in block
-        order), nothing for a block without one."""
-        if block not in self._ssm_blocks:
+        ``states`` (one a block with recurrent state, in block order),
+        nothing for a block without one."""
+        if block not in self._state_blocks:
             return {}
-        return {"carried": states[self._ssm_blocks.index(block)]}
+        return {"carried": states[self._state_blocks.index(block)]}
 
     def _table_of(self, table, block: int):
         """Block ``block``'s page table (or page list) among a
@@ -2547,7 +2569,7 @@ class ContinuousBatcher:
                     variables[name], h, bucket, None,
                     self._kv_dtype if self._kv_quant else False,
                     method="prefill",
-                    **({"length": ints[0]} if i in self._ssm_blocks else {}),
+                    **({"length": ints[0]} if i in self._state_blocks else {}),
                 )
                 # The pool's rows (a latent block's are whole: no V).
                 kvs.append(ck if cv is None else fuse_kv(ck, cv))
@@ -2606,7 +2628,7 @@ class ContinuousBatcher:
                 self.lm.block_names, self._blocks, caches
             )):
                 kw = {}
-                if i in self._ssm_blocks:
+                if i in self._state_blocks:
                     kw = {"length": ints[1], "carried": jax.tree.map(
                         lambda s: jnp.where(
                             pos0 == 0, jnp.zeros((), s.dtype),
@@ -2721,10 +2743,10 @@ class ContinuousBatcher:
     def _count_state_write(self, carried: bool) -> None:
         """Book one write of a slot's recurrent state (an admission or
         a chunk pass) and whether the pass began from a carried one."""
-        if self._ssm_blocks:
-            global_metrics().inc("ssm.state_writes")
+        for family in self._state_families:
+            global_metrics().inc(f"{family}.state_writes")
             if carried:
-                global_metrics().inc("ssm.chunks_carried")
+                global_metrics().inc(f"{family}.chunks_carried")
 
     # -- request lifecycle -------------------------------------------------
 
@@ -4681,11 +4703,11 @@ class ContinuousBatcher:
         """Refuse ``what`` for a model with recurrent state: it moves
         or shares PAGES, and a request's pages without the state that
         belongs to the same position are half a cache."""
-        if self._ssm_blocks:
+        if self._state_blocks:
             raise ValueError(
                 f"{what} does not run for a model with recurrent state "
-                f"({len(self._ssm_blocks)} blocks keep a state-space "
-                "mixer's state a slot beside their pages) yet"
+                f"({len(self._state_blocks)} blocks keep a mixer's state a "
+                "slot, beside their pages or in place of them) yet"
             )
 
     def _per_head_pages(self, what: str) -> None:
@@ -4704,7 +4726,7 @@ class ContinuousBatcher:
     def _shares_pages(self) -> bool:
         """Whether a prompt page may be shared between requests (the
         radix prefix cache): one cache group and no recurrent state."""
-        return len(self._groups) == 1 and not self._ssm_blocks
+        return len(self._groups) == 1 and not self._state_blocks
 
     def _hold_groups(self, slot: int, lo_pos: int, hi_pos: int) -> None:
         """Before a pass that writes positions ``[lo_pos, hi_pos)`` of
@@ -4782,7 +4804,7 @@ class ContinuousBatcher:
             # A model with recurrent state names the slot whose state
             # the pass carries on from.
             ints = [pos0, clen, req.top_k] + (
-                [slot.idx] if self._ssm_blocks else []
+                [slot.idx] if self._state_blocks else []
             )
             (first, first_lp, self._caches,
              self._states) = self._prefill_suffix_fn(
@@ -5230,6 +5252,10 @@ class ContinuousBatcher:
                 global_metrics().inc(
                     "mhc.mixes", float(2 * self._stream_blocks * self.chunk)
                 )
+            if self._linear_blocks:
+                global_metrics().inc(
+                    "kda.steps", float(self._linear_blocks * self.chunk)
+                )
             limits = np.full((toks.shape[1],), self.chunk, np.int64)
             if tracer.enabled and fl.t_span:
                 # Dispatch -> results-landed of one compiled decode
@@ -5486,8 +5512,8 @@ class ContinuousBatcher:
             out["state_bytes"] = sum(
                 x.nbytes for x in jax.tree.leaves(self._states)
             )
-            out["state_slots"] = len(self.slots) if self._ssm_blocks else 0
-            if self._ssm_blocks:
+            out["state_slots"] = len(self.slots) if self._state_blocks else 0
+            if self._state_blocks:
                 out["prefix_cache"] = "off: recurrent state"
             ps = self._pager.stats()
             out["pool_pages"] = ps.num_pages
@@ -5496,7 +5522,9 @@ class ContinuousBatcher:
             #: values and in bytes of the pool's own representation.
             out["pool_row_values"] = self._groups[0].position_values
             out["pool_row_bytes"] = sum(
-                x.nbytes for x in jax.tree.leaves(self._caches[0])
+                x.nbytes for x in jax.tree.leaves(
+                    self._caches[self._groups[0].blocks[0]]
+                )
             ) // (ps.num_pages * self._page)
             out["pages_in_use"] = ps.in_use
             out["pages_free"] = ps.free

@@ -764,9 +764,13 @@ class CacheGroup:
 def cache_groups(specs) -> list[CacheGroup]:
     """Group a model's blocks (their ``BlockSpec``) by cache geometry,
     in order of first appearance. One group: every block alike, which
-    is every model before per-layer patterns."""
+    is every model before per-layer patterns. A block that keeps NO
+    pages (``BlockSpec.linear`` set: linear attention, a recurrent
+    state in their place) belongs to no group and gets no pool."""
     keys: dict[tuple, list[int]] = {}
     for i, spec in enumerate(specs):
+        if spec.linear is not None:
+            continue
         keys.setdefault(
             (spec.window, spec.cache_heads, spec.attn_head_dim,
              spec.cache_row), []

@@ -1,0 +1,45 @@
+"""Readers of the per-layer metrics Solar-Open2 brings: the gated
+delta-rule layer's decode kernel against its floor, and its share of
+the decode program. Each returns None where the trace has no such
+operation (a commit before this architecture ran), and the line then
+leaves the metric out."""
+
+from __future__ import annotations
+
+from chipbench import solar_open2_yardstick as sy
+from chipbench import xtrace, yardstick
+from chipbench.k_exaone_readers import _op_seconds, _traced
+
+#: The kernel's name in a device trace (``adapt_tpu/ops/kda_step.py``).
+KERNEL = "_kda_step_impl"
+
+
+def kda_step_roofline(trace, rec, kind):
+    """The state update's floor in the traced ticks (every live row's
+    state once in and once out, in every step of the tick's scan and
+    every linear-attention layer) against the device time of the
+    kernel. The floor counts live rows only, the kernel also moves an
+    idle row's state: the share errs low."""
+    seconds, s = _op_seconds(trace, KERNEL), rec["shape"]
+    if not seconds or "tick_contexts" not in rec or "kda_layers" not in s:
+        return None
+    rows = sum(len(rec["tick_contexts"][i]) for i, _ in _traced(rec))
+    flops, nbytes = sy.kda_step_cost(
+        rows * rec["serving"]["chunk"] * s["kda_layers"], s["kda_heads"],
+        s["kda_head_dim"], rec["itemsize"],
+    )
+    if not nbytes:
+        return None
+    return 100.0 * yardstick.floor_seconds(flops, nbytes, kind) / seconds
+
+
+def kda_step_share_pct(trace, rec, kind):
+    """The kernel's device time over the decode program's
+    (``_step_chunk``): whether the mechanism does the work."""
+    seconds = _op_seconds(trace, KERNEL)
+    if not seconds:
+        return None
+    _, step = xtrace.module_seconds(trace.devices[0]).get(
+        "_step_chunk", (0, 0.0)
+    )
+    return 100.0 * seconds / step if step else None

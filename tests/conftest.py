@@ -137,6 +137,28 @@ def rng():
     return jax.random.PRNGKey(0)
 
 
+def greedy_by_full_forward(lm, variables, prompt, steps: int):
+    """The oracle of the cached-decode parity tests: ``steps`` tokens
+    by stepwise argmax of the FULL causal forward, (b, steps). The
+    sequence stands in ONE buffer of its final length (what lies past a
+    position is masked from it, window or not), so the forward is ONE
+    compiled program: a sequence that grows a token a step is a new
+    shape a step, every operation of the model compiled again (30
+    steps of a 2-layer model took 200 s of the suite)."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from adapt_tpu.models.transformer_lm import logits_full
+
+    b, s0 = prompt.shape
+    ids = jnp.zeros((b, s0 + steps), prompt.dtype).at[:, :s0].set(prompt)
+    forward = jax.jit(lambda v, ids: logits_full(lm, v, ids))
+    for i in range(steps):
+        nxt = jnp.argmax(forward(variables, ids)[:, s0 + i - 1], axis=-1)
+        ids = ids.at[:, s0 + i].set(nxt.astype(ids.dtype))
+    return np.asarray(ids)[:, s0:]
+
+
 def spawn_worker_proc(*cli_args: str) -> "subprocess.Popen":
     """Launch ``python -m adapt_tpu.comm.remote`` as a hermetic CPU child
     (shared by the comm and stress tests — one place owns the env recipe:

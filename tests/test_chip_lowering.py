@@ -838,6 +838,64 @@ def test_ssm_step_compiles_for_v5e_in_place(
     assert mem.temp_size_in_bytes < state // 16
 
 
+# -- Solar-Open2: the gated delta-rule layer's decode step ---------------------
+
+# solaropen2_longgen: 256 rows, 64 heads of a (128, 128) state.
+_KDA = (256, 64, 128)
+
+
+def _kda_operands(on=sds):
+    rows, heads, d = _KDA
+    return (
+        on((rows, heads, d, d), jnp.float32), on((rows, heads, d)),
+        on((rows, heads, d)), on((rows, heads, d)),
+        on((rows, heads, d), jnp.float32), on((rows, heads), jnp.float32),
+    )
+
+
+def test_kda_step_lowers_and_narrow_heads_route_to_xla(as_tpu):
+    from adapt_tpu.ops.kda_step import kda_step
+
+    lower_for_tpu(kda_step, *_kda_operands())
+    assert kernel_dispatch_stats()["kda_step"]["last"] == 1.0
+    narrow = (
+        sds((4, 4, 16, 16), jnp.float32), sds((4, 4, 16)), sds((4, 4, 16)),
+        sds((4, 4, 16)), sds((4, 4, 16), jnp.float32),
+        sds((4, 4), jnp.float32),
+    )
+    jax.jit(kda_step).trace(*narrow).lower(lowering_platforms=("tpu",))
+    assert kernel_dispatch_stats()["kda_step"]["last"] == 0.0
+    with pytest.raises(ValueError, match="whole \\(128, 128\\) tiles"):
+        jax.eval_shape(
+            functools.partial(kda_step, prefer="pallas"), *narrow
+        )
+
+
+def test_kda_step_compiles_for_v5e_in_place(
+    as_tpu, one_chip, no_persistent_cache
+):
+    """Mosaic's own compile at the published widths (three in-kernel
+    transposes a head turn alpha, k and q into columns; four 1 MiB
+    blocks of 16 heads in VMEM), under the name the benchmark's reader
+    sums (``_kda_step_impl``), and the donated state aliased to the
+    output: no second copy of 1.07 GB a layer."""
+    from adapt_tpu.ops.kda_step import kda_step
+
+    def on_chip(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    compiled = jax.jit(kda_step, donate_argnums=(0,)).lower(
+        *_kda_operands(on_chip)
+    ).compile()
+    assert re.search(
+        r"%_kda_step_impl[.\d]* = .*tpu_custom_call", compiled.as_text()
+    )
+    mem = compiled.memory_analysis()
+    state = 256 * 64 * 128 * 128 * 4
+    assert mem.alias_size_in_bytes == state
+    assert mem.temp_size_in_bytes < state // 16
+
+
 def test_ssm_step_on_narrow_heads_routes_to_xla_on_tpu(as_tpu):
     """A head's state that is not whole (128, 128) tiles cannot take
     the in-kernel transpose: auto dispatch books the plain arm, a

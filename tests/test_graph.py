@@ -203,9 +203,11 @@ def _roundtrip(graph, x):
     rebuilt = graph_from_spec(spec)
     assert rebuilt.topo_order() == graph.topo_order()
     assert rebuilt.output == graph.output
-    variables = graph.init(jax.random.PRNGKey(0), x)
-    y_ref = graph.apply(variables, x)
-    y = rebuilt.apply(variables, x)
+    # One compiled program each (the same HLO twice gives the same
+    # bits): eagerly every layer's operations compile one by one.
+    variables = jax.jit(graph.init)(jax.random.PRNGKey(0), x)
+    y_ref = jax.jit(graph.apply)(variables, x)
+    y = jax.jit(rebuilt.apply)(variables, x)
     np.testing.assert_array_equal(np.asarray(y_ref), np.asarray(y))
     return rebuilt
 

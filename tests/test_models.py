@@ -67,7 +67,7 @@ def test_vit_tiny_partition_and_compose():
 
 def test_efficientnet_b0_dag_partition(small_image):
     g = efficientnet_b0(num_classes=10)
-    variables = g.init(jax.random.PRNGKey(1), small_image)
+    variables = jax.jit(g.init)(jax.random.PRNGKey(1), small_image)
     y_full = g.apply(variables, small_image)
     assert y_full.shape == (1, 10)
     # Multi-branch DAG: identity-residual blocks create joins; partition at
@@ -210,16 +210,12 @@ def test_lm_generate_matches_uncached_greedy():
     variables = lm.graph.init(jax.random.PRNGKey(3), prompt)
     steps = 6
 
-    out = np.asarray(generate(lm, variables, prompt, steps))
+    from conftest import greedy_by_full_forward
 
-    ids = prompt
-    expect = []
-    for _ in range(steps):
-        nxt = jnp.argmax(logits_full(lm, variables, ids)[:, -1], axis=-1)
-        expect.append(np.asarray(nxt))
-        ids = jnp.concatenate([ids, nxt[:, None].astype(ids.dtype)], axis=1)
-    expect = np.stack(expect, axis=1)
-    np.testing.assert_array_equal(out, expect)
+    out = np.asarray(generate(lm, variables, prompt, steps))
+    np.testing.assert_array_equal(
+        out, greedy_by_full_forward(lm, variables, prompt, steps)
+    )
 
 
 def test_lm_pipeline_partition_parity():
@@ -542,15 +538,12 @@ def test_lm_gqa_generate_matches_uncached_greedy():
     variables = lm.graph.init(jax.random.PRNGKey(43), prompt)
     steps = 6
 
-    out = np.asarray(generate(lm, variables, prompt, steps))
+    from conftest import greedy_by_full_forward
 
-    ids = prompt
-    expect = []
-    for _ in range(steps):
-        nxt = jnp.argmax(logits_full(lm, variables, ids)[:, -1], axis=-1)
-        expect.append(np.asarray(nxt))
-        ids = jnp.concatenate([ids, nxt[:, None].astype(ids.dtype)], axis=1)
-    np.testing.assert_array_equal(out, np.stack(expect, axis=1))
+    out = np.asarray(generate(lm, variables, prompt, steps))
+    np.testing.assert_array_equal(
+        out, greedy_by_full_forward(lm, variables, prompt, steps)
+    )
 
 
 def test_lm_gqa_int8_cache_composes():

@@ -144,7 +144,7 @@ def test_moe_lm_cached_decode_matches_full_forward():
     """KV-cached greedy generate on the MoE decoder == stepwise argmax
     of the full causal forward — the same parity bar as the dense LM
     (dropless routing is what makes it reachable)."""
-    from adapt_tpu.models.transformer_lm import generate, logits_full
+    from adapt_tpu.models.transformer_lm import generate
 
     lm = _moe_lm()
     variables = lm.graph.init(
@@ -153,12 +153,12 @@ def test_moe_lm_cached_decode_matches_full_forward():
     prompt = jax.random.randint(
         jax.random.PRNGKey(1), (2, 6), 0, 53, jnp.int32
     )
+    from conftest import greedy_by_full_forward
+
     got = np.asarray(generate(lm, variables, prompt, steps=5))
-    ids = prompt
-    for _ in range(5):
-        nxt = jnp.argmax(logits_full(lm, variables, ids)[:, -1], -1)
-        ids = jnp.concatenate([ids, nxt[:, None]], axis=1)
-    np.testing.assert_array_equal(got, np.asarray(ids)[:, 6:])
+    np.testing.assert_array_equal(
+        got, greedy_by_full_forward(lm, variables, prompt, 5)
+    )
 
 
 def test_moe_lm_serves_through_paged_batcher():

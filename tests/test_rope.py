@@ -14,7 +14,6 @@ import pytest
 from adapt_tpu.models.transformer_lm import (
     apply_rope,
     generate,
-    logits_full,
     transformer_lm,
 )
 from adapt_tpu.runtime.continuous import ContinuousBatcher
@@ -63,12 +62,12 @@ def test_rope_cached_decode_matches_full_forward(rlm_setup):
         jax.random.PRNGKey(2), (2, 12), 0, 43, jnp.int32
     )
     steps = 20
+    from conftest import greedy_by_full_forward
+
     got = np.asarray(generate(lm, variables, prompt, steps))
-    ids = prompt
-    for _ in range(steps):
-        nxt = jnp.argmax(logits_full(lm, variables, ids)[:, -1], -1)
-        ids = jnp.concatenate([ids, nxt[:, None]], axis=1)
-    np.testing.assert_array_equal(got, np.asarray(ids)[:, 12:])
+    np.testing.assert_array_equal(
+        got, greedy_by_full_forward(lm, variables, prompt, steps)
+    )
 
 
 def test_rope_ragged_rows_equal_solo_bitwise(rlm_setup):

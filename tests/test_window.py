@@ -43,12 +43,12 @@ def test_windowed_cached_decode_matches_full_forward(wlm_setup):
         jax.random.PRNGKey(1), (2, 20), 0, 41, jnp.int32
     )
     steps = 30  # 20 + 30 = 50 positions >> window 12
+    from conftest import greedy_by_full_forward
+
     got = np.asarray(generate(lm, variables, prompt, steps))
-    ids = prompt
-    for _ in range(steps):
-        nxt = jnp.argmax(logits_full(lm, variables, ids)[:, -1], -1)
-        ids = jnp.concatenate([ids, nxt[:, None]], axis=1)
-    np.testing.assert_array_equal(got, np.asarray(ids)[:, 20:])
+    np.testing.assert_array_equal(
+        got, greedy_by_full_forward(lm, variables, prompt, steps)
+    )
 
 
 def test_window_actually_masks(wlm_setup):
