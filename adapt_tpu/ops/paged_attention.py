@@ -12,33 +12,51 @@ with ``slots x max_len``.
 The TPU part: attention over a paged cache must NOT gather pages into a
 contiguous buffer first (that would write + re-read the whole window,
 doubling HBM traffic — the exact cost paging exists to avoid). The
-Pallas kernels here stream pages directly: the page table rides as a
-SCALAR-PREFETCH operand (``pltpu.PrefetchScalarGridSpec``), and the
-pool's ``index_map`` consults it to pick each grid step's physical page — the
-DMA engine fetches pool blocks in table order while the online-softmax
-state carries across them. The decode and verify step body is
-``ops/decode_attention``'s ``_attend_tile`` (same masks, same float32
-softmax state, same fused dequant); only the block FETCH differs,
-which is the whole point: one attention discipline, two memory
-layouts.
+Pallas kernels here read pages where they live: the page table rides as
+a SCALAR-PREFETCH operand (``pltpu.PrefetchScalarGridSpec``) and says
+which physical page a position block is, while the online-softmax state
+carries across the blocks. The verify and chunk kernels let the
+pipeline fetch them (the pool's ``index_map`` consults the table to
+pick each grid step's page); the decode kernel copies them itself
+(below). The decode and verify step body is ``ops/decode_attention``'s
+``_attend_tile`` (same masks, same float32 softmax state, same fused
+dequant); only the block FETCH differs, which is the whole point: one
+attention discipline, two memory layouts.
 
-The DECODE kernel's grid is ``(slots, head blocks, pages a slot)`` and
-one step covers one page of EVERY KV head of its block — a
-``(1, heads, page, 2 * head_dim)`` block of fused K|V rows, ``heads`` the
-largest divisor of the head count that fits a stated VMEM budget
-(``decode_heads_per_step``: 16 of 16 at head_dim 128, 25 of 25 at 64;
-derived from the operands, the per-shard ones under tensor
-parallelism). A grid step has a price before it moves a byte and its
-body is a chain of dependent operations (product, reduce, exp,
-product); a block of heads pays the price once per ~1 MB and gives the
-scheduler independent chains to overlap, in ONE batched
-``_attend_tile`` (measured on a v5e, PERF.md section 6, PR 28). The
-slots' live positions are prefetched beside the table and the index
-map CLAMPS the page axis to each slot's live window: a step past the
-last live page (or before the first, for ragged rows) names the block
-already resident, so the pipeline issues no copy for it, and the body
-skips it (``pl.when``). The verify kernel keeps one head a step
-(``_verify_impl``).
+The DECODE kernel's grid is ``(slots, head blocks)`` and the kernel
+WALKS a row's live pages itself (``_walk_kernel``). Every plane of the
+pool is handed to the call once and stays where it lives
+(``memory_space=pl.ANY``: no blocked pool operand, no pin, no copy of
+the pool in the compiled program); the table, the slots' newest
+positions and, for ragged rows, their oldest ride as scalar prefetch,
+and a loop inside the kernel runs from the page of ``valid_from`` to
+the page of ``index``, nowhere else: a window layer that holds 3 pages
+of a 15-ordinal table copies two or three, a row of a nearly empty
+server's 30 dead slots costs a scalar read and a zeroed output block.
+An iteration copies ``pages`` table-mapped blocks of ``(heads, page, 2
+* head_dim)`` — one page of EVERY KV head of the row's head block,
+contiguous in the pool — with ``pltpu.make_async_copy`` into one of two
+VMEM buffers while the other's are consumed, and a row's last iteration
+has the first pages of the next LIVE row in grid order in flight before
+it waits on its own (buffers, semaphores and the buffer index outlive a
+grid step). ``heads`` is the largest divisor of the head count that
+fits a stated VMEM budget (``decode_heads_per_step``: 16 of 16 at
+head_dim 128, 25 of 25 at 64), ``pages`` the smallest power of two
+whose blocks keep ~0.75 MB in flight (``decode_pages_per_step``: 1
+where a block is 0.8-1 MB, 2 at K-EXAONE's 512 KB, 4 at Falcon-H1's
+256 KB); both are derived from the operands, the per-shard ones under
+tensor parallelism. A block of heads gives the scheduler independent
+chains (product, reduce, exp, product) to overlap in ONE batched
+``_attend_tile`` a page (PERF.md section 6, PR 28); a row's last,
+shorter iteration takes its pages in groups of ``pages / 2``, ..., 1.
+What it replaced kept the page axis on the grid, ``(slots, head
+blocks, pages a slot)``: a step outside a row's live window fetched
+nothing and skipped its body but was still a step, 0.75 us on a v5e,
+and a live one ran at the pipeline's pace and not its copy's: 9,728
+steps a decode step of ``kexaone_longgen`` for ~1,900 live blocks, 59%
+of the bytes floor in ``cgpt1b3_batchgen`` where the walk reads 75%
+(the kernel alone; PERF.md section 6, PR 44 and PR 46). The verify
+kernel keeps one head a step (``_verify_impl``).
 
 The CHUNK-PREFILL kernel (``_chunk_impl``) folds heads the same way,
 by its own sum (``chunk_heads_per_step``: a chunk's state is per query
@@ -55,17 +73,19 @@ cost a chunk's hundreds three times the vector work (measured on a
 v5e, PERF.md section 6, PR 42). Quantized pools keep ``_attend_tile``
 under the fold.
 
-The grid's page axis can additionally FLASH-SPLIT (``split`` on every
-dispatcher, ``config.KernelConfig.decode_split``): each (row, split)
-grid point streams its own run of the slot's pages with independent
-online-softmax scratch and emits unnormalized partials (accumulator +
-running max + denominator); a single-pass rescale combine reduces them
-— so a long-context slot's KV stream can fan across TensorCores. On a
-one-core chip the automatic split is 1 (``decode_attention.
-default_decode_split``: the split's axis is ``parallel``, and only
-another core can take it up). ``split=1`` is the unsplit kernel; the
-last split may be ragged (clamped in the index maps, masked in the
-kernel).
+The FLASH-SPLIT form (``split`` on every dispatcher,
+``config.KernelConfig.decode_split``) keeps the page axis on its grid,
+``(slots, head blocks, split, pages a split)``, and lets the pipeline
+fetch: each (row, split) grid point streams its own run of the slot's
+pages with independent online-softmax scratch and emits unnormalized
+partials (accumulator + running max + denominator); a single-pass
+rescale combine reduces them — so a long-context slot's KV stream can
+fan across TensorCores. Its dead steps name the block already resident
+and fetch nothing. On a one-core chip the automatic split is 1
+(``decode_attention.default_decode_split``: the split's axis is
+``parallel``, and only another core can take it up), which is the
+walk; the last split may be ragged (clamped in the index maps, masked
+in the kernel).
 
 Layouts:
 - pool (one per decoder block; ``runtime/paged.alloc_kv_pools`` is the
@@ -112,8 +132,9 @@ Layouts:
 - q: (slots, kv_heads, g, head_dim) group-folded, as in
   ``decode_attention``.
 
-``page_size`` must be a lane multiple (128); the grid streams
-``pages_per_slot`` blocks of ``page_size`` positions.
+``page_size`` must be a lane multiple (128); a row's walk (or, split,
+its grid) covers at most ``pages_per_slot`` blocks of ``page_size``
+positions, the table's width.
 
 No reference analog (SURVEY.md §2.2: the reference is CNN-only) — this
 is the framework's own serving-memory frontier.
@@ -404,21 +425,25 @@ def _acc_width(head_dim: int) -> int:
 
 
 def decode_step_vmem_bytes(heads, page, row_width, itemsize, scales,
-                           gq=8, head_dim=None) -> int:
-    """VMEM one grid step of the decode kernel asks for when it covers
-    ``heads`` KV heads of one page of a fused plane (``row_width`` =
-    the plane's last dimension, K|V): the block (and its two scale
-    tiles), double-buffered by the pipeline; q and the output block,
-    double-buffered; the per-head softmax state; and the body's float32
-    working set (one head's rows widened on their way into the
-    products, the block's score-shaped rows). Rows narrower than a lane
-    tile arrive transposed, the page on the lanes, and pad nothing; q,
-    the output and the state do."""
+                           gq=8, head_dim=None, pages=1) -> int:
+    """VMEM one grid step of the decode kernel asks for when an
+    iteration of its walk covers ``pages`` pages of ``heads`` KV heads
+    of a fused plane (``row_width`` = the plane's last dimension, K|V):
+    the kernel's own two buffers of ``pages`` blocks (and their two
+    scale tiles a page), one being copied into while the other is
+    consumed; q and the output block, double-buffered by the pipeline;
+    the per-head softmax state; and the body's float32 working set,
+    which is ONE page's whatever ``pages`` is (one head's rows widened
+    on their way into the products, the block's score-shaped rows).
+    Rows narrower than a lane tile arrive transposed, the page on the
+    lanes, and pad nothing; q, the output and the state do. (The
+    flash-split form's pipeline double-buffers one block a step: the
+    same sum at ``pages`` 1.)"""
     hd = head_dim or row_width // 2
     acc = _lanes(_acc_width(hd))
-    stream = 2 * heads * page * row_width * itemsize
+    stream = 2 * pages * heads * page * row_width * itemsize
     if scales:
-        stream += 2 * 2 * heads * max(page // 128, 8) * 128 * 4
+        stream += 2 * 2 * pages * heads * max(page // 128, 8) * 128 * 4
     rows = 2 * heads * gq * (acc + _lanes(hd)) * 4
     state = heads * (2 * 8 * 128 + gq * acc) * 4
     working = (page * _lanes(row_width) + heads * 6 * gq * page) * 4
@@ -429,16 +454,52 @@ def decode_heads_per_step(kv_heads, page, row_width, itemsize, scales,
                           gq=8, head_dim=None) -> int:
     """KV heads one grid step of the decode kernel covers: the largest
     divisor of ``kv_heads`` (the per-shard count under tensor
-    parallelism) whose step fits ``DECODE_STEP_VMEM_BUDGET``. A grid
-    step costs 0.15-0.25 us on a v5e before it moves a byte and its
-    body is a chain of dependent operations, so a step should cover as
-    many independent heads as fit: 16 heads of a bf16 page at head_dim
+    parallelism) whose step fits ``DECODE_STEP_VMEM_BUDGET``. A page's
+    block is one copy and one body, each with a price before it moves
+    a byte (0.15-0.25 us a step on a v5e while the pipeline fetched),
+    and the body is a chain of dependent operations, so a block should
+    cover as many independent heads as fit: 16 heads of a bf16 page at head_dim
     128 are 1 MB of fused rows, 25 heads at head_dim 64 0.8 MB; an int8
     pool at 1024-position pages comes out at 8 of 16 (5 of 25) by the
     same sum. Derived from the operands, never set."""
     return _heads_that_fit(kv_heads, lambda heads: decode_step_vmem_bytes(
         heads, page, row_width, itemsize, scales, gq, head_dim
     ))
+
+
+#: What an iteration of the decode kernel's walk should have in flight
+#: before it covers a second page. Measured on a v5e at the cells'
+#: standing populations, the kernel alone (PERF.md section 6, PR 46):
+#: a block of 1 MB (``cgpt1b3_batchgen``) reads 2% SLOWER in twos and
+#: one of 0.8 MB (GPT-2-XL) 6% faster at 8 slots and 2% slower at 32;
+#: 512 KB alone (K-EXAONE, Solar-Open2) reads 8-14% slower than in
+#: twos, 256 KB alone (Falcon-H1) 25% slower than in fours, and twice
+#: as many again gains nothing anywhere.
+DECODE_ITERATION_BYTES = 3 * 2 ** 18
+
+
+def decode_pages_per_step(pages_per_slot, heads, page, row_width, itemsize,
+                          scales, gq=8, head_dim=None) -> int:
+    """Pages one iteration of the decode kernel's walk covers: the
+    SMALLEST power of two whose blocks of ``heads`` KV heads reach
+    ``DECODE_ITERATION_BYTES``, at most the slot's pages and what fits
+    ``DECODE_STEP_VMEM_BUDGET`` in the kernel's two buffers. An
+    iteration has a price of its own (a wait, a branch, the next
+    copies' issue), so a thin block wants company; every page more is a
+    body more to lower in every program that holds the kernel, so a
+    block that fills the stream alone gets none. Derived from the
+    operands, never set."""
+    pages = 1
+    while (
+        pages * heads * page * row_width * itemsize < DECODE_ITERATION_BYTES
+        and pages * 2 <= pages_per_slot
+        and decode_step_vmem_bytes(
+            heads, page, row_width, itemsize, scales, gq, head_dim,
+            pages * 2,
+        ) <= DECODE_STEP_VMEM_BUDGET
+    ):
+        pages *= 2
+    return pages
 
 
 def _heads_that_fit(kv_heads, step_bytes) -> int:
@@ -543,9 +604,9 @@ def _attend_rows_on_lanes(q, kv, live, m_scr, l_scr, acc_scr, sm_scale):
     )  # (heads, aw, gc)
 
 
-@functools.partial(jax.jit, static_argnames=("heads", "split"))
+@functools.partial(jax.jit, static_argnames=("heads", "split", "pages"))
 def _paged_impl(q, kv_pool, k_scales, v_scales, page_table, index,
-                valid_from, heads=1, split=1):
+                valid_from, heads=1, split=1, pages=1):
     b, kvh, g, hd = q.shape
     page = kv_pool.shape[2]
     row = kv_pool.shape[3]  # K|V: 2 * head_dim, head_dim for packed int4
@@ -559,11 +620,10 @@ def _paged_impl(q, kv_pool, k_scales, v_scales, page_table, index,
     gq = g + pad_g
     aw = _acc_width(hd)
     q = _pad_q_lanes(q, hd)
-    bps = -(-pages_per_slot // split)  # pages per split (last may be ragged)
 
     # Scalar prefetch: the page table, each slot's newest live position
-    # and, for ragged rows, its oldest. The index maps read all three;
-    # the body reads the positions again for its masks.
+    # and, for ragged rows, its oldest. The kernels read all three:
+    # which pages a row owns and where its live window lies.
     prefetch = [
         jnp.asarray(page_table, jnp.int32),
         jnp.broadcast_to(jnp.asarray(index, jnp.int32).reshape(-1), (b,)),
@@ -573,22 +633,6 @@ def _paged_impl(q, kv_pool, k_scales, v_scales, page_table, index,
 
     def row_map(s, hb, *rest):
         return (s, hb, 0, 0)
-
-    def kv_map(s, hb, *rest):
-        # rest: the page axis (split: the split and its page), then the
-        # prefetched refs.
-        n = 1 if split == 1 else 2
-        js, (table_ref, idx_ref, *vf_ref) = rest[:n], rest[n:]
-        jg = js[0] if split == 1 else js[0] * bps + js[1]
-        # The walk stops at the slot's live window: a step past its last
-        # live page (or before its first) names the block the step
-        # before it held, so the pipeline issues no copy for it; a dead
-        # row (negative index) names its first table entry throughout.
-        last = jnp.minimum(
-            jnp.maximum(idx_ref[s], 0) // page, pages_per_slot - 1
-        )
-        first = jnp.clip(vf_ref[0][s] // page, 0, last) if has_vf else 0
-        return (table_ref[s, jnp.clip(jg, first, last)], hb, 0, 0)
 
     # A fused row of whole lane tiles (head_dim >= 64) lives row-major
     # and is read as it lives. A NARROWER row (head_dim under 64) lives
@@ -600,46 +644,25 @@ def _paged_impl(q, kv_pool, k_scales, v_scales, page_table, index,
     # and the body contracts the other axis (``kv_transposed``). Packed
     # int4 rows unpack along their lanes and stay as stored.
     transposed = row % 128 != 0 and not packed
-    kv_block = (1, heads, row, page) if transposed else (1, heads, page, row)
+    kv_block = (heads, row, page) if transposed else (heads, page, row)
     if transposed:
         kv_pool = jnp.swapaxes(kv_pool, 2, 3)
-    in_specs = [
-        pl.BlockSpec((1, heads, gq, aw), row_map, memory_space=_VMEM),
-        pl.BlockSpec(kv_block, kv_map, memory_space=_VMEM),
-    ]
-    # The pool stays in HBM and the kernel streams it from there. Left
-    # to itself XLA may park a plane small enough in fast memory on its
-    # way in (it did GPT-2-XL's 23 MB planes while they were still
-    # relaid out: the kernel then read at 107% of its bytes floor on a
-    # v5e, its time having left the fetch to a copy). (The interpreter
-    # knows no memory spaces.)
-    in_hbm = (lambda x: x) if pallas_interpret() else functools.partial(
-        pltpu.with_memory_space_constraint, memory_space=pltpu.HBM
-    )
-    operands = [q, in_hbm(kv_pool)]
+    planes, blocks = [kv_pool], [kv_block]
     if quantized:
         # (pages, kvh, P, 1) f32 scale pools -> (pages, kvh, P/128,
         # 128) CHUNKED views (position = row*128 + lane — the dense
         # kernel's scale-tile trick, so a >=1024 page fills whole f32
-        # (8, 128) tiles on hardware); table-addressed by the SAME
-        # scalar-prefetch index_map as the int8 payload, 4/head_dim of
-        # its bytes (one f32 per int8 vector).
-        for s in (k_scales, v_scales):
-            operands.append(
-                in_hbm(s.reshape(s.shape[0], kvh, page // 128, 128))
-            )
-            in_specs.append(
-                pl.BlockSpec(
-                    (1, heads, page // 128, 128), kv_map,
-                    memory_space=_VMEM,
-                )
-            )
-
-    kernel = functools.partial(
-        _paged_kernel,
+        # (8, 128) tiles on hardware); table-addressed like the int8
+        # payload, 4/head_dim of its bytes (one f32 per int8 vector).
+        planes += [
+            s.reshape(s.shape[0], kvh, page // 128, 128)
+            for s in (k_scales, v_scales)
+        ]
+        blocks += [(heads, page // 128, 128)] * 2
+    q_spec = pl.BlockSpec((1, heads, gq, aw), row_map, memory_space=_VMEM)
+    kernel_kw = dict(
         page=page,
         num_pages=pages_per_slot,
-        bps=None if split == 1 else bps,
         sm_scale=1.0 / (hd ** 0.5),
         quantized=quantized,
         has_vf=has_vf,
@@ -653,36 +676,81 @@ def _paged_impl(q, kv_pool, k_scales, v_scales, page_table, index,
     ]
     head_blocks = kvh // heads
     if split == 1:
+        # The walk: grid (slots, head blocks); every plane of the pool
+        # is handed over once and stays where it lives, and the kernel
+        # copies the pages a row's live window names out of it.
+        assert pages & (pages - 1) == 0, pages  # the walk halves its groups
         out = pl.pallas_call(
-            kernel,
+            functools.partial(
+                _walk_kernel, pages=pages, head_blocks=head_blocks,
+                **kernel_kw,
+            ),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=len(prefetch),
-                grid=(b, head_blocks, pages_per_slot),
-                in_specs=in_specs,
+                grid=(b, head_blocks),
+                in_specs=[q_spec] + [
+                    pl.BlockSpec(memory_space=pl.ANY) for _ in planes
+                ],
                 out_specs=pl.BlockSpec(
                     (1, heads, gq, hd), row_map, memory_space=_VMEM
                 ),
-                scratch_shapes=scratch,
+                scratch_shapes=[
+                    pltpu.VMEM((2, pages) + block, plane.dtype)
+                    for plane, block in zip(planes, blocks)
+                ] + [
+                    pltpu.SemaphoreType.DMA((2,)),
+                    pltpu.SMEM((1,), jnp.int32),
+                ] + scratch,
             ),
             out_shape=jax.ShapeDtypeStruct((b, kvh, gq, hd), q.dtype),
+            # The buffers carry one row's look-ahead into the next: the
+            # rows run in grid order (a v5e has one core to run them on).
             compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary")
+                dimension_semantics=("arbitrary", "arbitrary")
             ),
             interpret=pallas_interpret(),
-        )(*prefetch, *operands)
+        )(*prefetch, q, *planes)
         return out[:, :, :g, :]
 
-    # Flash-decoding split over the slot's page list: each (slot, head
-    # block, split) streams its own run of table entries and emits
-    # partials; the single-pass rescale combine reduces them (dense
-    # discipline).
+    # Flash-decoding split over the slot's page list: the page axis on
+    # the grid, each (slot, head block, split) streams its own run of
+    # table entries through the pipeline and emits partials; the
+    # single-pass rescale combine reduces them (dense discipline).
+    bps = -(-pages_per_slot // split)  # pages per split (last may be ragged)
+
+    def kv_map(s, hb, s_id, j, table_ref, idx_ref, *vf_ref):
+        jg = s_id * bps + j
+        # The walk stops at the slot's live window: a step past its last
+        # live page (or before its first) names the block the step
+        # before it held, so the pipeline issues no copy for it; a dead
+        # row (negative index) names its first table entry throughout.
+        last = jnp.minimum(
+            jnp.maximum(idx_ref[s], 0) // page, pages_per_slot - 1
+        )
+        first = jnp.clip(vf_ref[0][s] // page, 0, last) if has_vf else 0
+        return (table_ref[s, jnp.clip(jg, first, last)], hb, 0, 0)
+
+    # The pool stays in HBM and the pipeline streams it from there. Left
+    # to itself XLA may park a plane small enough in fast memory on its
+    # way in (it did GPT-2-XL's 23 MB planes while they were still
+    # relaid out: the kernel then read at 107% of its bytes floor on a
+    # v5e, its time having left the fetch to a copy). (The interpreter
+    # knows no memory spaces.)
+    in_hbm = (lambda x: x) if pallas_interpret() else functools.partial(
+        pltpu.with_memory_space_constraint, memory_space=pltpu.HBM
+    )
+    in_specs = [q_spec] + [
+        pl.BlockSpec((1,) + block, kv_map, memory_space=_VMEM)
+        for block in blocks
+    ]
+
     def part_map(s, hb, s_id, *rest):
         return (s, s_id, hb, 0, 0)
 
     part = pl.BlockSpec((1, 1, heads, gq, hd), part_map, memory_space=_VMEM)
     part_shape = jax.ShapeDtypeStruct((b, split, kvh, gq, hd), jnp.float32)
     o_p, m_p, l_p = pl.pallas_call(
-        kernel,
+        functools.partial(_paged_kernel, bps=bps, **kernel_kw),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(prefetch),
             grid=(b, head_blocks, split, bps),
@@ -697,7 +765,7 @@ def _paged_impl(q, kv_pool, k_scales, v_scales, page_table, index,
             )
         ),
         interpret=pallas_interpret(),
-    )(*prefetch, *operands)
+    )(*prefetch, q, *map(in_hbm, planes))
     return _combine_splits(o_p, m_p, l_p, q.dtype)[:, :, :g, :]
 
 
@@ -723,42 +791,184 @@ def _emit_softmax(o_ref, parts, m_scr, l_scr, acc_scr):
     l_ref[lead] = jnp.broadcast_to(l_scr[...], acc.shape)
 
 
+def _unpack_refs(refs, has_vf, quantized):
+    """The decode kernels' operand refs after the table and the
+    positions: ``(valid_from or None, q, [kv, k scales, v scales],
+    the rest)`` — the scale planes present for a quantized pool."""
+    refs = list(refs)
+    vf_ref = refs.pop(0) if has_vf else None
+    q_ref = refs.pop(0)
+    n = 3 if quantized else 1
+    return vf_ref, q_ref, refs[:n], refs[n:]
+
+
+def _walk_kernel(table_ref, idx_ref, *refs, page, pages, num_pages,
+                 head_blocks, sm_scale, quantized, has_vf, packed=False,
+                 transposed=False):
+    """``heads`` KV heads of one slot a grid step, grid (slots, head
+    blocks); the row's LIVE pages are walked here, ``pages`` an
+    iteration: the ordinals from the page of ``valid_from`` (0 without
+    one) to the page of ``index``, both read from the prefetched
+    positions, so a window layer that holds 3 pages of a 15-ordinal
+    table copies 2 or 3 and a dead row (negative index) none. Every
+    plane of the pool arrives whole, where it lives; a page's block
+    ``pool[table[s, j], hb * heads:(hb + 1) * heads]`` (and its two
+    scale tiles, quantized) is copied into ``bufs`` — (2, pages, heads,
+    page, 2 * hd) a plane, (2, pages, heads, 2 * hd, page) when
+    ``transposed`` — the two buffers the iterations alternate between,
+    ``sems`` one DMA semaphore a buffer, ``cur_ref`` (SMEM) the buffer
+    the next iteration to be consumed lands in. Buffers, semaphores and
+    ``cur_ref`` outlive a grid step: a row's last iteration has the
+    first pages of the next live (slot, head block) in grid order in
+    flight before it waits on its own, so a row begins with its copies
+    already under way (the first live row's are started at grid step
+    0) and a dead row costs a scalar read and a zeroed output block.
+    The body is ONE ``_attend_fused`` a page block, the head axis
+    leading (one attention discipline: its masks over ``[valid_from,
+    index]``, its float32 softmax state in (heads, gq, .) scratch, its
+    fused int8 dequant and int4 unpack); a row's last, shorter
+    iteration takes its pages in groups of ``pages / 2``, ..., 1 by the
+    bits of their number (PERF.md section 6, PR 44 and PR 46)."""
+    vf_ref, q_ref, planes, rest = _unpack_refs(refs, has_vf, quantized)
+    o_ref, *rest = rest
+    bufs, (sems, cur_ref, m_scr, l_scr, acc_scr) = (
+        rest[:len(planes)], rest[len(planes):]
+    )
+    slot, hb = pl.program_id(0), pl.program_id(1)
+    slots = pl.num_programs(0)
+    heads, gq = q_ref.shape[1], q_ref.shape[2]
+
+    def window(s):
+        # (first live ordinal, live pages) of slot s. (``lax.div``:
+        # nothing here is negative, ``_chunk_live_pages``.)
+        idx, p = idx_ref[s], jnp.int32(page)
+        last = jnp.minimum(lax.div(jnp.maximum(idx, 0), p), num_pages - 1)
+        first = 0
+        if has_vf:
+            first = jnp.minimum(lax.div(jnp.maximum(vf_ref[s], 0), p), last)
+        return first, jnp.where(idx < 0, 0, last - first + 1)
+
+    def copies(s, h, t, b, then, first, live):
+        # ``then`` (start or wait) each live page's copies of iteration
+        # t of (slot s, head block h), whose window is (first, live),
+        # into buffer b. The table is read under the guard: only a live
+        # ordinal's entry names a page.
+        for i in range(pages):
+            @pl.when(t * pages + i < live)
+            def _(i=i):
+                phys = table_ref[s, first + t * pages + i]
+                for plane, buf in zip(planes, bufs):
+                    then(pltpu.make_async_copy(
+                        plane.at[phys, pl.ds(h * heads, heads)],
+                        buf.at[b, i], sems.at[b],
+                    ))
+
+    def start(s, h, t, b, *win):
+        copies(s, h, t, b, lambda c: c.start(), *(win or window(s)))
+
+    def start_first_of(after, b):
+        # head block 0 of the first slot after ``after`` that has a
+        # page, found by a scalar loop over the prefetched positions.
+        n = jax.lax.while_loop(
+            lambda n: (n < slots) & (idx_ref[jnp.minimum(n, slots - 1)] < 0),
+            lambda n: n + 1, after + 1,
+        )
+        pl.when(n < slots)(lambda: start(n, 0, 0, b))
+
+    def start_next_row(b):
+        # the first copies of the next live (slot, head block) in grid
+        # order: this slot's next head block, else the next live slot's
+        # first.
+        if head_blocks == 1:
+            start_first_of(slot, b)
+            return
+        pl.when(hb + 1 < head_blocks)(
+            lambda: start(slot, hb + 1, 0, b, first, live)
+        )
+        pl.when(hb + 1 == head_blocks)(lambda: start_first_of(slot, b))
+
+    @pl.when((slot == 0) & (hb == 0))
+    def _first():
+        cur_ref[0] = 0
+        start_first_of(-1, 0)
+
+    idx = idx_ref[slot]
+    vf = vf_ref[slot] if has_vf else None
+
+    def attend(b, i, ordinal):
+        # buffer b's page i: the row's page ``ordinal``, live.
+        cols = ordinal * page + jax.lax.broadcasted_iota(
+            jnp.int32, (gq, page), 1
+        )
+        live = cols <= idx
+        if has_vf:
+            live = jnp.logical_and(live, cols >= vf)
+        ksc, vsc = (
+            buf[b, i].reshape(heads, 1, page) for buf in bufs[1:]
+        ) if quantized else (None, None)
+        _attend_fused(
+            q_ref[0], bufs[0][b, i], ksc, vsc, live, m_scr, l_scr, acc_scr,
+            sm_scale, packed, transposed,
+        )
+
+    _init_softmax_scratch(m_scr, l_scr, acc_scr)
+    groups = [pages >> k for k in range(pages.bit_length())]
+    first, live = window(slot)
+    iters = lax.div(live + pages - 1, jnp.int32(pages))
+    base = cur_ref[0]
+
+    def iteration(t, _):
+        b = lax.rem(base + t, 2)
+        # The copies after this iteration's go out before it waits on
+        # its own: the row's next, or the next live row's first.
+        pl.when(t + 1 < iters)(
+            lambda: start(slot, hb, t + 1, 1 - b, first, live)
+        )
+        pl.when(t + 1 == iters)(lambda: start_next_row(1 - b))
+        copies(slot, hb, t, b, lambda c: c.wait(), first, live)
+        if pages == 1:
+            attend(b, 0, first + t)
+            return
+        here = jnp.minimum(live - t * pages, pages)
+        # ``here`` live pages, taken in groups of pages, pages / 2, ...,
+        # 1 by its bits: a whole iteration is one group, a row's last
+        # one at most log2(pages), and no dead page is in any.
+        for g in groups:
+            @pl.when(here & g != 0)
+            def _(g=g):
+                at = 0 if 2 * g >= pages else here - lax.rem(here, 2 * g)
+                for i in range(g):
+                    attend(b, at + i, first + t * pages + at + i)
+
+    jax.lax.fori_loop(0, iters, iteration, None)
+    cur_ref[0] = lax.rem(base + iters, 2)
+    _emit_softmax(o_ref, None, m_scr, l_scr, acc_scr)
+
+
 def _paged_kernel(table_ref, idx_ref, *refs, page, num_pages, bps, sm_scale,
                   quantized, has_vf, packed=False, transposed=False):
-    """One page of ``heads`` KV heads of one slot per grid step: grid
-    (slots, head blocks, pages), or (slots, head blocks, split, bps)
-    when ``bps`` is set — the FLASH-SPLIT form, which emits each
-    split's unnormalized partials (accumulator, running max,
-    denominator) for ``_combine_splits`` instead of a normalized
-    output. The scalar-prefetched table is consumed by the index maps;
-    the prefetched positions give this slot's live window
+    """The FLASH-SPLIT form of the decode kernel, the page axis on the
+    grid: one page of ``heads`` KV heads of one slot per grid step,
+    grid (slots, head blocks, split, bps); it emits each split's
+    unnormalized partials (accumulator, running max, denominator) for
+    ``_combine_splits``. The scalar-prefetched table is consumed by the
+    index maps; the prefetched positions give this slot's live window
     ``[valid_from, index]``. The fused rows arrive as a (1, heads,
     page, 2 * hd) block ((1, heads, 2 * hd, page) when ``transposed``:
     head_dim under 64) and the body is ONE ``_attend_fused`` over the
-    block, the head axis leading (one attention discipline: its masks,
-    its float32 softmax state, its fused int8 dequant and int4
-    unpack), with per-head state in (heads, gq, .) scratch. Quantized
-    pools add chunked (1, heads, page/128, 128) f32 scale tiles of K
-    and of V, table-addressed like the payload. A page outside the live
-    window — every page of a dead row (negative index), the ragged
-    tail of the last split — skips the body, and its step fetched
-    nothing (``_paged_impl``'s ``kv_map``)."""
+    block, as ``_walk_kernel``'s. Quantized pools add chunked (1,
+    heads, page/128, 128) f32 scale tiles of K and of V,
+    table-addressed like the payload. A page outside the live window —
+    every page of a dead row (negative index), the ragged tail of the
+    last split — skips the body, and its step fetched nothing
+    (``_paged_impl``'s ``kv_map``) but is a step all the same."""
     del table_ref  # consumed by the index maps
-    refs = list(refs)
-    vf_ref = refs.pop(0) if has_vf else None
-    q_ref, kv_ref = refs[:2]
-    del refs[:2]
-    ksc_ref = refs.pop(0) if quantized else None
-    vsc_ref = refs.pop(0) if quantized else None
-    if bps is None:
-        o_ref, m_scr, l_scr, acc_scr = refs
-        parts = None
-        j = pl.program_id(2)
-        jg, last_j = j, num_pages - 1
-    else:
-        o_ref, *parts, m_scr, l_scr, acc_scr = refs
-        j = pl.program_id(3)
-        jg, last_j = pl.program_id(2) * bps + j, bps - 1
+    vf_ref, q_ref, (kv_ref, *scale_refs), rest = _unpack_refs(
+        refs, has_vf, quantized
+    )
+    o_ref, *parts, m_scr, l_scr, acc_scr = rest
+    j = pl.program_id(3)
+    jg = pl.program_id(2) * bps + j
     heads, gq = q_ref.shape[1], q_ref.shape[2]
     slot = pl.program_id(0)
     idx = idx_ref[slot]
@@ -775,22 +985,20 @@ def _paged_kernel(table_ref, idx_ref, *refs, page, num_pages, bps, sm_scale,
         live = cols <= idx
         if has_vf:
             live = jnp.logical_and(live, cols >= vf)
-
+        ksc, vsc = (
+            r[0].reshape(heads, 1, page) for r in scale_refs
+        ) if quantized else (None, None)
         _attend_fused(
-            q_ref[0], kv_ref[0],
-            ksc_ref[0].reshape(heads, 1, page) if quantized else None,
-            vsc_ref[0].reshape(heads, 1, page) if quantized else None,
-            live, m_scr, l_scr, acc_scr, sm_scale, packed, transposed,
+            q_ref[0], kv_ref[0], ksc, vsc, live, m_scr, l_scr, acc_scr,
+            sm_scale, packed, transposed,
         )
 
-    live_block = jg * page <= idx
-    if bps is not None:
-        live_block = jnp.logical_and(live_block, jg < num_pages)
+    live_block = jnp.logical_and(jg * page <= idx, jg < num_pages)
     if has_vf:
         live_block = jnp.logical_and(live_block, (jg + 1) * page > vf)
     pl.when(live_block)(_step)
 
-    @pl.when(j == last_j)
+    @pl.when(j == bps - 1)
     def _emit():
         _emit_softmax(o_ref, parts, m_scr, l_scr, acc_scr)
 
@@ -1463,23 +1671,38 @@ def paged_attention(
     derive from the given (per-shard, under TP) operands — q and pool
     must agree (``decode_attention.check_head_parity``) — and the
     books say what was derived (``kernel_dispatch_stats()
-    ["paged_decode"]``: ``heads_per_step``, ``split``)."""
+    ["paged_decode"]``: ``heads_per_step``, ``split``, the
+    ``pages_per_step`` an iteration of the unsplit kernel's walk
+    covers (``decode_pages_per_step``) and the ``grid_steps`` a call
+    takes: the rows, or every page of every row under a split)."""
     check_head_parity(q.shape[1], pool_values(pool).shape[1])
     if resolve_prefer(
         "paged_decode", prefer, kernel_unsupported(q, pool), on_tpu()
     ):
         kv, ks, vs = _pool_planes(pool)
-        heads = decode_heads_per_step(
-            _shard_heads(q, head_shard), kv.shape[2], kv.shape[3],
-            kv.dtype.itemsize,
-            ks is not None, q.shape[2] + (-q.shape[2]) % 8, q.shape[3],
+        geometry = (
+            kv.shape[2], kv.shape[3], kv.dtype.itemsize, ks is not None,
+            q.shape[2] + (-q.shape[2]) % 8, q.shape[3],
         )
+        kvh = _shard_heads(q, head_shard)
+        heads = decode_heads_per_step(kvh, *geometry)
         split = resolve_decode_split(page_table.shape[1], split)
+        # The split form keeps the page axis on its grid; the walk's
+        # grid is the rows, and an iteration of it covers ``pages``.
+        pages = 1 if split > 1 else decode_pages_per_step(
+            page_table.shape[1], heads, *geometry
+        )
+        rows = q.shape[0] * (kvh // heads)
         record_kernel_choice(
-            "paged_decode", heads_per_step=heads, split=split
+            "paged_decode", heads_per_step=heads, split=split,
+            pages_per_step=pages,
+            grid_steps=rows if split == 1
+            else rows * split * -(-page_table.shape[1] // split),
         )
         return _head_sharded(
-            functools.partial(_paged_impl, heads=heads, split=split),
+            functools.partial(
+                _paged_impl, heads=heads, split=split, pages=pages
+            ),
             head_shard,
             (q, kv, ks, vs),
             (jnp.asarray(page_table, jnp.int32),

@@ -138,6 +138,47 @@ def test_paged_decode_lowers_at_cell_shapes(as_tpu, cell, split):
     assert books["heads_per_step"] == _CELL_SHAPES[cell][1]
 
 
+#: cell (and cache group) -> slots, kv heads, query heads a KV head,
+#: head_dim, pages a slot, pool pages, and what the walk derives there:
+#: (heads a step, pages an iteration, grid steps a call). A block of
+#: every head of a page is 1 MB in ``cgpt1b3_batchgen`` and 0.8 MB in
+#: the GPT-2-XL cells and goes alone; K-EXAONE's and Solar-Open2's 512
+#: KB go in twos and Falcon-H1's 256 KB in fours (measured on a v5e,
+#: PERF.md section 6, PR 46). The grid is the rows: 9,728 steps a
+#: decode step of ``kexaone_longgen`` while the page axis was on it
+#: (128 x (16 + 4 x 15)), 640 now.
+_WALK_CELLS = {
+    "cgpt1b3_batchgen": (24, 16, 1, 128, 7, 169, (16, 1, 24)),
+    "gpt2xl_doc": (8, 25, 1, 64, 7, 57, (25, 1, 8)),
+    "gpt2xl_chat": (32, 25, 1, 64, 3, 97, (25, 1, 32)),
+    "kexaone_longgen.full": (128, 8, 8, 128, 16, 2049, (8, 2, 128)),
+    "kexaone_longgen.window": (128, 8, 8, 128, 15, 385, (8, 2, 128)),
+    "falconh1_longgen": (128, 4, 5, 128, 16, 2049, (4, 4, 128)),
+    "solaropen2_longgen": (256, 8, 8, 128, 15, 3841, (8, 2, 256)),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(_WALK_CELLS))
+def test_paged_decode_books_the_walk_at_cell_shapes(as_tpu, cell):
+    """``kernel_dispatch_stats()["paged_decode"]`` at every cell's
+    shape: the pages an iteration of the walk covers and the grid
+    steps a call takes, derived from the operands and lowered for a
+    TPU at the cell's own group of query heads (5 pads to 8)."""
+    b, kvh, g, hd, pps, npages, want = _WALK_CELLS[cell]
+    lower_for_tpu(
+        paged_attention,
+        sds((b, kvh, g, hd)), sds((npages, kvh, 128, 2 * hd)),
+        sds((b, pps), jnp.int32), sds((b,), jnp.int32),
+        sds((b,), jnp.int32),
+    )
+    books = kernel_dispatch_stats()["paged_decode"]
+    assert (
+        books["heads_per_step"], books["pages_per_step"],
+        books["grid_steps"],
+    ) == want
+    assert books["split"] == 1
+
+
 @pytest.mark.parametrize("cell", sorted(_CELL_SHAPES))
 def test_cell_planes_take_every_head_a_step(cell):
     """16 of 16 and 25 of 25: the fused plane's block (one stream of
@@ -430,22 +471,42 @@ def no_persistent_cache():
     compilation_cache.reset_cache()
 
 
+def _pallas_calls(jaxpr, found=None):
+    """Every ``pallas_call`` equation of a jaxpr, nested ones too."""
+    found = [] if found is None else found
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(eqn)
+        for v in eqn.params.values():
+            inner = getattr(v, "jaxpr", None)
+            if inner is not None:
+                _pallas_calls(getattr(inner, "jaxpr", inner), found)
+    return found
+
+
 @pytest.mark.parametrize("split", [1, 2])
 @pytest.mark.parametrize("shape", [
     (24, 16, 128, 7, 169, 128, "native"),  # cgpt1b3_batchgen
     (8, 25, 64, 7, 57, 128, "native"),  # gpt2xl_doc
     (32, 25, 64, 3, 97, 128, "native"),  # gpt2xl_chat
     (8, 16, 128, 2, 17, 1024, "int8"),  # 8 of 16 heads a step
-], ids=["cgpt1b3_batchgen", "gpt2xl_doc", "gpt2xl_chat", "int8-p1024"])
+    (8, 12, 32, 4, 33, 128, "native"),  # a row under a lane tile
+], ids=["cgpt1b3_batchgen", "gpt2xl_doc", "gpt2xl_chat", "int8-p1024",
+        "hd32-transposed"])
 def test_folded_paged_decode_compiles_for_v5e(
     as_tpu, one_chip, no_persistent_cache, shape, split
 ):
     """Mosaic's own compile of the folded decode kernel (the lowering
     above stops before it): the block of every head that
-    ``decode_heads_per_step`` derives fits the scoped VMEM of a v5e,
-    head_dim 64 reads its fused row whole and emits the accumulator's
-    upper lanes, and the operation keeps the name the benchmark's
-    readers sum (``_paged_impl``)."""
+    ``decode_heads_per_step`` derives, in the two buffers of the walk's
+    ``decode_pages_per_step``, fits the scoped VMEM of a v5e, head_dim
+    64 reads its fused row whole and emits the accumulator's upper
+    lanes, and the operation keeps the name the benchmark's readers sum
+    (``_paged_impl``). Unsplit, the kernel WALKS: the grid is the rows,
+    every plane of the pool is handed to the call once, whole and
+    where it lives (a blocked operand pinned to a memory space fails
+    here), and the compiled program holds no copy or relayout of it; a
+    row under a lane tile reaches the call through a bitcast."""
     b, kvh, hd, pps, npages, page, dtype = shape
 
     def on_chip(shape, dt=jnp.bfloat16):
@@ -458,13 +519,43 @@ def test_folded_paged_decode_compiles_for_v5e(
             on_chip((npages, kvh, page, 1), jnp.float32),
             on_chip((npages, kvh, page, 1), jnp.float32),
         )
-    text = jax.jit(
-        lambda q, kv, t, i, vf: paged_attention(q, kv, t, i, vf, split=split)
-    ).lower(
+    args = (
         on_chip((b, kvh, 1, hd)), kv, on_chip((b, pps), jnp.int32),
         on_chip((b,), jnp.int32), on_chip((b,), jnp.int32),
-    ).compile().as_text()
-    assert re.search(r"%_paged_impl[.\d]* = .*tpu_custom_call", text)
+    )
+
+    def attend(q, kv, t, i, vf):
+        return paged_attention(q, kv, t, i, vf, split=split)
+
+    text = jax.jit(attend).lower(*args).compile().as_text()
+    call = re.search(r"%_paged_impl[.\d]* = .*tpu_custom_call.*", text)
+    assert call
+    if split != 1:
+        return
+    books = kernel_dispatch_stats()["paged_decode"]
+    jaxpr = jax.make_jaxpr(attend)(*args)
+    (eqn,) = _pallas_calls(jaxpr.jaxpr)
+    assert "memory_space_constraint" not in str(jaxpr)
+    mapping = eqn.params["grid_mapping"]
+    assert mapping.grid == (b, kvh // books["heads_per_step"])
+    assert books["grid_steps"] == np.prod(mapping.grid)
+    planes = [
+        str(m.transformed_block_aval) for m in mapping.block_mappings
+        if "any" in str(m.transformed_block_aval)
+    ]
+    assert len(planes) == len(jax.tree.leaves(kv)), planes
+    row = (page, 2 * hd) if hd >= 64 else (2 * hd, page)
+    held = ",".join(map(str, (npages, kvh) + row))
+    assert f"[{held}]" in planes[0]  # the whole plane, not a block of it
+    constraints = call[0].split("operand_layout_constraints=")[1]
+    assert constraints.split("frontend_attributes")[0].count(f"[{held}]") == 1
+    for plane in jax.tree.leaves(kv):
+        assert _pool_copies(text, plane.shape) == (0, 0)
+    assert _pool_copies(text, (npages, kvh) + row) == (0, 0)
+    if hd < 64:
+        assert re.search(
+            rf"\[{held}\]\S* bitcast\(", text
+        ), "the swapped view of the plane is no longer a bitcast"
 
 
 @pytest.mark.parametrize("cell", sorted(_CHUNK_CELL_SHAPES))

@@ -8,6 +8,8 @@ callers share), and the ``ContinuousBatcher`` emitting token-for-token
 what ``generate()`` emits for each request alone — including under a
 pool small enough to force requests to wait for pages."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -578,6 +580,171 @@ def test_folded_paged_kernel_matches_oracle(shape, pool, split, ragged_left):
         np.asarray(out)[live], np.asarray(ref)[live], rtol=2e-5, atol=2e-5
     )
     assert np.isfinite(np.asarray(out)).all()  # the dead row: finite
+
+
+# The walk: the decode kernel's grid is (slots, head blocks) and a row's
+# LIVE pages are walked inside it, ``pages`` an iteration. A case is
+# (kv_heads, g, head_dim, pages a slot, each row's context in positions
+# held (0: a dead row; a function is given the pages an iteration the
+# entry point derives at these widths), the layer's window or None, and
+# (heads, pages) where the case drives ``_paged_impl`` itself: the
+# public entry takes every head that fits a step, so only there does
+# the look-ahead cross a head block).
+_WALK_CASES = {
+    "dead-rows-first": (4, 3, 128, 4, [0, 0, 300, 129], None, None),
+    "dead-rows-last": (4, 3, 128, 4, [300, 129, 0, 0], None, None),
+    "dead-rows-between": (8, 3, 64, 4, [300, 0, 0, 129, 0, 512], None, None),
+    "all-dead-but-one": (4, 3, 128, 4, [0, 0, 0, 200, 0], None, None),
+    "all-dead": (4, 3, 128, 4, [0, 0, 0], None, None),
+    "one-live-position": (8, 3, 64, 4, [1, 0, 1], None, None),
+    "window-2-pages-of-15": (
+        4, 8, 128, 15, [1920, 700, 129, 60, 0, 1500], 128, None,
+    ),
+    "window-3-pages-of-15": (
+        4, 8, 128, 15, [1920, 1000, 300, 257, 0, 140], 258, None,
+    ),
+    "window-wider-than-an-iteration": (
+        2, 2, 128, 15, [1920, 1300, 0, 1281], 1200, None,
+    ),
+    "a-page-over-and-under-an-iteration": (
+        2, 3, 128, 12,
+        [lambda P: (P + 1) * 128, lambda P: (P - 1) * 128,
+         lambda P: P * 128, lambda P: P * 128 + 1], None, None,
+    ),
+    "whole-iterations-to-the-tables-end": (
+        4, 3, 64, lambda P: 2 * P,
+        [lambda P: 2 * P * 128, lambda P: P * 128, lambda P: 2 * P * 128],
+        None, None,
+    ),
+    "table-ends-mid-iteration": (
+        4, 3, 64, lambda P: P + P // 2,
+        [lambda P: (P + P // 2) * 128, lambda P: (P + 1) * 128,
+         lambda P: (P + P // 2) * 128 - 5], None, None,
+    ),
+    "one-row-live": (4, 3, 128, 4, [385], None, None),
+    "one-row-dead": (4, 3, 128, 4, [0], None, None),
+    "head-blocks-2-pages-1": (
+        4, 2, 64, 4, [300, 0, 129, 512, 0], None, (2, 1),
+    ),
+    "head-blocks-2-pages-2": (
+        4, 2, 64, 5, [0, 300, 0, 640, 129, 0], None, (2, 2),
+    ),
+    "head-blocks-4-pages-4-window": (
+        4, 1, 128, 9, [1152, 0, 600, 1, 0], 300, (1, 4),
+    ),
+    # (widths at which an iteration covers 2 pages: every page more is
+    # a body more for the interpreter to compile)
+    "hd32-g5": (16, 5, 32, 6, [700, 0, 128, 40], None, None),
+    "hd64-g5": (8, 5, 64, 6, [700, 0, 128, 40], None, None),
+    "hd128-g5": (4, 5, 128, 6, [700, 0, 128, 40], None, None),
+    "hd32-g8": (16, 8, 32, 6, [768, 0, 257, 40], 200, None),
+    "hd64-g8": (8, 8, 64, 6, [768, 0, 257, 40], None, None),
+    "hd128-g8": (4, 8, 128, 6, [768, 0, 257, 40], 200, None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_WALK_CASES))
+def test_paged_walk_reads_live_pages_only(case, monkeypatch):
+    """The walk against the gather oracle with NaN written on EVERY
+    page no slot owns, the trash page (every dead table entry's)
+    among them: a dead page that reaches a product, or a live one
+    skipped, shows. A dead page copied and never scored shows nowhere
+    in the result, so the copies the kernel STARTS are counted (the
+    interpreter runs a callback placed in ``start``): one a live page
+    and head block, not one more. Dead rows get zeros. The books say
+    what engaged."""
+    import sys
+
+    from adapt_tpu.ops.dispatch import kernel_dispatch_stats
+    from adapt_tpu.ops.paged_attention import (
+        decode_heads_per_step,
+        decode_pages_per_step,
+    )
+
+    # (the package re-exports the function under the module's name)
+    pa = sys.modules["adapt_tpu.ops.paged_attention"]
+    started = []
+    make_copy = pa.pltpu.make_async_copy
+
+    class Counted:
+        def __init__(self, *refs):
+            self.copy = make_copy(*refs)
+
+        def start(self):
+            jax.debug.callback(lambda: started.append(1))
+            self.copy.start()
+
+        def wait(self):
+            self.copy.wait()
+
+    monkeypatch.setattr(pa.pltpu, "make_async_copy", Counted)
+    # (the jitted entry may hold a trace from before the patch, and
+    # jit's cache goes by the function: a fresh one a case)
+    impl = pa._paged_impl.__wrapped__
+
+    @functools.partial(jax.jit, static_argnames=("heads", "split", "pages"))
+    def _paged_impl(*operands, heads=1, split=1, pages=1):
+        return impl(*operands, heads=heads, split=split, pages=pages)
+
+    monkeypatch.setattr(pa, "_paged_impl", _paged_impl)
+
+    kvh, g, hd, pps, ctx, window, forced = _WALK_CASES[case]
+    page = 128
+    geometry = (page, 2 * hd, 4, False, g + (-g) % 8, hd)
+
+    def derived(pps):
+        return decode_pages_per_step(
+            pps, decode_heads_per_step(kvh, *geometry), *geometry
+        )
+
+    if callable(pps):  # so many iterations of the derived P
+        pps = pps(derived(64))
+    pages = forced[1] if forced else derived(pps)
+    if not forced:
+        assert pages > 1  # these blocks are thin: the groups are driven
+    ctx = np.asarray([c(pages) if callable(c) else c for c in ctx])
+    assert ctx.max() <= pps * page
+    b = len(ctx)
+    index = ctx - 1
+    vf = None if window is None else np.maximum(ctx - window, 0)
+    first = np.zeros(b, int) if vf is None else vf // page
+    live = np.where(ctx > 0, index // page - first + 1, 0)
+    npages = int(live.sum()) + 3
+    rs = np.random.RandomState(len(case))
+    owned = 1 + rs.permutation(npages - 1)
+    table = np.zeros((b, pps), np.int32)  # dead entries: the trash page
+    at = np.concatenate([[0], np.cumsum(live)])
+    for s in range(b):
+        table[s, first[s]:first[s] + live[s]] = owned[at[s]:at[s + 1]]
+    unowned = np.ones(npages, bool)
+    unowned[owned[:at[-1]]] = False
+    key = jax.random.PRNGKey(len(case))
+    q = jax.random.normal(key, (b, kvh, g, hd))
+    clean = _pool(key, npages, kvh, page, hd)
+    pool = jnp.where(
+        jnp.asarray(unowned)[:, None, None, None], jnp.nan, clean
+    )
+    table, idx = jnp.asarray(table), jnp.asarray(index, jnp.int32)
+    vf = None if vf is None else jnp.asarray(vf, jnp.int32)
+    # the oracle gathers whole windows (0 x NaN is NaN): the clean pool
+    ref = np.asarray(paged_attention_reference(q, clean, table, idx, vf))
+    if forced:
+        out = _paged_impl(
+            q, pool, None, None, table, idx, vf, heads=forced[0],
+            split=1, pages=pages,
+        )
+    else:
+        out = paged_attention(q, pool, table, idx, vf, prefer="pallas")
+        books = kernel_dispatch_stats()["paged_decode"]
+        assert books["pages_per_step"] == pages
+        assert books["grid_steps"] == b * (kvh // books["heads_per_step"])
+    out = np.asarray(out)
+    jax.effects_barrier()
+    heads = forced[0] if forced else kvh
+    assert len(started) == live.sum() * (kvh // heads)
+    rows = ctx > 0
+    np.testing.assert_allclose(out[rows], ref[rows], rtol=2e-5, atol=2e-5)
+    assert not out[~rows].any()  # a dead row reads nothing: zeros
 
 
 #: kv_heads, page, the fused row's width (2 x head_dim), itemsize, scale
