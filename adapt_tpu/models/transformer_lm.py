@@ -54,7 +54,7 @@ from adapt_tpu.models.mla import LatentSelfAttention, LatentSpec
 from adapt_tpu.models.moe import ExpertSpec, MoEDecoderMlp, RoutedExperts
 from adapt_tpu.models.rope import apply_rope
 from adapt_tpu.models.ssm import Mamba2Mixer, SsmSpec, scaled
-from adapt_tpu.ops.quantize import quantize_kv_vectors, unpack_int4
+from adapt_tpu.ops.quantize import LANES, quantize_kv_vectors, unpack_int4
 
 _NEG_INF = -1e30
 
@@ -1148,18 +1148,31 @@ class TokenEmbed(nn.Module):
     #: layer (the drift that flips a router near a tie, PERF.md
     #: section 6, PR 43); a sub-layer still computes in ``dtype``.
     streams: int = 1
+    #: Lanes a row of the two tables is HELD with (unset: ``dim``): a
+    #: holder that keeps its copy of the tables padded to whole lane
+    #: tiles (:func:`lane_tiled`) says so here; every lookup gathers the
+    #: held row and keeps its first ``dim`` lanes.
+    table_dim: int | None = None
 
     def setup(self):
-        self.tok = nn.Embed(self.vocab, self.dim, dtype=self.dtype)
+        held = self.table_dim or self.dim
+        self.tok = nn.Embed(self.vocab, held, dtype=self.dtype)
         if self.use_pos:
             self.pos = self.param(
                 "pos_embed",
                 nn.initializers.normal(0.02),
-                (self.max_len, self.dim),
+                (self.max_len, held),
                 jnp.float32,
             )
 
-    def _expand(self, out):
+    def _lookup(self, ids, pos_rows):
+        """The one lookup: the tokens' rows, cut to ``dim`` BEFORE scale,
+        positions (``pos_rows(table)``: the rows of the position table
+        the caller's schedule wants) and streams."""
+        out = scaled(self.tok(ids)[..., : self.dim], self.scale)
+        if self.use_pos:
+            p = pos_rows(self.pos)[..., : self.dim]
+            out = out + p.astype(self.dtype)
         if self.streams == 1:
             return out
         return jnp.broadcast_to(
@@ -1168,28 +1181,60 @@ class TokenEmbed(nn.Module):
         )
 
     def __call__(self, ids):
-        s = ids.shape[1]
-        out = scaled(self.tok(ids), self.scale)
-        if self.use_pos:
-            out = out + self.pos[:s].astype(self.dtype)
-        return self._expand(out)
+        return self._lookup(ids, lambda pos: pos[: ids.shape[1]])
 
     def embed_at(self, ids_t, index):
         """Embed a single token column at traced position ``index``."""
-        out = scaled(self.tok(ids_t), self.scale)
-        if self.use_pos:
-            p = lax.dynamic_slice(self.pos, (index, 0), (1, self.dim))
-            out = out + p.astype(self.dtype)
-        return self._expand(out)
+        return self._lookup(
+            ids_t,
+            lambda pos: lax.dynamic_slice(pos, (index, 0), (1, self.dim)),
+        )
 
     def embed_positions(self, ids, pos_ids):
         """Embed with explicit per-row position ids (ragged batches:
         a left-padded row's logical positions start at 0 at its first
         real token, not at buffer column 0)."""
-        out = scaled(self.tok(ids), self.scale)
-        if self.use_pos:
-            out = out + self.pos[jnp.clip(pos_ids, 0)].astype(self.dtype)
-        return self._expand(out)
+        return self._lookup(ids, lambda pos: pos[jnp.clip(pos_ids, 0)])
+
+
+def lane_tiled(embed: TokenEmbed) -> TokenEmbed:
+    """``embed`` for a holder that gathers ROWS from its tables program
+    after program: where ``dim`` is not a whole number of lane tiles the
+    device's default layout puts a table's LONG axis on the lanes (50257
+    pads by 0.09%, rows of 1600 would pad by 4%), and every program that
+    looks a row up first rewrites the whole table row-major (GPT-2-XL:
+    322 MB a program, PERF.md section 6, PR 47). Rows held padded to the
+    next tile lie row-major and are gathered as they lie. Whole-tile
+    rows: ``embed`` itself."""
+    pad = -embed.dim % LANES
+    return embed.clone(table_dim=embed.dim + pad) if pad else embed
+
+
+@partial(jax.jit, static_argnums=1)
+def _rows_at(tables, held: int):
+    """Every table of the tree with rows of ``held`` lanes: cut, or
+    padded with zeros. ONE program for both tables (a start-up pays a
+    compile each)."""
+
+    def fit(table):
+        have = table.shape[-1]
+        if have > held:
+            return table[:, :held]
+        return jnp.pad(table, ((0, 0), (0, held - have)))
+
+    return jax.tree.map(fit, tables)
+
+
+def embed_tables_for(embed: TokenEmbed, variables):
+    """The graph's ``variables`` with the embedding tables' rows at the
+    width ``embed`` holds them with: padded (the model's tree to a
+    :func:`lane_tiled` module's) or cut (the way back). Only the
+    ``embed`` entry is rebuilt; at that width already, ``variables``
+    itself."""
+    held = embed.table_dim or embed.dim
+    if jax.tree.leaves(variables["embed"])[0].shape[-1] == held:
+        return variables
+    return {**variables, "embed": _rows_at(variables["embed"], held)}
 
 
 class LMHead(nn.Module):

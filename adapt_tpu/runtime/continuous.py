@@ -294,6 +294,8 @@ from adapt_tpu.models.ssm import zero_state
 from adapt_tpu.models.transformer_lm import (
     TransformerLM,
     chosen_logprob,
+    embed_tables_for,
+    lane_tiled,
     nucleus_filter,
     validate_tp,
 )
@@ -806,7 +808,6 @@ class ContinuousBatcher:
             prompt_buckets.append(lm.max_len)
         self.prompt_buckets = tuple(sorted(prompt_buckets))
         g = lm.graph
-        self._embed = g.node("embed").module
         self._head = g.node("head").module
         self._blocks = [g.node(n).module for n in lm.block_names]
         #: CACHE GROUPS (``runtime/paged.cache_groups``): blocks with the
@@ -3479,10 +3480,10 @@ class ContinuousBatcher:
             new_mesh = Mesh(np.asarray(new_devices), (axis,))
             repl = NamedSharding(new_mesh, P())
             kv_sh = kv_head_sharding(new_mesh, axis)
-            self.variables = jax.device_put(
-                self.variables,
+            self._served = jax.device_put(
+                self._served,
                 tree_shardings(
-                    self.variables, new_mesh,
+                    self._served, new_mesh,
                     rules=partial(lm_tp_rules, axis=axis),
                 ),
             )
@@ -3494,7 +3495,7 @@ class ContinuousBatcher:
             new_mesh = None
             repl = SingleDeviceSharding(new_devices[0])
             kv_sh = repl
-            self.variables = jax.device_put(self.variables, repl)
+            self._served = jax.device_put(self._served, repl)
         # Live-state migration: KV on the head axis per the plan;
         # replicated members from a surviving replica.
         self._caches = plan.migrate_tree(self._caches, kv_sh)
@@ -4435,7 +4436,7 @@ class ContinuousBatcher:
                 first, first_lp, self._caches, _ = self._prefill_suffix_fn(
                     sbucket, n_pad
                 )(
-                    self.variables,
+                    self._served,
                     self._caches,
                     self._h2d(np.asarray(pages, np.int32)),
                     self._h2d(ids),
@@ -4459,7 +4460,7 @@ class ContinuousBatcher:
                 # (and never gives back) the rest of the bucket.
                 self._hold_groups(i, s0, s0)
                 first, first_lp, kvs, carried = self._prefill_fn(bucket)(
-                    self.variables,
+                    self._served,
                     self._h2d(ids),
                     self._h2d(np.array([s0, req.top_k], np.int32)),
                     self._h2d(np.array(
@@ -4810,7 +4811,7 @@ class ContinuousBatcher:
              self._states) = self._prefill_suffix_fn(
                 cbucket, n_pad, sample=final
             )(
-                self.variables,
+                self._served,
                 self._caches,
                 self._pages_of(slot.idx, n_strip, n_pad),
                 self._h2d(ids),
@@ -4938,7 +4939,7 @@ class ContinuousBatcher:
         t_ph = eo.now() if eo.enabled else 0.0
         t_verify = tracer.now() if tracer.enabled else 0.0
         toks, lps, acc, self._caches, self._dstate = self._spec_verify(
-            self.variables,
+            self._served,
             self._caches,
             self._dstate,
             dtoks,
@@ -5176,7 +5177,7 @@ class ContinuousBatcher:
                         ))
                 (toks, lps, self._caches, self._dstate,
                  moe, self._states) = self._step_chunk(
-                    self.variables,
+                    self._served,
                     self._caches,
                     self._dstate,
                     self._current_table(),
@@ -5407,6 +5408,30 @@ class ContinuousBatcher:
             return None
         return self._capacity.book()
 
+    @property
+    def variables(self):
+        """The MODEL's tree, as placed: what the graph's own modules, a
+        ``PrefillWorker`` or a reference apply, and what a caller sets.
+        The engine's programs take ``_served``: the same tree where a
+        row of the embedding tables is whole lane tiles, else the tables
+        padded to the next tile, once, when the tree is set (so no
+        program rewrites the token table row-major before its gather:
+        ``transformer_lm.lane_tiled``), applied by ``_embed``, the
+        module that knows the width; this cuts them back."""
+        if self._served is None:
+            return None
+        return embed_tables_for(
+            self.lm.graph.node("embed").module, self._served
+        )
+
+    @variables.setter
+    def variables(self, tree):
+        # None: a caller dropping the weights before it builds the next.
+        self._embed = lane_tiled(self.lm.graph.node("embed").module)
+        self._served = (
+            None if tree is None else embed_tables_for(self._embed, tree)
+        )
+
     def stats(self) -> dict:
         """Serving observability snapshot: slot occupancy, queue depth,
         and THIS batcher's lifetime admit/complete/tick counts
@@ -5437,6 +5462,13 @@ class ContinuousBatcher:
                 # committed-token counters for a prefill/decode
                 # tokens-per-second split.
                 "prefill_tokens": self._prefill_tokens,
+                # Lanes added to a row of the embedding tables this
+                # engine holds (``variables``): 0 where the model's
+                # rows are whole lane tiles.
+                "embed_row_pad": (
+                    (self._embed.table_dim or self._embed.dim)
+                    - self._embed.dim
+                ),
                 # Host->device staging transfers this batcher issued
                 # (every jnp.asarray in this module funnels through
                 # _h2d): the fused-staging contract is ZERO per
@@ -5655,7 +5687,7 @@ class ContinuousBatcher:
             return self._roofline_costs
         av = jax.tree.map(
             lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
-            (self.variables, self._caches, self._dstate, self._states),
+            (self._served, self._caches, self._dstate, self._states),
         )
         a_vars, a_caches, a_dstate, a_states = av
         a_table = jax.ShapeDtypeStruct(

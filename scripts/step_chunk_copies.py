@@ -8,7 +8,8 @@ Builds the cell's ``ContinuousBatcher`` as ``chipbench/lm_engine.py``
 does (same model, slots and pool pages), lowers the batcher's own
 ``_step_chunk`` on ``ShapeDtypeStruct``s, compiles it, and counts the
 ``copy`` / ``copy-start`` operations of the pool's shape in
-``compiled.as_text()`` by result layout; then does the same for every
+``compiled.as_text()`` by result layout, and those of the token and the
+position table's shape; then does the same for every
 chunked-prefill pass the cell's longest prompt takes (the batcher's
 ``_prefill_suffix_fn``; where the traffic prefills whole prompts, its
 one ``_prefill_fn`` program instead). On the chip it compiles for the attached device; ``--describe`` compiles for a described v5e from
@@ -101,7 +102,7 @@ def main(argv=None) -> int:
         return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
 
     a_vars, a_caches, a_dstate, a_states = jax.tree.map(
-        abstract, (srv.variables, srv._caches, srv._dstate, srv._states)
+        abstract, (srv._served, srv._caches, srv._dstate, srv._states)
     )
     a_table = abstract(
         jax.ShapeDtypeStruct(
@@ -111,25 +112,28 @@ def main(argv=None) -> int:
     if len(srv._groups) > 1:  # a page table a cache group
         a_table = (a_table,) * len(srv._groups)
     planes = jax.tree.leaves(srv._caches)
-    dims = re.escape(",".join(map(str, planes[0].shape)))
-    buf = r"\w+\[" + dims + r"\](\{[^}]*\})"
-    sync = re.compile(r"= " + buf + r" copy\(")
-    start = re.compile(r"= \(" + buf + ", " + buf + r".*\) copy-start\(")
+    # The embedding tables as the engine holds them (a row padded to
+    # whole lane tiles where the model's is not: PERF.md section 6, PR 47).
+    tables = {
+        "/".join(str(k.key) for k in path[1:]): leaf.shape
+        for path, leaf in jax.tree_util.tree_leaves_with_path(
+            a_vars["embed"]
+        )
+    }
 
-    def tiles(layout):
-        return re.sub(r"S\(\d+\)", "", layout)
+    def copies_of(text, shape):
+        """As tests/conftest.pool_copies, by kind: a ``copy``, or a
+        ``copy-start`` between two layouts, relays a buffer of ``shape``
+        out; a ``copy-start`` between equal layouts stages it through
+        fast memory (``S(1)``) as it is."""
+        dims = re.escape(",".join(map(str, shape)))
+        buf = r"\w+\[" + dims + r"\](\{[^}]*\})"
+        sync = re.compile(r"= " + buf + r" copy\(")
+        start = re.compile(r"= \(" + buf + ", " + buf + r".*\) copy-start\(")
 
-    where = (
-        "described v5e, no chip" if args.describe
-        else jax.devices()[0].device_kind
-    )
+        def tiles(layout):
+            return re.sub(r"S\(\d+\)", "", layout)
 
-    def report(tag, what, compiled):
-        text = compiled.as_text()
-        # As tests/test_chip_lowering._pool_copies: a ``copy``, or a
-        # ``copy-start`` between two layouts, relays the plane out; a
-        # ``copy-start`` between equal layouts stages it through fast
-        # memory (``S(1)``) as it is.
         kinds: dict[str, int] = {}
         for line in text.splitlines():
             if m := sync.search(line):
@@ -143,6 +147,16 @@ def main(argv=None) -> int:
             else:
                 continue
             kinds[key] = kinds.get(key, 0) + 1
+        return kinds
+
+    where = (
+        "described v5e, no chip" if args.describe
+        else jax.devices()[0].device_kind
+    )
+
+    def report(tag, what, compiled):
+        text = compiled.as_text()
+        kinds = copies_of(text, planes[0].shape)
         total = sum(kinds.values())
         print(
             f"{tag} {args.cell}: pool {planes[0].shape} x {len(planes)} "
@@ -154,6 +168,15 @@ def main(argv=None) -> int:
         )
         for key, n in sorted(kinds.items()):
             print(f"    {n:4d} x {key}", flush=True)
+        for name, shape in tables.items():
+            kinds = copies_of(text, shape)
+            relayouts = sum(
+                n for key, n in kinds.items() if key.startswith("relayout")
+            )
+            print(
+                f"    table {name} {shape}: relayouts {relayouts}, "
+                f"moves {sum(kinds.values()) - relayouts}", flush=True,
+            )
         return text
 
     text = report(

@@ -159,6 +159,117 @@ def greedy_by_full_forward(lm, variables, prompt, steps: int):
     return np.asarray(ids)[:, s0:]
 
 
+def logits_by_cached_decode(lm, variables, ids, s0: int, quant=False):
+    """Teacher-forced incremental decoding, the other side of the cached
+    decode parity tests: prefill ``ids[:, :s0]``, then every further
+    column through ``embed_at`` + ``decode_step``. Returns the prefill's
+    logits (b, s0, V), the steps' (b, s - s0, V) and the prefill's caches,
+    a (k, v) a block. TWO compiled programs, the step's position traced:
+    applied eagerly a step is some hundred programs of one operation, a
+    Python position a new set of them (13-16 s a test of 7 steps)."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    g = lm.graph
+    embed, head = g.node("embed").module, g.node("head").module
+    blocks = [g.node(n).module for n in lm.block_names]
+
+    @jax.jit
+    def prefill(variables, ids):
+        h = embed.apply(variables["embed"], ids)
+        caches = []
+        for name, block in zip(lm.block_names, blocks):
+            h, ck, cv = block.apply(
+                variables[name], h, lm.max_len, None, quant, method="prefill"
+            )
+            caches.append((ck, cv))
+        return head.apply(variables["head"], h), caches
+
+    @jax.jit
+    def step(variables, caches, ids_t, t):
+        x = embed.apply(variables["embed"], ids_t, t, method="embed_at")
+        new = []
+        for name, block, (ck, cv) in zip(lm.block_names, blocks, caches):
+            x, ck, cv = block.apply(
+                variables[name], x, ck, cv, t, None, quant,
+                method="decode_step",
+            )
+            new.append((ck, cv))
+        return head.apply(variables["head"], x)[:, 0], new
+
+    first, prefilled = prefill(variables, ids[:, :s0])
+    caches, steps = prefilled, []
+    for t in range(s0, ids.shape[1]):
+        logits, caches = step(
+            variables, caches, ids[:, t : t + 1], jnp.int32(t)
+        )
+        steps.append(np.asarray(logits))
+    return np.asarray(first), np.stack(steps, axis=1), prefilled
+
+
+# -- compiling for a described v5e (tests/test_chip_lowering.py and
+# tests/test_embed_lanes.py; neither fixture is autouse: only a test that
+# asks for the chip loads libtpu) ------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """One chip of a described v5e (the TPU compiler is installed here;
+    no chip is attached). Skips where it cannot be described."""
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 — no libtpu, or it is taken
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return jax.sharding.SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent
+    cache but cannot be read back without a chip (the next one warns):
+    keep these compiles out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def pool_copies(text, shape):
+    """``(relayouts, moves)`` of buffers of ``shape`` in a compiled
+    program's text: a ``copy`` (or a ``copy-start`` whose two layouts
+    differ) rewrites the whole buffer into another physical layout;
+    a ``copy-start`` between equal layouts is the compiler staging a
+    buffer through fast memory (``S(1)``), which it does to buffers small
+    enough to fit."""
+    dims = re.escape(",".join(map(str, shape)))
+    buf = r"\w+\[" + dims + r"\](\{[^}]*\})"
+    sync = re.compile(r"= " + buf + r" copy\(")
+    start = re.compile(r"= \(" + buf + ", " + buf + r".*\) copy-start\(")
+
+    def tiles(layout):
+        return re.sub(r"S\(\d+\)", "", layout)
+
+    relayouts = moves = 0
+    for line in text.splitlines():
+        if sync.search(line):
+            relayouts += 1
+        elif m := start.search(line):
+            if tiles(m.group(1)) == tiles(m.group(2)):
+                moves += 1
+            else:
+                relayouts += 1
+    return relayouts, moves
+
+
 def spawn_worker_proc(*cli_args: str) -> "subprocess.Popen":
     """Launch ``python -m adapt_tpu.comm.remote`` as a hermetic CPU child
     (shared by the comm and stress tests — one place owns the env recipe:

@@ -25,12 +25,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import pool_copies
 from jax import lax
 from jax.sharding import (
     Mesh,
     NamedSharding,
     PartitionSpec as P,
-    SingleDeviceSharding,
 )
 
 from adapt_tpu.models.transformer_lm import BlockSpec, DecoderBlock
@@ -440,37 +440,6 @@ def test_head_sharded_kernels_match_oracles(devices):
 # -- the pool write compiles with no relayout of the pool ----------------------
 
 
-@pytest.fixture(scope="module")
-def one_chip():
-    """One chip of a described v5e (the TPU compiler is installed here;
-    no chip is attached). Skips where it cannot be described."""
-    from jax.experimental import topologies
-
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    try:
-        topo = topologies.get_topology_desc(
-            platform="tpu", topology_name="v5e:2x2"
-        )
-    except Exception as e:  # noqa: BLE001 — no libtpu, or it is taken
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
-
-
-@pytest.fixture
-def no_persistent_cache():
-    """A compile for a described chip is written to the persistent
-    cache but cannot be read back without a chip (the next one warns):
-    keep these compiles out of it."""
-    from jax.experimental.compilation_cache import compilation_cache
-
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    yield
-    jax.config.update("jax_enable_compilation_cache", was)
-    compilation_cache.reset_cache()
-
-
 def _pallas_calls(jaxpr, found=None):
     """Every ``pallas_call`` equation of a jaxpr, nested ones too."""
     found = [] if found is None else found
@@ -550,8 +519,8 @@ def test_folded_paged_decode_compiles_for_v5e(
     constraints = call[0].split("operand_layout_constraints=")[1]
     assert constraints.split("frontend_attributes")[0].count(f"[{held}]") == 1
     for plane in jax.tree.leaves(kv):
-        assert _pool_copies(text, plane.shape) == (0, 0)
-    assert _pool_copies(text, (npages, kvh) + row) == (0, 0)
+        assert pool_copies(text, plane.shape) == (0, 0)
+    assert pool_copies(text, (npages, kvh) + row) == (0, 0)
     if hd < 64:
         assert re.search(
             rf"\[{held}\]\S* bitcast\(", text
@@ -594,33 +563,6 @@ def test_folded_paged_chunk_compiles_for_v5e(
     assert used <= (
         DECODE_STEP_VMEM_BUDGET if want > 1 else 2 * DECODE_STEP_VMEM_BUDGET
     )
-
-
-def _pool_copies(text, shape):
-    """``(relayouts, moves)`` of pool-shaped buffers in a compiled
-    program's text: a ``copy`` (or a ``copy-start`` whose two layouts
-    differ) rewrites the whole pool plane into another physical layout;
-    a ``copy-start`` between equal layouts is the compiler staging a
-    plane through fast memory (``S(1)``), which it does to planes small
-    enough to fit."""
-    dims = re.escape(",".join(map(str, shape)))
-    buf = r"\w+\[" + dims + r"\](\{[^}]*\})"
-    sync = re.compile(r"= " + buf + r" copy\(")
-    start = re.compile(r"= \(" + buf + ", " + buf + r".*\) copy-start\(")
-
-    def tiles(layout):
-        return re.sub(r"S\(\d+\)", "", layout)
-
-    relayouts = moves = 0
-    for line in text.splitlines():
-        if sync.search(line):
-            relayouts += 1
-        elif m := start.search(line):
-            if tiles(m.group(1)) == tiles(m.group(2)):
-                moves += 1
-            else:
-                relayouts += 1
-    return relayouts, moves
 
 
 _LAYERS = 2
@@ -725,7 +667,7 @@ def test_pool_write_compiles_without_pool_relayout(
     ).compile()
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= _LAYERS
-    assert _pool_copies(text, shape) == (0, 0)
+    assert pool_copies(text, shape) == (0, 0)
     # And no row loop: neither deployment's plane takes
     # ``append_kv_paged``'s narrow arm (one pool-shaped
     # ``dynamic-update-slice`` a token, 4-5 us each on a v5e).
@@ -746,8 +688,8 @@ def test_pool_copies_counts_what_the_scatter_cost():
   %copy.3 = bf16[8,25,1,64]{3,0,1,2:T(8,128)(2,1)} copy(%x)
   %dus = bf16[57,25,128,64]{2,3,1,0:T(8,128)(2,1)} dynamic-update-slice(%a, %copy.3, %i, %z, %j, %z)
 """
-    assert _pool_copies(text, (57, 25, 128, 64)) == (3, 1)
-    assert _pool_copies(text, (8, 25, 1, 64)) == (1, 0)
+    assert pool_copies(text, (57, 25, 128, 64)) == (3, 1)
+    assert pool_copies(text, (8, 25, 1, 64)) == (1, 0)
 
 
 # -- compile cache placement --------------------------------------------------
@@ -870,8 +812,13 @@ def test_step_chunk_with_a_table_a_group_lowers(as_tpu):
     assert [g.name for g in srv._groups] == ["full", "window"]
     tables = srv._current_table()
     assert isinstance(tables, tuple) and len(tables) == 2
+    # Rows of 256 are whole lane tiles: the engine holds the model's own
+    # tree and applies the graph's own embedding (tests/test_embed_lanes).
+    assert srv.stats()["embed_row_pad"] == 0
+    assert srv._served is variables and srv.variables is variables
+    assert srv._embed is lm.graph.node("embed").module
     text = type(srv)._step_chunk.trace(
-        srv, srv.variables, srv._caches, srv._dstate, tables,
+        srv, srv._served, srv._caches, srv._dstate, tables,
         truncate=False, nucleus=False, epoch=0,
     ).lower(lowering_platforms=("tpu",)).as_text()
     srv.close()
@@ -1028,7 +975,7 @@ def test_step_chunk_with_recurrent_state_lowers(as_tpu):
     )
     srv = ContinuousBatcher(lm, variables, slots=8, chunk=2, page_size=128)
     text = type(srv)._step_chunk.trace(
-        srv, srv.variables, srv._caches, srv._dstate, srv._current_table(),
+        srv, srv._served, srv._caches, srv._dstate, srv._current_table(),
         srv._states, truncate=False, nucleus=False, epoch=0,
     ).lower(lowering_platforms=("tpu",)).as_text()
     srv.close()

@@ -160,44 +160,24 @@ def test_lm_cached_decode_matches_full_forward():
 
     lm = lm_tiny(vocab=97, max_len=32)
     ids = jax.random.randint(jax.random.PRNGKey(0), (2, 12), 0, 97)
-    variables = lm.graph.init(jax.random.PRNGKey(1), ids)
+    variables = jax.jit(lm.graph.init)(jax.random.PRNGKey(1), ids)
     full = np.asarray(logits_full(lm, variables, ids))  # (2, 12, 97)
-
-    g = lm.graph
-    embed = g.node("embed").module
-    head = g.node("head").module
-    blocks = [g.node(n).module for n in lm.block_names]
 
     # Prefill on the first 5 tokens, then feed ground-truth tokens 5..11
     # through decode_step; logits must match the full forward at every
     # position.
+    from conftest import logits_by_cached_decode
+
     s0 = 5
-    h = embed.apply(variables["embed"], ids[:, :s0])
-    caches = []
-    for name, block in zip(lm.block_names, blocks):
-        h, ck, cv = block.apply(
-            variables[name], h, lm.max_len, method="prefill"
-        )
-        caches.append([ck, cv])
-    prefill_logits = np.asarray(head.apply(variables["head"], h))
+    prefill_logits, step_logits, _ = logits_by_cached_decode(
+        lm, variables, ids, s0
+    )
     np.testing.assert_allclose(
         prefill_logits, full[:, :s0], rtol=2e-4, atol=2e-4
     )
-
-    for t in range(s0, ids.shape[1]):
-        x_t = embed.apply(
-            variables["embed"], ids[:, t : t + 1], t, method="embed_at"
-        )
-        for i, (name, block) in enumerate(zip(lm.block_names, blocks)):
-            x_t, ck, cv = block.apply(
-                variables[name], x_t, *caches[i], t, method="decode_step"
-            )
-            caches[i] = [ck, cv]
-        step_logits = np.asarray(head.apply(variables["head"], x_t))[:, 0]
-        np.testing.assert_allclose(
-            step_logits, full[:, t], rtol=2e-4, atol=2e-4,
-            err_msg=f"position {t}",
-        )
+    np.testing.assert_allclose(
+        step_logits, full[:, s0:], rtol=2e-4, atol=2e-4
+    )
 
 
 def test_lm_generate_matches_uncached_greedy():
@@ -371,41 +351,21 @@ def test_lm_generate_int8_kv_cache():
 
     lm = lm_tiny(vocab=37, max_len=24)
     prompt = jax.random.randint(jax.random.PRNGKey(30), (2, 6), 0, 37)
-    variables = lm.graph.init(jax.random.PRNGKey(31), prompt)
-
-    g = lm.graph
-    embed = g.node("embed").module
-    head = g.node("head").module
-    blocks = [g.node(n).module for n in lm.block_names]
+    variables = jax.jit(lm.graph.init)(jax.random.PRNGKey(31), prompt)
 
     # One FIXED token sequence feeds both runs (true teacher forcing):
     # a quantization-induced argmax flip must not send the two runs down
     # different decode paths, or the logits comparison is meaningless.
+    from conftest import logits_by_cached_decode
+
     forced = jax.random.randint(jax.random.PRNGKey(32), (4, 2), 0, 37)
+    ids = jnp.concatenate([prompt, forced.T], axis=1)
 
     def run(quant):
-        h = embed.apply(variables["embed"], prompt)
-        caches = []
-        for name, block in zip(lm.block_names, blocks):
-            h, ck, cv = block.apply(
-                variables[name], h, lm.max_len, None, quant,
-                method="prefill",
-            )
-            caches.append([ck, cv])
-        logits = [np.asarray(head.apply(variables["head"], h[:, -1:]))]
-        for step, t in enumerate(range(6, 10)):
-            x_t = embed.apply(
-                variables["embed"], forced[step][:, None], t,
-                method="embed_at",
-            )
-            for i, (name, block) in enumerate(zip(lm.block_names, blocks)):
-                x_t, ck, cv = block.apply(
-                    variables[name], x_t, *caches[i], t, None, quant,
-                    method="decode_step",
-                )
-                caches[i] = [ck, cv]
-            logits.append(np.asarray(head.apply(variables["head"], x_t)))
-        return np.concatenate(logits, axis=1), caches
+        first, steps, caches = logits_by_cached_decode(
+            lm, variables, ids, prompt.shape[1], quant
+        )
+        return np.concatenate([first[:, -1:], steps], axis=1), caches
 
     lg_native, _ = run(False)
     lg_int8, caches = run(True)
@@ -487,44 +447,26 @@ def test_lm_gqa_cached_decode_matches_full_forward(kv_heads):
     vocab = 47
     lm = _gqa_lm(vocab=vocab, heads=4, kv_heads=kv_heads)
     ids = jax.random.randint(jax.random.PRNGKey(40), (2, 10), 0, vocab)
-    variables = lm.graph.init(jax.random.PRNGKey(41), ids)
+    variables = jax.jit(lm.graph.init)(jax.random.PRNGKey(41), ids)
     full = np.asarray(logits_full(lm, variables, ids))
 
-    g = lm.graph
-    embed = g.node("embed").module
-    head = g.node("head").module
-    blocks = [g.node(n).module for n in lm.block_names]
+    from conftest import logits_by_cached_decode
 
     s0 = 4
-    h = embed.apply(variables["embed"], ids[:, :s0])
-    caches = []
-    for name, block in zip(lm.block_names, blocks):
-        h, ck, cv = block.apply(
-            variables[name], h, lm.max_len, method="prefill"
-        )
+    prefill_logits, step_logits, caches = logits_by_cached_decode(
+        lm, variables, ids, s0
+    )
+    for ck, cv in caches:
         # The whole point of GQA: the cache head axis is kv_heads, not
         # heads — 4/kv_heads x less HBM per decoded context.
-        assert ck.shape == (2, kv_heads, lm.max_len, 32 // 4)
-        caches.append([ck, cv])
-    prefill_logits = np.asarray(head.apply(variables["head"], h))
+        assert ck.shape == cv.shape == (2, kv_heads, lm.max_len, 32 // 4)
     np.testing.assert_allclose(
         prefill_logits, full[:, :s0], rtol=2e-4, atol=2e-4
     )
-
-    for t in range(s0, ids.shape[1]):
-        x_t = embed.apply(
-            variables["embed"], ids[:, t : t + 1], t, method="embed_at"
-        )
-        for i, (name, block) in enumerate(zip(lm.block_names, blocks)):
-            x_t, ck, cv = block.apply(
-                variables[name], x_t, *caches[i], t, method="decode_step"
-            )
-            caches[i] = [ck, cv]
-        step_logits = np.asarray(head.apply(variables["head"], x_t))[:, 0]
-        np.testing.assert_allclose(
-            step_logits, full[:, t], rtol=2e-4, atol=2e-4,
-            err_msg=f"kv_heads={kv_heads} position {t}",
-        )
+    np.testing.assert_allclose(
+        step_logits, full[:, s0:], rtol=2e-4, atol=2e-4,
+        err_msg=f"kv_heads={kv_heads}",
+    )
 
 
 def test_lm_gqa_generate_matches_uncached_greedy():

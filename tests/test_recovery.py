@@ -142,6 +142,14 @@ def test_kill_midstream_bit_identical(lm_setup, sim_mesh, page_size):
     assert st["recovery_dropped"] == 0
     assert st["last_recovery_wall_s"] > 0.0
     assert st["cache_bytes_per_device"] * 2 == st["cache_bytes"]
+    # The embedding tables (rows of 32) were re-placed as the engine
+    # holds them, a whole lane tile a row; it hands out the model's.
+    assert st["embed_row_pad"] == 96
+    held, own = (
+        {t.shape[-1] for t in jax.tree.leaves(v["embed"])}
+        for v in (bat._served, bat.variables)
+    )
+    assert (held, own) == ({128}, {32})
     for i in range(3):
         np.testing.assert_array_equal(
             got[i], base[i], err_msg=f"req {i}: killed != uninterrupted"
