@@ -146,30 +146,17 @@ class KernelConfig:
     decode/verify programs lower against: ``None`` = the measured auto
     rule (``decode_kernel_wins`` / TPU-with-supported-pages), ``"xla"``
     = the einsum oracle, ``"pallas"`` = the streaming kernel (fused
-    int8/int4 dequant in VMEM). ``decode_split`` is the flash-decoding
-    split along the KV-length axis: each split streams its share of the
-    cache blocks (pages, in the paged layout) with its own
-    online-softmax state and a single-pass rescale combine reduces the
-    partials — long-context slots use the whole VPU/MXU instead of one
-    sequential stream. ``None`` auto-derives from the block count
-    (``ops.decode_attention.default_decode_split``) on real TPUs and
-    stays 1 off-TPU; 1 is the original single-stream kernel, bit-exact.
-    Which path actually serves is observable as the
-    ``engine.kernel_dispatch.<op>`` gauges
+    int8/int4 dequant in VMEM). Which path actually serves is
+    observable as the ``engine.kernel_dispatch.<op>`` gauges
     (``docs/OBSERVABILITY.md``)."""
 
     attn_impl: str | None = None
-    decode_split: int | None = None
 
     def __post_init__(self):
         if self.attn_impl not in (None, "xla", "pallas"):
             raise ValueError(
                 f"attn_impl={self.attn_impl!r}: expected None, 'xla' "
                 "or 'pallas'"
-            )
-        if self.decode_split is not None and self.decode_split < 1:
-            raise ValueError(
-                f"decode_split must be >= 1, got {self.decode_split}"
             )
 
 
@@ -924,49 +911,6 @@ class RouterConfig:
 
 
 @dataclasses.dataclass(frozen=True)
-class RuntimeConfig:
-    """Tick-runtime pipelining (``runtime/continuous.py`` "Pipelined
-    async runtime", docs/SERVING.md §3 "Async runtime").
-
-    ``pipeline_depth`` left unset (``None``, the default) resolves to
-    **2** in the batcher's constructor, whatever the model
-    (``stats()["pipeline_depth"]`` reports it): a model with several
-    cache groups grants and recycles its further groups' pages from the
-    position each row has been DISPATCHED to, which an in-flight tick
-    has moved. An explicit 1 or 2 means what it says.
-
-    ``pipeline_depth=2`` overlaps host and device: while tick *t*'s
-    programs execute on device, the host runs tick *t+1*'s scheduler
-    pass and fused admission/staging, and tick *t*'s results commit
-    one call LATER (the one-tick commit lag — EOS/stop/cancel/SLO
-    bookkeeping and ``on_token`` delivery operate on tick *t−1*'s
-    results while *t* runs; a caller of manual ``tick()`` sees a
-    request's tokens one call after the call that dispatched them, and
-    ``drain()`` is the boundary that lands what is in flight).
-    ``pipeline_depth=1`` is the synchronous loop: each ``tick()``
-    dispatches the decode/verify programs, blocks on the one-fetch
-    D2H, and commits the results before returning — what a caller that
-    asserts per-tick state asks for by name. Greedy streams stay
-    bit-identical between depths; delivery timing (TTFT/ITL stamps,
-    cancel consumption) measures commit, not device completion. Depths
-    beyond 2 buy nothing on a one-program-per-tick engine (the device
-    queue is already full with one tick in flight), so they are
-    rejected eagerly rather than silently behaving like 2."""
-
-    #: None = 2; 1 = synchronous tick loop (by name only); 2 = one
-    #: tick in flight (dispatch t while committing t-1).
-    pipeline_depth: int | None = None
-
-    def __post_init__(self):
-        if self.pipeline_depth not in (None, 1, 2):
-            raise ValueError(
-                "pipeline_depth must be 1 (synchronous), 2 (one tick "
-                "in flight) or None (2), got "
-                f"{self.pipeline_depth}"
-            )
-
-
-@dataclasses.dataclass(frozen=True)
 class ServeConfig:
     """Top-level serving configuration."""
 
@@ -1001,9 +945,6 @@ class ServeConfig:
     )
     prefill: PrefillConfig = dataclasses.field(
         default_factory=PrefillConfig
-    )
-    runtime: RuntimeConfig = dataclasses.field(
-        default_factory=RuntimeConfig
     )
     capacity: CapacityConfig = dataclasses.field(
         default_factory=CapacityConfig

@@ -209,7 +209,7 @@ class LatentSelfAttention(nn.Module):
 
     def decode_step_paged(
         self, x_t, pool, page_table, index, valid_from=None,
-        attn_impl=None, split=None, head_shard=None,
+        attn_impl=None, head_shard=None,
     ):
         """One token against the latent paged cache: write its row at
         ``index``'s (page, offset), then attend the table-mapped window
@@ -220,10 +220,6 @@ class LatentSelfAttention(nn.Module):
             latent_only("a ragged (left-padded) batch")
         if head_shard is not None:
             latent_only("a tp-partitioned decode step")
-        if split not in (None, 1):
-            raise NotImplementedError(
-                "the latent decode kernel has no flash-split form"
-            )
         lat = self.spec.latent
         b, page = x_t.shape[0], pool.shape[2]
         idx = jnp.broadcast_to(

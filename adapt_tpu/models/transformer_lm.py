@@ -547,7 +547,7 @@ class CausalSelfAttention(nn.Module):
 
     def decode_step(
         self, x_t, cache_k, cache_v, index, valid_from=None, quantized=False,
-        attn_impl=None, split=None,
+        attn_impl=None,
     ):
         """One token: write its K/V at ``index``, attend its q over the
         cache. ``index`` is traced — the same compiled step serves every
@@ -559,8 +559,7 @@ class CausalSelfAttention(nn.Module):
         :func:`adapt_tpu.ops.decode_attention.decode_attention` —
         ``attn_impl`` (None = measured auto, ``"xla"``, ``"pallas"``)
         picks between the einsum schedule and the streaming Pallas
-        kernel that dequantizes int8 caches in VMEM; ``split`` is the
-        kernel's flash-decoding KV split (``config.KernelConfig``)."""
+        kernel that dequantizes int8 caches in VMEM."""
         b = x_t.shape[0]
         q, k, v = self._project(x_t)  # q (b, h, 1, hd); k/v (b, kv_h, 1, hd)
         if self.rope:
@@ -584,7 +583,6 @@ class CausalSelfAttention(nn.Module):
         o = decode_attention(
             q, cache_k, cache_v, index,
             self._window_from(index, b, valid_from), prefer=attn_impl,
-            split=split,
         ).astype(x_t.dtype)
         o = self._ungroup_o(o, 1)  # (b, h, 1, hd)
         return self._finish(o, x_t), cache_k, cache_v
@@ -592,7 +590,7 @@ class CausalSelfAttention(nn.Module):
 
     def decode_step_paged(
         self, x_t, pool, page_table, index, valid_from=None,
-        attn_impl=None, split=None, head_shard=None,
+        attn_impl=None, head_shard=None,
     ):
         """One token against a PAGED cache (``ops/paged_attention``):
         write this step's K|V row into the slot's physical page at
@@ -640,7 +638,7 @@ class CausalSelfAttention(nn.Module):
         o = paged_attention(
             q, pool, page_table, index,
             self._window_from(index, b, valid_from), prefer=attn_impl,
-            split=split, head_shard=head_shard,
+            head_shard=head_shard,
         ).astype(x_t.dtype)
         o = self._ungroup_o(o, 1)
         return self._finish(o, x_t), pool
@@ -831,7 +829,7 @@ class CausalSelfAttention(nn.Module):
 
     def verify_chunk_paged(
         self, x, pool, page_table, index, attn_impl=None,
-        tree_tail=0, split=None, head_shard=None,
+        tree_tail=0, head_shard=None,
     ):
         """Batched verify over a PAGED cache: scatter each slot's K
         chunk tokens into its own pages at ``index[b]..index[b]+K-1``
@@ -843,8 +841,8 @@ class CausalSelfAttention(nn.Module):
         writes route to the trash page and its positions all mask.
         A quantized ``(values, k_scales, v_scales)`` pool takes the
         chunk's quantized rows into all three planes (the scale planes
-        ride the same page table). ``tree_tail``/``split`` as in
-        ``verify_chunk`` / ``decode_step_paged``."""
+        ride the same page table). ``tree_tail`` as in
+        ``verify_chunk``."""
         b, kc, _ = x.shape
         page = pool_values(pool).shape[2]
         q, k, v = self._project(x)  # q (b, h, K, hd); k/v (b, kv_h, K, hd)
@@ -871,7 +869,7 @@ class CausalSelfAttention(nn.Module):
         pool = self._write_kv_pool(pool, k, v, write)
         o = paged_verify_attention(
             q, pool, page_table, idx, kc, prefer=attn_impl,
-            window=self.window, tree_tail=tree_tail, split=split,
+            window=self.window, tree_tail=tree_tail,
             head_shard=head_shard,
         ).astype(x.dtype)
         o = self._ungroup_o(o, kc)
@@ -1058,17 +1056,17 @@ class DecoderBlock(nn.Module):
 
     def decode_step(
         self, x_t, cache_k, cache_v, index, valid_from=None, quantized=False,
-        attn_impl=None, split=None,
+        attn_impl=None,
     ):
         self._no_state("decode_step over dense cache strips")
         return self._mixers(x_t, lambda u: self.attn.decode_step(
-            u, cache_k, cache_v, index, valid_from, quantized, attn_impl,
-            split,
+            u, cache_k, cache_v, index, valid_from, quantized,
+            attn_impl=attn_impl,
         ))
 
     def decode_step_paged(
         self, x_t, pool, page_table, index, valid_from=None,
-        attn_impl=None, split=None, head_shard=None, carried=None,
+        attn_impl=None, head_shard=None, carried=None,
     ):
         """``carried``: the rows' recurrent ``(state, tail)`` where the
         block has a state-space mixer; advanced for the rows whose
@@ -1076,8 +1074,8 @@ class DecoderBlock(nn.Module):
         return self._mixers(
             x_t,
             lambda u: self.attn.decode_step_paged(
-                u, pool, page_table, index, valid_from, attn_impl, split,
-                head_shard,
+                u, pool, page_table, index, valid_from,
+                attn_impl=attn_impl, head_shard=head_shard,
             ),
             lambda ssm, u: ssm.step(
                 u, carried,
@@ -1115,14 +1113,14 @@ class DecoderBlock(nn.Module):
 
     def verify_chunk_paged(
         self, x, pool, page_table, index, attn_impl=None,
-        tree_tail=0, split=None, head_shard=None,
+        tree_tail=0, head_shard=None,
     ):
         self._no_state(
             "verify_chunk_paged (a rejected token cannot be un-stepped)"
         )
         return self._mixers(x, lambda u: self.attn.verify_chunk_paged(
-            u, pool, page_table, index, attn_impl, tree_tail, split,
-            head_shard,
+            u, pool, page_table, index, attn_impl=attn_impl,
+            tree_tail=tree_tail, head_shard=head_shard,
         ))
 
 

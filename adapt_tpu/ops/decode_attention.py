@@ -77,8 +77,7 @@ def default_decode_split(num_blocks: int, cores: int = 1) -> int:
     called, and a split adds a ragged step, three float32 partial
     outputs and the combine pass (timed on a v5e at the benchmark's
     three shapes, PERF.md section 6, PR 28: split 2 lost to 1 at every
-    one). Short caches stay unsplit on any device. ``config.
-    KernelConfig.decode_split`` overrides it."""
+    one). Short caches stay unsplit on any device."""
     s = 1
     while s < min(8, cores) and num_blocks >= 4 * s:
         s *= 2

@@ -73,8 +73,8 @@ cost a chunk's hundreds three times the vector work (measured on a
 v5e, PERF.md section 6, PR 42). Quantized pools keep ``_attend_tile``
 under the fold.
 
-The FLASH-SPLIT form (``split`` on every dispatcher,
-``config.KernelConfig.decode_split``) keeps the page axis on its grid,
+The FLASH-SPLIT form (``split`` on every dispatcher, which no layer
+above ``ops/`` passes) keeps the page axis on its grid,
 ``(slots, head blocks, split, pages a split)``, and lets the pipeline
 fetch: each (row, split) grid point streams its own run of the slot's
 pages with independent online-softmax scratch and emits unnormalized

@@ -283,7 +283,6 @@ from adapt_tpu.config import (
     ParallelConfig,
     PrefillConfig,
     RecoveryConfig,
-    RuntimeConfig,
     SchedulerConfig,
     SLOSpec,
     SpeculativeConfig,
@@ -318,10 +317,11 @@ from adapt_tpu.parallel.sharding import (
 from adapt_tpu.parallel.sp_prefill import SPPrefiller, build_sp_mesh
 from adapt_tpu.runtime.capacity import CapacityModel
 from adapt_tpu.runtime.paged import (
+    CACHE_PROPERTIES,
     HostKVTier,
     Pager,
     alloc_kv_pools,
-    cache_groups,
+    cache_layout,
     group_pool_pages,
     insert_prefill_pages,
     pool_geometry,
@@ -498,11 +498,9 @@ class _AsyncFetch:
 
     Construction starts the D2H copy immediately
     (``copy_to_host_async`` on every leaf), so the transfer overlaps
-    whatever host work runs between dispatch and commit — in the
-    synchronous loop that is the tracer/phase bookkeeping (the old
-    path double-synced: dispatch enqueued the programs, then
-    ``jax.device_get`` started a cold blocking copy); in the pipelined
-    loop it is the WHOLE next tick's scheduler pass and dispatch.
+    whatever host work runs between dispatch and commit: the WHOLE
+    next tick's scheduler pass and dispatch, or before a ``drain()``
+    the tracer/phase bookkeeping alone.
     ``commit()`` blocks until the copy lands and returns host numpy
     arrays (cached — commit is idempotent); ``wait_s`` records how
     long it actually blocked, which is the non-overlapped device wall
@@ -542,10 +540,57 @@ class _AsyncFetch:
         return self._host
 
 
+# Rows several features share: one that needs every property, one that
+# SHARES a prompt page between requests, one that MOVES pages of K and V.
+_ALL = dict.fromkeys(CACHE_PROPERTIES, "")
+_SHARES = {"one_group": "", "pages_only": ""}
+_MOVES = {"pages_only": "", "per_head_pages": ""}
+#: What each feature needs of the model's cache
+#: (``paged.CacheLayout.lacks``): a property it names here it cannot do
+#: without, and the value is what a refusal for THAT property says
+#: after the feature's name. A tp mesh over recurrent state is the
+#: model's to refuse (``validate_tp``).
+_CACHE_NEEDS: dict[str, dict[str, str]] = {
+    "a draft model": {
+        "one_group": " (speculative decoding)",
+        "pages_only": " (speculative decoding: a rejected token cannot "
+                      "be un-stepped)",
+        "per_head_pages": " (speculative decoding: verify_chunk_paged "
+                          "reads per-head pages)",
+    },
+    "a tp mesh": {"one_group": "", "per_head_pages": ""},
+    "a host cache tier": _ALL,
+    "sequence-parallel prefill": _ALL,
+    "cache-aware admission (the radix prefix cache)": _SHARES,
+    "elastic recovery (health=)": _MOVES,
+    "a quantized KV pool": {
+        "pages_only": " beside it (untested)", "per_head_pages": "",
+    },
+    "a handoff of prefilled pages": _ALL,
+    "the radix prefix cache": _SHARES,
+    "copy-on-write fan-out": _SHARES,
+}
+
+
+def _unmet(layout, *features: str) -> tuple[str, str, str] | None:
+    """The first thing a cache of ``layout`` cannot do that one of
+    ``features`` (rows of ``_CACHE_NEEDS``) needs, property by property
+    and then in the order given: the feature as a refusal names it and
+    ``CacheLayout.lacks``'s pair. None: all run."""
+    for prop in CACHE_PROPERTIES:
+        why = layout.lacks(prop)
+        if why is None:
+            continue
+        for feature in features:
+            if prop in _CACHE_NEEDS[feature]:
+                return (feature + _CACHE_NEEDS[feature][prop], *why)
+    return None
+
+
 @dataclasses.dataclass
 class _InFlight:
-    """One dispatched-but-uncommitted decode tick (``pipeline_depth >=
-    2``; the synchronous loop builds one and commits it immediately).
+    """One dispatched-but-uncommitted decode tick: what a ``tick()``
+    leaves for the next one (or a ``drain()``) to commit.
 
     ``reqs``/``lives`` capture per-slot BINDING IDENTITY at dispatch:
     commit applies a slot's results only when the slot still holds the
@@ -623,7 +668,6 @@ class ContinuousBatcher:
         cache_tier: CacheTierConfig | None = None,
         prefill: PrefillConfig | None = None,
         sp_mesh: Mesh | None = None,
-        runtime: RuntimeConfig | None = None,
         observability: ObservabilityConfig | None = None,
         capacity: CapacityConfig | None = None,
     ):
@@ -810,62 +854,46 @@ class ContinuousBatcher:
         g = lm.graph
         self._head = g.node("head").module
         self._blocks = [g.node(n).module for n in lm.block_names]
-        #: CACHE GROUPS (``runtime/paged.cache_groups``): blocks with the
-        #: same (window, kv_heads, head_dim) share a pool geometry, a
-        #: ``Pager`` and a page table. The FIRST group is the one
-        #: ``pool_pages=`` sizes and every request reserves whole (the
-        #: only group of a model whose blocks are all alike: today's
-        #: path, unchanged); each further group grants pages pass by
-        #: pass (``Pager.hold``) from a pool derived from what one
-        #: request can hold there, so a window layer keeps its window
-        #: and not the sequence.
         specs = [b.spec for b in self._blocks]
-        groups = cache_groups(specs)
+        #: What the blocks keep for a request (``paged.CacheLayout``).
+        #: Blocks alike in (window, kv_heads, head_dim, row) are a CACHE
+        #: GROUP with a pool geometry, a ``Pager`` and a page table: the
+        #: FIRST is the one ``pool_pages=`` sizes and every request
+        #: reserves whole (the only one where all blocks are alike);
+        #: each further group grants pages pass by pass (``Pager.hold``)
+        #: from a pool derived from what one request can hold there, so
+        #: a window layer keeps its window and not the sequence.
+        self._layout = cache_layout(specs)
+        groups = self._groups = self._layout.groups
         if not groups:
             raise ValueError(
                 "no block of this model keeps pages: the batcher's "
                 "positions, admission and preemption are the pager's, so "
                 "it serves a model with at least one attention block"
             )
-        # The group that reserves whole: the full-attention one if any.
-        groups.sort(key=lambda g: g.window is not None)
-        self._groups = groups
-        self._group_of = [0] * len(specs)
-        for gi, g in enumerate(groups):
-            for bi in g.blocks:
-                self._group_of[bi] = gi
-        if len(groups) > 1:
-            unsupported = {
-                "a draft model (speculative decoding)": draft_lm,
-                "a tp mesh": mesh,
-                "a host cache tier": cache_tier,
-                "sequence-parallel prefill": prefill,
-                "cache-aware admission (the radix prefix cache)": (
-                    scheduler is not None and scheduler.cache_aware
-                ) or None,
-            }
-            for what, given in unsupported.items():
-                if given is not None:
-                    self._one_cache_group(what)
+        self._require(*(
+            feature for feature, given in {
+                "a draft model": draft_lm is not None,
+                "a tp mesh": mesh is not None,
+                "a host cache tier": cache_tier is not None,
+                "sequence-parallel prefill": prefill is not None,
+                "cache-aware admission (the radix prefix cache)":
+                    scheduler is not None and scheduler.cache_aware,
+                "elastic recovery (health=)": health is not None,
+                "a quantized KV pool": kv_cache_dtype != "native",
+            }.items() if given
+        ))
+        #: Whether a prompt page may be shared between requests.
+        self._shares_pages = (
+            _unmet(self._layout, "the radix prefix cache") is None
+        )
         #: Blocks whose MLP is the routed-expert layer: their per-expert
         #: token counts leave ``_step_chunk`` with the step's tokens.
         self._moe_blocks = tuple(
             i for i, sp in enumerate(specs) if sp.mlp == "experts"
         )
-        #: RECURRENT STATE beside (or in place of) the pages: a block with
-        #: a state-space mixer (``BlockSpec.ssm``, ``models/ssm``) or a
-        #: linear-attention one (``BlockSpec.linear``, ``models/kda``:
-        #: such a block keeps NO pages) keeps, per SLOT, a ``(state,
-        #: tail)`` pair. Not paged: every request holds exactly
-        #: one, overwritten in every step and written WHOLE at admission
-        #: (so whatever a dead row's steps left in a retired slot never
-        #: reaches its next tenant). Device-resident and donated through
-        #: every program that advances it, like the pools.
-        self._state_blocks = tuple(
-            i for i, sp in enumerate(specs) if sp.state_spec is not None
-        )
-        #: Of those, the linear-attention ones (``kda.steps``), and the
-        #: counter families a state write is booked under.
+        #: The linear-attention blocks (``kda.steps``), and the counter
+        #: families a state write is booked under.
         self._linear_blocks = sum(
             1 for sp in specs if sp.linear is not None
         )
@@ -875,52 +903,20 @@ class ContinuousBatcher:
                 ("kda", self._linear_blocks),
             ) if has
         )
+        #: The recurrent state: not paged. Every request holds exactly
+        #: one, overwritten in every step and written WHOLE at admission
+        #: (so whatever a dead row's steps left in a retired slot never
+        #: reaches its next tenant). Device-resident and donated through
+        #: every program that advances it, like the pools.
         self._states = tuple(
             zero_state(specs[i].state_spec, slots, self._blocks[i].dtype)
-            for i in self._state_blocks
+            for i in self._layout.state_blocks
         ) or None
-        if self._state_blocks:
-            unsupported = {
-                "a draft model (speculative decoding: a rejected token "
-                "cannot be un-stepped)": draft_lm,
-                "a host cache tier": cache_tier,
-                "sequence-parallel prefill": prefill,
-                "cache-aware admission (the radix prefix cache)": (
-                    scheduler is not None and scheduler.cache_aware
-                ) or None,
-                "elastic recovery (health=)": health,
-                "a quantized KV pool beside it (untested)": (
-                    kv_cache_dtype != "native"
-                ) or None,
-            }
-            for what, given in unsupported.items():
-                if given is not None:
-                    self._pages_only(what)
-        #: LATENT blocks (``BlockSpec.latent``) keep one row a position
-        #: with no head axis: the pager, the table and the tick are
-        #: unchanged (a page is a page), and everything that moves or
-        #: shards PER-HEAD pages refuses the model by name.
-        self._latent_blocks = tuple(
-            i for i, sp in enumerate(specs) if sp.latent is not None
-        )
         #: Blocks whose residual is streams (``BlockSpec.streams``):
         #: two mixes a block and step, booked as ``mhc.mixes``.
         self._stream_blocks = sum(
             1 for sp in specs if sp.streams is not None
         )
-        if self._latent_blocks:
-            unsupported = {
-                "a draft model (speculative decoding: verify_chunk_paged "
-                "reads per-head pages)": draft_lm,
-                "a tp mesh": mesh,
-                "a host cache tier": cache_tier,
-                "sequence-parallel prefill": prefill,
-                "elastic recovery (health=)": health,
-                "a quantized KV pool": (kv_cache_dtype != "native") or None,
-            }
-            for what, given in unsupported.items():
-                if given is not None:
-                    self._per_head_pages(what)
         #: Sliding-window models: decode masking lives in the model;
         #: the batcher's job is page RECYCLING behind the window.
         self._window = groups[0].window
@@ -992,7 +988,7 @@ class ContinuousBatcher:
                 page_size, groups[gi].head_dim, block.dtype,
                 kv_cache_dtype, row=groups[gi].row,
             ) if block.spec.linear is None else None
-            for gi, block in zip(self._group_of, self._blocks)
+            for gi, block in zip(self._layout.group_of, self._blocks)
         ]
         if mesh is not None:
             # Head-sharded KV: each device holds kv_heads / tp of every
@@ -1006,7 +1002,7 @@ class ContinuousBatcher:
             self._pagers[gi].num_pages * page_size
             * groups[gi].position_values
             * jnp.dtype(block.dtype).itemsize
-            for gi, block in zip(self._group_of, self._blocks)
+            for gi, block in zip(self._layout.group_of, self._blocks)
             if block.spec.linear is None
         )
         #: Idle-row cache position: a negative sentinel that stays
@@ -1248,19 +1244,14 @@ class ContinuousBatcher:
         self.obs_timeline = True
         self._itl_pending: list[float] = []
         self._ttft_pending: list[float] = []
-        # -- pipelined tick runtime (config.RuntimeConfig) -----------------
-        # depth=1: tick() dispatches and commits synchronously (the
-        # historical loop, byte-identical scheduling). depth=2: tick()
-        # dispatches tick t, then commits tick t-1's _InFlight while t
-        # runs on device — one tick of results stays in flight between
-        # calls, drained at every pipeline boundary (run() exit,
-        # recover(), drain(), server-loop stop). Left unset, the depth
-        # is 2 whatever the model: a further cache group grants and
-        # recycles its pages pass by pass from the position each row
-        # has been DISPATCHED to (_dispatched_pos), which an in-flight
-        # tick has moved though no commit has.
-        self._runtime = runtime or RuntimeConfig()
-        self._depth = self._runtime.pipeline_depth or 2
+        # -- the tick order ------------------------------------------------
+        # tick() dispatches tick t, then commits tick t-1's _InFlight
+        # while t runs on device — one tick of results stays in flight
+        # between calls, drained at every pipeline boundary (run() exit,
+        # recover(), drain(), server-loop stop). A further cache group
+        # grants and recycles its pages pass by pass from the position
+        # each row has been DISPATCHED to (_dispatched_pos), which an
+        # in-flight tick has moved though no commit has.
         self._inflight: _InFlight | None = None
         #: SLO accounting (docs/OBSERVABILITY.md "Workload telemetry").
         #: Hot path touches only these plain ints (one attribute inc
@@ -1678,10 +1669,9 @@ class ContinuousBatcher:
             )):
                 out = block.apply(
                     variables[name], x, pool, self._table_of(table, i),
-                    pos, None,
-                    self._kernel.attn_impl,
-                    self._kernel.decode_split,
-                    self._head_shard(),
+                    pos,
+                    attn_impl=self._kernel.attn_impl,
+                    head_shard=self._head_shard(),
                     method="decode_step_paged",
                     mutable=["intermediates"] if i in self._moe_blocks
                     else False,
@@ -1758,9 +1748,9 @@ class ContinuousBatcher:
         for block ``block``: its ``(state, tail)`` among a program's
         ``states`` (one a block with recurrent state, in block order),
         nothing for a block without one."""
-        if block not in self._state_blocks:
+        if block not in self._layout.state_blocks:
             return {}
-        return {"carried": states[self._state_blocks.index(block)]}
+        return {"carried": states[self._layout.state_blocks.index(block)]}
 
     def _table_of(self, table, block: int):
         """Block ``block``'s page table (or page list) among a
@@ -1768,7 +1758,7 @@ class ContinuousBatcher:
         its group's entry of the tuple."""
         if len(self._groups) == 1:
             return table
-        return table[self._group_of[block]]
+        return table[self._layout.group_of[block]]
 
     def _moe_counts(self, moe) -> None:
         """Book one chunk's expert counts (``_step_chunk``'s last
@@ -1878,9 +1868,8 @@ class ContinuousBatcher:
         ):
             x, pool = block.apply(
                 variables[name], x, pool, table, pos,
-                self._kernel.attn_impl, w,
-                self._kernel.decode_split,
-                self._head_shard(),
+                attn_impl=self._kernel.attn_impl, tree_tail=w,
+                head_shard=self._head_shard(),
                 method="verify_chunk_paged",
             )
             new_caches.append(pool)
@@ -2123,9 +2112,7 @@ class ContinuousBatcher:
         ``ValueError`` on geometry mismatches (page size,
         quantization, block count/shapes) — a malformed handoff must
         fail by name, never scatter garbage into live pages."""
-        self._one_cache_group("a handoff of prefilled pages")
-        self._pages_only("a handoff of prefilled pages")
-        self._per_head_pages("a handoff of prefilled pages")
+        self._require("a handoff of prefilled pages")
         # The device-lost gate tick() runs: a handoff landing between
         # ticks must not device_put shard slices onto a dead device or
         # dispatch the adoption program at a stale mesh epoch (the
@@ -2492,8 +2479,7 @@ class ContinuousBatcher:
         or capacity audit wants (``benchmarks/load/tier_smoke``
         measures the host tier's servable-prefix multiplier with
         it)."""
-        self._one_cache_group("the radix prefix cache")
-        self._pages_only("the radix prefix cache")
+        self._require("the radix prefix cache")
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         n = 0
         for j in range((prompt.shape[0] - 1) // self._page):
@@ -2570,7 +2556,10 @@ class ContinuousBatcher:
                     variables[name], h, bucket, None,
                     self._kv_dtype if self._kv_quant else False,
                     method="prefill",
-                    **({"length": ints[0]} if i in self._state_blocks else {}),
+                    **(
+                        {"length": ints[0]}
+                        if i in self._layout.state_blocks else {}
+                    ),
                 )
                 # The pool's rows (a latent block's are whole: no V).
                 kvs.append(ck if cv is None else fuse_kv(ck, cv))
@@ -2629,7 +2618,7 @@ class ContinuousBatcher:
                 self.lm.block_names, self._blocks, caches
             )):
                 kw = {}
-                if i in self._state_blocks:
+                if i in self._layout.state_blocks:
                     kw = {"length": ints[1], "carried": jax.tree.map(
                         lambda s: jnp.where(
                             pos0 == 0, jnp.zeros((), s.dtype),
@@ -3030,8 +3019,7 @@ class ContinuousBatcher:
         siblings STAY queued (their ids are lost with the raise — a
         caller that must know them should submit serially); the group
         shrinks to the survivors."""
-        self._one_cache_group("copy-on-write fan-out")
-        self._pages_only("copy-on-write fan-out")
+        self._require("copy-on-write fan-out")
         if n < 1:
             raise ValueError(f"fan-out width must be >= 1, got {n}")
         sib_rngs: list = [None] * n
@@ -3419,12 +3407,10 @@ class ContinuousBatcher:
         summary (also recorded as the ``mesh_reshard`` flight event).
         Raises :class:`DeviceLostError` when no recovery exists (all
         devices lost, or survivors below ``min_tp``)."""
-        # Pipeline boundary (RuntimeConfig.pipeline_depth >= 2): a
-        # dispatched-but-uncommitted tick drains BEFORE the mesh
-        # surgery below. Its results were computed on the old layout —
-        # under the simulated kill they are still readable, exactly
-        # like the last completed tick the synchronous loop commits
-        # before detecting the loss — and its commits move
+        # Pipeline boundary: a dispatched-but-uncommitted tick drains
+        # BEFORE the mesh surgery below. Its results were computed on
+        # the old layout — under the simulated kill they are still
+        # readable — and its commits move
         # slot.tokens/emitted, which the migrate-vs-replay decisions
         # and ``_replay_slot``'s delivered-token arithmetic read. This
         # is where ``_lost_pending`` is consumed relative to the
@@ -4689,45 +4675,16 @@ class ContinuousBatcher:
             dev for _, dev in self._group_tables
         )
 
-    def _one_cache_group(self, what: str) -> None:
-        """Refuse ``what`` for a model whose cache is in several layer
-        groups: a shared prompt page would need every window layer's
-        last positions beside it."""
-        if len(self._groups) > 1:
+    def _require(self, *features: str) -> None:
+        """Refuse by name what this model's cache cannot do for one of
+        ``features`` (:func:`_unmet`)."""
+        unmet = _unmet(self._layout, *features)
+        if unmet:
+            feature, what, detail = unmet
             raise ValueError(
-                f"{what} does not run over a cache in "
-                f"{len(self._groups)} layer groups "
-                f"({', '.join(g.name for g in self._groups)}) yet"
+                f"{feature} does not run for a model with {what} "
+                f"({detail}) yet"
             )
-
-    def _pages_only(self, what: str) -> None:
-        """Refuse ``what`` for a model with recurrent state: it moves
-        or shares PAGES, and a request's pages without the state that
-        belongs to the same position are half a cache."""
-        if self._state_blocks:
-            raise ValueError(
-                f"{what} does not run for a model with recurrent state "
-                f"({len(self._state_blocks)} blocks keep a mixer's state a "
-                "slot, beside their pages or in place of them) yet"
-            )
-
-    def _per_head_pages(self, what: str) -> None:
-        """Refuse ``what`` for a model with latent-attention blocks:
-        it moves, shards or re-encodes pages of K and V a KV head, and
-        a latent pool's row has no head axis and no K|V halves."""
-        if self._latent_blocks:
-            raise ValueError(
-                f"{what} does not run for a model with a latent cache "
-                f"({len(self._latent_blocks)} blocks keep one "
-                f"{self._groups[0].row}-value row a position, no head "
-                "axis) yet"
-            )
-
-    @property
-    def _shares_pages(self) -> bool:
-        """Whether a prompt page may be shared between requests (the
-        radix prefix cache): one cache group and no recurrent state."""
-        return len(self._groups) == 1 and not self._state_blocks
 
     def _hold_groups(self, slot: int, lo_pos: int, hi_pos: int) -> None:
         """Before a pass that writes positions ``[lo_pos, hi_pos)`` of
@@ -4805,7 +4762,7 @@ class ContinuousBatcher:
             # A model with recurrent state names the slot whose state
             # the pass carries on from.
             ints = [pos0, clen, req.top_k] + (
-                [slot.idx] if self._state_blocks else []
+                [slot.idx] if self._layout.state_blocks else []
             )
             (first, first_lp, self._caches,
              self._states) = self._prefill_suffix_fn(
@@ -4864,7 +4821,7 @@ class ContinuousBatcher:
         desynchronize — guarded by the compile-count test. Stages zero
         host arrays steady-state; the round's (tokens, logprobs,
         accepted) D2H starts here as ONE async fetch and lands in
-        ``_tick_commit`` (same call at depth 1, next tick at depth 2).
+        ``_tick_commit``, one ``tick()`` later (or at a ``drain()``).
         Returns the round's :class:`_InFlight` (binding identity is
         filled in by ``_tick_dispatch``)."""
         d = self._spec_k_eff
@@ -4978,16 +4935,16 @@ class ContinuousBatcher:
         (``_tick_dispatch``: scheduler/admission/prefill + the decode
         dispatch, with the D2H fetch started asynchronously) and a
         **commit** half (``_tick_commit``: land the fetch, apply
-        per-slot commits, flush telemetry). At depth 2 — what an
-        unset ``RuntimeConfig.pipeline_depth`` resolves to, whatever
-        the model (``stats()["pipeline_depth"]``) — this
-        call dispatches tick *t* and then commits tick *t−1* while *t*
-        runs on device: the host's fetch, commits, callbacks and the
-        caller's own work between calls overlap the device wall, and
-        every result is delivered with a one-tick lag (drained at
-        :meth:`drain` / :meth:`run` exit / :meth:`recover`). At depth
-        1 (explicit only) the halves run back to back: the synchronous
-        loop.
+        per-slot commits, flush telemetry). This call dispatches tick
+        *t* (its programs enqueue behind *t−1*'s on the device stream)
+        and then commits tick *t−1* while *t* runs on device: the
+        host's fetch, commits, callbacks and the caller's own work
+        between calls overlap the device wall, and every result is
+        delivered with a one-tick lag. :meth:`drain` lands what is in
+        flight and is THE way to stand at a known position;
+        :meth:`run` exit and :meth:`recover` drain, the latter also
+        from ``_ensure_mesh`` inside the dispatch half on a device
+        loss.
 
         Phases (``utils.profiling.EngineObs``): the call is one
         ``engine.tick`` region holding ``engine.admit`` /
@@ -5001,20 +4958,13 @@ class ContinuousBatcher:
         ``engine.phase.<name>_s`` histogram sample; off, a site costs
         the annotation and one branch. The cross-half stamps verify /
         decode / dispatch / commit_lag are histogram-only:
-        decode/verify span dispatch→results-landed, so under the
-        pipelined loop they OVERLAP the other phases — that overlap is
+        decode/verify span dispatch→results-landed, so they OVERLAP
+        the other phases of the next call — that overlap is
         the win, gauged as ``runtime.overlap_ratio``. The compile
         sentinel samples once at the end of every commit half, so an
         unexpected recompile is flagged next to the tick that paid for
         it."""
         with self._eobs.region("tick"):
-            if self._depth <= 1:
-                fl = self._tick_dispatch()
-                return self._tick_commit(fl) if fl is not None else 0
-            # Pipelined: dispatch t FIRST (its programs enqueue behind
-            # t-1's on the device stream), then commit t-1 on the host
-            # while t runs. _ensure_mesh inside the dispatch half drains
-            # the in-flight tick through recover() on a device loss.
             fl = self._tick_dispatch()
             prev, self._inflight = self._inflight, fl
             if prev is not None:
@@ -5022,8 +4972,8 @@ class ContinuousBatcher:
             return 0
 
     def drain(self) -> int:
-        """Commit the in-flight tick, if any (pipelined runtime) —
-        the explicit pipeline boundary. Call before reading results
+        """Commit the in-flight tick, if any — the explicit pipeline
+        boundary. Call before reading results
         outside :meth:`run` / :meth:`result`, before handing the
         device to another dispatcher (DisaggServer does), or before
         tearing down. Idempotent; returns the committed tick's active
@@ -5228,17 +5178,17 @@ class ContinuousBatcher:
         commits (skipping slots whose binding changed since dispatch —
         their columns are a bounded garbage tail nobody reads), then
         window recycling, the telemetry flush, and the compile-
-        sentinel sample. Runs in the same :meth:`tick` call at depth
-        1; one tick later at depth 2, where ``overlapped`` says another
-        decode dispatch followed ``fl``'s before this commit
+        sentinel sample. ``overlapped`` says another decode dispatch
+        followed ``fl``'s before this commit
         (``runtime.ticks_overlapped`` counts those commits,
-        ``runtime.ticks_synchronous`` the rest)."""
+        ``runtime.ticks_synchronous`` the rest: a :meth:`drain`, the
+        last tick of a :meth:`run`)."""
         eo = self._eobs
         eo_on = eo.enabled
         if eo_on and fl.t_dispatched:
-            # Dispatch-end -> commit-entry: ~0 at depth 1; the NEXT
-            # tick's dispatch wall at depth 2 (the lag the stream
-            # timing docs describe).
+            # Dispatch-end -> commit-entry: the NEXT tick's dispatch
+            # wall (the lag the stream timing docs describe), ~0 at a
+            # drain().
             eo.phase("commit_lag", fl.t_dispatched, span=False)
         # The blocked part of the result fetch (``fetch.wait_s``): what
         # the device, or the transfer after it, made the host wait.
@@ -5321,9 +5271,9 @@ class ContinuousBatcher:
             limits = np.asarray(acc, np.int64) + 1
         if fl.t0:
             # Overlap gauge: the fraction of the dispatch->results
-            # wall the host did NOT spend blocked on the fetch. ~0 for
-            # a device-bound synchronous loop; -> 1 when the pipelined
-            # loop hides the device wall behind the next dispatch.
+            # wall the host did NOT spend blocked on the fetch: -> 1
+            # when the next dispatch hides the device wall, ~0 for a
+            # device-bound commit with nothing dispatched behind it.
             wall = time.perf_counter() - fl.t0
             if wall > 0:
                 global_metrics().set_gauge(
@@ -5449,12 +5399,7 @@ class ContinuousBatcher:
                 "admitted": self._admitted,
                 "completed": self._completed,
                 "ticks": self._ticks,
-                # Tick-runtime shape AS RESOLVED (config.RuntimeConfig;
-                # unset: 2 for every model): depth 1 =
-                # synchronous dispatch+commit; depth 2 = one tick in
-                # flight between calls (inflight reports whether one is
-                # pending right now).
-                "pipeline_depth": self._depth,
+                # Whether a dispatched tick awaits its commit right now.
                 "inflight": self._inflight is not None,
                 # Prompt positions prefilled IN-TICK by this batcher
                 # (full/suffix/chunk passes; prefix-cache hits and
@@ -5538,15 +5483,18 @@ class ContinuousBatcher:
                     gs = pager.stats()
                     out[f"pool_pages.{g.name}"] = gs.num_pages
                     out[f"pages_in_use.{g.name}"] = gs.in_use
-                out["prefix_cache"] = "off: several cache groups"
             #: Recurrent state beside the pages (0 where the model has
             #: none): bytes resident, and the slots that hold one each.
             out["state_bytes"] = sum(
                 x.nbytes for x in jax.tree.leaves(self._states)
             )
-            out["state_slots"] = len(self.slots) if self._state_blocks else 0
-            if self._state_blocks:
-                out["prefix_cache"] = "off: recurrent state"
+            out["state_slots"] = (
+                len(self.slots) if self._layout.state_blocks else 0
+            )
+            if not self._shares_pages:
+                out["prefix_cache"] = "off: " + _unmet(
+                    self._layout, "the radix prefix cache"
+                )[1]
             ps = self._pager.stats()
             out["pool_pages"] = ps.num_pages
             #: What ONE position stores in a block of the first cache

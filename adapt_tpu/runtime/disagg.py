@@ -1212,9 +1212,8 @@ class DisaggServer:
         """One server scheduling round: prefill step -> land handoffs
         -> decode tick. Returns the decode tick's active-slot count.
 
-        When the decode batcher runs the pipelined tick runtime
-        (``config.RuntimeConfig(pipeline_depth=2)``), its tick() here
-        dispatches round *t* and commits round *t−1* — the handoffs
+        The decode batcher's tick() here dispatches round *t* and
+        commits round *t−1* — the handoffs
         landed above still enter admission on THIS call (admission is
         dispatch-side), only result delivery lags one round. The
         driver needs no pacing changes: :meth:`run`'s busy loop keys

@@ -785,6 +785,65 @@ def cache_groups(specs) -> list[CacheGroup]:
     return out
 
 
+#: What a feature may need of a model's cache (``CacheLayout.lacks``),
+#: in the order a refusal names them.
+CACHE_PROPERTIES = ("one_group", "pages_only", "per_head_pages")
+
+
+@dataclasses.dataclass(frozen=True)
+class CacheLayout:
+    """What a model's blocks keep for a request (:func:`cache_layout`):
+    the one description the batcher sizes its pools from and refuses
+    features by."""
+
+    groups: tuple[CacheGroup, ...]  # the one that reserves whole first
+    group_of: tuple[int, ...]  # a block's group (0: it keeps no pages)
+    #: Blocks with a recurrent ``(state, tail)`` a SLOT, beside their
+    #: pages (``BlockSpec.ssm``) or in place of them (``.linear``).
+    state_blocks: tuple[int, ...]
+    latent_blocks: tuple[int, ...]  # one row a position, no head axis
+
+    def lacks(self, prop: str) -> tuple[str, str] | None:
+        """None where this cache has ``prop`` (of ``CACHE_PROPERTIES``),
+        else what it has in its place, and the detail a refusal adds."""
+        if prop == "one_group" and len(self.groups) > 1:
+            # A shared or moved prompt page would need every window
+            # layer's last positions beside it.
+            return (
+                f"a cache in {len(self.groups)} layer groups",
+                ", ".join(g.name for g in self.groups),
+            )
+        if prop == "pages_only" and self.state_blocks:
+            # Pages without the state of the same position: half a cache.
+            return "recurrent state", (
+                f"{len(self.state_blocks)} blocks keep a mixer's state a "
+                "slot, beside their pages or in place of them"
+            )
+        if prop == "per_head_pages" and self.latent_blocks:
+            # No head axis and no K|V halves to shard, move or re-encode.
+            row = self.groups[self.group_of[self.latent_blocks[0]]].row
+            return "a latent cache", (
+                f"{len(self.latent_blocks)} blocks keep one {row}-value "
+                "row a position, no head axis"
+            )
+        return None
+
+
+def cache_layout(specs) -> CacheLayout:
+    """The layout of a model's blocks (their ``BlockSpec``); the group
+    that reserves whole, the full-attention one if any, comes first."""
+    groups = sorted(cache_groups(specs), key=lambda g: g.window is not None)
+    group_of = [0] * len(specs)
+    for gi, g in enumerate(groups):
+        for bi in g.blocks:
+            group_of[bi] = gi
+    return CacheLayout(
+        tuple(groups), tuple(group_of),
+        tuple(i for i, sp in enumerate(specs) if sp.state_spec is not None),
+        tuple(i for i, sp in enumerate(specs) if sp.latent is not None),
+    )
+
+
 def window_hold_pages(
     window: int, page_size: int, chunk: int, prefill_chunk: int | None
 ) -> int:

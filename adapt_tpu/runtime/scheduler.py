@@ -526,11 +526,11 @@ class DegradationController:
         """One control evaluation (ticking thread, host arithmetic
         only): escalate/de-escalate at most one rung per dwell.
 
-        Staleness bound under the pipelined tick runtime
-        (``config.RuntimeConfig(pipeline_depth=2)``): this runs at the
-        top of the DISPATCH half, so the occupancy/attainment inputs
-        read here predate the in-flight tick's commit — slots that
-        tick retires still count occupied, and its SLO verdicts are
+        Staleness bound under the batcher's tick order (dispatch *t*,
+        then commit *t−1*): this runs at the top of the DISPATCH half,
+        so the occupancy/attainment inputs read here predate the
+        in-flight tick's commit — slots that tick retires still count
+        occupied, and its SLO verdicts are
         not yet in ``_slo_totals``. The error is bounded by exactly
         ONE tick (at most ``chunk`` tokens per slot of pending
         retirement, one tick of attainment movement), which is well

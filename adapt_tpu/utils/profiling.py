@@ -1075,7 +1075,7 @@ class EngineObs:
         (the next phase's open). For the tick's cross-half stamps
         (``decode`` / ``verify`` / ``dispatch`` / ``commit_lag``): they
         open in the dispatch half and close in the commit half, one
-        ``tick()`` call later at ``pipeline_depth=2``, so they overlap
+        ``tick()`` call later, so they overlap
         the other phases, cannot nest and cannot be profiler
         annotations. Every same-thread site of the tick uses
         :meth:`region`; ``LocalPipeline``'s stage/hop stay on this form

@@ -347,8 +347,8 @@ def drive_phase(
             if gap > 0:
                 time.sleep(min(gap, 0.01))
         bat.tick()
-    # Pipelined runtimes (config.RuntimeConfig) may hold one garbage
-    # tick in flight after the last finish edge — drain it so the
+    # The batcher may hold one garbage tick in flight after the last
+    # finish edge — drain it so the
     # phase's windowed snapshot (and the next phase) start clean.
     drain = getattr(bat, "drain", None)
     if drain is not None:
@@ -506,7 +506,6 @@ def build_batcher(
     cache_tier=None,
     prefill=None,
     prefill_chunk: int | None = None,
-    runtime=None,
 ):
     """The harness's model+batcher factory (tiny LM — the harness
     measures the serving tier's behavior under load, not model quality;
@@ -520,9 +519,7 @@ def build_batcher(
     sequence-parallel long-context prefill path on — the sp-on arm of
     the long_context A/B (the caller must provision
     ``sp_width`` virtual devices first, e.g.
-    ``benchmarks.common.force_cpu_mesh``). ``runtime`` (a
-    ``config.RuntimeConfig``) selects the tick runtime — depth 2 is
-    the pipelined/async arm of the runtime A/B."""
+    ``benchmarks.common.force_cpu_mesh``)."""
     import jax
     import jax.numpy as jnp
 
@@ -537,7 +534,7 @@ def build_batcher(
         lm, variables, slots=slots, chunk=chunk, page_size=page_size,
         pool_pages=pool_pages, cache_tier=cache_tier,
         scheduler=scheduler, prefill=prefill,
-        prefill_chunk=prefill_chunk, runtime=runtime,
+        prefill_chunk=prefill_chunk,
     )
 
 
@@ -552,7 +549,6 @@ def build_disagg(
     busy_prompt_threshold: int | None = None,
     scheduler=None,
     prefill=None,
-    runtime=None,
 ):
     """The disaggregated counterpart of :func:`build_batcher`: a paged
     decode batcher, a chunked ``PrefillWorker`` and the
@@ -562,7 +558,7 @@ def build_disagg(
     defaults to two pages (the per-tick stall bound)."""
     decode = build_batcher(
         vocab, max_len, slots, chunk,
-        page_size=page_size, scheduler=scheduler, runtime=runtime,
+        page_size=page_size, scheduler=scheduler,
     )
     from adapt_tpu.config import DisaggConfig
     from adapt_tpu.runtime.disagg import DisaggServer, PrefillWorker
@@ -637,14 +633,6 @@ def main() -> int:
     sp_arg = str_flag(sys.argv, "--sp", "off", choices=("off", "on"))
     sp_width = int_flag(sys.argv, "--sp-width", 2)
     sp_threshold = int_flag(sys.argv, "--sp-threshold", 4096)
-    # Tick runtime: "async" runs the pipelined depth-2 runtime
-    # (config.RuntimeConfig(pipeline_depth=2) — host scheduling of
-    # tick t+1 overlaps tick t's device programs) so the SAME seeded
-    # schedule drives async-vs-sync arms, e.g. `--runtime async` vs
-    # `--runtime sync` (see load/async_ratio.py for the gated ratio).
-    runtime_arg = str_flag(
-        sys.argv, "--runtime", "sync", choices=("sync", "async")
-    )
     out = str_flag(sys.argv, "--out", "")
     try:
         rates = [float(r) for r in rates_arg.split(",") if r]
@@ -683,13 +671,6 @@ def main() -> int:
             sp_cfg = PrefillConfig(
                 sp_threshold=sp_threshold, sp_width=sp_width
             )
-        from adapt_tpu.config import RuntimeConfig
-
-        # Each arm names its order: left unset the batcher would
-        # resolve the overlapped one for both (docs/SERVING.md §3).
-        runtime = RuntimeConfig(
-            pipeline_depth=2 if runtime_arg == "async" else 1
-        )
         if placement == "disagg":
             # Same schedule, disaggregated serving path (paged decode +
             # prefill tier) — the apples-to-apples arm of the
@@ -701,7 +682,6 @@ def main() -> int:
                 chunk,
                 scheduler=scheduler,
                 prefill=sp_cfg,
-                runtime=runtime,
             )
         else:
             bat = build_batcher(
@@ -712,7 +692,6 @@ def main() -> int:
                 scheduler=scheduler,
                 cache_tier=cache_tier,
                 prefill=sp_cfg,
-                runtime=runtime,
             )
         # Phase timing on: every curve point gets its roofline
         # annotation (mbu/mfu need measured phase walls).
@@ -741,7 +720,6 @@ def main() -> int:
             "scheduler": sched_arg,
             "fanout": fanout_arg,
             "sp": sp_arg,
-            "runtime": runtime_arg,
             "prefill_cfg": (
                 dataclasses.asdict(sp_cfg) if sp_cfg else None
             ),

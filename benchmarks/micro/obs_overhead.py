@@ -30,19 +30,16 @@ Four configurations over the SAME ContinuousBatcher steady state
   worker-side collect and the parent-side ingest of one report, i.e.
   both halves of the fleet path, timed inside the serving loop.
 
-THREE JSON lines: ``micro_obs_overhead_pct`` (fully-enabled "trace"
-overhead vs the floor, percent; ``vs_baseline`` = the 5% budget minus
-the measured overhead, positive = within budget),
+TWO JSON lines: ``micro_obs_overhead_async_pct`` (fully-enabled
+"trace" overhead vs the floor, percent; ``vs_baseline`` = the 5%
+budget minus the measured overhead, positive = within budget; the
+tick's deferred commit half carries the ``_obs_flush``/SLO arithmetic,
+and this row holds that seam to the budget) and
 ``micro_obs_federation_pct`` (federation config vs the same floor,
-same budget — gated via benchmarks/baselines/seed.json) and
-``micro_obs_overhead_async_pct`` (the same off-vs-trace delta measured
-on a SECOND batcher running the pipelined tick runtime,
-``RuntimeConfig(pipeline_depth=2)`` — the async loop moves the
-``_obs_flush``/SLO arithmetic onto the deferred commit half, and this
-row holds that seam to the SAME < 5% budget). Per-config per-tick
-means and the engine-only overhead ride in extras.
+same budget — gated via benchmarks/baselines/seed.json). Per-config
+per-tick means and the engine-only overhead ride in extras.
 
-A FOURTH gated line, ``micro_obs_overhead_capacity_pct``, measures the
+A THIRD gated line, ``micro_obs_overhead_capacity_pct``, measures the
 capacity/placement-signal plane (``runtime/capacity.CapacityModel``)
 on a PAIR of fresh paged batchers: one with
 ``CapacityConfig(enabled=False)`` (the floor — no model attached, zero
@@ -90,7 +87,7 @@ def main() -> int:
         jax.config.update("jax_platforms", "cpu")
         import jax.numpy as jnp
 
-        from adapt_tpu.config import RuntimeConfig, SLOSpec
+        from adapt_tpu.config import SLOSpec
         from adapt_tpu.models.transformer_lm import lm_tiny
         from adapt_tpu.runtime.continuous import ContinuousBatcher
         from adapt_tpu.utils.tracing import global_tracer
@@ -107,12 +104,7 @@ def main() -> int:
         variables = lm.graph.init(
             jax.random.PRNGKey(0), jnp.zeros((1, 4), jnp.int32)
         )
-        # The synchronous arm, by name (left unset the batcher would
-        # resolve the overlapped order, measured separately below).
-        bat = ContinuousBatcher(
-            lm, variables, slots=slots, chunk=chunk,
-            runtime=RuntimeConfig(pipeline_depth=1),
-        )
+        bat = ContinuousBatcher(lm, variables, slots=slots, chunk=chunk)
         rng = np.random.RandomState(0)
         # Generous budgets that never miss: the measured cost is the
         # EVALUATION (two comparisons per commit + the per-tick flush),
@@ -192,7 +184,7 @@ def main() -> int:
         overhead_pct = (t_trace / t_off - 1.0) * 100.0
         federation_pct = (t_fed / t_off - 1.0) * 100.0
         emit(
-            "micro_obs_overhead_pct",
+            "micro_obs_overhead_async_pct",
             overhead_pct,
             "% tick wall time (trace+engine+timeline vs off)",
             BUDGET_PCT - overhead_pct,
@@ -221,63 +213,7 @@ def main() -> int:
             .get("reports", 0),
         )
 
-        # Async-runtime arm: off vs trace on a pipelined (depth-2)
-        # batcher. The deferred commit half carries the _obs_flush +
-        # SLO arithmetic there — same budget, measured separately so a
-        # regression on the deferred seam can't hide behind the sync
-        # numbers above. Same lm (its max_len covers this shorter
-        # plan); fresh batcher so jit caches and KV state don't cross.
         bat.close()
-        abat = ContinuousBatcher(
-            lm, variables, slots=slots, chunk=chunk,
-            runtime=RuntimeConfig(pipeline_depth=2),
-        )
-        asteps = (n_ticks * (2 * trials + 1) + 8) * chunk
-        for _ in range(slots):
-            abat.submit(
-                rng.randint(0, 37, size=6).astype(np.int32), asteps,
-                slo=slo,
-            )
-        abat.tick()  # admission burst + this batcher's compiles
-        abat.tick()
-        for _ in range(n_ticks):  # warm before any timed window
-            abat.tick()
-        abest = {"off": float("inf"), "trace": float("inf")}
-        for t in range(trials):
-            order = (
-                ("off", "trace") if t % 2 == 0 else ("trace", "off")
-            )
-            for name in order:
-                on = name == "trace"
-                abat.obs_timeline = on
-                eobs.enabled = on
-                tracer.enabled = on
-                t0 = time.perf_counter()
-                for _ in range(n_ticks):
-                    abat.tick()
-                abest[name] = min(
-                    abest[name], (time.perf_counter() - t0) / n_ticks
-                )
-        tracer.enabled = False
-        eobs.enabled = False
-        if abat.stats()["active"] != slots:
-            raise RuntimeError(
-                "async batcher fell out of steady state mid-measure"
-            )
-        abat.close()
-        async_pct = (abest["trace"] / abest["off"] - 1.0) * 100.0
-        emit(
-            "micro_obs_overhead_async_pct",
-            async_pct,
-            "% tick wall time (trace vs off, pipelined depth-2 runtime)",
-            BUDGET_PCT - async_pct,
-            budget_pct=BUDGET_PCT,
-            tick_off_ms=round(abest["off"] * 1e3, 4),
-            tick_trace_ms=round(abest["trace"] * 1e3, 4),
-            slots=slots,
-            ticks=n_ticks,
-            trials=trials,
-        )
 
         # Capacity-plane arm: a fresh PAGED batcher pair (paged so the
         # book rebuild pays the full bill — headroom from Pager.stats
@@ -353,19 +289,13 @@ def main() -> int:
         )
     except Exception as e:  # noqa: BLE001 — always one JSON line, rc 0
         emit(
-            "micro_obs_overhead_pct", 0.0,
+            "micro_obs_overhead_async_pct", 0.0,
             "% tick wall time (trace+engine+timeline vs off)", 0.0,
             error=str(e)[-300:],
         )
         emit(
             "micro_obs_federation_pct", 0.0,
             "% tick wall time (trace + telemetry report path vs off)",
-            0.0,
-            error=str(e)[-300:],
-        )
-        emit(
-            "micro_obs_overhead_async_pct", 0.0,
-            "% tick wall time (trace vs off, pipelined depth-2 runtime)",
             0.0,
             error=str(e)[-300:],
         )

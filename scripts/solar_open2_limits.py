@@ -149,7 +149,7 @@ def main() -> int:
     ap.add_argument("--state", action="store_true",
                     help="also hold every KDA layer's served state of the "
                     "sample's three slots to the reference's at the same "
-                    "position (synchronous ticks, steps - 1 a whole number "
+                    "position (every tick drained, steps - 1 a whole number "
                     "of scans: the state then stands where the row ended)")
     ap.add_argument("--rehearse", action="store_true")
     ap.add_argument("--steps", type=int, default=0,
@@ -162,7 +162,6 @@ def main() -> int:
     import jax
     import numpy as np
 
-    from adapt_tpu.config import RuntimeConfig
     from adapt_tpu.runtime.continuous import ContinuousBatcher
     from chipbench import lm_engine as eng
     from chipbench import manifest as mf
@@ -213,11 +212,13 @@ def main() -> int:
                 pool_pages=serving["pool_pages"],
                 prefill_chunk=serving["prefill_chunk"],
                 prompt_buckets=tuple(serving["prompt_buckets"]),
-                # No scan past a request's end: its state stays where
-                # its last served step left it.
-                **({"runtime": RuntimeConfig(pipeline_depth=1)}
-                   if a.state else {}),
             )
+            if a.state:
+                # No scan past a request's end: every tick is committed
+                # before the next is dispatched, so a row's state stays
+                # where its last served step left it.
+                tick = srv.tick
+                srv.tick = lambda: tick() + srv.drain()
         else:
             srv.variables = variables
         kept, claimed = {}, []

@@ -10,7 +10,7 @@ Every argument goes to ``chipbench/run.py`` of ``--root`` (default:
 this checkout; another one is an unpacked ``git archive`` in an
 ignored directory). The line:
 
-    tick order: depth D overlapped A synchronous B (share S) \
+    tick order: overlapped A synchronous B (share S) \
         rows_past_end R of N rows decoded (share T)
 
 ``overlapped`` / ``synchronous`` are ``runtime.ticks_overlapped`` /
@@ -56,8 +56,8 @@ def main(argv: list[str]) -> int:
                     if rec["t_open"] < t[1] <= rec["t_close"]
                 )
                 print(
-                    f"tick order: depth {rec['stats']['pipeline_depth']} "
-                    f"overlapped {over:.0f} synchronous {sync:.0f} "
+                    f"tick order: overlapped {over:.0f} "
+                    f"synchronous {sync:.0f} "
                     f"(share {over / max(over + sync, 1):.4f}) "
                     f"rows_past_end {past:.0f} of {rows} rows decoded "
                     f"(share {past / max(rows, 1):.4f})",

@@ -137,6 +137,16 @@ def rng():
     return jax.random.PRNGKey(0)
 
 
+def drained(bat):
+    """``bat`` (a ``ContinuousBatcher``), every tick of it committed
+    before the next is dispatched (``tick(); drain()``): what a test
+    that reads state after each tick drives, and what the overlapped
+    order is compared with."""
+    tick = bat.tick
+    bat.tick = lambda: tick() + bat.drain()
+    return bat
+
+
 def greedy_by_full_forward(lm, variables, prompt, steps: int):
     """The oracle of the cached-decode parity tests: ``steps`` tokens
     by stepwise argmax of the FULL causal forward, (b, steps). The

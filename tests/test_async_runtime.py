@@ -1,12 +1,12 @@
-"""Pipelined tick runtime (``config.RuntimeConfig``): the depth-2 loop
-— dispatch tick *t*, commit tick *t−1* while *t* runs on device — must
-be INVISIBLE in outputs. Greedy streams stay bit-identical to the
-synchronous ``pipeline_depth=1`` loop on both KV layouts, including
-speculative + int8 + tp=2 composed; cancels, preemption and a
-kill-mid-stream recovery all land exactly-once with balanced lifecycle
-books while the in-flight tick drains at the pipeline boundary; and
-the hot-path invariants (0 h2d per steady tick, the two-program
-compile footprint) survive the overlapped loop."""
+"""The batcher's tick order — dispatch tick *t*, commit tick *t−1*
+while *t* runs on device — must be INVISIBLE in outputs. Greedy
+streams stay bit-identical to the same batcher driven ``tick();
+drain()`` (every tick committed before the next is dispatched) and to
+``generate()``, including speculative + int8 + tp=2 composed; cancels,
+preemption and a kill-mid-stream recovery all land exactly-once with
+balanced lifecycle books while the in-flight tick drains at the
+pipeline boundary; and the hot-path invariants hold (0 h2d per steady
+tick, the two-program compile footprint)."""
 
 from __future__ import annotations
 
@@ -22,9 +22,7 @@ import jax.numpy as jnp
 
 from adapt_tpu.config import (
     ParallelConfig,
-    RuntimeConfig,
     SchedulerConfig,
-    ServeConfig,
     SLOSpec,
     SpeculativeConfig,
 )
@@ -38,6 +36,7 @@ from adapt_tpu.runtime.continuous import ContinuousBatcher
 from adapt_tpu.utils.metrics import global_metrics
 from adapt_tpu.utils.profiling import global_compile_sentinel
 from adapt_tpu.utils.tracing import global_flight_recorder
+from conftest import drained
 
 
 @pytest.fixture(scope="module")
@@ -69,8 +68,14 @@ def _solo(lm, variables, prompt, steps, **kw):
     )[0]
 
 
-def _depth(n):
-    return RuntimeConfig(pipeline_depth=n)
+#: The two ways a test drives one batcher: as it is, and with every
+#: tick committed before the next is dispatched.
+ORDERS = ("drained", "overlapped")
+
+
+def _batcher(order, *args, **kw):
+    bat = ContinuousBatcher(*args, **kw)
+    return drained(bat) if order == "drained" else bat
 
 
 RNG = np.random.RandomState(11)
@@ -97,16 +102,6 @@ def _staggered(bat, cancel_idx=None):
     return {ids[r]: out[r] for r in ids}
 
 
-def test_runtime_config_validation():
-    """Depths outside {1, 2} fail eagerly, by name; left unset the
-    depth is None: the batcher resolves it (to 2)."""
-    assert RuntimeConfig().pipeline_depth is None
-    assert ServeConfig().runtime.pipeline_depth is None
-    for bad in (0, 3, -1):
-        with pytest.raises(ValueError, match="pipeline_depth"):
-            RuntimeConfig(pipeline_depth=bad)
-
-
 def _two_group_lm(window=8):
     """K-EXAONE's cache shape at toy widths: window layers and a full
     one, so two cache groups."""
@@ -124,32 +119,18 @@ def _two_group_lm(window=8):
     return lm, variables
 
 
-@pytest.mark.parametrize(
-    "model, runtime, depth",
-    [
-        ("one_group", None, 2),
-        ("one_group", RuntimeConfig(), 2),
-        ("one_group", _depth(1), 1),
-        ("two_groups", None, 2),
-        ("two_groups", _depth(1), 1),
-        ("two_groups", _depth(2), 2),
-    ],
-)
-def test_unset_depth_is_resolved_by_the_batcher(
-    lm_setup, model, runtime, depth
-):
-    """An unset depth resolves to the overlapped order whatever the
-    model (one cache group or several); an explicit depth means what
-    it says, and an explicit 2 under cache groups is no longer
-    refused."""
+@pytest.mark.parametrize("model", ["one_group", "two_groups"])
+def test_a_tick_leaves_its_results_in_flight(lm_setup, model):
+    """Whatever the model (one cache group or several), a ``tick()``
+    leaves what it dispatched for the next call, and ``drain()`` lands
+    it."""
     lm, variables = lm_setup if model == "one_group" else _two_group_lm()
-    kw = dict(slots=2, chunk=2, page_size=8)
-    bat = ContinuousBatcher(lm, variables, runtime=runtime, **kw)
-    assert bat.stats()["pipeline_depth"] == depth
+    bat = ContinuousBatcher(lm, variables, slots=2, chunk=2, page_size=8)
     rid = bat.submit(PROMPTS[0], 6)
     bat.tick()
     bat.tick()
-    assert bat.stats()["inflight"] == (depth == 2)
+    assert bat.stats()["inflight"]
+    assert bat.drain() == 1 and not bat.stats()["inflight"]
     assert len(bat.run()[rid]) == 6
     bat.close()
 
@@ -168,30 +149,30 @@ def test_unset_depth_is_resolved_by_the_batcher(
 )
 def test_async_bit_identical_staggered(lm_setup, page_size):
     """THE identity pin: the same staggered workload (admits,
-    retirements, mid-stream EOS-by-steps) under depth 1 and depth 2
-    yields bit-identical streams at both page sizes, each equal to
-    solo generate(); books balance and the pipeline drains empty."""
+    retirements, mid-stream EOS-by-steps) drained after every tick and
+    overlapped yields bit-identical streams at both page sizes, each
+    equal to solo generate(); books balance and the pipeline drains
+    empty."""
     lm, variables = lm_setup
     kw = dict(slots=3, chunk=2, page_size=page_size)
     outs = {}
-    for depth in (1, 2):
-        bat = ContinuousBatcher(
-            lm, variables, runtime=_depth(depth), **kw
-        )
-        outs[depth] = _staggered(bat)
+    for order in ORDERS:
+        bat = _batcher(order, lm, variables, **kw)
+        outs[order] = _staggered(bat)
         st = bat.stats()
-        assert st["pipeline_depth"] == depth
         assert st["active"] == 0 and st["queued"] == 0
         assert not st["inflight"]  # run() drained the pipeline
         assert st["admitted"] == st["completed"] == len(PROMPTS)
         bat.close()
     for i in range(len(PROMPTS)):
         np.testing.assert_array_equal(
-            outs[2][i], outs[1][i], err_msg=f"req {i}: depth2 != depth1"
+            outs["overlapped"][i], outs["drained"][i],
+            err_msg=f"req {i}: overlapped != drained",
         )
         np.testing.assert_array_equal(
-            outs[2][i], _solo(lm, variables, PROMPTS[i], STEPS[i]),
-            err_msg=f"req {i}: depth2 != generate",
+            outs["overlapped"][i],
+            _solo(lm, variables, PROMPTS[i], STEPS[i]),
+            err_msg=f"req {i}: overlapped != generate",
         )
 
 
@@ -213,7 +194,7 @@ def test_async_bit_identical_two_cache_groups(
     """The identity pin under CACHE GROUPS: a window group's pages are
     granted and recycled from the position each row has been DISPATCHED
     to, so the overlapped order serves a two-group model the streams
-    the synchronous one does, each equal to solo generate(): staggered
+    a drain after every tick does, each equal to solo generate(): staggered
     admits, more requests than slots, whole-prompt and chunked prefill,
     retirement by step count mid-chunk and by EOS, every request
     decoding past the window and across page edges. Afterwards no
@@ -235,8 +216,8 @@ def test_async_bit_identical_two_cache_groups(
     kw = dict(slots=2, chunk=chunk, page_size=page,
               prefill_chunk=prefill_chunk, prompt_buckets=(8, 16, 32))
     outs, past_end = {}, {}
-    for depth in (1, 2):
-        bat = ContinuousBatcher(lm, variables, runtime=_depth(depth), **kw)
+    for order in ORDERS:
+        bat = _batcher(order, lm, variables, **kw)
         assert [g.window for g in bat._groups] == [None, window]
         snap = global_metrics().snapshot(window=True)
         ids = {}
@@ -261,27 +242,29 @@ def test_async_bit_identical_two_cache_groups(
         for i in range(4, len(prompts)):
             submit(i)
         out = bat.run()
-        outs[depth] = {ids[r]: np.asarray(out[r]) for r in ids}
+        outs[order] = {ids[r]: np.asarray(out[r]) for r in ids}
         st = bat.stats()
-        assert st["pipeline_depth"] == depth and not st["inflight"]
+        assert not st["inflight"]
         assert st["active"] == 0 and st["queued"] == 0
         assert st["admitted"] == st["completed"] == len(prompts)
         assert st["pages_in_use.full"] == st["pages_in_use.window"] == 0
         c = global_metrics().snapshot(since=snap)["counters"]
-        past_end[depth] = c.get("runtime.rows_past_end", 0)
-        if depth == 2:
+        past_end[order] = c.get("runtime.rows_past_end", 0)
+        if order == "overlapped":
             assert c["runtime.ticks_overlapped"] > c[
                 "runtime.ticks_synchronous"
             ]
             assert any(emptied) == (chunk > window + page)
         bat.close()
-    assert past_end[1] == 0 < past_end[2]
+    assert past_end["drained"] == 0 < past_end["overlapped"]
     for i in range(len(prompts)):
         np.testing.assert_array_equal(
-            outs[2][i], outs[1][i], err_msg=f"req {i}: depth2 != depth1"
+            outs["overlapped"][i], outs["drained"][i],
+            err_msg=f"req {i}: overlapped != drained",
         )
         np.testing.assert_array_equal(
-            outs[2][i], solo[i], err_msg=f"req {i}: depth2 != generate"
+            outs["overlapped"][i], solo[i],
+            err_msg=f"req {i}: overlapped != generate",
         )
 
 
@@ -292,9 +275,7 @@ def test_async_cancel_mid_flight(lm_setup):
     and the lifecycle books balance."""
     lm, variables = lm_setup
     got: list[tuple[int, int, int]] = []
-    bat = ContinuousBatcher(
-        lm, variables, slots=2, chunk=2, runtime=_depth(2)
-    )
+    bat = ContinuousBatcher(lm, variables, slots=2, chunk=2)
     r0 = bat.submit(
         PROMPTS[0], STEPS[0],
         on_token=lambda rid, tok, idx: got.append((rid, tok, idx)),
@@ -322,14 +303,12 @@ def test_async_cancel_mid_flight(lm_setup):
 
 
 def test_async_zero_h2d_and_compile_footprint(lm_setup):
-    """The hot-path invariants survive the pipelined loop: steady-state
-    depth-2 ticks stage ZERO host arrays, the step-chunk program holds
+    """The hot-path invariants of the tick loop: steady-state
+    ticks stage ZERO host arrays, the step-chunk program holds
     ONE compiled variant across churn, and drain() is idempotent."""
     lm, variables = lm_setup
     sentinel = global_compile_sentinel()
-    bat = ContinuousBatcher(
-        lm, variables, slots=2, chunk=2, runtime=_depth(2)
-    )
+    bat = ContinuousBatcher(lm, variables, slots=2, chunk=2)
     before = sentinel.compiles("continuous.step_chunk")
     r1 = bat.submit(np.asarray([1, 2, 3], np.int32), 30)
     bat.tick()
@@ -358,11 +337,10 @@ def test_async_zero_h2d_and_compile_footprint(lm_setup):
 def test_async_spec_int8_tp2_bit_identical(
     lm_setup, draft_setup, sim_mesh, page_size
 ):
-    """The composed pin: speculative + int8 KV + tp=2, depth 1 vs
-    depth 2 — streams bit-identical to each other and to solo
-    generate(kv_cache_dtype='int8'); exactly ONE verify variant
-    compiles per batcher (two-program footprint under the async
-    loop)."""
+    """The composed pin: speculative + int8 KV + tp=2, drained after
+    every tick vs overlapped — streams bit-identical to each other and
+    to solo generate(kv_cache_dtype='int8'); exactly ONE verify variant
+    compiles per batcher (two-program footprint)."""
     _async_spec_int8_tp2(lm_setup, draft_setup, sim_mesh, page_size)
 
 
@@ -387,10 +365,10 @@ def _async_spec_int8_tp2(lm_setup, draft_setup, sim_mesh, page_size):
               page_size=page_size)
     prompts, steps = PROMPTS[:3], [7, 9, 5]
     outs = {}
-    for depth in (1, 2):
-        bat = ContinuousBatcher(
-            lm, variables, mesh=sim_mesh(2),
-            parallel=ParallelConfig(tp=2), runtime=_depth(depth), **kw,
+    for order in ORDERS:
+        bat = _batcher(
+            order, lm, variables, mesh=sim_mesh(2),
+            parallel=ParallelConfig(tp=2), **kw,
         )
         before = sentinel.compiles("continuous.spec_verify")
         ids = {bat.submit(p, s): i
@@ -398,17 +376,18 @@ def _async_spec_int8_tp2(lm_setup, draft_setup, sim_mesh, page_size):
         out = bat.run()
         assert sentinel.compiles("continuous.spec_verify") - before == 1
         assert 0.0 <= bat.stats()["spec_acceptance"] <= 1.0
-        outs[depth] = {ids[r]: out[r] for r in ids}
+        outs[order] = {ids[r]: out[r] for r in ids}
         bat.close()
     for i in range(3):
         np.testing.assert_array_equal(
-            outs[2][i], outs[1][i], err_msg=f"req {i}: depth2 != depth1"
+            outs["overlapped"][i], outs["drained"][i],
+            err_msg=f"req {i}: overlapped != drained",
         )
         np.testing.assert_array_equal(
-            outs[2][i],
+            outs["overlapped"][i],
             _solo(lm, variables, prompts[i], steps[i],
                   kv_cache_dtype="int8"),
-            err_msg=f"req {i}: depth2 != solo int8",
+            err_msg=f"req {i}: overlapped != solo int8",
         )
 
 
@@ -432,7 +411,6 @@ def test_async_preemption_exactly_once(lm_setup):
 
     bat = ContinuousBatcher(
         lm, variables, slots=1, chunk=2, kv_layout="paged", page_size=8,
-        runtime=_depth(2),
         scheduler=SchedulerConfig(
             preempt=True, preempt_ttft_fraction=0.5, degrade=False
         ),
@@ -486,7 +464,6 @@ def test_async_kill_midstream_recovery_drains_pipeline(
     bat = ContinuousBatcher(
         lm, variables, mesh=sim_mesh(4), parallel=ParallelConfig(tp=4),
         health=mon, slots=3, chunk=2, kv_layout="paged", page_size=8,
-        runtime=_depth(2),
     )
     delivered: dict[int, list] = {}
 
@@ -547,7 +524,7 @@ BENCH_PATTERNS = {
 }
 
 
-def _drive_like_the_benchmark(config_name, runtime):
+def _drive_like_the_benchmark(config_name, order):
     """What ``chipbench/lm_engine.py`` does to a batcher, with its own
     ``Driver`` and ``warm_up``, counted in requests and never timed:
     the correctness sample's three requests and their ``logprobs``,
@@ -570,13 +547,13 @@ def _drive_like_the_benchmark(config_name, runtime):
     traffic = BENCH_PATTERNS[config_name]
     lm, variables, shape = builders.gpt2(model, config["dtype"], seed=5)
     pairs = tg.templates(traffic, shape["max_len"])
-    kw = {} if runtime is None else dict(runtime=runtime)
-    srv = ContinuousBatcher(
-        lm, variables, slots=serving["slots"], chunk=serving["chunk"],
+    srv = _batcher(
+        order, lm, variables,
+        slots=serving["slots"], chunk=serving["chunk"],
         kv_layout=serving["kv_layout"], page_size=serving["page_size"],
         pool_pages=lm_engine.pool_pages(serving, pairs, shape["max_len"]),
         prefill_chunk=serving["prefill_chunk"],
-        prompt_buckets=tuple(serving["prompt_buckets"]), **kw,
+        prompt_buckets=tuple(serving["prompt_buckets"]),
     )
     snap = global_metrics().snapshot(window=True)
     drv = lm_engine.Driver(srv, shape["vocab"], 5, contextlib.nullcontext)
@@ -602,7 +579,7 @@ def _drive_like_the_benchmark(config_name, runtime):
     drv.run_until(lambda: not drv.live)
     paused = srv.stats()
     assert paused["active"] == 0
-    assert paused["inflight"] == (paused["pipeline_depth"] == 2)
+    assert paused["inflight"] == (order == "overlapped")
     # The next arrival lands the stale tick with its own first one.
     late = drv.submit(tg.Request(*pairs[0]), 0.0)
     drv.run_until(lambda: not drv.live)
@@ -632,13 +609,12 @@ def test_the_benchmarks_driving_pattern_is_bit_identical_overlapped(
     (manual ``tick()`` with callbacks, refill between ticks, cancel
     then tick to empty, blocking first-token reads in the dispatch
     half, ``logprobs`` after the last callback, a pause with a tick in
-    flight): a batcher built with no ``runtime=`` serves them in the
-    overlapped order, token for token and logprob for logprob what the
-    explicit synchronous order serves. The three counters say how
-    often the order engaged and what it wasted."""
-    sync, st1, c1, n1 = _drive_like_the_benchmark(config_name, _depth(1))
-    over, st2, c2, n2 = _drive_like_the_benchmark(config_name, None)
-    assert (st1["pipeline_depth"], st2["pipeline_depth"]) == (1, 2)
+    flight): the batcher serves them in the overlapped order, token
+    for token and logprob for logprob what the same batcher serves
+    drained after every tick. The three counters say how often the
+    order engaged and what it wasted."""
+    sync, st1, c1, n1 = _drive_like_the_benchmark(config_name, "drained")
+    over, st2, c2, n2 = _drive_like_the_benchmark(config_name, "overlapped")
     # Submission k is the same request under both orders (the driver
     # draws ids and lengths by submission index).
     assert sync.keys() == over.keys() and n1 == n2
@@ -649,7 +625,7 @@ def test_the_benchmarks_driving_pattern_is_bit_identical_overlapped(
         )
     assert st2["completed"] == st1["completed"]
     assert st2["active"] == st2["queued"] == 0
-    # Synchronous: every commit lands before the next dispatch, and no
+    # Drained: every commit lands before the next dispatch, and no
     # row is decoded for a request already finished.
     assert c1.get("runtime.ticks_overlapped", 0) == 0
     assert c1.get("runtime.rows_past_end", 0) == 0

@@ -16,7 +16,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from adapt_tpu.config import RuntimeConfig
 from adapt_tpu.models.transformer_lm import lm_tiny
 from adapt_tpu.runtime.continuous import ContinuousBatcher
 from adapt_tpu.utils.metrics import global_metrics
@@ -112,7 +111,6 @@ def journey():
         span0 = tracer._seq
         bat = ContinuousBatcher(
             lm, variables, slots=2, chunk=2, prompt_buckets=(8, 16, 32),
-            runtime=RuntimeConfig(pipeline_depth=1),
         )
         j["construct_s"] = _gauges("engine.construct_s")
         j["first_tokens"] = serve(bat, 3)

@@ -3,7 +3,7 @@
 Interpreter-mode parity for every new kernel branch (split decode
 dense/paged x native/int8/int4, ragged last split, split=1 degenerate
 == the unsplit kernel bit-exact; tree-mask verify vs a jnp oracle),
-the batcher-level invariants under `KernelConfig` split dispatch
+the batcher-level invariants under a forced-Pallas `KernelConfig`
 (bit-identical greedy streams, 0 h2d/steady tick, frozen compile
 footprint), tree-draft losslessness + the > 5.0 accepted-per-pass
 claim, int4 composition (top-1 agreement vs int8, prefix cache, disagg
@@ -315,10 +315,10 @@ def test_roofline_peaks_per_generation(monkeypatch):
 
 @pytest.mark.slow
 def test_batcher_split_streams_bit_identical():
-    """Greedy streams are BIT-IDENTICAL across split in {1, 2, 4} and
-    vs the default XLA path, across staggered
+    """Greedy streams are BIT-IDENTICAL between the forced Pallas
+    kernels and the default XLA path, across staggered
     admits/retires/cancels; 0 h2d per steady tick and a frozen compile
-    footprint hold under the split kernels (sentinel-pinned)."""
+    footprint hold under the kernels (sentinel-pinned)."""
     from adapt_tpu.utils.profiling import global_compile_sentinel
 
     lm = transformer_lm(VOCAB, 32, 2, 2, 64, max_len=256,
@@ -333,9 +333,7 @@ def test_batcher_split_streams_bit_identical():
     streams = {}
     for tag, kern in (
         ("xla", None),
-        ("s1", KernelConfig(attn_impl="pallas", decode_split=1)),
-        ("s2", KernelConfig(attn_impl="pallas", decode_split=2)),
-        ("s4", KernelConfig(attn_impl="pallas", decode_split=4)),
+        ("pallas", KernelConfig(attn_impl="pallas")),
     ):
         bat = ContinuousBatcher(
             lm, variables, slots=2, kernel=kern, chunk=2,
@@ -360,19 +358,18 @@ def test_batcher_split_streams_bit_identical():
         out = bat.run()
         streams[tag] = {0: out[r1], 1: out[r2]}
         bat.close()
-    for tag in ("s1", "s2", "s4"):
-        for i in (0, 1):
-            np.testing.assert_array_equal(
-                streams[tag][i], streams["xla"][i],
-                err_msg=f"{tag} req {i} diverged",
-            )
+    for i in (0, 1):
+        np.testing.assert_array_equal(
+            streams["pallas"][i], streams["xla"][i],
+            err_msg=f"pallas req {i} diverged",
+        )
 
 
 @pytest.mark.slow
 def test_batcher_split_speculative_int8():
-    """Split dispatch composes with speculative mode over int8 pools:
-    the spec stream under (pallas, split=2) equals the XLA-path spec
-    stream AND solo generate(int8)."""
+    """The forced Pallas kernels compose with speculative mode over
+    int8 pools: their spec stream equals the XLA-path spec stream AND
+    solo generate(int8)."""
     lm = transformer_lm(VOCAB, 32, 2, 2, 64, max_len=256,
                         name="split_spec")
     variables = lm.graph.init(
@@ -382,7 +379,7 @@ def test_batcher_split_speculative_int8():
     outs = {}
     for tag, kern in (
         ("xla", None),
-        ("s2", KernelConfig(attn_impl="pallas", decode_split=2)),
+        ("pallas", KernelConfig(attn_impl="pallas")),
     ):
         bat = ContinuousBatcher(
             lm, variables, slots=2, kv_layout="paged", page_size=128,
@@ -393,8 +390,8 @@ def test_batcher_split_speculative_int8():
         outs[tag] = bat.run()[r]
         bat.close()
     solo = _solo(lm, variables, p, 10, kv_cache_dtype="int8")
-    np.testing.assert_array_equal(outs["s2"], outs["xla"])
-    np.testing.assert_array_equal(outs["s2"], solo)
+    np.testing.assert_array_equal(outs["pallas"], outs["xla"])
+    np.testing.assert_array_equal(outs["pallas"], solo)
 
 
 # -- tree drafts -------------------------------------------------------------

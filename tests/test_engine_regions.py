@@ -10,7 +10,6 @@ import numpy as np
 import pytest
 from jax.profiler import TraceAnnotation
 
-from adapt_tpu.config import RuntimeConfig
 from adapt_tpu.models.transformer_lm import lm_tiny
 from adapt_tpu.runtime.continuous import ContinuousBatcher
 from adapt_tpu.utils.metrics import global_metrics
@@ -54,9 +53,6 @@ def _paged_batcher(lm_setup):
     return ContinuousBatcher(
         lm, variables, slots=2, chunk=2, kv_layout="paged", page_size=8,
         pool_pages=20, prefill_chunk=8, prompt_buckets=(8, 16, 32),
-        # These tests count spans and samples PER TICK (a launch and
-        # its commit half in the same call): the synchronous order.
-        runtime=RuntimeConfig(pipeline_depth=1),
     )
 
 
@@ -84,6 +80,7 @@ def test_traced_ticks_hold_the_span_tree(lm_setup, tmp_path):
         _submit_chunked_and_whole(bat)
         for _ in range(5):
             bat.tick()
+    bat.drain()  # the fifth tick's commit half: outside the trace
     trace = xtrace.load(xtrace.find_xplane(str(tmp_path)))
     spans = {}
     for s, e, name in trace.host:
@@ -91,12 +88,12 @@ def test_traced_ticks_hold_the_span_tree(lm_setup, tmp_path):
             spans.setdefault(name, []).append((s, e))
     assert set(spans) == set(TREE) | {"engine.tick"}
     assert len(spans["engine.tick"]) == 5
-    # The phases a tick always has, once each; a launch and its commit
-    # half in every tick that decoded.
-    for name in ("engine.admit", "engine.prefill"):
+    # The phases a tick always has, once each; a launch in every tick
+    # that decoded, and the commit half of the tick before it.
+    for name in ("engine.admit", "engine.prefill", "engine.launch"):
         assert len(spans[name]) == 5, name
     for name in ("engine.fetch", "engine.commit", "engine.update"):
-        assert len(spans[name]) == len(spans["engine.launch"]) == 5, name
+        assert len(spans[name]) == 4, name
     assert len(spans["engine.prefill_chunk"]) == 3
     for child, parents in TREE.items():
         for span in spans[child]:
@@ -109,10 +106,14 @@ def test_traced_ticks_hold_the_span_tree(lm_setup, tmp_path):
     assert sum(bool(_inside(f, spans["engine.admit"])) for f in firsts) == 1
     assert sum(bool(_inside(f, spans["engine.prefill"])) for f in firsts) == 1
     # The phases of one tick follow each other; none overlaps the next.
-    tick = spans["engine.tick"][0]
+    # The second tick: its own dispatch, then the first one's commit.
+    tick = spans["engine.tick"][1]
     order = [
-        spans[n][0] for n in (
+        spans[n][1] for n in (
             "engine.admit", "engine.prefill", "engine.launch",
+        )
+    ] + [
+        spans[n][0] for n in (
             "engine.fetch", "engine.commit", "engine.update",
         )
     ]
@@ -172,11 +173,13 @@ def test_batcher_phases_reach_histograms_and_ring_through_region(
     before = {n: _samples(n) for n in names}
     seq = tracer.spans_since(0)[1]
     bat.tick()  # gate and tracer off: nothing recorded
+    bat.drain()
     assert {n: _samples(n) for n in names} == before
     assert not tracer.spans_since(seq)[0]
     eo.enabled = tracer.enabled = True
     for _ in range(4):
         bat.tick()
+    bat.drain()  # the fourth tick's commit half
     eo.enabled = tracer.enabled = False
     got = {n: _samples(n) - before[n] for n in names}
     # The first tick above took the whole-prompt admission and the first

@@ -15,7 +15,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from adapt_tpu.config import RuntimeConfig
 from adapt_tpu.models.kda import KdaMixer, KdaSpec, kda_chunked, kda_recurrent
 from adapt_tpu.models.moe import ExpertSpec, RoutedExperts
 from adapt_tpu.models.transformer_lm import (
@@ -33,6 +32,7 @@ from adapt_tpu.ops.kda_step import (
 from adapt_tpu.runtime.continuous import ContinuousBatcher
 from adapt_tpu.runtime.paged import cache_groups
 from adapt_tpu.utils.metrics import global_metrics
+from conftest import drained
 
 ROOT = Path(__file__).resolve().parents[1]
 PAGE, CHUNK = 16, 4
@@ -213,7 +213,8 @@ def served(built):
     beside dead rows. A prompt: the ids served, their logprobs, the
     counters the request moved."""
     lm, variables, shape = built
-    srv = _batcher(lm, variables, runtime=RuntimeConfig(pipeline_depth=1))
+    # No scan past a request's end: the counters below are its own.
+    srv = drained(_batcher(lm, variables))
     out = {}
     for n in PROMPTS:
         snap = global_metrics().snapshot(window=True)

@@ -135,24 +135,26 @@ def test_a_window_layer_holds_its_window_while_a_full_layer_grows(served):
     assert full_peak == 9 > bound
 
 
-@pytest.mark.parametrize("depth", [1, None])
-def test_the_expert_counters_say_how_the_routing_fell(built, depth):
+@pytest.mark.parametrize("order", ["drained", "overlapped"])
+def test_the_expert_counters_say_how_the_routing_fell(built, order):
     """The counters book what the DEVICE routed: under the overlapped
-    order (the default) that is one chunk more, the one dispatched
-    before the host learned that the request had ended
+    order that is one chunk more than with every tick drained, the one
+    dispatched before the host learned that the request had ended
     (``runtime.rows_past_end``)."""
-    from adapt_tpu.config import RuntimeConfig
     from adapt_tpu.utils.metrics import global_metrics
+    from conftest import drained
 
     lm, variables, _ = built
-    srv = _batcher(lm, variables, runtime=RuntimeConfig(pipeline_depth=depth))
+    srv = _batcher(lm, variables)
+    if order == "drained":
+        drained(srv)
     snap = global_metrics().snapshot(window=True)
     srv.submit(np.arange(10, dtype=np.int32), 13)
     srv.run()
     srv.close()
     c = global_metrics().snapshot(since=snap)["counters"]
     past_end = c.get("runtime.rows_past_end", 0)
-    assert past_end == (0 if depth == 1 else 1)
+    assert past_end == (0 if order == "drained" else 1)
     # 12 tokens after the prefill's, and the chunk past the end.
     steps = (3 + past_end) * CHUNK
     assert c["moe.steps"] == steps

@@ -31,9 +31,11 @@ from adapt_tpu.ops.paged_attention import (
 from adapt_tpu.ops.quantize import quantize_kv_vectors
 from adapt_tpu.runtime.continuous import ContinuousBatcher
 from adapt_tpu.runtime.paged import (
+    CACHE_PROPERTIES,
     CacheGroup,
     Pager,
     alloc_kv_pools,
+    cache_layout,
     group_pool_pages,
     insert_prefill_pages,
     kv_value_width,
@@ -87,6 +89,97 @@ def test_hold_past_a_rows_end_releases_and_grants_nothing():
     assert p.owned(0) == [] and p.base(0) == 6 and p.stats().free == 3
     p.free_slot(0)
     assert p.base(0) == 0
+
+
+def _toy_specs(kind):
+    from adapt_tpu.models.kda import KdaSpec
+    from adapt_tpu.models.mla import LatentSpec, YarnSpec
+    from adapt_tpu.models.ssm import SsmSpec
+    from adapt_tpu.models.transformer_lm import BlockSpec
+
+    def spec(**kw):
+        return BlockSpec(32, 4, 64, **kw)
+
+    return {
+        "one_group": [spec(), spec()],
+        "two_groups": [spec(window=8), spec(), spec(window=8)],
+        "state": [spec(ssm=SsmSpec(
+            heads=4, head_dim=16, d_state=32, groups=2, chunk=16
+        ))] * 2,
+        "linear": [
+            spec(), spec(linear=KdaSpec(heads=4, head_dim=8, rank=4))
+        ],
+        "latent": [spec(rope_base=1e4, latent=LatentSpec(
+            24, 32, 16, 8, 16, yarn=YarnSpec(64.0, 4096, 32.0, 1.0, 1.0, 1.0)
+        ))] * 2,
+    }[kind]
+
+
+#: What PR 47's constructor refused in each of its three tables
+#: (several cache groups, recurrent state, a latent row), in that order.
+_REFUSED_AT_PR47 = dict(
+    one_group=(
+        "a draft model", "a tp mesh", "a host cache tier",
+        "sequence-parallel prefill",
+        "cache-aware admission (the radix prefix cache)",
+        "a handoff of prefilled pages", "the radix prefix cache",
+        "copy-on-write fan-out",
+    ),
+    pages_only=(
+        "a draft model", "a host cache tier", "sequence-parallel prefill",
+        "cache-aware admission (the radix prefix cache)",
+        "elastic recovery (health=)", "a quantized KV pool",
+        "a handoff of prefilled pages", "the radix prefix cache",
+        "copy-on-write fan-out",
+    ),
+    per_head_pages=(
+        "a draft model", "a tp mesh", "a host cache tier",
+        "sequence-parallel prefill", "elastic recovery (health=)",
+        "a quantized KV pool", "a handoff of prefilled pages",
+    ),
+)
+
+
+@pytest.mark.parametrize("kind, groups, group_of, state, latent, lacks", [
+    ("one_group", ["full"], (0, 0), (), (), ()),
+    ("two_groups", ["full", "window"], (1, 0, 1), (), (), ("one_group",)),
+    ("state", ["full"], (0, 0), (0, 1), (), ("pages_only",)),
+    ("linear", ["full"], (0, 0), (1,), (), ("pages_only",)),
+    ("latent", ["full"], (0, 0), (), (0, 1), ("per_head_pages",)),
+])
+def test_the_cache_layout_is_what_the_batcher_derived_piecemeal(
+    kind, groups, group_of, state, latent, lacks
+):
+    """``cache_layout`` says of a model's blocks what the batcher's
+    constructor worked out in five loops (the groups with the one that
+    reserves whole first, a block's group, the blocks with a state and
+    the latent ones), and the one table refuses, for each kind of
+    cache, exactly the features the three tables did, with that kind's
+    sentence."""
+    from adapt_tpu.runtime import continuous
+
+    layout = cache_layout(_toy_specs(kind))
+    assert [g.name for g in layout.groups] == groups
+    assert layout.group_of == group_of
+    assert layout.state_blocks == state
+    assert layout.latent_blocks == latent
+    assert tuple(
+        p for p in CACHE_PROPERTIES if layout.lacks(p) is not None
+    ) == lacks
+    assert set(continuous._CACHE_NEEDS) == set().union(
+        *_REFUSED_AT_PR47.values()
+    )
+    for feature, needs in continuous._CACHE_NEEDS.items():
+        assert set(needs) == {
+            p for p, told in _REFUSED_AT_PR47.items() if feature in told
+        }, feature
+        refused = [p for p in lacks if p in needs]
+        unmet = continuous._unmet(layout, feature)
+        if not refused:
+            assert unmet is None, feature
+            continue
+        assert unmet[0].startswith(feature)
+        assert unmet[1:] == layout.lacks(refused[0])
 
 
 @pytest.mark.parametrize("seed", [0, 1])

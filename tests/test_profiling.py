@@ -15,7 +15,6 @@ import numpy as np
 import pytest
 
 from benchmarks import ci_gate
-from adapt_tpu.config import RuntimeConfig
 from adapt_tpu.models.transformer_lm import lm_tiny
 from adapt_tpu.runtime.continuous import ContinuousBatcher
 from adapt_tpu.utils import profiling
@@ -221,13 +220,14 @@ def test_batcher_forced_shape_change_fires_sentinel(lm_setup):
     old_warmup = sent.warmup_samples
     sent.warmup_samples = 3
     try:
-        # One sample per phase PER TICK is the assertion below: the
-        # synchronous order (overlapped, a tick's decode and commit
-        # samples land one call later).
-        bat = ContinuousBatcher(
-            lm, variables, slots=2, chunk=2,
-            runtime=RuntimeConfig(pipeline_depth=1),
-        )
+        bat = ContinuousBatcher(lm, variables, slots=2, chunk=2)
+
+        def tick():
+            # One sample per phase PER TICK is the assertion below: a
+            # tick's decode and commit samples land with its drain.
+            bat.tick()
+            bat.drain()
+
         prompt = np.asarray([1, 2, 3], np.int32)
         r1 = bat.submit(prompt, 40)
 
@@ -240,13 +240,13 @@ def test_batcher_forced_shape_change_fires_sentinel(lm_setup):
         phases = ("admit", "prefill", "decode", "commit", "update")
         before = {n: phase_count(n) for n in phases}
         for _ in range(3):  # gate off: no phase samples recorded
-            bat.tick()
+            tick()
         for n, c in before.items():
             assert phase_count(n) == c, n
         eo.enabled = True
         try:
             for _ in range(3):  # past warmup, steady greedy decode
-                bat.tick()
+                tick()
             for n, c in before.items():
                 assert phase_count(n) >= c + 3, n
         finally:
@@ -260,7 +260,7 @@ def test_batcher_forced_shape_change_fires_sentinel(lm_setup):
             prompt, 4, temperature=0.7, top_k=5,
             rng=jax.random.PRNGKey(3),
         )
-        bat.tick()
+        tick()
         assert sent.events > events_before
         assert (
             global_metrics().counter("engine.compile_events")

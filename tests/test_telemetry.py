@@ -622,7 +622,16 @@ def test_two_process_fleet_metrics_forensics_and_staleness(
         del os.environ["ADAPT_TPU_TRACE"]
     cfg = ServeConfig(
         fault=FaultConfig(
-            lease_ttl_s=2.0,
+            # A lease no starved child can lapse: under the suite's six
+            # workers the worker process, compiling its stage, has gone
+            # 2 s without a ping; its lease lapsed, the dispatcher
+            # rebound stage 1 to the in-process worker, the request
+            # completed THERE and ``remote.stage_execs`` never moved in
+            # the worker whose reports this test reads (PR 48: a 3 s
+            # SIGSTOP of the worker before the submit reproduces it).
+            # The staleness checks below read report AGE after a kill,
+            # not the lease.
+            lease_ttl_s=120.0,
             heartbeat_s=0.2,
             task_deadline_s=30.0,
             watchdog_period_s=0.2,
@@ -671,12 +680,9 @@ def test_two_process_fleet_metrics_forensics_and_staleness(
         rid = fut.request_id
 
         # Wait for at least one POST-EXEC report from the worker
-        # (pushes every ~0.3s on the dispatcher link's ping thread —
-        # which a loaded machine starves: under the suite's six
-        # workers the first such report took over 20 s, the loop gave
-        # up with a pre-exec report in hand, and the assertions below
-        # failed on counters that had not arrived yet).
-        deadline = time.monotonic() + 120.0
+        # (pushed every ~0.3 s on the dispatcher link's ping thread;
+        # the reports before it are pre-exec).
+        deadline = time.monotonic() + 60.0
         wkey = None
         post_exec = False
         while time.monotonic() < deadline and not post_exec:
