@@ -1,8 +1,9 @@
 """A gated delta-rule linear-attention mixer (Kimi Delta Attention: the
-delta rule with a decay per key channel) as the hybrid decoders use it
-IN PLACE OF attention in a block (``transformer_lm.BlockSpec.linear``):
-such a block holds a recurrent state a request and NO pages. One
-parameter structure, three schedules over it, as ``models/ssm``:
+delta rule with a decay per key channel; Gated DeltaNet: the same rule
+with ONE decay a head) as the hybrid decoders use it IN PLACE OF
+attention in a block (``transformer_lm.BlockSpec.linear``): such a
+block holds a recurrent state a request and NO pages. One parameter
+structure, three schedules over it, as ``models/ssm``:
 
 - **whole prompt / chunk pass** (:meth:`KdaMixer.scan`): the recurrence
   in its chunked form at ``_CHUNK`` positions a chunk (a unit
@@ -22,14 +23,18 @@ the causal convolution over ``q | k | v``.
 
 Per position, ``u`` the block's normed input (no projection bias):
 
-    q, k, v  = silu(conv1d_causal(W_qkv u))           heads x d each
+    q, k, v  = silu(conv1d_causal(W_qkv u))   q, k: key_heads x d; v: heads x d
     q        = q / |q|_2 * d_k^-1/2;   k = k / |k|_2
+               (value head h reads key head h // (heads / key_heads))
     g        = -exp(A_log) * softplus(W_f2 (W_f1 u) + dt_bias)
     alpha    = exp(g)                   a head AND key channel, in (0, 1)
+      or, ``head_decay``:  g = -exp(A_log) * softplus(W_a u + dt_bias),
+               ONE scalar a head
     beta     = sigmoid(W_b u) * (2 if neg_eigval else 1)       a head
     S_t      = (I - beta k k^T) Diag(alpha) S_{t-1} + beta k v^T
     o_t      = S_t^T q
-    out      = W_o [RMSNorm_head(o) * sigmoid(W_g2 (W_g1 u))]
+    out      = W_o [RMSNorm_head(o) * gate_scale sigmoid(W_g2 (W_g1 u))]
+               (``rank`` None: one full-width W_g)
 """
 
 from __future__ import annotations
@@ -41,7 +46,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from adapt_tpu.models.ssm import init_a_log, zero_state
+from adapt_tpu.models.ssm import init_a_log, scaled, zero_state
 from adapt_tpu.ops.kda_step import kda_step
 
 F32 = jnp.float32
@@ -55,14 +60,41 @@ class KdaSpec:
     """A block's linear-attention mixer, read from a model's
     configuration."""
 
-    heads: int
+    heads: int  # value heads: a state a head
     head_dim: int  # d_k = d_v
-    #: Width of the low-rank pairs that make the decay and the gate.
-    rank: int
+    #: Width of the low-rank pairs that make the decay a key channel
+    #: and the gate; None: the gate is ONE full-width projection.
+    rank: int | None
     d_conv: int = 4
     #: ``beta`` in (0, 2): ``I - beta k k^T`` may flip a direction.
     neg_eigval: bool = True
     norm_eps: float = 1e-5
+    #: Heads of q and k where fewer than ``heads``: value head ``h``
+    #: reads key head ``h // (heads // key_heads)``. None: ``heads``.
+    key_heads: int | None = None
+    #: ONE decay a head from one projection (``A_log`` and ``dt_bias``
+    #: a head: Gated DeltaNet), not one a key channel from a low-rank
+    #: pair (Kimi Delta Attention).
+    head_decay: bool = False
+    #: What multiplies the output gate's sigmoid (2: the gate is one at
+    #: a pre-activation of zero).
+    gate_scale: float = 1.0
+
+    def __post_init__(self):
+        if self.heads % self.qk_heads:
+            raise ValueError(
+                f"heads {self.heads} not divisible by key_heads "
+                f"{self.key_heads}"
+            )
+        if self.rank is None and not self.head_decay:
+            raise ValueError(
+                "a decay a key channel is made by a low-rank pair: rank "
+                "says how wide"
+            )
+
+    @property
+    def qk_heads(self) -> int:
+        return self.key_heads or self.heads
 
     @property
     def d_inner(self) -> int:
@@ -71,7 +103,7 @@ class KdaSpec:
     @property
     def conv_dim(self) -> int:
         """Channels of the convolution: ``q | k | v``."""
-        return 3 * self.d_inner
+        return (2 * self.qk_heads + self.heads) * self.head_dim
 
     def state_shapes(self, rows: int, dtype):
         """``(state, tail)`` of ``rows`` requests, as shape structs."""
@@ -112,13 +144,22 @@ class KdaMixer(nn.Module):
             "conv_kernel", nn.initializers.lecun_normal(),
             (spec.d_conv, spec.conv_dim),
         )
-        self.f_down = dense(spec.rank, "f_down")
-        self.f_up = dense(spec.d_inner, "f_up")
+        if spec.head_decay:
+            self.a_proj = dense(spec.heads, "a_proj")
+        else:
+            self.f_down = dense(spec.rank, "f_down")
+            self.f_up = dense(spec.d_inner, "f_up")
         self.a_log = self.param("A_log", init_a_log, (spec.heads,))
-        self.dt_bias = self.param("dt_bias", init_dt_bias, (spec.d_inner,))
+        self.dt_bias = self.param(
+            "dt_bias", init_dt_bias,
+            (spec.heads if spec.head_decay else spec.d_inner,),
+        )
         self.b_proj = dense(spec.heads, "b_proj")
-        self.g_down = dense(spec.rank, "g_down")
-        self.g_up = dense(spec.d_inner, "g_up")
+        if spec.rank is None:
+            self.g_proj = dense(spec.d_inner, "g_proj")
+        else:
+            self.g_down = dense(spec.rank, "g_down")
+            self.g_up = dense(spec.d_inner, "g_up")
         self.norm_scale = self.param(
             "norm_scale", nn.initializers.ones, (spec.head_dim,)
         )
@@ -127,7 +168,7 @@ class KdaMixer(nn.Module):
     # -- the pieces every schedule shares ------------------------------
 
     def _heads(self, t):
-        return t.reshape(*t.shape[:-1], self.spec.heads, self.spec.head_dim)
+        return t.reshape(*t.shape[:-1], -1, self.spec.head_dim)
 
     def _conv(self, full, s: int):
         """``full`` (b, d_conv - 1 + s, conv_dim): the inputs of ``s``
@@ -139,27 +180,49 @@ class KdaMixer(nn.Module):
             full[:, j: j + s].astype(F32) * w[j]
             for j in range(self.spec.d_conv)
         ))
-        q, k, v = (self._heads(t) for t in jnp.split(out, 3, axis=-1))
+        spec = self.spec
+        if spec.key_heads is None:
+            q, k, v = (self._heads(t) for t in jnp.split(out, 3, axis=-1))
+        else:
+            qk = spec.qk_heads * spec.head_dim
+            q, k, v = (
+                self._heads(t)
+                for t in jnp.split(out, (qk, 2 * qk), axis=-1)
+            )
 
         def unit(t):
             return t * lax.rsqrt(jnp.sum(t * t, -1, keepdims=True) + 1e-6)
 
-        q = unit(q) * self.spec.head_dim ** -0.5
-        return tuple(t.astype(self.dtype) for t in (q, unit(k), v))
+        q, k = unit(q) * spec.head_dim ** -0.5, unit(k)
+        if spec.key_heads is not None:
+            # A key head under each of its value heads: from here on
+            # every schedule counts value heads.
+            q, k = (
+                jnp.repeat(t, spec.heads // spec.qk_heads, axis=-2)
+                for t in (q, k)
+            )
+        return tuple(t.astype(self.dtype) for t in (q, k, v))
 
     def _gates(self, u, live):
-        """``g`` (.., heads, d_k) and ``beta`` (.., heads) in float32;
-        where ``live`` (broadcast over the leading axes) is false both
-        are zero: the position steps nothing."""
-        f = self._heads(
-            self.f_up(self.f_down(u)).astype(F32) + self.dt_bias.astype(F32)
-        )
-        g = -jnp.exp(self.a_log.astype(F32))[:, None] * jax.nn.softplus(f)
+        """``g`` (.., heads, d_k), or (.., heads) where the decay is a
+        head's, and ``beta`` (.., heads) in float32; where ``live``
+        (broadcast over the leading axes) is false both are zero: the
+        position steps nothing."""
+        if self.spec.head_decay:
+            f = self.a_proj(u).astype(F32) + self.dt_bias.astype(F32)
+            g = -jnp.exp(self.a_log.astype(F32)) * jax.nn.softplus(f)
+        else:
+            f = self._heads(
+                self.f_up(self.f_down(u)).astype(F32)
+                + self.dt_bias.astype(F32)
+            )
+            g = -jnp.exp(self.a_log.astype(F32))[:, None] * jax.nn.softplus(f)
         beta = jax.nn.sigmoid(self.b_proj(u).astype(F32))
         if self.spec.neg_eigval:
             beta = 2.0 * beta
+        over = (None,) * (g.ndim - beta.ndim + 1)  # a head's channels too
         return (
-            jnp.where(live[..., None, None], g, 0.0),
+            jnp.where(live[(..., *over)], g, 0.0),
             jnp.where(live[..., None], beta, 0.0),
         )
 
@@ -170,7 +233,11 @@ class KdaMixer(nn.Module):
         o = o * lax.rsqrt(
             jnp.mean(o * o, axis=-1, keepdims=True) + self.spec.norm_eps
         ) * self.norm_scale.astype(F32)
-        gate = jax.nn.sigmoid(self.g_up(self.g_down(u)).astype(F32))
+        z = (
+            self.g_proj(u) if self.spec.rank is None
+            else self.g_up(self.g_down(u))
+        )
+        gate = scaled(jax.nn.sigmoid(z.astype(F32)), self.spec.gate_scale)
         o = o.reshape(*o.shape[:-2], self.spec.d_inner) * gate
         return self.out_proj(o.astype(self.dtype))
 
@@ -201,8 +268,9 @@ class KdaMixer(nn.Module):
                 full, length, spec.d_conv - 1, axis=1
             )
         g, beta = self._gates(u, live)
+        chunked = kda_chunked_head if spec.head_decay else kda_chunked
         with jax.named_scope("kda_prefill_scan"):
-            o, state = jax.vmap(kda_chunked)(q, k, v, g, beta, state)
+            o, state = jax.vmap(chunked)(q, k, v, g, beta, state)
         return self._finish(o, u), (state, new_tail.astype(tail.dtype))
 
     def step(self, u_t, carried, live, prefer=None):
@@ -217,7 +285,10 @@ class KdaMixer(nn.Module):
         )
         q, k, v = (t[:, 0] for t in self._conv(window, 1))
         g, beta = self._gates(u, live)
-        o, state = kda_step(state, q, k, v, jnp.exp(g), beta, prefer=prefer)
+        alpha = jnp.exp(g)
+        if self.spec.head_decay:  # the head's scalar over its channels
+            alpha = jnp.broadcast_to(alpha[..., None], k.shape)
+        o, state = kda_step(state, q, k, v, alpha, beta, prefer=prefer)
         tail = jnp.where(
             live[:, None, None], window[:, 1:].astype(tail.dtype), tail
         )
@@ -263,6 +334,34 @@ def _solve_unit_lower(a, rhs):
     return lax.scan(row, jnp.zeros_like(rhs), jnp.arange(c))[0]
 
 
+def _mm(spec, a, b):
+    return jnp.einsum(spec, a, b, precision=_HIGHEST)
+
+
+def _whole_chunks(q, k, v, g, beta, chunk):
+    """The operands of a chunked form padded to whole chunks with
+    positions that step nothing, q, k and v in float32."""
+    pad = -q.shape[0] % chunk
+    if pad:  # g = 0 and beta = 0: steps nothing
+        q, k, v, g, beta = (
+            jnp.pad(t, ((0, pad),) + ((0, 0),) * (t.ndim - 1))
+            for t in (q, k, v, g, beta)
+        )
+    q, k, v = (t.astype(F32) for t in (q, k, v))
+    return q, k, v, g, beta
+
+
+def _scan_chunks(one, state, operands, chunk, s):
+    """``one(state, (q, k, v, g, beta))`` -> ``(state, o)`` over chunks
+    of ``chunk`` positions; the ``s`` real positions' outputs and the
+    state left."""
+    def chunks(t):
+        return t.reshape(-1, chunk, *t.shape[1:])
+
+    state, o = lax.scan(one, state, tuple(chunks(t) for t in operands))
+    return o.reshape(-1, *o.shape[2:])[:s], state
+
+
 def kda_chunked(q, k, v, g, beta, state, chunk=_CHUNK):
     """:func:`kda_recurrent` in its chunked form: a scan over chunks of
     ``chunk`` positions carries the state; inside a chunk, with ``G``
@@ -277,18 +376,9 @@ def kda_chunked(q, k, v, g, beta, state, chunk=_CHUNK):
     everything in float32. ``exp(G_r - G_i)`` is formed pairwise (never
     ``1 / exp(G_i)`` alone, which overflows under a strong decay), and
     only where ``i <= r``, where it is at most one."""
-    s, heads, d_k = q.shape
-    pad = -s % chunk
-    if pad:  # g = 0 and beta = 0: steps nothing
-        q, k, v, g, beta = (
-            jnp.pad(t, ((0, pad),) + ((0, 0),) * (t.ndim - 1))
-            for t in (q, k, v, g, beta)
-        )
-    q, k, v = (t.astype(F32) for t in (q, k, v))
+    s = q.shape[0]
+    q, k, v, g, beta = _whole_chunks(q, k, v, g, beta, chunk)
     at_or_before = jnp.tril(jnp.ones((chunk, chunk), bool))
-
-    def mm(spec, a, b):
-        return jnp.einsum(spec, a, b, precision=_HIGHEST)
 
     def one(state, xs):
         q, k, v, g, beta = xs  # (C, H, d), (C, H)
@@ -305,20 +395,61 @@ def kda_chunked(q, k, v, g, beta, state, chunk=_CHUNK):
         bh = beta.T  # (H, C)
         into = jnp.exp(gh)  # the decay from the chunk's start through r
         rhs = bh[..., None] * (
-            jnp.swapaxes(v, 0, 1) - mm("hrk,hkv->hrv", into * kh, state)
+            jnp.swapaxes(v, 0, 1) - _mm("hrk,hkv->hrv", into * kh, state)
         )
         w = _solve_unit_lower(bh[..., None] * kk, rhs)  # (H, C, d_v)
-        o = mm("hrk,hkv->hrv", into * qh, state) + mm("hri,hiv->hrv", qk, w)
+        o = _mm("hrk,hkv->hrv", into * qh, state) + _mm(
+            "hri,hiv->hrv", qk, w
+        )
         to_end = jnp.exp(gh[:, -1:, :] - gh)  # (H, C, d_k)
-        state = state * into[:, -1, :, None] + mm(
+        state = state * into[:, -1, :, None] + _mm(
             "hik,hiv->hkv", to_end * kh, w
         )
         return state, jnp.swapaxes(o, 0, 1)
 
-    def chunks(t):
-        return t.reshape(-1, chunk, *t.shape[1:])
+    return _scan_chunks(one, state, (q, k, v, g, beta), chunk, s)
 
-    state, o = lax.scan(
-        one, state, tuple(chunks(t) for t in (q, k, v, g, beta))
-    )
-    return o.reshape(-1, heads, v.shape[-1])[:s], state
+
+def kda_chunked_head(q, k, v, g, beta, state, chunk=_CHUNK):
+    """:func:`kda_chunked` where the decay is ONE scalar a head (``g``
+    (s, H)): the decay leaves the channel sums, so that ``K K^T`` and
+    ``Q K^T`` are matrix products and the pairwise decays a (C, C)
+    matrix a head, not (C, C, d_k):
+
+        A_ri = exp(G_r - G_i) (k_r . k_i)                      (i < r)
+        (I + Diag(beta) tril(A, -1)) W = Diag(beta) (V - exp(G) * (K S_0))
+        o_r  = exp(G_r) S_0^T q_r
+               + sum_{i <= r} exp(G_r - G_i) (q_r . k_i) w_i
+        S_C  = exp(G_C) S_0 + sum_i exp(G_C - G_i) k_i w_i^T
+
+    ``exp(G_r - G_i)`` is still formed pairwise and only where
+    ``i <= r``."""
+    s = q.shape[0]
+    q, k, v, g, beta = _whole_chunks(q, k, v, g, beta, chunk)
+    at_or_before = jnp.tril(jnp.ones((chunk, chunk), bool))
+
+    def one(state, xs):
+        q, k, v, g, beta = xs  # (C, H, d), (C, H)
+        gh = jnp.cumsum(g, axis=0).T  # (H, C): log decay through r
+        between = jnp.exp(jnp.where(
+            at_or_before, gh[:, :, None] - gh[:, None, :], -jnp.inf
+        ))  # (H, r, i)
+        kh, qh = jnp.swapaxes(k, 0, 1), jnp.swapaxes(q, 0, 1)
+        kk = between * _mm("hrk,hik->hri", kh, kh)
+        qk = between * _mm("hrk,hik->hri", qh, kh)
+        bh = beta.T[..., None]  # (H, C, 1)
+        into = jnp.exp(gh)[..., None]  # from the chunk's start through r
+        rhs = bh * (
+            jnp.swapaxes(v, 0, 1) - into * _mm("hrk,hkv->hrv", kh, state)
+        )
+        w = _solve_unit_lower(bh * kk, rhs)  # (H, C, d_v)
+        o = into * _mm("hrk,hkv->hrv", qh, state) + _mm(
+            "hri,hiv->hrv", qk, w
+        )
+        to_end = jnp.exp(gh[:, -1:] - gh)[..., None]  # (H, C, 1)
+        state = state * into[:, -1, :, None] + _mm(
+            "hik,hiv->hkv", to_end * kh, w
+        )
+        return state, jnp.swapaxes(o, 0, 1)
+
+    return _scan_chunks(one, state, (q, k, v, g, beta), chunk, s)

@@ -13,6 +13,9 @@ bias:
     spec names them); ONE k_r for all heads
     k_h = [c_kv W_UK,h | k_r],  v_h = c_kv W_UV,h
     a   = concat_h(softmax(q_h k_h^T * scale) v_h) W_o
+    (``spec.attn_gate``: the heads' values times sigmoid(u W_g), element
+    by element, before W_o; in the absorbed form after ``W_UV``, so the
+    kernels are the ungated layer's)
 
 THE CACHE holds ``[c_kv | k_r]``: ``kv_rank + rope`` values a position,
 once, not per head (``ops/latent_attention``). The full forward and the
@@ -27,6 +30,7 @@ from __future__ import annotations
 import dataclasses
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -118,6 +122,8 @@ class LatentSelfAttention(nn.Module):
         self.kv_b = self.param(
             "kv_b", fan_in, (lat.kv_rank, spec.heads, lat.nope_dim + lat.v_dim)
         )
+        if spec.attn_gate:
+            self.gate = dense(spec.heads * lat.v_dim, "gate")
         self.out = dense(spec.dim, "out")
 
     def _rotate(self, x, positions):
@@ -152,7 +158,17 @@ class LatentSelfAttention(nn.Module):
         nope = self.spec.latent.nope_dim
         return w[..., :nope], w[..., nope:]
 
-    def _expanded(self, q_nope, q_rope, rows):
+    def _finish(self, o, x):
+        """The tail both forms share: the heads' values (b, s, h *
+        v_dim) of the block input ``x``, gated where the spec says (the
+        sigmoid in float32, as ``CausalSelfAttention._finish``),
+        projected out."""
+        if self.spec.attn_gate:
+            gate = jax.nn.sigmoid(self.gate(x).astype(jnp.float32))
+            o = (o.astype(jnp.float32) * gate).astype(o.dtype)
+        return self.out(o)
+
+    def _expanded(self, x, q_nope, q_rope, rows):
         """Full causal attention with K and V expanded a head."""
         lat = self.spec.latent
         b, s = rows.shape[:2]
@@ -174,7 +190,7 @@ class LatentSelfAttention(nn.Module):
         )
         v = jnp.pad(v, [(0, 0)] * 3 + [(0, lat.qk_dim - lat.v_dim)])
         o = flash_attention(q, k, v, causal=True)[..., : lat.v_dim]
-        return self.out(jnp.swapaxes(o, 1, 2).reshape(b, s, -1))
+        return self._finish(jnp.swapaxes(o, 1, 2).reshape(b, s, -1), x)
 
     def _absorb_q(self, q_nope, q_rope):
         """-> q~ (b, s, h, row): ``W_UK`` folded into the query."""
@@ -183,14 +199,14 @@ class LatentSelfAttention(nn.Module):
             [jnp.einsum("bshn,rhn->bshr", q_nope, w_uk), q_rope], axis=-1
         )
 
-    def _unabsorb_o(self, o):
+    def _unabsorb_o(self, o, x):
         """(b, s, h, kv_rank) weighted latents -> the block's output."""
         _, w_uv = self._w_uk_uv()
         o = jnp.einsum("bshr,rhv->bshv", o, w_uv)
-        return self.out(o.reshape(*o.shape[:2], -1))
+        return self._finish(o.reshape(*o.shape[:2], -1), x)
 
     def __call__(self, x):
-        return self._expanded(*self._project(x, jnp.arange(x.shape[1])))
+        return self._expanded(x, *self._project(x, jnp.arange(x.shape[1])))
 
     def prefill(self, x, max_len: int, valid_from=None, quantize_cache=False):
         """Full causal attention over the prompt; returns ``(out, rows,
@@ -202,7 +218,7 @@ class LatentSelfAttention(nn.Module):
         if quantize_cache:
             latent_only("a quantized KV cache")
         q_nope, q_rope, rows = self._project(x, jnp.arange(x.shape[1]))
-        out = self._expanded(q_nope, q_rope, rows)
+        out = self._expanded(x, q_nope, q_rope, rows)
         return out, jnp.pad(
             rows, ((0, 0), (0, max_len - x.shape[1]), (0, 0))
         ), None
@@ -239,7 +255,7 @@ class LatentSelfAttention(nn.Module):
             sm_scale=lat.softmax_scale, v_width=lat.kv_rank,
             prefer=attn_impl,
         ).astype(x_t.dtype)
-        return self._unabsorb_o(o[:, None]), pool
+        return self._unabsorb_o(o[:, None], x_t), pool
 
     def prefill_chunk_paged(
         self, x, pool, pages, pos0, attn_impl=None, head_shard=None,
@@ -265,7 +281,7 @@ class LatentSelfAttention(nn.Module):
             jnp.swapaxes(self._absorb_q(q_nope, q_rope)[0], 0, 1),
             pool, pages, pos0, lat.softmax_scale, lat.kv_rank,
         ).astype(x.dtype)  # (h, C, kv_rank)
-        return self._unabsorb_o(jnp.swapaxes(o, 0, 1)[None]), pool
+        return self._unabsorb_o(jnp.swapaxes(o, 0, 1)[None], x), pool
 
     # -- what moves per-head K and V ---------------------------------------
 

@@ -206,6 +206,9 @@ class ExpertSpec:
     #: expert); what the absent experts would add is left out — no code
     #: stands in for the other chips or their exchange. None: all.
     held: tuple[int, int] | None = None
+    #: A ``swiglu_limit`` on every expert, the shared one too
+    #: (:func:`limited`); None: none.
+    swiglu_limit: float | None = None
 
     def __post_init__(self):
         if not 1 <= self.top_k <= self.num_experts:
@@ -289,17 +292,31 @@ def grouped_matmul(x, w, sizes, prefer=None, tiling=None):
     )[:m]
 
 
-@functools.partial(jax.jit, static_argnames=("prefer",))
-def expert_product(x, w_gate, w_up, w_down, sizes, prefer=None):
+def limited(t, limit: float | None, both: bool = False):
+    """A ``swiglu_limit`` on a gated SiLU MLP, ``down(silu(limited(gate))
+    * limited(up, both=True))``: the gate held from above, ``up`` on
+    ``both`` sides. No limit: ``t`` as it is, no clamp in the program
+    (and, called where the operand was, no operation moved in it)."""
+    if limit is None:
+        return t
+    return jnp.clip(t, -limit if both else None, limit)
+
+
+@functools.partial(jax.jit, static_argnames=("prefer", "limit"))
+def expert_product(x, w_gate, w_up, w_down, sizes, prefer=None, limit=None):
     """The grouped product of the experts held: rows of ``x`` (m, d),
     sorted by expert, against each expert's gated SiLU MLP —
     ``sizes[e]`` rows belong to expert ``e``, rows past their sum are
     nobody's and come back zero. Three :func:`grouped_matmul`: every
     row meets ONE expert's matrices. A jitted function of its own so
-    that a device trace shows its operations under one name."""
+    that a device trace shows its operations under one name. ``limit``:
+    the experts' ``swiglu_limit`` (:func:`limited`)."""
     gate = grouped_matmul(x, w_gate, sizes, prefer)
     up = grouped_matmul(x, w_up, sizes, prefer)
-    out = grouped_matmul(jax.nn.silu(gate) * up, w_down, sizes, prefer)
+    out = grouped_matmul(
+        jax.nn.silu(limited(gate, limit)) * limited(up, limit, both=True),
+        w_down, sizes, prefer,
+    )
     mine = jnp.arange(x.shape[0])[:, None] < jnp.sum(sizes)
     return jnp.where(mine, out, 0)
 
@@ -365,7 +382,7 @@ class RoutedExperts(nn.Module):
         xt = tokens.astype(self.dtype)
         y = expert_product(
             xt[tok], w_gate.astype(self.dtype), w_up.astype(self.dtype),
-            w_down.astype(self.dtype), sizes,
+            w_down.astype(self.dtype), sizes, limit=spec.swiglu_limit,
         )
         y = y.astype(jnp.float32) * w.reshape(n * k)[order][:, None]
         out = jnp.zeros((n, d), jnp.float32).at[tok].add(y)
@@ -375,9 +392,13 @@ class RoutedExperts(nn.Module):
                     m, dtype=self.dtype, use_bias=False, name=name
                 )
 
+            limit = spec.swiglu_limit
             out = out + dense(d, "shared_down")(
-                nn.silu(dense(spec.shared_dim, "shared_gate")(xt))
-                * dense(spec.shared_dim, "shared_up")(xt)
+                nn.silu(limited(
+                    dense(spec.shared_dim, "shared_gate")(xt), limit
+                )) * limited(
+                    dense(spec.shared_dim, "shared_up")(xt), limit, both=True
+                )
             ).astype(jnp.float32)
         return out.reshape(b, s, d).astype(x.dtype)
 

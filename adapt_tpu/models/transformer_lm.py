@@ -51,7 +51,12 @@ from adapt_tpu.ops.paged_attention import (
 from adapt_tpu.models.kda import KdaMixer, KdaSpec
 from adapt_tpu.models.mhc import HyperConnection, HyperSpec, merge
 from adapt_tpu.models.mla import LatentSelfAttention, LatentSpec
-from adapt_tpu.models.moe import ExpertSpec, MoEDecoderMlp, RoutedExperts
+from adapt_tpu.models.moe import (
+    ExpertSpec,
+    MoEDecoderMlp,
+    RoutedExperts,
+    limited,
+)
 from adapt_tpu.models.rope import apply_rope
 from adapt_tpu.models.ssm import Mamba2Mixer, SsmSpec, scaled
 from adapt_tpu.ops.quantize import LANES, quantize_kv_vectors, unpack_int4
@@ -93,6 +98,10 @@ class BlockSpec:
     #: False: the norm on each sub-layer's INPUT (``x + f(norm(x))``).
     #: True: on its OUTPUT (``x + norm(f(x))``).
     post_norm: bool = False
+    #: A norm on each sub-layer's input AND on its output, four a block
+    #: (``x + norm(f(norm(x)))``: ``ln1`` / ``ln2`` before, ``ln1_post``
+    #: / ``ln2_post`` after).
+    sandwich_norm: bool = False
     #: RMSNorm over head_dim (learned scale) on q and on k, before any
     #: rotation.
     qk_norm: bool = False
@@ -103,6 +112,9 @@ class BlockSpec:
     #: grouped product) or ``"moe_dense"`` (the masked-dense GELU
     #: mixture, ``moe_experts`` / ``moe_top_k``).
     mlp: str = "gelu"
+    #: A ``swiglu_limit`` on the ``"gated_silu"`` MLP
+    #: (``moe.limited``; the experts' is ``experts.swiglu_limit``).
+    swiglu_limit: float | None = None
     experts: ExpertSpec | None = None
     moe_experts: int | None = None
     moe_top_k: int = 1
@@ -130,8 +142,9 @@ class BlockSpec:
     #: no group), and only the schedules that carry a state serve it.
     #: ``heads`` repeats ``linear.heads``; no attention field applies.
     linear: KdaSpec | None = None
-    #: An output gate on the attention, ``attn * sigmoid(W_gate u)``
-    #: element by element, before the out-projection.
+    #: An output gate on the attention (latent attention too), ``attn *
+    #: sigmoid(W_gate u)`` element by element, before the
+    #: out-projection.
     attn_gate: bool = False
     #: The residual as ``streams.streams`` streams of ``dim`` mixed
     #: around every sub-layer (``models/mhc``); the block's input and
@@ -177,10 +190,23 @@ class BlockSpec:
             raise ValueError(f"mlp={self.mlp!r}")
         if (self.mlp == "experts") != (self.experts is not None):
             raise ValueError("mlp='experts' goes with an ExpertSpec")
+        if self.swiglu_limit is not None and self.mlp != "gated_silu":
+            raise ValueError(
+                f"swiglu_limit clamps a gated_silu MLP, not mlp={self.mlp!r} "
+                "(experts: ExpertSpec.swiglu_limit)"
+            )
         if self.ssm is not None and self.post_norm:
             raise ValueError(
                 "a mixer beside the attention reads the block's normed "
                 "INPUT: post_norm has none"
+            )
+        if self.sandwich_norm and (
+            self.post_norm or self.ssm or self.streams
+        ):
+            raise ValueError(
+                "sandwich_norm is norms on BOTH sides of a sub-layer: "
+                "post_norm says the output alone, and no norm is defined "
+                "on the sum of two mixers or on a write-back into streams"
             )
         if self.latent is not None:
             if self.rope_base is None:
@@ -221,17 +247,14 @@ class BlockSpec:
                 raise ValueError(
                     "qk_norm, attn_gate and post_norm do not apply to a "
                     "linear-attention block (the mixer normalises q and "
-                    "k, gates its own output and reads the normed INPUT)"
+                    "k, gates its own output and reads the normed INPUT; "
+                    "sandwich_norm adds a norm on its output)"
                 )
             if self.heads != self.linear.heads:
                 raise ValueError(
                     f"heads {self.heads} != linear.heads "
                     f"{self.linear.heads}"
                 )
-        if self.attn_gate and self.latent is not None:
-            raise ValueError(
-                "attn_gate is not defined for a latent-attention block"
-            )
 
     @property
     def attn_head_dim(self) -> int:
@@ -932,6 +955,9 @@ class DecoderBlock(nn.Module):
             )
             self.attn = attention(spec, dtype=self.dtype)
         self.ln2 = _norm(spec.norm, spec.norm_eps, self.dtype)
+        if spec.sandwich_norm:
+            self.ln1_post = _norm(spec.norm, spec.norm_eps, self.dtype)
+            self.ln2_post = _norm(spec.norm, spec.norm_eps, self.dtype)
         if spec.streams is not None:
             self.hc_attn = HyperConnection(
                 spec.streams, spec.dim, dtype=self.dtype
@@ -968,7 +994,10 @@ class DecoderBlock(nn.Module):
         if kind == "gated_silu":
             gate = scaled(self.mlp_gate(x), self.spec.mlp_gate_mult)
             return scaled(
-                self.mlp_out(nn.silu(gate) * self.mlp_in(x)),
+                self.mlp_out(
+                    nn.silu(limited(gate, self.spec.swiglu_limit))
+                    * limited(self.mlp_in(x), self.spec.swiglu_limit, True)
+                ),
                 self.spec.mlp_out_mult,
             )
         return self.mlp_out(nn.gelu(self.mlp_in(x)))
@@ -994,6 +1023,8 @@ class DecoderBlock(nn.Module):
             return merge(x, a, back)
         if s is not None:
             a = a + scaled(s, self.spec.ssm.out_mult)
+        if self.spec.sandwich_norm:
+            return x + self.ln1_post(a)
         return x + (self.ln1(a) if self.spec.post_norm else a)
 
     def _mixers(self, x, attend, mix=None, caches=1):
@@ -1007,6 +1038,8 @@ class DecoderBlock(nn.Module):
         (it has no pages), then ``carried'``."""
         if self.spec.linear is not None:
             s, carried = mix(self.mixer, self._attn_in(x))
+            if self.spec.sandwich_norm:
+                s = self.ln1_post(s)
             return (self._mlp_res(x + s), *(None,) * caches, carried)
         if self.spec.streams is None:
             u, back = self._attn_in(x), None
@@ -1032,6 +1065,8 @@ class DecoderBlock(nn.Module):
             return merge(x, self._mlp(self.ln2(u)), mix)
         if self.spec.post_norm:
             return x + self.ln2(self._mlp(x))
+        if self.spec.sandwich_norm:
+            return x + self.ln2_post(self._mlp(self.ln2(x)))
         return x + self._mlp(self.ln2(x))
 
     def __call__(self, x):

@@ -576,14 +576,23 @@ def _unmet(layout, *features: str) -> tuple[str, str, str] | None:
     """The first thing a cache of ``layout`` cannot do that one of
     ``features`` (rows of ``_CACHE_NEEDS``) needs, property by property
     and then in the order given: the feature as a refusal names it and
-    ``CacheLayout.lacks``'s pair. None: all run."""
+    ``CacheLayout.lacks``'s pair. A cache that lacks SEVERAL properties
+    the feature needs (a request that owns recurrent states AND latent
+    pages) is refused for each: the pairs before the last are chained
+    into the second entry. None: all run."""
     for prop in CACHE_PROPERTIES:
-        why = layout.lacks(prop)
-        if why is None:
+        if layout.lacks(prop) is None:
             continue
         for feature in features:
-            if prop in _CACHE_NEEDS[feature]:
-                return (feature + _CACHE_NEEDS[feature][prop], *why)
+            needs = _CACHE_NEEDS[feature]
+            if prop not in needs:
+                continue
+            *before, (what, detail) = [
+                why for p in CACHE_PROPERTIES
+                if p in needs and (why := layout.lacks(p))
+            ]
+            lead = "".join(f"{w} ({d}) and " for w, d in before)
+            return (feature + needs[prop], lead + what, detail)
     return None
 
 
@@ -5206,6 +5215,11 @@ class ContinuousBatcher:
             if self._linear_blocks:
                 global_metrics().inc(
                     "kda.steps", float(self._linear_blocks * self.chunk)
+                )
+            if self._layout.latent_blocks:
+                global_metrics().inc(
+                    "mla.steps",
+                    float(len(self._layout.latent_blocks) * self.chunk),
                 )
             limits = np.full((toks.shape[1],), self.chunk, np.int64)
             if tracer.enabled and fl.t_span:

@@ -6,6 +6,9 @@ and ``PRECISION``, its ``correct`` block.
     chiprun -- python3 scripts/solar_open2_limits.py --seeds 1,2,3 [--out F]
     python3 scripts/solar_open2_limits.py --judge F [--lengths 128,512]
 
+``--cell`` reads another cell whose reference has the same entries
+(``gigachat35_longgen8k``: ``gigachat3.5-432b-a28b``'s block).
+
 Each seed: the cell's deployment as ``lm_engine.run_cell`` builds it
 (ONE batcher a process, that seed's weights swapped in, as
 ``scripts/xing4_limits.py`` does), the correctness sample served ONCE
@@ -41,23 +44,23 @@ if str(ROOT) not in sys.path:
 CELL = "solaropen2_longgen"
 
 
-def _parts():
+def _parts(cell: str = CELL):
     """The cell's configuration, traffic and reference module."""
     import importlib
 
     from chipbench import manifest as mf
 
     manifest = mf.load(ROOT)
-    entry = mf.cell(manifest, CELL)
+    entry = mf.cell(manifest, cell)
     config = mf.config_of(manifest, entry)
     ref = importlib.import_module(config["reference"].split(":")[0])
     return config, mf.traffic_of(manifest, entry), ref
 
 
-def judge(path: str, lengths: list[int]) -> int:
+def judge(path: str, lengths: list[int], cell: str = CELL) -> int:
     import numpy as np
 
-    config, _, ref = _parts()
+    config, _, ref = _parts(cell)
     correct = config["correct"]
     tol = correct["logprob_tol"]
     must = set(correct["controls"])
@@ -140,6 +143,7 @@ def main() -> int:
     ap.add_argument("--seeds", default="1")
     ap.add_argument("--out", default="")
     ap.add_argument("--judge", default="")
+    ap.add_argument("--cell", default=CELL)
     ap.add_argument("--lengths", default="",
                     help="with --judge: hold the first N served steps of "
                     "every request to the rule, for each N listed")
@@ -157,7 +161,9 @@ def main() -> int:
                     "file's sample_steps")
     a = ap.parse_args()
     if a.judge:
-        return judge(a.judge, [int(n) for n in a.lengths.split(",") if n])
+        return judge(
+            a.judge, [int(n) for n in a.lengths.split(",") if n], a.cell
+        )
 
     import jax
     import numpy as np
@@ -167,7 +173,7 @@ def main() -> int:
     from chipbench import manifest as mf
     from chipbench import traffic as tg
 
-    config, traffic, ref = _parts()
+    config, traffic, ref = _parts(a.cell)
     correct = dict(config["correct"])
     if a.steps:
         correct["sample_steps"] = a.steps

@@ -985,8 +985,15 @@ def test_step_chunk_with_recurrent_state_lowers(as_tpu):
 # -- the latent (MLA) cache: decode kernel and per-token write ----------------
 
 
-def _latent_operands(on=sds):
-    b, heads, row, pps, pages, page = 128, 32, 576, 64, 257, 128
+#: (slots, heads, pool pages): xing4_longgen8k's kernel shape (a small
+#: pool: its size is no kernel's operand), and gigachat35_longgen8k's
+#: (64 heads, 192 slots, the cell's pool of 192 x 62 + 1 pages).
+_LATENT_CELLS = {"xing4": (128, 32, 257), "gigachat35": (192, 64, 11905)}
+
+
+def _latent_operands(on=sds, cell="xing4"):
+    b, heads, pages = _LATENT_CELLS[cell]
+    row, pps, page = 576, 64, 128
     return (
         on((b, heads, row)), on((pages, row, page)), on((b, row)),
         on((b,), jnp.int32), on((b,), jnp.int32), on((b, pps), jnp.int32),
@@ -1013,8 +1020,9 @@ def test_latent_decode_and_write_lower(as_tpu):
     lower_for_tpu(_latent_step, *_latent_operands(), kernels=2)
 
 
+@pytest.mark.parametrize("cell", sorted(_LATENT_CELLS))
 def test_latent_decode_and_write_compile_for_v5e_in_place(
-    as_tpu, one_chip, no_persistent_cache
+    as_tpu, one_chip, no_persistent_cache, cell
 ):
     """Mosaic's own compile at the cell's shapes, and no copy of the
     pool around either kernel: a page's positions lie on the lanes,
@@ -1027,11 +1035,12 @@ def test_latent_decode_and_write_compile_for_v5e_in_place(
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
     compiled = jax.jit(_latent_step, donate_argnums=(1,)).lower(
-        *_latent_operands(on_chip)
+        *_latent_operands(on_chip, cell)
     ).compile()
     text = compiled.as_text()
+    pool = f"bf16[{_LATENT_CELLS[cell][2]},576,128]"
     call = re.search(r"%_latent_impl[.\d]* = .*tpu_custom_call.*", text)
-    assert call and call.group(0).count("bf16[257,576,128]") == 1
+    assert call and call.group(0).count(pool) == 1
     assert re.search(r"%_latent_write_impl[.\d]* = .*tpu_custom_call", text)
-    assert not re.search(r"bf16\[257,576,128\]\S* copy\(", text)
+    assert not re.search(re.escape(pool) + r"\S* copy\(", text)
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
