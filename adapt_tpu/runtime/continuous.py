@@ -38,6 +38,9 @@ TPU-first:
   served through the batcher emits token-for-token what ``generate``
   would have emitted for it alone — tested with staggered arrivals and
   mixed greedy/sampled traffic. Slot scheduling is invisible in outputs.
+  A greedy row beside sampled ones keeps its arg-max bit for bit
+  (``jnp.where(greedy, ...)``); ``stats()["ticks_sampled"]`` of
+  ``["ticks"]`` says how many ticks had a row that read the draw.
 
 ``kv_cache_dtype="int8"`` stores KV caches quantized (absmax per K/V
 vector, the same scheme as ``generate``): ~2-4x the resident context
@@ -1234,6 +1237,9 @@ class ContinuousBatcher:
         self._admitted = 0
         self._completed = 0
         self._ticks = 0
+        #: Ticks on which some active request had a temperature; on
+        #: the rest every row was greedy and nobody read the draw.
+        self._ticks_sampled = 0
         #: Prompt tokens THIS batcher prefilled in-tick (full
         #: admissions, suffix passes, chunk passes — positions actually
         #: computed, prefix-cache hits excluded). Mirrored as the
@@ -1705,8 +1711,9 @@ class ContinuousBatcher:
             nxt = jnp.where(greedy, pick_greedy, pick_sampled).astype(
                 tokens.dtype
             )
-            # One cheap (B, V) reduction per step, always emitted;
-            # chosen_logprob is THE shared scoring convention.
+            # Always emitted: the chosen logit less one log-sum-exp of
+            # its row, no (B, V) result; chosen_logprob is THE shared
+            # scoring convention.
             lp = chosen_logprob(logits, nxt)
             moe = None
             if held:
@@ -1751,6 +1758,32 @@ class ContinuousBatcher:
             toks, lps, self._shard_kv(list(caches)),
             self._repl_state(new), moe, states or None,
         )
+
+    def _sampling_flags(self, active) -> tuple[bool, bool, bool]:
+        """``(sample, truncate, nucleus)`` of one decode dispatch, read
+        from the requests in the batch and nothing else (ONE derivation
+        for ``_step_chunk`` and ``_spec_verify``): whether any active
+        row has a temperature, and whether the top-k / top-p sorts are
+        needed by some active request (never on an all-greedy batch,
+        whatever knobs its requests carry)."""
+        sample = any(s.req.temperature > 0.0 for s in active)
+        return (
+            sample,
+            sample and any(s.req.top_k < self.lm.vocab for s in active),
+            sample and any(s.req.top_p < 1.0 for s in active),
+        )
+
+    def _count_tick(self, sample: bool) -> None:
+        """Book one decode dispatch, and whether any of its rows
+        samples: ``1 - ticks_sampled / ticks`` is the share of ticks
+        whose draw nobody reads (every row greedy)."""
+        with self._cv:
+            self._ticks += 1
+            self._ticks_sampled += sample
+        m = global_metrics()
+        m.inc("continuous.ticks")
+        if sample:
+            m.inc("continuous.ticks_sampled")
 
     def _carried(self, states, block: int) -> dict:
         """``decode_step_paged`` / ``prefill_chunk_paged``'s keyword
@@ -4835,17 +4868,13 @@ class ContinuousBatcher:
         filled in by ``_tick_dispatch``)."""
         d = self._spec_k_eff
         w = self._spec_w
-        # Static sampling flags, computed host-side exactly like the
-        # lockstep path's: an all-greedy batch keeps dispatching the
-        # PR-12 program text (bit-identity + compile footprint pinned);
-        # any sampled row switches the verify to its speculative-
-        # sampling variant, with the truncate/nucleus sorts elided
-        # unless some active request needs them.
-        sample = any(s.req.temperature > 0.0 for s in active)
-        truncate = sample and any(
-            s.req.top_k < self.lm.vocab for s in active
-        )
-        nucleus = sample and any(s.req.top_p < 1.0 for s in active)
+        # Static sampling flags, the lockstep path's own derivation:
+        # an all-greedy batch keeps dispatching the PR-12 program text
+        # (bit-identity + compile footprint pinned); any sampled row
+        # switches the verify to its speculative-sampling variant, with
+        # the truncate/nucleus sorts elided unless some active request
+        # needs them.
+        sample, truncate, nucleus = self._sampling_flags(active)
         self._variants.setdefault("speculative.draft_chunk", set()).add(d)
         self._variants.setdefault("continuous.spec_verify", set()).add(
             (d, sample, truncate, nucleus)
@@ -4916,9 +4945,7 @@ class ContinuousBatcher:
             nucleus=nucleus,
             epoch=self._mesh_epoch,
         )
-        with self._cv:
-            self._ticks += 1
-        global_metrics().inc("continuous.ticks")
+        self._count_tick(sample)
         # The round's ONE host fetch covers all three arrays — started
         # here (async), landed at commit.
         return _InFlight(
@@ -5110,10 +5137,7 @@ class ContinuousBatcher:
                 # on device (_dstate, staged once per admission), so a
                 # steady-state tick stages zero host scalars and the
                 # paged table re-uploads only when it changed.
-                truncate = any(
-                    s.req.top_k < self.lm.vocab for s in active
-                )
-                nucleus = any(s.req.top_p < 1.0 for s in active)
+                sample, truncate, nucleus = self._sampling_flags(active)
                 self._variants.setdefault(
                     "continuous.step_chunk", set()
                 ).add((truncate, nucleus))
@@ -5145,9 +5169,7 @@ class ContinuousBatcher:
                     nucleus=nucleus,
                     epoch=self._mesh_epoch,
                 )
-                with self._cv:
-                    self._ticks += 1
-                global_metrics().inc("continuous.ticks")
+                self._count_tick(sample)
                 # The chunk's ONE host fetch covers both arrays —
                 # started here (async), landed at commit.
                 fl = _InFlight(
@@ -5413,6 +5435,8 @@ class ContinuousBatcher:
                 "admitted": self._admitted,
                 "completed": self._completed,
                 "ticks": self._ticks,
+                # Of those, the ticks on which some row sampled.
+                "ticks_sampled": self._ticks_sampled,
                 # Whether a dispatched tick awaits its commit right now.
                 "inflight": self._inflight is not None,
                 # Prompt positions prefilled IN-TICK by this batcher

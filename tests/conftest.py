@@ -147,6 +147,16 @@ def drained(bat):
     return bat
 
 
+def log_softmax_score(logits, tokens):
+    """``transformer_lm.chosen_logprob`` as it stood until PR 50 (the
+    whole log-softmax, then one value a row): what the tests of the
+    score compare with."""
+    lp = jax.nn.log_softmax(logits, axis=-1)
+    return jax.numpy.take_along_axis(
+        lp, tokens[:, None].astype("int32"), axis=-1
+    )[:, 0]
+
+
 def greedy_by_full_forward(lm, variables, prompt, steps: int):
     """The oracle of the cached-decode parity tests: ``steps`` tokens
     by stepwise argmax of the FULL causal forward, (b, steps). The

@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from conftest import pool_copies
+from conftest import log_softmax_score, pool_copies
 from jax import lax
 from jax.sharding import (
     Mesh,
@@ -1044,3 +1044,36 @@ def test_latent_decode_and_write_compile_for_v5e_in_place(
     assert re.search(r"%_latent_write_impl[.\d]* = .*tpu_custom_call", text)
     assert not re.search(re.escape(pool) + r"\S* copy\(", text)
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
+
+
+@pytest.mark.parametrize("rows,vocab", [
+    (128, 261120),  # falconh1_longgen: the whole published vocabulary
+    (256, 24576),  # solaropen2_longgen
+])
+def test_the_greedy_tail_writes_no_rows_by_vocabulary_array(
+    one_chip, no_persistent_cache, rows, vocab
+):
+    """A greedy row's tail over the vocabulary (the arg-max, then
+    ``chosen_logprob``), compiled for a v5e at two cells' shapes: only
+    reading passes over the logits. The log-softmax it replaced (PR 50)
+    compiled to a fusion whose RESULT was a second float32
+    ``(rows, vocabulary)`` array, 134 MB a step in Falcon-H1; the same
+    function holds that array, so the count tells the two apart."""
+    from adapt_tpu.models.transformer_lm import chosen_logprob
+
+    def tail(score, logits):
+        nxt = jnp.argmax(logits, axis=-1)
+        return nxt, score(logits, nxt)
+
+    logits = jax.ShapeDtypeStruct((rows, vocab), jnp.float32,
+                                  sharding=one_chip)
+    wide = re.compile(rf"= f32\[{rows},{vocab}\]\S* fusion\(")
+
+    def written(score):
+        text = jax.jit(functools.partial(tail, score)).lower(
+            logits
+        ).compile().as_text()
+        return len(wide.findall(text))
+
+    assert written(chosen_logprob) == 0
+    assert written(log_softmax_score) == 1

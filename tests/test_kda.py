@@ -256,7 +256,8 @@ def test_served_logprobs_are_the_plain_references(built, served, prompt_len):
     assert c["kda.state_writes"] == passes
     assert c.get("kda.chunks_carried", 0) == passes - 1
     assert c["kda.steps"] == 3 * CHUNK  # one KDA layer, three ticks
-    assert "ssm.state_writes" not in c
+    # Zero, or absent where no state-space model ran in this process.
+    assert not c.get("ssm.state_writes")
     assert c["moe.steps"] == 3 * CHUNK
 
 

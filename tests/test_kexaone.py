@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import log_softmax_score
 
 from adapt_tpu.models.moe import ExpertSpec, RoutedExperts
 from adapt_tpu.models.transformer_lm import logits_full
@@ -253,16 +254,28 @@ def test_what_cannot_run_over_cache_groups_says_so(built, what, kw):
 
 #: sha256 (16 hex) of the lowered text of ``_step_chunk`` and of the
 #: first bucket's ``prefill`` at each GPT-2 configuration's rehearsal
-#: sizes, read on the commit before the block-spec refactor (PR 30's
-#: tree) with this same function: the GPT-2 specs are held still.
+#: sizes. ``log_softmax`` was read on the commit before the block-spec
+#: refactor (PR 30's tree) with this same function: the GPT-2 specs are
+#: held still. PR 50 moved both texts ON PURPOSE and in the score alone:
+#: step and prefill score through ``chosen_logprob``'s one log-sum-exp
+#: where a whole log-softmax stood. Scored the way PR 30's tree scored
+#: (``conftest.log_softmax_score``), both still lower to ``log_softmax`` to the
+#: digit: everything but the score is the text it was. ``served`` is
+#: what the batcher dispatches since PR 50.
 GPT2_LOWERED = {
-    "gpt2-xl": ("d113387e5b3f28a5", "d6a656519e6b5a03"),
-    "cerebras-gpt-1.3b": ("29a8f1d1ce1d060f", "630bcf9702481864"),
+    ("gpt2-xl", "log_softmax"): ("d113387e5b3f28a5", "d6a656519e6b5a03"),
+    ("cerebras-gpt-1.3b", "log_softmax"): (
+        "29a8f1d1ce1d060f", "630bcf9702481864"),
+    ("gpt2-xl", "served"): ("394374b9285166c5", "f15c62f5955d342f"),
+    ("cerebras-gpt-1.3b", "served"): (
+        "579522a9516e48f1", "cc4629c06deaaa74"),
 }
 
 
-@pytest.mark.parametrize("name", sorted(GPT2_LOWERED))
-def test_the_gpt2_programs_lower_to_the_text_they_had(name, monkeypatch):
+@pytest.mark.parametrize("name,tail", sorted(GPT2_LOWERED))
+def test_the_gpt2_programs_lower_to_the_text_they_had(
+    name, tail, monkeypatch
+):
     # The rehearsal's rows of 64 are not whole lane tiles, so an engine
     # would hold the embedding tables padded (PR 47: another text, by
     # two pads' worth). Held as the model gives them, as every
@@ -270,6 +283,10 @@ def test_the_gpt2_programs_lower_to_the_text_they_had(name, monkeypatch):
     from adapt_tpu.runtime import continuous
 
     monkeypatch.setattr(continuous, "lane_tiled", lambda embed: embed)
+    if tail == "log_softmax":
+        monkeypatch.setattr(
+            continuous, "chosen_logprob", log_softmax_score
+        )
     config = json.loads((ROOT / f"chipbench/configs/{name}.json").read_text())
     model = {**config["model"], **config["rehearse"]["model"]}
     serving = {**config["serving"], **config["rehearse"]["serving"]}
@@ -294,7 +311,7 @@ def test_the_gpt2_programs_lower_to_the_text_they_had(name, monkeypatch):
     got = tuple(
         hashlib.sha256(t.encode()).hexdigest()[:16] for t in (step, pre)
     )
-    assert got == GPT2_LOWERED[name]
+    assert got == GPT2_LOWERED[name, tail], got
 
 
 def test_the_configuration_file_holds_the_published_keys_twice_and_equal():
