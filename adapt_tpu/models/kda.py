@@ -7,12 +7,13 @@ structure, three schedules over it, as ``models/ssm``:
 
 - **whole prompt / chunk pass** (:meth:`KdaMixer.scan`): the recurrence
   in its chunked form at ``_CHUNK`` positions a chunk (a unit
-  lower-triangular solve inside a chunk, a carried state between
-  chunks); a chunked-prefill pass starts from the state and convolution
-  tail the pass before left. Positions at or past ``length`` (a prompt
-  shorter than its bucket) get ``g = 0`` and ``beta = 0`` and step
-  nothing, so what comes back is the state and tail of the LAST REAL
-  position.
+  lower-triangular system a chunk, solved for every chunk of a group
+  at once, then a scan that carries the state from chunk to chunk:
+  :func:`_chunked`); a chunked-prefill pass starts from the state and
+  convolution tail the pass before left. Positions at or past
+  ``length`` (a prompt shorter than its bucket) get ``g = 0`` and
+  ``beta = 0`` and step nothing, so what comes back is the state and
+  tail of the LAST REAL position.
 - **one decode step** (:meth:`KdaMixer.step`): a token a row against
   its slot's state (``ops/kda_step``: read once, written once in
   place); a dead row (negative index) keeps state and tail untouched.
@@ -40,6 +41,7 @@ Per position, ``u`` the block's normed input (no projection bias):
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import flax.linen as nn
 import jax
@@ -47,12 +49,21 @@ import jax.numpy as jnp
 from jax import lax
 
 from adapt_tpu.models.ssm import init_a_log, scaled, zero_state
+from adapt_tpu.ops.dispatch import record_kernel_choice
 from adapt_tpu.ops.kda_step import kda_step
 
 F32 = jnp.float32
 _HIGHEST = lax.Precision.HIGHEST
 #: Positions a chunk of the chunked prefill.
 _CHUNK = 64
+#: Chunks whose triangular systems and decayed operands the chunked
+#: prefill forms at once, ahead of the scan that carries the state: 512
+#: positions, which keeps what is hoisted of a 2,048-position pass at a
+#: quarter (~140 MB at 64 heads of 128 x 128, not ~550).
+_GROUP = 8
+#: Rows of a diagonal block that :func:`_unit_lower_inverse` inverts by
+#: forward substitution: its steps in sequence, whatever the chunks.
+_SOLVE_BLOCK = 16
 
 
 @dataclasses.dataclass(frozen=True)
@@ -316,56 +327,187 @@ def kda_recurrent(q, k, v, g, beta, state):
     return o, state
 
 
-def _solve_unit_lower(a, rhs):
-    """``(I + tril(a, -1)) w = rhs`` by forward substitution: ``a``
-    (H, C, C), ``rhs`` (H, C, d) -> ``w`` (H, C, d). Row ``r`` reads
-    the rows before it, so C steps in sequence, each a product a row
-    (float32, no pivoting to go wrong: the diagonal is one)."""
-    c = a.shape[1]
-    a = jnp.tril(a, -1)
-
-    def row(w, r):
-        a_r = lax.dynamic_index_in_dim(a, r, 1, keepdims=False)  # (H, C)
-        new = lax.dynamic_index_in_dim(rhs, r, 1, keepdims=False) - jnp.einsum(
-            "hi,hid->hd", a_r, w, precision=_HIGHEST
-        )
-        return lax.dynamic_update_index_in_dim(w, new, r, 1), None
-
-    return lax.scan(row, jnp.zeros_like(rhs), jnp.arange(c))[0]
-
-
 def _mm(spec, a, b):
     return jnp.einsum(spec, a, b, precision=_HIGHEST)
 
 
-def _whole_chunks(q, k, v, g, beta, chunk):
-    """The operands of a chunked form padded to whole chunks with
-    positions that step nothing, q, k and v in float32."""
-    pad = -q.shape[0] % chunk
+def _diagonal_blocks(a, width):
+    """``a`` (.., C, C) -> its ``C // width`` diagonal blocks
+    (.., C // width, width, width)."""
+    return jnp.stack(
+        [
+            a[..., j: j + width, j: j + width]
+            for j in range(0, a.shape[-1], width)
+        ],
+        axis=-3,
+    )
+
+
+def _unit_lower_inverse(a, block):
+    """``(I + tril(a, -1))^-1`` of every matrix of ``a`` (.., C, C) at
+    once. The diagonal blocks of ``block`` rows are inverted by forward
+    substitution: ``block`` steps in sequence however many matrices
+    there are, each a product a row (float32, no pivoting to go wrong:
+    the diagonal is one). Neighbouring blocks are then merged by
+    products until one block is the matrix:
+
+        [[A, 0], [B, D]]^-1 = [[A^-1, 0], [-D^-1 B A^-1, D^-1]]
+    """
+    c = a.shape[-1]
+    if c % block or (c // block) & (c // block - 1):
+        raise ValueError(
+            f"{c} rows are no power of two of blocks of {block}"
+        )
+    a = jnp.tril(a, -1)
+    diag = _diagonal_blocks(a, block)
+    eye = jnp.eye(block, dtype=a.dtype)
+    rows = diag.ndim - 2
+
+    def row(inv, r):
+        a_r = lax.dynamic_index_in_dim(diag, r, rows, keepdims=False)
+        new = lax.dynamic_index_in_dim(eye, r, keepdims=False) - _mm(
+            "...i,...id->...d", a_r, inv
+        )
+        return lax.dynamic_update_index_in_dim(inv, new, r, rows), None
+
+    inv = lax.scan(row, jnp.zeros_like(diag), jnp.arange(block))[0]
+    width = block
+    while width < c:
+        pairs = inv.reshape(*inv.shape[:-3], -1, 2, width, width)
+        top, bottom = pairs[..., 0, :, :], pairs[..., 1, :, :]
+        below = _diagonal_blocks(a, 2 * width)[..., width:, :width]
+        corner = -_mm(
+            "...ri,...id->...rd", bottom,
+            _mm("...ri,...id->...rd", below, top),
+        )
+        inv = jnp.concatenate([
+            jnp.concatenate([top, jnp.zeros_like(top)], axis=-1),
+            jnp.concatenate([corner, bottom], axis=-1),
+        ], axis=-2)
+        width *= 2
+    return inv[..., 0, :, :]
+
+
+def _channel_decays(q, k, g):
+    """The pre-pass where the decay is a key channel's: ``q``, ``k``
+    (n, H, C, d_k) and the log decays through a position ``g`` (n, H,
+    C, d_k) of ``n`` chunks -> ``kk``, ``qk`` (n, H, C, C), ``exp(G) *
+    K``, ``exp(G) * Q``, ``exp(G_C - G) * K`` (n, H, C, d_k) and
+    ``exp(G_C)`` (n, H, d_k). The pairwise decays ``(H, C, C, d_k)``
+    live inside ONE chunk's multiply-and-reduce: a map over the chunks,
+    never a batch of them."""
+    c = q.shape[2]
+    at_or_before = jnp.tril(jnp.ones((c, c), bool))[None, :, :, None]
+
+    def pairwise(xs):
+        g, q, k = xs  # (H, C, d_k)
+        # exp(G_r - G_i) for i <= r, zero elsewhere: (H, r, i, d_k)
+        between = jnp.exp(jnp.where(
+            at_or_before, g[:, :, None, :] - g[:, None, :, :], -jnp.inf
+        ))
+        return (
+            jnp.sum(between * k[:, :, None, :] * k[:, None, :, :], -1),
+            jnp.sum(between * q[:, :, None, :] * k[:, None, :, :], -1),
+        )
+
+    kk, qk = lax.map(pairwise, (g, q, k))
+    into = jnp.exp(g)  # the decay from the chunk's start through r
+    to_end = jnp.exp(g[:, :, -1:] - g)
+    return kk, qk, into * k, into * q, to_end * k, into[:, :, -1]
+
+
+def _head_decays(q, k, g):
+    """The pre-pass where the decay is ONE scalar a head (``g`` (n, H,
+    C)): the decay leaves the channel sums, so that ``K K^T`` and
+    ``Q K^T`` are matrix products and the pairwise decays a (C, C)
+    matrix a head; ``exp(G_C)`` comes back (n, H, 1)."""
+    c = q.shape[2]
+    between = jnp.exp(jnp.where(
+        jnp.tril(jnp.ones((c, c), bool)),
+        g[..., :, None] - g[..., None, :], -jnp.inf,
+    ))  # (n, H, r, i)
+    kk = between * _mm("nhrk,nhik->nhri", k, k)
+    qk = between * _mm("nhrk,nhik->nhri", q, k)
+    into = jnp.exp(g)[..., None]
+    to_end = jnp.exp(g[:, :, -1:] - g)[..., None]
+    return kk, qk, into * k, into * q, to_end * k, into[:, :, -1]
+
+
+# Jitted so that an eager caller compiles ONE program, not one an
+# operation; inside a traced program the call is inlined.
+@functools.partial(jax.jit, static_argnums=(0, 7))
+def _chunked(decays, q, k, v, g, beta, state, chunk):
+    """What both chunked forms share. A chunk's triangular system
+
+        (I + Diag(beta) tril(A, -1)) W = Diag(beta) (V - (exp(G) * K) S_0)
+
+    has a left side that does not read the carried state, so with
+    ``T = (I + Diag(beta) tril(A, -1))^-1``
+
+        W = W_v - W_k S_0,  W_v = T (beta * V),  W_k = T (beta * exp(G) * K)
+
+    and ``T``, ``W_v`` and ``W_k`` are formed for every chunk of a
+    GROUP at once (``decays``, then ONE blocked inverse), before the
+    scan that carries the state over the group's chunks, which keeps
+    four products a chunk and no loop. A group is at most ``_GROUP``
+    chunks, which bounds what is hoisted; a longer pass is a scan over
+    groups."""
+    s = q.shape[0]
+    chunks = -(-s // chunk)
+    groups = -(-chunks // _GROUP)
+    per = -(-chunks // groups)  # chunks a group
+    block = min(_SOLVE_BLOCK, chunk)
+    record_kernel_choice(
+        "kda_prefill", chunk=chunk, group=per, solve_block=block,
+        solve_steps=groups * block,
+    )
+    pad = groups * per * chunk - s
     if pad:  # g = 0 and beta = 0: steps nothing
         q, k, v, g, beta = (
             jnp.pad(t, ((0, pad),) + ((0, 0),) * (t.ndim - 1))
             for t in (q, k, v, g, beta)
         )
-    q, k, v = (t.astype(F32) for t in (q, k, v))
-    return q, k, v, g, beta
 
+    def group(state, xs):
+        # (n, C, H, ..) as the pass holds them -> (n, H, C, ..) float32
+        q, k, v, g, beta = (jnp.swapaxes(t.astype(F32), 1, 2) for t in xs)
+        beta = beta[..., None]
+        kk, qk, k_in, q_in, k_out, decay = decays(
+            q, k, jnp.cumsum(g, axis=2)
+        )
+        t = _unit_lower_inverse(beta * kk, block)
+        w_v = _mm("nhri,nhid->nhrd", t, beta * v)
+        w_k = _mm("nhri,nhid->nhrd", t, beta * k_in)
 
-def _scan_chunks(one, state, operands, chunk, s):
-    """``one(state, (q, k, v, g, beta))`` -> ``(state, o)`` over chunks
-    of ``chunk`` positions; the ``s`` real positions' outputs and the
-    state left."""
-    def chunks(t):
-        return t.reshape(-1, chunk, *t.shape[1:])
+        def one(state, xs):
+            w_v, w_k, q_in, qk, k_out, decay = xs
+            w = w_v - _mm("hrk,hkv->hrv", w_k, state)
+            o = _mm("hrk,hkv->hrv", q_in, state) + _mm(
+                "hri,hiv->hrv", qk, w
+            )
+            state = state * decay[..., None] + _mm(
+                "hik,hiv->hkv", k_out, w
+            )
+            return state, o
 
-    state, o = lax.scan(one, state, tuple(chunks(t) for t in operands))
-    return o.reshape(-1, *o.shape[2:])[:s], state
+        return lax.scan(one, state, (w_v, w_k, q_in, qk, k_out, decay))
+
+    operands = tuple(
+        t.reshape(groups, per, chunk, *t.shape[1:])
+        for t in (q, k, v, g, beta)
+    )
+    if groups == 1:  # every pass of 512 positions or fewer: no outer loop
+        state, o = group(state, tuple(t[0] for t in operands))
+    else:
+        state, o = lax.scan(group, state, operands)
+    # (.., H, C, d_v) a chunk -> the s real positions' (s, H, d_v)
+    o = jnp.swapaxes(o, -3, -2)
+    return o.reshape(-1, *o.shape[-2:])[:s], state
 
 
 def kda_chunked(q, k, v, g, beta, state, chunk=_CHUNK):
-    """:func:`kda_recurrent` in its chunked form: a scan over chunks of
-    ``chunk`` positions carries the state; inside a chunk, with ``G``
-    the running sum of ``g``,
+    """:func:`kda_recurrent` in its chunked form: inside a chunk of
+    ``chunk`` positions, with ``G`` the running sum of ``g``,
 
         A_ri = sum_c exp(G_r - G_i)_c k_rc k_ic             (i < r)
         (I + Diag(beta) tril(A, -1)) W = Diag(beta) (V - (exp(G) * K) S_0)
@@ -373,48 +515,17 @@ def kda_chunked(q, k, v, g, beta, state, chunk=_CHUNK):
                + sum_{i <= r} [sum_c exp(G_r - G_i)_c q_rc k_ic] w_i
         S_C  = Diag(exp(G_C)) S_0 + sum_i (exp(G_C - G_i) * k_i) w_i^T
 
-    everything in float32. ``exp(G_r - G_i)`` is formed pairwise (never
-    ``1 / exp(G_i)`` alone, which overflows under a strong decay), and
-    only where ``i <= r``, where it is at most one."""
-    s = q.shape[0]
-    q, k, v, g, beta = _whole_chunks(q, k, v, g, beta, chunk)
-    at_or_before = jnp.tril(jnp.ones((chunk, chunk), bool))
-
-    def one(state, xs):
-        q, k, v, g, beta = xs  # (C, H, d), (C, H)
-        gc = jnp.cumsum(g, axis=0)  # (C, H, d_k): log decay through r
-        # exp(G_r - G_i) for i <= r, zero elsewhere: (H, r, i, d_k)
-        gh = jnp.swapaxes(gc, 0, 1)
-        between = jnp.exp(jnp.where(
-            at_or_before[None, :, :, None],
-            gh[:, :, None, :] - gh[:, None, :, :], -jnp.inf,
-        ))
-        kh, qh = jnp.swapaxes(k, 0, 1), jnp.swapaxes(q, 0, 1)
-        kk = jnp.sum(between * kh[:, :, None, :] * kh[:, None, :, :], -1)
-        qk = jnp.sum(between * qh[:, :, None, :] * kh[:, None, :, :], -1)
-        bh = beta.T  # (H, C)
-        into = jnp.exp(gh)  # the decay from the chunk's start through r
-        rhs = bh[..., None] * (
-            jnp.swapaxes(v, 0, 1) - _mm("hrk,hkv->hrv", into * kh, state)
-        )
-        w = _solve_unit_lower(bh[..., None] * kk, rhs)  # (H, C, d_v)
-        o = _mm("hrk,hkv->hrv", into * qh, state) + _mm(
-            "hri,hiv->hrv", qk, w
-        )
-        to_end = jnp.exp(gh[:, -1:, :] - gh)  # (H, C, d_k)
-        state = state * into[:, -1, :, None] + _mm(
-            "hik,hiv->hkv", to_end * kh, w
-        )
-        return state, jnp.swapaxes(o, 0, 1)
-
-    return _scan_chunks(one, state, (q, k, v, g, beta), chunk, s)
+    everything in float32 (:func:`_chunked`: the system solved for
+    every chunk before the scan that carries the state). ``exp(G_r -
+    G_i)`` is formed pairwise (never ``1 / exp(G_i)`` alone, which
+    overflows under a strong decay), and only where ``i <= r``, where
+    it is at most one."""
+    return _chunked(_channel_decays, q, k, v, g, beta, state, chunk)
 
 
 def kda_chunked_head(q, k, v, g, beta, state, chunk=_CHUNK):
     """:func:`kda_chunked` where the decay is ONE scalar a head (``g``
-    (s, H)): the decay leaves the channel sums, so that ``K K^T`` and
-    ``Q K^T`` are matrix products and the pairwise decays a (C, C)
-    matrix a head, not (C, C, d_k):
+    (s, H)):
 
         A_ri = exp(G_r - G_i) (k_r . k_i)                      (i < r)
         (I + Diag(beta) tril(A, -1)) W = Diag(beta) (V - exp(G) * (K S_0))
@@ -424,32 +535,4 @@ def kda_chunked_head(q, k, v, g, beta, state, chunk=_CHUNK):
 
     ``exp(G_r - G_i)`` is still formed pairwise and only where
     ``i <= r``."""
-    s = q.shape[0]
-    q, k, v, g, beta = _whole_chunks(q, k, v, g, beta, chunk)
-    at_or_before = jnp.tril(jnp.ones((chunk, chunk), bool))
-
-    def one(state, xs):
-        q, k, v, g, beta = xs  # (C, H, d), (C, H)
-        gh = jnp.cumsum(g, axis=0).T  # (H, C): log decay through r
-        between = jnp.exp(jnp.where(
-            at_or_before, gh[:, :, None] - gh[:, None, :], -jnp.inf
-        ))  # (H, r, i)
-        kh, qh = jnp.swapaxes(k, 0, 1), jnp.swapaxes(q, 0, 1)
-        kk = between * _mm("hrk,hik->hri", kh, kh)
-        qk = between * _mm("hrk,hik->hri", qh, kh)
-        bh = beta.T[..., None]  # (H, C, 1)
-        into = jnp.exp(gh)[..., None]  # from the chunk's start through r
-        rhs = bh * (
-            jnp.swapaxes(v, 0, 1) - into * _mm("hrk,hkv->hrv", kh, state)
-        )
-        w = _solve_unit_lower(bh * kk, rhs)  # (H, C, d_v)
-        o = into * _mm("hrk,hkv->hrv", qh, state) + _mm(
-            "hri,hiv->hrv", qk, w
-        )
-        to_end = jnp.exp(gh[:, -1:] - gh)[..., None]  # (H, C, 1)
-        state = state * into[:, -1, :, None] + _mm(
-            "hik,hiv->hkv", to_end * kh, w
-        )
-        return state, jnp.swapaxes(o, 0, 1)
-
-    return _scan_chunks(one, state, (q, k, v, g, beta), chunk, s)
+    return _chunked(_head_decays, q, k, v, g, beta, state, chunk)

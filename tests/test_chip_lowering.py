@@ -1077,3 +1077,95 @@ def test_the_greedy_tail_writes_no_rows_by_vocabulary_array(
 
     assert written(chosen_logprob) == 0
     assert written(log_softmax_score) == 1
+
+
+def _while_loops(text):
+    """The ``while`` operations of a compiled program's text as a tree:
+    ``[(trip_count, loops_inside_its_body), ..]`` from the entry
+    computation down (through fusions and calls). A trip count is the
+    constant its condition compares the counter with (what a
+    ``lax.scan`` compiles to); None where there is no such constant."""
+    bodies, name = {}, None
+    for line in text.splitlines():
+        if m := re.match(r"(ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", line):
+            name = "ENTRY" if m.group(1) else m.group(2)
+            bodies[name] = []
+        elif name is not None:
+            bodies[name].append(line)
+
+    def trips(condition):
+        lines = "\n".join(bodies[condition])
+        if not re.search(r"compare\(.*direction=LT", lines):
+            return None
+        found = re.findall(r"= s32\[\]\S* constant\((\d+)\)", lines)
+        return int(found[0]) if len(found) == 1 else None
+
+    def inside(name):
+        out = []
+        for line in bodies[name]:
+            if m := re.search(
+                r" while\(.*condition=%?([\w.\-]+), body=%?([\w.\-]+)", line
+            ):
+                out.append((trips(m.group(1)), inside(m.group(2))))
+            else:
+                for callee in re.findall(
+                    r"(?:calls|to_apply)=%?([\w.\-]+)", line
+                ):
+                    out += inside(callee)
+        return out
+
+    return inside("ENTRY")
+
+
+@pytest.mark.parametrize("decay", ["channel", "head"])
+def test_kda_prefill_compiles_for_v5e_with_the_solve_outside_the_state_scan(
+    as_tpu, one_chip, no_persistent_cache, decay
+):
+    """Both chunked forms at the published widths (64 heads of a
+    128 x 128 state; ``solar-open2-250b`` a decay a channel,
+    ``gigachat3.5-432b-a28b`` one a head), compiled for a v5e. A pass of
+    256 positions (every pass of both cells) is ONE group of 4 chunks:
+    no loop inside another, the substitution's 16 steps beside the
+    4-chunk state scan (and, a decay a channel, the 4-chunk map that
+    keeps the pairwise decays to one chunk's multiply-and-reduce). A
+    pass of 2,048 is a scan over 4 groups of 8 and nothing deeper, and
+    what it holds beside its operands stays under 256 MB: neither the
+    (64, 64, 64, 128) pairwise tensor (134 MB a chunk) nor the hoisted
+    operands were formed for the whole pass."""
+    from adapt_tpu.models import kda
+
+    fn = kda.kda_chunked if decay == "channel" else kda.kda_chunked_head
+    heads, d = 64, 128
+
+    def compiled(s):
+        def on_chip(shape, dt=jnp.bfloat16):
+            return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+        g = (s, heads, d) if decay == "channel" else (s, heads)
+        return jax.jit(fn).lower(
+            *(on_chip((s, heads, d)) for _ in "qkv"),
+            on_chip(g, jnp.float32), on_chip((s, heads), jnp.float32),
+            on_chip((heads, d, d), jnp.float32),
+        ).compile()
+
+    block = kda._SOLVE_BLOCK
+    pre_pass = [4] if decay == "channel" else []
+    loops = _while_loops(compiled(256).as_text())
+    assert sorted(loops) == sorted(
+        (n, []) for n in [*pre_pass, block, 4]
+    ), loops
+    def booked():
+        books = kernel_dispatch_stats()["kda_prefill"]
+        return [
+            books[k] for k in ("chunk", "group", "solve_block", "solve_steps")
+        ]
+
+    assert booked() == [64, 4, block, block]
+    long = compiled(2048)
+    (groups, inner), = _while_loops(long.as_text())
+    pre_pass = [8] if decay == "channel" else []
+    assert groups == 4 and sorted(inner) == sorted(
+        (n, []) for n in [*pre_pass, block, 8]
+    ), (groups, inner)
+    assert booked() == [64, 8, block, 4 * block]
+    assert long.memory_analysis().temp_size_in_bytes < 256e6

@@ -10,6 +10,7 @@ reference's full forward pass, and what the batcher keeps and refuses
 for a request that owns states AND latent pages. CPU, at the
 configuration's ``rehearse`` sizes; the kernels run interpreted."""
 
+import functools
 import json
 from pathlib import Path
 
@@ -94,6 +95,34 @@ def _mixer(spec=GDN, dim=32, seed=0):
     u = jax.random.normal(jax.random.PRNGKey(seed), (2, 21, dim))
     params = mixer.init(jax.random.PRNGKey(seed + 1), u)
     return mixer, params, u
+
+
+@pytest.mark.parametrize("s", [1, 63, 65, 200, 1000])
+def test_the_hoisted_scalar_scan_is_the_recurrence_from_a_carried_state(s):
+    """At the module's chunk of 64 from a NON-zero state: less than a
+    chunk, a position either side of one, several chunks in one group,
+    and 16 chunks in two groups of 8 (the scan over groups)."""
+    q, k, v, g, beta, state = _operands(s, 3, 16, seed=s)
+    want_o, want_s = kda_recurrent(q, k, v, _channels(g, 16), beta, state)
+    got_o, got_s = jax.jit(kda_chunked_head)(q, k, v, g, beta, state)
+    np.testing.assert_allclose(got_o, want_o, atol=2e-5)
+    np.testing.assert_allclose(got_s, want_s, atol=2e-5)
+
+
+def test_a_pass_of_padding_alone_leaves_state_and_tail_as_they_were():
+    """``length`` 0 under a decay a head and shared key heads: the pass
+    steps nothing, state and tail come back bit for bit."""
+    mixer, params, u = _mixer()
+    scan = jax.jit(functools.partial(mixer.apply, method="scan"))
+    empty = tuple(
+        jnp.zeros((2, *t.shape[1:]), t.dtype)
+        for t in mixer.spec.state_shapes(1, jnp.float32)
+    )
+    _, carried = scan(params, u, empty, 13)
+    _, after = scan(params, u, carried, 0)
+    for was, now in zip(carried, after):
+        assert np.abs(np.asarray(was)).max() > 0
+        np.testing.assert_array_equal(now, was)
 
 
 def test_the_mixers_schedules_agree_under_shared_key_heads():

@@ -7,6 +7,7 @@ the plain reference's full forward pass, and what the batcher keeps and
 refuses for such a model. CPU, at the configuration's ``rehearse``
 sizes; the kernel runs interpreted."""
 
+import functools
 import json
 from pathlib import Path
 
@@ -15,7 +16,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from adapt_tpu.models.kda import KdaMixer, KdaSpec, kda_chunked, kda_recurrent
+from adapt_tpu.models.kda import (
+    KdaMixer,
+    KdaSpec,
+    _unit_lower_inverse,
+    kda_chunked,
+    kda_recurrent,
+)
 from adapt_tpu.models.moe import ExpertSpec, RoutedExperts
 from adapt_tpu.models.transformer_lm import (
     BlockSpec,
@@ -80,6 +87,85 @@ def test_a_strong_decay_does_not_overflow_the_chunked_scan():
     np.testing.assert_allclose(got_s, want_s, atol=2e-5)
 
 
+def _solve_unit_lower(a, rhs):
+    """``(I + tril(a, -1)) w = rhs`` by forward substitution, a row a
+    step: ``a`` (H, C, C), ``rhs`` (H, C, d) -> ``w`` (H, C, d). What
+    the chunked prefill ran inside every chunk until the blocked
+    inverse took its place; kept as what the inverse is held to."""
+    a = jnp.tril(a, -1)
+
+    def row(w, r):
+        new = rhs[:, r] - jnp.einsum(
+            "hi,hid->hd", a[:, r], w, precision=jax.lax.Precision.HIGHEST
+        )
+        return w.at[:, r].set(new), None
+
+    return jax.lax.scan(
+        row, jnp.zeros_like(rhs), jnp.arange(a.shape[1])
+    )[0]
+
+
+@pytest.mark.parametrize("beta_range", [(0.0, 0.05), (1.95, 2.0)])
+@pytest.mark.parametrize("block", [16, 32])
+def test_the_blocked_inverse_solves_a_chunks_system(block, beta_range):
+    """``T = (I + Diag(beta) tril(A, -1))^-1`` at C = 64, diagonal
+    blocks of 16 or 32 by substitution and merged by products, for
+    several chunks and heads at once: ``T rhs`` is what a triangular
+    solve of the same system gives, with ``beta`` near zero (``T`` near
+    the identity) and near two (its largest entries)."""
+    from jax.scipy.linalg import solve_triangular
+
+    n, heads, c, d = 3, 2, 64, 16
+    _, k, v, g, _, _ = _operands(n * c, heads, d, seed=4)
+    kh = jnp.swapaxes(k.reshape(n, c, heads, d), 1, 2)
+    gh = jnp.swapaxes(jnp.cumsum(g.reshape(n, c, heads, d), 1), 1, 2)
+    decay = jnp.exp(jnp.minimum(gh[:, :, :, None] - gh[:, :, None, :], 0.0))
+    kk = jnp.sum(decay * kh[:, :, :, None] * kh[:, :, None, :], -1)
+    beta = jax.random.uniform(
+        jax.random.PRNGKey(5), (n, heads, c, 1),
+        minval=beta_range[0], maxval=beta_range[1],
+    )
+    a = beta * kk
+    rhs = jnp.swapaxes(v.reshape(n, c, heads, d), 1, 2)
+    t = jax.jit(_unit_lower_inverse, static_argnums=1)(a, block)
+    got = jnp.einsum(
+        "nhri,nhid->nhrd", t, rhs, precision=jax.lax.Precision.HIGHEST
+    )
+    system = jnp.eye(c) + jnp.tril(a, -1)
+    want = solve_triangular(system, rhs, lower=True, unit_diagonal=True)
+    scale = max(1.0, float(jnp.abs(want).max()))
+    np.testing.assert_allclose(got, want, atol=2e-5 * scale)
+    rows = jax.vmap(_solve_unit_lower)(a, rhs)
+    np.testing.assert_allclose(got, rows, atol=2e-5 * scale)
+    # the strict upper triangle is exactly zero, the diagonal exactly one
+    t = np.asarray(t)
+    np.testing.assert_array_equal(np.triu(t, 1), 0.0)
+    np.testing.assert_array_equal(np.diagonal(t, axis1=-2, axis2=-1), 1.0)
+
+
+def test_the_blocked_inverse_refuses_blocks_it_cannot_pair():
+    with pytest.raises(ValueError, match="no power of two of blocks"):
+        _unit_lower_inverse(jnp.zeros((1, 48, 48)), 16)
+
+
+@pytest.mark.parametrize("s", [1, 63, 65, 200, 1000])
+def test_the_hoisted_scan_is_the_recurrence_from_a_carried_state(s):
+    """At the module's chunk of 64 from a NON-zero state: less than a
+    chunk, a position either side of one, several chunks in one group,
+    and 16 chunks in two groups of 8 (the scan over groups)."""
+    q, k, v, g, beta, state = _operands(s, 3, 16, seed=s)
+    want_o, want_s = kda_recurrent(q, k, v, g, beta, state)
+    got_o, got_s = jax.jit(kda_chunked)(q, k, v, g, beta, state)
+    np.testing.assert_allclose(got_o, want_o, atol=2e-5)
+    np.testing.assert_allclose(got_s, want_s, atol=2e-5)
+    books = kernel_dispatch_stats()["kda_prefill"]
+    chunks = -(-s // 64)
+    groups = -(-chunks // 8)
+    assert books["chunk"] == 64 and books["solve_block"] == 16
+    assert books["solve_steps"] == 16 * groups
+    assert books["group"] == -(-chunks // groups)
+
+
 def test_the_decode_kernel_is_one_step_of_the_recurrence():
     """Interpreted: four rows at once, one of them dead (``alpha`` one,
     ``beta`` zero), whose state comes back bit for bit."""
@@ -140,6 +226,25 @@ def test_the_mixers_schedules_agree():
         np.testing.assert_allclose(out[0], whole[0, t: t + 1], atol=1e-5)
     for leaf in carried:
         np.testing.assert_array_equal(leaf[1], jnp.full_like(leaf[1], 0.5))
+
+
+def test_a_pass_of_padding_alone_leaves_state_and_tail_as_they_were():
+    """``length`` 0: every position of the pass steps nothing (``g`` =
+    0, ``beta`` = 0), so the state and the convolution's tail come back
+    bit for bit."""
+    mixer = KdaMixer(KdaSpec(heads=2, head_dim=8, rank=4), 16)
+    u = jax.random.normal(jax.random.PRNGKey(0), (1, 80, 16))
+    params = jax.jit(mixer.init)(jax.random.PRNGKey(1), u)
+    scan = jax.jit(functools.partial(mixer.apply, method="scan"))
+    empty = tuple(
+        jnp.zeros(t.shape, t.dtype)
+        for t in mixer.spec.state_shapes(1, jnp.float32)
+    )
+    _, carried = scan(params, u, empty, 70)
+    _, after = scan(params, u, carried, 0)
+    for was, now in zip(carried, after):
+        assert np.abs(np.asarray(was)).max() > 0
+        np.testing.assert_array_equal(now, was)
 
 
 # -- the served model against the plain reference ------------------------------
