@@ -209,8 +209,23 @@ class ExpertSpec:
     #: A ``swiglu_limit`` on every expert, the shared one too
     #: (:func:`limited`); None: none.
     swiglu_limit: float | None = None
+    #: ``(n_group, topk_group)``: a group-limited choice (DeepSeek-V3):
+    #: the experts lie in ``n_group`` groups of consecutive experts, a
+    #: group scores the sum of its two largest (score + bias), only the
+    #: ``topk_group`` best groups' experts can be chosen. None: no limit.
+    groups: tuple[int, int] | None = None
 
     def __post_init__(self):
+        if self.groups is not None:
+            n, keep = self.groups
+            if not 1 <= keep <= n or self.num_experts % n or (
+                keep * (self.num_experts // n) < self.top_k
+            ) or self.num_experts // n < 2:
+                raise ValueError(
+                    f"groups={self.groups}: {self.num_experts} experts do "
+                    f"not lie in {n} groups of at least 2 of which {keep} "
+                    f"hold top_k {self.top_k}"
+                )
         if not 1 <= self.top_k <= self.num_experts:
             raise ValueError(
                 f"top_k {self.top_k} outside [1, num_experts="
@@ -230,6 +245,20 @@ class ExpertSpec:
         return self.held or (0, self.num_experts)
 
 
+def _group_limited(chosen, n_group: int, keep: int):
+    """(n, E) choice scores with every expert outside the ``keep`` best
+    of ``n_group`` groups at -inf; a group's score is the sum of its two
+    largest."""
+    n = chosen.shape[0]
+    grouped = chosen.reshape(n, n_group, -1)
+    best = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)  # (n, groups)
+    _, kept = jax.lax.top_k(best, keep)
+    mask = jnp.zeros((n, n_group), bool).at[
+        jnp.arange(n)[:, None], kept
+    ].set(True)
+    return jnp.where(mask[..., None], grouped, -jnp.inf).reshape(n, -1)
+
+
 def route(spec: ExpertSpec, logits: jax.Array, bias: jax.Array | None):
     """Router logits (n, E) float32 -> the chosen experts (n, k) int32
     and their weights (n, k) float32. The choice is by score plus
@@ -239,6 +268,8 @@ def route(spec: ExpertSpec, logits: jax.Array, bias: jax.Array | None):
     else:
         scores = jax.nn.softmax(logits, axis=-1)
     chosen = scores if bias is None else scores + bias
+    if spec.groups is not None:
+        chosen = _group_limited(chosen, *spec.groups)
     _, idx = jax.lax.top_k(chosen, spec.top_k)
     w = jnp.take_along_axis(scores, idx, axis=-1)
     if spec.normalize:

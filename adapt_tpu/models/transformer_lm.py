@@ -290,6 +290,14 @@ class BlockSpec:
         (no head axis); None: K and V a KV head."""
         return None if self.latent is None else self.latent.row
 
+    @property
+    def cache_index_row(self) -> int | None:
+        """Width of the index key a position stores in a SELECTING
+        latent block's second plane; None: the block keeps none."""
+        if self.latent is None or self.latent.index is None:
+            return None
+        return self.latent.index.dim
+
 
 def _norm(kind: str, eps: float, dtype):
     """A norm module by its spec's ``norm`` and ``norm_eps`` (one
@@ -1589,8 +1597,9 @@ def validate_generate_args(
     if any(sp.latent for sp in specs):
         raise ValueError(
             "generate() decodes over dense per-head cache strips: a "
-            "latent-attention model keeps one row a position and serves "
-            "through ContinuousBatcher"
+            "latent-attention model keeps one row a position (a selecting "
+            "one an index key beside it) and serves through "
+            "ContinuousBatcher"
         )
     if steps < 1:
         raise ValueError(f"steps must be >= 1, got {steps}")

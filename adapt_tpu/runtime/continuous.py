@@ -546,8 +546,8 @@ class _AsyncFetch:
 # Rows several features share: one that needs every property, one that
 # SHARES a prompt page between requests, one that MOVES pages of K and V.
 _ALL = dict.fromkeys(CACHE_PROPERTIES, "")
-_SHARES = {"one_group": "", "pages_only": ""}
-_MOVES = {"pages_only": "", "per_head_pages": ""}
+_SHARES = {"one_group": "", "pages_only": "", "one_plane": ""}
+_MOVES = {"pages_only": "", "per_head_pages": "", "one_plane": ""}
 #: What each feature needs of the model's cache
 #: (``paged.CacheLayout.lacks``): a property it names here it cannot do
 #: without, and the value is what a refusal for THAT property says
@@ -560,14 +560,17 @@ _CACHE_NEEDS: dict[str, dict[str, str]] = {
                       "be un-stepped)",
         "per_head_pages": " (speculative decoding: verify_chunk_paged "
                           "reads per-head pages)",
+        "one_plane": " (speculative decoding: no verify pass scores and "
+                     "selects)",
     },
-    "a tp mesh": {"one_group": "", "per_head_pages": ""},
+    "a tp mesh": {"one_group": "", "per_head_pages": "", "one_plane": ""},
     "a host cache tier": _ALL,
     "sequence-parallel prefill": _ALL,
     "cache-aware admission (the radix prefix cache)": _SHARES,
     "elastic recovery (health=)": _MOVES,
     "a quantized KV pool": {
         "pages_only": " beside it (untested)", "per_head_pages": "",
+        "one_plane": "",
     },
     "a handoff of prefilled pages": _ALL,
     "the radix prefix cache": _SHARES,
@@ -909,6 +912,12 @@ class ContinuousBatcher:
         self._linear_blocks = sum(
             1 for sp in specs if sp.linear is not None
         )
+        #: What a selecting block reads of a context at most
+        #: (``dsa.positions_selected``); 0: no block selects.
+        self._select_top_k = max(
+            (specs[i].latent.index.top_k
+             for i in self._layout.selecting_blocks), default=0,
+        )
         self._state_families = tuple(
             family for family, has in (
                 ("ssm", any(sp.ssm for sp in specs)),
@@ -999,6 +1008,7 @@ class ContinuousBatcher:
                 self._pagers[gi].num_pages, groups[gi].kv_heads,
                 page_size, groups[gi].head_dim, block.dtype,
                 kv_cache_dtype, row=groups[gi].row,
+                index_row=groups[gi].index_row,
             ) if block.spec.linear is None else None
             for gi, block in zip(self._layout.group_of, self._blocks)
         ]
@@ -4717,6 +4727,24 @@ class ContinuousBatcher:
             dev for _, dev in self._group_tables
         )
 
+    def _count_selection(self, fl: "_InFlight") -> None:
+        """``dsa.*`` of one committed tick, from the host's own
+        positions (no device read): the selecting layers' scan steps,
+        and over the tick's live rows, steps and selecting layers the
+        positions an indexer scored (the context) and those the
+        attention then read (at most ``top_k`` of it)."""
+        layers = len(self._layout.selecting_blocks)
+        ctx = np.asarray([
+            slot.pos for i, slot in enumerate(self.slots)
+            if fl.reqs[i] is not None and slot.req is fl.reqs[i]
+        ], np.int64)[:, None] + np.arange(1, self.chunk + 1)
+        m = global_metrics()
+        m.inc("dsa.steps", float(layers * self.chunk))
+        m.inc("dsa.positions_scored", float(layers * ctx.sum()))
+        m.inc("dsa.positions_selected", float(
+            layers * np.minimum(ctx, self._select_top_k).sum()
+        ))
+
     def _require(self, *features: str) -> None:
         """Refuse by name what this model's cache cannot do for one of
         ``features`` (:func:`_unmet`)."""
@@ -5243,6 +5271,8 @@ class ContinuousBatcher:
                     "mla.steps",
                     float(len(self._layout.latent_blocks) * self.chunk),
                 )
+            if self._select_top_k:
+                self._count_selection(fl)
             limits = np.full((toks.shape[1],), self.chunk, np.int64)
             if tracer.enabled and fl.t_span:
                 # Dispatch -> results-landed of one compiled decode

@@ -754,11 +754,17 @@ class CacheGroup:
     #: and ``head_dim`` then say nothing of the pool. None: K and V a
     #: KV head.
     row: int | None = None
+    #: A SELECTING latent group (``BlockSpec.cache_index_row``): a
+    #: position also stores one index key this wide, in a second plane
+    #: the same page table addresses. None: one plane.
+    index_row: int | None = None
 
     @property
     def position_values(self) -> int:
         """Values ONE position stores in a block's pool."""
-        return self.row or 2 * self.kv_heads * self.head_dim
+        if self.row:
+            return self.row + (self.index_row or 0)
+        return 2 * self.kv_heads * self.head_dim
 
 
 def cache_groups(specs) -> list[CacheGroup]:
@@ -773,7 +779,7 @@ def cache_groups(specs) -> list[CacheGroup]:
             continue
         keys.setdefault(
             (spec.window, spec.cache_heads, spec.attn_head_dim,
-             spec.cache_row), []
+             spec.cache_row, spec.cache_index_row), []
         ).append(i)
     kinds = ["full" if k[0] is None else "window" for k in keys]
     out = []
@@ -781,13 +787,17 @@ def cache_groups(specs) -> list[CacheGroup]:
         kind = kinds[n]
         if kinds.count(kind) > 1:
             kind += str(kinds[:n].count(kind))
-        out.append(CacheGroup(kind, *key[:3], tuple(blocks), row=key[3]))
+        out.append(CacheGroup(
+            kind, *key[:3], tuple(blocks), row=key[3], index_row=key[4]
+        ))
     return out
 
 
 #: What a feature may need of a model's cache (``CacheLayout.lacks``),
 #: in the order a refusal names them.
-CACHE_PROPERTIES = ("one_group", "pages_only", "per_head_pages")
+CACHE_PROPERTIES = (
+    "one_group", "pages_only", "per_head_pages", "one_plane",
+)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -802,6 +812,9 @@ class CacheLayout:
     #: pages (``BlockSpec.ssm``) or in place of them (``.linear``).
     state_blocks: tuple[int, ...]
     latent_blocks: tuple[int, ...]  # one row a position, no head axis
+    #: Latent blocks that SELECT what they read: an index key a
+    #: position in a second plane beside the row.
+    selecting_blocks: tuple[int, ...] = ()
 
     def lacks(self, prop: str) -> tuple[str, str] | None:
         """None where this cache has ``prop`` (of ``CACHE_PROPERTIES``),
@@ -826,6 +839,15 @@ class CacheLayout:
                 f"{len(self.latent_blocks)} blocks keep one {row}-value "
                 "row a position, no head axis"
             )
+        if prop == "one_plane" and self.selecting_blocks:
+            # A page shared, moved or verified is its rows AND the index
+            # keys of the same positions; nothing moves the pair yet.
+            group = self.groups[self.group_of[self.selecting_blocks[0]]]
+            return "a selecting cache", (
+                f"{len(self.selecting_blocks)} blocks keep a "
+                f"{group.index_row}-value index key a position in a second "
+                "plane and attend the positions it picks"
+            )
         return None
 
 
@@ -841,6 +863,10 @@ def cache_layout(specs) -> CacheLayout:
         tuple(groups), tuple(group_of),
         tuple(i for i, sp in enumerate(specs) if sp.state_spec is not None),
         tuple(i for i, sp in enumerate(specs) if sp.latent is not None),
+        tuple(
+            i for i, sp in enumerate(specs)
+            if sp.cache_index_row is not None
+        ),
     )
 
 
@@ -903,6 +929,7 @@ def alloc_kv_pools(
     dtype,
     kv_cache_dtype: str = "native",
     row: int | None = None,
+    index_row: int | None = None,
 ):
     """One decoder block's zeroed page pool — THE definition of what a
     pool is. ``row`` (a latent-attention block, ``CacheGroup.row``):
@@ -912,7 +939,13 @@ def alloc_kv_pools(
     page's positions lie on the minor axis (where a TPU puts them for
     a row that does not fill whole lane tiles), and every consumer
     knows the format by the plane's three dimensions
-    (``ops/latent_attention``). Otherwise:
+    (``ops/latent_attention``). With ``index_row`` (a SELECTING latent
+    block, ``CacheGroup.index_row``) the pool is the PAIR ``(rows,
+    index keys)``: a second plane ``(pool_pages, index_row,
+    page_size)`` of the same format, one index key a position, which
+    the same page table addresses (one grant and one free a page, as
+    the quantized pool's scale planes): the score pass streams it and
+    never the rows (``ops/sparse_latent_attention``). Otherwise:
 
     A position's K and V live side by side on the lanes of ONE
     row: lanes ``[0, w)`` hold K, lanes ``[w, 2w)`` V, ``w`` =
@@ -937,7 +970,10 @@ def alloc_kv_pools(
                 "quantized (one scale a K or V vector has no meaning for "
                 "a [c_kv | k_r] row)"
             )
-        return jnp.zeros((pool_pages, row, page_size), dtype)
+        rows = jnp.zeros((pool_pages, row, page_size), dtype)
+        if index_row is None:
+            return rows
+        return rows, jnp.zeros((pool_pages, index_row, page_size), dtype)
     width = kv_value_width(head_dim, kv_cache_dtype)
     plane = (pool_pages, kv_heads, page_size)
     if kv_cache_dtype == "native":

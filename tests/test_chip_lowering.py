@@ -1046,6 +1046,52 @@ def test_latent_decode_and_write_compile_for_v5e_in_place(
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
 
 
+def _sparse_latent_step(q, q_i, w, pool, ipool, new, key, phys, off, table,
+                        index):
+    from adapt_tpu.ops.latent_attention import append_latent_paged
+    from adapt_tpu.ops.sparse_latent_attention import (
+        sparse_latent_paged_attention,
+    )
+
+    pool = append_latent_paged(pool, new, phys, off)
+    ipool = append_latent_paged(ipool, key, phys, off)
+    return sparse_latent_paged_attention(
+        q, q_i, w, pool, ipool, table, index, sm_scale=0.13523, v_width=512,
+        top_k=2048,
+    ), pool, ipool
+
+
+def test_the_selecting_decode_compiles_for_v5e_in_place(
+    as_tpu, one_chip, no_persistent_cache
+):
+    """``dsv32_longgen32k``'s step of one layer at its shapes (32 slots
+    of 252 pages, 128 heads, 64 index heads of 128, both planes of the
+    cell's pool): Mosaic's own compile of the ONE kernel that scores,
+    bisects and reads, the write kernel once a plane, and no copy of
+    either plane around them."""
+    def on_chip(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    b, pages = 32, 8065
+    compiled = jax.jit(_sparse_latent_step, donate_argnums=(3, 4)).lower(
+        on_chip((b, 128, 576)), on_chip((b, 64, 128)),
+        on_chip((b, 64), jnp.float32), on_chip((pages, 576, 128)),
+        on_chip((pages, 128, 128)), on_chip((b, 576)), on_chip((b, 128)),
+        on_chip((b,), jnp.int32), on_chip((b,), jnp.int32),
+        on_chip((b, 252), jnp.int32), on_chip((b,), jnp.int32),
+    ).compile()
+    text = compiled.as_text()
+    call = re.search(r"%_sparse_latent_impl[.\d]* = .*tpu_custom_call.*", text)
+    assert call
+    for plane in (f"bf16[{pages},576,128]", f"bf16[{pages},128,128]"):
+        assert call.group(0).count(plane) == 1
+        assert not re.search(re.escape(plane) + r"\S* copy\(", text)
+    assert len(re.findall(
+        r"%_latent_write_impl[.\d]* = .*tpu_custom_call", text
+    )) == 2
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
+
+
 @pytest.mark.parametrize("rows,vocab", [
     (128, 261120),  # falconh1_longgen: the whole published vocabulary
     (256, 24576),  # solaropen2_longgen
