@@ -9,7 +9,8 @@ from __future__ import annotations
 
 from chipbench import deepseek_v32_yardstick as dy
 from chipbench import xtrace, yardstick
-from chipbench.k_exaone_readers import _op_seconds, _traced
+from chipbench.decode_runs import decode_runs, seconds_in
+from chipbench.k_exaone_readers import _op_seconds
 
 #: The device operations of the score pass, the top-k and the selected
 #: read, as a device trace names them (DEEPSEEK_V32.md, "operation
@@ -23,15 +24,17 @@ def _seconds(trace) -> float:
 
 
 def sparse_latent_roofline(trace, rec, kind):
-    """The bytes floor of selection and attention in the traced ticks
-    (every live row's index keys and its selected rows once a layer and
-    step, at the chip's HBM peak) against the device time of the score
-    pass, the top-k and the selected read together."""
-    seconds, s = _seconds(trace), rec["shape"]
+    """The bytes floor of selection and attention in the decode runs
+    the trace holds whole (every live row's index keys and its selected
+    rows once a layer and step, at the chip's HBM peak) against the
+    device time, inside those runs, of the score pass, the top-k and
+    the selected read together."""
+    s, runs = rec["shape"], decode_runs(trace, rec)
+    seconds = seconds_in(trace, runs, KERNELS) if runs else None
     if not seconds or "tick_contexts" not in rec or "index_row" not in s:
         return None
     nbytes = 0
-    for i, _ in _traced(rec):
+    for i, _, _ in runs:
         contexts = rec["tick_contexts"][i]
         for j in range(rec["serving"]["chunk"]):
             nbytes += dy.sparse_latent_cost(

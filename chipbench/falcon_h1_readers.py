@@ -8,22 +8,24 @@ from __future__ import annotations
 
 from chipbench import falcon_h1_yardstick as fy
 from chipbench import xtrace, yardstick
-from chipbench.k_exaone_readers import _op_seconds, _traced
+from chipbench.decode_runs import decode_runs, seconds_in
+from chipbench.k_exaone_readers import _op_seconds
 
 #: The kernel's name in a device trace (``adapt_tpu/ops/ssm_step.py``).
 KERNEL = "_ssm_step_impl"
 
 
 def ssm_step_roofline(trace, rec, kind):
-    """The state update's floor in the traced ticks (every live row's
-    state once in and once out, in every step of the tick's scan and
-    every mixer layer) against the device time of the kernel. The
-    floor counts live rows only, the kernel also moves an idle row's
-    state: the share errs low."""
-    seconds, m = _op_seconds(trace, KERNEL), rec["model"]
+    """The state update's floor in the decode runs the trace holds
+    whole (every live row's state once in and once out, in every step
+    of the run's scan and every mixer layer) against the device time
+    of the kernel inside those runs. The floor counts live rows only,
+    the kernel also moves an idle row's state: the share errs low."""
+    m, runs = rec["model"], decode_runs(trace, rec)
+    seconds = seconds_in(trace, runs, (KERNEL,)) if runs else None
     if not seconds or "tick_contexts" not in rec or "mamba_n_heads" not in m:
         return None
-    rows = sum(len(rec["tick_contexts"][i]) for i, _ in _traced(rec))
+    rows = sum(len(rec["tick_contexts"][i]) for i, _, _ in runs)
     flops, nbytes = fy.ssm_step_cost(
         rows * rec["serving"]["chunk"] * m["num_hidden_layers"],
         m["mamba_n_heads"], m["mamba_d_head"], m["mamba_d_state"],

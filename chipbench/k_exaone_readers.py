@@ -8,6 +8,7 @@ from __future__ import annotations
 
 from chipbench import k_exaone_yardstick as ky
 from chipbench import xtrace, yardstick
+from chipbench.decode_runs import decode_runs, seconds_in
 
 
 def _layers(rec):
@@ -62,16 +63,6 @@ def pool_peak_pct_window(trace, rec, kind):
     return _pool_peak_pct(rec, "window")
 
 
-def _traced(rec):
-    """(index, tick) of the ticks inside the traced part of the window
-    that decoded something."""
-    tr = rec["trace"]
-    return [
-        (i, t) for i, t in enumerate(rec["ticks"])
-        if tr["t0"] <= t[0] and t[1] <= tr["t1"] and t[2]
-    ]
-
-
 def _op_seconds(trace, name: str):
     if not trace or not trace.devices:
         return None
@@ -79,43 +70,49 @@ def _op_seconds(trace, name: str):
 
 
 def expert_product_roofline(trace, rec, kind):
-    """The grouped product's floor in the traced ticks against the
-    device time of ``gmm`` (the Pallas grouped matmul: three calls a
-    sparse layer and step). The counters cover the whole window; the
-    traced ticks take their share of them by decode steps, the closed
-    loop being as full at the window's end as at its start. The floor
-    counts live rows only, the kernel also routes what an idle row
-    holds: the share errs low."""
-    seconds, ticks = _op_seconds(trace, "gmm"), _traced(rec)
+    """The grouped product's floor in the decode runs the trace holds
+    whole against the device time of ``gmm`` inside those runs (the
+    Pallas grouped matmul: three calls a sparse layer and step; a
+    prefill program's calls are no decode step's and are left out, as
+    the counters leave them out). The counters cover the whole window;
+    the runs take their share of them by decode steps, the closed loop
+    being as full at the window's end as at its start. The floor counts
+    live rows only, the kernel also routes what an idle row holds: the
+    share errs low."""
+    runs = decode_runs(trace, rec)
+    seconds = seconds_in(trace, runs, ("gmm",)) if runs else None
     c = rec["counters"]
     steps = c.get("moe.steps")
-    if not seconds or not ticks or not steps:
+    if not seconds or not steps:
         return None
-    share = len(ticks) * rec["serving"]["chunk"] / steps
+    share = len(runs) * rec["serving"]["chunk"] / steps
     m = rec["model"]
     flops, nbytes = ky.expert_product_cost(
         c["moe.assignments_held"], c["moe.experts_hit"],
         m["hidden_size"], m["moe_intermediate_size"], rec["itemsize"],
     )
     floor = yardstick.floor_seconds(flops * share, nbytes * share, kind)
-    return 100.0 * floor / seconds
+    return 100.0 * floor / seconds if floor else None
 
 
 def paged_decode_grouped_roofline(trace, rec, kind):
-    """Bytes the decode kernel had to move in the traced ticks, the
-    full layer at each live row's context and each window layer at
-    ``min(context, window)``, against the device time of
-    ``_paged_impl``."""
-    seconds, ticks = _op_seconds(trace, "_paged_impl"), _traced(rec)
-    if not seconds or not ticks or "tick_contexts" not in rec:
+    """Bytes the decode kernel had to move in the decode runs the trace
+    holds whole, the full layer at each live row's context and each
+    window layer at ``min(context, window)``, against the device time
+    of ``_paged_impl`` inside those runs."""
+    runs = decode_runs(trace, rec)
+    seconds = seconds_in(trace, runs, ("_paged_impl",)) if runs else None
+    if not seconds or "tick_contexts" not in rec:
         return None
     windows, _, _ = _layers(rec)
     s = rec["shape"]
     nbytes = 0
-    for i, _ in ticks:
+    for i, _, _ in runs:
         for j in range(rec["serving"]["chunk"]):
             nbytes += ky.grouped_decode_bytes(
                 rec["tick_contexts"][i], j, windows, s["heads"],
                 s["kv_heads"], s["head_dim"], rec["itemsize"],
             )
+    if not nbytes:
+        return None
     return 100.0 * yardstick.floor_seconds(0, nbytes, kind) / seconds

@@ -24,6 +24,7 @@ from chipbench import manifest as mf
 from chipbench import stall
 from chipbench import traffic as tg
 from chipbench import window as win
+from chipbench import xtrace
 
 #: Seconds of the window that a ``--trace 1`` run records (its end).
 TRACE_SECONDS = 8.0
@@ -313,7 +314,10 @@ def measure(drv: Driver, traffic: dict, pairs, seconds: float, seed: int,
     """The measured window. Opens right after a tick has committed and
     closes right after the first tick that ends past ``seconds``: both
     edges sit on commit instants, so a rate counts whole ticks over
-    exactly the time they took."""
+    exactly the time they took. A traced pass reads ``t0`` and ``t1``
+    inside a span each, which puts both instants on the profiler's
+    clock (``xtrace.device_window`` cuts the device's busy time at
+    them); an untraced pass reaches neither."""
     import jax
 
     arrivals = (
@@ -333,10 +337,11 @@ def measure(drv: Driver, traffic: dict, pairs, seconds: float, seed: int,
             opts = jax.profiler.ProfileOptions()
             opts.python_tracer_level = 0
             jax.profiler.start_trace(trace_dir, profiler_options=opts)
-            trace.update(
-                on=True, t0=time.perf_counter(),
-                prefill0=drv.srv.stats()["prefill_tokens"],
-            )
+            with drv.annotate(xtrace.WINDOW_OPEN):
+                trace.update(
+                    on=True, t0=time.perf_counter(),
+                    prefill0=drv.srv.stats()["prefill_tokens"],
+                )
         while i < len(arrivals) and t_open + arrivals[i].due_s <= now:
             due = t_open + arrivals[i].due_s
             drv.submit(arrivals[i], due)
@@ -349,9 +354,10 @@ def measure(drv: Driver, traffic: dict, pairs, seconds: float, seed: int,
             time.sleep(0.001)  # idle server: wait for the next arrival
     t_close = time.perf_counter()
     if trace["on"]:
-        trace.update(
-            t1=t_close, prefill1=drv.srv.stats()["prefill_tokens"]
-        )
+        with drv.annotate(xtrace.WINDOW_CLOSE):
+            trace.update(
+                t1=t_close, prefill1=drv.srv.stats()["prefill_tokens"]
+            )
         jax.profiler.stop_trace()
     return dict(t_open=t_open, t_close=t_close, late_ms=late_ms, trace=trace)
 

@@ -89,10 +89,7 @@ def _idle_by_span(trace):
     if not ticks:
         return None
     lo, hi = min(s for s, _ in ticks), max(e for _, e in ticks)
-    busy = xtrace.union(
-        (max(s, lo), min(e, hi))
-        for s, e, _ in trace.devices[0].ops if e > lo and s < hi
-    )
+    busy = xtrace.busy_between(trace.devices[0], lo, hi)
     if not busy:
         return None
     edges = [lo] + [t for s, e in busy for t in (s, e)] + [hi]

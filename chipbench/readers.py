@@ -11,11 +11,7 @@ from __future__ import annotations
 
 from chipbench import window as win
 from chipbench import xtrace, yardstick
-
-
-def _traced_ticks(rec):
-    tr = rec["trace"]
-    return [t for t in rec["ticks"] if tr["t0"] <= t[0] and t[1] <= tr["t1"]]
+from chipbench.decode_runs import decode_runs, seconds_in
 
 
 def _device(trace):
@@ -72,10 +68,7 @@ def tick_host_ms(trace, rec, kind):
     if dev is None or not spans:
         return None
     lo, hi = spans[0][0], spans[-1][1]
-    busy = sum(
-        min(e, hi) - max(s, lo)
-        for s, e in xtrace.union(dev.ops) if e > lo and s < hi
-    )
+    busy = sum(e - s for s, e in xtrace.busy_between(dev, lo, hi))
     return max(0.0, (hi - lo) - busy) / len(spans) / 1e6
 
 
@@ -109,21 +102,26 @@ def _attention_shape(rec):
 
 
 def paged_decode_roofline(trace, rec, kind):
-    """Bytes the decode kernel had to move in the traced ticks (every
-    live row's context once per layer and step) over peak bandwidth,
-    against the device time of ``_paged_impl``."""
-    dev, ticks = _device(trace), _traced_ticks(rec)
-    seconds = xtrace.op_seconds(dev).get("_paged_impl") if dev else None
-    if not seconds or not ticks:
+    """Bytes the decode kernel had to move in the decode runs the trace
+    holds whole (every live row's context once per layer and step)
+    over peak bandwidth, against the device time of ``_paged_impl``
+    inside those runs."""
+    runs = decode_runs(trace, rec)
+    seconds = seconds_in(trace, runs, ("_paged_impl",)) if runs else None
+    if not seconds:
         return None
     heads, kvh, hd, layers = _attention_shape(rec)
     chunk = rec["serving"]["chunk"]
     nbytes = 0
-    for _, _, rows, ctx in ticks:
+    for i, _, _ in runs:
+        # the rows the books held live at the launch and their contexts
+        rows, ctx = len(rec["tick_contexts"][i]), rec["ticks"][i][3]
         for j in range(chunk):
             nbytes += layers * yardstick.paged_decode_bytes(
                 ctx + j * rows, rows, heads, kvh, hd, rec["itemsize"]
             )
+    if not nbytes:
+        return None
     return 100.0 * yardstick.floor_seconds(0, nbytes, kind) / seconds
 
 

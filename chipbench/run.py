@@ -48,6 +48,37 @@ def _device_block(devices, chips: int) -> dict:
     }
 
 
+def traced_device(trace) -> tuple[dict, dict]:
+    """(``busy_s`` and ``window_s`` of the device block, ``breakdown``)
+    of a traced run. The window's two edges and every interval are on
+    the profiler's clock (``xtrace.device_window``): ``window_s`` is the
+    time between the edges, ``busy_s`` the mean over the devices of the
+    time inside them in which an operation ran, so 0 <= ``busy_s`` <=
+    ``window_s`` whatever overhangs an edge, and ``idle_gaps`` (device
+    0, the same edges) sums to device 0's ``window_s`` less busy time.
+    ``device_ops`` is the whole trace's: what ran, beside the window
+    (the kernels' rooflines sum theirs over the decode runs the trace
+    holds whole: ``decode_runs.py``)."""
+    lo, hi = xtrace.device_window(trace)
+    busy = [
+        sum(e - s for s, e in xtrace.busy_between(d, lo, hi)) / 1e9
+        for d in trace.devices
+    ]
+    dev = trace.devices[0]
+    gaps = xtrace.idle_gaps(dev, trace.host, lo, hi)
+    return (
+        {"busy_s": sum(busy) / len(busy), "window_s": (hi - lo) / 1e9},
+        {
+            "device_ops": xtrace.top(
+                {_sanitize(k): v for k, v in xtrace.op_seconds(dev).items()}
+            ),
+            "idle_gaps": xtrace.top(
+                {_sanitize(k): v for k, v in gaps.items()}
+            ),
+        },
+    )
+
+
 def run_one(manifest, name: str, args, root: Path = ROOT):
     """Run one cell; returns the result object (None in rehearsal)."""
     if not (args.rehearse and args.trace):
@@ -142,20 +173,8 @@ def _run_one(manifest, name: str, args, root: Path, trace_dir: str):
         "failed": out["failed"], "metrics": metrics, "device": device,
     }
     if trace is not None and trace.devices:
-        tr = out["records"]["trace"]
-        busy = [xtrace.busy_seconds(d) for d in trace.devices]
-        device["busy_s"] = sum(busy) / len(busy)
-        device["window_s"] = tr["t1"] - tr["t0"]
-        dev = trace.devices[0]
-        result["breakdown"] = {
-            "device_ops": xtrace.top(
-                {_sanitize(k): v for k, v in xtrace.op_seconds(dev).items()}
-            ),
-            "idle_gaps": xtrace.top(
-                {_sanitize(k): v
-                 for k, v in xtrace.idle_gaps(dev, trace.host).items()}
-            ),
-        }
+        seconds, result["breakdown"] = traced_device(trace)
+        device.update(seconds)
     # Every number compared beside its limit, as standard error's last
     # lines too: of a run that is not correct the driver keeps those.
     for line in out.get("compared", []):

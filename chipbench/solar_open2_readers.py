@@ -8,22 +8,25 @@ from __future__ import annotations
 
 from chipbench import solar_open2_yardstick as sy
 from chipbench import xtrace, yardstick
-from chipbench.k_exaone_readers import _op_seconds, _traced
+from chipbench.decode_runs import decode_runs, seconds_in
+from chipbench.k_exaone_readers import _op_seconds
 
 #: The kernel's name in a device trace (``adapt_tpu/ops/kda_step.py``).
 KERNEL = "_kda_step_impl"
 
 
 def kda_step_roofline(trace, rec, kind):
-    """The state update's floor in the traced ticks (every live row's
-    state once in and once out, in every step of the tick's scan and
-    every linear-attention layer) against the device time of the
-    kernel. The floor counts live rows only, the kernel also moves an
-    idle row's state: the share errs low."""
-    seconds, s = _op_seconds(trace, KERNEL), rec["shape"]
+    """The state update's floor in the decode runs the trace holds
+    whole (every live row's state once in and once out, in every step
+    of the run's scan and every linear-attention layer) against the
+    device time of the kernel inside those runs. The floor counts live
+    rows only, the kernel also moves an idle row's state: the share
+    errs low."""
+    s, runs = rec["shape"], decode_runs(trace, rec)
+    seconds = seconds_in(trace, runs, (KERNEL,)) if runs else None
     if not seconds or "tick_contexts" not in rec or "kda_layers" not in s:
         return None
-    rows = sum(len(rec["tick_contexts"][i]) for i, _ in _traced(rec))
+    rows = sum(len(rec["tick_contexts"][i]) for i, _, _ in runs)
     flops, nbytes = sy.kda_step_cost(
         rows * rec["serving"]["chunk"] * s["kda_layers"], s["kda_heads"],
         s["kda_head_dim"], rec["itemsize"],

@@ -7,7 +7,8 @@ from __future__ import annotations
 
 from chipbench import xing4_yardstick as xy
 from chipbench import xtrace, yardstick
-from chipbench.k_exaone_readers import _op_seconds, _traced
+from chipbench.decode_runs import decode_runs, seconds_in
+from chipbench.k_exaone_readers import _op_seconds
 
 #: The kernel's name in a device trace
 #: (``adapt_tpu/ops/latent_attention.py``).
@@ -15,15 +16,16 @@ KERNEL = "_latent_impl"
 
 
 def latent_decode_roofline(trace, rec, kind):
-    """The kernel's floor in the traced ticks (every live row's
-    context once a layer and step, the larger of its bytes over peak
-    bandwidth and its operations over peak rate) against the device
-    time of the kernel."""
-    seconds, s = _op_seconds(trace, KERNEL), rec["shape"]
+    """The kernel's floor in the decode runs the trace holds whole
+    (every live row's context once a layer and step, the larger of its
+    bytes over peak bandwidth and its operations over peak rate)
+    against the device time of the kernel inside those runs."""
+    s, runs = rec["shape"], decode_runs(trace, rec)
+    seconds = seconds_in(trace, runs, (KERNEL,)) if runs else None
     if not seconds or "tick_contexts" not in rec or "latent_row" not in s:
         return None
     floor = 0.0
-    for i, _ in _traced(rec):
+    for i, _, _ in runs:
         contexts = rec["tick_contexts"][i]
         for j in range(rec["serving"]["chunk"]):
             flops, nbytes = xy.latent_decode_cost(

@@ -25,6 +25,11 @@ _MODULE = re.compile(r"^(?:jit_)?(.*?)(?:\(\d+\))?$")
 #: operations, not something the host did; they are summed apart.
 SHORT_GAP_NS = 20_000
 _WRAPPERS = frozenset({"while", "conditional", "call"})
+#: The traced window's two edges, as ``lm_engine.measure`` marks them on
+#: the profiler's clock: the window runs from the END of the open mark
+#: to the START of the close mark.
+WINDOW_OPEN = "chipbench.window_open"
+WINDOW_CLOSE = "chipbench.window_close"
 
 
 def op_name(event_name: str) -> str:
@@ -114,8 +119,39 @@ def union(intervals) -> list[tuple[float, float]]:
 
 
 def busy_seconds(dev: DeviceTrace) -> float:
-    """Seconds in which at least one operation ran on the device."""
+    """Seconds in which at least one operation ran on the device, over
+    the whole trace."""
     return sum(e - s for s, e in union(dev.ops)) / 1e9
+
+
+def _clip(busy, lo_ns, hi_ns):
+    return [
+        (max(s, lo_ns), min(e, hi_ns))
+        for s, e in busy if e > lo_ns and s < hi_ns
+    ]
+
+
+def busy_between(dev: DeviceTrace, lo_ns, hi_ns) -> list[tuple[float, float]]:
+    """``union(dev.ops)`` clipped to ``[lo_ns, hi_ns]``: the intervals
+    of the window in which an operation ran. An operation that
+    overhangs an edge counts up to the edge."""
+    return _clip(union(dev.ops), lo_ns, hi_ns)
+
+
+def device_window(trace: Trace) -> tuple[float, float]:
+    """(lo_ns, hi_ns) of the traced window, both on the profiler's
+    clock: the end of the first ``WINDOW_OPEN`` mark and the start of
+    the last ``WINDOW_CLOSE`` mark. An edge whose mark the trace does
+    not hold (an engine that does not go through ``lm_engine.measure``)
+    is device 0's first operation's start, or its last one's end. Not
+    the extent of the ``chipbench.tick`` spans: an open loop's server
+    sleeps between requests, and its idle tail belongs to the window."""
+    opened = [e for _, e, name in trace.host if name == WINDOW_OPEN]
+    closed = [s for s, _, name in trace.host if name == WINDOW_CLOSE]
+    ops = trace.devices[0].ops
+    lo = min(opened) if opened else min(s for s, _, _ in ops)
+    hi = max(closed) if closed else max(e for _, e, _ in ops)
+    return lo, hi
 
 
 def op_seconds(dev: DeviceTrace) -> dict[str, float]:
@@ -139,16 +175,20 @@ def module_seconds(dev: DeviceTrace) -> dict[str, tuple[int, float]]:
 
 
 def idle_gaps(dev: DeviceTrace, host, t0_ns=None, t1_ns=None):
-    """Idle seconds of the device by the host event that covers the
-    middle of each gap: the innermost (shortest) covering event, so a
-    ``chipbench.*`` annotation is named only where nothing more
-    specific (``PjitFunction_*``, a transfer) ran inside it. Gaps
-    under ``SHORT_GAP_NS`` go to ``gaps_under_20us``."""
+    """Idle seconds of the device between ``t0_ns`` and ``t1_ns`` (the
+    first operation's start and the last one's end where not given) by
+    the host event that covers the middle of each gap: the innermost
+    (shortest) covering event, so a ``chipbench.*`` annotation is named
+    only where nothing more specific (``PjitFunction_*``, a transfer)
+    ran inside it. Gaps under ``SHORT_GAP_NS`` go to
+    ``gaps_under_20us``. The values sum to the window less the busy
+    time of ``busy_between`` over the same edges."""
     busy = union(dev.ops)
     if not busy:
         return {}
     t0 = busy[0][0] if t0_ns is None else t0_ns
     t1 = busy[-1][1] if t1_ns is None else t1_ns
+    busy = _clip(busy, t0, t1)
     edges = [t0] + [x for s, e in busy for x in (s, e)] + [t1]
     gaps = [
         (edges[i], edges[i + 1])

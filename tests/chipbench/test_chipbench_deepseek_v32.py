@@ -21,6 +21,7 @@ from chipbench import deepseek_v32_reference as ref
 from chipbench import deepseek_v32_yardstick as dy
 from chipbench import manifest as mf
 from chipbench import traffic as tg
+from paired_trace import trace_of
 
 ROOT = Path(__file__).parents[2]
 CELL = "dsv32_longgen32k"
@@ -304,21 +305,16 @@ def _record():
 
 
 def _trace(ops, modules):
-    dev = types.SimpleNamespace(ops=ops, modules=modules)
-    return types.SimpleNamespace(devices=[dev], host=[])
+    return trace_of(_record(), ops, modules)
 
 
-def test_the_floor_is_token_granular_and_the_readers_read_it(monkeypatch):
+def test_the_floor_is_token_granular_and_the_readers_read_it():
     """One traced tick that decoded 2 rows for 8 steps: every live
     position's index key once a layer, and the selected rows (at most
     2,048 a row) once a layer."""
-    from chipbench import xtrace
-
     assert dy.sparse_latent_cost([10000, 300], 5, 2048, 128, 576, 2) == (
         5 * 2 * ((10000 + 300) * 128 + (2048 + 300) * 576)
     )
-    monkeypatch.setattr(xtrace, "op_seconds", lambda dev: dev.ops)
-    monkeypatch.setattr(xtrace, "module_seconds", lambda dev: dev.modules)
     rec = _record()
     seen = _trace({dr.KERNELS[0]: 0.004}, {"_step_chunk": (1, 0.016)})
     nbytes = sum(

@@ -21,6 +21,7 @@ from chipbench import manifest as mf
 from chipbench import solar_open2_readers as sr
 from chipbench import traffic as tg
 from chipbench import xing4_readers as xr
+from paired_trace import trace_of
 
 ROOT = Path(__file__).parents[2]
 CELL = "gigachat35_longgen8k"
@@ -242,21 +243,18 @@ def _record():
 
 
 def _trace(ops, modules):
-    dev = types.SimpleNamespace(ops=ops, modules=modules)
-    return types.SimpleNamespace(devices=[dev], host=[])
+    return trace_of(_record(), ops, modules)
 
 
-def test_both_families_of_readers_take_the_builders_shape(monkeypatch):
+def test_both_families_of_readers_take_the_builders_shape():
     """One traced tick that decoded 2 rows for 8 steps: the state
     update's floor counts four layers of 64 heads (``g`` a channel and
     q, k a value head: 0.6% over this layer's own operands, which a
     floor that errs high would not forgive and this one does: 8.5 MB a
     row either way), the latent kernel's one layer of 64 heads."""
     from chipbench import xing4_yardstick as xy
-    from chipbench import xtrace, yardstick
+    from chipbench import yardstick
 
-    monkeypatch.setattr(xtrace, "op_seconds", lambda dev: dev.ops)
-    monkeypatch.setattr(xtrace, "module_seconds", lambda dev: dev.modules)
     rec = _record()
     seen = _trace(
         {sr.KERNEL: 0.002, xr.KERNEL: 0.001}, {"_step_chunk": (1, 0.008)}

@@ -15,7 +15,7 @@ from pathlib import Path
 import pytest
 
 from chipbench import run as bench_run
-from chipbench import xtrace, yardstick
+from chipbench import decode_runs, xtrace, yardstick
 from run_loop_cases import ADDED, ROOT, control_cases
 from run_loop_cases import rehearse as _rehearse
 
@@ -281,8 +281,10 @@ def test_addition_by_data(added, capsys, monkeypatch):
     assert NEW_METRIC in traced
     # The joined metrics read a device plane, and a CPU run has none:
     # put one in the trace's place (one kernel call, one step program,
-    # one tick span) and the readers that are there answer for the new
-    # cell from its ``shape`` and its records.
+    # one tick span; the run it holds whole is the last one that a tick
+    # with live rows launched: how ticks and runs pair on a chip is
+    # test_chipbench_decode_runs.py's) and the readers that are there
+    # answer for the new cell from its ``shape`` and its records.
     device = xtrace.DeviceTrace(
         ops=[(1_000, 9_000, "_paged_impl")],
         modules=[(0, 10_000, "_step_chunk")],
@@ -290,6 +292,9 @@ def test_addition_by_data(added, capsys, monkeypatch):
     monkeypatch.setattr(xtrace, "load", lambda path: xtrace.Trace(
         [device], [(0, 20_000, "chipbench.tick")]
     ))
+    monkeypatch.setattr(decode_runs, "_pair", lambda trace, rec: [(
+        max(i for i, c in enumerate(rec["tick_contexts"]) if c), 0, 10_000
+    )])
     monkeypatch.setitem(yardstick.PEAKS, "cpu", (1e12, 1e11))
     _, traced = _rehearse(
         capsys, "--root", str(root), "--workload", ADDED_CELL

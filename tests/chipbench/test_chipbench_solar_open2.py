@@ -20,6 +20,7 @@ from chipbench import solar_open2_readers as sr
 from chipbench import solar_open2_reference as ref
 from chipbench import solar_open2_yardstick as sy
 from chipbench import traffic as tg
+from paired_trace import trace_of
 
 ROOT = Path(__file__).parents[2]
 CELL = "solaropen2_longgen"
@@ -206,17 +207,10 @@ def _record():
 
 
 def _trace(ops, modules):
-    dev = types.SimpleNamespace(ops=ops, modules=modules)
-    return types.SimpleNamespace(devices=[dev], host=[])
+    return trace_of(_record(), ops, modules)
 
 
-def test_the_readers_find_nothing_where_the_program_has_no_such_kernel(
-    monkeypatch,
-):
-    from chipbench import xtrace
-
-    monkeypatch.setattr(xtrace, "op_seconds", lambda dev: dev.ops)
-    monkeypatch.setattr(xtrace, "module_seconds", lambda dev: dev.modules)
+def test_the_readers_find_nothing_where_the_program_has_no_such_kernel():
     rec = _record()
     for reader in (sr.kda_step_roofline, sr.kda_step_share_pct):
         assert reader(None, rec, "TPU v5e") is None

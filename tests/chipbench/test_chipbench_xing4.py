@@ -8,7 +8,6 @@ is written out). The file's four controls run in
 ``test_chipbench_run_loop_xing4.py``."""
 
 import json
-import types
 from pathlib import Path
 
 import pytest
@@ -19,6 +18,7 @@ from chipbench import traffic as tg
 from chipbench import xing4_readers as xr
 from chipbench import xing4_reference as ref
 from run_loop_cases import STARTUP_REHEARSED
+from paired_trace import trace_of
 
 ROOT = Path(__file__).parents[2]
 CELL = "xing4_longgen8k"
@@ -206,17 +206,10 @@ def _record():
 
 
 def _trace(ops, modules):
-    dev = types.SimpleNamespace(ops=ops, modules=modules)
-    return types.SimpleNamespace(devices=[dev], host=[])
+    return trace_of(_record(), ops, modules)
 
 
-def test_the_readers_find_nothing_where_the_program_has_no_such_kernel(
-    monkeypatch,
-):
-    from chipbench import xtrace
-
-    monkeypatch.setattr(xtrace, "op_seconds", lambda dev: dev.ops)
-    monkeypatch.setattr(xtrace, "module_seconds", lambda dev: dev.modules)
+def test_the_readers_find_nothing_where_the_program_has_no_such_kernel():
     rec = _record()
     for reader in (xr.latent_decode_roofline, xr.decode_share_pct):
         assert reader(None, rec, "TPU v5e") is None
